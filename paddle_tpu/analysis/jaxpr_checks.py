@@ -13,6 +13,7 @@ caught here on CPU first).
 import numpy as np
 
 import jax
+from jax.extend import core as jax_core
 
 from .diagnostics import Diagnostic
 
@@ -79,9 +80,9 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(value):
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jax_core.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jax_core.Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:

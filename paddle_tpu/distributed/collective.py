@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core.jax_compat import shard_map
+from jax import shard_map
 from ..core.tensor import Tensor
 from ..core.dispatch import in_trace
 from . import topology

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core.jax_compat import shard_map
+from jax import shard_map
 
 from ..core import dispatch, random as random_core
 from ..core.tensor import Tensor

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from ... import nn
-from ...core import jax_compat
 from ...core.tensor import Tensor
 from ...core.dispatch import apply_op
 from .. import topology
@@ -43,11 +42,11 @@ def pipeline_spmd_fn(stage_apply, num_stages, num_micro):
         stage = jax.lax.axis_index("pp")
         p_slice = jax.tree.map(lambda a: a[0], params_local)
         # mark carries as device-varying over pp (shard_map vma tracking)
-        carry_in = jax_compat.pcast(jnp.zeros_like(micro_local[0]), ("pp",), to="varying")
-        outputs = jax_compat.pcast(
+        carry_in = jax.lax.pcast(jnp.zeros_like(micro_local[0]), ("pp",), to="varying")
+        outputs = jax.lax.pcast(
             jnp.zeros((num_micro,) + micro_local.shape[1:], micro_local[0].dtype),
             ("pp",), to="varying")
-        micro_local = jax_compat.pcast(micro_local, ("pp",), to="varying")
+        micro_local = jax.lax.pcast(micro_local, ("pp",), to="varying")
         perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 
         def tick(state, t):

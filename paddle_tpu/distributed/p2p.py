@@ -55,7 +55,6 @@ import threading
 
 import numpy as np
 
-from ..core import jax_compat
 
 __all__ = ["get_transport", "shutdown"]
 
@@ -182,7 +181,7 @@ class Transport:
             import jax
             from jax._src.distributed import global_state
 
-            if jax_compat.distributed_is_initialized():
+            if jax.distributed.is_initialized():
                 return global_state.client
         except Exception:
             pass

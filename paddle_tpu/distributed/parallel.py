@@ -8,7 +8,6 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
-from ..core import jax_compat
 from ..nn.layer import Layer
 from . import topology
 
@@ -73,15 +72,7 @@ def init_parallel_env():
         n = 1
     coordinator = os.environ.get("PADDLE_COORDINATOR")
     if (n > 1 and coordinator and not _distributed_initialized
-            and not jax_compat.distributed_is_initialized()):
-        # 0.4.x CPU refuses multiprocess computations unless a host
-        # collectives backend is selected (newer jax defaults this); the
-        # option only affects CPU execution, so set it unconditionally
-        # rather than guessing the platform from env
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — unknown option / no gloo build
-            pass
+            and not jax.distributed.is_initialized()):
         # the coordination service races worker startup: early workers
         # see connection-refused/timeouts until the coordinator binds.
         # Backoff+jitter instead of crashing the whole gang (knobs:

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core import dispatch, jax_compat, random as random_core
+from ..core import dispatch, random as random_core
 from ..core.tensor import Tensor
 from . import topology
 
@@ -230,10 +230,10 @@ def build_pipeline_train_step(pre_layers, trunk_layers, post_layers, loss_fn,
         b_loc = h_local.shape[0]
         m_shape = (num_micro, b_loc // num_micro) + h_local.shape[1:]
         micro = h_local.reshape(m_shape)
-        micro = jax_compat.pcast(micro, ("pp",), to="varying")
-        carry_in = jax_compat.pcast(jnp.zeros(m_shape[1:], h_local.dtype),
+        micro = jax.lax.pcast(micro, ("pp",), to="varying")
+        carry_in = jax.lax.pcast(jnp.zeros(m_shape[1:], h_local.dtype),
                                  shard_axes, to="varying")
-        outputs = jax_compat.pcast(jnp.zeros(m_shape, h_local.dtype),
+        outputs = jax.lax.pcast(jnp.zeros(m_shape, h_local.dtype),
                                 shard_axes, to="varying")
         perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 
@@ -271,7 +271,7 @@ def build_pipeline_train_step(pre_layers, trunk_layers, post_layers, loss_fn,
     # expert parallel inside pipeline stages). For meshes with no such
     # axis this is identical to all-manual.
     manual_axes = frozenset(shard_axes)
-    trunk_fn = jax_compat.shard_map(
+    trunk_fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("pp"), h_in_spec, P()),
         out_specs=h_in_spec, axis_names=manual_axes)

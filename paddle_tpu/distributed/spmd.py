@@ -24,50 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core import dispatch, jax_compat, random as random_core
+from ..core import dispatch, random as random_core
 from ..core.tensor import Tensor
 from . import topology
 
-
-class _DonationSafeJit:
-    """Call a donating jit, falling back to a non-donating recompile when
-    XLA rejects the aliasing at run time.
-
-    Older jaxlib (0.4.x) CHECK-fails with ``INTERNAL: Expected aliased
-    input ... to have the same size`` when a donated param cannot alias
-    its resharded output (ZeRO/mp stacking changes the per-device
-    sub-shape); newer jaxlib just drops the alias with a warning. The
-    fallback trades the in-place update for correctness on such builds.
-
-    Caveat: the retry reuses the original argument arrays. On the 0.4.x
-    builds this targets, the aliasing CHECK fires before any donated
-    buffer is consumed (verified by the ZeRO/mp suites training through
-    the fallback); a runtime that consumed inputs before erroring would
-    surface 'Array has been deleted' here instead of silently corrupting
-    state.
-    """
-
-    def __init__(self, fn, jit_kwargs, donate_argnums):
-        self._fn = fn
-        self._kwargs = jit_kwargs
-        self.jitted = jax.jit(fn, donate_argnums=donate_argnums,
-                              **jit_kwargs)
-        self._donating = bool(donate_argnums)
-
-    def __call__(self, *args):
-        try:
-            return self.jitted(*args)
-        except Exception as e:  # noqa: BLE001 — matched on message below
-            if not self._donating or \
-                    "Expected aliased input" not in str(e):
-                raise
-            self._donating = False
-            self.jitted = jax.jit(self._fn, **self._kwargs)
-            return self.jitted(*args)
-
-    def lower(self, *args, **kwargs):
-        # AOT/lowering introspection (tests, memory checks)
-        return self.jitted.lower(*args, **kwargs)
+#: where offloaded optimizer state lives between steps
+_HOST_MEMORY_KIND = "pinned_host"
 
 
 def param_sharding_spec(layer, mesh):
@@ -203,6 +165,7 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
         try:
             with contextlib.ExitStack() as stack:
                 stack.enter_context(dispatch.trace_mode())
+                stack.enter_context(topology.tracing_for(mesh))
                 stack.enter_context(random_core.rng_guard(key))
                 if amp_enabled:
                     from ..amp.auto_cast import auto_cast as _auto_cast
@@ -285,10 +248,8 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
         local_grad_fn = comm_opt.make_local_grad_fn(
             forward_loss, data_axes, param_names,
             fp16_allreduce=fp16_allreduce, dgc_configs=dgc_configs)
-        from ..core.jax_compat import shard_map as _shard_map
-
         pspec = P(data_axes)
-        local_grads_smapped = _shard_map(
+        local_grads_smapped = jax.shard_map(
             local_grad_fn, mesh=mesh,
             in_specs=({n: P() for n in param_names},
                       {n: P() for n in buffer_names},
@@ -376,7 +337,7 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
             st = optimizer._init_state(params0[n])
             if offload:
                 opt_state[n] = tuple(
-                    jax.device_put(a, jax_compat.with_memory_kind(s, jax_compat.host_memory_kind())
+                    jax.device_put(a, s.with_memory_kind(_HOST_MEMORY_KIND)
                                    if a.ndim else s)
                     for a, s in zip(st, opt_state_specs[n]))
             elif shard_optimizer:
@@ -406,9 +367,9 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
     # donate params + opt_state: the step returns their replacements, so
     # XLA can update in place instead of holding both copies in HBM
     # (no-op on CPU backends, which don't implement donation)
-    step_jit = _DonationSafeJit(
-        step, dict(in_shardings=in_shardings, out_shardings=out_shardings),
-        donate_argnums=(0, 1) if donate else ())
+    step_jit = jax.jit(step, in_shardings=in_shardings,
+                       out_shardings=out_shardings,
+                       donate_argnums=(0, 1) if donate else ())
 
     # buffers thread through the step (BN stats / QAT scales update);
     # the latest values live in this cell and are synced back onto the
@@ -421,7 +382,7 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
         host memory and copies it in around the update)."""
         return {
             n: tuple(
-                jax.device_put(a, jax_compat.with_memory_kind(s, kind)) if a.ndim else a
+                jax.device_put(a, s.with_memory_kind(kind)) if a.ndim else a
                 for a, s in zip(opt_state[n], opt_state_specs[n]))
             for n in opt_state}
 
@@ -445,7 +406,7 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
             params, opt_state, buffers_cell["cur"], x, y, key, lr)
         loss, new_params, new_state, new_buffers = out[:4]
         if offload:
-            new_state = _bounce(new_state, jax_compat.host_memory_kind())
+            new_state = _bounce(new_state, _HOST_MEMORY_KIND)
         buffers_cell["cur"] = new_buffers
         if buffer_names:
             layer.load_functional_state(None, new_buffers)
@@ -621,11 +582,11 @@ def build_fsdp_train_step(layers, loss_fn, optimizer, mesh=None,
             new_state[n] = tuple(out[1:])
         return loss, new_params, new_state
 
-    step_jit = _DonationSafeJit(
+    step_jit = jax.jit(
         step,
-        dict(in_shardings=(param_shards, None, batch_shard, batch_shard,
-                           repl, repl),
-             out_shardings=(repl, param_shards, None)),
+        in_shardings=(param_shards, None, batch_shard, batch_shard,
+                      repl, repl),
+        out_shardings=(repl, param_shards, None),
         donate_argnums=(0, 1) if donate else ())
 
     def init_fn():

@@ -9,12 +9,16 @@ the innermost (fastest-varying) axis 'mp' maps to physically-adjacent
 chips on the ICI torus (tensor parallel needs the highest bandwidth),
 then 'sharding', then 'pp', then 'dp' (scaling-book §sharding recipe).
 """
+import contextlib
+import contextvars
+
 import numpy as np
 import jax
 from jax.sharding import Mesh
 
 _HYBRID_GROUP = None
 _GLOBAL_MESH = None
+_TRACED_MESH = contextvars.ContextVar("traced_mesh", default=None)
 
 AXIS_ORDER = ("dp", "pp", "sharding", "sp", "ep", "mp")
 
@@ -38,6 +42,25 @@ def data_axes(mesh):
     every consumer of its layout must agree on this set)."""
     return tuple(ax for ax in ("dp", "sharding")
                  if mesh.shape.get(ax, 1) > 1)
+
+
+@contextlib.contextmanager
+def tracing_for(mesh):
+    """Mark the code inside as being traced into a program partitioned
+    over ``mesh`` (the train-step builders enter it around the forward).
+    An op GSPMD cannot partition — a Mosaic kernel — reads it through
+    ``traced_mesh()`` and shards itself with a shard_map."""
+    token = _TRACED_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _TRACED_MESH.reset(token)
+
+
+def traced_mesh():
+    """The mesh of the partitioned program being traced, else None
+    (eager ops, to_static, a plain jax.jit)."""
+    return _TRACED_MESH.get()
 
 
 def set_global_mesh(mesh):

@@ -40,7 +40,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import nn
 from ..core.dispatch import apply_op
-from ..core.jax_compat import shard_map
+from jax import shard_map
 
 
 def _capacity_combine(xf, probs, top_k, cap):

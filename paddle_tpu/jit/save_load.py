@@ -2,8 +2,8 @@
 TranslatedLayer; format: save_inference_model's ProgramDesc+params).
 
 TPU-native format: serialized StableHLO (jax.export) + numpy params +
-a JSON signature — the portable compiled-program analog. Falls back to
-npz params + a marker when export is unavailable for an input spec.
+a JSON signature — the portable compiled-program analog. A model that
+cannot be exported at its input spec fails the save.
 """
 import json
 import os
@@ -82,10 +82,10 @@ def save(layer, path, input_spec=None, quant=None, quant_calib=None,
     """paddle.jit.save — export layer.forward at the given input spec.
 
     Dims given as None/-1 are exported batch-polymorphically (symbolic
-    shapes) when the model traces under them, so the saved StableHLO can
-    be run — and AOT-compiled per shape bucket by the serving engine —
-    at any concrete size. Models that cannot trace symbolically fall
-    back to the old behavior (dynamic dims pinned to 1).
+    shapes), so the saved StableHLO can be run — and AOT-compiled per
+    shape bucket by the serving engine — at any concrete size. A model
+    that cannot trace under symbolic sizes fails the save (give it
+    concrete dims instead); nothing is pinned to 1 behind its back.
 
     ``quant`` exports a QUANTIZED serving artifact (README "Quantized
     serving"): ``"w8"`` freezes every Linear/Conv2D to int8 weights +
@@ -199,58 +199,36 @@ def write_artifacts(path, jitted_fn, state_specs, input_specs, params,
     Input specs may carry jax.export symbolic dims (batch-polymorphic
     save); ``spec_candidates`` orders alternative symbolic spellings of
     the same spec (shared batch dim first, then independent symbols).
-    If every symbolic export fails — not every program traces under
-    abstract sizes — the export retries with those dims pinned to 1,
-    preserving the pre-polymorphism behavior."""
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    If no spelling exports, the save raises with the last error as its
+    cause and writes no artifact."""
+    from jax import export as jax_export
+
     from ..framework import op_version
 
-    payload = {
-        "params": params,
-        "buffers": buffers,
-        "input_specs": [_json_spec(s) for s in input_specs],
-        "op_versions": op_version.all_op_versions(),
-    }
-    symbolic = any(_is_symbolic_dim(d) for s in input_specs for d in s.shape)
-    attempts = [(c, any(_is_symbolic_dim(d) for s in c for d in s.shape))
-                for c in (spec_candidates or [input_specs])]
-    if symbolic:
-        concrete = [jax.ShapeDtypeStruct(
-            tuple(1 if _is_symbolic_dim(d) else int(d) for d in s.shape),
-            s.dtype) for s in input_specs]
-        attempts.append((concrete, False))
-    last_err = None
-    for specs, poly in attempts:
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    exported = specs = err = None
+    for cand in (spec_candidates or [input_specs]):
         try:
-            from jax import export as jax_export
-
-            exported = jax_export.export(jitted_fn)(*state_specs, *specs)
-            blob = serialize_exported(exported)
-            with open(path + ".pdmodel", "wb") as f:
-                f.write(blob)
-            payload["format"] = "stablehlo"
-            payload["polymorphic"] = poly
-            # content identity of the exported program (weights are
-            # runtime args): the serving engine keys its persistent
-            # compiled-artifact store on this. The quant mode folds in,
-            # so quantized programs are distinct store identities.
-            payload["fingerprint"] = model_fingerprint(blob, quant=quant)
-            # record the shapes actually exported (symbolic dims
-            # serialize as None; pinned dims as 1 on the fallback)
-            payload["input_specs"] = [_json_spec(s) for s in specs]
-            last_err = None
+            exported = jax_export.export(jitted_fn)(*state_specs, *cand)
+            specs = cand
             break
-        except Exception as e:  # noqa: BLE001
-            last_err = e
-    if last_err is not None:
-        payload["format"] = "params-only"
-        payload["export_error"] = repr(last_err)
+        except Exception as e:  # noqa: BLE001 - re-raised below
+            err = e
+    if exported is None:
+        raise RuntimeError(
+            f"jit.save({path!r}): the model does not export at input "
+            f"spec {[_json_spec(s) for s in input_specs]} "
+            f"({type(err).__name__}: {err}); give the failing dims "
+            "concrete sizes or make the forward shape-polymorphic") from err
+    blob = serialize_exported(exported)
+    with open(path + ".pdmodel", "wb") as f:
+        f.write(blob)
     # .pdiparams is an npz (never pickle: loaded models may come from
     # untrusted sources, and np.load defaults to allow_pickle=False);
     # bfloat16 arrays round-trip as uint16 views since numpy's npz
     # format has no native bf16
     arrays = {}
-    for prefix, d in (("p", payload["params"]), ("b", payload["buffers"])):
+    for prefix, d in (("p", params), ("b", buffers)):
         for n, a in d.items():
             a = np.asarray(a)
             if a.dtype.name == "bfloat16":
@@ -264,11 +242,19 @@ def write_artifacts(path, jitted_fn, state_specs, input_specs, params,
     with open(path + ".pdiparams", "wb") as f:
         f.write(buf.getvalue())
     with open(path + ".pdmeta.json", "w") as f:
-        json.dump({"format": payload["format"],
-                   "input_specs": payload["input_specs"],
-                   "polymorphic": payload.get("polymorphic", False),
-                   "fingerprint": payload.get("fingerprint"),
-                   "op_versions": payload["op_versions"],
+        json.dump({"format": "stablehlo",
+                   # the shapes actually exported (symbolic dims
+                   # serialize as None)
+                   "input_specs": [_json_spec(s) for s in specs],
+                   "polymorphic": any(_is_symbolic_dim(d)
+                                      for s in specs for d in s.shape),
+                   # content identity of the exported program (weights
+                   # are runtime args): the serving engine keys its
+                   # persistent compiled-artifact store on this. The
+                   # quant mode folds in, so quantized programs are
+                   # distinct store identities.
+                   "fingerprint": model_fingerprint(blob, quant=quant),
+                   "op_versions": op_version.all_op_versions(),
                    # serving quant mode (None = f32) + its scale
                    # metadata: jit.load re-folds the mode into the
                    # fingerprint it computes from the module bytes
@@ -278,8 +264,7 @@ def write_artifacts(path, jitted_fn, state_specs, input_specs, params,
                    # serve_model fail-fasts on contradiction; the
                    # program itself is mesh-independent (weights are
                    # runtime args, sharded at load by the engines)
-                   "mesh": mesh,
-                   "export_error": payload.get("export_error")}, f)
+                   "mesh": mesh}, f)
 
 
 class TranslatedLayer(Layer):
@@ -376,5 +361,5 @@ def load(path, **configs):
                                quant=quant,
                                mesh=payload.get("mesh"))
     raise RuntimeError(
-        f"model at {path} was saved without a serialized program "
-        f"({payload.get('export_error')}); re-save with a supported spec")
+        f"model at {path} has no serialized program (format "
+        f"{payload.get('format')!r}); re-save it with jit.save")

@@ -531,8 +531,7 @@ _HANDLERS = {
     "integer_pow": _h_integer_pow, "clamp": _h_clamp,
     "is_finite": None,  # replaced below to raise clearly
     "stop_gradient": _simple("Identity"), "copy": _simple("Identity"),
-    # jax 0.4.x materialises committed-constant placement as device_put
-    # eqns inside the jaxpr; placement has no ONNX meaning
+    # placement (device_put eqns inside the jaxpr) has no ONNX meaning
     "device_put": _simple("Identity"),
     "gt": _simple("Greater"), "lt": _simple("Less"),
     "ge": _h_opset12("GreaterOrEqual"), "le": _h_opset12("LessOrEqual"),

@@ -7,14 +7,16 @@ TPU-native design is a Pallas blockwise-softmax kernel (ops/pallas/
 flash_attention.py) selected on TPU, with this jnp implementation as the
 portable reference; XLA already fuses it into few kernels on TPU.
 """
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..core import flags, random as random_core
 from ..core.dispatch import apply_op
+from ..distributed import topology
 
 
 def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal,
@@ -49,20 +51,55 @@ def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal,
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-# kernel configs that failed once: skipped (with one warning each) so
-# every later step neither re-pays the failed trace nor hides it
-_KERNEL_FAILED = set()
-
-
 def _use_pallas():
-    if not flags.get_flags("use_pallas_kernels")["use_pallas_kernels"]:
+    """Kernel selection is a function of what can be observed: the
+    use_pallas_kernels flag and the platform (a TPU, or the
+    pallas_interpret flag the CPU tests and the chip_smoke dry run set).
+    A platform query that fails is an error, not "no kernel"."""
+    if not flags.flag_value("use_pallas_kernels"):
         return False
+    if flags.flag_value("pallas_interpret"):
+        return True
     from ..core.place import is_tpu_available
 
-    try:
-        return is_tpu_available()
-    except Exception:
-        return False
+    return is_tpu_available()
+
+
+def _flash(q, k, v, key, *, scale, is_causal, dropout_p, interpret):
+    """The Pallas kernel on [batch, heads, seq, head_dim]. Inside a step
+    being traced for a multi-device mesh (topology.traced_mesh) the call
+    is wrapped in a shard_map: GSPMD cannot partition a Mosaic kernel,
+    every mesh axis has to be manual around it. (batch, head) programs
+    are independent, so batch shards over the data axes and heads over
+    'mp' — each only where it divides; otherwise that dim is computed
+    whole on every device of the axis."""
+    from .pallas import flash_attention
+
+    seed = (jnp.zeros((), jnp.int32) if key is None else
+            jax.random.key_data(key).reshape(-1)[-1].astype(jnp.int32))
+    kernel = functools.partial(
+        flash_attention.mha, scale=scale, causal=is_causal,
+        dropout_p=dropout_p, interpret=interpret)
+    mesh = topology.traced_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v, seed=seed)
+
+    data = topology.data_axes(mesh)
+    n_data = math.prod(mesh.shape[ax] for ax in data)
+    n_mp = mesh.shape.get("mp", 1)
+    b_axes = data if n_data > 1 and q.shape[0] % n_data == 0 else ()
+    h_axes = ("mp",) if n_mp > 1 and q.shape[1] % n_mp == 0 else ()
+    spec = P(b_axes or None, h_axes or None, None, None)
+
+    def on_shard(q, k, v, seed):
+        # the mask hash counts (batch, head) from 0 on every shard: give
+        # each shard its own seed or they all drop the same entries
+        for ax in b_axes + h_axes:
+            seed = seed * jnp.int32(mesh.shape[ax]) + jax.lax.axis_index(ax)
+        return kernel(q, k, v, seed=seed)
+
+    return jax.shard_map(on_shard, mesh=mesh, in_specs=(spec, spec, spec, P()),
+                         out_specs=spec, check_vma=False)(q, k, v, seed)
 
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
@@ -84,34 +121,14 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
     min_seq = flags.flag_value("pallas_attention_min_seq")
     seq_q, seq_k = q.shape[-2], k.shape[-2]
     kernel_pays = seq_k >= min_seq or seq_q * seq_k >= min_seq * min_seq
-    fail_key = (tuple(q.shape), tuple(k.shape), str(q.dtype),
-                bool(is_causal), p > 0.0)
-    if (kernel_pays and fail_key not in _KERNEL_FAILED and _use_pallas()
-            and attn_mask is None):
-        from .pallas import flash_attention
-
-        def _flash(q, k, v, key, *, scale, is_causal, dropout_p):
-            seed = (None if key is None else
-                    jax.random.key_data(key).reshape(-1)[-1].astype(jnp.int32))
-            return flash_attention.mha(q, k, v, scale=scale, causal=is_causal,
-                                       dropout_p=dropout_p, seed=seed)
-
-        try:
-            return apply_op(
-                "flash_attention", _flash, q, k, v, key,
-                scale=scale, is_causal=bool(is_causal), dropout_p=p)
-        except Exception as e:
-            # fall back to the reference path, but never silently (a
-            # broken kernel would otherwise hide as a perf regression),
-            # and remember the config so later steps neither re-pay the
-            # failed trace nor drown the log
-            _KERNEL_FAILED.add(fail_key)
-            import warnings
-
-            warnings.warn(
-                f"flash attention kernel failed ({type(e).__name__}: "
-                f"{e}); falling back to the XLA reference path for "
-                f"this config from now on: {fail_key}", RuntimeWarning)
+    if kernel_pays and attn_mask is None and _use_pallas():
+        # a selected kernel that fails raises: falling back to the XLA
+        # path would report a broken kernel as a slow one. interpret
+        # rides the static kwargs so a flag flip retraces.
+        return apply_op(
+            "flash_attention", _flash, q, k, v, key,
+            scale=scale, is_causal=bool(is_causal), dropout_p=p,
+            interpret=bool(flags.flag_value("pallas_interpret")))
 
     # the flag rides the static kwargs so the per-(op, shape) dispatch
     # cache keys on it — a flag flip must not serve a stale trace

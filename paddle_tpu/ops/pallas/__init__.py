@@ -1,2 +1,4 @@
-"""Pallas TPU kernels. Selected when running on real TPU hardware
-(FLAGS_use_pallas_kernels); CPU tests exercise the jnp reference paths."""
+"""Pallas TPU kernels. Selected when running on a TPU
+(FLAGS_use_pallas_kernels) and compiled by Mosaic there; the CPU tests
+and the chip_smoke dry run ask for the Pallas interpreter explicitly
+(FLAGS_pallas_interpret)."""

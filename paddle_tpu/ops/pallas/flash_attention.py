@@ -20,9 +20,10 @@ flash-attention online-softmax recurrence tiled for the MXU:
   by the 16 MB scoped-VMEM limit).
 
 All matmuls request `preferred_element_type=float32` so the MXU
-accumulates in f32 even for bf16 inputs. On CPU the same kernels run in
-Pallas interpret mode (used by the test-suite); on TPU they compile via
-Mosaic.
+accumulates in f32 even for bf16 inputs. The kernels compile via Mosaic;
+``mha(..., interpret=True)`` runs them in the Pallas interpreter instead
+(the CPU test-suite and the chip_smoke dry run ask for it explicitly —
+it is never inferred from the backend).
 """
 import functools
 import math
@@ -35,10 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.random import fmix32, keep_thresh_u32
 
 NEG_INF = -1e30
-
-
-def _interpret():
-    return jax.default_backend() not in ("tpu",)
 
 
 def _i32(x):
@@ -78,9 +75,7 @@ LANES = 128
 # all three kernels run (outer, outer, streamed) grids: the outer dims
 # are independent work; only the streamed accumulation dim is
 # order-dependent
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams  # pre-0.5 spelling
-_STREAM_GRID_PARAMS = _CompilerParams(
+_STREAM_GRID_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
@@ -205,7 +200,8 @@ def _keep_thresh(dropout_p):
     return keep_thresh_u32(1.0 - dropout_p)
 
 
-def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p):
+def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
+         interpret):
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
     grid = (bh, seq_q // block_q, seq_k // block_k)
@@ -252,7 +248,7 @@ def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p):
             pltpu.VMEM((block_q, LANES), jnp.float32),   # running sum
             pltpu.VMEM((block_q, d), jnp.float32),       # output acc
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         compiler_params=_STREAM_GRID_PARAMS,
         cost_estimate=pl.CostEstimate(
             flops=4 * seq_q * seq_k * d,
@@ -408,7 +404,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, block_q, block_k, dropout_p, res, do):
+def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
     q, k, v, o, lse, seed = res
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
@@ -451,7 +447,7 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, res, do):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret,
         compiler_params=_STREAM_GRID_PARAMS,
     )(seed, q, k, v, do, lse, delta)
 
@@ -483,25 +479,31 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, res, do):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         compiler_params=_STREAM_GRID_PARAMS,
     )(seed, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, seed, scale, causal, block_q, block_k, dropout_p):
-    o, _ = _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
+           interpret):
+    o, _ = _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
+                interpret)
     return o
 
 
-def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p):
-    o, lse = _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p)
+def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
+               interpret):
+    o, lse = _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
+                  interpret)
     return o, (q, k, v, o, lse, seed)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, dropout_p, res, do):
-    dq, dk, dv = _bwd(scale, causal, block_q, block_k, dropout_p, res, do)
+def _flash_bwd(scale, causal, block_q, block_k, dropout_p, interpret, res,
+               do):
+    dq, dk, dv = _bwd(scale, causal, block_q, block_k, dropout_p, interpret,
+                      res, do)
     return dq, dk, dv, None
 
 
@@ -509,13 +511,16 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
-        block_q=256, block_k=256):
+        block_q=256, block_k=256, interpret=False):
     """Flash attention. q,k,v: [batch, heads, seq, head_dim] (or 3-d
     [batch*heads, seq, head_dim]). Returns same shape as q.
 
     dropout_p > 0 applies dropout to the attention probabilities inside the
     kernel (counter-based mask keyed by ``seed``, an int32 scalar array —
-    pass a fresh seed per step; same seed -> same mask)."""
+    pass a fresh seed per step; same seed -> same mask).
+
+    ``interpret=True`` runs the three kernels in the Pallas interpreter
+    (any backend) instead of compiling them with Mosaic."""
     squeeze = q.ndim == 3
     if squeeze:
         q, k, v = q[None], k[None], v[None]
@@ -532,6 +537,6 @@ def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
         seed = jnp.zeros((), jnp.int32)
     seed2d = jnp.asarray(seed, jnp.int32).reshape(1, 1)
     o = _flash(q3, k3, v3, seed2d, float(scale), bool(causal), bq, bk,
-               float(dropout_p))
+               float(dropout_p), bool(interpret))
     o = o.reshape(b, h, sq, d)
     return o[0] if squeeze else o

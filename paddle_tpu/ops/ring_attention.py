@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..core import jax_compat
 
 
 def _ring_attn_shard(q, k, v, *, axis_name, n_shards, scale, causal):
@@ -106,8 +105,8 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", scale=None,
     spec = P(None, None, axis_name, None)
     fn = functools.partial(_dispatch_ring, axis_name=axis_name, n=n,
                            scale=scale, causal=causal)
-    return jax_compat.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                                out_specs=spec)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)
 
 
 def ring_attention_in_shard_map(q, k, v, axis_name="sp", scale=None,
@@ -123,7 +122,7 @@ def ring_attention_in_shard_map(q, k, v, axis_name="sp", scale=None,
     axis size 1) it falls back to plain local attention (the 1-device
     oracle)."""
     try:
-        n = jax_compat.axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
     except NameError:
         n = 1  # not inside a manual context carrying this axis
     return _dispatch_ring(q, k, v, axis_name, n, scale, causal)

@@ -22,9 +22,11 @@ place:
   compatible runtime, so this string is part of the store key: a
   version skew is a clean store *miss* (recompile), never a crash.
 
-A **bit-flipped export blob can deserialize and execute silently
-wrong** (measured on jaxlib 0.4.37: the flatbuffer has no integrity
-check of the embedded StableHLO payload) — which is why every consumer
+A **bit-flipped export blob is not rejected cleanly**: the flatbuffer
+has no integrity check of the embedded StableHLO payload, so a flipped
+bit can deserialize and execute silently wrong (seen on an earlier
+jaxlib) or take the process down with a segfault inside deserialize
+(seen on jaxlib 0.9.0, PR 21 probe) — which is why every consumer
 of these bytes must verify a sha256 over them BEFORE deserializing.
 The artifact store's MANIFEST does exactly that; ``jit.load`` trusts
 local files the same way it always has.
@@ -53,8 +55,9 @@ def canonical_module_bytes(exported):
     tables and inline ``loc(...)`` attributes) whose numbering depends
     on how many programs were traced earlier in the process — two
     byte-for-byte identical models can serialize differently depending
-    on trace order (measured on jaxlib 0.4.37: the first trace in a
-    process carries a smaller loc table than later ones). Anything that
+    on trace order (seen on an earlier jaxlib; a small re-export on
+    0.9.0 came out byte-equal, so treat it as possible, not certain).
+    Anything that
     keys on *model identity* — the artifact store, the decode
     KV-snapshot header — must therefore hash the module with every
     location stripped, or a resume between two processes at different

@@ -5,6 +5,7 @@ gather_op.cc, scatter_op.cc, slice_op.cc ...).
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import export as jax_export
 
 from ..core.dispatch import apply_op, in_trace
 from ..core.tensor import Tensor
@@ -16,7 +17,14 @@ def _shape_arg(shape):
         return tuple(int(v) for v in shape.numpy().reshape(-1))
     out = []
     for s in shape:
-        out.append(int(s.numpy()) if isinstance(s, Tensor) else int(s))
+        if isinstance(s, Tensor):
+            out.append(int(s.numpy()))
+        elif jax_export.is_symbolic_dim(s):
+            # x.shape[0] under a batch-polymorphic jit.save: the symbol
+            # itself is the size (int() of it cannot be decided)
+            out.append(s)
+        else:
+            out.append(int(s))
     return tuple(out)
 
 

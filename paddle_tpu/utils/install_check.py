@@ -14,7 +14,9 @@ def run_check():
     from paddle_tpu import nn, optimizer
     from paddle_tpu.distributed import spmd, topology
 
-    print("Running verify PaddlePaddle(TPU-native) program ...")
+    dev = jax.devices()[0]
+    print(f"Running verify PaddlePaddle(TPU-native) program on "
+          f"{len(jax.devices())} x {dev.platform} ({dev.device_kind}) ...")
     paddle.seed(0)
     rng = np.random.RandomState(0)
     x = rng.rand(16, 8).astype(np.float32)
@@ -53,7 +55,7 @@ def run_check():
     assert float(loss) < loss0, "compiled SPMD loss did not decrease"
 
     if ndev > 1:
-        print(f"PaddlePaddle(TPU-native) works well on {ndev} devices "
-              f"(dp={ndev} mesh).")
+        print(f"PaddlePaddle(TPU-native) works well on {ndev} "
+              f"{dev.platform} devices (dp={ndev} mesh).")
     print("PaddlePaddle(TPU-native) is installed successfully! Let's start "
           "deep learning with PaddlePaddle(TPU-native) now.")

@@ -30,21 +30,14 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# Persistent compile cache (same dir conftest/bench use): all ranks
-# compile the SAME SPMD program, and this box has 2 cores — without the
-# cache every rank pays the full XLA compile on every run.
-_CACHE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), ".jax_compile_cache")
-try:
-    jax.config.update("jax_compilation_cache_dir", _CACHE)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-except Exception:  # noqa: BLE001 - cache is an optimization only
-    pass
-
 import numpy as np  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
+from paddle_tpu.utils.compile_cache import configure_compile_cache  # noqa: E402
+
+# all ranks compile the SAME SPMD program: without the shared persistent
+# cache every rank pays the full XLA compile on every run
+configure_compile_cache()
 from paddle_tpu import optimizer  # noqa: E402
 import paddle_tpu.distributed as dist  # noqa: E402
 from paddle_tpu.distributed import spmd, topology  # noqa: E402

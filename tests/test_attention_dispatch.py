@@ -4,10 +4,9 @@ Round-5 v5e measurement: at seq 128 the flash kernel is 3x slower than
 XLA's batched-matmul attention (per-program overhead), while at long
 seq XLA's S^2 logits buffer explodes and the kernel wins. The gate —
 kernel when seq_k >= pallas_attention_min_seq OR seq_q*seq_k >=
-min_seq^2 — and its warn-don't-hide fallback are pinned here.
+min_seq^2 — is pinned here, and so is what happens when a selected
+kernel fails: it raises (no fallback to the XLA path).
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -40,9 +39,16 @@ def track_kernel(monkeypatch):
         return real(*args, **kwargs)
 
     monkeypatch.setattr(flash_attention, "mha", spy)
-    # pallas is gated on a TPU backend; tests run CPU — force it on
-    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
     return calls
+
+
+@pytest.fixture(autouse=True)
+def _interpret_kernels():
+    # the kernel is selected on a TPU, or when the interpreter is asked
+    # for explicitly — tests run on CPU
+    paddle.set_flags({"pallas_interpret": True})
+    yield
+    paddle.set_flags({"pallas_interpret": False})
 
 
 def _qkv(sq, sk, d=16):
@@ -102,26 +108,29 @@ def test_paths_numerically_agree(track_kernel):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_kernel_failure_warns_and_falls_back(monkeypatch):
+def test_kernel_failure_raises(monkeypatch, recwarn):
+    """A selected kernel that fails is an error — never a warning plus
+    the XLA path, which would report a broken kernel as a slow one."""
     from paddle_tpu.ops.pallas import flash_attention
 
     def boom(*a, **kw):
         raise RuntimeError("kernel exploded")
 
     monkeypatch.setattr(flash_attention, "mha", boom)
-    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
-    monkeypatch.setattr(attention, "_KERNEL_FAILED", set())
     q, k, v = _qkv(64, 2048)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        out = attention.scaled_dot_product_attention(q, k, v,
-                                                     training=False)
-        # second call: failure is cached — no retry, no second warning
-        attention.scaled_dot_product_attention(q, k, v, training=False)
-    assert sum("falling back" in str(x.message) for x in w) == 1
-    ref = attention._sdpa_ref(q, k, v, None, None,
-                              scale=1.0 / np.sqrt(16), dropout_p=0.0,
-                              is_causal=False)
-    ov = out._value if hasattr(out, "_value") else out
-    np.testing.assert_allclose(np.asarray(ov), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+    for _ in range(2):  # the failure is not remembered either
+        with pytest.raises(RuntimeError, match="kernel exploded"):
+            attention.scaled_dot_product_attention(q, k, v, training=False)
+    assert not [w for w in recwarn if "falling back" in str(w.message)]
+
+
+def test_kernel_not_selected_off_tpu_without_the_flag():
+    paddle.set_flags({"pallas_interpret": False})
+    assert attention._use_pallas() is False  # CPU backend, no flag
+    paddle.set_flags({"pallas_interpret": True})
+    assert attention._use_pallas() is True
+    paddle.set_flags({"use_pallas_kernels": False})
+    try:
+        assert attention._use_pallas() is False
+    finally:
+        paddle.set_flags({"use_pallas_kernels": True})

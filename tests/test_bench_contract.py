@@ -38,14 +38,15 @@ class TestBenchContract:
         assert set(rec) >= {"metric", "value", "unit", "vs_baseline"}
 
     def test_deadline_always_produces_failure_json(self):
-        """With no TPU and a tiny deadline the bench must still print
-        the one failure record and exit non-zero WITHIN the deadline —
-        never a silent rc-124."""
+        """With no TPU (and no BENCH_CPU=1) the bench prints the one
+        failure record at once and exits non-zero — no retry, no CPU
+        number, never a silent rc-124."""
         r = _run({"JAX_PLATFORMS": "cpu", "BENCH_DEADLINE": "25"},
                  timeout=90)
         assert r.returncode != 0
         rec = _one_json_line(r.stdout)
-        assert rec["value"] == 0.0 and "error" in rec
+        assert rec["value"] == 0.0
+        assert rec["error"].startswith("tpu_unavailable")
         assert rec["metric"] == "bert_base_pretrain_tokens_per_sec_per_chip"
 
     def test_flash_mode_metric_fields(self):
@@ -66,8 +67,9 @@ class TestBenchContract:
         assert rec["metric"] == "llama_374m_pretrain_tokens_per_sec_per_chip"
         assert rec["unit"] == "tokens/s"
         # vs_baseline doubles as MFU for this config (no published
-        # per-chip baseline; see run_llama docstring)
-        assert rec["vs_baseline"] == rec["mfu"]
+        # per-chip baseline; see run_llama docstring) — and a CPU smoke
+        # run has no device peak to be a fraction of
+        assert rec["vs_baseline"] is None and rec["mfu"] is None
         assert rec["smoke"] is True and rec["params_m"] > 0
 
     @pytest.mark.slow  # subprocess bench run; tier-1 is near its
@@ -303,9 +305,10 @@ class TestBenchContract:
         rec = _one_json_line(r.stdout)
         assert rec["metric"] == "llama_374m_decode_tokens_per_sec_per_chip"
         assert rec["unit"] == "tokens/s"
-        # vs_baseline = fraction of the HBM-bandwidth roofline
-        assert 0 <= rec["vs_baseline"] <= 1.5
-        assert rec["roofline_tokens_per_sec"] > 0
+        # vs_baseline = fraction of the device's HBM-bandwidth roofline:
+        # a CPU smoke run reports none (no peaks for an unknown device)
+        assert rec["vs_baseline"] is None
+        assert rec["roofline_tokens_per_sec"] is None
         assert rec["smoke"] is True
 
 

@@ -1,9 +1,10 @@
 """Regression tests for the persistent-compile-cache corruption guard
-(tests/conftest.py): a truncated or garbage ``.jax_compile_cache``
+(paddle_tpu.utils.compile_cache, called by tests/conftest.py): a truncated or garbage ``.jax_compile_cache``
 entry — the realistic leftovers of a run killed mid-write — must never
 fail tier-1. jax itself degrades a corrupt entry to a warning +
 recompile at read time; the conftest guard additionally scrubs
-zero-byte entries up front. Both properties are pinned here with real
+zero-byte entries up front. The cache is placed from outside through
+``JAX_COMPILATION_CACHE_DIR`` — no code sets a directory when it is set. Both properties are pinned here with real
 subprocesses so a jax upgrade that turns corrupt-cache reads into hard
 errors is caught by the suite, not by a mysteriously red tier-1.
 """
@@ -18,7 +19,7 @@ import os
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, numpy as np
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", {cache!r})
+assert jax.config.jax_compilation_cache_dir == {cache!r}
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 out = jax.jit(lambda x: x @ x + 1.0)(np.ones((32, 32), np.float32))
@@ -31,6 +32,7 @@ def _run_compile(cache_dir):
     return subprocess.run(
         [sys.executable, "-c",
          _COMPILE_SNIPPET.format(cache=str(cache_dir))],
+        env=dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache_dir)),
         capture_output=True, text=True, timeout=180)
 
 
@@ -57,7 +59,7 @@ def test_corrupt_cache_entry_degrades_to_recompile(tmp_path):
 
 def test_tier1_collects_and_passes_with_poisoned_cache(tmp_path):
     """The satellite contract: a poisoned compile-cache dir pointed at
-    by PADDLE_TPU_TEST_COMPILE_CACHE must not fail the suite — it
+    by JAX_COMPILATION_CACHE_DIR must not fail the suite — it
     still collects, runs, and passes (a fast representative slice)."""
     cache = tmp_path / "cache"
     cache.mkdir()
@@ -68,7 +70,7 @@ def test_tier1_collects_and_passes_with_poisoned_cache(tmp_path):
     zero = cache / ("jit_f-" + "cd" * 32 + "-cache")
     zero.write_bytes(b"")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PADDLE_TPU_TEST_COMPILE_CACHE=str(cache))
+               JAX_COMPILATION_CACHE_DIR=str(cache))
     r = subprocess.run(
         [sys.executable, "-m", "pytest",
          "tests/test_artifact_store.py", "-q", "-p", "no:cacheprovider",

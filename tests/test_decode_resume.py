@@ -40,6 +40,7 @@ from paddle_tpu.inference.server import (_decode_arrays, _encode_arrays,
                                          _encode_decode_opts, _read_all)
 from paddle_tpu.obs import prometheus as obs_prometheus
 from paddle_tpu.resilience import chaos
+from paddle_tpu.utils.compile_cache import compile_cache_dir
 
 from decode_worker import reference_decode, toy_decode_model
 from test_decode_serving import make_server
@@ -497,8 +498,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def spawn_worker(store_dir, seed=0):
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=os.path.join(
-                   REPO, ".jax_compile_cache"),
+               JAX_COMPILATION_CACHE_DIR=compile_cache_dir(),
                DECODE_WORKER_HIDDEN=str(HID),
                DECODE_WORKER_VOCAB=str(VOCAB),
                DECODE_WORKER_SEED=str(seed),

@@ -49,6 +49,7 @@ from paddle_tpu.inference.router import FleetRouter
 from paddle_tpu.inference.server import _read_all
 from paddle_tpu.obs import prometheus as obs_prometheus
 from paddle_tpu.obs.httpd import MetricsServer
+from paddle_tpu.utils.compile_cache import compile_cache_dir
 from paddle_tpu.resilience import chaos
 
 from decode_worker import reference_decode, toy_decode_model
@@ -322,8 +323,7 @@ class TestPrefillHandoffRetry:
 def spawn_phase_worker(store_dir, phase):
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=os.path.join(
-                   REPO, ".jax_compile_cache"),
+               JAX_COMPILATION_CACHE_DIR=compile_cache_dir(),
                DECODE_WORKER_HIDDEN=str(HID),
                DECODE_WORKER_VOCAB=str(VOCAB),
                DECODE_WORKER_SEED="0",

@@ -294,7 +294,7 @@ class TestPipeline:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from paddle_tpu.core.jax_compat import shard_map
+        from jax import shard_map
         from paddle_tpu.distributed.meta_parallel.pipeline_parallel import (
             pipeline_spmd_fn)
 

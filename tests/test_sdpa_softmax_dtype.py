@@ -1,6 +1,6 @@
 """sdpa_softmax_fp32 flag: bf16 attention softmax must not break
-convergence (the accuracy half of the step_tune variant-F lever — the
-throughput half runs on the TPU)."""
+convergence (the accuracy half of that lever — the throughput half is
+a chip measurement)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

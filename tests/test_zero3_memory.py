@@ -28,11 +28,8 @@ def _build(stage, offload=False, recompute=False):
 
 
 def _host_kind():
-    # the host-side memory kind this backend exposes (pinned_host on
-    # TPU/GPU, unpinned_host on 0.4.x CPU jaxlib)
-    from paddle_tpu.core.jax_compat import host_memory_kind
-
-    return host_memory_kind()
+    # where spmd keeps offloaded optimizer state between steps
+    return spmd._HOST_MEMORY_KIND
 
 
 def _data():
