@@ -275,15 +275,6 @@ def device_peaks():
     return DEVICE_PEAKS[kind]
 
 
-def _print_trace_summary(profile_dir):
-    try:
-        from paddle_tpu.utils.profiler import print_op_summary
-
-        print_op_summary(profile_dir, top=20, printer=log)
-    except Exception as e:  # noqa: BLE001 - summary is best-effort
-        log(f"op summary failed: {e}")
-
-
 def main():
     import jax
 
@@ -494,7 +485,6 @@ def main():
             if profile_dir:
                 jax.profiler.stop_trace()
                 log(f"profiler trace written to {profile_dir}")
-                _print_trace_summary(profile_dir)
         tokens_per_sec = batch * seq * steps / dt
         log(f"{steps} steps in {dt:.2f}s -> {tokens_per_sec:.0f} tokens/s, "
             f"final loss {final_loss:.4f}")
@@ -586,7 +576,6 @@ def run_resnet50(smoke, platform):
         finally:
             if profile_dir:
                 jax.profiler.stop_trace()
-                _print_trace_summary(profile_dir)
         images_per_sec = batch * steps / dt
         log(f"{steps} steps in {dt:.2f}s -> {images_per_sec:.0f} images/s, "
             f"final loss {final_loss:.4f}")
@@ -690,7 +679,6 @@ def run_llama(smoke, platform):
         finally:
             if profile_dir:
                 jax.profiler.stop_trace()
-                _print_trace_summary(profile_dir)
         tokens_per_sec = batch * seq * steps / dt
         log(f"{steps} steps in {dt:.2f}s -> {tokens_per_sec:.0f} tokens/s, "
             f"final loss {final_loss:.4f}")

@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import dispatch, random as random_core
 from ..core.tensor import Tensor
+from ..obs import tracing
 from . import topology
 
 #: where offloaded optimizer state lives between steps
@@ -182,10 +183,14 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
                 # through the collector keeps tracers off the Layer
                 with collect_aux_losses() as auxes:
                     clear_direct_aux_losses(layer)
-                    out = layer.forward(Tensor(x, stop_gradient=True))
+                    # forward is called directly (no hooks on the root),
+                    # so the root's scope is entered here
+                    with jax.named_scope(layer.scope_name()):
+                        out = layer.forward(Tensor(x, stop_gradient=True))
                     sweep_direct_aux_losses(layer, auxes)
                 out_arr = out._value if isinstance(out, Tensor) else out
-                loss = loss_fn(out_arr, y) + total_aux_loss(auxes)
+                with jax.named_scope("loss"):
+                    loss = loss_fn(out_arr, y) + total_aux_loss(auxes)
                 # capture in-forward buffer updates (BatchNorm running
                 # stats, QAT moving scales) so they thread through the
                 # compiled step instead of silently freezing at init
@@ -265,7 +270,12 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
             # grad before any collective, so keep grads per-worker
             check_vma=False)
 
-    def step(params, opt_state, buffers, x, y, key, lr):
+    def train_step(params, opt_state, buffers, x, y, key, lr):
+        # the jitted program's name (``jit_train_step`` in a trace's module
+        # line, ``jit(train_step)/`` in every op_name). It is part of the
+        # compile-cache key, metadata is not: under its old name ``step``
+        # a cache written before the program had scopes would hand back an
+        # executable without them (PERF.md, PR 23).
         # batch stays dp-sharded via in_shardings; grads of replicated params
         # get psum'd across dp by SPMD automatically.
         # ZeRO-3 note: params arrive SHARDED (param_shards) and are NOT
@@ -290,16 +300,19 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
                      for n in param_names}
         if grad_clip is not None:
             names = list(grads)
-            clipped = grad_clip.clip_arrays([grads[n] for n in names])
+            with jax.named_scope("clip"):
+                clipped = grad_clip.clip_arrays([grads[n] for n in names])
             grads = dict(zip(names, clipped))
         new_params, new_state = {}, {}
-        for name in param_names:
-            g = grads[name].astype(params[name].dtype)
-            if l1_coeff:
-                g = g + l1_coeff * jnp.sign(params[name])
-            out = opt_update(params[name], g, lr, *opt_state[name], **hypers)
-            new_params[name] = out[0]
-            new_state[name] = tuple(out[1:])
+        with jax.named_scope("optimizer"):
+            for name in param_names:
+                g = grads[name].astype(params[name].dtype)
+                if l1_coeff:
+                    g = g + l1_coeff * jnp.sign(params[name])
+                out = opt_update(params[name], g, lr, *opt_state[name],
+                                 **hypers)
+                new_params[name] = out[0]
+                new_state[name] = tuple(out[1:])
         if use_local_grads and dgc_configs is not None:
             new_state["__comm__"] = new_comm
         if bad_step_guard:
@@ -367,7 +380,7 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
     # donate params + opt_state: the step returns their replacements, so
     # XLA can update in place instead of holding both copies in HBM
     # (no-op on CPU backends, which don't implement donation)
-    step_jit = jax.jit(step, in_shardings=in_shardings,
+    step_jit = jax.jit(train_step, in_shardings=in_shardings,
                        out_shardings=out_shardings,
                        donate_argnums=(0, 1) if donate else ())
 
@@ -387,29 +400,41 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
             for n in opt_state}
 
     def step_fn(params, opt_state, x, y, key=None, lr=None):
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        if lr is None:
-            lr = jnp.asarray(optimizer.get_lr(), jnp.float32)
-        if offload:
-            opt_state = _bounce(opt_state, "device")
-        if buffer_names:
-            # pick up buffers loaded onto the layer since the last step
-            # (set_state_dict from a checkpoint etc.) — the cell only
-            # tracks values this step_fn wrote itself
-            _, live = layer.functional_state()
-            cur = buffers_cell["cur"]
-            if any(live.get(n) is not cur.get(n) for n in buffer_names):
-                buffers_cell["cur"] = {n: jnp.asarray(live[n])
-                                       for n in buffer_names}
-        out = step_jit(
-            params, opt_state, buffers_cell["cur"], x, y, key, lr)
-        loss, new_params, new_state, new_buffers = out[:4]
-        if offload:
-            new_state = _bounce(new_state, _HOST_MEMORY_KIND)
-        buffers_cell["cur"] = new_buffers
-        if buffer_names:
-            layer.load_functional_state(None, new_buffers)
+        # one ``train.step`` span a call, a child span for each piece of
+        # per-call host work: what the host does between two dispatches
+        # is what a device gap is attributed to (PERF.md section 3)
+        with tracing.span("train.step"):
+            if key is None:
+                key = jax.random.PRNGKey(0)
+            if lr is None:
+                with tracing.span("train.step.lr"):
+                    lr = jnp.asarray(optimizer.get_lr(), jnp.float32)
+            if offload:
+                with tracing.span("train.step.offload", to="device"):
+                    opt_state = _bounce(opt_state, "device")
+            with tracing.span("train.step.buffers_in",
+                              buffers=len(buffer_names)):
+                if buffer_names:
+                    # pick up buffers loaded onto the layer since the last
+                    # step (set_state_dict from a checkpoint etc.) — the
+                    # cell only tracks values this step_fn wrote itself
+                    _, live = layer.functional_state()
+                    cur = buffers_cell["cur"]
+                    if any(live.get(n) is not cur.get(n)
+                           for n in buffer_names):
+                        buffers_cell["cur"] = {n: jnp.asarray(live[n])
+                                               for n in buffer_names}
+            with tracing.span("train.step.call"):
+                out = step_jit(
+                    params, opt_state, buffers_cell["cur"], x, y, key, lr)
+            loss, new_params, new_state, new_buffers = out[:4]
+            if offload:
+                with tracing.span("train.step.offload", to="host"):
+                    new_state = _bounce(new_state, _HOST_MEMORY_KIND)
+            with tracing.span("train.step.buffers_out"):
+                buffers_cell["cur"] = new_buffers
+                if buffer_names:
+                    layer.load_functional_state(None, new_buffers)
         if bad_step_guard:
             return loss, new_params, new_state, out[4]
         return loss, new_params, new_state
@@ -425,19 +450,23 @@ def shard_batch(batch, mesh=None, axis=None):
     contract: each process passes its LOCAL batch and the global array is
     assembled across processes (global dim 0 = local * num_processes)."""
     mesh = mesh or topology.get_global_mesh()
-    arr = batch._value if isinstance(batch, Tensor) else jnp.asarray(np.asarray(batch))
-    if axis is None:
-        axes = topology.data_axes(mesh)
-        spec = P(axes) if axes else P()
-    else:
-        spec = P(axis)
-    sharding = NamedSharding(mesh, spec)
-    if jax.process_count() > 1 and spec != P():
-        local = np.asarray(arr)
-        global_shape = (local.shape[0] * jax.process_count(),) + local.shape[1:]
-        return jax.make_array_from_process_local_data(sharding, local,
-                                                      global_shape)
-    return jax.device_put(arr, sharding)
+    with tracing.span("spmd.shard_batch", devices=mesh.size) as sp:
+        arr = (batch._value if isinstance(batch, Tensor)
+               else jnp.asarray(np.asarray(batch)))
+        sp.attrs["bytes"] = arr.nbytes
+        if axis is None:
+            axes = topology.data_axes(mesh)
+            spec = P(axes) if axes else P()
+        else:
+            spec = P(axis)
+        sharding = NamedSharding(mesh, spec)
+        if jax.process_count() > 1 and spec != P():
+            local = np.asarray(arr)
+            global_shape = ((local.shape[0] * jax.process_count(),)
+                            + local.shape[1:])
+            return jax.make_array_from_process_local_data(sharding, local,
+                                                          global_shape)
+        return jax.device_put(arr, sharding)
 
 
 def build_fsdp_train_step(layers, loss_fn, optimizer, mesh=None,

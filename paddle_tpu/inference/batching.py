@@ -1208,65 +1208,71 @@ class BatchingEngine:
             group = self._next_group(gen)
             if group is None:
                 return  # closed and drained, or superseded by a restart
-            with self._lock:
-                self._inflight[gen] = group
-            # From here until dispatch hand-off, an unhandled exception
-            # (e.g. injected chaos) kills this thread WITH the group
-            # still recorded in _inflight — the watchdog then fails
-            # exactly that group with a retryable status (never a hang)
-            # and restarts the scheduler for the parked requests.
-            chaos.hit("serving.scheduler.loop")
-            bucket = self._group_bucket(group)
-            key = (bucket, group[0].sig)
-            now = time.monotonic()
-            with self._lock:
-                br = self._breaker_for(key)
-                allowed = br.allow(now)
+            # one region span an iteration, from the group's pop to its
+            # hand-off: the gap between two of them is the scheduler
+            # waiting for requests in _next_group
+            with obs_tracing.span("serving.scheduler.loop",
+                                  engine=self.name,
+                                  rows=sum(r.rows for r in group)):
+                with self._lock:
+                    self._inflight[gen] = group
+                # From here until dispatch hand-off, an unhandled exception
+                # (e.g. injected chaos) kills this thread WITH the group
+                # still recorded in _inflight — the watchdog then fails
+                # exactly that group with a retryable status (never a hang)
+                # and restarts the scheduler for the parked requests.
+                chaos.hit("serving.scheduler.loop")
+                bucket = self._group_bucket(group)
+                key = (bucket, group[0].sig)
+                now = time.monotonic()
+                with self._lock:
+                    br = self._breaker_for(key)
+                    allowed = br.allow(now)
+                    if not allowed:
+                        br.shed += len(group)
+                        self._m_shed.inc(len(group), reason="quarantine")
                 if not allowed:
-                    br.shed += len(group)
-                    self._m_shed.inc(len(group), reason="quarantine")
-            if not allowed:
-                err = BucketQuarantined(
-                    f"{self.name} bucket {bucket} is quarantined after "
-                    f"{br.failures} consecutive failures; retry after "
-                    f"cooldown ({self.breaker_cooldown}s)")
-                for r in group:
-                    r.fail(err)
-                with self._lock:
-                    self._inflight.pop(gen, None)
-                continue
-            with self._lock:
-                cold = key not in self._cache
-            if cold:
-                # a cold bucket pays a multi-second XLA compile: run it
-                # on its own thread so already-compiled buckets keep
-                # flowing instead of stalling head-of-line behind it.
-                # The cold thread owns delivery from here (its guarded
-                # wrapper cannot strand waiters).
-                with self._lock:
-                    self._cold_seq += 1
-                    token = self._cold_seq
-                t = threading.Thread(target=self._run_cold_group,
-                                     args=(token, group, br),
-                                     name=f"{self.name}-cold-compile",
-                                     daemon=True)
-                with self._lock:
-                    self._inflight.pop(gen, None)
-                    self._cold_inflight[token] = (group, time.monotonic())
-                    self._cold_threads = [x for x in self._cold_threads
-                                          if x.is_alive()]
-                    self._cold_threads.append(t)
-                t.start()
-            else:
-                try:
-                    self._run_group_guarded(group, br)
-                finally:
-                    # _run_group_guarded never raises (it fails the
-                    # group instead), so waiters are already answered —
-                    # clear even on a BaseException so a later watchdog
-                    # restart cannot double-fail a delivered group
+                    err = BucketQuarantined(
+                        f"{self.name} bucket {bucket} is quarantined after "
+                        f"{br.failures} consecutive failures; retry after "
+                        f"cooldown ({self.breaker_cooldown}s)")
+                    for r in group:
+                        r.fail(err)
                     with self._lock:
                         self._inflight.pop(gen, None)
+                    continue
+                with self._lock:
+                    cold = key not in self._cache
+                if cold:
+                    # a cold bucket pays a multi-second XLA compile: run it
+                    # on its own thread so already-compiled buckets keep
+                    # flowing instead of stalling head-of-line behind it.
+                    # The cold thread owns delivery from here (its guarded
+                    # wrapper cannot strand waiters).
+                    with self._lock:
+                        self._cold_seq += 1
+                        token = self._cold_seq
+                    t = threading.Thread(target=self._run_cold_group,
+                                         args=(token, group, br),
+                                         name=f"{self.name}-cold-compile",
+                                         daemon=True)
+                    with self._lock:
+                        self._inflight.pop(gen, None)
+                        self._cold_inflight[token] = (group, time.monotonic())
+                        self._cold_threads = [x for x in self._cold_threads
+                                              if x.is_alive()]
+                        self._cold_threads.append(t)
+                    t.start()
+                else:
+                    try:
+                        self._run_group_guarded(group, br)
+                    finally:
+                        # _run_group_guarded never raises (it fails the
+                        # group instead), so waiters are already answered —
+                        # clear even on a BaseException so a later watchdog
+                        # restart cannot double-fail a delivered group
+                        with self._lock:
+                            self._inflight.pop(gen, None)
 
     def _run_cold_group(self, token, group, br):
         """Like _run_group_guarded, but the breaker outcome is recorded
@@ -1411,19 +1417,21 @@ class BatchingEngine:
                          else parts[0])
         chaos.hit("serving.execute")
         chaos.hit(f"serving.execute.bucket{bucket}")
-        t0 = time.monotonic()
-        outs = run(batch)
-        dt_ms = (time.monotonic() - t0) * 1000.0
-        # one execute per group; traced requests each get a span with
-        # the shared duration, untraced traffic only feeds the table
-        tids = {r.trace_id for r in group if r.trace_id is not None}
-        if tids:
-            for tid in tids:
-                obs_tracing.record_span(
-                    "serving.execute", dt_ms / 1000.0, trace_id=tid,
-                    engine=self.name, bucket=bucket, rows=rows)
-        else:
-            obs_tracing.observe("serving.execute", dt_ms / 1000.0)
+        # one execute per group and one region span for it (a Span per
+        # batch, never per request); every further traced request of the
+        # group gets a copy with the shared duration under its own id
+        tids = sorted({r.trace_id for r in group if r.trace_id is not None})
+        with obs_tracing.span("serving.execute",
+                              trace_id=tids[0] if tids else None,
+                              engine=self.name, bucket=bucket,
+                              rows=rows) as sp:
+            outs = run(batch)
+        dt_ms = sp.duration_s * 1000.0
+        for tid in tids[1:]:
+            obs_tracing.record_span(
+                "serving.execute", sp.duration_s, trace_id=tid,
+                parent_id=sp.span_id, engine=self.name, bucket=bucket,
+                rows=rows)
         for j, o in enumerate(outs):
             if getattr(o, "ndim", 0) == 0 or o.shape[0] != bucket:
                 raise ValueError(
@@ -1626,27 +1634,22 @@ class BatchingEngine:
             try:
                 chaos.hit("serving.compile")
                 chaos.hit(f"serving.compile.bucket{bucket}")
-                t0 = time.monotonic()
-                if self._compile_takes_warming:
-                    res = self._runner.compile(bucket, sig,
-                                               warming=warming)
-                else:
-                    res = self._runner.compile(bucket, sig)
-                run, source = (res if isinstance(res, tuple)
-                               else (res, "inline"))
+                with obs_tracing.span("serving.compile", trace_id=trace_id,
+                                      engine=self.name,
+                                      bucket=bucket) as sp:
+                    if self._compile_takes_warming:
+                        res = self._runner.compile(bucket, sig,
+                                                   warming=warming)
+                    else:
+                        res = self._runner.compile(bucket, sig)
+                    run, source = (res if isinstance(res, tuple)
+                                   else (res, "inline"))
+                    sp.attrs["source"] = source
             except BaseException:
                 with self._lock:
                     self._compiling.pop(key, None)
                 ev.set()
                 raise
-            dt = time.monotonic() - t0
-            if trace_id is not None:
-                obs_tracing.record_span("serving.compile", dt,
-                                        trace_id=trace_id,
-                                        engine=self.name, bucket=bucket,
-                                        source=source)
-            else:
-                obs_tracing.observe("serving.compile", dt)
             with self._lock:
                 self._cache[key] = run
                 st = self._stats_for(bucket, sig)

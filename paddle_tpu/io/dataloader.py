@@ -13,10 +13,12 @@ import itertools
 import multiprocessing as mp
 import queue as queue_mod
 import threading
+import time
 
 import numpy as np
 
 from ..core.tensor import Tensor
+from ..obs import tracing
 from ..resilience.retry import call_with_retry
 from .dataset import IterableDataset
 from .sampler import BatchSampler, SequenceSampler, RandomSampler
@@ -67,6 +69,25 @@ def _to_tensor_tree(obj):
     return obj
 
 
+def _tree_nbytes(obj):
+    """Bytes of the arrays of a batch (the ``bytes`` of ``io.next_batch``)."""
+    if isinstance(obj, dict):
+        return sum(_tree_nbytes(v) for v in obj.values())
+    if isinstance(obj, (list, tuple)):
+        return sum(_tree_nbytes(v) for v in obj)
+    return getattr(obj._value if isinstance(obj, Tensor) else obj,
+                   "nbytes", 0)
+
+
+def _convert(data, sp):
+    """The batch as Tensors (a host-to-device copy per array), timed as
+    ``io.next_batch.convert``; its size goes onto the enclosing span."""
+    with tracing.span("io.next_batch.convert"):
+        out = _to_tensor_tree(data)
+    sp.attrs["bytes"] = _tree_nbytes(out)
+    return out
+
+
 def _worker_loop(dataset, index_queue, out_queue, collate_fn, worker_id,
                  num_workers, seed, iterable):
     global _worker_info
@@ -82,9 +103,9 @@ def _worker_loop(dataset, index_queue, out_queue, collate_fn, worker_id,
                 batch_idx, batch_size = cmd
                 samples = list(itertools.islice(it, batch_size))
                 if not samples:
-                    out_queue.put((batch_idx, StopIteration()))
+                    out_queue.put((batch_idx, StopIteration(), None))
                     break
-                out_queue.put((batch_idx, collate_fn(samples)))
+                out_queue.put((batch_idx, collate_fn(samples), None))
         else:
             while True:
                 cmd = index_queue.get()
@@ -92,15 +113,21 @@ def _worker_loop(dataset, index_queue, out_queue, collate_fn, worker_id,
                     break
                 batch_idx, indices = cmd
                 try:
+                    t0 = time.monotonic()
                     # transient I/O from remote-FS-backed datasets gets
                     # backoff+retry instead of poisoning the batch
                     samples = [call_with_retry(dataset.__getitem__, i,
                                                retry_on=(OSError,),
                                                base_delay=0.05)
                                for i in indices]
-                    out_queue.put((batch_idx, collate_fn(samples)))
+                    batch = collate_fn(samples)
+                    # the worker's fetch+collate seconds travel with the
+                    # batch: the parent records ``io.worker.produce``, so
+                    # a reader can tell slow workers from a slow pipe
+                    out_queue.put((batch_idx, batch,
+                                   time.monotonic() - t0))
                 except Exception as e:  # noqa: BLE001
-                    out_queue.put((batch_idx, e))
+                    out_queue.put((batch_idx, e, None))
     except KeyboardInterrupt:
         pass
 
@@ -151,16 +178,26 @@ class _MultiprocessIter:
         if self.rcvd_idx >= self.send_idx and self.done_sending:
             self._shutdown()
             raise StopIteration
-        while self.rcvd_idx not in self.reorder:
-            idx, data = self.out_queue.get()
-            self.reorder[idx] = data
-        data = self.reorder.pop(self.rcvd_idx)
-        self.rcvd_idx += 1
-        self._send_next()
-        if isinstance(data, Exception):
-            self._shutdown()
-            raise data
-        return _to_tensor_tree(data)
+        with tracing.span("io.next_batch", source="workers",
+                          workers=len(self.workers)) as sp:
+            with tracing.span("io.next_batch.wait"):
+                # blocked on the pipe: the workers' pace, the transfer and
+                # the unpickling thread together
+                while self.rcvd_idx not in self.reorder:
+                    idx, data, produce_s = self.out_queue.get()
+                    self.reorder[idx] = data
+                    if produce_s is not None:
+                        tracing.record_span(
+                            "io.worker.produce", produce_s,
+                            worker=idx % len(self.workers),
+                            seconds=produce_s)
+            data = self.reorder.pop(self.rcvd_idx)
+            self.rcvd_idx += 1
+            self._send_next()
+            if isinstance(data, Exception):
+                self._shutdown()
+                raise data
+            return _convert(data, sp)
 
     def _shutdown(self):
         for iq in self.index_queues:
@@ -182,11 +219,14 @@ class _SingleProcessIter:
 
     def __next__(self):
         indices = next(self.batches)
-        # same transient-I/O retry the multiprocess workers get
-        samples = [call_with_retry(self.loader.dataset.__getitem__, i,
-                                   retry_on=(OSError,), base_delay=0.05)
-                   for i in indices]
-        return _to_tensor_tree(self.loader.collate_fn(samples))
+        # self time = fetch + collate in this process
+        with tracing.span("io.next_batch", source="dataset",
+                          workers=0) as sp:
+            # same transient-I/O retry the multiprocess workers get
+            samples = [call_with_retry(self.loader.dataset.__getitem__, i,
+                                       retry_on=(OSError,), base_delay=0.05)
+                       for i in indices]
+            return _convert(self.loader.collate_fn(samples), sp)
 
 
 class _IterableDatasetIter:
@@ -232,13 +272,16 @@ class _PrefetchIter:
             self.q.put(("error", e))
 
     def __next__(self):
-        kind, payload = self.q.get()
-        self._slots.release()  # consumer took a batch: free one slot
-        if kind == "stop":
-            raise StopIteration
-        if kind == "error":
-            raise payload
-        return payload
+        with tracing.span("io.next_batch", source="prefetch") as sp:
+            with tracing.span("io.next_batch.wait"):
+                kind, payload = self.q.get()
+            self._slots.release()  # consumer took a batch: free one slot
+            if kind == "stop":
+                raise StopIteration
+            if kind == "error":
+                raise payload
+            sp.attrs["bytes"] = _tree_nbytes(payload)
+            return payload
 
 
 class DataLoader:

@@ -9,8 +9,10 @@ steps use to thread parameters through pure functions.
 """
 import collections
 
+import jax
 import numpy as np
 
+from ..core import dispatch
 from ..core import dtype as dtype_mod
 from ..core.tensor import Parameter, Tensor
 from ..framework.param_attr import ParamAttr
@@ -75,6 +77,8 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        if sublayer is not None:
+            object.__setattr__(sublayer, "_scope_attr", str(name))
         return sublayer
 
     def register_buffer(self, name, tensor, persistable=True):
@@ -93,6 +97,7 @@ class Layer:
         elif isinstance(value, Layer):
             self.__dict__.pop(name, None)
             self._sub_layers[name] = value
+            object.__setattr__(value, "_scope_attr", name)
         elif isinstance(value, (list, tuple)) and value and all(
                 isinstance(v, Parameter) for v in value):
             # ParameterList-like assignment
@@ -279,7 +284,24 @@ class Layer:
         return HookRemoveHelper(self._forward_post_hooks, key)
 
     # ------------------------------------------------------------ call
+    def scope_name(self):
+        """The module's ``jax.named_scope`` in a traced program: its class
+        and, where a parent registered it, the attribute it is held under
+        (``self_attn:MultiHeadAttention``). A trace reader finds a
+        module's operations by it in the HLO ``op_name``."""
+        attr = self.__dict__.get("_scope_attr")
+        cls = type(self).__name__
+        return f"{attr}:{cls}" if attr else cls
+
     def __call__(self, *inputs, **kwargs):
+        if dispatch.in_trace():
+            # scopes are HLO metadata, written while tracing only: eager
+            # dispatch enters nothing
+            with jax.named_scope(self.scope_name()):
+                return self._call(inputs, kwargs)
+        return self._call(inputs, kwargs)
+
+    def _call(self, inputs, kwargs):
         for hook in self._forward_pre_hooks.values():
             out = hook(self, inputs)
             if out is not None:

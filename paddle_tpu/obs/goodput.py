@@ -8,7 +8,10 @@ silently loses. ROADMAP item 3 reduces to this ledger.
 
 Categories:
 
-    step        a useful training step (the numerator)
+    step        a useful training step (the numerator). Nothing in
+                the program feeds it: the training loop wraps its own
+                steps (below). ``train.step`` spans time the dispatch of
+                a step, not its wall time, and are not step seconds
     checkpoint  save/restore I/O (resilience/checkpoint.py feeds this)
     retry       backoff sleeps (resilience/retry.py feeds this)
     rollback    bad-step checkpoint restores (resilience/badstep.py)

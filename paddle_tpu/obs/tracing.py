@@ -1,40 +1,51 @@
-"""Span tracing: one clock, one summary table, request-scoped trace ids.
+"""Span tracing: one clock, one summary table, one bounded ring, and the
+profiler's trace.
 
-A *span* is a named timed region. Spans come from three places and all
-land in the same aggregation table and the same bounded buffer of
-finished spans:
+A *span* is a named timed region: ``name``, ``t0``/``t1`` (seconds on
+``time.monotonic``, the clock ``obs/goodput.py`` and the benchmark use),
+``span_id``, ``parent_id``, ``trace_id``, ``thread`` and ``attrs``. Every
+finished span lands in the aggregation table (``summary_rows``) and in a
+bounded ring of finished spans (``finished``).
 
-- serving: per-request spans over enqueue -> batch -> (compile) ->
-  execute -> reply, tagged with the request's ``trace_id`` (propagated
-  from the client over the wire — see inference/server.py
-  TRACE_MARKER);
-- training: per-step spans feeding the goodput accountant
-  (obs/goodput.py);
-- the legacy ``utils.profiler.RecordEvent`` API, which now routes here
-  (its ``summary()`` printer reads :func:`summary_rows`), so BENCH
-  profiles and serving spans share one clock (``time.perf_counter``)
-  and one table.
+A *region* span (``span()``, or ``start_span`` ... ``finish``) is also a
+``jax.profiler.TraceAnnotation`` named ``paddle_tpu:<name>``, entered and
+left with the span, so a profiler session holds the program's host spans
+on the same clock as the device's operations. With no session running the
+annotation is TraceMe's inactive path; that is all "tracing off" means
+here: there is no switch. jax is only used where the process has already
+imported it, so the jax-free wire clients stay jax-free and memory-only.
 
-Trace ids are 64-bit, non-zero, hex-rendered; ``trace(tid)`` installs
-an ambient id for the current thread that ``span()``/``start_span()``
-inherit, and explicit ``trace_id=`` wins — the engine scheduler runs in
-a different thread from the submitting handler, so the id travels on
-the request object, not on the thread.
+Spans come from: the train path (``io.next_batch``, ``spmd.shard_batch``,
+``train.step`` and their children), the serving engine
+(``serving.scheduler.loop`` / ``.execute`` / ``.compile`` as regions;
+``serving.queue`` / ``.request`` / ``.reply`` pre-measured per traced
+request with ``record_span``), checkpoints, and the legacy
+``utils.profiler.RecordEvent`` (an alias of ``span``).
+
+``span()`` installs itself as the thread's ambient parent: spans opened
+inside it on the same thread take its id as ``parent_id``; an explicit
+``parent_id=`` wins (the engine scheduler finishes what a handler thread
+opened). Trace ids are 64-bit, non-zero, hex-rendered; ``trace(tid)``
+installs an ambient id for the thread and an explicit ``trace_id=`` wins.
 """
 import collections
 import contextlib
-import os
+import itertools
 import random
+import sys
 import threading
 import time
 
-_BUFFER_CAP = int(os.environ.get("PADDLE_TPU_OBS_SPAN_BUFFER", "4096"))
+#: prefix of the program's spans in the profiler's trace
+ANNOTATION_PREFIX = "paddle_tpu:"
+#: finished spans kept; a 10 s benchmark window leaves under a thousand
+_RING = 8192
 
 _lock = threading.Lock()
-_finished = collections.deque(maxlen=_BUFFER_CAP)
+_finished = collections.deque(maxlen=_RING)
 _agg = {}  # name -> [calls, total_s, max_s, min_s]
 _tls = threading.local()
-_span_seq = [0]
+_span_ids = itertools.count(1)  # next() is atomic under the GIL
 
 
 def new_trace_id():
@@ -65,57 +76,87 @@ def trace(trace_id):
         _tls.trace_id = prev
 
 
+def _annotation(name):
+    """An entered ``TraceAnnotation`` for a region span, or None in a
+    process that has not imported jax."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
-    """One timed region. Created by :func:`start_span`; must be
-    :meth:`finish`-ed (or used via the :func:`span` context manager).
-    A Span may be finished from a different thread than it was started
-    on — the engine scheduler finishes queue spans the handler thread
-    opened."""
+    """One timed region, open until :meth:`finish`. Used as a context
+    manager (:func:`span`) it is also the thread's ambient parent. A Span
+    may be finished from another thread than it was started on: the engine
+    scheduler finishes queue spans a handler thread opened."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "t_start", "duration_s", "_done")
+                 "t0", "t1", "thread", "_ann")
 
-    def __init__(self, name, trace_id=None, parent_id=None, attrs=None):
+    def __init__(self, name, trace_id=None, parent_id=None, attrs=None,
+                 annotate=True):
         self.name = name
         self.trace_id = (trace_id if trace_id is not None
-                         else current_trace_id())
-        with _lock:
-            _span_seq[0] += 1
-            self.span_id = _span_seq[0]
+                         else getattr(_tls, "trace_id", None))
+        self.span_id = next(_span_ids)
+        if parent_id is None:
+            stack = getattr(_tls, "stack", None)
+            if stack:
+                parent_id = stack[-1].span_id
         self.parent_id = parent_id
-        self.attrs = dict(attrs) if attrs else {}
-        self.t_start = time.perf_counter()
-        self.duration_s = None
-        self._done = False
+        self.attrs = attrs or {}
+        self.thread = threading.get_ident()
+        self.t1 = None
+        self._ann = _annotation(name) if annotate else None
+        self.t0 = time.monotonic()
+
+    @property
+    def duration_s(self):
+        return None if self.t1 is None else self.t1 - self.t0
 
     def finish(self, **attrs):
         """Record the span (idempotent). Extra attrs merge in."""
-        if self._done:
+        if self.t1 is not None:
             return self
-        self._done = True
-        self.duration_s = time.perf_counter() - self.t_start
+        self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if attrs:
             self.attrs.update(attrs)
         _record(self)
         return self
 
+    def __enter__(self):
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        _tls.stack.pop()
+        self.finish()
+        return False
+
     def as_dict(self):
-        return {"name": self.name, "trace_id": self.trace_id,
-                "span_id": self.span_id, "parent_id": self.parent_id,
-                "duration_s": self.duration_s, "attrs": dict(self.attrs)}
+        return {"name": self.name, "t0": self.t0, "t1": self.t1,
+                "duration_s": self.duration_s, "span_id": self.span_id,
+                "parent_id": self.parent_id, "trace_id": self.trace_id,
+                "thread": self.thread, "attrs": dict(self.attrs)}
 
 
 def start_span(name, trace_id=None, parent_id=None, **attrs):
-    return Span(name, trace_id=trace_id, parent_id=parent_id, attrs=attrs)
+    """Open a span. Either the caller calls ``finish()`` (from any
+    thread), or it is used as ``with span("io.next_batch") as sp:`` and is
+    then the ambient parent of the spans opened inside it on this thread."""
+    return Span(name, trace_id, parent_id, attrs)
 
 
-@contextlib.contextmanager
-def span(name, trace_id=None, **attrs):
-    sp = start_span(name, trace_id=trace_id, **attrs)
-    try:
-        yield sp
-    finally:
-        sp.finish()
+span = start_span
 
 
 def _agg_update_locked(name, duration_s):
@@ -131,8 +172,8 @@ def _agg_update_locked(name, duration_s):
 
 def _record(sp):
     with _lock:
-        _finished.append(sp.as_dict())
-        _agg_update_locked(sp.name, sp.duration_s)
+        _finished.append(sp)
+        _agg_update_locked(sp.name, sp.t1 - sp.t0)
 
 
 def observe(name, duration_s):
@@ -144,36 +185,47 @@ def observe(name, duration_s):
 
 
 def record_span(name, duration_s, trace_id=None, parent_id=None, **attrs):
-    """Record an already-measured region as a finished span (the
-    engine measures one batch execute and attributes it to every traced
-    request in the group)."""
-    sp = Span.__new__(Span)
-    sp.name = name
-    sp.trace_id = trace_id if trace_id is not None else current_trace_id()
-    with _lock:
-        _span_seq[0] += 1
-        sp.span_id = _span_seq[0]
-    sp.parent_id = parent_id
-    sp.attrs = dict(attrs)
-    sp.t_start = time.perf_counter() - duration_s
-    sp.duration_s = float(duration_s)
-    sp._done = True
+    """Record an already-measured region that ends now as a finished span
+    (``t0 = t1 - duration_s``; memory only: the profiler's trace cannot
+    take an event after the fact). The engine measures one batch and
+    attributes it to every traced request in the group."""
+    sp = Span(name, trace_id, parent_id, attrs, annotate=False)
+    sp.t1 = time.monotonic()
+    sp.t0 = sp.t1 - float(duration_s)
     _record(sp)
     return sp
 
 
 def finished(trace_id=None, name=None):
     """Finished spans (as dicts, oldest first), optionally filtered by
-    trace id and/or span name. The buffer is bounded
-    (PADDLE_TPU_OBS_SPAN_BUFFER, default 4096): this is a debugging /
-    test surface, not a durable trace store."""
+    trace id and/or span name. The ring is bounded: a window to read
+    after a run, not a durable trace store."""
     with _lock:
         spans = list(_finished)
-    if trace_id is not None:
-        spans = [s for s in spans if s["trace_id"] == trace_id]
-    if name is not None:
-        spans = [s for s in spans if s["name"] == name]
-    return spans
+    return [s.as_dict() for s in spans
+            if (trace_id is None or s.trace_id == trace_id)
+            and (name is None or s.name == name)]
+
+
+def self_times(spans):
+    """``span_id -> seconds`` of each span's self time: its duration minus
+    the union of the parts of it that its children cover (children on
+    other threads may overlap each other; a union counts them once)."""
+    children = collections.defaultdict(list)
+    for s in spans:
+        if s["parent_id"] is not None:
+            children[s["parent_id"]].append(s)
+    out = {}
+    for s in spans:
+        covered, end = 0.0, s["t0"]
+        for c in sorted(children.get(s["span_id"], ()),
+                        key=lambda c: c["t0"]):
+            lo, hi = max(c["t0"], end), min(c["t1"], s["t1"])
+            if hi > lo:
+                covered += hi - lo
+                end = hi
+        out[s["span_id"]] = (s["t1"] - s["t0"]) - covered
+    return out
 
 
 def summary_rows():
@@ -187,13 +239,13 @@ def summary_rows():
 
 def reset_summary():
     """Clear the aggregation table (the profiler.reset_summary()
-    contract); the finished-span buffer survives."""
+    contract); the finished-span ring survives."""
     with _lock:
         _agg.clear()
 
 
 def reset():
-    """Clear both the aggregation table and the finished-span buffer."""
+    """Clear both the aggregation table and the finished-span ring."""
     with _lock:
         _agg.clear()
         _finished.clear()

@@ -1,17 +1,15 @@
 """Profiler (reference: paddle/fluid/platform/profiler.* RecordEvent +
 DeviceTracer/CUPTI; python fluid/profiler.py).
 
-TPU-native: jax.profiler produces XPlane traces viewable in TensorBoard /
-Perfetto (the chrome-trace analog); RecordEvent spans map to
-jax.profiler.TraceAnnotation (host) which the XLA runtime correlates with
-device timelines — CUPTI's role is played by the TPU runtime itself.
-
-Host-side aggregation routes through the unified span layer
-(``paddle_tpu.obs.tracing``): RecordEvent spans, serving spans
-(enqueue/batch/execute/reply), and checkpoint/compile spans share one
-clock (``time.perf_counter``) and one summary table — ``summary()``
-prints all of them, and a RecordEvent inside a traced request inherits
-the ambient trace id.
+TPU-native: jax.profiler writes an XPlane trace (``.xplane.pb``, read with
+``jax.profiler.ProfileData`` or TensorBoard); CUPTI's role is played by the
+TPU runtime itself. The program's host spans are ``paddle_tpu.obs.tracing``
+region spans: each is a ``jax.profiler.TraceAnnotation`` named
+``paddle_tpu:<name>``, so a trace started here holds them on the device
+operations' clock, and the step program's operations carry their module
+and phase (``jax.named_scope``) in ``op_name``. ``summary()`` prints the
+span layer's one table: RecordEvent, train-path, serving, checkpoint and
+compile spans.
 """
 import contextlib
 
@@ -19,27 +17,19 @@ import jax
 
 from ..obs import tracing as _tracing
 
+#: RAII span (reference: profiler.h:127): an ``obs.tracing`` region span.
+#: Inside a traced request it inherits the ambient trace id.
+RecordEvent = _tracing.span
 
-class RecordEvent:
-    """RAII span (reference: profiler.h:127): feeds the TraceAnnotation
-    (device-correlated XPlane span) AND the unified obs.tracing span
-    layer that backs ``summary()`` (the profiler.cc summary-table
-    analog)."""
 
-    def __init__(self, name):
-        self.name = name
-        self._ann = jax.profiler.TraceAnnotation(name)
-        self._span = None
-
-    def __enter__(self):
-        self._ann.__enter__()
-        self._span = _tracing.start_span(self.name)
-        return self
-
-    def __exit__(self, *exc):
-        self._span.finish()
-        self._span = None
-        return self._ann.__exit__(*exc)
+def _start_trace(profile_path):
+    """The profiler with the host tracer on TraceMe spans only: per-call
+    Python events (a million frames a second) would bury the
+    ``paddle_tpu:`` spans and slow the host they time."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(profile_path, profiler_options=opts)
 
 
 def reset_summary():
@@ -70,7 +60,7 @@ def summary(sorted_by="total", printer=print):
 def profiler(state="All", sorted_key=None, profile_path="/tmp/profile",
              tracer_option="Default"):
     """paddle.utils.profiler.profiler context (fluid/profiler.py analog)."""
-    jax.profiler.start_trace(profile_path)
+    _start_trace(profile_path)
     try:
         yield
     finally:
@@ -79,7 +69,7 @@ def profiler(state="All", sorted_key=None, profile_path="/tmp/profile",
 
 def start_profiler(state="All", tracer_option="Default",
                    profile_path="/tmp/profile"):
-    jax.profiler.start_trace(profile_path)
+    _start_trace(profile_path)
 
 
 def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
@@ -149,90 +139,3 @@ def get_profiler(options=None):
     if _profiler is None:
         _profiler = Profiler(options=options)
     return _profiler
-
-
-# --------------------------------------------------------------------------
-# Device-trace op summary (reference: paddle/fluid/platform/profiler.cc
-# PrintProfiler's per-op table). jax.profiler.start_trace writes a
-# Chrome-trace json under <dir>/plugins/profile/<run>/*.trace.json.gz;
-# on TPU/GPU it contains per-device lanes with one complete ('X') event
-# per executed XLA op. These helpers aggregate that into the
-# reference-style "op, calls, total ms, avg ms, ratio" table — the
-# in-repo replacement for manually opening the trace in TensorBoard.
-
-
-def _find_trace_files(trace_dir):
-    import glob
-    import os as _os
-
-    pats = sorted(glob.glob(_os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.trace.json.gz")),
-        key=_os.path.getmtime)
-    if not pats:
-        pats = sorted(glob.glob(_os.path.join(trace_dir,
-                                              "*.trace.json.gz")),
-                      key=_os.path.getmtime)
-    return pats[-1:] if pats else []
-
-
-def op_summary_from_trace(trace_dir, top=20, device_only=True):
-    """Aggregate the newest trace under ``trace_dir`` into per-op rows.
-
-    Returns a list of dicts (name, calls, total_ms, avg_ms, ratio)
-    sorted by total time descending. ``device_only=True`` restricts to
-    device lanes (process names containing '/device:'); when the trace
-    has none (CPU backend), falls back to every lane.
-    """
-    import gzip
-    import json as _json
-    from collections import defaultdict
-
-    files = _find_trace_files(trace_dir)
-    if not files:
-        raise FileNotFoundError(
-            f"no *.trace.json.gz under {trace_dir!r} — run inside "
-            "jax.profiler.start_trace/stop_trace first")
-    with gzip.open(files[0], "rt") as f:
-        events = _json.load(f).get("traceEvents", [])
-
-    proc_names = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            proc_names[e["pid"]] = e.get("args", {}).get("name", "")
-    device_pids = {pid for pid, n in proc_names.items()
-                   if "/device:" in n or n.startswith("TPU")}
-    use_pids = device_pids if (device_only and device_pids) else None
-
-    total = defaultdict(float)
-    calls = defaultdict(int)
-    for e in events:
-        if e.get("ph") != "X" or "dur" not in e:
-            continue
-        if use_pids is not None and e.get("pid") not in use_pids:
-            continue
-        name = e.get("name", "?")
-        total[name] += float(e["dur"])          # microseconds
-        calls[name] += 1
-    grand = sum(total.values()) or 1.0
-    rows = [{"name": n, "calls": calls[n],
-             "total_ms": total[n] / 1000.0,
-             "avg_ms": total[n] / calls[n] / 1000.0,
-             "ratio": total[n] / grand}
-            for n in total]
-    rows.sort(key=lambda r: -r["total_ms"])
-    return rows[:top] if top else rows
-
-
-def print_op_summary(trace_dir, top=20, printer=print, device_only=True):
-    """Reference profiler.cc-style table for the newest trace in
-    ``trace_dir``; returns the rows it printed."""
-    rows = op_summary_from_trace(trace_dir, top=top,
-                                 device_only=device_only)
-    width = max([len(r["name"]) for r in rows] + [8])
-    printer(f"{'op':<{width}}  {'calls':>6}  {'total ms':>10}  "
-            f"{'avg ms':>9}  {'ratio':>6}")
-    for r in rows:
-        printer(f"{r['name']:<{width}}  {r['calls']:>6}  "
-                f"{r['total_ms']:>10.3f}  {r['avg_ms']:>9.4f}  "
-                f"{r['ratio']:>6.1%}")
-    return rows
