@@ -14,6 +14,8 @@ weights are as published / random from a seed):
   3  kernels scaled_dot_product_attention (causal, bf16, fwd + bwd) at
              b8 h12 s4096 d64 and b4 h32 s2048 d128, dropout 0 and 0.1,
              compiled by Mosaic and checked against the XLA reference;
+             packed_self_attention (the whole-sequence kernel) at the BERT
+             cells' b256 h12 s128 d64 likewise, alone and on the dp=N mesh;
              then one train step of a 4-layer Llama block stack at seq 2048
   4  decode  DecodeEngine behind PredictorServer, toy decoder (hidden
              256): a does-it-run-on-the-device check at toy width
@@ -57,6 +59,7 @@ FULL = dict(
     seq=128, rows_per_chip=256, max_pred=20, warmup=3, steps=10,
     serve_requests=36, serve_clients=4,
     attn_shapes=((8, 12, 4096, 64), (4, 32, 2048, 128)),
+    short_shape=(256, 12, 128, 64),  # the BERT cells' attention, per chip
     llama=dict(vocab_size=32000, hidden_size=1024, num_layers=4,
                num_heads=8, intermediate_size=2816, max_seq_len=2048),
     llama_seq=2048, llama_rows_per_chip=2,
@@ -70,6 +73,7 @@ TOY = dict(
     seq=32, rows_per_chip=4, max_pred=4, warmup=3, steps=10,
     serve_requests=12, serve_clients=2,
     attn_shapes=((1, 2, 256, 64),),  # head dims differ for Mosaic only
+    short_shape=(4, 2, 128, 64),
     llama=dict(vocab_size=256, hidden_size=64, num_layers=1, num_heads=2,
                intermediate_size=128, max_seq_len=256),
     llama_seq=256, llama_rows_per_chip=1,
@@ -97,6 +101,17 @@ ATTN_REL_TOL = 3e-2
 # binomial noise is ~1e-3 at most; bf16 rounding of the row sums it is
 # read from adds < 4e-3. (Observed on the v5e, PR 21: 0.8985.)
 KEEP_FRAC_TOL = 1e-2
+
+
+def _rel_err(got, ref):
+    """Largest absolute difference over the largest reference magnitude."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    got = np.asarray(got.astype(jnp.float32))
+    ref = np.asarray(ref.astype(jnp.float32))
+    assert np.isfinite(got).all()
+    return float(np.abs(got - ref).max() / (np.abs(ref).max() + 1e-6))
 
 
 def _recv_exact(sock, n):
@@ -541,12 +556,6 @@ class Smoke:
             #                 global generator: drop the tracer it left
             return text.count("tpu_custom_call")
 
-        def rel_err(got, ref):
-            got = np.asarray(got.astype(jnp.float32))
-            ref = np.asarray(ref.astype(jnp.float32))
-            assert np.isfinite(got).all()
-            return float(np.abs(got - ref).max() / (np.abs(ref).max() + 1e-6))
-
         info = {}
         for shape in self.cfg["attn_shapes"]:
             b, h, s, d = shape
@@ -577,7 +586,7 @@ class Smoke:
                     ref = attention(q[bs, hs], k[bs, hs], v[bs, hs], 0.0,
                                     seed=0)
                     for g, r in zip(got, ref):
-                        worst = max(worst, rel_err(g[bs, hs], r))
+                        worst = max(worst, _rel_err(g[bs, hs], r))
             finally:
                 paddle.set_flags({"use_pallas_kernels": True})
             assert worst <= ATTN_REL_TOL, (tag, worst)
@@ -607,6 +616,8 @@ class Smoke:
             assert abs(frac - 0.9) <= KEEP_FRAC_TOL, (tag, frac)
             rec["dropout_keep_fraction"] = round(float(frac), 5)
             info[tag] = rec
+
+        info["short"] = self.short_kernel()
 
         # the kernel inside the framework's own tape, amp and donation
         n, devs = self.n, self.devices
@@ -648,6 +659,106 @@ class Smoke:
             "mosaic_calls": n_calls, "loss": [round(loss0, 4),
                                               round(loss1, 4)]}
         return info
+
+    def short_kernel(self):
+        """The whole-sequence kernel at the BERT cells' attention shape,
+        bf16, through packed_self_attention: Mosaic calls lowered (forward
+        + ONE backward), agreement with the XLA route at dropout 0, the
+        dropout mask's determinism and keep fraction, and the same call on
+        the dp=N mesh against the one-device result (masks included)."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        import paddle_tpu as paddle
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from paddle_tpu.core import random as random_core
+        from paddle_tpu.distributed import topology
+        from paddle_tpu.ops import attention as attn_ops
+
+        b, h, s, d = self.cfg["short_shape"]
+        e = h * d
+        rng = np.random.RandomState(7)
+        qkv = jnp.asarray(rng.randn(b, s, 3 * e) * 0.5, jnp.bfloat16)
+        w = jnp.cos(jnp.arange(e, dtype=jnp.float32))
+
+        def loss(qkv, p):
+            out = attn_ops.packed_self_attention(
+                paddle.Tensor(qkv), h, dropout_p=p, training=True)._value
+            return jnp.sum(out.astype(jnp.float32) * w), out
+
+        # a program says which devices it is for (the gate picks no
+        # kernel for a jit that GSPMD might partition): one, or the mesh
+        one = topology.build_mesh(dp=1, devices=self.devices[:1])
+
+        def jitted(p, seed, mesh=None):
+            """(grad, out) of the call as one program; the key feeds the
+            mask."""
+            def fn(qkv):
+                with random_core.rng_guard(jax.random.PRNGKey(seed)), \
+                        topology.tracing_for(mesh or one):
+                    return jax.grad(loss, has_aux=True)(qkv, p)
+            if mesh is None:
+                return jax.jit(fn)
+            return jax.jit(fn, in_shardings=(NamedSharding(mesh, P("dp")),))
+
+        def run(qkv, p, seed, mesh=None):
+            return jitted(p, seed, mesh)(qkv)
+
+        rec = {"shape": [b, h, s, d]}
+        before = attn_ops._ROUTE_TOTAL.value(route="short")
+        for p in (0.0, 0.1):
+            n_calls = jitted(p, 0).lower(qkv).as_text().count(
+                "tpu_custom_call")
+            assert n_calls == (0 if self.dry_run else 2), \
+                f"short p={p}: {n_calls} Mosaic calls lowered"
+            rec[f"mosaic_calls_p{p}"] = n_calls
+        assert attn_ops._ROUTE_TOTAL.value(route="short") == before + 2
+
+        got = run(qkv, 0.0, 0)
+        paddle.set_flags({"use_pallas_kernels": False})
+        try:
+            ref = run(qkv, 0.0, 0)
+        finally:
+            paddle.set_flags({"use_pallas_kernels": True})
+        worst = max(_rel_err(g, r) for g, r in zip(got, ref))
+        assert worst <= ATTN_REL_TOL, ("short", worst)
+        rec["max_rel_err_vs_sdpa_ref"] = round(worst, 5)
+
+        a1, a2, a3 = run(qkv, 0.1, 11), run(qkv, 0.1, 11), run(qkv, 0.1, 12)
+        assert all(bool(jnp.array_equal(x, y)) for x, y in zip(a1, a2)), \
+            "short: same key gave different dropout results"
+        assert not bool(jnp.array_equal(a1[1], a3[1])), \
+            "short: a different key gave the same dropout mask"
+        # q = k = 0, v = 1: every row is uniform, the output its kept
+        # share / 0.9
+        ones = jnp.concatenate([jnp.zeros((b, s, 2 * e), jnp.bfloat16),
+                                jnp.ones((b, s, e), jnp.bfloat16)], axis=-1)
+        out = run(ones, 0.1, 13)[1]
+        frac = float(np.asarray(out[:, :, ::d].astype(jnp.float32),
+                                np.float64).mean() * 0.9)
+        assert abs(frac - 0.9) <= KEEP_FRAC_TOL, ("short", frac)
+        rec["dropout_keep_fraction"] = round(frac, 5)
+
+        if self.n > 1:
+            # b rows a chip on dp=N: the shard_map wrapper, rows of every
+            # shard equal to the one-device result
+            mesh = topology.build_mesh(dp=self.n, devices=self.devices)
+            wide = jnp.concatenate([qkv] * self.n, axis=0)
+            on_mesh = run(wide, 0.0, 0, mesh=mesh)
+            worst = max(_rel_err(m[i * b:(i + 1) * b], g)
+                        for m, g in zip(on_mesh, got) for i in range(self.n))
+            assert worst <= 1e-6, ("short on mesh", worst)
+            # the mask hashes the rows' numbers in the whole batch: two
+            # shards do not drop the same entries, and the mesh does not
+            # change what is dropped
+            wide_ones = jnp.concatenate([ones] * self.n, axis=0)
+            od = run(wide_ones, 0.1, 13, mesh=mesh)[1]
+            assert not bool(jnp.array_equal(od[:b], od[b:2 * b])), \
+                "short on mesh: two shards drew the same mask"
+            assert bool(jnp.array_equal(od, run(wide_ones, 0.1, 13)[1])), \
+                "short on mesh: the mask depends on the mesh"
+            rec[f"dp{self.n}_max_rel_err_vs_one_device"] = round(worst, 8)
+        return rec
 
     # ------------------------------------------------------------ 4
     def phase_decode(self):

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 AMP_WHITE_LIST = {
     "matmul", "bmm", "mm", "linear", "conv1d", "conv2d", "conv3d",
     "conv2d_transpose", "conv1d_transpose", "einsum", "fused_lstm", "fused_gru",
-    "fused_rnn", "sdpa", "flash_attention", "addmm",
+    "fused_rnn", "sdpa", "flash_attention", "short_attention", "addmm",
 }
 
 # numerically-sensitive ops — force fp32.

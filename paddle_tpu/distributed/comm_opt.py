@@ -180,6 +180,8 @@ def build_localsgd_train_step(layer, loss_fn, optimizer, mesh=None,
         try:
             with contextlib.ExitStack() as stack:
                 stack.enter_context(dispatch.trace_mode())
+                # traced inside the shard_map below: already per device
+                stack.enter_context(topology.tracing_for(mesh))
                 stack.enter_context(random_core.rng_guard(key))
                 if amp_enabled:
                     from ..amp.auto_cast import auto_cast as _auto_cast

@@ -592,8 +592,9 @@ def build_fsdp_train_step(layers, loss_fn, optimizer, mesh=None,
     batch_shard = NamedSharding(mesh, P(data_axes)) if data_axes else repl
 
     def step(params, opt_state, x, y, key, lr):
-        loss, grads = jax.value_and_grad(
-            lambda p: forward_loss(p, x, y, key))(params)
+        with topology.tracing_for(mesh):
+            loss, grads = jax.value_and_grad(
+                lambda p: forward_loss(p, x, y, key))(params)
         # keep grads in the shard layout -> reduce-scatter, ZeRO-2 style
         grads = {n: jax.lax.with_sharding_constraint(g, param_shards[n])
                  for n, g in grads.items()}

@@ -1,6 +1,9 @@
 """Transformer stack (reference: python/paddle/nn/layer/transformer.py:107
 MultiHeadAttention, :1086 Transformer). Attention dispatches through
-F.scaled_dot_product_attention → Pallas flash kernel on TPU.
+ops/attention.py: fused self-attention hands its packed QKV projection to
+``packed_self_attention`` (the whole-sequence Pallas kernel on TPU when the
+shapes allow), everything else goes through
+``F.scaled_dot_product_attention``.
 """
 from .. import functional as F
 from ..layer import Layer
@@ -92,7 +95,8 @@ class MultiHeadAttention(Layer):
         """Self-attention fast path: one [H, 3H] matmul instead of three
         [H, H] gemms — fewer kernel launches, larger MXU tile. Bitwise
         identical to the separate projections (each output element is
-        the same dot product; concatenation only widens the gemm)."""
+        the same dot product; concatenation only widens the gemm).
+        Returns the packed [batch, seq, 3H] result, q | k | v."""
         from ... import tensor as pt
 
         w = pt.concat([self.q_proj.weight, self.k_proj.weight,
@@ -101,11 +105,11 @@ class MultiHeadAttention(Layer):
         biases = [p.bias for p in (self.q_proj, self.k_proj, self.v_proj)]
         if all(b is not None for b in biases):
             qkv = qkv + pt.concat(biases, axis=0)
-        q, k, v = pt.split(qkv, 3, axis=-1)
-        return q, k, v
+        return qkv
 
     def forward(self, query, key=None, value=None, attn_mask=None, cache=None):
         from ... import tensor as pt
+        from ...ops import attention as attn_ops
 
         key = query if key is None else key
         value = key if value is None else value
@@ -114,26 +118,33 @@ class MultiHeadAttention(Layer):
                    and not isinstance(cache, self.StaticCache)
                    and (self.q_proj.bias is None) == (self.k_proj.bias is None)
                    == (self.v_proj.bias is None))
-        if fusable:
-            q, k, v = self._fused_qkv(query)
-            q = self._split_heads(q)
-            k = self._split_heads(k)
-            v = self._split_heads(v)
+        attn_mask = _convert_attention_mask(attn_mask)
+        if fusable and cache is None:
+            # the packed projection goes to attention as it is: the
+            # whole-sequence kernel reads the heads in place, any other
+            # route splits them there
+            out = attn_ops.packed_self_attention(
+                self._fused_qkv(query), self.num_heads, attn_mask=attn_mask,
+                dropout_p=self.dropout, training=self.training)
         else:
-            q = self._split_heads(self.q_proj(query))
-            if isinstance(cache, self.StaticCache):
-                k, v = cache.k, cache.v
+            if fusable:
+                q, k, v = (self._split_heads(x) for x in pt.split(
+                    self._fused_qkv(query), 3, axis=-1))
             else:
-                k = self._split_heads(self.k_proj(key))
-                v = self._split_heads(self.v_proj(value))
-        if isinstance(cache, self.Cache):
-            k = pt.concat([cache.k, k], axis=2)
-            v = pt.concat([cache.v, v], axis=2)
-            cache = self.Cache(k, v)
-        out = F.scaled_dot_product_attention(
-            q, k, v, attn_mask=_convert_attention_mask(attn_mask),
-            dropout_p=self.dropout, training=self.training)
-        out = self.out_proj(self._merge_heads(out))
+                q = self._split_heads(self.q_proj(query))
+                if isinstance(cache, self.StaticCache):
+                    k, v = cache.k, cache.v
+                else:
+                    k = self._split_heads(self.k_proj(key))
+                    v = self._split_heads(self.v_proj(value))
+            if isinstance(cache, self.Cache):
+                k = pt.concat([cache.k, k], axis=2)
+                v = pt.concat([cache.v, v], axis=2)
+                cache = self.Cache(k, v)
+            out = self._merge_heads(F.scaled_dot_product_attention(
+                q, k, v, attn_mask=attn_mask, dropout_p=self.dropout,
+                training=self.training))
+        out = self.out_proj(out)
         outs = [out]
         if self.need_weights:
             outs.append(None)

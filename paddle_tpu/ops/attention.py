@@ -1,11 +1,28 @@
-"""Scaled-dot-product attention: jnp reference + Pallas flash kernel switch.
+"""Scaled-dot-product attention: one gate, three routes.
 
 The reference has no flash attention (SURVEY §5 long-context: absent) —
 its closest analog is the fused BERT encoder functor
-(reference: paddle/fluid/operators/math/bert_encoder_functor.cu). Here the
-TPU-native design is a Pallas blockwise-softmax kernel (ops/pallas/
-flash_attention.py) selected on TPU, with this jnp implementation as the
-portable reference; XLA already fuses it into few kernels on TPU.
+(reference: paddle/fluid/operators/math/bert_encoder_functor.cu). Here
+``attention_route`` picks, from what it can observe (shapes, mask,
+causality, platform), one of:
+
+- ``short``: the whole-sequence Pallas kernel (ops/pallas/
+  flash_attention.py ``mha_packed``) on the packed [batch, seq, 3*embed]
+  projection output — unmasked, non-causal self-attention whose keys fit
+  one tile (BERT at 128..512). Only ``packed_self_attention`` can take it:
+  it owns the layout that makes the head transposes unnecessary.
+- ``stream``: the streaming flash kernel (``mha``) on [batch, heads, seq,
+  head_dim] for long keys, where XLA's S^2 logits buffer explodes.
+- ``xla``: the jnp implementation below, which XLA fuses into a few
+  kernels — every masked call, every CPU run without ``pallas_interpret``.
+
+A selected kernel that fails raises; no route falls back to another.
+GSPMD cannot partition a Mosaic call, so ``short`` is chosen only where
+the gate knows the program's devices (``_placeable``): a step builder
+announced its mesh and the kernel shards itself over it, or the process
+has one device.
+Every decision counts in ``paddle_tpu_attention_route_total{route}`` (at
+trace time under jit: one count per traced call site).
 """
 import functools
 import math
@@ -17,6 +34,18 @@ from jax.sharding import PartitionSpec as P
 from ..core import flags, random as random_core
 from ..core.dispatch import apply_op
 from ..distributed import topology
+from ..obs import metrics as obs_metrics
+
+_ROUTE_TOTAL = obs_metrics.counter(
+    "paddle_tpu_attention_route_total",
+    "attention calls by the route the gate chose (short | stream | xla); "
+    "under jit one count per traced call site",
+    labelnames=("route",))
+
+# the stream kernel runs one k block of up to 256 keys per grid step: with
+# fewer keys than two blocks a program is per-program overhead however
+# long q is, so the logits-product clause needs this many keys too
+_STREAM_MIN_KEYS = 512
 
 
 def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal,
@@ -65,41 +94,137 @@ def _use_pallas():
     return is_tpu_available()
 
 
-def _flash(q, k, v, key, *, scale, is_causal, dropout_p, interpret):
-    """The Pallas kernel on [batch, heads, seq, head_dim]. Inside a step
-    being traced for a multi-device mesh (topology.traced_mesh) the call
-    is wrapped in a shard_map: GSPMD cannot partition a Mosaic kernel,
-    every mesh axis has to be manual around it. (batch, head) programs
-    are independent, so batch shards over the data axes and heads over
-    'mp' — each only where it divides; otherwise that dim is computed
-    whole on every device of the axis."""
+def _placeable():
+    """Whether the program being traced can hold a Mosaic call that is
+    not told its mesh at lowering. GSPMD cannot partition one, so the gate
+    has to know the program's devices now: a mesh its builder announced
+    (topology.tracing_for; ``_on_mesh`` shards the kernel over it), or a
+    process with one device. A plain jax.jit on a process with several
+    devices may be partitioned over them, which only its lowering sees:
+    not known here, so no kernel. The interpreter's calls are plain HLO
+    and go anywhere."""
+    return (flags.flag_value("pallas_interpret")
+            or topology.traced_mesh() is not None
+            or jax.device_count() == 1)
+
+
+def attention_route(*, batch, seq_q, seq_k, num_heads, head_dim, dtype,
+                    packed, masked, is_causal):
+    """``short`` | ``stream`` | ``xla`` for one attention call.
+    ``packed`` says the caller holds the fused [batch, seq, 3*embed]
+    projection (so it is self-attention and seq_q == seq_k)."""
+    if masked or not _use_pallas():
+        return "xla"
     from .pallas import flash_attention
 
-    seed = (jnp.zeros((), jnp.int32) if key is None else
-            jax.random.key_data(key).reshape(-1)[-1].astype(jnp.int32))
-    kernel = functools.partial(
-        flash_attention.mha, scale=scale, causal=is_causal,
-        dropout_p=dropout_p, interpret=interpret)
-    mesh = topology.traced_mesh()
-    if mesh is None or mesh.size == 1:
-        return kernel(q, k, v, seed=seed)
+    # the short kernel's grid is the batch in row blocks: a batch that is
+    # a symbol (jit.save's batch-polymorphic export) has no divisors.
+    # Where it cannot be placed XLA's route serves (its S^2 buffers are
+    # small at these lengths); the stream kernel has no such way out, so
+    # it is chosen all the same and raises if GSPMD has to partition it
+    if (packed and not is_causal and isinstance(batch, int)
+            and flash_attention.short_supported(
+                seq_q, num_heads, head_dim, dtype)
+            and _placeable()):
+        return "short"
+    # kernel overhead is governed by seq_k (the per-program inner-loop
+    # length), XLA's memory blowup by the seq_q*seq_k logits buffer. So:
+    # stream when the k side is long, or when the logits are as big as a
+    # min_seq^2 square AND k is at least two blocks long (long-q/short-k
+    # stays on XLA: its logits are small and the kernel would run one k
+    # block per program). 0 = always the kernel.
+    min_seq = flags.flag_value("pallas_attention_min_seq")
+    if seq_k >= min_seq or (seq_k >= _STREAM_MIN_KEYS
+                            and seq_q * seq_k >= min_seq * min_seq):
+        return "stream"
+    return "xla"
 
+
+def _kernel_seed(key):
+    """The kernels' 32-bit dropout seed from a PRNG key: every key word
+    is folded in (w0 ^ fmix32(w1 ^ fmix32(w2 ...))), not the last word
+    alone — keys that differ in any word give different masks."""
+    if key is None:
+        return jnp.zeros((), jnp.int32)
+    words = jax.random.key_data(key).reshape(-1).astype(jnp.uint32)
+    seed = words[-1]
+    for i in range(words.shape[0] - 2, -1, -1):
+        seed = words[i] ^ random_core.fmix32(seed)
+    return jax.lax.bitcast_convert_type(seed, jnp.int32)
+
+
+def _on_mesh(kernel, arrays, seed, *, head_axis, seed_per_shard):
+    """Run ``kernel(*arrays, seed=seed)`` — directly, or inside a step
+    being traced for a multi-device mesh (topology.traced_mesh) under a
+    shard_map: GSPMD cannot partition a Mosaic kernel, every mesh axis has
+    to be manual around it (a builder whose step already runs inside a
+    shard_map over the whole mesh has done that: direct again). Programs
+    are independent per batch row and head, so dim 0 of every array
+    shards over the data axes and dim
+    ``head_axis`` (None: heads are not a dim of their own) over 'mp' —
+    each only where it divides; otherwise that dim is computed whole on
+    every device of the axis. ``seed_per_shard``: the kernel's mask hash
+    counts (batch, head) from 0 on every shard, so each shard gets a seed
+    of its own or they all drop the same entries."""
+    mesh = topology.traced_mesh()
+    if (mesh is None or mesh.size == 1 or set(mesh.axis_names) <= set(
+            jax.sharding.get_abstract_mesh().manual_axes)):
+        return kernel(*arrays, seed=seed)
+
+    shape = arrays[0].shape
     data = topology.data_axes(mesh)
     n_data = math.prod(mesh.shape[ax] for ax in data)
     n_mp = mesh.shape.get("mp", 1)
-    b_axes = data if n_data > 1 and q.shape[0] % n_data == 0 else ()
-    h_axes = ("mp",) if n_mp > 1 and q.shape[1] % n_mp == 0 else ()
-    spec = P(b_axes or None, h_axes or None, None, None)
+    b_axes = data if n_data > 1 and shape[0] % n_data == 0 else ()
+    h_axes = (("mp",) if head_axis is not None and n_mp > 1
+              and shape[head_axis] % n_mp == 0 else ())
 
-    def on_shard(q, k, v, seed):
-        # the mask hash counts (batch, head) from 0 on every shard: give
-        # each shard its own seed or they all drop the same entries
-        for ax in b_axes + h_axes:
-            seed = seed * jnp.int32(mesh.shape[ax]) + jax.lax.axis_index(ax)
-        return kernel(q, k, v, seed=seed)
+    def spec(a):
+        dims = [None] * a.ndim
+        dims[0] = b_axes or None
+        if h_axes:
+            dims[head_axis] = h_axes
+        return P(*dims)
 
-    return jax.shard_map(on_shard, mesh=mesh, in_specs=(spec, spec, spec, P()),
-                         out_specs=spec, check_vma=False)(q, k, v, seed)
+    def on_shard(*args):
+        *shards, seed = args
+        if seed_per_shard:
+            for ax in b_axes + h_axes:
+                seed = (seed * jnp.int32(mesh.shape[ax])
+                        + jax.lax.axis_index(ax))
+        return kernel(*shards, seed=seed)
+
+    return jax.shard_map(
+        on_shard, mesh=mesh, in_specs=tuple(map(spec, arrays)) + (P(),),
+        out_specs=spec(arrays[0]), check_vma=False)(*arrays, seed)
+
+
+def _flash(q, k, v, key, *, scale, is_causal, dropout_p, interpret):
+    """The streaming kernel on [batch, heads, seq, head_dim]."""
+    from .pallas import flash_attention
+
+    kernel = functools.partial(
+        flash_attention.mha, scale=scale, causal=is_causal,
+        dropout_p=dropout_p, interpret=interpret)
+    return _on_mesh(kernel, (q, k, v), _kernel_seed(key), head_axis=1,
+                    seed_per_shard=True)
+
+
+def _short(qkv, key, *, num_heads, scale, dropout_p, interpret):
+    """The whole-sequence kernel on packed [batch, seq, 3*embed]; under a
+    mesh the batch shards over the data axes. The rows carry their numbers
+    in the whole batch, which is what the mask hashes: one seed serves
+    every shard, and the mask does not depend on the mesh."""
+    from .pallas import flash_attention
+
+    def kernel(qkv, row_ids, seed):
+        return flash_attention.mha_packed(
+            qkv, num_heads, scale=scale, dropout_p=dropout_p, seed=seed,
+            row_ids=row_ids, interpret=interpret)
+
+    rows = jnp.arange(qkv.shape[0], dtype=jnp.int32)
+    return _on_mesh(kernel, (qkv, rows), _kernel_seed(key), head_axis=None,
+                    seed_per_shard=False)
 
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
@@ -107,24 +232,15 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
     head_dim = q.shape[-1]
     scale = 1.0 / math.sqrt(head_dim)
     p = float(dropout_p) if training else 0.0
+    route = attention_route(
+        batch=q.shape[0], seq_q=q.shape[-2], seq_k=k.shape[-2],
+        num_heads=q.shape[-3], head_dim=head_dim, dtype=q.dtype,
+        packed=False, masked=attn_mask is not None,
+        is_causal=bool(is_causal))
+    _ROUTE_TOTAL.inc(route=route)
     key = random_core.next_key() if p > 0.0 else None
-
-    # seq-length dispatch threshold: below it, XLA's own fused attention
-    # runs (at one 128-block per program the kernel is overhead-bound and
-    # 3x slower than XLA's batched matmul — v5e measurement in the flag's
-    # help text; 0 = always use the kernel). Kernel overhead is governed
-    # by seq_k (the per-program inner-loop length); XLA's memory blowup
-    # by the seq_q*seq_k logits buffer. So: kernel when the k side is
-    # long, OR when the logits product is as big as a min_seq^2 square
-    # (long-q/short-k stays on XLA — its logits are small and the kernel
-    # would be one k-block per program again).
-    min_seq = flags.flag_value("pallas_attention_min_seq")
-    seq_q, seq_k = q.shape[-2], k.shape[-2]
-    kernel_pays = seq_k >= min_seq or seq_q * seq_k >= min_seq * min_seq
-    if kernel_pays and attn_mask is None and _use_pallas():
-        # a selected kernel that fails raises: falling back to the XLA
-        # path would report a broken kernel as a slow one. interpret
-        # rides the static kwargs so a flag flip retraces.
+    if route == "stream":
+        # interpret rides the static kwargs so a flag flip retraces
         return apply_op(
             "flash_attention", _flash, q, k, v, key,
             scale=scale, is_causal=bool(is_causal), dropout_p=p,
@@ -136,3 +252,39 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
         "sdpa", _sdpa_ref, q, k, v, attn_mask, key,
         scale=scale, dropout_p=p, is_causal=bool(is_causal),
         fp32_softmax=bool(flags.flag_value("sdpa_softmax_fp32")))
+
+
+def packed_self_attention(qkv, num_heads, attn_mask=None, dropout_p=0.0,
+                          is_causal=False, training=True):
+    """Self-attention on the fused projection's output. qkv: [batch, seq,
+    3*embed], q | k | v along the last axis; returns [batch, seq, embed]
+    with the heads merged. Route ``short`` reads and writes those layouts
+    in place; any other route splits the heads, calls
+    ``scaled_dot_product_attention`` and merges them again."""
+    batch, seq, embed3 = qkv.shape
+    embed = embed3 // 3
+    head_dim = embed // num_heads
+    route = attention_route(
+        batch=batch, seq_q=seq, seq_k=seq, num_heads=num_heads,
+        head_dim=head_dim, dtype=qkv.dtype, packed=True,
+        masked=attn_mask is not None, is_causal=bool(is_causal))
+    if route == "short":
+        _ROUTE_TOTAL.inc(route=route)
+        p = float(dropout_p) if training else 0.0
+        key = random_core.next_key() if p > 0.0 else None
+        return apply_op(
+            "short_attention", _short, qkv, key, num_heads=int(num_heads),
+            scale=1.0 / math.sqrt(head_dim), dropout_p=p,
+            interpret=bool(flags.flag_value("pallas_interpret")))
+
+    from .. import tensor as pt
+
+    def split_heads(x):
+        x = pt.reshape(x, [batch, seq, num_heads, head_dim])
+        return pt.transpose(x, [0, 2, 1, 3])
+
+    q, k, v = (split_heads(x) for x in pt.split(qkv, 3, axis=-1))
+    out = scaled_dot_product_attention(
+        q, k, v, attn_mask=attn_mask, dropout_p=dropout_p,
+        is_causal=is_causal, training=training)
+    return pt.reshape(pt.transpose(out, [0, 2, 1, 3]), [batch, seq, embed])

@@ -1,4 +1,8 @@
-"""Flash (blockwise-softmax) multi-head attention as a Pallas TPU kernel.
+"""Fused multi-head attention as Pallas TPU kernels: a streaming flash
+(blockwise-softmax) kernel for long keys (``mha``), and a whole-sequence
+kernel for sequences whose keys fit one tile (``mha_packed``, further down:
+many heads a program, no recurrence, one backward kernel, the packed QKV
+projection read in place). ops/attention.py picks between them by shape.
 
 TPU-native replacement for the reference's fused attention math
 (reference: paddle/fluid/operators/math/bert_encoder_functor.cu,
@@ -19,9 +23,10 @@ flash-attention online-softmax recurrence tiled for the MXU:
   anywhere, and no full-K/V VMEM residency: seq length is not capped
   by the 16 MB scoped-VMEM limit).
 
-All matmuls request `preferred_element_type=float32` so the MXU
-accumulates in f32 even for bf16 inputs. The kernels compile via Mosaic;
-``mha(..., interpret=True)`` runs them in the Pallas interpreter instead
+All matmuls (both kernels') request `preferred_element_type=float32` so
+the MXU accumulates in f32 even for bf16 inputs, and both draw dropout
+masks from one counter hash (``_keep_mask``). The kernels compile via
+Mosaic; ``interpret=True`` runs them in the Pallas interpreter instead
 (the CPU test-suite and the chip_smoke dry run ask for it explicitly —
 it is never inferred from the backend).
 """
@@ -50,21 +55,30 @@ def _block(seq, want):
     return seq  # tiny/odd seq: single block
 
 
-def _keep_mask(seed, b, rows, cols, seq_q, seq_k, keep_thresh):
-    """Counter-based dropout mask: a murmur-style hash of the global element
-    index (b, row, col), so forward and both backward kernels regenerate
-    bit-identical masks from the same seed with no PRNG state — pure uint32
-    vector math that lowers on both Mosaic and interpret mode (the pltpu
-    hardware PRNG has no interpret-mode lowering).
-
-    The batch-head index is folded into the seed by its own hash round
-    (not a flat linear index) so masks stay decorrelated even when
+def _mask_seed(seed, b):
+    """The batch-head index folded into the seed by its own hash round
+    (not a flat linear index), so masks stay decorrelated even when
     bh * seq_q * seq_k exceeds 2^32."""
     bseed = seed ^ (b.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B))
     bseed ^= bseed >> jnp.uint32(13)
-    bseed *= jnp.uint32(0xC2B2AE35)
-    idx = (rows * _i32(seq_k) + cols).astype(jnp.uint32)
-    h = fmix32(idx * jnp.uint32(0x9E3779B1) ^ bseed)
+    return bseed * jnp.uint32(0xC2B2AE35)
+
+
+def _mask_index(rows, cols, seq_k):
+    """The element's term of the hash: its (query row, key column) index,
+    spread by the golden-ratio multiplier. It does not depend on the seed,
+    so a kernel that hashes many heads computes it once."""
+    return ((rows * _i32(seq_k) + cols).astype(jnp.uint32)
+            * jnp.uint32(0x9E3779B1))
+
+
+def _keep_mask(seed, b, rows, cols, seq_q, seq_k, keep_thresh):
+    """Counter-based dropout mask: a murmur-style hash of the global element
+    index (b, row, col), so forward and backward kernels regenerate
+    bit-identical masks from the same seed with no PRNG state — pure uint32
+    vector math that lowers on both Mosaic and interpret mode (the pltpu
+    hardware PRNG has no interpret-mode lowering)."""
+    h = fmix32(_mask_index(rows, cols, seq_k) ^ _mask_seed(seed, b))
     return h < jnp.uint32(keep_thresh)
 
 
@@ -540,3 +554,352 @@ def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
                float(dropout_p), bool(interpret))
     o = o.reshape(b, h, sq, d)
     return o[0] if squeeze else o
+
+
+# ------------------------------------------- whole-sequence ("short") kernel
+#
+# Where every key of a row fits one tile there is nothing to stream: no
+# online-softmax recurrence, no k grid dimension, and one backward kernel
+# that emits dq, dk and dv together. A program takes a block of batch
+# rows with ALL their heads, straight from the packed [batch, seq, 3*embed]
+# output of the fused QKV projection, and writes [batch, seq, embed] for the
+# output projection: the head split/merge transposes never exist, the DMAs
+# are whole contiguous rows, and nothing of size [batch, heads, seq, seq]
+# reaches HBM in either pass (the residuals are the packed input and the
+# row log-sum-exp).
+#
+# Inside, heads are taken a 128-lane group at a time (two heads of 64, one
+# of 128). A head narrower than the group shares the group's 128-deep MXU
+# contraction: the other heads' lanes of q (and of dO) are zeroed, which
+# costs the array nothing (a 64-deep contraction fills half of it anyway),
+# and the output lanes of each head are picked from its own product.
+# Scores are held TRANSPOSED ([keys, queries]: keys on sublanes, queries on
+# lanes) so that the row statistics (max, sum, lse, delta) are lane-dense
+# [1, seq] rows: they reduce over sublanes, broadcast back for free, and
+# the log-sum-exp is stored compactly ([batch, groups, heads a group, seq]
+# f32; a [.., seq, 1] column would be padded to 128 lanes in HBM).
+
+# what the backward keeps live in VMEM beside its pipelined row blocks:
+# about eight f32 [seq, seq] tiles (scores, probabilities, dP, dS, the mask
+# hash) = 8 MiB at 512
+SHORT_MAX_SEQ = 512
+_SHORT_LIVE_TILES = 8
+_SHORT_VMEM_LIMIT = 48 * 2**20
+# double-buffered row blocks of one program may take this much of it
+_SHORT_BLOCK_BUDGET = 12 * 2**20
+# blocks of a batch row, in [seq, embed] arrays: the packed projection and
+# the output forward; the projection, dO and the packed gradient backward
+_SHORT_FWD_BLOCKS, _SHORT_BWD_BLOCKS = 4, 7
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _short_row_bytes(seq, num_heads, head_dim, itemsize, blocks):
+    """One batch row of a program's blocks, the f32 log-sum-exp included."""
+    return seq * (blocks * num_heads * head_dim * itemsize + num_heads * 4)
+
+
+def short_supported(seq, num_heads, head_dim, dtype):
+    """Shapes the whole-sequence kernel takes: a lane-dense score tile
+    (seq a multiple of 128, at most SHORT_MAX_SEQ), heads that tile the
+    128 lanes (head_dim divides 128, or is a multiple of it), and a
+    backward that fits VMEM at one row a program — a program holds ALL
+    heads of its rows, so that grows with embed x seq x itemsize."""
+    group = max(head_dim, LANES)
+    if not (0 < seq <= SHORT_MAX_SEQ and seq % LANES == 0
+            and (LANES % head_dim == 0 or head_dim % LANES == 0)
+            and (num_heads * head_dim) % group == 0):
+        return False
+    row = _short_row_bytes(seq, num_heads, head_dim,
+                           jnp.dtype(dtype).itemsize, _SHORT_BWD_BLOCKS)
+    return 2 * row + _SHORT_LIVE_TILES * 4 * seq * seq <= _SHORT_VMEM_LIMIT
+
+
+def _short_rows(batch, row_bytes):
+    """Batch rows per program: the largest divisor of ``batch`` whose
+    double-buffered blocks fit the budget (one row always goes)."""
+    cap = min(batch, max(1, _SHORT_BLOCK_BUDGET // (2 * row_bytes)))
+    return max(r for r in range(1, cap + 1) if batch % r == 0)
+
+
+def _short_groups(num_heads, head_dim):
+    """(lane-group width, heads per group, number of groups)."""
+    width = max(head_dim, LANES)
+    return width, width // head_dim, num_heads * head_dim // width
+
+
+def _short_each_group(rows, groups, body):
+    """``body(r, j)`` for every batch row r of the block (a loop) and every
+    lane group j (unrolled). One group is a chain of dependent steps
+    (matmul, reduce, exp, matmul); the unrolled groups of a row are what
+    the scheduler overlaps. On the v5e at BERT's shape, forward + backward
+    a layer: 2.06 ms as one loop over (row, group), 1.10 ms with a row's
+    six groups unrolled, 0.97 ms with four rows' — not worth four times
+    the code to trace and compile at every start."""
+    def one_row(r, carry):
+        for j in range(groups):
+            body(r, j)
+        return carry
+
+    jax.lax.fori_loop(0, rows, one_row, 0)
+
+
+def _short_head_lanes(seq, width, head_dim, per_group):
+    """For each head of a lane group, the [seq, width] mask of its lanes
+    (None when the head is the whole group)."""
+    if per_group == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (seq, width), 1)
+    return [(lane >= t * head_dim) & (lane < (t + 1) * head_dim)
+            for t in range(per_group)]
+
+
+def _only(mine, x):
+    """x with the lanes of the group's other heads zeroed."""
+    return x if mine is None else jnp.where(mine, x, jnp.zeros_like(x))
+
+
+def _short_mask_index(seq):
+    """_mask_index of a [keys, queries] tile: sublanes count keys, lanes
+    count queries."""
+    return _mask_index(
+        jax.lax.broadcasted_iota(jnp.int32, (seq, seq), 1),
+        jax.lax.broadcasted_iota(jnp.int32, (seq, seq), 0), seq)
+
+
+def _short_fwd_kernel(seed_ref, ids_ref, qkv_ref, o_ref, lse_ref, *, scale,
+                      heads, head_dim, dropout_p, keep_thresh):
+    rows, seq, embed = o_ref.shape
+    width, per_group, groups = _short_groups(heads, head_dim)
+    row0 = _i32(pl.program_id(0)) * _i32(rows)
+    seed = seed_ref[0, 0].astype(jnp.uint32)
+    head_lanes = _short_head_lanes(seq, width, head_dim, per_group)
+    index = _short_mask_index(seq) if dropout_p > 0.0 else None
+    inv_keep = 1.0 / (1.0 - dropout_p)
+
+    def group(r, j):
+        at = j * width
+        q = qkv_ref[r, :, pl.ds(at, width)]
+        k = qkv_ref[r, :, pl.ds(embed + at, width)]
+        v = qkv_ref[r, :, pl.ds(2 * embed + at, width)]
+        out = None
+        for t, mine in enumerate(head_lanes):
+            s = jax.lax.dot_general(
+                k, _only(mine, q), _NT,
+                preferred_element_type=jnp.float32) * scale  # [keys, queries]
+            m = jnp.max(s, axis=0, keepdims=True)            # [1, seq]
+            e = jnp.exp(s - m)
+            l = jnp.sum(e, axis=0, keepdims=True)
+            lse_ref[r, j, t:t + 1, :] = m + jnp.log(l)
+            p = e * (inv_keep / l)
+            if dropout_p > 0.0:
+                bh = (ids_ref[row0 + r] * _i32(heads)
+                      + _i32(j * per_group + t))
+                keep = (fmix32(index ^ _mask_seed(seed, bh))
+                        < jnp.uint32(keep_thresh))
+                p = jnp.where(keep, p, 0.0)
+            o_t = jax.lax.dot_general(
+                p.astype(v.dtype), v, _TN,
+                preferred_element_type=jnp.float32)          # [seq, width]
+            out = o_t if out is None else jnp.where(mine, o_t, out)
+        o_ref[r, :, pl.ds(at, width)] = out.astype(o_ref.dtype)
+
+    _short_each_group(rows, groups, group)
+
+
+def _short_bwd_kernel(seed_ref, ids_ref, qkv_ref, do_ref, lse_ref, dqkv_ref,
+                      *, scale, heads, head_dim, dropout_p, keep_thresh):
+    """dq, dk and dv in one pass: with every key in the tile each
+    probability is recomputed once (from the saved log-sum-exp) and used
+    for all three. delta = rowsum(P * dP) comes from the tile too, in f32,
+    so the forward's output is not a residual."""
+    rows, seq, embed = do_ref.shape
+    width, per_group, groups = _short_groups(heads, head_dim)
+    row0 = _i32(pl.program_id(0)) * _i32(rows)
+    seed = seed_ref[0, 0].astype(jnp.uint32)
+    head_lanes = _short_head_lanes(seq, width, head_dim, per_group)
+    index = _short_mask_index(seq) if dropout_p > 0.0 else None
+    inv_keep = 1.0 / (1.0 - dropout_p)
+
+    def group(r, j):
+        at = j * width
+        q = qkv_ref[r, :, pl.ds(at, width)]
+        k = qkv_ref[r, :, pl.ds(embed + at, width)]
+        v = qkv_ref[r, :, pl.ds(2 * embed + at, width)]
+        do = do_ref[r, :, pl.ds(at, width)]
+        dq = dk = dv = None
+        for t, mine in enumerate(head_lanes):
+            s = jax.lax.dot_general(
+                k, _only(mine, q), _NT,
+                preferred_element_type=jnp.float32) * scale
+            p = jnp.exp(s - lse_ref[r, j, t:t + 1, :])       # [keys, queries]
+            dp = jax.lax.dot_general(
+                v, _only(mine, do), _NT, preferred_element_type=jnp.float32)
+            if dropout_p > 0.0:
+                bh = (ids_ref[row0 + r] * _i32(heads)
+                      + _i32(j * per_group + t))
+                keep = (fmix32(index ^ _mask_seed(seed, bh))
+                        < jnp.uint32(keep_thresh))
+                dp = jnp.where(keep, dp * inv_keep, 0.0)
+                p_drop = jnp.where(keep, p * inv_keep, 0.0)
+            else:
+                p_drop = p
+            delta = jnp.sum(p * dp, axis=0, keepdims=True)   # [1, seq]
+            ds = (p * (dp - delta)).astype(q.dtype)
+            dv_t = jax.lax.dot_general(
+                p_drop.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32)          # [keys, width]
+            dk_t = jax.lax.dot_general(
+                ds, q, _NN, preferred_element_type=jnp.float32)
+            dq_t = jax.lax.dot_general(
+                ds, k, _TN, preferred_element_type=jnp.float32)
+            if dq is None:
+                dq, dk, dv = dq_t, dk_t, dv_t
+            else:
+                dq = jnp.where(mine, dq_t, dq)
+                dk = jnp.where(mine, dk_t, dk)
+                dv = jnp.where(mine, dv_t, dv)
+        dt = dqkv_ref.dtype
+        dqkv_ref[r, :, pl.ds(at, width)] = (dq * scale).astype(dt)
+        dqkv_ref[r, :, pl.ds(embed + at, width)] = (dk * scale).astype(dt)
+        dqkv_ref[r, :, pl.ds(2 * embed + at, width)] = dv.astype(dt)
+
+    _short_each_group(rows, groups, group)
+
+
+_SHORT_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel",), vmem_limit_bytes=_SHORT_VMEM_LIMIT)
+
+
+def _short_statics(qkv, heads, scale, dropout_p, blocks, kernel):
+    """What both pallas_calls derive from the packed shape: rows a program
+    (``blocks`` [seq, embed] arrays of blocks a row), the lse layout, the
+    kernel with its static arguments bound."""
+    batch, seq, embed3 = qkv.shape
+    head_dim = embed3 // 3 // heads
+    rows = _short_rows(batch, _short_row_bytes(
+        seq, heads, head_dim, qkv.dtype.itemsize, blocks))
+    _, per_group, groups = _short_groups(heads, head_dim)
+    kernel = functools.partial(
+        kernel, scale=scale, heads=heads, head_dim=head_dim,
+        dropout_p=dropout_p, keep_thresh=_keep_thresh(dropout_p))
+    lse_spec = pl.BlockSpec((rows, groups, per_group, seq),
+                            lambda i: (i, 0, 0, 0))
+    return rows, (batch, groups, per_group, seq), lse_spec, kernel
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+# Both calls are jitted on their static arguments so that the layers of a
+# model share ONE trace and ONE Mosaic lowering of each kernel: tracing and
+# lowering a pallas_call costs ~0.25 s, on every start (it precedes the
+# compile-cache lookup), and BERT-base has 24 of them.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _short_fwd(qkv, ids, seed, heads, scale, dropout_p, interpret):
+    batch, seq, embed3 = qkv.shape
+    embed = embed3 // 3
+    rows, lse_shape, lse_spec, kernel = _short_statics(
+        qkv, heads, scale, dropout_p, _SHORT_FWD_BLOCKS, _short_fwd_kernel)
+    return pl.pallas_call(
+        kernel,
+        grid=(batch // rows,),
+        in_specs=[
+            _SMEM, _SMEM,
+            pl.BlockSpec((rows, seq, embed3), lambda i: (i, 0, 0)),
+        ],
+        out_specs=(
+            pl.BlockSpec((rows, seq, embed), lambda i: (i, 0, 0)),
+            lse_spec,
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((batch, seq, embed), qkv.dtype),
+            # the row log-sum-exp of head j * per_group + t, lane-dense
+            jax.ShapeDtypeStruct(lse_shape, jnp.float32),
+        ),
+        interpret=interpret,
+        compiler_params=_SHORT_PARAMS,
+        cost_estimate=pl.CostEstimate(
+            flops=4 * batch * seq * seq * embed,
+            bytes_accessed=batch * seq * 4 * embed * qkv.dtype.itemsize,
+            transcendentals=batch * heads * seq * seq),
+    )(seed, ids, qkv)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _short_bwd(qkv, ids, seed, lse, do, heads, scale, dropout_p, interpret):
+    batch, seq, embed3 = qkv.shape
+    embed = embed3 // 3
+    rows, _, lse_spec, kernel = _short_statics(
+        qkv, heads, scale, dropout_p, _SHORT_BWD_BLOCKS, _short_bwd_kernel)
+    return pl.pallas_call(
+        kernel,
+        grid=(batch // rows,),
+        in_specs=[
+            _SMEM, _SMEM,
+            pl.BlockSpec((rows, seq, embed3), lambda i: (i, 0, 0)),
+            pl.BlockSpec((rows, seq, embed), lambda i: (i, 0, 0)),
+            lse_spec,
+        ],
+        out_specs=pl.BlockSpec((rows, seq, embed3), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        interpret=interpret,
+        compiler_params=_SHORT_PARAMS,
+        cost_estimate=pl.CostEstimate(
+            flops=10 * batch * seq * seq * embed,
+            bytes_accessed=batch * seq * 7 * embed * qkv.dtype.itemsize,
+            transcendentals=batch * heads * seq * seq),
+    )(seed, ids, qkv, do, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _short(qkv, ids, seed, heads, scale, dropout_p, interpret):
+    return _short_fwd(qkv, ids, seed, heads, scale, dropout_p, interpret)[0]
+
+
+def _short_vjp_fwd(qkv, ids, seed, heads, scale, dropout_p, interpret):
+    o, lse = _short_fwd(qkv, ids, seed, heads, scale, dropout_p, interpret)
+    return o, (qkv, ids, seed, lse)
+
+
+def _short_vjp_bwd(heads, scale, dropout_p, interpret, res, do):
+    qkv = res[0]
+    return (_short_bwd(*res, do.astype(qkv.dtype), heads, scale, dropout_p,
+                       interpret), None, None)
+
+
+_short.defvjp(_short_vjp_fwd, _short_vjp_bwd)
+
+
+def mha_packed(qkv, num_heads, *, scale=None, dropout_p=0.0, seed=None,
+               row_ids=None, interpret=False):
+    """Whole-sequence self-attention on the packed projection output.
+    qkv: [batch, seq, 3 * embed] (q | k | v along the last axis, heads
+    contiguous inside each), for shapes ``short_supported`` admits; not
+    causal, no mask. Returns [batch, seq, embed], heads merged.
+
+    Dropout as in ``mha``: the mask of head h of batch row b is the hash
+    ``_keep_mask`` gives for batch-head index ``row_ids[b] * num_heads +
+    h``. ``row_ids`` (int32 [batch], default 0..batch-1) are the rows'
+    numbers in the whole batch: a caller that holds one shard of it passes
+    that shard's, and draws the mask the unsharded call would."""
+    batch, seq, embed3 = qkv.shape
+    embed = embed3 // 3
+    head_dim = embed // num_heads
+    if embed3 != 3 * num_heads * head_dim or not short_supported(
+            seq, num_heads, head_dim, qkv.dtype):
+        raise ValueError(
+            f"mha_packed does not take qkv {qkv.shape} with {num_heads} "
+            "heads (see short_supported)")
+    if scale is None:
+        scale = 1.0 / math.sqrt(head_dim)
+    if seed is None:
+        seed = jnp.zeros((), jnp.int32)
+    if row_ids is None:
+        row_ids = jnp.arange(batch, dtype=jnp.int32)
+    return _short(qkv, jnp.asarray(row_ids, jnp.int32),
+                  jnp.asarray(seed, jnp.int32).reshape(1, 1),
+                  int(num_heads), float(scale), float(dropout_p),
+                  bool(interpret))

@@ -234,3 +234,103 @@ def test_flash_kernel_shards_itself_over_the_traced_mesh():
     finally:
         paddle.set_flags({"pallas_interpret": False,
                           "pallas_attention_min_seq": 1024})
+
+
+def test_short_kernel_shards_its_batch_over_the_traced_mesh():
+    """The whole-sequence kernel goes through the same shard_map wrapper:
+    the packed batch over the data axes (an 'mp' axis computes it whole),
+    results — dropout masks too — equal to the unsharded run."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.core import random as random_core
+    from paddle_tpu.distributed import topology
+    from paddle_tpu.ops import attention
+
+    paddle.set_flags({"pallas_interpret": True})
+    try:
+        rng = np.random.RandomState(0)
+        qkv = jnp.asarray(rng.randn(4, 128, 3 * 128), jnp.float32)
+
+        def loss(qkv, p=0.0):
+            out = attention.packed_self_attention(
+                paddle.Tensor(qkv), 2, dropout_p=p, training=True)._value
+            return jnp.sum(jnp.sin(out)), out
+
+        grad = jax.grad(loss, has_aux=True)
+        g_ref, o_ref = jax.jit(grad)(qkv)
+        mesh = topology.build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+        sh = NamedSharding(mesh, P("dp"))
+
+        def on_mesh(qkv):
+            with topology.tracing_for(mesh):
+                return grad(qkv)
+
+        before = attention._ROUTE_TOTAL.value(route="short")
+        g, o = jax.jit(on_mesh, in_shardings=(sh,))(qkv)
+        assert attention._ROUTE_TOTAL.value(route="short") == before + 1
+        assert o.sharding.spec == P("dp")
+        np.testing.assert_allclose(o, o_ref, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(g, g_ref, rtol=1e-6, atol=1e-6)
+
+        def dropped(qkv):
+            with topology.tracing_for(mesh), \
+                    random_core.rng_guard(jax.random.PRNGKey(3)):
+                return loss(qkv, 0.5)[1]
+
+        # q = k = 0, v = 1: the output is the kept share of each row
+        ones = jnp.concatenate([jnp.zeros((4, 128, 256), jnp.float32),
+                                jnp.ones((4, 128, 128), jnp.float32)], -1)
+        od = np.asarray(jax.jit(dropped, in_shardings=(sh,))(ones))
+        assert not np.array_equal(od[:2], od[2:])
+        # the mask hashes the rows' numbers in the whole batch: the mesh
+        # does not change it
+        with random_core.rng_guard(jax.random.PRNGKey(3)):
+            whole = np.asarray(jax.jit(lambda x: loss(x, 0.5)[1])(ones))
+        assert np.array_equal(od, whole)
+    finally:
+        paddle.set_flags({"pallas_interpret": False})
+
+
+@pytest.mark.parametrize("builder", ["train_step", "fsdp", "localsgd"])
+def test_step_builders_announce_their_mesh(builder, monkeypatch):
+    """The gate picks the short kernel only where it knows the program's
+    devices, so every builder that owns a mesh says so (tracing_for). The
+    kernel then sees one shard's rows with every mesh axis manual around
+    it: under _on_mesh's shard_map, or LocalSGD's own."""
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.distributed import comm_opt, spmd, topology
+    from paddle_tpu.ops import attention
+    from paddle_tpu.ops.pallas import flash_attention
+
+    seen = []
+    real = flash_attention.mha_packed
+
+    def spy(qkv, *args, **kwargs):
+        seen.append((topology.traced_mesh(), qkv.shape[0], set(
+            jax.sharding.get_abstract_mesh().manual_axes)))
+        return real(qkv, *args, **kwargs)
+
+    monkeypatch.setattr(flash_attention, "mha_packed", spy)
+    paddle.set_flags({"pallas_interpret": True})
+    try:
+        paddle.seed(0)
+        mesh = topology.build_mesh(dp=4, devices=jax.devices()[:4])
+        blocks = [nn.TransformerEncoderLayer(128, 2, 128, dropout=0.1)
+                  for _ in range(2)]
+        model = nn.Sequential(*blocks)
+        opt = optimizer.SGD(0.01, parameters=model.parameters())
+        build = {"train_step": spmd.build_train_step,
+                 "fsdp": spmd.build_fsdp_train_step,
+                 "localsgd": comm_opt.build_localsgd_train_step}[builder]
+        step, init = build(model, lambda o, t: jnp.mean((o - t) ** 2), opt,
+                           mesh=mesh)
+        x = np.random.RandomState(0).randn(8, 128, 128).astype(np.float32)
+        before = attention._ROUTE_TOTAL.value(route="short")
+        out = step(*init(), x, x)
+        assert np.isfinite(float(out[0]))
+        assert attention._ROUTE_TOTAL.value(route="short") > before
+        assert seen and all(
+            m is mesh and rows == 2 and manual == set(mesh.axis_names)
+            for m, rows, manual in seen), seen
+    finally:
+        paddle.set_flags({"pallas_interpret": False})

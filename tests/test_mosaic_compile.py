@@ -1,0 +1,164 @@
+"""The whole-sequence attention kernel compiled by Mosaic for a DESCRIBED
+v5e (no chip attached: the on-chip-measurement guide's third rehearsal), at
+the BERT cells' shape and at the largest shapes the gate admits. It proves
+compilation and which route a program gets — never a time, never numerics
+(tests/test_pallas_kernels.py holds the numerics, chip_smoke.py phase 3 runs
+the kernel on the chip).
+
+The compiles run in ONE child process (this file as a script) whose
+environment describes the topology before libtpu loads: libtpu reads it
+once, and the test workers' environment stays as it was. Only a missing
+libtpu skips; a child that fails, fails the tests.
+"""
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+BATCH, SEQ, HEADS, HEAD_DIM = 256, 128, 12, 64   # a chip's rows of the cell
+MOSAIC = 'custom_call_target="tpu_custom_call"'
+
+#: what lets the sandbox's libtpu describe a v5e it is not attached to
+DESCRIBED_V5E = {"TPU_LOG_DIR": "disabled", "TPU_SKIP_MDS_QUERY": "1",
+                 "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+                 "TPU_WORKER_HOSTNAMES": "localhost", "TPU_WORKER_ID": "0",
+                 "JAX_PLATFORMS": "cpu", "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+                 "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+
+#: kernel alone on one described chip: (batch, seq, heads, head_dim, dtype,
+#: dropout). The last two sit at the VMEM clause of ``short_supported``:
+#: one more 128-lane group and the gate says xla
+KERNEL_CASES = {
+    "cell-shape-p0": (BATCH, SEQ, HEADS, HEAD_DIM, "bfloat16", 0.0),
+    "cell-shape-p0.1": (BATCH, SEQ, HEADS, HEAD_DIM, "bfloat16", 0.1),
+    "largest-f32-s512": (2, 512, 11, 128, "float32", 0.1),
+    "largest-bf16-s512": (2, 512, 22, 128, "bfloat16", 0.1),
+}
+GATE_CASES = ("announced-dp4", "plain-jit-over-mesh-arrays")
+
+
+def _child():
+    """Every compile, in the described environment; one JSON line out."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core import random as random_core
+    from paddle_tpu.distributed import topology
+    from paddle_tpu.ops import attention
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    # an executable for a described device cannot be read back
+    jax.config.update("jax_enable_compilation_cache", False)
+    v5e = list(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices)
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    out = {}
+
+    def packed(sharding, batch, seq, embed, dtype):
+        return jax.ShapeDtypeStruct((batch, seq, 3 * embed), dtype,
+                                    sharding=sharding)
+
+    for name, (batch, seq, heads, head_dim, dtype, p) in KERNEL_CASES.items():
+        def loss(qkv, seed):
+            o = fa.mha_packed(qkv, heads, dropout_p=p, seed=seed)
+            return jnp.sum(o.astype(jnp.float32))
+
+        admitted = fa.short_supported(seq, heads, head_dim, dtype)
+        wider = fa.short_supported(
+            seq, heads + 128 // head_dim, head_dim, dtype)
+        text = jax.jit(jax.grad(loss)).lower(
+            packed(one, batch, seq, heads * head_dim, dtype),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
+        ).compile().as_text()
+        out[name] = {"mosaic": text.count(MOSAIC), "admitted": admitted,
+                     "wider_admitted": wider,
+                     "scores_in_hbm": f"{batch},{heads},{seq},{seq}" in text}
+
+    # through the gate, on a process with several devices: the kernel where
+    # the step announced its mesh, XLA's route where a plain jit is handed
+    # arrays on a mesh (the dp4 cell's reference check does that)
+    attention._use_pallas = lambda: True
+    assert jax.device_count() > 1
+    mesh = topology.build_mesh(dp=4, devices=v5e)
+    sharded = NamedSharding(mesh, P("dp"))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    for name in GATE_CASES:
+        def step(qkv, key):
+            def loss(qkv):
+                o = attention.packed_self_attention(
+                    paddle.Tensor(qkv), HEADS, dropout_p=0.1)._value
+                return jnp.sum(o.astype(jnp.float32))
+
+            with random_core.rng_guard(key):
+                if name == "announced-dp4":
+                    with topology.tracing_for(mesh):
+                        return jax.grad(loss)(qkv)
+                return jax.grad(loss)(qkv)
+
+        before = {r: attention._ROUTE_TOTAL.value(route=r)
+                  for r in ("short", "xla")}
+        text = jax.jit(step, in_shardings=(sharded, None)).lower(
+            packed(sharded, 4 * BATCH, SEQ, HEADS * HEAD_DIM, jnp.bfloat16),
+            key).compile().as_text()
+        out[name] = {
+            "mosaic": text.count(MOSAIC),
+            "routes": {r: attention._ROUTE_TOTAL.value(route=r) - n
+                       for r, n in before.items()},
+            "a_shards_rows":
+                f"bf16[{BATCH},{SEQ},{3 * HEADS * HEAD_DIM}]" in text}
+    print(json.dumps(out))
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no libtpu installed: no TPU compiler to describe a v5e")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, **DESCRIBED_V5E, "PYTHONPATH": repo}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    done = subprocess.run([sys.executable, os.path.abspath(__file__)],
+                          env=env, capture_output=True, text=True,
+                          timeout=900)
+    assert done.returncode == 0, done.stderr[-4000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_short_kernel_compiles(compiled, case):
+    """Forward + ONE backward Mosaic call within the VMEM the kernel asks
+    for, nothing of the scores' shape in HBM; the largest cases are the
+    last the gate admits at their sequence length."""
+    got = compiled[case]
+    assert got["mosaic"] == 2 and not got["scores_in_hbm"]
+    assert got["admitted"]
+    assert got["wider_admitted"] == case.startswith("cell-shape")
+
+
+def test_announced_mesh_keeps_the_kernel_under_shard_map(compiled):
+    """Inside a step traced for an announced mesh the kernel shards itself
+    (batch over dp) and stays a Mosaic call, a shard's rows a call."""
+    got = compiled["announced-dp4"]
+    assert got["routes"] == {"short": 1.0, "xla": 0.0}
+    assert got["mosaic"] == 2 and got["a_shards_rows"]
+
+
+def test_plain_jit_over_mesh_arrays_takes_the_xla_route(compiled):
+    """GSPMD cannot partition a Mosaic call, and only the lowering would
+    see that a plain jit's arrays live on a mesh: with several devices and
+    no announced mesh the gate says xla, counts xla, and XLA partitions
+    its own route (a shard's 64 rows a device)."""
+    got = compiled["plain-jit-over-mesh-arrays"]
+    assert got["routes"] == {"short": 0.0, "xla": 1.0}
+    assert got["mosaic"] == 0
+
+
+if __name__ == "__main__":
+    _child()

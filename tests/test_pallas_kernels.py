@@ -264,3 +264,200 @@ def test_flash_head_dim_128(causal):
     for a, b in zip(g_fa, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-4, atol=3e-4)
+
+
+# ------------------------------------------- whole-sequence ("short") kernel
+
+from paddle_tpu.ops import attention  # noqa: E402
+
+mha_packed = functools.partial(fa.mha_packed, interpret=True)
+
+
+def _split_packed(qkv, heads):
+    b, s, e3 = qkv.shape
+    d = e3 // 3 // heads
+    return [x.reshape(b, s, heads, d).transpose(0, 2, 1, 3)
+            for x in jnp.split(qkv, 3, axis=-1)]
+
+
+def _merge_heads(o):
+    b, h, s, d = o.shape
+    return o.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _packed_sdpa_ref(qkv, heads):
+    """split heads -> ops/attention.py::_sdpa_ref -> merge heads."""
+    q, k, v = _split_packed(qkv, heads)
+    return _merge_heads(attention._sdpa_ref(
+        q, k, v, None, None, scale=1.0 / np.sqrt(q.shape[-1]),
+        dropout_p=0.0, is_causal=False))
+
+
+def _packed_ref_dropout(qkv, heads, seed, dropout_p):
+    """The reference with the mask the kernel used: head h of row b hashes
+    batch-head index b * heads + h, rows = queries, cols = keys."""
+    q, k, v = _split_packed(qkv, heads)
+    b, h, s, d = q.shape
+    p = jax.nn.softmax(jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d), -1)
+    keep = _hash_keep_np(
+        seed, np.arange(b * h).reshape(b, h, 1, 1),
+        np.arange(s).reshape(1, 1, s, 1), np.arange(s).reshape(1, 1, 1, s),
+        s, s, dropout_p)
+    p = jnp.where(jnp.asarray(keep), p / (1.0 - dropout_p), 0.0)
+    return _merge_heads(jnp.einsum("bhqk,bhkd->bhqd", p, v))
+
+
+def _packed_input(seed, batch, seq, heads, head_dim, dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+    return jnp.asarray(rng.randn(batch, seq, 3 * heads * head_dim) * 0.5,
+                       dtype)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-4),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("seq", [128, 256, 384])
+def test_short_matches_sdpa_ref(seq, head_dim, dtype, tol):
+    """Forward and dq/dk/dv (one packed gradient) against _sdpa_ref
+    (test_short_rows_per_program has the row counts that are no multiple
+    of the preferred block)."""
+    heads = 256 // head_dim
+    qkv = _packed_input(seq + head_dim, 3, seq, heads, head_dim, dtype)
+    wide = qkv.astype(jnp.float32)
+    out = mha_packed(qkv, heads)
+    assert out.dtype == dtype and out.shape == (3, seq, 256)
+    ref = _packed_sdpa_ref(wide, heads)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=tol, atol=tol)
+
+    def loss(fn):
+        return lambda x: jnp.sum(jnp.sin(fn(x).astype(jnp.float32)))
+
+    got = jax.grad(loss(lambda x: mha_packed(x, heads)))(qkv)
+    want = jax.grad(loss(lambda x: _packed_sdpa_ref(x, heads)))(wide)
+    assert got.dtype == dtype
+    for g, w, name in zip(jnp.split(got.astype(jnp.float32), 3, -1),
+                          jnp.split(want, 3, -1), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=tol,
+                                   atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("batch,fwd_rows,bwd_rows",
+                         [(6, 6, 6), (26, 13, 13), (28, 14, 7), (29, 1, 1)])
+def test_short_rows_per_program(batch, fwd_rows, bwd_rows):
+    """Rows a program: the largest divisor of the batch whose blocks fit
+    the budget (23 rows forward, 13 backward at this shape) — down to 1
+    for a prime batch; the result does not depend on it."""
+    heads, seq = 2, 128
+    row = functools.partial(fa._short_row_bytes, seq, heads, 64, 4)
+    assert fa._short_rows(batch, row(fa._SHORT_FWD_BLOCKS)) == fwd_rows
+    assert fa._short_rows(batch, row(fa._SHORT_BWD_BLOCKS)) == bwd_rows
+    qkv = _packed_input(1, batch, seq, heads, 64)
+
+    def loss(fn):
+        return lambda x: jnp.sum(jnp.sin(fn(x)))
+
+    np.testing.assert_allclose(
+        np.asarray(mha_packed(qkv, heads)),
+        np.asarray(_packed_sdpa_ref(qkv, heads)), rtol=3e-4, atol=3e-4)
+    np.testing.assert_allclose(
+        np.asarray(jax.grad(loss(lambda x: mha_packed(x, heads)))(qkv)),
+        np.asarray(jax.grad(loss(lambda x: _packed_sdpa_ref(x, heads)))(qkv)),
+        rtol=3e-4, atol=3e-4)
+
+
+@pytest.mark.parametrize("shape,heads", [((2, 100, 384), 2),   # seq % 128
+                                         ((2, 640, 384), 2),   # seq > 512
+                                         ((2, 128, 3 * 96), 2),  # head 48
+                                         ((2, 128, 3 * 192), 3),  # 3 x 64
+                                         ((1, 512, 3 * 2048), 32)])  # VMEM
+def test_short_refuses_other_shapes(shape, heads):
+    e = shape[-1] // 3
+    assert not fa.short_supported(shape[1], heads, e // heads, jnp.float32)
+    with pytest.raises(ValueError, match="short_supported"):
+        mha_packed(jnp.zeros(shape, jnp.float32), heads)
+
+
+def test_short_dropout_matches_hash_reference():
+    """Output and gradients equal a reference that applies the mask the
+    kernel used — so forward and backward regenerate the same mask."""
+    heads, p_drop, seed = 2, 0.2, 4321
+    qkv = _packed_input(8, 3, 128, heads, 64)
+    out = mha_packed(qkv, heads, dropout_p=p_drop, seed=jnp.int32(seed))
+    ref = _packed_ref_dropout(qkv, heads, seed, p_drop)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=3e-4, atol=3e-4)
+
+    def loss(fn):
+        return lambda x: jnp.sum(jnp.sin(fn(x)))
+
+    got = jax.grad(loss(lambda x: mha_packed(
+        x, heads, dropout_p=p_drop, seed=jnp.int32(seed))))(qkv)
+    want = jax.grad(loss(lambda x: _packed_ref_dropout(
+        x, heads, seed, p_drop)))(qkv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+
+
+def _short_keep_bits(seed, p_drop, batch=2, seq=128, heads=2, head_dim=64):
+    """q = k = 0 makes every row uniform and v = e_j picks column j: the
+    output IS the mask (1 / (seq * keep) where kept), read one key at a
+    time — here for key 0 of every (row, head, query)."""
+    e = heads * head_dim
+    v = jnp.zeros((batch, seq, e), jnp.float32).at[:, 0, :].set(1.0)
+    qkv = jnp.concatenate([jnp.zeros((batch, seq, 2 * e), jnp.float32), v],
+                          axis=-1)
+    out = mha_packed(qkv, heads, dropout_p=p_drop, seed=seed)
+    return np.asarray(out[:, :, ::head_dim]) > 0     # [batch, seq, heads]
+
+
+def test_short_dropout_statistics_and_determinism():
+    """Keep fraction within binomial bounds; same seed -> same output;
+    another seed -> another mask."""
+    heads, p_drop = 2, 0.1
+    # v = 1, q = k = 0: out[b, s, :] of head h = kept share of the row
+    e = heads * 64
+    qkv = jnp.concatenate([jnp.zeros((4, 128, 2 * e), jnp.float32),
+                           jnp.ones((4, 128, e), jnp.float32)], axis=-1)
+    out = mha_packed(qkv, heads, dropout_p=p_drop, seed=jnp.int32(7))
+    kept = np.asarray(out[:, :, ::64], np.float64) * (1 - p_drop)
+    n = 4 * heads * 128 * 128
+    sigma = np.sqrt(p_drop * (1 - p_drop) / n)
+    assert abs(kept.mean() - (1 - p_drop)) < 4 * sigma, kept.mean()
+    assert kept.min() > 0.6 and kept.max() <= 1.0 + 1e-6  # per row too
+
+    x = _packed_input(9, 2, 128, heads, 64)
+    a = mha_packed(x, heads, dropout_p=p_drop, seed=jnp.int32(11))
+    b = mha_packed(x, heads, dropout_p=p_drop, seed=jnp.int32(11))
+    c = mha_packed(x, heads, dropout_p=p_drop, seed=jnp.int32(12))
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert not np.allclose(np.asarray(a), np.asarray(c))
+
+
+@pytest.mark.parametrize("word", [0, 1], ids=["first-word", "last-word"])
+def test_kernel_seed_folds_every_key_word(word):
+    """Two keys that differ in one word only — the first as much as the
+    last — give different kernel seeds and different masks (ADVICE: the
+    seed used to be the key's last word alone)."""
+    words = np.array([[123, 456], [123, 456]], np.uint32)
+    words[1, word] ^= 0x10
+    k0, k1 = (jax.random.wrap_key_data(jnp.asarray(w)) for w in words)
+    s0, s1 = attention._kernel_seed(k0), attention._kernel_seed(k1)
+    assert s0.dtype == jnp.int32 and int(s0) != int(s1)
+    m0, m1 = _short_keep_bits(s0, 0.5), _short_keep_bits(s1, 0.5)
+    assert 0.35 < m0.mean() < 0.65
+    assert (m0 != m1).mean() > 0.3
+
+
+def test_short_mask_hashes_the_rows_own_numbers():
+    """A caller that holds one shard of the batch passes that shard's row
+    numbers and draws the mask the unsharded call would: the result does
+    not depend on how the batch was split."""
+    qkv = _packed_input(12, 4, 128, 2, 64)
+    whole = mha_packed(qkv, 2, dropout_p=0.3, seed=jnp.int32(5))
+    shard = mha_packed(qkv[2:], 2, dropout_p=0.3, seed=jnp.int32(5),
+                       row_ids=jnp.arange(2, 4))
+    assert np.array_equal(np.asarray(whole[2:]), np.asarray(shard))
+    again = mha_packed(qkv[2:], 2, dropout_p=0.3, seed=jnp.int32(5))
+    assert not np.allclose(np.asarray(whole[2:]), np.asarray(again))
