@@ -11,6 +11,9 @@ AMP_WHITE_LIST = {
     "matmul", "bmm", "mm", "linear", "conv1d", "conv2d", "conv3d",
     "conv2d_transpose", "conv1d_transpose", "einsum", "fused_lstm", "fused_gru",
     "fused_rnn", "sdpa", "flash_attention", "short_attention", "addmm",
+    # the expert gemms of the dropless MoE path, and the fused LM head +
+    # loss (its logits and softmax are float32 inside)
+    "moe_experts_sorted", "linear_cross_entropy",
 }
 
 # numerically-sensitive ops — force fp32.

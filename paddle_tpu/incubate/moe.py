@@ -1,55 +1,278 @@
-"""Mixture-of-Experts with expert parallelism over the 'ep' mesh axis.
+"""Mixture-of-Experts: a top-k routed expert FFN block, on one device or
+with the experts sharded over the 'ep' mesh axis.
 
 The reference (~v2.1) predates its MoE work, so this is green-field
-TPU-native design (like ring attention). Expert FFN weights are stacked
-[E, ...] and SHARDED over 'ep'. Two dispatch modes behind one API:
+TPU-native design (like ring attention). Expert weights are stacked
+[E, ...] (``w_up``, ``w_down``, and ``w_gate`` for SwiGLU experts) and
+carry ``mp_spec = P('ep')``. One API, four paths; ``dispatch_mode='auto'``
+(the default) picks from what the layer can observe — the number of
+experts and whether the program's mesh shards them:
 
-- ``dense``: every expert's FFN runs for every token and the top-k gate
-  mask zeroes the rest; the expert-dim contraction compiles to a psum
-  over the ep axis. No capacity overflow, static shapes, but E/k wasted
-  FLOPs — right only for small expert counts.
-- ``capacity`` (GShard/Switch): each expert processes at most
-  C = ceil(capacity_factor * k * N / E) tokens; tokens claim capacity
-  slots in order (per-expert cumsum) and overflow tokens DROP that
-  expert's contribution, exactly the GShard top-2 formulation. Dispatch
-  and combine are one-hot einsums — static shapes end to end — so the
-  FFN compute is E*C = k*capacity_factor*N token-slots instead of
-  E*N: the compute-sparse path. The [E, C, H] expert buffers inherit
-  the 'ep' sharding from the weights, so XLA materialises the
-  token->expert shuffle as collectives over ep (the all_to_all of the
-  GShard paper) while the FFN einsums stay local per expert shard.
+- ``sorted`` (dropless; what ``auto`` resolves to for 8 experts or more
+  where no 'ep' axis shards them — one device, or a dp / mp mesh): the
+  (token, choice) pairs are sorted by expert, the rows gathered into that
+  order, the experts run as grouped matrix multiplications over the ragged
+  groups (each row meets its own expert's weights and no other: the Pallas
+  megablox kernel on a one-device TPU program, ``jax.lax.ragged_dot``
+  elsewhere — ``_grouped_matmul``), and the results are weighted,
+  un-sorted by the inverse permutation and summed over the k choices. No [N, E, C] tensor, no
+  capacity, no dropped token; exact under any imbalance (an expert with no
+  token, an expert with every token). Both permutations are gathers with
+  gathers for gradients. It cannot be asked for by name: it is not an
+  option, it is what the layer does where nothing is sharded.
+- ``dense`` (``auto`` below 8 experts): every expert's FFN runs for every
+  token and the top-k gate mask zeroes the rest; the expert-dim contraction
+  compiles to a psum over the ep axis. Never drops, static shapes, E/k
+  wasted FLOPs.
+- ``capacity`` (GShard/Switch; ``auto`` for 8 experts or more under a mesh
+  whose 'ep' axis is larger than 1): each expert processes at most
+  C = ceil(capacity_factor * k * N / E) tokens; tokens claim capacity slots
+  in order (per-expert cumsum) and overflow tokens DROP that expert's
+  contribution, exactly the GShard top-2 formulation. Dispatch and combine
+  are one-hot [N, E, C] einsums — static shapes, but 10.7 GB in f32 at
+  N = 16,384, E = 64, C = 2,560: sizes for tests and small expert counts.
+  The [E, C, H] expert buffers inherit the 'ep' sharding from the weights,
+  so XLA materialises the token->expert shuffle as collectives over ep.
+- ``alltoall`` (explicit only): the literal GShard layout under
+  ``jax.shard_map`` — tokens batch-sharded over the data axes x ep, each
+  shard routes its LOCAL tokens into [E, C, H] capacity buffers,
+  ``lax.all_to_all`` swaps the expert dim across shards, the FFN runs on
+  local expert weights only, and a second all_to_all routes results back.
+  It requires a live global mesh with ep > 1, batch divisible by ep, and E
+  divisible by ep.
 
-- ``alltoall``: the literal GShard layout under ``jax.shard_map`` —
-  tokens batch-sharded over the data axes x ep (GShard's groups), each
-  shard routes its LOCAL tokens into [E, C, H] capacity buffers, ``lax.all_to_all`` swaps the
-  expert dim across shards (each shard then holds its own E/ep experts'
-  tokens from every shard), the FFN runs on local expert weights only,
-  and a second all_to_all routes results back. Guaranteed all-to-all on
-  ICI + per-shard compute exactly E*C/ep token-slots, independent of
-  the XLA partitioner's einsum strategy.
-
-``dispatch_mode='auto'`` (default) picks capacity for E >= 8, dense
-below — at tiny E dense dispatch wastes little and never drops.
-'alltoall' is explicit: it requires a live global mesh with ep > 1,
-batch divisible by ep, and E divisible by ep.
+The layer's arithmetic is the constructor's: ``activation`` ('gelu': two
+matrices; 'swiglu': ``w_down(silu(x w_gate) * (x w_up))``), ``gate_bias``,
+``norm_topk_prob`` (renormalise the k weights or keep the softmax's), and
+two auxiliary losses through ``nn.aux_loss.emit_aux_loss``: load balancing
+times ``aux_weight`` and the router z-loss ``mean_t logsumexp(r_t)^2`` times
+``z_loss_weight``. The sorted path's load-balancing term is the
+Switch / HF form over all k choices, ``E * sum_e (n_e / N) * mean_t
+p[t, e]`` (n_e = assignments to e); dense divides it by k; capacity and
+alltoall use GShard's top-1 fraction. Which path a call took is counted in
+``paddle_tpu_moe_dispatch_total{path}`` (trace time: one a layer call).
 """
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+import functools
+
 from .. import nn
 from ..core.dispatch import apply_op
+from ..obs import metrics as obs_metrics
 from jax import shard_map
 
+_DISPATCH_TOTAL = obs_metrics.counter(
+    "paddle_tpu_moe_dispatch_total",
+    "expert-layer calls by the path taken (sorted | capacity | dense | "
+    "alltoall); under jit one count per traced layer call",
+    labelnames=("path",))
 
-def _capacity_combine(xf, probs, top_k, cap):
+#: ``auto`` runs every expert on every token below this many experts
+_DENSE_BELOW = 8
+
+
+def _expert_ffn(buf, w_gate, w_up, w_down, eq_up, eq_down):
+    """The experts' FFN as batched einsums over stacked weights: gelu on
+    two matrices, or SwiGLU where the layer holds ``w_gate``."""
+    h = jnp.einsum(eq_up, buf, w_up)
+    if w_gate is None:
+        h = jax.nn.gelu(h)
+    else:
+        h = jax.nn.silu(jnp.einsum(eq_up, buf, w_gate)) * h
+    return jnp.einsum(eq_down, h, w_down)
+
+
+def _z_loss(logits):
+    """Router z-loss (ST-MoE): mean over tokens of logsumexp(logits)^2."""
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    return jnp.mean(jnp.square(lse))
+
+
+# ---------------------------------------------------------------- sorted
+# The dropless path's two permutations. Both are bijections on the N*k
+# (token, choice) pairs, so each is a row gather whose gradient is the row
+# gather by the inverse permutation: no scatter in either direction.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_to_expert_order(x, order, inv, k):
+    """x [N, H] -> [N*k, H]: row i is the token of sorted pair i."""
+    return x[order // k]
+
+
+def _rows_to_expert_order_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _rows_to_expert_order_bwd(k, inv, g):
+    by_token = g[inv].reshape(inv.shape[0] // k, k, g.shape[-1])
+    return (jnp.sum(by_token.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_rows_to_expert_order.defvjp(_rows_to_expert_order_fwd,
+                             _rows_to_expert_order_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_token_order(ys, order, inv):
+    """ys [N*k, H] in expert order -> token-major order (pair t*k + j)."""
+    return ys[inv]
+
+
+def _rows_to_token_order_fwd(ys, order, inv):
+    return ys[inv], order
+
+
+def _rows_to_token_order_bwd(order, g):
+    return g[order], None, None
+
+
+_rows_to_token_order.defvjp(_rows_to_token_order_fwd,
+                            _rows_to_token_order_bwd)
+
+
+def _route(x, w_router, b_router, *, top_k, renorm):
+    """The router in float32: [B, S, H] -> the k weights [N, k] and expert
+    ids [N, k] of every token, the load-balancing term (Switch / HF form
+    over all k choices) and the z-loss. The weights are the softmax's own
+    unless ``renorm``."""
+    with jax.named_scope("moe.route"):
+        xf = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+        # float32 in earnest: a TPU's default precision would multiply in
+        # bf16, and a router logit off by 2e-3 changes which experts a
+        # token takes (64 columns: the six passes cost nothing)
+        logits = jnp.dot(xf, w_router.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        if b_router is not None:
+            logits = logits + b_router.astype(jnp.float32)
+        n, e = logits.shape
+        probs = jax.nn.softmax(logits, axis=-1)
+        topv, topi = jax.lax.top_k(probs, top_k)
+        if renorm:
+            topv = topv / jnp.maximum(
+                jnp.sum(topv, axis=-1, keepdims=True), 1e-9)
+        assigned = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32),
+                           axis=(0, 1))                 # n_e
+        balance = e * jnp.sum(assigned / n * jnp.mean(probs, axis=0))
+        return topv, topi.astype(jnp.int32), balance, _z_loss(logits)
+
+
+#: megablox tilings (rows, contraction, output columns) by operand size;
+#: measured on the v5e at the OLMoE cell's shapes (PERF.md section 6, PR
+#: 25): bf16 (512, 1024, 1024) runs the three gemms of a layer, forward and
+#: backward, in 0.69 of ragged_dot's time; 1024 rows or 2048 columns a tile
+#: run out of VMEM, and float32 tiles take twice the bytes
+_GMM_TILING = {2: (512, 1024, 1024), 4: (256, 512, 512)}
+
+
+def _gmm_tiling(rows, itemsize):
+    """The kernel's tiling for this many assigned rows, or None where it
+    cannot take them: its row tile has to divide the rows."""
+    want = _GMM_TILING.get(itemsize)
+    if want is None:
+        return None
+    tm = next((t for t in (want[0], 256, 128, 64, 32, 16, 8)
+               if t <= want[0] and rows % t == 0), None)
+    return None if tm is None else (tm,) + want[1:]
+
+
+def _one_device_program():
+    """Whether the program being traced runs on one device: GSPMD cannot
+    partition a Mosaic call, and the expert layer has no shard_map of its
+    own (a step builder's announced mesh, else the process's devices)."""
+    from ..distributed import topology
+
+    mesh = topology.traced_mesh()
+    return mesh.size == 1 if mesh is not None else jax.device_count() == 1
+
+
+def _expert_kernel():
+    """Which grouped matmul the sorted path runs: ``mosaic`` (the Pallas
+    megablox kernel) where kernels are selected and the program runs on
+    one device, ``interpret`` under the ``pallas_interpret`` flag, else
+    ``xla`` (``jax.lax.ragged_dot``, which XLA partitions and the CPU
+    lowers). Decided where the op is dispatched, so it rides the op's
+    static arguments and a flag flip retraces."""
+    from ..core import flags
+    from ..ops.attention import _use_pallas
+
+    if not _use_pallas():
+        return "xla"
+    if flags.flag_value("pallas_interpret"):
+        return "interpret"
+    return "mosaic" if _one_device_program() else "xla"
+
+
+def _grouped_matmul(rows, w, group_sizes, kernel):
+    """rows [M, K] sorted by group, w [G, K, N] -> [M, N]: each row times
+    its own group's matrix; exact under any group sizes. ``kernel`` as
+    ``_expert_kernel`` says; the megablox kernel's row tile has to divide
+    M, else ragged_dot serves. Measured on the chip (PERF.md section 6, PR
+    25): the kernel is the faster, and XLA's TPU expansion of ragged_dot
+    loses the operation's scope in a trace."""
+    tiling = _gmm_tiling(rows.shape[0], rows.dtype.itemsize)
+    if kernel != "xla" and tiling is not None and rows.dtype == w.dtype:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(rows, w, group_sizes, rows.dtype, tiling,
+                   interpret=kernel == "interpret")
+    return jax.lax.ragged_dot(rows, w, group_sizes,
+                              preferred_element_type=rows.dtype)
+
+
+def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel="xla"):
+    """Dispatch and experts of the dropless path: [B, S, H] and the expert
+    ids [N, k] -> every (token, choice) pair's expert output [N*k, H] in
+    expert order, with the permutation and its inverse."""
+    n, k = topi.shape
+    e = w_up.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        flat = topi.reshape(-1)
+        order = jnp.argsort(flat).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+        group_sizes = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32),
+                              axis=0)
+        xs = _rows_to_expert_order(x.reshape(n, x.shape[-1]), order, inv, k)
+    with jax.named_scope("moe.experts"):
+        def grouped(rows, w):
+            return _grouped_matmul(rows, w, group_sizes, kernel)
+
+        up = grouped(xs, w_up)
+        if w_gate is None:
+            mid = jax.nn.gelu(up)
+        else:
+            mid = (jax.nn.silu(grouped(xs, w_gate).astype(jnp.float32))
+                   * up.astype(jnp.float32)).astype(xs.dtype)
+        ys = grouped(mid, w_down)
+    return ys, order, inv
+
+
+def _combine(ys, topv, order, inv, *, shape):
+    """Weight each pair's output, un-sort, sum a token's k choices (f32)."""
+    with jax.named_scope("moe.combine"):
+        n, k = topv.shape
+        by_token = _rows_to_token_order(ys, order, inv).reshape(n, k, -1)
+        out = jnp.einsum("nkh,nk->nh", by_token.astype(jnp.float32),
+                         topv.astype(jnp.float32))
+        return out.astype(jnp.promote_types(ys.dtype, topv.dtype)
+                          ).reshape(shape)
+
+
+
+def _capacity_combine(xf, probs, top_k, cap, renorm=True):
     """GShard combine/dispatch build for one token group (fig. 6 of the
     paper): tokens claim per-expert capacity slots in order, overflow
     drops. Returns (combine [N,E,C] f32, dispatch [N,E,C], top1 idx)."""
     n, e = probs.shape
     topv, topi = jax.lax.top_k(probs, top_k)           # [N, k]
-    gates = topv / jnp.maximum(jnp.sum(topv, axis=-1, keepdims=True), 1e-9)
+    gates = topv
+    if renorm:
+        gates = topv / jnp.maximum(
+            jnp.sum(topv, axis=-1, keepdims=True), 1e-9)
     combine = jnp.zeros((n, e, cap), jnp.float32)
     counts = jnp.zeros((e,), jnp.float32)              # slots claimed
     for j in range(top_k):
@@ -79,52 +302,89 @@ def _gshard_aux(probs, topi):
 class MoELayer(nn.Layer):
     """Top-k gated expert FFN block (pre-norm residual not included).
 
-    forward: [B, S, H] -> [B, S, H]. Gate scores are softmaxed over the
-    selected top_k experts (renormalized, Switch/GShard style); the
-    auxiliary load-balancing loss (GShard aux) is routed through
-    ``nn.aux_loss.emit_aux_loss``: in eager mode it lands on
+    forward: [B, S, H] -> [B, S, H]. The router is a softmax over all
+    experts; the k selected weights are renormalised to sum to one
+    (Switch/GShard style) unless ``norm_topk_prob=False`` keeps the
+    softmax's own values (OLMoE). ``activation``: 'gelu' (two matrices)
+    or 'swiglu' (three). The auxiliary losses (load balancing times
+    ``aux_weight``, router z-loss times ``z_loss_weight``) are routed
+    through ``nn.aux_loss.emit_aux_loss``: in eager mode they land on
     ``self.aux_loss`` (add it to the objective yourself); inside
-    ``spmd.build_train_step`` / ``comm_opt`` train steps it is collected
+    ``spmd.build_train_step`` / ``comm_opt`` train steps they are collected
     into the compiled loss automatically; in inference traces
-    (jit.save / onnx.export / generation) it is dropped so no tracer
+    (jit.save / onnx.export / generation) they are dropped so no tracer
     escapes onto the layer. Pipeline/FSDP per-stage applies currently
-    drop it too — add the aux term explicitly there if it matters.
+    drop them too — add the aux term explicitly there if it matters.
     """
 
     def __init__(self, hidden_size, ffn_hidden, num_experts, top_k=2,
                  shard_axis="ep", aux_weight=0.01, dispatch_mode="auto",
-                 capacity_factor=1.25):
+                 capacity_factor=1.25, activation="gelu", gate_bias=True,
+                 norm_topk_prob=True, z_loss_weight=0.0, weight_attr=None):
         super().__init__()
         self.num_experts = int(num_experts)
         self.top_k = int(top_k)
         self.aux_weight = float(aux_weight)
-        if dispatch_mode == "auto":
-            dispatch_mode = "capacity" if self.num_experts >= 8 else "dense"
-        if dispatch_mode not in ("dense", "capacity", "alltoall"):
+        self.z_loss_weight = float(z_loss_weight)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        if dispatch_mode not in ("auto", "dense", "capacity", "alltoall"):
             raise ValueError(f"dispatch_mode must be 'auto'/'dense'/"
                              f"'capacity'/'alltoall', got {dispatch_mode!r}")
+        if activation not in ("gelu", "swiglu"):
+            raise ValueError(f"activation must be 'gelu' or 'swiglu', got "
+                             f"{activation!r}")
         self.shard_axis = shard_axis
         self.dispatch_mode = dispatch_mode
         self.capacity_factor = float(capacity_factor)
-        self.gate = nn.Linear(hidden_size, num_experts)
+        self.gate = nn.Linear(hidden_size, num_experts,
+                              weight_attr=weight_attr,
+                              bias_attr=None if gate_bias else False)
         k = 1.0 / np.sqrt(hidden_size)
-        self.w_up = self.create_parameter(
-            [num_experts, hidden_size, ffn_hidden],
-            default_initializer=nn.initializer.Uniform(-k, k))
         k2 = 1.0 / np.sqrt(ffn_hidden)
-        self.w_down = self.create_parameter(
-            [num_experts, ffn_hidden, hidden_size],
-            default_initializer=nn.initializer.Uniform(-k2, k2))
-        # experts live sharded over 'ep' (spmd.build_train_step honors
-        # mp_spec); the contraction over the expert dim emits the psum
-        self.w_up.mp_spec = P(shard_axis)
-        self.w_down.mp_spec = P(shard_axis)
+
+        def stacked(shape, bound):
+            w = self.create_parameter(
+                shape, attr=weight_attr,
+                default_initializer=nn.initializer.Uniform(-bound, bound))
+            # experts live sharded over 'ep' (spmd.build_train_step honors
+            # mp_spec); the contraction over the expert dim emits the psum
+            w.mp_spec = P(shard_axis)
+            return w
+
+        self.w_gate = (stacked([num_experts, hidden_size, ffn_hidden], k)
+                       if activation == "swiglu" else None)
+        self.w_up = stacked([num_experts, hidden_size, ffn_hidden], k)
+        self.w_down = stacked([num_experts, ffn_hidden, hidden_size], k2)
         self.aux_loss = None
 
+    def resolved_mode(self):
+        """The path a call takes now: the mode asked for, or for ``auto``
+        what the layer can observe — below 8 experts ``dense``; from 8 on
+        ``capacity`` where the program's mesh (the one a step builder
+        announced, else the global one) shards the experts over an axis
+        larger than 1, and the dropless ``sorted`` path where none does."""
+        if self.dispatch_mode != "auto":
+            return self.dispatch_mode
+        if self.num_experts < _DENSE_BELOW:
+            return "dense"
+        from ..distributed import topology
+
+        mesh = topology.traced_mesh() or topology.get_global_mesh()
+        sharded = mesh.shape.get(self.shard_axis, 1) > 1
+        return "capacity" if sharded else "sorted"
+
     def forward(self, x):
+        from ..nn.aux_loss import emit_aux_loss
+
+        mode = self.resolved_mode()
+        _DISPATCH_TOTAL.inc(path=mode)
+        if mode == "sorted":
+            out, aux = self._forward_sorted(x)
+            emit_aux_loss(self, aux)
+            return out
         logits = self.gate(x)  # [B, S, E]
 
-        def _moe(x, logits, w_up, w_down, *, top_k):
+        def _moe(x, logits, w_gate, w_up, w_down, *, top_k, renorm):
             e = logits.shape[-1]
             probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
             # exact top-k mask from indices (a >=threshold compare would
@@ -135,14 +395,14 @@ class MoELayer(nn.Layer):
                            axis=-2)
             mask = jnp.minimum(mask, 1.0)
             gates = probs * mask
-            gates = gates / jnp.maximum(
-                jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+            if renorm:
+                gates = gates / jnp.maximum(
+                    jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
             # dense dispatch: every expert on every token, gated sum.
             # w_up/w_down sharded on e -> per-shard partial experts; the
             # final contraction over e all-reduces over 'ep'.
-            h = jnp.einsum("bsh,ehf->besf", x, w_up)
-            h = jax.nn.gelu(h)
-            y = jnp.einsum("besf,efh->besh", h, w_down)
+            y = _expert_ffn(x, w_gate, w_up, w_down,
+                            "bsh,ehf->besf", "besf,efh->besh")
             out = jnp.einsum("bse,besh->bsh", gates.astype(y.dtype), y)
             # GShard aux loss: E * sum_e (frac tokens routed to e *
             # mean gate prob of e)
@@ -151,7 +411,8 @@ class MoELayer(nn.Layer):
             aux = e * jnp.sum(frac / top_k * imp)
             return out, aux.astype(x.dtype)
 
-        def _moe_capacity(x, logits, w_up, w_down, *, top_k, cap_factor):
+        def _moe_capacity(x, logits, w_gate, w_up, w_down, *, top_k,
+                          cap_factor, renorm):
             """GShard top-k capacity dispatch (Lepikhin et al. 2020,
             algorithm in fig. 6): one-hot dispatch/combine einsums with
             per-expert capacity C and drop-overflow. Static shapes; the
@@ -165,28 +426,49 @@ class MoELayer(nn.Layer):
             probs = jax.nn.softmax(
                 logits.astype(jnp.float32), axis=-1).reshape(n, e)
             combine, dispatch, topi = _capacity_combine(xf, probs, top_k,
-                                                        cap)
+                                                        cap, renorm)
             buf = jnp.einsum("nec,nh->ech", dispatch, xf)
-            h = jax.nn.gelu(jnp.einsum("ech,ehf->ecf", buf, w_up))
-            y = jnp.einsum("ecf,efh->ech", h, w_down)
+            y = _expert_ffn(buf, w_gate, w_up, w_down,
+                            "ech,ehf->ecf", "ecf,efh->ech")
             out = jnp.einsum("nec,ech->nh", combine.astype(y.dtype), y)
             aux = _gshard_aux(probs, topi)
             return out.reshape(b, s, hdim), aux.astype(x.dtype)
 
-        if self.dispatch_mode == "alltoall":
+        if mode == "alltoall":
             out, aux = self._forward_alltoall(x, logits)
-        elif self.dispatch_mode == "capacity":
+        elif mode == "capacity":
             out, aux = apply_op("moe_ffn_capacity", _moe_capacity, x,
-                                logits, self.w_up, self.w_down,
+                                logits, self.w_gate, self.w_up, self.w_down,
                                 top_k=self.top_k,
-                                cap_factor=self.capacity_factor)
+                                cap_factor=self.capacity_factor,
+                                renorm=self.norm_topk_prob)
         else:
-            out, aux = apply_op("moe_ffn", _moe, x, logits, self.w_up,
-                                self.w_down, top_k=self.top_k)
-        from ..nn.aux_loss import emit_aux_loss
-
-        emit_aux_loss(self, aux * self.aux_weight)
+            out, aux = apply_op("moe_ffn", _moe, x, logits, self.w_gate,
+                                self.w_up, self.w_down, top_k=self.top_k,
+                                renorm=self.norm_topk_prob)
+        aux = aux * self.aux_weight
+        if self.z_loss_weight:
+            aux = aux + self.z_loss_weight * apply_op(
+                "moe_z_loss", _z_loss, logits)
+        emit_aux_loss(self, aux)
         return out
+
+    def _forward_sorted(self, x):
+        """The dropless path (module docstring), as three ops so that amp
+        O1 casts only the expert matmuls' operands: the router and the
+        weighted sum over a token's k choices stay in float32."""
+        topv, topi, balance, z = apply_op(
+            "moe_route", _route, x, self.gate.weight, self.gate.bias,
+            top_k=self.top_k, renorm=self.norm_topk_prob)
+        ys, order, inv = apply_op(
+            "moe_experts_sorted", _sorted_experts, x, topi, self.w_gate,
+            self.w_up, self.w_down, kernel=_expert_kernel())
+        out = apply_op("moe_combine", _combine, ys, topv, order, inv,
+                       shape=tuple(x.shape))
+        aux = balance * self.aux_weight
+        if self.z_loss_weight:
+            aux = aux + z * self.z_loss_weight
+        return out, aux
 
     def _forward_alltoall(self, x, logits):
         """Explicit GShard a2a dispatch under shard_map over 'ep' (see
@@ -219,9 +501,14 @@ class MoELayer(nn.Layer):
             raise ValueError(f"batch {b} must be divisible by the token "
                              f"shard count {groups} (axes {tok_axes})")
 
-        def local_fn(x, logits, w_up, w_down):
+        renorm = self.norm_topk_prob
+
+        def local_fn(x, logits, *weights):
             # x: [B/groups, S, H] (groups = data axes x ep shards);
-            # w_up/w_down: [E/ep, ...] (local experts)
+            # weights: ([w_gate,] w_up, w_down), each [E/ep, ...] (local
+            # experts)
+            w_gate = weights[0] if len(weights) == 3 else None
+            w_up, w_down = weights[-2:]
             b_loc, s, hdim = x.shape
             n = b_loc * s
             cap = max(1, int(np.ceil(cf * top_k * n / e)))
@@ -229,29 +516,32 @@ class MoELayer(nn.Layer):
             probs = jax.nn.softmax(
                 logits.astype(jnp.float32), axis=-1).reshape(n, e)
             combine, dispatch, topi = _capacity_combine(xf, probs, top_k,
-                                                        cap)
+                                                        cap, renorm)
             buf = jnp.einsum("nec,nh->ech", dispatch, xf)  # [E, C, H]
             # shard r keeps experts [r*E/ep, (r+1)*E/ep): swap the
             # expert dim across shards, stacking every shard's tokens
             # for my experts along capacity
             buf = jax.lax.all_to_all(buf, axis, split_axis=0,
                                      concat_axis=1, tiled=True)
-            h = jax.nn.gelu(jnp.einsum("ech,ehf->ecf", buf, w_up))
-            y = jnp.einsum("ecf,efh->ech", h, w_down)
+            y = _expert_ffn(buf, w_gate, w_up, w_down,
+                            "ech,ehf->ecf", "ecf,efh->ech")
             y = jax.lax.all_to_all(y, axis, split_axis=1, concat_axis=0,
                                    tiled=True)              # [E, C, H]
             out = jnp.einsum("nec,ech->nh", combine.astype(y.dtype), y)
             aux = jax.lax.pmean(_gshard_aux(probs, topi), tok_axes)
             return out.reshape(b_loc, s, hdim), aux.astype(x.dtype)
 
-        def _a2a(x, logits, w_up, w_down):
+        def _a2a(x, logits, *weights):
             tok = P(tok_axes, None, None)
             wsp = P(axis, None, None)
             fn = shard_map(local_fn, mesh=mesh,
-                           in_specs=(tok, tok, wsp, wsp),
+                           in_specs=(tok, tok) + (wsp,) * len(weights),
                            out_specs=(tok, P()),
                            check_vma=False)
-            return fn(x, logits, w_up, w_down)
+            return fn(x, logits, *weights)
+
+        weights = tuple(w for w in (self.w_gate, self.w_up, self.w_down)
+                        if w is not None)
 
         from ..core.dispatch import in_trace
 
@@ -268,13 +558,13 @@ class MoELayer(nn.Layer):
 
             _place(x, P())
             _place(logits, P())
-            _place(self.w_up, P(axis))
-            _place(self.w_down, P(axis))
+            for w in weights:
+                _place(w, P(axis))
         # cache key must discriminate everything the closure captures:
         # the mesh's token-shard group count, and the routing params
         # (top_k / capacity_factor / num_experts) — two layers differing
         # only in top_k would otherwise share the cached jit
         return apply_op(
             f"moe_ffn_a2a_{axis}{ep}_g{groups}_m{id(mesh)}"
-            f"_k{top_k}_cf{cf}_e{e}",
-            _a2a, x, logits, self.w_up, self.w_down)
+            f"_k{top_k}_cf{cf}_e{e}_r{int(renorm)}",
+            _a2a, x, logits, *weights)

@@ -49,7 +49,7 @@ def _i32(x):
 
 def _block(seq, want):
     """Largest block size <= want that divides seq (>=8 when possible)."""
-    for b in (want, 256, 128, 64, 32, 16, 8):
+    for b in (want, 512, 256, 128, 64, 32, 16, 8):
         if b <= want and seq % b == 0:
             return b
     return seq  # tiny/odd seq: single block
@@ -263,6 +263,7 @@ def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
             pltpu.VMEM((block_q, d), jnp.float32),       # output acc
         ],
         interpret=interpret,
+        name="flash_stream_fwd",
         compiler_params=_STREAM_GRID_PARAMS,
         cost_estimate=pl.CostEstimate(
             flops=4 * seq_q * seq_k * d,
@@ -462,6 +463,7 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_stream_bwd_dq",
         compiler_params=_STREAM_GRID_PARAMS,
     )(seed, q, k, v, do, lse, delta)
 
@@ -494,6 +496,7 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_stream_bwd_dkv",
         compiler_params=_STREAM_GRID_PARAMS,
     )(seed, q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -524,8 +527,22 @@ def _flash_bwd(scale, causal, block_q, block_k, dropout_p, interpret, res,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _stream_block(head_dim, itemsize):
+    """The streaming kernels' q and k block. A program pays a fixed latency
+    per grid step, so blocks as large as VMEM allows: beside the f32 score
+    tiles [block, block] the backward kernels hold six [block, head_dim]
+    operand tiles double-buffered, so the block halves as a row's bytes
+    double. Measured on the v5e at b4 h16 s4096 d128 bf16 causal, forward +
+    backward (PERF.md section 6, PR 25): 256/256 26.2 ms, 512/512 12.7,
+    1024/512 11.6, 1024/1024 11.5; compiled for a described v5e
+    (tests/test_mosaic_compile.py): 1024 runs out of VMEM at 512 bytes a
+    row (d128 f32, d256 bf16), 512 does not."""
+    row_bytes = head_dim * itemsize
+    return 1024 if row_bytes <= 256 else 512 if row_bytes <= 512 else 256
+
+
 def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
-        block_q=256, block_k=256, interpret=False):
+        block_q=None, block_k=None, interpret=False):
     """Flash attention. q,k,v: [batch, heads, seq, head_dim] (or 3-d
     [batch*heads, seq, head_dim]). Returns same shape as q.
 
@@ -542,8 +559,9 @@ def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
     sk = k.shape[2]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    bq = _block(sq, block_q)
-    bk = _block(sk, block_k)
+    want = _stream_block(d, q.dtype.itemsize)
+    bq = _block(sq, block_q or want)
+    bk = _block(sk, block_k or want)
     q3 = q.reshape(b * h, sq, d)
     k3 = k.reshape(b * h, sk, d)
     v3 = v.reshape(b * h, sk, d)

@@ -211,9 +211,13 @@ def rms_norm(x, w, *, eps=1e-6):
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * w
 
 
-def _rope(x, base=10000.0, positions=None):
+def _rope(x, base=10000.0, positions=None, pairing="interleaved"):
     """Rotary embedding. x: [B, H, T, D]; positions: [T] absolute positions
-    (defaults to 0..T-1). Shared with generation.py's cached decode."""
+    (defaults to 0..T-1). Shared with generation.py's cached decode.
+    ``pairing``: which features rotate together — ``interleaved`` pairs
+    (2i, 2i+1) (the Llama block here), ``half`` pairs (i, i + D/2) (the
+    rotate-half form of the HF sources; OLMoE). The two differ by a fixed
+    permutation of the columns of the q and k projections."""
     import jax.numpy as jnp
 
     d = x.shape[-1]
@@ -224,6 +228,10 @@ def _rope(x, base=10000.0, positions=None):
     freqs = jnp.outer(positions, inv)
     cos = jnp.cos(freqs)[None, None].astype(x.dtype)
     sin = jnp.sin(freqs)[None, None].astype(x.dtype)
+    if pairing == "half":
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1)
     x1, x2 = x[..., ::2], x[..., 1::2]
     out1 = x1 * cos - x2 * sin
     out2 = x2 * cos + x1 * sin
@@ -362,3 +370,128 @@ class LlamaModel(nn.Layer):
             kwargs.pop("pad_token_id", None)
             return _llama_generate(self, input_ids, **kwargs)
         return _generate(self, input_ids, **kwargs)
+
+
+class OlmoeAttention(nn.Layer):
+    """OLMoE's attention: bias-free projections, RMSNorm over the whole
+    hidden-wide q and k before the split into heads (QK-norm), rotate-half
+    RoPE, causal attention on [B, H, T, D] through the dispatching sdpa
+    (the streaming flash kernel at long sequences, like LlamaAttention)."""
+
+    def __init__(self, hidden_size, num_heads, rms_norm_eps=1e-5,
+                 rope_theta=10000.0, weight_attr=None):
+        super().__init__()
+        self.num_heads = num_heads
+        self.head_dim = hidden_size // num_heads
+        self.rope_theta = float(rope_theta)
+
+        def proj():
+            return nn.Linear(hidden_size, hidden_size,
+                             weight_attr=weight_attr, bias_attr=False)
+
+        self.q_proj, self.k_proj, self.v_proj = proj(), proj(), proj()
+        self.o_proj = proj()
+        self.q_norm = RMSNorm(hidden_size, eps=rms_norm_eps)
+        self.k_norm = RMSNorm(hidden_size, eps=rms_norm_eps)
+
+    def forward(self, x):
+        from ..core.dispatch import apply_op
+        from ..ops.attention import scaled_dot_product_attention as _sdpa
+
+        def _heads(q, k, v, *, nh, hd, base):
+            def split(t):
+                b, s, _ = t.shape
+                return t.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+
+            return (_rope(split(q), base, pairing="half"),
+                    _rope(split(k), base, pairing="half"), split(v))
+
+        q, k, v = apply_op(
+            "olmoe_heads_rope", _heads, self.q_norm(self.q_proj(x)),
+            self.k_norm(self.k_proj(x)), self.v_proj(x),
+            nh=self.num_heads, hd=self.head_dim, base=self.rope_theta)
+        out = _sdpa(q, k, v, is_causal=True, training=self.training)
+
+        def _merge(out):
+            b, h, t, d = out.shape
+            return out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+        return self.o_proj(apply_op("merge_heads", _merge, out))
+
+
+class OlmoeDecoderLayer(nn.Layer):
+    """Pre-norm block: attention, then the sparse-expert FFN
+    (``incubate.moe.MoELayer``: SwiGLU experts, bias-free softmax router,
+    top-k weights not renormalised, no shared expert)."""
+
+    def __init__(self, hidden_size, num_heads, intermediate_size,
+                 num_experts, num_experts_per_tok, rms_norm_eps=1e-5,
+                 rope_theta=10000.0, norm_topk_prob=False,
+                 router_aux_loss_coef=0.01, router_z_loss_coef=0.001,
+                 weight_attr=None):
+        super().__init__()
+        from ..incubate.moe import MoELayer
+
+        self.input_layernorm = RMSNorm(hidden_size, eps=rms_norm_eps)
+        self.self_attn = OlmoeAttention(hidden_size, num_heads, rms_norm_eps,
+                                        rope_theta, weight_attr)
+        self.post_attention_layernorm = RMSNorm(hidden_size,
+                                                eps=rms_norm_eps)
+        self.mlp = MoELayer(
+            hidden_size, intermediate_size, num_experts,
+            top_k=num_experts_per_tok, activation="swiglu", gate_bias=False,
+            norm_topk_prob=norm_topk_prob, aux_weight=router_aux_loss_coef,
+            z_loss_weight=router_z_loss_coef, weight_attr=weight_attr)
+
+    def forward(self, x):
+        x = x + self.self_attn(self.input_layernorm(x))
+        return x + self.mlp(self.post_attention_layernorm(x))
+
+
+class OlmoeModel(nn.Layer):
+    """OLMoE (Muennighoff et al. 2024, arXiv:2409.02060; HF ``olmoe``):
+    a decoder whose every layer is attention with QK-norm plus a
+    dropless sparse-expert FFN. Defaults are OLMoE-1B-7B's published
+    sizes; every weight starts Normal(0, ``initializer_range``).
+
+    ``forward`` returns the logits [B, T, vocab]. A training loss over a
+    large vocabulary should not hold them: ``features`` returns the
+    final-normed hidden states, to be given with ``lm_head.weight`` to
+    ``F.linear_cross_entropy``. The expert layers' load-balancing and
+    z-losses reach a compiled step through ``nn.aux_loss``."""
+
+    def __init__(self, vocab_size=50304, hidden_size=2048,
+                 num_hidden_layers=16, num_attention_heads=16,
+                 intermediate_size=1024, num_experts=64,
+                 num_experts_per_tok=8, rms_norm_eps=1e-5,
+                 rope_theta=10000.0, norm_topk_prob=False,
+                 router_aux_loss_coef=0.01, router_z_loss_coef=0.001,
+                 initializer_range=0.02):
+        super().__init__()
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            OlmoeDecoderLayer(
+                hidden_size, num_attention_heads, intermediate_size,
+                num_experts, num_experts_per_tok, rms_norm_eps, rope_theta,
+                norm_topk_prob, router_aux_loss_coef, router_z_loss_coef,
+                weight_attr=attr())
+            for _ in range(num_hidden_layers)])
+        self.norm = RMSNorm(hidden_size, eps=rms_norm_eps)
+        self.lm_head = nn.Linear(hidden_size, vocab_size,
+                                 weight_attr=attr(), bias_attr=False)
+
+    def features(self, input_ids):
+        x = self.embed_tokens(input_ids)
+        for layer in self.layers:
+            x = layer(x)
+        return self.norm(x)
+
+    def forward(self, input_ids):
+        return self.lm_head(self.features(input_ids))

@@ -227,16 +227,27 @@ class TestCapacityDispatch:
 
     def _twins(self, cf):
         paddle.seed(9)
-        cap = MoELayer(8, 16, num_experts=8, top_k=2, capacity_factor=cf)
+        cap = MoELayer(8, 16, num_experts=8, top_k=2, capacity_factor=cf,
+                       dispatch_mode="capacity")
         dense = MoELayer(8, 16, num_experts=8, top_k=2,
                          dispatch_mode="dense")
         dense.set_state_dict(cap.state_dict())
         return cap, dense
 
-    def test_auto_mode_picks_capacity_at_8_experts(self):
-        cap, dense = self._twins(2.0)
-        assert cap.dispatch_mode == "capacity"
-        assert MoELayer(8, 16, num_experts=4).dispatch_mode == "dense"
+    def test_auto_mode_resolves_from_experts_and_mesh(self):
+        """``auto`` is decided by what the layer can observe: below 8
+        experts dense; from 8 on the dropless sorted path where no 'ep'
+        axis shards the experts, capacity where one does."""
+        auto = MoELayer(8, 16, num_experts=8, top_k=2)
+        assert auto.dispatch_mode == "auto"
+        assert auto.resolved_mode() == "sorted"
+        assert MoELayer(8, 16, num_experts=4).resolved_mode() == "dense"
+        with topology.tracing_for(topology.build_mesh(dp=2, ep=4)):
+            assert auto.resolved_mode() == "capacity"
+        with topology.tracing_for(topology.build_mesh(dp=8)):
+            assert auto.resolved_mode() == "sorted"
+        cap, _ = self._twins(2.0)
+        assert cap.resolved_mode() == "capacity"
 
     def test_matches_dense_when_nothing_drops(self):
         cap, dense = self._twins(8.0)  # C >= N: no token can overflow

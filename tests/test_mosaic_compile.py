@@ -38,6 +38,19 @@ KERNEL_CASES = {
     "largest-bf16-s512": (2, 512, 22, 128, "bfloat16", 0.1),
 }
 GATE_CASES = ("announced-dp4", "plain-jit-over-mesh-arrays")
+#: the streaming kernel, causal (batch, heads, seq, head_dim, dtype): at the
+#: OLMoE cell's attention, at the same in float32 (the cell's reference
+#: check runs it) and at 512 bytes a row in bf16 — the block sizes
+#: ``_stream_block`` picks have to fit VMEM at each. STREAM_CALLS: the names
+#: its three Mosaic calls carry (the benchmark's flash_roofline finds them
+#: in a trace by these)
+STREAM_CASES = {
+    "olmoe-cell-bf16": (4, 16, 4096, 128, "bfloat16"),
+    "olmoe-check-f32": (1, 16, 4096, 128, "float32"),
+    "d256-bf16": (2, 8, 8192, 256, "bfloat16"),
+}
+STREAM_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
+                "flash_stream_bwd_dkv")
 
 
 def _child():
@@ -80,6 +93,18 @@ def _child():
         out[name] = {"mosaic": text.count(MOSAIC), "admitted": admitted,
                      "wider_admitted": wider,
                      "scores_in_hbm": f"{batch},{heads},{seq},{seq}" in text}
+
+    for name, (batch, heads, seq, head_dim, dtype) in STREAM_CASES.items():
+        qkv = jax.ShapeDtypeStruct((batch, heads, seq, head_dim), dtype,
+                                   sharding=one)
+        text = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(fa.mha(q, k, v, causal=True)
+                                    .astype(jnp.float32)),
+            argnums=(0, 1, 2))).lower(qkv, qkv, qkv).compile().as_text()
+        out["stream-" + name] = {
+            "mosaic": text.count(MOSAIC),
+            "calls": [c for c in STREAM_CALLS if c in text],
+            "scores_in_hbm": f"{seq},{seq}]" in text}
 
     # through the gate, on a process with several devices: the kernel where
     # the step announced its mesh, XLA's route where a plain jit is handed
@@ -140,6 +165,17 @@ def test_short_kernel_compiles(compiled, case):
     assert got["mosaic"] == 2 and not got["scores_in_hbm"]
     assert got["admitted"]
     assert got["wider_admitted"] == case.startswith("cell-shape")
+
+
+@pytest.mark.parametrize("case", list(STREAM_CASES))
+def test_stream_kernel_compiles(compiled, case):
+    """The streaming kernel, forward and both backward calls, causal, at
+    the OLMoE cell's b4 h16 s4096 d128 bf16, in float32 and at d256: three
+    Mosaic calls under their names within VMEM at the block sizes the
+    kernel picks, no [seq, seq] scores in HBM."""
+    got = compiled["stream-" + case]
+    assert got["mosaic"] == 3 and got["calls"] == list(STREAM_CALLS)
+    assert not got["scores_in_hbm"]
 
 
 def test_announced_mesh_keeps_the_kernel_under_shard_map(compiled):
