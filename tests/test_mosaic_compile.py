@@ -10,9 +10,11 @@ environment describes the topology before libtpu loads: libtpu reads it
 once, and the test workers' environment stays as it was. Only a missing
 libtpu skips; a child that fails, fails the tests.
 """
+import collections
 import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -51,6 +53,50 @@ STREAM_CASES = {
 }
 STREAM_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
                 "flash_stream_bwd_dkv")
+FFN_WIDTH = 3072                  # bert-base's intermediate_size
+#: what XLA's expansion of erfc brings into a fusion and erf does not
+ERFC_OPCODES = ("exponential", "divide", "select", "compare")
+
+
+def _fusions(text):
+    """The fusions of a compiled module's entry computation: for each, its
+    result type, the ``op_name`` of its root and the opcodes of the
+    computation it calls, those of nested fusions included."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = []
+        elif name and line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(line)
+
+    def opcodes(computation):
+        counts = collections.Counter()
+        for line in bodies[computation]:
+            op = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = .*? ([\w\-]+)\(", line)
+            if not op:
+                continue
+            counts[op.group(1)] += 1
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            if op.group(1) == "fusion" and called:
+                counts.update(opcodes(called.group(1)))
+        return counts
+
+    found = []
+    for line in bodies["ENTRY"]:
+        fusion = re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) fusion\(.*calls=%([\w.\-]+)",
+            line)
+        if fusion:
+            scope = re.search(r'op_name="([^"]*)"', line)
+            found.append({
+                "result": re.sub(r"\{[^}]*\}", "", fusion.group(1)),
+                "op_name": scope.group(1) if scope else "",
+                "opcodes": dict(opcodes(fusion.group(2)))})
+    return found
 
 
 def _child():
@@ -62,6 +108,7 @@ def _child():
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     import paddle_tpu as paddle
+    import paddle_tpu.nn.functional as F
     from paddle_tpu.core import random as random_core
     from paddle_tpu.distributed import topology
     from paddle_tpu.ops import attention
@@ -105,6 +152,38 @@ def _child():
             "mosaic": text.count(MOSAIC),
             "calls": [c for c in STREAM_CALLS if c in text],
             "scores_in_hbm": f"{seq},{seq}]" in text}
+
+    # the s128 cell's FFN under amp O1, forward + backward: what F.gelu's
+    # erf lowers to behind linear1's gemm, and what leaves that fusion
+    def ffn_loss(p, x):
+        with paddle.amp.auto_cast(level="O1"):
+            with jax.named_scope("linear1"):
+                h = F.linear(paddle.Tensor(x), paddle.Tensor(p["w1"]),
+                             paddle.Tensor(p["b1"]))
+            g = F.gelu(h)
+            o = F.linear(g, paddle.Tensor(p["w2"]), paddle.Tensor(p["b2"]))
+        return jnp.sum(jnp.square(o._value.astype(jnp.float32)))
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    embed = HEADS * HEAD_DIM
+    text = jax.jit(jax.grad(ffn_loss, argnums=(0, 1))).lower(
+        {"w1": f32(embed, FFN_WIDTH), "b1": f32(FFN_WIDTH),
+         "w2": f32(FFN_WIDTH, embed), "b2": f32(embed)},
+        f32(BATCH, SEQ, embed)).compile().as_text()
+    wide = f"[{BATCH},{SEQ},{FFN_WIDTH}]"
+    fusions = _fusions(text)
+    out["ffn-gelu"] = {
+        "linear1_forward": [
+            f for f in fusions if "convolution" in f["opcodes"]
+            and "linear1" in f["op_name"]
+            and "transpose(" not in f["op_name"]],
+        "f32_wide_results": [f["result"] for f in fusions
+                             if "f32" + wide in f["result"]],
+        "erfc_expansions": [f["op_name"] for f in fusions
+                            if "divide" in f["opcodes"]
+                            or "select" in f["opcodes"]]}
 
     # through the gate, on a process with several devices: the kernel where
     # the step announced its mesh, XLA's route where a plain jit is handed
@@ -176,6 +255,25 @@ def test_stream_kernel_compiles(compiled, case):
     got = compiled["stream-" + case]
     assert got["mosaic"] == 3 and got["calls"] == list(STREAM_CALLS)
     assert not got["scores_in_hbm"]
+
+
+def test_gelu_stays_one_erf_behind_the_ffn_up_gemm(compiled):
+    """F.gelu under amp O1 at the s128 cell's FFN shape: the fusion rooted
+    at linear1's forward gemm evaluates ONE native ``erf`` an element and
+    nothing of erfc's three-branch expansion, writes the pre-activation
+    and gelu's value in bf16 and nothing else of that shape; no fusion of
+    the program hands on a float32 ``[256,128,3072]``. A jax or XLA that
+    expands ``erf`` (or brings ``erfc`` back) fails here, not on the chip.
+    (The cell's whole step keeps the same two tensors only because gelu's
+    value sits behind a barrier there: PERF.md section 6, PR 26.)"""
+    got = compiled["ffn-gelu"]
+    fusion, = got["linear1_forward"]
+    assert fusion["opcodes"].get("erf") == 1
+    assert not [op for op in ERFC_OPCODES if op in fusion["opcodes"]]
+    wide = f"bf16[{BATCH},{SEQ},{FFN_WIDTH}]"
+    assert fusion["result"] == f"({wide}, {wide})"
+    assert got["f32_wide_results"] == []
+    assert got["erfc_expansions"] == []
 
 
 def test_announced_mesh_keeps_the_kernel_under_shard_map(compiled):
