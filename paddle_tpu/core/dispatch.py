@@ -206,8 +206,7 @@ def _check_nan_inf(name, arrays):
 
 
 _HOT = None  # (Tensor, tape_mod) resolved once — import machinery is
-# measurable per-op overhead on the eager path (tools/op_bench.py
-# --eager-overhead)
+# measurable per-op overhead on the eager path
 
 
 def _hot_mods():

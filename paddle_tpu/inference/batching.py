@@ -68,8 +68,7 @@ registry exposes the same instruments to Prometheus (wire cmd 6 and
 enqueue -> batch -> (compile) -> execute, tagged with the
 wire-propagated trace id (``infer(trace_id=...)``), and every AOT
 bucket compile lands in the compile ledger (``obs.LEDGER``) with its
-cost-analysis FLOPs and structural HLO fingerprint — the data
-``bench.py perfproxy`` gates on.
+cost-analysis FLOPs and structural HLO fingerprint.
 
 Env knobs (constructor kwargs override):
     PADDLE_TPU_SERVING_BREAKER_THRESHOLD   consecutive failures to trip
@@ -223,7 +222,7 @@ class _BucketStats:
         self.compiles = 0  # real inline XLA compiles only
         self.store_loads = 0  # programs deserialized from the artifact
         # store — split so a store miss can never masquerade as (or
-        # hide) a real recompile regression in cmd-5 stats / perfproxy
+        # hide) a real recompile regression in cmd-5 stats
         self.batches = 0
         self.requests = 0
         self.rows = 0
@@ -537,8 +536,7 @@ class AotLayerRunner:
     def _jit(self, flat_fn, donate, n_inputs):
         """The one jit construction both the inline compile and the
         export share. Single mesh: byte-for-byte the historical call
-        (no sharding kwargs — the committed perfproxy baseline pins
-        its fingerprints). Sharded: weights pinned to their discipline
+        (no sharding kwargs). Sharded: weights pinned to their discipline
         layout, batch inputs and outputs replicated, so the host-side
         engine (and the wire) see exactly the single-chip shapes."""
         jax = self._jax
@@ -657,8 +655,8 @@ class AotLayerRunner:
             return None
         # the ledger distinguishes store loads from real compiles, so
         # single-flight across processes is assertable ("exactly one
-        # kind=aot event per bucket, fleet-wide") and perfproxy's
-        # compile counts never conflate a store miss with a regression
+        # kind=aot event per bucket, fleet-wide") and a compile count
+        # never conflates a store miss with a regression
         LEDGER.record(f"serving/bucket{bucket}",
                       duration_s=time.monotonic() - t0, kind="store",
                       extra={"bucket": bucket,
@@ -691,8 +689,7 @@ class AotLayerRunner:
 
     def _quant_extra(self):
         """Ledger-event mode/mesh tags. Empty for f32/single, so every
-        historical event shape (and the committed perfproxy baseline's
-        f32 single-chip sections) stays byte-identical."""
+        historical event shape stays byte-identical."""
         extra = {}
         if self.quant_mode:
             extra["quant"] = self.quant_mode
@@ -724,8 +721,7 @@ class AotLayerRunner:
                         .compile())
         # every AOT compile lands in the process compile ledger: bucket,
         # duration, cost_analysis FLOPs/bytes, structural HLO
-        # fingerprint — what bench.py perfproxy diffs against its
-        # committed baseline
+        # fingerprint
         LEDGER.record(f"serving/bucket{bucket}",
                       duration_s=time.monotonic() - t0, compiled=compiled,
                       kind="aot",

@@ -88,8 +88,7 @@ are engine-owned obs.metrics instruments exposed through the process
 registry (wire cmd 6 / ``/metrics``); traced requests get per-token
 ``serving.decode.token`` spans in the obs.tracing buffer; every
 program materialization lands in the compile ledger under
-``decode/...`` labels (what ``bench.py perfproxy``'s decode contract
-gates on).
+``decode/...`` labels.
 
 **Stream resume (PR 17).** A running sequence can be checkpointed into
 a self-describing *kv-snapshot block* (``wire_spec.encode_kv_snapshot``:
@@ -359,8 +358,7 @@ class _Programs:
 
     def _quant_extra(self):
         """Ledger-event mode/mesh tags (empty for f32/single —
-        historical event shapes and the committed perfproxy decode
-        section stay byte-identical)."""
+        historical event shapes stay byte-identical)."""
         extra = {}
         q = getattr(self._model, "quant", None)
         if q:

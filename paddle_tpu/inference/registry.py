@@ -22,8 +22,8 @@ decision reads:
   half-open probe (the PR 5 circuit-breaker shape): success readmits,
   failure re-ejects and restarts the cooldown.
 
-Chaos site: ``fleet.heartbeat`` fires once per replica probe, so tests
-and ``bench.py fleet`` can deterministically fail/delay heartbeats.
+Chaos site: ``fleet.heartbeat`` fires once per replica probe, so
+tests/test_fleet.py can deterministically fail/delay heartbeats.
 
 Env knobs (constructor kwargs win):
     PADDLE_TPU_FLEET_HEARTBEAT_S       probe period          (0.25)

@@ -1,7 +1,7 @@
 """Front-tier fleet router (ROADMAP item 3 tentpole).
 
 Speaks the exact PredictorServer wire protocol on its front socket, so
-every existing client (Go/R/C, bench.py, plain sockets) points at the
+every existing client (Go/R/C, plain sockets) points at the
 router instead of a replica and nothing else changes. Behind it, a
 :class:`~paddle_tpu.inference.registry.ReplicaRegistry` of ``serve_model``
 replicas. Per cmd-1 infer request the router:

@@ -123,11 +123,10 @@ from .wire_spec import (CMD_DRAIN, CMD_HEALTH, CMD_INFER, CMD_KV_PUT,
                         CMD_STOP, DEADLINE_MARKER, DECODE_MARKER,
                         DECODE_ONESHOT_BIT, TENANT_MARKER, TRACE_MARKER)
 
-# historical aliases (tests, bench.py, and the router import these
+# historical aliases (the router and the serving tests import these
 # names from here): the tables live in wire_spec now
 _DTYPES = wire_spec.NUMPY_BY_CODE
 _DTYPE_CODES = wire_spec.CODE_BY_NUMPY
-_WIDEN_TO_F32 = wire_spec.WIDEN_TO_F32
 
 STATUS_OK = wire_spec.STATUS_OK
 STATUS_ERROR = wire_spec.STATUS_ERROR
@@ -172,7 +171,7 @@ def _read_all(sock, n, limit=None):
 
 # The codec lives in wire_spec (the one Python encoder/decoder of the
 # framing); these historical underscore names are what the rest of the
-# repo — router, bench.py, the serving test tree — imports from here.
+# repo — router, fleet, the serving test tree — imports from here.
 _encode_arrays = wire_spec.encode_arrays
 _encode_deadline = wire_spec.encode_deadline
 _encode_trace = wire_spec.encode_trace
@@ -1010,7 +1009,7 @@ def serve_model(path_prefix, port=0, dynamic_batching=False,
     persistent compiled-artifact store instead of compiling: a fresh
     replica process reaches its first healthy reply with zero XLA
     compiles once any replica has published the ladder
-    (``bench.py coldstart`` measures exactly this), and a corrupt or
+    (tests/test_artifact_serving.py holds exactly this), and a corrupt or
     stale store entry silently degrades that bucket to an inline
     compile (README "Artifact store" has the degradation matrix).
 

@@ -248,10 +248,9 @@ class ServingMesh:
         return frac
 
     def per_shard_bytes(self, arrays):
-        """Weight bytes RESIDENT PER DEVICE under this mesh — the
-        bigger-than-one-chip proxy ``bench.py sharded`` reports (a
-        model whose per-shard bytes fit HBM serves even when its total
-        bytes do not)."""
+        """Weight bytes RESIDENT PER DEVICE under this mesh (a model
+        whose per-shard bytes fit HBM serves even when its total bytes
+        do not)."""
         import numpy as np
 
         total = 0.0

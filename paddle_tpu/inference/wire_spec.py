@@ -481,7 +481,7 @@ IMPLEMENTATIONS = {
 
 # ------------------------------------------------------ codec (Python)
 # The ONE Python encoder/decoder for the framing above. server.py,
-# router.py, bench.py and the test tree all route through these (the
+# router.py and the test tree all route through these (the
 # server re-exports them under its historical underscore names) — the
 # bytes they produce are the protocol, bit for bit.
 

@@ -133,8 +133,8 @@ def save(layer, path, input_spec=None, quant=None, quant_calib=None,
         # the stored/streamed weights are bf16 (half the bytes the
         # decode hot path reads per token); the traced fn upcasts to
         # f32 below, so compute accumulates in f32 and the exported
-        # program carries the convert ops perfproxy's quant section
-        # asserts on
+        # program carries the convert ops
+        # tests/test_quant_serving.py asserts on
         params = {n: a.astype(jnp.bfloat16)
                   if np.dtype(a.dtype) == np.dtype(np.float32) else a
                   for n, a in params.items()}

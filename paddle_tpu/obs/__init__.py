@@ -3,9 +3,8 @@ accounting, compile ledger, Prometheus exposition.
 
 One instrumentation layer for training, serving, and CI (ROADMAP items
 3 and 4). Pure-stdlib on purpose: importable from the resilience
-runtime, the serving engine, clients of the wire protocol, and
-bench.py without dragging jax into anything that doesn't already have
-it.
+runtime, the serving engine and clients of the wire protocol without
+dragging jax into anything that doesn't already have it.
 
 Quick tour::
 

@@ -120,10 +120,6 @@ class GoodputAccountant:
         out["steps"] = steps
         out["total_s"] = round(total, 6)
         out["goodput"] = round(totals["step"] / total, 6) if total else 0.0
-        # the fleet-facing rate (bench.py goodput): useful steps per
-        # wall-clock hour, the "ML Productivity Goodput" numerator
-        out["steps_per_hour"] = (round(steps / total * 3600.0, 3)
-                                 if total else 0.0)
         return out
 
     def reset(self):

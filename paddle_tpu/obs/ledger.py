@@ -16,9 +16,9 @@ exactly from run to run. Every entry records:
                  literal payloads, so two compiles of the same program
                  shape match even when buffer ids differ
 
-``bench.py perfproxy`` replays a fixed scenario against this ledger and
-diffs compile counts / op counts / FLOPs against a committed baseline
-(PERFPROXY_BASELINE.json) — counts, not a speed.
+The serving suites read it to count real compiles apart from store
+loads (tests/test_artifact_serving.py, test_quant_serving.py,
+test_sharded_serving.py); the counts are exact, never a speed.
 """
 import hashlib
 import re
@@ -66,7 +66,7 @@ def hlo_typed_opcodes(hlo_text):
     skipped) — ``convert:f32``, ``parameter:s8``, ``dot:f32``;
     tuple-typed results report ``tuple``. The ONE parsing pass: the
     untyped view is a projection (:func:`hlo_opcodes`). The dtype
-    dimension is what the quant-ladder perfproxy section gates on: a
+    dimension is what tests/test_quant_serving.py reads: a
     ``parameter:s8`` / ``parameter:bf16`` count proves
     reduced-precision weights actually reached XLA instead of silently
     promoting to f32 upstream of the lowering."""
@@ -140,9 +140,8 @@ def analyze_compiled(compiled):
         for op in typed_ops:
             typed[op] = typed.get(op, 0) + 1
         # opcode:result_dtype counts — the reduced-precision evidence
-        # (parameter:s8 / parameter:bf16 / convert:f32) the quant
-        # perfproxy section diffs; untyped totals stay the gate for
-        # everything else
+        # (parameter:s8 / parameter:bf16 / convert:f32) that
+        # tests/test_quant_serving.py asserts on
         out["typed_op_counts"] = typed
     except Exception:  # noqa: BLE001
         pass
@@ -185,8 +184,8 @@ class CompileLedger:
         return evs
 
     def totals(self, key_prefix=None):
-        """Aggregate view the perf-proxy gate diffs: compile count,
-        summed flops/bytes, merged op counts."""
+        """Aggregate view: compile count, summed flops/bytes, merged
+        op counts."""
         evs = self.events(key_prefix)
         op_counts = {}
         flops = 0.0

@@ -22,8 +22,8 @@ and the serving engines exactly like f32 weights do, which is where the
 streams every weight every token). Compute dequantizes into the float
 domain (the MXU path; the pallas guide's ``values.astype(f32) * scale``
 pattern), so XLA sees genuine ``s8``/``bf16`` parameters plus
-``convert`` ops — which is exactly what ``bench.py perfproxy``'s
-quant-ladder section asserts reached the HLO.
+``convert`` ops — which tests/test_quant_serving.py
+(``test_ledger_events_carry_mode``) asserts reached the HLO.
 
 Documented accuracy bounds vs the float program, on well-scaled
 (unit-ish variance) weights — what tests/test_quant_serving.py pins on
@@ -241,6 +241,5 @@ def quantize_decode_model(model, quant):
 
 def weight_bytes(params):
     """Total bytes of a flat param list — the per-decode-step
-    bytes-moved proxy ``bench.py decode --quant`` reports (every decode
-    step streams every weight once)."""
+    bytes-moved proxy (every decode step streams every weight once)."""
     return int(sum(np.asarray(p).nbytes for p in params))

@@ -160,7 +160,7 @@ _EXC_WHITELIST = ("RuntimeError", "OSError", "IOError", "ValueError",
 
 def arm_from_env(env=None):
     """Arm faults described in the ``PADDLE_TPU_CHAOS`` env var — how a
-    launcher (bench.py goodput, the elastic e2e suite) injects
+    launcher (the elastic e2e suite, tests/test_elastic.py) injects
     deterministic faults into SUBPROCESS trainers it cannot reach with
     ``chaos.arm`` directly.
 

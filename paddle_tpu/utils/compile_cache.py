@@ -1,5 +1,5 @@
 """Where the persistent XLA compilation cache lives — the one rule
-shared by chip_smoke.py, bench.py and the test harness.
+shared by chip_smoke.py, benchmark/run.py and the test harness.
 
 The directory is part of the cache key, so it must not move between
 runs: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (jax

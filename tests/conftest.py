@@ -62,11 +62,11 @@ if os.environ.get("PADDLE_TPU_RESTRACE", "0") not in ("0", "", "false"):
     _RESTRACE_ARMED = _restrace.maybe_enable_from_env()
 
 
-# Persistent XLA compile cache (the rule bench.py and chip_smoke.py
-# share — paddle_tpu.utils.compile_cache): most of the tier-1 wall clock
-# is repeated big compiles, and a warm cache cuts the suite roughly in
-# half. Placed after the locktrace block, which must run before the
-# package import this needs.
+# Persistent XLA compile cache (the rule chip_smoke.py and
+# benchmark/run.py share — paddle_tpu.utils.compile_cache): most of the
+# tier-1 wall clock is repeated big compiles, and a warm cache cuts the
+# suite roughly in half. Placed after the locktrace block, which must
+# run before the package import this needs.
 from paddle_tpu.utils.compile_cache import configure_compile_cache  # noqa: E402
 
 configure_compile_cache()

@@ -10,8 +10,8 @@ continuous-batching determinism contract, tests/test_decode.py).
 
 Run as ``python tests/decode_worker.py`` (env-configured) it serves
 the model through a PredictorServer with a warmed DecodeEngine and
-prints one ``PORT <n>`` line — the subprocess replica the decode
-bench and the serving tests drive. Env:
+prints one ``PORT <n>`` line — the subprocess replica that
+tests/test_decode_resume.py and tests/test_disagg.py drive. Env:
 
     DECODE_WORKER_SEED        model weights seed          (0)
     DECODE_WORKER_HIDDEN      hidden width                (32)
@@ -19,27 +19,12 @@ bench and the serving tests drive. Env:
     DECODE_WORKER_MAX_SLOTS   concurrent sequences        (8)
     DECODE_WORKER_MAX_SEQ     max prompt+generated length (64)
     DECODE_WORKER_MAX_PROMPT  admission cap on prompts    (16)
-    DECODE_WORKER_WARM        1 = warm the ladder before PORT prints
-    DECODE_WORKER_QUANT       serving quant mode ("w8" | "bf16w";
-                              empty = f32)
     DECODE_WORKER_PHASE       replica pool ("prefill" | "decode";
                               empty = both) — shapes the warmup
                               ladder and the health/stats phase field
-    DECODE_WORKER_MESH        serving mesh descriptor ("tp2", ...;
-                              empty = single-chip). The spawner must
-                              also export an XLA device count >= the
-                              mesh width (bench.py sharded does).
-    DECODE_WORKER_DRAFT       1 = attach a draft companion model
-                              (speculative decoding; pair with
-                              PADDLE_TPU_SPEC_K >= 2)
-    DECODE_WORKER_DRAFT_HIDDEN  draft hidden width          (8)
-    DECODE_WORKER_ANCHOR      shared token-transition bias strength
-                              (float; 0 = off) — raises draft/target
-                              greedy agreement, see toy_decode_model
     PADDLE_TPU_ARTIFACT_DIR   artifact store (zero-cold-start rewarm)
-    PADDLE_TPU_PREFIX_DIR     persistent prefix-cache tier (warm-
-                              prefix inheritance across replicas)
-    PADDLE_TPU_SPEC_K         speculative burst width (engine knob)
+
+The ladder is warmed before ``PORT`` prints.
 """
 import os
 import sys
@@ -187,31 +172,21 @@ def main():
     from paddle_tpu.inference.decode import DecodeEngine
     from paddle_tpu.inference.server import PredictorServer
 
-    anchor = float(os.environ.get("DECODE_WORKER_ANCHOR", "0") or 0)
-    vocab = _env_int("DECODE_WORKER_VOCAB", 64)
-    seed = _env_int("DECODE_WORKER_SEED", 0)
-    draft = None
-    if os.environ.get("DECODE_WORKER_DRAFT") == "1":
-        draft = toy_decode_model(
-            hidden=_env_int("DECODE_WORKER_DRAFT_HIDDEN", 8),
-            vocab=vocab, seed=seed + 1, anchor=anchor)
     model = toy_decode_model(
         hidden=_env_int("DECODE_WORKER_HIDDEN", 32),
-        vocab=vocab, seed=seed, anchor=anchor, draft=draft)
+        vocab=_env_int("DECODE_WORKER_VOCAB", 64),
+        seed=_env_int("DECODE_WORKER_SEED", 0))
     engine = DecodeEngine(
         model,
-        quant=os.environ.get("DECODE_WORKER_QUANT") or None,
-        mesh=os.environ.get("DECODE_WORKER_MESH") or None,
         phase=os.environ.get("DECODE_WORKER_PHASE") or None,
         max_slots=_env_int("DECODE_WORKER_MAX_SLOTS", 8),
         max_seq_len=_env_int("DECODE_WORKER_MAX_SEQ", 64),
         max_prompt_len=_env_int("DECODE_WORKER_MAX_PROMPT", 16),
-        max_queue=_env_int("DECODE_WORKER_MAX_QUEUE", 256))
-    if os.environ.get("DECODE_WORKER_WARM", "1") == "1":
-        engine.warmup()
+        max_queue=256)
+    engine.warmup()
 
-    def run_fn(*arrays):  # non-decode cmd-1 traffic: echo (unused by
-        return list(arrays)  # the bench; keeps the server generic)
+    def run_fn(*arrays):  # non-decode cmd-1 traffic: echo
+        return list(arrays)
 
     server = PredictorServer(run_fn, decode_engine=engine,
                              own_decode_engine=True)

@@ -1,5 +1,4 @@
-"""Elastic pod trainer driven by tests/test_elastic.py (and mirrored by
-bench.py goodput's embedded worker).
+"""Elastic pod trainer driven by tests/test_elastic.py.
 
 Two layouts over the same elastic protocol:
 
@@ -139,9 +138,8 @@ def main():
         return (x[rank * shard:(rank + 1) * shard],
                 y[rank * shard:(rank + 1) * shard])
 
-    # bench.py goodput pads each step to a realistic duration so the
-    # steps/hour ratio is dominated by training + recovery, not python
-    # startup noise
+    # tests/test_elastic.py pads each step so a kill, a dead-host
+    # timeout or a launcher signal lands while steps are underway
     step_sleep = float(os.environ.get("PADDLE_TPU_ELASTIC_STEP_SLEEP", 0.0))
 
     losses = []
@@ -155,13 +153,8 @@ def main():
             preemption.write_resume_marker(ckpt_root, step=target,
                                            world_size=world)
         el.saved(target)
-        payload = {"preempted": True, "step": target, "rank": rank}
-        if rank == 0:
-            # this incarnation's useful-step ledger rides along so the
-            # goodput bench can aggregate across preempted attempts
-            payload["goodput"] = goodput.report()
-            payload["prometheus_goodput"] = _goodput_exposition()
-        _write_report(report_dir, rank, payload)
+        _write_report(report_dir, rank,
+                      {"preempted": True, "step": target, "rank": rank})
         el.close()
         raise preemption.PreemptedExit(step=target)
 

@@ -1,6 +1,5 @@
 """Subprocess / multi-process worker for the sharded-serving tests
-(tests/test_sharded_serving.py, ``bench.py sharded``, the perfproxy
-sharded section).
+(tests/test_sharded_serving.py, tests/test_fleet.py).
 
 Sharded engines need more than one jax device; the tier-1 parent
 process initialized jax with one CPU device, so every sharded scenario
@@ -33,8 +32,8 @@ Modes (argv[1]):
 
   serve <prefix> <mesh>
       Single-process multi-device serve_model replica (prints
-      ``PORT <n>``); the wire-level equivalence, fleet-relay, and
-      bench.py sharded tests drive it. SHARDED_WORKER_DECODE=1 serves
+      ``PORT <n>``); the wire-level equivalence and fleet-relay
+      tests drive it. SHARDED_WORKER_DECODE=1 serves
       the toy decode model through a DecodeEngine instead.
 
   rank <outdir> <mesh>
@@ -42,12 +41,6 @@ Modes (argv[1]):
       device per process): init_parallel_env, build the cross-process
       serving mesh, warm a sharded BatchingEngine, run the fixed
       request sequence in lockstep, rank 0 dumps outputs + stats.
-
-  perfproxy <outfile> <mesh>
-      Single-process multi-device: warm the sharded bucket ladder +
-      decode ladder with the artifact store disabled and dump the
-      compile-ledger structural record (exact compile counts, FLOPs,
-      opcode counts) — the perfproxy sharded section.
 """
 import json
 import os
@@ -186,6 +179,9 @@ def run_contract(outfile, meshes):
         record["meshes"][mesh] = {
             "dtypes": per_dtype,
             "ledger_mesh_tags": sorted({e.get("mesh") for e in events}),
+            "ledger_collectives": sum(
+                e.get("op_counts", {}).get(op, 0) for e in events
+                for op in ("all-gather", "all-reduce")),
         }
     # metrics label check: render while a sharded engine is LIVE (its
     # registry collector unregisters on close)
@@ -347,18 +343,14 @@ def run_serve(prefix, mesh):
         from decode_worker import toy_decode_model
         from paddle_tpu.inference.decode import DecodeEngine
 
-        model = toy_decode_model(
-            hidden=int(os.environ.get("DECODE_WORKER_HIDDEN", "32")),
-            vocab=int(os.environ.get("DECODE_WORKER_VOCAB", "64")),
-            seed=int(os.environ.get("DECODE_WORKER_SEED", "0")))
+        model = toy_decode_model(hidden=32, vocab=64, seed=0)
         engine = DecodeEngine(
             model, mesh=mesh,
             max_slots=int(os.environ.get("DECODE_WORKER_MAX_SLOTS", "8")),
             max_seq_len=int(os.environ.get("DECODE_WORKER_MAX_SEQ", "64")),
             max_prompt_len=int(os.environ.get("DECODE_WORKER_MAX_PROMPT",
                                               "16")),
-            max_queue=int(os.environ.get("DECODE_WORKER_MAX_QUEUE",
-                                         "256")))
+            max_queue=256)
         engine.warmup()
         server = PredictorServer(lambda *a: list(a),
                                  decode_engine=engine,
@@ -414,87 +406,6 @@ def run_rank(outdir, mesh):
         os.replace(path + ".tmp", path)
 
 
-# ---------------------------------------------------------------- perfproxy
-def run_perfproxy_section(outfile, mesh):
-    """Structural record of the sharded ladders (store disabled: every
-    materialization is a real inline XLA compile the ledger analyzed).
-    The parent diffs this against the committed baseline's sharded
-    section — exact compile counts, zero post-warmup compiles, FLOPs,
-    opcode counts."""
-    import numpy as np
-    from decode_worker import toy_decode_model
-    from paddle_tpu.inference.batching import BatchingEngine
-    from paddle_tpu.inference.decode import DecodeEngine
-    from paddle_tpu.jit import load as jit_load
-    from paddle_tpu.obs.ledger import LEDGER
-
-    os.environ["PADDLE_TPU_ARTIFACT_DISABLE"] = "1"
-    prefixes = build_models()
-    LEDGER.reset()
-    engine = BatchingEngine.for_layer(jit_load(prefixes["f32"]),
-                                      max_batch_size=8, max_wait_ms=1.0,
-                                      watchdog_interval=0, mesh=mesh,
-                                      name="perfproxy-sharded")
-    try:
-        engine.warmup()
-        warm = LEDGER.totals("serving/")
-        buckets = {}
-        for ev in LEDGER.events("serving/"):
-            buckets[str(ev["bucket"])] = {
-                "flops": ev.get("flops", 0.0),
-                "n_ops": ev.get("n_ops", 0),
-                "fingerprint": ev.get("fingerprint", ""),
-            }
-        rng = np.random.RandomState(0)
-        for rows in (1, 3, 8):
-            engine.infer([rng.randn(rows, 8).astype(np.float32)],
-                         timeout=120)
-        post = LEDGER.totals("serving/")["compiles"] - warm["compiles"]
-    finally:
-        engine.close()
-
-    dmodel = toy_decode_model(hidden=32, vocab=64, seed=0)
-    LEDGER.reset()
-    dengine = DecodeEngine(dmodel, max_slots=4, max_seq_len=32,
-                           min_seq_bucket=8, max_prompt_len=8,
-                           watchdog_interval=0, mesh=mesh,
-                           name="perfproxy-sharded-decode")
-    try:
-        dengine.warmup()
-        d_warm = LEDGER.totals("decode/")
-        reqs = [dengine.submit(np.array([1, 2, 3], np.int32),
-                               max_new_tokens=10),
-                dengine.submit(np.array([4, 5], np.int32),
-                               max_new_tokens=4)]
-        for r in reqs:
-            r.result(timeout=240)
-        d_post = LEDGER.totals("decode/")["compiles"] - d_warm["compiles"]
-    finally:
-        dengine.close()
-
-    record = {
-        "mesh": mesh,
-        "serving": {
-            "warmup_compiles": int(warm["compiles"]),
-            "post_warmup_compiles": int(post),
-            "flops": warm["flops"],
-            "n_ops": int(warm["n_ops"]),
-            "op_counts": warm["op_counts"],
-            "buckets": buckets,
-        },
-        "decode": {
-            "warmup_compiles": int(d_warm["compiles"]),
-            "post_warmup_compiles": int(d_post),
-            "flops": d_warm["flops"],
-            "n_ops": int(d_warm["n_ops"]),
-            "op_counts": d_warm["op_counts"],
-        },
-    }
-    with open(outfile + ".tmp", "w") as f:
-        json.dump(record, f)
-    os.replace(outfile + ".tmp", outfile)
-
-
 def main():
     mode = sys.argv[1]
     if mode == "rank":
@@ -517,8 +428,6 @@ def main():
         run_decode(sys.argv[2], sys.argv[3])
     elif mode == "serve":
         run_serve(sys.argv[2], sys.argv[3])
-    elif mode == "perfproxy":
-        run_perfproxy_section(sys.argv[2], sys.argv[3])
     else:
         raise SystemExit(f"unknown mode {mode!r}")
 

@@ -1,6 +1,6 @@
 """amp O2 (pure bf16) through spmd.build_train_step.
 
-bench.py runs amp_level="O2" on the TPU (BENCH_AMP=O2); a broken O2
+``amp_level="O2"`` is part of build_train_step's surface; a broken O2
 path must fail here (CPU, tiny BERT), not on chip time. O1
 and O2 train the same seeded model: both must converge, and their loss
 trajectories must stay close (bf16 master weights cost ~3 decimal

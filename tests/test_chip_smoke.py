@@ -137,15 +137,15 @@ def test_bert_saves_batch_polymorphic(tmp_path):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_bench_has_no_peaks_for_an_unknown_device():
+def test_benchmark_has_no_peaks_for_an_unknown_device():
     sys.path.insert(0, REPO)
     try:
-        import bench
+        from benchmark.harness import peaks
     finally:
         sys.path.pop(0)
-    assert "TPU v5 lite" in bench.DEVICE_PEAKS
-    with pytest.raises(bench.BenchFailure, match="no_peaks_for_device"):
-        bench.device_peaks()  # the CPU the tests run on
+    assert "TPU v5 lite" in peaks.PEAKS
+    with pytest.raises(KeyError, match="peaks table"):
+        peaks.peaks_for(jax.devices()[0].device_kind)  # the tests' CPU
 
 
 # -------------------------------------------------- compile cache rule

@@ -19,6 +19,7 @@ Layers covered here:
   ``stream_resume`` retry cause, the resume-latency histogram, and a
   zero live ``kv_snapshot`` census under the restrace sanitizer.
 """
+import json
 import os
 import signal
 import socket
@@ -505,7 +506,6 @@ def spawn_worker(store_dir, seed=0):
                DECODE_WORKER_MAX_SLOTS="4",
                DECODE_WORKER_MAX_SEQ="32",
                DECODE_WORKER_MAX_PROMPT="8",
-               DECODE_WORKER_WARM="1",
                PADDLE_TPU_ARTIFACT_DIR=store_dir)
     env.pop("PADDLE_TPU_SERVING_QUANT", None)
     env.pop("PADDLE_TPU_SERVING_MESH", None)
@@ -517,6 +517,16 @@ def spawn_worker(store_dir, seed=0):
     line = proc.stdout.readline()
     assert line.startswith("PORT "), f"worker died: {line!r}"
     return proc, int(line.split()[1])
+
+
+def inline_compiles(port):
+    """The replica's count of inline XLA compiles (cmd-5 stats)."""
+    with socket.create_connection(("127.0.0.1", port), timeout=30) as s:
+        s.sendall(ws.build_request(ws.CMD_STATS))
+        (blen,) = struct.unpack("<I", _read_all(s, 4))
+        resp = _read_all(s, blen)
+    assert resp[0] == ws.STATUS_OK
+    return json.loads(resp[1:].decode())["decode"]["compiles"]
 
 
 def wait_routable(registry, n, timeout=30.0):
@@ -544,8 +554,9 @@ class TestRouterFailover:
         replica SIGKILLed mid-relay is invisible to the client — one
         unbroken status-0 stream, bitwise the unbroken solo decode,
         zero duplicated and zero lost tokens — while the resume
-        metrics fire and the router's held snapshot is released
-        (zero live kv_snapshot census)."""
+        metrics fire, the survivor absorbs the resume join on its
+        warmed ladder (no inline compile) and the router's held
+        snapshot is released (zero live kv_snapshot census)."""
         max_new = 16
         ref16 = reference_decode(model, PROMPT, max_new,
                                  max_seq_len=32).tolist()
@@ -568,6 +579,8 @@ class TestRouterFailover:
 
         try:
             wait_routable(registry, 2)
+            warm = {rid: inline_compiles(port)
+                    for rid, (_, port) in procs.items()}
             frames = stream_request(
                 router.port,
                 decode_body(PROMPT, max_new, budget_ms=2000.0),
@@ -577,6 +590,8 @@ class TestRouterFailover:
             assert status == 0, f"stream died with status {status}"
             assert tokens == ref16
             assert not snaps  # stripped: the client never opted in
+            (survivor,) = set(procs) - set(killed)
+            assert inline_compiles(procs[survivor][1]) == warm[survivor]
             after = resume_counters()
             assert after["ok"] - before["ok"] >= 1
             assert after["refused"] == before["refused"]

@@ -330,7 +330,6 @@ def spawn_phase_worker(store_dir, phase):
                DECODE_WORKER_MAX_SLOTS="4",
                DECODE_WORKER_MAX_SEQ="32",
                DECODE_WORKER_MAX_PROMPT="8",
-               DECODE_WORKER_WARM="1",
                DECODE_WORKER_PHASE=phase,
                PADDLE_TPU_ARTIFACT_DIR=store_dir)
     env.pop("PADDLE_TPU_SERVING_QUANT", None)

@@ -510,6 +510,9 @@ class TestElasticPodE2E:
         assert rc == 0
         final = json.load(open(os.path.join(rep2, "rank-0.json")))
         assert final["completed"] and final["final_step"] == 12
+        # the resumed pod trains only what the consensus step left:
+        # a useful step is counted once across the preemption
+        assert final["goodput"]["steps"] == 12 - consensus
 
         # bit-identity across the 4 -> 2 reshard: assemble both
         # checkpoints (pure numpy) and compare every leaf

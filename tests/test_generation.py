@@ -124,10 +124,9 @@ class TestSamplingOps:
 
 
 class TestBf16Decode:
-    """The decode roofline bench (BENCH_MODEL=decode-roofline) casts
-    the model to bf16 serving precision before the cached generate —
-    pin that path on CPU so a dtype bug fails here, not on chip
-    time."""
+    """``m.to(dtype="bfloat16")`` is the serving precision for the
+    cached generate — pin that path on CPU so a dtype bug fails here,
+    not on chip time."""
 
     def test_bf16_cached_decode_runs_and_is_deterministic(self):
         paddle.seed(5)

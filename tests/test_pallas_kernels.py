@@ -184,7 +184,7 @@ def test_flash_bfloat16():
 def test_flash_multiblock_long_seq(causal):
     """S=512 = 4 q-blocks x 4 k-blocks of 128: the multi-block
     accumulation path (online softmax across k blocks, dq/dkv loops)
-    that the seq-4k flash bench runs — the tests above stay within one
+    that long sequences run on the chip — the tests above stay within one
     block and would miss cross-block bugs."""
     rng = np.random.RandomState(7)
     B, H, S, D = 1, 1, 512, 16
@@ -244,8 +244,8 @@ def test_flash_fully_masked_rows_zero():
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_head_dim_128(causal):
     """head_dim 128 = the Llama attention shape (two full lane groups in
-    the d dimension; every other test uses d <= 64). The llama_2048 and
-    flash d128 benches run this config on the TPU — a wrong result here
+    the d dimension; every other test uses d <= 64). chip_smoke.py and
+    the OLMoE cell run this config on the TPU — a wrong result here
     must fail in-suite (Mosaic lowering itself is chip_smoke phase 3)."""
     rng = np.random.RandomState(3)
     B, H, S, D = 1, 2, 512, 128

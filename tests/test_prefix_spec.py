@@ -240,6 +240,29 @@ class TestPrefixEngine:
             assert st["prefix"]["store_hits"] >= 1
             assert st["prefix_fill_steps"] >= 1  # the finishing step
 
+    def test_fresh_replica_on_both_stores_compiles_nothing(
+            self, tmp_path):
+        """Warm-prefix inheritance composed with the artifact store: a
+        fresh replica sharing both serves a cached prefix with zero
+        prefill programs AND zero inline compiles."""
+        from paddle_tpu.serialize.artifact_store import ArtifactStore
+
+        store = ArtifactStore(str(tmp_path / "store"))
+        d = str(tmp_path / "prefixes")
+        refs = {}
+        for name in ("pub", "fresh"):
+            m = toy_decode_model(hidden=HID, vocab=VOCAB, seed=0)
+            with make_engine(m, prefix_dir=d, store=store,
+                             name=f"both-{name}") as eng:
+                eng.warmup()
+                refs[name] = eng.generate(PREFIX, max_new_tokens=5,
+                                          timeout=60).tolist()
+                st = eng.stats()
+        assert refs["fresh"] == refs["pub"]
+        assert st["prefills"] == 0 and st["compiles"] == 0, st["programs"]
+        assert st["store_loads"] > 0
+        assert st["prefix"]["store_hits"] >= 1
+
     def test_restart_sweep_never_double_frees_shared_pages(
             self, model, traced_resources):
         """A watchdog restart's slot sweep DECREMENTS shared pages
@@ -287,6 +310,50 @@ class TestSpeculative:
             st = eng.stats()["spec"]
             assert st["iterations"] >= 1 and st["verify_steps"] >= 1
             assert st["accepted"] >= 1, "anchored draft never accepted"
+
+    def test_verify_rung_is_one_batched_program(self):
+        """The compile ledger's witness of batched verify: warmup
+        compiles each verify rung exactly once, its dot count is
+        spec_k x a step's (k positions fused into one dispatch, not k
+        dispatches), and mixed speculative / plain / shared-prefix
+        traffic inside the warmed ladder compiles nothing more."""
+        from paddle_tpu.obs.ledger import LEDGER
+
+        spec_k = 4
+        LEDGER.reset()
+        with make_engine(spec_model(), spec_k=spec_k,
+                         max_prompt_len=PAGE) as eng:
+            eng.warmup()
+            warm = eng.stats()["compiles"]
+            dots = {"verify": set(), "step": set()}
+            rungs = {}
+            for ev in LEDGER.events("decode/"):
+                name = ev["key"].split("/", 1)[1]
+                for phase in dots:
+                    if name.startswith(phase):
+                        dots[phase].add(ev["op_counts"].get("dot", 0))
+                if name.startswith("verify"):
+                    rungs[name] = rungs.get(name, 0) + 1
+            assert rungs and set(rungs.values()) == {1}, rungs
+            # target and draft toys share the per-position op
+            # structure, so the unroll ratio is exact
+            (verify_dots,), (step_dots,) = dots["verify"], dots["step"]
+            assert verify_dots == spec_k * step_dots
+            shared = PREFIX[:PAGE]
+            eng.generate(shared, max_new_tokens=2, timeout=60)
+            reqs = [eng.submit(shared, max_new_tokens=12,
+                               speculative=True),
+                    eng.submit(shared, max_new_tokens=6),
+                    eng.submit(np.array([4, 5], np.int32),
+                               max_new_tokens=4),
+                    eng.submit(shared, max_new_tokens=9,
+                               speculative=True)]
+            for r in reqs:
+                r.result(timeout=60)
+            st = eng.stats()
+            assert st["compiles"] == warm
+            assert st["prefix"]["hits"] >= 1
+            assert st["spec"]["iterations"] >= 1
 
     def test_spec_disabled_without_draft_or_k(self, model):
         """No draft companion or k < 2 -> speculation quietly off;

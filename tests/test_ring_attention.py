@@ -175,7 +175,7 @@ class TestLongContext:
     def test_ring_llama_head_dim_128(self, sp_mesh):
         """The Llama attention width (head_dim 128): the sp-axis hybrid
         runs ring attention over shards whose inner mha uses two full
-        lane groups in d — the same shape the llama_2048 bench drives
+        lane groups in d — the shape chip_smoke.py's Llama step drives
         single-chip. Must match the dense oracle."""
         rng = np.random.RandomState(9)
         B, H, S, D = 1, 2, 1024, 128

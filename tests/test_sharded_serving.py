@@ -267,6 +267,9 @@ class TestShardedContract:
         assert contract["meshes"]["tp2"]["ledger_mesh_tags"] == ["tp2"]
         assert contract["meshes"]["fsdp2xtp2"]["ledger_mesh_tags"] == \
             ["fsdp2xtp2"]
+        # the sharding reached the HLO: the programs hold collectives
+        for mesh in ("tp2", "fsdp2xtp2"):
+            assert contract["meshes"][mesh]["ledger_collectives"] > 0
 
     def test_metrics_carry_mesh_const_label(self, contract):
         lines = contract["exposition_mesh_lines"]
@@ -309,6 +312,17 @@ class TestShardedDecode:
         assert record["solo_vs_batch_bitwise"]
         assert record["i64_echo"]
         assert record["stats_mesh"] == "tp2"
+
+    def test_tokens_agree_with_single_chip(self, record):
+        """Sharded logits sit within the documented tolerance of the
+        single-chip ones; on this fixed toy the greedy argmax chain is
+        the same, so the tokens agree exactly."""
+        from decode_worker import reference_decode, toy_decode_model
+
+        single = reference_decode(
+            toy_decode_model(hidden=32, vocab=64, seed=0),
+            np.array([3, 1, 4, 1, 5], np.int32), 12, max_seq_len=32)
+        assert record["tokens"][0] == single.tolist()
 
     def test_decode_ladder_rewarms_from_store(self, record):
         st = record["store"]

@@ -1,13 +1,12 @@
 #!/usr/bin/env python
 """CI gate: tracelint + suppression audit + tier-1 pytest (+ chaos,
-+ serving, + perfproxy), one exit status.
++ serving), one exit status.
 
 Usage:
     python tools/ci_gate.py [--paths paddle_tpu]
         [--skip-tests] [--pytest-args "tests/ -q -m 'not slow'"]
         [--disable TPU005,...] [--chaos] [--serving] [--serving-chaos]
         [--elastic] [--artifacts] [--fleet] [--decode] [--disagg]
-        [--perfproxy]
         [--concurrency] [--protocol] [--protocol-impl NAME=PATH]
         [--resources]
         [--clean-paths paddle_tpu/resilience paddle_tpu/inference
@@ -23,84 +22,74 @@ tier-1 pytest command (ROADMAP.md) — ``--skip-tests`` elides it,
 ``--pytest-args`` overrides the selection. With the default selection
 the stage diffs the observed failure set against the committed
 ``KNOWN_FAILURES.json``: a failure NOT on the list fails the gate even
-when the total count matches HEAD's, and a listed test that passes
-also fails the gate until it is removed from the list (fixes are
-recorded, never silently absorbed). ``--chaos`` adds a fourth
-stage running the fault-injection suite (``-m chaos``) on its own, so
-recovery paths are exercised and reported separately from the
+when the total count matches HEAD's, and a listed test that passes also
+fails the gate until it is removed from the list (fixes are recorded,
+never silently absorbed); the file's ``tier1_flaky`` entries (each with
+the rate seen and the cause) may fail or pass. ``--chaos`` adds a
+fourth stage running the fault-injection suite (``-m chaos``) on its
+own, so recovery paths are exercised and reported separately from the
 functional tests. ``--serving`` adds a stage running the
-dynamic-batching serving suite (``-m serving``) — including its
-slow-marked cases like the serving bench contract that tier-1's
-``not slow`` filter skips. ``--serving-chaos`` adds a stage running the
-serving fault-injection suite (``-m 'chaos and serving'``: scheduler
-death, poisoned-bucket quarantine, deadlines, hot reload) so the
-self-healing invariants gate releases on their own line. ``--elastic``
-adds a stage running the elastic pod-scale training suite
-(``-m elastic``: multi-process preemption consensus, reshard-on-resume,
-straggler detection, and the goodput bench contract — subprocess pods,
-so it owns its own budget line). ``--artifacts`` adds a stage running
-the compiled-artifact-store suite (``-m artifacts``: bit-flip /
-torn-publish / version-skew chaos, multi-process single-flight warmup
-races, and the coldstart bench contract), excluded from tier-1 by the
-same compositional double-run guard as serving/elastic. ``--fleet``
-adds a stage running the fleet-tier suite (``-m fleet``: router WFQ
-fairness / eject-probe-readmit / retry-on-different-replica /
-drain-zero-drops units, the chaos-kill multi-replica e2e, and the
-``bench.py fleet`` goodput + SLO-isolation contract), with the same
-compositional tier-1 exclusion. ``--decode`` adds a stage running the
-continuous-batching decode suite plus the quantized-serving suite
-(``-m 'decode or quant or prefix'``: bitwise solo-vs-batch equivalence across
+dynamic-batching serving suite (``-m serving``) — including the
+slow-marked cases that tier-1's ``not slow`` filter skips.
+``--serving-chaos`` adds a stage running the serving fault-injection
+suite (``-m 'chaos and serving'``: scheduler death, poisoned-bucket
+quarantine, deadlines, hot reload) so the self-healing invariants gate
+releases on their own line. ``--elastic`` adds a stage running the
+elastic pod-scale training suite (``-m elastic``: multi-process
+preemption consensus, reshard-on-resume, straggler detection —
+subprocess pods, so it owns its own budget line). ``--artifacts`` adds
+a stage running the compiled-artifact-store suite (``-m artifacts``:
+bit-flip / torn-publish / version-skew chaos, multi-process
+single-flight warmup races), excluded from tier-1 by the same
+compositional double-run guard as serving/elastic. ``--fleet`` adds a
+stage running the fleet-tier suite (``-m fleet``: router WFQ fairness /
+eject-probe-readmit / retry-on-different-replica / drain-zero-drops
+units, the chaos-kill multi-replica e2e), with the same compositional
+tier-1 exclusion. ``--decode`` adds a stage running the
+continuous-batching decode suite plus the quantized-serving suite (``-m
+'decode or quant or prefix'``: bitwise solo-vs-batch equivalence across
 join/leave events and every wire dtype, per-token SLO enforcement,
-streaming-wire + router-relay tests, the slot-purge chaos audit, the
-slow ``bench.py decode`` storm contract, and the ISSUE 13 quant ladder
-— per-channel axis audit, w8/w8a8/bf16w export + engine + artifact-key
-contracts, ``decode --quant`` and quant-coldstart bench contracts),
-again with the compositional tier-1 double-run exclusion of BOTH
-markers. ``--sharded`` adds a stage running the sharded multi-chip
-serving suite (``-m sharded``: per-(bucket, mesh) pjit-program
-equivalence at engine AND wire level per wire dtype, mesh-keyed
-artifact-store round trips with clean skew misses, decode
-solo-vs-batch per mesh, the multi-process gloo mesh over the PR 9
-launcher, mesh fail-fasts, and the ``bench.py sharded`` contract),
-with the same compositional tier-1 exclusion — and when ``--fleet``
-runs too, the fleet stage narrows to ``fleet and not sharded`` so the
-dual-marked router-relay case runs once. ``--disagg`` adds a stage
-running the disaggregated prefill/decode serving suite (``-m disagg``:
-phase-pool routing + handoff bitwise equivalence, prefill-death retry
-and decode-death resume chaos, pool-at-zero degradation, per-pool
-autoscaler isolation, handoff metrics exposition, and the slow
-``bench.py disagg`` storm contract), with the same compositional
-tier-1 double-run exclusion. ``--perfproxy``
-adds a stage running ``bench.py perfproxy`` on CPU against the
-committed PERFPROXY_BASELINE.json — compile counts, HLO op counts, and
-cost-analysis FLOPs must match: exact, repeatable counts that catch a
-structural change before any chip run does. ``--concurrency``
-adds a stage that (a) runs the TPU3xx concurrency passes
-(``tracelint.py --concurrency``) STRICTLY — any unsuppressed TPU3xx
-finding, warning or error, fails — and (b) runs the locktrace smoke:
-``tests/test_locktrace.py`` under ``PADDLE_TPU_LOCKTRACE=1``, which
-drives a real BatchingEngine (and a chaos scenario) with the runtime
-lock-order sanitizer recording every acquisition, so the static lock
-model is verified against observed behaviour. ``--protocol`` adds a
-stage running the TPU4xx wire-contract passes
-(``tracelint.py --protocol-only``) STRICTLY — any unsuppressed TPU4xx
-finding fails: every implementation of the serving wire protocol
+streaming-wire + router-relay tests, the slot-purge chaos audit, and
+the ISSUE 13 quant ladder — per-channel axis audit, w8/w8a8/bf16w
+export + engine + artifact-key contracts), again with the compositional
+tier-1 double-run exclusion of BOTH markers. ``--sharded`` adds a stage
+running the sharded multi-chip serving suite (``-m sharded``:
+per-(bucket, mesh) pjit-program equivalence at engine AND wire level
+per wire dtype, mesh-keyed artifact-store round trips with clean skew
+misses, decode solo-vs-batch per mesh, the multi-process gloo mesh over
+the PR 9 launcher, mesh fail-fasts), with the same compositional tier-1
+exclusion — and when ``--fleet`` runs too, the fleet stage narrows to
+``fleet and not sharded`` so the dual-marked router-relay case runs
+once. ``--disagg`` adds a stage running the disaggregated
+prefill/decode serving suite (``-m disagg``: phase-pool routing +
+handoff bitwise equivalence, prefill-death retry and decode-death
+resume chaos, pool-at-zero degradation, per-pool autoscaler isolation,
+handoff metrics exposition), with the same compositional tier-1
+double-run exclusion. ``--concurrency`` adds a stage that (a) runs the
+TPU3xx concurrency passes (``tracelint.py --concurrency``) STRICTLY —
+any unsuppressed TPU3xx finding, warning or error, fails — and (b) runs
+the locktrace smoke: ``tests/test_locktrace.py`` under
+``PADDLE_TPU_LOCKTRACE=1``, which drives a real BatchingEngine (and a
+chaos scenario) with the runtime lock-order sanitizer recording every
+acquisition, so the static lock model is verified against observed
+behaviour. ``--protocol`` adds a stage running the TPU4xx wire-contract
+passes (``tracelint.py --protocol-only``) STRICTLY — any unsuppressed
+TPU4xx finding fails: every implementation of the serving wire protocol
 (Python server stack, Go/R/C clients) is extracted and diffed against
 ``paddle_tpu/inference/wire_spec.py``, and the ok-or-retryable error
 taxonomy is statically verified over the Python serving stack, so the
-protocol can never drift one language at a time
-(``--protocol-impl name=path`` forwards an implementation override to
-tracelint — the planted-drift gate tests run the stage against mutated
-fixture copies this way). ``--resources`` adds a stage that (a) runs
-the TPU5xx resource-lifecycle passes (``tracelint.py
---resources-only``) STRICTLY — any unsuppressed TPU50x finding fails:
-every declared acquire (KV slot, pooled router socket, compile
-lockfile, scratch dir, thread, breaker trip, signal handler) must have
-an owner that releases it on every path — and (b) runs the restrace
-smoke: the decode/fleet/artifact suites under ``PADDLE_TPU_RESTRACE=1
-PADDLE_TPU_RESTRACE_RAISE=1``, so the declared lifecycle sites are
-leak-checked at runtime and a suite ending with a nonzero live-handle
-census fails. Exit 1 when any phase
+protocol can never drift one language at a time (``--protocol-impl
+name=path`` forwards an implementation override to tracelint — the
+planted-drift gate tests run the stage against mutated fixture copies
+this way). ``--resources`` adds a stage that (a) runs the TPU5xx
+resource-lifecycle passes (``tracelint.py --resources-only``) STRICTLY
+— any unsuppressed TPU50x finding fails: every declared acquire (KV
+slot, pooled router socket, compile lockfile, scratch dir, thread,
+breaker trip, signal handler) must have an owner that releases it on
+every path — and (b) runs the restrace smoke: the decode/fleet/artifact
+suites under ``PADDLE_TPU_RESTRACE=1 PADDLE_TPU_RESTRACE_RAISE=1``, so
+the declared lifecycle sites are leak-checked at runtime and a suite
+ending with a nonzero live-handle census fails. Exit 1 when any phase
 fails; the JSON line printed last summarises all of them for log
 scrapers (mirroring tools/check_op_benchmark_result.py's contract).
 """
@@ -119,46 +108,42 @@ TRACELINT = os.path.join(REPO, "tools", "tracelint.py")
 
 DEFAULT_PYTEST_ARGS = ("tests/ -q -m 'not slow' "
                        "--continue-on-collection-errors -p no:cacheprovider")
-# 'and not serving': the serving fault-injection suite (incl. slow
-# subprocess goodput benches) belongs to the --serving-chaos stage —
+# 'and not serving': the serving fault-injection suite (incl. its slow
+# subprocess cases) belongs to the --serving-chaos stage —
 # plain --chaos must not balloon by minutes because PR 5 added tests
 CHAOS_PYTEST_ARGS = "tests/ -q -m 'chaos and not serving' -p no:cacheprovider"
 SERVING_PYTEST_ARGS = "tests/ -q -m serving -p no:cacheprovider"
 SERVING_CHAOS_PYTEST_ARGS = ("tests/ -q -m 'chaos and serving' "
                              "-p no:cacheprovider")
 # the elastic pod suite: multi-process consensus/reshard/straggler e2e
-# (including its slow-marked subprocess cases and the goodput bench
-# contract) runs as its own stage
+# (including its slow-marked subprocess cases) runs as its own stage
 ELASTIC_PYTEST_ARGS = "tests/ -q -m elastic -p no:cacheprovider"
 # the artifact-store suite: chaos (bit-flip / torn publish / version
 # skew) + multi-process single-flight warmup cases, including its
-# slow-marked subprocess races and the coldstart bench contract
+# slow-marked subprocess races
 ARTIFACTS_PYTEST_ARGS = "tests/ -q -m artifacts -p no:cacheprovider"
 # the fleet-tier suite: router/registry units (WFQ fairness,
 # eject/readmit, retry-on-different-replica, drain-zero-drops) plus
-# the slow chaos-kill e2e and the `bench.py fleet` contract
+# the slow chaos-kill e2e
 FLEET_PYTEST_ARGS = "tests/ -q -m fleet -p no:cacheprovider"
 # the continuous-batching decode suite: bitwise equivalence, per-token
-# SLOs, streaming wire/router relay, slot-purge chaos, plus the slow
-# `bench.py decode` storm contract. The quantized-serving suite
-# (`quant` marker: per-channel axis audit, w8/w8a8/bf16w export +
-# engine + store contracts, the `decode --quant` and quant-coldstart
-# bench contracts) rides in this stage — quantization is the decode
-# path's bandwidth lever, and a separate stage would re-pay the same
-# model/ladder setup
+# SLOs, streaming wire/router relay, slot-purge chaos. The
+# quantized-serving suite (`quant` marker: per-channel axis audit,
+# w8/w8a8/bf16w export + engine + store contracts) rides in this stage
+# — quantization is the decode path's bandwidth lever, and a separate
+# stage would re-pay the same model/ladder setup
 DECODE_PYTEST_ARGS = ("tests/ -q -m 'decode or quant or prefix' "
                       "-p no:cacheprovider")
 # the sharded multi-chip serving suite: per-(bucket, mesh) engine/wire
 # equivalence, mesh-keyed store round trips + skew misses, the
-# multi-process gloo mesh via the PR 9 launcher, mesh fail-fasts, and
-# the `bench.py sharded` contract — subprocess-heavy (sharded engines
+# multi-process gloo mesh via the PR 9 launcher, mesh fail-fasts —
+# subprocess-heavy (sharded engines
 # need more devices than the tier-1 process has), so it owns a stage
 SHARDED_PYTEST_ARGS = "tests/ -q -m sharded -p no:cacheprovider"
 # the disaggregated prefill/decode serving suite: phase-pool routing,
 # handoff retry + pool-loss degradation chaos, per-pool autoscaler
-# isolation, handoff metrics exposition, and the `bench.py disagg`
-# contract — subprocess-heavy (one replica process per pool member),
-# so it owns a stage
+# isolation, handoff metrics exposition — subprocess-heavy (one replica
+# process per pool member), so it owns a stage
 DISAGG_PYTEST_ARGS = "tests/ -q -m disagg -p no:cacheprovider"
 # subsystems that must stay suppression-free: resilience (PR 2), the
 # serving stack (PRs 4-5), the telemetry layer (PR 7), and the analyzer
@@ -336,35 +321,31 @@ def run_pytest_capturing_failures(pytest_args):
     return proc.wait(), sorted(failed)
 
 
-def load_known_failures(path=KNOWN_FAILURES_FILE):
+def load_known_failures(path=KNOWN_FAILURES_FILE, key="tier1"):
     """The committed tier-1 failure list, or None when no file exists
-    (the diff is then skipped and plain rc==0 gates the stage)."""
+    (the diff is then skipped and plain rc==0 gates the stage).
+    ``key="tier1_flaky"`` reads the tests that fail only some of the
+    time: entries are ``{"test", "rate", "cause"}`` objects."""
     try:
         with open(path) as f:
             data = json.load(f)
     except (OSError, json.JSONDecodeError):
         return None
-    known = data.get("tier1")
+    known = data.get(key)
     if not isinstance(known, list):
         return None
-    return sorted(str(k) for k in known)
+    return sorted(str(k["test"] if isinstance(k, dict) else k)
+                  for k in known)
 
 
-def diff_known_failures(failed, known):
+def diff_known_failures(failed, known, flaky=()):
     """-> (new, fixed): failures not in the committed list, and
     committed entries that did not fail (each non-empty list fails the
     gate — the first is a regression, the second a stale KNOWN_FAILURES
-    entry that must be removed so the fix is recorded)."""
-    failed, known = set(failed), set(known)
+    entry that must be removed so the fix is recorded). A ``flaky``
+    test is neither: it may fail or pass."""
+    failed, known = set(failed) - set(flaky), set(known)
     return sorted(failed - known), sorted(known - failed)
-
-
-def run_perfproxy():
-    """bench.py perfproxy vs the committed baseline (always CPU)."""
-    cmd = [sys.executable, os.path.join(REPO, "bench.py"), "perfproxy"]
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(cmd, cwd=REPO, env=env)
-    return proc.returncode
 
 
 def run_concurrency_lint(paths, disable=""):
@@ -505,50 +486,43 @@ def main(argv=None):
                     help="also run the elastic pod-scale training suite "
                          "(-m elastic: multi-process preemption "
                          "consensus, reshard-on-resume, straggler "
-                         "detection, goodput bench contract)")
+                         "detection)")
     ap.add_argument("--elastic-args", default=ELASTIC_PYTEST_ARGS)
     ap.add_argument("--artifacts", action="store_true",
                     help="also run the compiled-artifact-store suite "
                          "(-m artifacts: corruption/torn-publish/"
                          "version-skew chaos, multi-process single-"
-                         "flight warmup, coldstart bench contract)")
+                         "flight warmup)")
     ap.add_argument("--artifacts-args", default=ARTIFACTS_PYTEST_ARGS)
     ap.add_argument("--fleet", action="store_true",
                     help="also run the fleet-tier suite (-m fleet: "
                          "router WFQ/eject/drain units, chaos-kill "
-                         "multi-replica e2e, fleet bench contract)")
+                         "multi-replica e2e)")
     ap.add_argument("--fleet-args", default=FLEET_PYTEST_ARGS)
     ap.add_argument("--decode", action="store_true",
                     help="also run the continuous-batching decode + "
                          "quantized-serving suites (-m 'decode or "
                          "quant': bitwise solo-vs-batch equivalence, "
                          "per-token SLOs, streaming wire/router relay, "
-                         "slot-purge chaos, decode bench contract, "
-                         "quant axis audit + export/engine/store "
-                         "contracts + quant bench contracts)")
+                         "slot-purge chaos, quant axis audit + "
+                         "export/engine/store contracts)")
     ap.add_argument("--decode-args", default=DECODE_PYTEST_ARGS)
     ap.add_argument("--sharded", action="store_true",
                     help="also run the sharded multi-chip serving "
                          "suite (-m sharded: per-(bucket, mesh) "
                          "engine/wire equivalence, mesh-keyed store "
-                         "round trips, multi-process gloo mesh, "
-                         "sharded bench contract)")
+                         "round trips, multi-process gloo mesh)")
     ap.add_argument("--sharded-args", default=SHARDED_PYTEST_ARGS)
     ap.add_argument("--disagg", action="store_true",
                     help="also run the disaggregated prefill/decode "
                          "serving suite (-m disagg: phase-pool routing "
                          "+ handoff equivalence, handoff-retry and "
                          "pool-loss chaos, per-pool autoscaler "
-                         "isolation, handoff metrics, disagg bench "
-                         "contract)")
+                         "isolation, handoff metrics)")
     ap.add_argument("--disagg-args", default=DISAGG_PYTEST_ARGS)
     ap.add_argument("--known-failures", default=KNOWN_FAILURES_FILE,
                     help="JSON file naming the committed pre-existing "
                          "tier-1 failures the stage diffs against")
-    ap.add_argument("--perfproxy", action="store_true",
-                    help="also run bench.py perfproxy (CPU compile-"
-                         "ledger regression check vs the committed "
-                         "PERFPROXY_BASELINE.json)")
     ap.add_argument("--concurrency", action="store_true",
                     help="also run the TPU3xx concurrency passes "
                          "strictly (zero unsuppressed findings) plus "
@@ -626,7 +600,10 @@ def main(argv=None):
             # diff the observed failure set against the committed list:
             # exact match (in both directions) is the only green state
             rc, failed = run_pytest_capturing_failures(pytest_args)
-            tier1_new, tier1_fixed = diff_known_failures(failed, known)
+            tier1_new, tier1_fixed = diff_known_failures(
+                failed, known,
+                load_known_failures(ns.known_failures, "tier1_flaky")
+                or ())
             for t in tier1_new:
                 print(f"tier1: NEW failure (not in KNOWN_FAILURES.json): "
                       f"{t}", file=sys.stderr)
@@ -653,7 +630,6 @@ def main(argv=None):
         serving_args = ns.serving_args
         if ns.serving_chaos and serving_args == SERVING_PYTEST_ARGS:
             # same guard: the serving-chaos stage owns chaos+serving
-            # (including the slow subprocess goodput bench)
             serving_args = serving_args.replace(
                 "-m serving", "-m 'serving and not chaos'")
         serving_ok = run_pytest(serving_args) == 0
@@ -692,10 +668,6 @@ def main(argv=None):
     if ns.disagg:
         disagg_ok = run_pytest(ns.disagg_args) == 0
 
-    perfproxy_ok = True
-    if ns.perfproxy:
-        perfproxy_ok = run_perfproxy() == 0
-
     concurrency_ok = True
     conc_report = {}
     if ns.concurrency:
@@ -730,7 +702,6 @@ def main(argv=None):
                  + ("+decode" if ns.decode else "")
                  + ("+sharded" if ns.sharded else "")
                  + ("+disagg" if ns.disagg else "")
-                 + ("+perfproxy" if ns.perfproxy else "")
                  + ("+concurrency" if ns.concurrency else "")
                  + ("+protocol" if ns.protocol else "")
                  + ("+resources" if ns.resources else "")),
@@ -763,8 +734,6 @@ def main(argv=None):
         "sharded_run": bool(ns.sharded),
         "disagg_ok": disagg_ok,
         "disagg_run": bool(ns.disagg),
-        "perfproxy_ok": perfproxy_ok,
-        "perfproxy_run": bool(ns.perfproxy),
         "concurrency_ok": concurrency_ok,
         "concurrency_run": bool(ns.concurrency),
         "concurrency_tpu3xx": conc_report.get("tpu3xx", 0),
@@ -781,8 +750,8 @@ def main(argv=None):
     if not (lint_ok and audit_ok and tests_ok and chaos_ok
             and serving_ok and serving_chaos_ok and elastic_ok
             and artifacts_ok and fleet_ok and decode_ok and sharded_ok
-            and disagg_ok and perfproxy_ok and concurrency_ok
-            and protocol_ok and resources_ok):
+            and disagg_ok and concurrency_ok and protocol_ok
+            and resources_ok):
         print("ci_gate: FAILED", file=sys.stderr)
         return 1
     return 0
