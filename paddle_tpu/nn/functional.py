@@ -678,9 +678,19 @@ def batch_norm(x, running_mean, running_var, weight=None, bias=None, training=Fa
                name=None):
     """reference: operators/batch_norm_op.cc.
 
-    Eager training mode updates running stats in-place on the passed
-    Tensors (mutable-shell); the traced path uses the functional core in
-    nn.layer.norm which threads state explicitly.
+    ``_BatchNormBase.forward`` calls this in every mode, eager and traced.
+    Training mode writes the updated running stats into the passed buffer
+    Tensors: in place when eager; under a trace the values are tracers that
+    the managed trace paths thread out of the program (see below).
+
+    The batch statistics are two sums of ONE pass over ``x``, taken about
+    the running mean ``c`` (a constant per channel, under ``stop_gradient``):
+    ``mean = c + E[x-c]``, ``var = E[(x-c)^2] - E[x-c]^2``. That is the
+    variance for every ``c``; the shift keeps the subtraction from
+    cancelling (relative error of ``var`` about ``eps * (1 + (mean-c)^2 /
+    var)``, and ``c`` tracks the batch mean). Two independent reductions
+    fuse into the epilogue of the convolution that produced ``x``; the
+    two-pass ``jnp.var`` read every activation a second time.
     """
     chan_ax = 1 if data_format.startswith("NC") and x.ndim > 1 else x.ndim - 1
     axes = tuple(i for i in range(x.ndim) if i != chan_ax)
@@ -701,11 +711,16 @@ def batch_norm(x, running_mean, running_var, weight=None, bias=None, training=Fa
         return apply_op("batch_norm_infer", _bn_infer, x, running_mean, running_var,
                         weight, bias, eps=float(epsilon), chan_ax=chan_ax)
 
-    def _bn_train(x, w, b, *, eps, axes, chan_ax):
-        mean = jnp.mean(x, axis=axes)
-        var = jnp.var(x, axis=axes)
+    def _bn_train(x, w, b, rm, *, eps, axes, chan_ax):
         shape = [1] * x.ndim
         shape[chan_ax] = -1
+        c = jax.lax.stop_gradient(rm).astype(x.dtype)
+        c = jnp.where(jnp.isfinite(c), c, 0)  # a poisoned buffer stays out
+        d = x - c.reshape(shape)
+        s1 = jnp.mean(d, axis=axes)
+        s2 = jnp.mean(d * d, axis=axes)
+        mean = c + s1
+        var = jnp.maximum(s2 - s1 * s1, 0)
         inv = jax.lax.rsqrt(var.reshape(shape) + eps)
         y = (x - mean.reshape(shape)) * inv
         if w is not None:
@@ -714,7 +729,9 @@ def batch_norm(x, running_mean, running_var, weight=None, bias=None, training=Fa
             y = y + b.reshape(shape)
         return y, mean, var
 
-    y, mean, var = apply_op("batch_norm_train", _bn_train, x, weight, bias,
+    rm = (jnp.zeros((x.shape[chan_ax],), jnp.float32)
+          if running_mean is None else running_mean)
+    y, mean, var = apply_op("batch_norm_train", _bn_train, x, weight, bias, rm,
                             eps=float(epsilon), axes=axes, chan_ax=chan_ax)
     # update running stats (no grad). Under trace this writes tracers into
     # the buffer Tensors on purpose: the managed trace paths

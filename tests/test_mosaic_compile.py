@@ -3,7 +3,9 @@ v5e (no chip attached: the on-chip-measurement guide's third rehearsal), at
 the BERT cells' shape and at the largest shapes the gate admits. It proves
 compilation and which route a program gets — never a time, never numerics
 (tests/test_pallas_kernels.py holds the numerics, chip_smoke.py phase 3 runs
-the kernel on the chip).
+the kernel on the chip). Two XLA-only programs ride along, each a tripwire
+for what a jax upgrade may undo: the BERT cells' FFN (what ``F.gelu`` lowers
+to) and one ResNet-50 bottleneck (where batch norm's statistics end up).
 
 The compiles run in ONE child process (this file as a script) whose
 environment describes the topology before libtpu loads: libtpu reads it
@@ -56,6 +58,17 @@ STREAM_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
+#: a ResNet-50 bottleneck of layer 1 at the cell's rows: [256,256,56,56] in,
+#: widths 64 / 64 / 256
+BOTTLENECK = {"rows": 256, "inplanes": 256, "planes": 64, "hw": 56}
+
+
+def _hlo_bytes():
+    """tools/hlo_bytes.py (``tools`` is no package)."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import hlo_bytes
+    return hlo_bytes
 
 
 def _fusions(text):
@@ -185,6 +198,50 @@ def _child():
                             if "divide" in f["opcodes"]
                             or "select" in f["opcodes"]]}
 
+    # one ResNet-50 bottleneck, forward + backward + Momentum under amp O1
+    # through build_train_step: which fusions carry batch norm's statistics
+    from paddle_tpu import optimizer
+    from paddle_tpu.distributed import spmd
+    from paddle_tpu.vision.models.resnet import BottleneckBlock
+
+    hlo = _hlo_bytes()
+    rows, hw = BOTTLENECK["rows"], BOTTLENECK["hw"]
+    block = BottleneckBlock(BOTTLENECK["inplanes"], BOTTLENECK["planes"])
+    block.train()
+    opt = optimizer.Momentum(0.1, parameters=block.parameters())
+    one_mesh = topology.build_mesh(dp=1, devices=v5e[:1])
+    step, _ = spmd.build_train_step(
+        block, lambda o, y: jnp.mean(jnp.square(o)), opt, mesh=one_mesh,
+        amp_level="O1", donate=True)
+    repl = NamedSharding(one_mesh, P())
+
+    def like(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=repl)
+
+    params0, buffers0 = block.functional_state()
+    text = step.jitted.lower(
+        {n: like(a) for n, a in params0.items()},
+        {n: tuple(like(s) for s in opt._init_state(a))
+         for n, a in params0.items()},
+        {n: like(jnp.asarray(a)) for n, a in buffers0.items()},
+        jax.ShapeDtypeStruct((rows, BOTTLENECK["inplanes"], hw, hw),
+                             jnp.float32, sharding=repl),
+        jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=repl),
+        like(jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=repl),
+    ).compile().as_text()
+    out["bottleneck-bn"] = {"forward_convolutions": [], "statistics_passes": []}
+    for inst, operands, (direction, scope, kind) in hlo.operations(text)[0]:
+        if direction != "fwd":
+            continue
+        if kind == "convolution":
+            out["bottleneck-bn"]["forward_convolutions"].append(
+                [[dtype, list(dims)] for dtype, dims in inst["result"]])
+        elif (scope.startswith("BatchNorm") and kind == "[C]-only reduction"
+              and any(len(dims) == 4 and dims[0] == rows
+                      for _, dims in operands)):
+            out["bottleneck-bn"]["statistics_passes"].append(inst["op_name"])
+
     # through the gate, on a process with several devices: the kernel where
     # the step announced its mesh, XLA's route where a plain jit is handed
     # arrays on a mesh (the dp4 cell's reference check does that)
@@ -274,6 +331,28 @@ def test_gelu_stays_one_erf_behind_the_ffn_up_gemm(compiled):
     assert fusion["result"] == f"({wide}, {wide})"
     assert got["f32_wide_results"] == []
     assert got["erfc_expansions"] == []
+
+
+def test_batch_norm_statistics_ride_the_convolution_epilogue(compiled):
+    """One ResNet-50 bottleneck at the cell's layer-1 shapes, forward +
+    backward under amp O1: each of the three forward convolution fusions
+    carries TWO float32 ``[C]`` results beside its bf16 output (the sums of
+    ``x - c`` and of its square: ``F.batch_norm``'s one-pass statistics),
+    and no forward fusion scoped under ``BatchNorm2D`` reads a
+    ``[256,C,H,W]`` operand to return only ``[C]``-sized results — the
+    standalone variance pass of the two-pass form (53 of them, 5.69 GB a
+    step, in the whole ResNet-50 step before PR 28: PERF.md section 5). A
+    jax or XLA that stops fusing a second reduction into a convolution's
+    epilogue fails here, not on the chip."""
+    got = compiled["bottleneck-bn"]
+    rows, hw = BOTTLENECK["rows"], BOTTLENECK["hw"]
+    widths = sorted(r[0][1][0] for r in got["forward_convolutions"])
+    assert widths == [64, 64, 256]
+    for result in got["forward_convolutions"]:
+        c = result[0][1][0]
+        assert result == [["f32", [c]], ["f32", [c]],
+                          ["bf16", [rows, c, hw, hw]]]
+    assert got["statistics_passes"] == []
 
 
 def test_announced_mesh_keeps_the_kernel_under_shard_map(compiled):
