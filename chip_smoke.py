@@ -58,7 +58,10 @@ FULL = dict(
     #                                 FFN 3072, vocab 30522
     seq=128, rows_per_chip=256, max_pred=20, warmup=3, steps=10,
     serve_requests=36, serve_clients=4,
-    attn_shapes=((8, 12, 4096, 64), (4, 32, 2048, 128)),
+    # (batch, heads, seq, head width[, value width]): the last is latent
+    # attention's core at the JoyAI cell's shape, keys wider than values
+    attn_shapes=((8, 12, 4096, 64), (4, 32, 2048, 128),
+                 (1, 32, 8192, 192, 128)),
     short_shape=(256, 12, 128, 64),  # the BERT cells' attention, per chip
     llama=dict(vocab_size=32000, hidden_size=1024, num_layers=4,
                num_heads=8, intermediate_size=2816, max_seq_len=2048),
@@ -72,7 +75,7 @@ TOY = dict(
               max_position_embeddings=64),
     seq=32, rows_per_chip=4, max_pred=4, warmup=3, steps=10,
     serve_requests=12, serve_clients=2,
-    attn_shapes=((1, 2, 256, 64),),  # head dims differ for Mosaic only
+    attn_shapes=((1, 2, 256, 64), (1, 2, 256, 24, 16)),
     short_shape=(4, 2, 128, 64),
     llama=dict(vocab_size=256, hidden_size=64, num_layers=1, num_heads=2,
                intermediate_size=128, max_seq_len=256),
@@ -549,20 +552,23 @@ class Smoke:
                     dropout_p=p, is_causal=True, training=True)
                 return jnp.sum(out._value.astype(jnp.float32))
 
-            spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+            spec = jax.ShapeDtypeStruct(shape[:4], jnp.bfloat16)
+            v_spec = jax.ShapeDtypeStruct(shape[:3] + shape[-1:],
+                                          jnp.bfloat16)
             text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-                spec, spec, spec).as_text()
+                spec, spec, v_spec).as_text()
             paddle.seed(0)  # the trace drew its dropout key from the
             #                 global generator: drop the tracer it left
             return text.count("tpu_custom_call")
 
         info = {}
         for shape in self.cfg["attn_shapes"]:
-            b, h, s, d = shape
-            tag = f"b{b}h{h}s{s}d{d}"
+            b, h, s, d = shape[:4]
+            v_shape = shape[:3] + shape[-1:]
+            tag = f"b{b}h{h}s{s}d{d}" + (f"v{shape[4]}" if shape[4:] else "")
             rng = np.random.RandomState(3)
-            q, k, v = (jnp.asarray(rng.randn(*shape) * 0.5, jnp.bfloat16)
-                       for _ in range(3))
+            q, k, v = (jnp.asarray(rng.randn(*sh) * 0.5, jnp.bfloat16)
+                       for sh in (shape[:4], shape[:4], v_shape))
             rec = {}
             for p in (0.0, 0.1):
                 n_calls = mosaic_calls(shape, p)
@@ -606,8 +612,8 @@ class Smoke:
             # q = k = 0 makes every causal row uniform, v = 1 makes the
             # output the kept share of that row: out[r] = kept_r /
             # ((r + 1) * 0.9)
-            zeros = jnp.zeros(shape, jnp.bfloat16)
-            out = attention(zeros, zeros, jnp.ones(shape, jnp.bfloat16),
+            zeros = jnp.zeros(shape[:4], jnp.bfloat16)
+            out = attention(zeros, zeros, jnp.ones(v_shape, jnp.bfloat16),
                             0.1, seed=13)[0]
             row_len = np.arange(1, s + 1, dtype=np.float64)
             kept = (np.asarray(out[..., 0].astype(jnp.float32), np.float64)

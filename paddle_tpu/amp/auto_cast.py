@@ -13,7 +13,7 @@ AMP_WHITE_LIST = {
     "fused_rnn", "sdpa", "flash_attention", "short_attention", "addmm",
     # the expert gemms of the dropless MoE path, and the fused LM head +
     # loss (its logits and softmax are float32 inside)
-    "moe_experts_sorted", "linear_cross_entropy",
+    "moe_experts_sorted", "moe_experts_held", "linear_cross_entropy",
 }
 
 # numerically-sensitive ops — force fp32.

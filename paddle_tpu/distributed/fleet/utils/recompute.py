@@ -3,7 +3,10 @@ RecomputeFunction; static analog backward.py:729
 _append_backward_ops_with_checkpoints_).
 
 TPU-native: in traced mode this is literally ``jax.checkpoint`` — XLA
-rematerialises the segment in backward. In eager mode recompute is the
+rematerialises the segment in backward. Where ``function`` is a Layer (or
+a Layer's bound method), the buffers it rewrites and the auxiliary losses
+it emits leave the checkpointed function as outputs and are put back where
+an unwrapped call would have left them. In eager mode recompute is the
 identity: the tape's per-op cached vjps already recompute each op's
 forward inside the backward (inherent rematerialisation), and wrapping
 the segment as one opaque op would hide captured Layer parameters from
@@ -18,15 +21,41 @@ from ....core.tensor import Tensor
 def recompute(function, *args, **kwargs):
     preserve_rng_state = kwargs.pop("preserve_rng_state", True)
     if dispatch.in_trace():
+        from ....nn import Layer
+        from ....nn.aux_loss import (collect_aux_losses, forward_aux_loss,
+                                     total_aux_loss)
+
         arrs = [a._value if isinstance(a, Tensor) else a for a in args]
+        owner = getattr(function, "__self__", function)
+        layer = owner if isinstance(owner, Layer) else None
 
         def pure(*xs):
-            outs = function(*[Tensor(x, stop_gradient=True) for x in xs], **kwargs)
+            # what a segment leaves behind besides its outputs has to leave
+            # the checkpointed function as an output too, or a tracer of
+            # this inner trace stays on the layer: the buffers it rewrote
+            # (batch-norm statistics, a router's selection bias) and the
+            # auxiliary losses it emitted
+            before = layer.functional_state()[1] if layer else {}
+            with collect_aux_losses() as auxes:
+                outs = function(*[Tensor(x, stop_gradient=True) for x in xs],
+                                **kwargs)
             if isinstance(outs, (tuple, list)):
-                return tuple(o._value if isinstance(o, Tensor) else o for o in outs)
-            return outs._value if isinstance(outs, Tensor) else outs
+                outs = tuple(o._value if isinstance(o, Tensor) else o
+                             for o in outs)
+            elif isinstance(outs, Tensor):
+                outs = outs._value
+            after = layer.functional_state()[1] if layer else {}
+            rewritten = {n: v for n, v in after.items()
+                         if v is not before[n]}
+            if rewritten:
+                layer.load_functional_state(None, before)
+            return outs, rewritten, (total_aux_loss(auxes) if auxes else None)
 
-        out = jax.checkpoint(pure)(*arrs)
+        out, rewritten, aux = jax.checkpoint(pure)(*arrs)
+        if rewritten:
+            layer.load_functional_state(None, rewritten)
+        if aux is not None:
+            forward_aux_loss(aux)
         if isinstance(out, tuple):
             return tuple(Tensor(o) for o in out)
         return Tensor(out)

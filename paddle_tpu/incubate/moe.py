@@ -4,7 +4,7 @@ with the experts sharded over the 'ep' mesh axis.
 The reference (~v2.1) predates its MoE work, so this is green-field
 TPU-native design (like ring attention). Expert weights are stacked
 [E, ...] (``w_up``, ``w_down``, and ``w_gate`` for SwiGLU experts) and
-carry ``mp_spec = P('ep')``. One API, four paths; ``dispatch_mode='auto'``
+carry ``mp_spec = P('ep')``. One API, five paths; ``dispatch_mode='auto'``
 (the default) picks from what the layer can observe — the number of
 experts and whether the program's mesh shards them:
 
@@ -41,10 +41,27 @@ experts and whether the program's mesh shards them:
   It requires a live global mesh with ep > 1, batch divisible by ep, and E
   divisible by ep.
 
+- ``sorted_held`` (what a layer built with ``held=(first, count)`` runs):
+  one chip's share of an expert-parallel deployment, without the exchange.
+  The router scores and picks over ALL experts; only the (token, choice)
+  pairs whose expert is one of the ``count`` held from ``first`` on are
+  sorted, gathered and multiplied, into a row buffer of
+  ``held_rows_factor`` times the mean number of such pairs (rounded up to
+  the row tile); the layer returns the held experts' part of the sum
+  (plus the shared expert). It is exact whenever the pairs fit, and the
+  pairs that do not are dropped AND counted in the ``held_overflow`` buffer,
+  which leaves a train step with the other buffers. Nothing stands in for
+  the absent experts or their traffic.
+
 The layer's arithmetic is the constructor's: ``activation`` ('gelu': two
 matrices; 'swiglu': ``w_down(silu(x w_gate) * (x w_up))``), ``gate_bias``,
-``norm_topk_prob`` (renormalise the k weights or keep the softmax's), and
-two auxiliary losses through ``nn.aux_loss.emit_aux_loss``: load balancing
+``norm_topk_prob`` (renormalise the k weights or keep the router's own),
+``scoring`` ('softmax'; 'sigmoid' with ``select_bias``, a buffer added to
+the scores for the CHOICE only and moved by ``bias_update_speed`` against
+each training step's loads — DeepSeek-V3's auxiliary-loss-free balancing —
+and ``routed_scale``), ``shared_width`` (a SwiGLU every token takes, added
+to the routed sum; sigmoid scoring and the shared expert run on the two
+sorted paths), and two auxiliary losses through ``nn.aux_loss.emit_aux_loss``: load balancing
 times ``aux_weight`` and the router z-loss ``mean_t logsumexp(r_t)^2`` times
 ``z_loss_weight``. The sorted path's load-balancing term is the
 Switch / HF form over all k choices, ``E * sum_e (n_e / N) * mean_t
@@ -61,13 +78,14 @@ import functools
 
 from .. import nn
 from ..core.dispatch import apply_op
+from ..core.tensor import Tensor
 from ..obs import metrics as obs_metrics
 from jax import shard_map
 
 _DISPATCH_TOTAL = obs_metrics.counter(
     "paddle_tpu_moe_dispatch_total",
-    "expert-layer calls by the path taken (sorted | capacity | dense | "
-    "alltoall); under jit one count per traced layer call",
+    "expert-layer calls by the path taken (sorted | sorted_held | capacity "
+    "| dense | alltoall); under jit one count per traced layer call",
     labelnames=("path",))
 
 #: ``auto`` runs every expert on every token below this many experts
@@ -134,11 +152,18 @@ _rows_to_token_order.defvjp(_rows_to_token_order_fwd,
                             _rows_to_token_order_bwd)
 
 
-def _route(x, w_router, b_router, *, top_k, renorm):
+def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
+           scoring="softmax", routed_scale=1.0):
     """The router in float32: [B, S, H] -> the k weights [N, k] and expert
     ids [N, k] of every token, the load-balancing term (Switch / HF form
-    over all k choices) and the z-loss. The weights are the softmax's own
-    unless ``renorm``."""
+    over all k choices) and the z-loss.
+    ``scoring`` 'softmax': the weights are the softmax's own unless
+    ``renorm``. 'sigmoid' (DeepSeek-V3's ``noaux_tc``): s = sigmoid(logits);
+    the choice is top-k of s + ``select_bias``, the weights are s at the
+    chosen experts (the bias moves the choice and never the weight),
+    divided by their sum if ``renorm``, times ``routed_scale``; the
+    balancing term takes s normalised over the experts, and there is no
+    z-loss."""
     with jax.named_scope("moe.route"):
         xf = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
         # float32 in earnest: a TPU's default precision would multiply in
@@ -149,15 +174,33 @@ def _route(x, w_router, b_router, *, top_k, renorm):
         if b_router is not None:
             logits = logits + b_router.astype(jnp.float32)
         n, e = logits.shape
-        probs = jax.nn.softmax(logits, axis=-1)
-        topv, topi = jax.lax.top_k(probs, top_k)
-        if renorm:
-            topv = topv / jnp.maximum(
-                jnp.sum(topv, axis=-1, keepdims=True), 1e-9)
+        if scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            biased = (scores if select_bias is None else
+                      scores + select_bias.astype(jnp.float32))
+            topi = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)[1]
+            # the scores at the chosen experts as a masked sum, exact in
+            # float32: a gather of 8 of 256 columns a row took 6.7 ms a
+            # step on the v5e at 8,192 tokens (PERF.md section 6, PR 29),
+            # and its gradient would be a scatter
+            topv = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32)
+                           * scores[:, None, :], axis=-1)
+            if renorm:
+                topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+            topv = topv * routed_scale
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            topv, topi = jax.lax.top_k(probs, top_k)
+            if renorm:
+                topv = topv / jnp.maximum(
+                    jnp.sum(topv, axis=-1, keepdims=True), 1e-9)
         assigned = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32),
                            axis=(0, 1))                 # n_e
         balance = e * jnp.sum(assigned / n * jnp.mean(probs, axis=0))
-        return topv, topi.astype(jnp.int32), balance, _z_loss(logits)
+        z = (_z_loss(logits) if scoring == "softmax"
+             else jnp.zeros((), jnp.float32))
+        return topv, topi.astype(jnp.int32), balance, z
 
 
 #: megablox tilings (rows, contraction, output columns) by operand size;
@@ -168,15 +211,23 @@ def _route(x, w_router, b_router, *, top_k, renorm):
 _GMM_TILING = {2: (512, 1024, 1024), 4: (256, 512, 512)}
 
 
-def _gmm_tiling(rows, itemsize):
-    """The kernel's tiling for this many assigned rows, or None where it
-    cannot take them: its row tile has to divide the rows."""
+def _gmm_tiling(rows, itemsize, k, n):
+    """The kernel's tiling for this many assigned rows of a [k -> n] gemm,
+    or None where it cannot take them: its row tile has to divide the
+    rows. Where a tile is wider than the operand (an expert of width 768
+    under the 1024 tile) the answer is the kernel's table form, a function
+    of each call's (m, k, n), so that the backward calls, whose k and n
+    swap, are clamped too and no tile is masked or half empty."""
     want = _GMM_TILING.get(itemsize)
     if want is None:
         return None
     tm = next((t for t in (want[0], 256, 128, 64, 32, 16, 8)
                if t <= want[0] and rows % t == 0), None)
-    return None if tm is None else (tm,) + want[1:]
+    if tm is None:
+        return None
+    if k % want[1] == 0 and n % want[2] == 0:
+        return (tm,) + want[1:]
+    return lambda m, k, n: (tm, min(want[1], k), min(want[2], n))
 
 
 def _one_device_program():
@@ -213,7 +264,7 @@ def _grouped_matmul(rows, w, group_sizes, kernel):
     M, else ragged_dot serves. Measured on the chip (PERF.md section 6, PR
     25): the kernel is the faster, and XLA's TPU expansion of ragged_dot
     loses the operation's scope in a trace."""
-    tiling = _gmm_tiling(rows.shape[0], rows.dtype.itemsize)
+    tiling = _gmm_tiling(rows.shape[0], rows.dtype.itemsize, *w.shape[1:])
     if kernel != "xla" and tiling is not None and rows.dtype == w.dtype:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
@@ -221,6 +272,22 @@ def _grouped_matmul(rows, w, group_sizes, kernel):
                    interpret=kernel == "interpret")
     return jax.lax.ragged_dot(rows, w, group_sizes,
                               preferred_element_type=rows.dtype)
+
+
+def _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel):
+    """The experts on rows in expert order: two grouped matmuls with gelu
+    between, or three with SwiGLU (its product in float32)."""
+    with jax.named_scope("moe.experts"):
+        def grouped(rows, w):
+            return _grouped_matmul(rows, w, group_sizes, kernel)
+
+        up = grouped(xs, w_up)
+        if w_gate is None:
+            mid = jax.nn.gelu(up)
+        else:
+            mid = (jax.nn.silu(grouped(xs, w_gate).astype(jnp.float32))
+                   * up.astype(jnp.float32)).astype(xs.dtype)
+        return grouped(mid, w_down)
 
 
 def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel="xla"):
@@ -237,30 +304,113 @@ def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel="xla"):
         group_sizes = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32),
                               axis=0)
         xs = _rows_to_expert_order(x.reshape(n, x.shape[-1]), order, inv, k)
-    with jax.named_scope("moe.experts"):
-        def grouped(rows, w):
-            return _grouped_matmul(rows, w, group_sizes, kernel)
-
-        up = grouped(xs, w_up)
-        if w_gate is None:
-            mid = jax.nn.gelu(up)
-        else:
-            mid = (jax.nn.silu(grouped(xs, w_gate).astype(jnp.float32))
-                   * up.astype(jnp.float32)).astype(xs.dtype)
-        ys = grouped(mid, w_down)
+    ys = _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel)
     return ys, order, inv
 
 
-def _combine(ys, topv, order, inv, *, shape):
-    """Weight each pair's output, un-sort, sum a token's k choices (f32)."""
+def _combine(ys, topv, order, inv, *, shape, held=False):
+    """Weight each pair's output, un-sort, sum a token's k choices (f32).
+    ``held``: ys are a held share's rows (``order`` the pair computed in
+    each, ``inv`` each pair's row or the zero row past them)."""
     with jax.named_scope("moe.combine"):
         n, k = topv.shape
-        by_token = _rows_to_token_order(ys, order, inv).reshape(n, k, -1)
+        back = _rows_from_held_order if held else _rows_to_token_order
+        by_token = back(ys, order, inv).reshape(n, k, -1)
         out = jnp.einsum("nkh,nk->nh", by_token.astype(jnp.float32),
                          topv.astype(jnp.float32))
         return out.astype(jnp.promote_types(ys.dtype, topv.dtype)
                           ).reshape(shape)
 
+
+# ------------------------------------------------------------ held share
+# One chip's share of the experts (expert parallelism without the
+# exchange): the router scores and picks over ALL experts, and only the
+# (token, choice) pairs whose expert lives here are sorted, gathered and
+# multiplied. How many pairs land here depends on the data, so they go
+# into a buffer of ``rows`` rows, a stated factor over the mean: pairs
+# beyond it are dropped and COUNTED (``held_overflow``), never silently.
+# Row ``rows`` of the padded arrays is a zero row that every pair not
+# computed here points at, so both directions stay gathers.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_to_held_order(x, taken, inv, k):
+    """x [N, H] -> [rows, H]: row i is the token of the i-th pair here."""
+    return x[taken // k]
+
+
+def _rows_to_held_order_fwd(x, taken, inv, k):
+    return x[taken // k], inv
+
+
+def _rows_to_held_order_bwd(k, inv, g):
+    padded = jnp.concatenate([g, jnp.zeros_like(g[:1])])
+    by_token = padded[inv].reshape(inv.shape[0] // k, k, g.shape[-1])
+    return (jnp.sum(by_token.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_rows_to_held_order.defvjp(_rows_to_held_order_fwd, _rows_to_held_order_bwd)
+
+
+@jax.custom_vjp
+def _rows_from_held_order(ys, taken, inv):
+    """ys [rows, H] -> [N*k, H] in token-major order, zero at the pairs
+    that were not computed here."""
+    return jnp.concatenate([ys, jnp.zeros_like(ys[:1])])[inv]
+
+
+def _rows_from_held_order_fwd(ys, taken, inv):
+    return _rows_from_held_order(ys, taken, inv), (taken, inv)
+
+
+def _rows_from_held_order_bwd(res, g):
+    taken, inv = res
+    here = inv[taken] < taken.shape[0]
+    return jnp.where(here[:, None], g[taken], 0), None, None
+
+
+_rows_from_held_order.defvjp(_rows_from_held_order_fwd,
+                             _rows_from_held_order_bwd)
+
+
+def held_rows(tokens, top_k, count, num_experts, factor, tile=512):
+    """The held path's row buffer: ``factor`` times the mean number of
+    pairs that land on ``count`` of ``num_experts`` experts, rounded up to
+    the grouped matmul's row tile and never more than every pair."""
+    mean = tokens * top_k * count / num_experts
+    rows = -(-int(np.ceil(factor * mean)) // tile) * tile
+    return min(rows, tokens * top_k)
+
+
+def _held_experts(x, topi, w_gate, w_up, w_down, *, first, rows,
+                  kernel="xla"):
+    """Dispatch and experts of a held share: [B, S, H] and the expert ids
+    [N, k] over ALL experts -> the outputs [rows, H] of the pairs whose
+    expert is one of the ``w_up.shape[0]`` held from ``first`` on, in
+    expert order; ``taken`` [rows] (the pair computed in each row), ``inv``
+    [N*k] (each pair's row, ``rows`` where it was not computed here) and
+    the number of held pairs that did not fit (exact whenever it is 0)."""
+    n, k = topi.shape
+    count = w_up.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        local = topi.reshape(-1) - first
+        held = (local >= 0) & (local < count)
+        group = jnp.where(held, local, count)       # absent experts sort last
+        order = jnp.argsort(group).astype(jnp.int32)
+        position = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+        sizes = jnp.sum(jax.nn.one_hot(group, count, dtype=jnp.int32), axis=0)
+        overflow = jnp.maximum(jnp.sum(sizes) - rows, 0)
+        # every row belongs to a group: the last one takes the rows that no
+        # held pair fills (pairs of absent experts; their output meets no
+        # weight: ``inv`` never points at them)
+        ends = jnp.minimum(jnp.cumsum(sizes), rows).at[-1].set(rows)
+        group_sizes = jnp.diff(ends, prepend=0)
+        taken = order[:rows]
+        inv = jnp.where(held & (position < rows), position, rows)
+        xs = _rows_to_held_order(x.reshape(n, x.shape[-1]), taken, inv, k)
+    ys = _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel)
+    return ys, taken, inv, overflow
 
 
 def _capacity_combine(xf, probs, top_k, cap, renorm=True):
@@ -306,7 +456,10 @@ class MoELayer(nn.Layer):
     experts; the k selected weights are renormalised to sum to one
     (Switch/GShard style) unless ``norm_topk_prob=False`` keeps the
     softmax's own values (OLMoE). ``activation``: 'gelu' (two matrices)
-    or 'swiglu' (three). The auxiliary losses (load balancing times
+    or 'swiglu' (three). ``scoring='sigmoid'``, ``select_bias``,
+    ``bias_update_speed``, ``routed_scale`` and ``shared_width`` give the
+    DeepSeek-V3 family's layer, ``held=(first, count)`` one chip's range of
+    its experts (module docstring). The auxiliary losses (load balancing times
     ``aux_weight``, router z-loss times ``z_loss_weight``) are routed
     through ``nn.aux_loss.emit_aux_loss``: in eager mode they land on
     ``self.aux_loss`` (add it to the objective yourself); inside
@@ -320,7 +473,10 @@ class MoELayer(nn.Layer):
     def __init__(self, hidden_size, ffn_hidden, num_experts, top_k=2,
                  shard_axis="ep", aux_weight=0.01, dispatch_mode="auto",
                  capacity_factor=1.25, activation="gelu", gate_bias=True,
-                 norm_topk_prob=True, z_loss_weight=0.0, weight_attr=None):
+                 norm_topk_prob=True, z_loss_weight=0.0, weight_attr=None,
+                 scoring="softmax", select_bias=False, bias_update_speed=0.0,
+                 routed_scale=1.0, shared_width=0, held=None,
+                 held_rows_factor=2.0):
         super().__init__()
         self.num_experts = int(num_experts)
         self.top_k = int(top_k)
@@ -333,6 +489,21 @@ class MoELayer(nn.Layer):
         if activation not in ("gelu", "swiglu"):
             raise ValueError(f"activation must be 'gelu' or 'swiglu', got "
                              f"{activation!r}")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got "
+                             f"{scoring!r}")
+        self.scoring = scoring
+        self.routed_scale = float(routed_scale)
+        self.bias_update_speed = float(bias_update_speed)
+        first, count = (0, self.num_experts) if held is None else held
+        if not 0 <= first < first + count <= self.num_experts:
+            raise ValueError(f"held=(first, count)={held!r} is no range of "
+                             f"the {self.num_experts} experts")
+        self.held = None if held is None else (int(first), int(count))
+        self.held_rows_factor = float(held_rows_factor)
+        if self.held and dispatch_mode != "auto":
+            raise ValueError("a held share of the experts runs the sorted "
+                             "path: leave dispatch_mode='auto'")
         self.shard_axis = shard_axis
         self.dispatch_mode = dispatch_mode
         self.capacity_factor = float(capacity_factor)
@@ -347,14 +518,34 @@ class MoELayer(nn.Layer):
                 shape, attr=weight_attr,
                 default_initializer=nn.initializer.Uniform(-bound, bound))
             # experts live sharded over 'ep' (spmd.build_train_step honors
-            # mp_spec); the contraction over the expert dim emits the psum
-            w.mp_spec = P(shard_axis)
+            # mp_spec); the contraction over the expert dim emits the psum.
+            # A held share IS one device's shard: nothing left to divide
+            if self.held is None:
+                w.mp_spec = P(shard_axis)
             return w
 
-        self.w_gate = (stacked([num_experts, hidden_size, ffn_hidden], k)
+        self.w_gate = (stacked([count, hidden_size, ffn_hidden], k)
                        if activation == "swiglu" else None)
-        self.w_up = stacked([num_experts, hidden_size, ffn_hidden], k)
-        self.w_down = stacked([num_experts, ffn_hidden, hidden_size], k2)
+        self.w_up = stacked([count, hidden_size, ffn_hidden], k)
+        self.w_down = stacked([count, ffn_hidden, hidden_size], k2)
+        self.shared = None
+        if shared_width:
+            # the expert every token takes (DeepSeek's shared expert)
+            from ..text.models import LlamaMLP
+
+            self.shared = LlamaMLP(hidden_size, shared_width, weight_attr)
+        if select_bias:
+            # DeepSeek-V3's e_score_correction_bias: no gradient, moved
+            # after each training step against the step's loads
+            self.register_buffer("e_score_correction_bias", Tensor(
+                np.zeros(self.num_experts, np.float32)))
+        else:
+            self.e_score_correction_bias = None
+        if self.held:
+            # held pairs that did not fit the row buffer, summed over the
+            # training steps so far: 0 means every step was exact
+            self.register_buffer("held_overflow",
+                                 Tensor(np.zeros((), np.int32)))
         self.aux_loss = None
 
     def resolved_mode(self):
@@ -365,6 +556,8 @@ class MoELayer(nn.Layer):
         larger than 1, and the dropless ``sorted`` path where none does."""
         if self.dispatch_mode != "auto":
             return self.dispatch_mode
+        if self.held:
+            return "sorted_held"
         if self.num_experts < _DENSE_BELOW:
             return "dense"
         from ..distributed import topology
@@ -378,10 +571,18 @@ class MoELayer(nn.Layer):
 
         mode = self.resolved_mode()
         _DISPATCH_TOTAL.inc(path=mode)
-        if mode == "sorted":
+        if mode in ("sorted", "sorted_held"):
             out, aux = self._forward_sorted(x)
             emit_aux_loss(self, aux)
-            return out
+            if self.shared is None:
+                return out
+            with jax.named_scope("moe.shared"):
+                return out + self.shared(x)
+        if self.scoring != "softmax" or self.shared is not None:
+            raise NotImplementedError(
+                f"the {mode} path has the softmax router and no shared "
+                "expert; sigmoid scoring and a shared expert run on the "
+                "sorted paths")
         logits = self.gate(x)  # [B, S, E]
 
         def _moe(x, logits, w_gate, w_up, w_down, *, top_k, renorm):
@@ -459,12 +660,38 @@ class MoELayer(nn.Layer):
         weighted sum over a token's k choices stay in float32."""
         topv, topi, balance, z = apply_op(
             "moe_route", _route, x, self.gate.weight, self.gate.bias,
-            top_k=self.top_k, renorm=self.norm_topk_prob)
-        ys, order, inv = apply_op(
-            "moe_experts_sorted", _sorted_experts, x, topi, self.w_gate,
-            self.w_up, self.w_down, kernel=_expert_kernel())
-        out = apply_op("moe_combine", _combine, ys, topv, order, inv,
-                       shape=tuple(x.shape))
+            self.e_score_correction_bias, top_k=self.top_k,
+            renorm=self.norm_topk_prob, scoring=self.scoring,
+            routed_scale=self.routed_scale)
+        if self.held is None:
+            ys, order, inv = apply_op(
+                "moe_experts_sorted", _sorted_experts, x, topi, self.w_gate,
+                self.w_up, self.w_down, kernel=_expert_kernel())
+            out = apply_op("moe_combine", _combine, ys, topv, order, inv,
+                           shape=tuple(x.shape))
+        else:
+            first, count = self.held
+            tokens = int(np.prod(x.shape[:-1]))
+            ys, taken, inv, overflow = apply_op(
+                "moe_experts_held", _held_experts, x, topi, self.w_gate,
+                self.w_up, self.w_down, first=first, rows=held_rows(
+                    tokens, self.top_k, count, self.num_experts,
+                    self.held_rows_factor), kernel=_expert_kernel())
+            out = apply_op("moe_combine", _combine, ys, topv, taken, inv,
+                           shape=tuple(x.shape), held=True)
+            if self.training:
+                self.held_overflow.set_value(
+                    self.held_overflow._value + overflow._value)
+        if (self.training and self.bias_update_speed
+                and self.e_score_correction_bias is not None):
+            # the noaux_tc balancing: an overloaded expert's bias falls, an
+            # underloaded one's rises, by a fixed step; it is part of the
+            # train step (the buffer threads through it)
+            n_e = jnp.sum(jax.nn.one_hot(topi._value, self.num_experts,
+                                         dtype=jnp.float32), axis=(0, 1))
+            bias = self.e_score_correction_bias
+            bias.set_value(bias._value + self.bias_update_speed
+                           * jnp.sign(jnp.mean(n_e) - n_e))
         aux = balance * self.aux_weight
         if self.z_loss_weight:
             aux = aux + z * self.z_loss_weight

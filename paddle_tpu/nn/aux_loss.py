@@ -48,6 +48,15 @@ def emit_aux_loss(layer, value):
         layer.aux_loss = value
 
 
+def forward_aux_loss(value):
+    """Hand a value collected in an inner block (a recomputed segment's
+    own collector) on to the enclosing collector; dropped without one,
+    as a bare trace drops an emission."""
+    acc = _COLLECTOR.get()
+    if acc is not None:
+        acc.append(value)
+
+
 def total_aux_loss(collected):
     """Sum a collector's list (0.0 when nothing was emitted)."""
     total = None

@@ -141,9 +141,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     num_kb = seq_k // block_k
     q_start = qi * _i32(block_q)
     k_start = ki * _i32(block_k)
-    d = q_ref.shape[-1]
     bcast_k = _lane_bcast(block_q, block_k)
-    bcast_d = _lane_bcast(block_q, d)
+    bcast_d = _lane_bcast(block_q, v_ref.shape[-1])
 
     if causal:
         # the last k block this q block attends to; later ones are
@@ -217,10 +216,10 @@ def _keep_thresh(dropout_p):
 def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
          interpret):
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    seq_k, dv = k.shape[1], v.shape[2]
     grid = (bh, seq_q // block_q, seq_k // block_k)
     out_shape = (
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
         # lse kept 3-d with trailing dim 1: TPU block shapes must tile
         # (8,128) or match the array dims exactly
         jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
@@ -250,24 +249,25 @@ def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, dv), kv_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ),
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),   # running max
             pltpu.VMEM((block_q, LANES), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),       # output acc
+            pltpu.VMEM((block_q, dv), jnp.float32),      # output acc
         ],
         interpret=interpret,
         name="flash_stream_fwd",
         compiler_params=_STREAM_GRID_PARAMS,
         cost_estimate=pl.CostEstimate(
-            flops=4 * seq_q * seq_k * d,
-            bytes_accessed=(seq_q + 2 * seq_k) * d * q.dtype.itemsize,
+            flops=2 * seq_q * seq_k * (d + dv),
+            bytes_accessed=((seq_q + seq_k) * d + seq_k * dv)
+            * q.dtype.itemsize,
             transcendentals=seq_q * seq_k),
     )(seed, q, k, v)
     return o, lse
@@ -422,7 +422,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
     q, k, v, o, lse, seed = res
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    seq_k, dv = k.shape[1], v.shape[2]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)            # [bh, seq_q, 1]
     off = seq_k - seq_q
@@ -454,8 +454,8 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, dv), kv_index),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
@@ -478,14 +478,14 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), q_index),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), q_index),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), q_index),
             pl.BlockSpec((1, block_q, 1), q_index),
             pl.BlockSpec((1, block_q, 1), q_index),
         ],
         out_specs=(
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -493,7 +493,7 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
         ),
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_stream_bwd_dkv",
@@ -527,24 +527,38 @@ def _flash_bwd(scale, causal, block_q, block_k, dropout_p, interpret, res,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _stream_block(head_dim, itemsize):
+def _stream_block(d_qk, d_v, itemsize, dropout=False):
     """The streaming kernels' q and k block. A program pays a fixed latency
     per grid step, so blocks as large as VMEM allows: beside the f32 score
-    tiles [block, block] the backward kernels hold six [block, head_dim]
+    tiles [block, block] the backward kernels hold six [block, width]
     operand tiles double-buffered, so the block halves as a row's bytes
-    double. Measured on the v5e at b4 h16 s4096 d128 bf16 causal, forward +
-    backward (PERF.md section 6, PR 25): 256/256 26.2 ms, 512/512 12.7,
-    1024/512 11.6, 1024/1024 11.5; compiled for a described v5e
-    (tests/test_mosaic_compile.py): 1024 runs out of VMEM at 512 bytes a
-    row (d128 f32, d256 bf16), 512 does not."""
-    row_bytes = head_dim * itemsize
-    return 1024 if row_bytes <= 256 else 512 if row_bytes <= 512 else 256
+    double. A row's bytes are those of the mean of the key and the value
+    width, each as VMEM holds it (rounded up to the 128 lanes: latent
+    attention's 192-wide keys take two lane groups, its 128-wide values
+    one). Measured on the v5e, forward + backward: b4 h16 s4096 d128 bf16
+    causal (PERF.md section 6, PR 25) 256/256 26.2 ms, 512/512 12.7,
+    1024/512 11.6, 1024/1024 11.5; b1 h32 s8192 d192/128 bf16 causal (PR 29)
+    256 51.2, 512 31.8, 1024/512 29.6, 1024 28.8. Compiled for a described
+    v5e (tests/test_mosaic_compile.py): 1024 runs out of VMEM at 512 bytes
+    a row (d128 f32, d256 bf16) and at d192/128 f32 (768), 512 does not.
+    With ``dropout`` the mask hash's [block, block] tiles take what one
+    more lane group a row would: d192/128 bf16 ran out of VMEM at 1024 on
+    the chip with a mask (chip_smoke.py, PR 29) and not without."""
+    def in_lanes(d):
+        return -(-d // LANES) * LANES
+
+    row_bytes = (in_lanes(d_qk) + in_lanes(d_v)) // 2 * itemsize
+    if dropout:
+        row_bytes += LANES
+    return 1024 if row_bytes <= 384 else 512 if row_bytes <= 768 else 256
 
 
 def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
         block_q=None, block_k=None, interpret=False):
-    """Flash attention. q,k,v: [batch, heads, seq, head_dim] (or 3-d
-    [batch*heads, seq, head_dim]). Returns same shape as q.
+    """Flash attention. q,k: [batch, heads, seq, head_dim] (or 3-d
+    [batch*heads, seq, head_dim]); v the same, or with a width of its own
+    (latent attention: 192-wide keys, 128-wide values). Returns q's shape
+    with v's width.
 
     dropout_p > 0 applies dropout to the attention probabilities inside the
     kernel (counter-based mask keyed by ``seed``, an int32 scalar array —
@@ -556,21 +570,21 @@ def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
     if squeeze:
         q, k, v = q[None], k[None], v[None]
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    want = _stream_block(d, q.dtype.itemsize)
+    want = _stream_block(d, dv, q.dtype.itemsize, dropout_p > 0.0)
     bq = _block(sq, block_q or want)
     bk = _block(sk, block_k or want)
     q3 = q.reshape(b * h, sq, d)
     k3 = k.reshape(b * h, sk, d)
-    v3 = v.reshape(b * h, sk, d)
+    v3 = v.reshape(b * h, sk, dv)
     if seed is None:
         seed = jnp.zeros((), jnp.int32)
     seed2d = jnp.asarray(seed, jnp.int32).reshape(1, 1)
     o = _flash(q3, k3, v3, seed2d, float(scale), bool(causal), bq, bk,
                float(dropout_p), bool(interpret))
-    o = o.reshape(b, h, sq, d)
+    o = o.reshape(b, h, sq, dv)
     return o[0] if squeeze else o
 
 

@@ -6,6 +6,7 @@ BERT matches the PaddleNLP/ERNIE architecture the north-star names
 framework's own transformer stack (nn/layers/transformer.py ->
 Pallas flash attention on TPU).
 """
+import contextlib
 import math
 
 import numpy as np
@@ -287,11 +288,17 @@ class LlamaAttention(nn.Layer):
 
 
 class LlamaMLP(nn.Layer):
-    def __init__(self, hidden_size, intermediate_size):
+    """SwiGLU: ``down(silu(gate(x)) * up(x))``, no biases."""
+
+    def __init__(self, hidden_size, intermediate_size, weight_attr=None):
         super().__init__()
-        self.gate_proj = nn.Linear(hidden_size, intermediate_size, bias_attr=False)
-        self.up_proj = nn.Linear(hidden_size, intermediate_size, bias_attr=False)
-        self.down_proj = nn.Linear(intermediate_size, hidden_size, bias_attr=False)
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.gate_proj = proj(hidden_size, intermediate_size)
+        self.up_proj = proj(hidden_size, intermediate_size)
+        self.down_proj = proj(intermediate_size, hidden_size)
 
     def forward(self, x):
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
@@ -495,3 +502,296 @@ class OlmoeModel(nn.Layer):
 
     def forward(self, input_ids):
         return self.lm_head(self.features(input_ids))
+
+
+class MLAttention(nn.Layer):
+    """Multi-head latent attention (DeepSeek-V2/V3; HF ``DeepseekV3Attention``)
+    in its training form, nothing absorbed: q through a low-rank pair with a
+    RMSNorm between, ``[c_kv ; k_r] = x W_kva`` with c_kv normed and expanded
+    to each head's (no-position key, value), RoPE on each head's
+    ``rope_dim`` query features and on the ONE ``rope_dim``-wide k_r that
+    all heads share, then a causal core whose keys (``nope_dim + rope_dim``)
+    are wider than its values (``v_dim``), through the dispatching sdpa (the
+    streaming kernel takes the two widths as they are)."""
+
+    def __init__(self, hidden_size, num_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rms_norm_eps=1e-6, rope_theta=10000.0, weight_attr=None):
+        super().__init__()
+        self.num_heads = num_heads
+        self.nope_dim, self.rope_dim = qk_nope_head_dim, qk_rope_head_dim
+        self.v_dim = v_head_dim
+        self.kv_rank = kv_lora_rank
+        self.rope_theta = float(rope_theta)
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        qk = qk_nope_head_dim + qk_rope_head_dim
+        self.q_a_proj = proj(hidden_size, q_lora_rank)
+        self.q_a_layernorm = RMSNorm(q_lora_rank, eps=rms_norm_eps)
+        self.q_b_proj = proj(q_lora_rank, num_heads * qk)
+        self.kv_a_proj_with_mqa = proj(hidden_size,
+                                       kv_lora_rank + qk_rope_head_dim)
+        self.kv_a_layernorm = RMSNorm(kv_lora_rank, eps=rms_norm_eps)
+        self.kv_b_proj = proj(kv_lora_rank,
+                              num_heads * (qk_nope_head_dim + v_head_dim))
+        self.o_proj = proj(num_heads * v_head_dim, hidden_size)
+
+    def forward(self, x):
+        import jax
+        import jax.numpy as jnp
+
+        from ..core.dispatch import apply_op
+        from ..ops.attention import scaled_dot_product_attention as _sdpa
+
+        nh, nope, rope, dv = (self.num_heads, self.nope_dim, self.rope_dim,
+                              self.v_dim)
+
+        def _split(kv_a, *, rank):
+            return kv_a[..., :rank], kv_a[..., rank:]
+
+        def _heads(q, kv, k_r, *, base):
+            b, s, _ = q.shape
+            q = q.reshape(b, s, nh, nope + rope).transpose(0, 2, 1, 3)
+            kv = kv.reshape(b, s, nh, nope + dv).transpose(0, 2, 1, 3)
+            # interleaved pairs (2i, 2i + 1) as the checkpoint stores them
+            # (rope_interleave); one rotated k_r serves every head
+            q_r = _rope(q[..., nope:], base)
+            k_r = _rope(k_r[:, None], base)
+            k_r = jnp.broadcast_to(k_r, (b, nh, s, rope))
+            return (jnp.concatenate([q[..., :nope], q_r], axis=-1),
+                    jnp.concatenate([kv[..., :nope], k_r], axis=-1),
+                    kv[..., nope:])
+
+        with jax.named_scope("mla.q"):
+            q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+        with jax.named_scope("mla.kv"):
+            c_kv, k_r = apply_op("mla_split", _split,
+                                 self.kv_a_proj_with_mqa(x),
+                                 rank=self.kv_rank)
+            kv = self.kv_b_proj(self.kv_a_layernorm(c_kv))
+        with jax.named_scope("mla.rope"):
+            q, k, v = apply_op("mla_heads_rope", _heads, q, kv, k_r,
+                               base=self.rope_theta)
+        with jax.named_scope("mla.core"):
+            out = _sdpa(q, k, v, is_causal=True, training=self.training)
+
+        def _merge(out):
+            b, h, t, d = out.shape
+            return out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+        with jax.named_scope("mla.out"):
+            return self.o_proj(apply_op("merge_heads", _merge, out))
+
+
+class JoyAIDecoderLayer(nn.Layer):
+    """Pre-norm block of the DeepSeek-V3 family: latent attention, then a
+    dense SwiGLU (the leading layers) or the expert layer — sigmoid router
+    with a selection bias, renormalised top-k times ``routed_scaling_factor``,
+    a shared expert, and the held range of the routed experts."""
+
+    def __init__(self, cfg, dense, weight_attr=None):
+        super().__init__()
+        from ..incubate.moe import MoELayer
+
+        hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+        self.input_layernorm = RMSNorm(hidden, eps=eps)
+        self.self_attn = MLAttention(
+            hidden, cfg["num_attention_heads"], cfg["q_lora_rank"],
+            cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
+            cfg["qk_rope_head_dim"], cfg["v_head_dim"], eps,
+            cfg["rope_theta"], weight_attr)
+        self.post_attention_layernorm = RMSNorm(hidden, eps=eps)
+        if dense:
+            self.mlp = LlamaMLP(hidden, cfg["intermediate_size"],
+                                weight_attr)
+        else:
+            self.mlp = MoELayer(
+                hidden, cfg["moe_intermediate_size"],
+                cfg["n_routed_experts"], top_k=cfg["num_experts_per_tok"],
+                activation="swiglu", gate_bias=False,
+                norm_topk_prob=cfg["norm_topk_prob"], scoring="sigmoid",
+                select_bias=True,
+                bias_update_speed=cfg["bias_update_speed"],
+                routed_scale=cfg["routed_scaling_factor"],
+                shared_width=cfg["n_shared_experts"]
+                * cfg["moe_intermediate_size"],
+                held=cfg["held_experts"],
+                held_rows_factor=cfg["held_rows_factor"],
+                aux_weight=cfg["balance_loss_weight"],
+                weight_attr=weight_attr)
+
+    def forward(self, x):
+        x = x + self.self_attn(self.input_layernorm(x))
+        return x + self.mlp(self.post_attention_layernorm(x))
+
+
+class JoyAIFlashModel(nn.Layer):
+    """JoyAI-LLM-Flash (HF ``joyai_llm_flash``; the DeepSeek-V3 family's
+    equations): ``first_k_dense_replace`` dense blocks, then expert blocks,
+    every one with latent attention; a final norm and an untied head; and
+    ``num_nextn_predict_layers`` multi-token-prediction modules that share
+    the embedding and the head. Defaults are the published sizes.
+
+    ``held_experts=(first, count)`` gives every expert layer this chip's
+    range of the routed experts (default: all of them). ``use_recompute``
+    runs each block under ``fleet.utils.recompute`` in a traced step.
+
+    ``forward`` gives the main logits. A training loss should not hold
+    them: ``training_features`` gives the final-normed hidden states of the
+    main model and of the MTP module, for ``mtp_lm_loss`` with
+    ``lm_head.weight``."""
+
+    def __init__(self, vocab_size=129280, hidden_size=2048,
+                 num_hidden_layers=40, num_attention_heads=32,
+                 intermediate_size=7168, moe_intermediate_size=768,
+                 n_routed_experts=256, num_experts_per_tok=8,
+                 n_shared_experts=1, first_k_dense_replace=1,
+                 q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, rms_norm_eps=1e-6,
+                 rope_theta=32000000.0, norm_topk_prob=True,
+                 routed_scaling_factor=2.5, num_nextn_predict_layers=1,
+                 bias_update_speed=0.001, balance_loss_weight=0.0,
+                 initializer_range=0.02, held_experts=None,
+                 held_rows_factor=2.0, use_recompute=False):
+        super().__init__()
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        cfg = dict(
+            hidden_size=hidden_size, num_attention_heads=num_attention_heads,
+            intermediate_size=intermediate_size,
+            moe_intermediate_size=moe_intermediate_size,
+            n_routed_experts=n_routed_experts,
+            num_experts_per_tok=num_experts_per_tok,
+            n_shared_experts=n_shared_experts, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rms_norm_eps=rms_norm_eps, rope_theta=rope_theta,
+            norm_topk_prob=norm_topk_prob,
+            routed_scaling_factor=routed_scaling_factor,
+            bias_update_speed=bias_update_speed,
+            balance_loss_weight=balance_loss_weight,
+            held_experts=None if held_experts is None else tuple(held_experts),
+            held_rows_factor=held_rows_factor)
+        self.use_recompute = bool(use_recompute)
+        self._tap = None
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            JoyAIDecoderLayer(cfg, dense=i < first_k_dense_replace,
+                              weight_attr=attr())
+            for i in range(num_hidden_layers)])
+        self.norm = RMSNorm(hidden_size, eps=rms_norm_eps)
+        self.lm_head = nn.Linear(hidden_size, vocab_size,
+                                 weight_attr=attr(), bias_attr=False)
+        self.mtp = nn.LayerList([
+            MultiTokenPredictor(cfg, weight_attr=attr())
+            for _ in range(num_nextn_predict_layers)])
+
+    def _block(self, block, *inputs):
+        if self.use_recompute and self.training:
+            from ..distributed.fleet.utils import recompute
+
+            out = recompute(block, *inputs)
+        else:
+            out = block(*inputs)
+        if self._tap is not None:
+            self._tap.append((block, inputs, out))
+        return out
+
+    @contextlib.contextmanager
+    def tapped(self):
+        """Collect (block, its inputs, its output) of every decoder block
+        and MTP module called inside: what a block-by-block comparison
+        with a reference feeds on."""
+        self._tap = taps = []
+        try:
+            yield taps
+        finally:
+            self._tap = None
+
+    def _trunk(self, input_ids):
+        """The last block's output, before the final norm."""
+        x = self.embed_tokens(input_ids)
+        for layer in self.layers:
+            x = self._block(layer, x)
+        return x
+
+    def features(self, input_ids):
+        return self.norm(self._trunk(input_ids))
+
+    def forward(self, input_ids):
+        return self.lm_head(self.features(input_ids))
+
+    def training_features(self, input_ids):
+        """(final-normed hidden states, [each MTP module's]): position i of
+        module d's output predicts token i + d + 2. The modules chain: the
+        first reads the last block's output (before the final norm), each
+        later one the module before it, with the embedding of the token one
+        further on; a row's last positions see the row's last token again
+        and carry no label."""
+        from .. import tensor as pt
+
+        h = self._trunk(input_ids)
+        main, heads, ids = self.norm(h), [], input_ids
+        for module in self.mtp:
+            ids = pt.concat([ids[:, 1:], ids[:, -1:]], axis=1)
+            h, normed = self._block(module, h, self.embed_tokens(ids))
+            heads.append(normed)
+        return main, heads
+
+
+class MultiTokenPredictor(nn.Layer):
+    """One multi-token-prediction module (DeepSeek-V3 report, section 2.2):
+    ``h' = W_eh [RMSNorm_h(h_i) ; RMSNorm_e(Emb(t_{i+1}))]``, one decoder
+    block of the expert kind, and the norm before the shared head. Returns
+    (the block's output, for the next module; its normed form, for the
+    head)."""
+
+    def __init__(self, cfg, weight_attr=None):
+        super().__init__()
+        hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+        self.hnorm = RMSNorm(hidden, eps=eps)
+        self.enorm = RMSNorm(hidden, eps=eps)
+        self.eh_proj = nn.Linear(2 * hidden, hidden, weight_attr=weight_attr,
+                                 bias_attr=False)
+        self.block = JoyAIDecoderLayer(cfg, dense=False,
+                                       weight_attr=weight_attr)
+        self.norm = RMSNorm(hidden, eps=eps)
+
+    def forward(self, h, emb):
+        from .. import tensor as pt
+
+        x = self.eh_proj(pt.concat([self.hnorm(h), self.enorm(emb)],
+                                   axis=-1))
+        x = self.block(x)
+        return x, self.norm(x)
+
+
+def mtp_lm_loss(hidden, mtp_hidden, head_weight, input_ids, mtp_weight=0.3):
+    """``CE(main_i, t_{i+1}) + mtp_weight * mean_d CE(mtp_d_i, t_{i+d+2})``
+    from the final hidden states, never holding a [tokens, vocab] array
+    (``F.linear_cross_entropy`` on the one shared head): the label of
+    position i is the token ``shift`` further on, the row's last ``shift``
+    positions predict nothing. Returns (total, main term, MTP term)."""
+    from .. import tensor as pt
+
+    def shifted(shift):
+        pad = pt.full_like(input_ids[:, :shift], -100)
+        return pt.concat([input_ids[:, shift:], pad], axis=1)
+
+    main = F.linear_cross_entropy(hidden, head_weight, shifted(1))
+    if not mtp_hidden:
+        return main, main, None
+    terms = [F.linear_cross_entropy(h, head_weight, shifted(d + 2))
+             for d, h in enumerate(mtp_hidden)]
+    mtp = terms[0]
+    for t in terms[1:]:
+        mtp = mtp + t
+    mtp = mtp / float(len(terms))
+    return main + mtp * float(mtp_weight), main, mtp

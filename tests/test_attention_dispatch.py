@@ -79,11 +79,11 @@ def _interpret_kernels():
     paddle.set_flags({"pallas_interpret": False})
 
 
-def _qkv(sq, sk, d=16):
+def _qkv(sq, sk, d=16, dv=None):
     rng = np.random.RandomState(0)
     return (jnp.asarray(rng.randn(1, 2, sq, d), jnp.float32),
             jnp.asarray(rng.randn(1, 2, sk, d), jnp.float32),
-            jnp.asarray(rng.randn(1, 2, sk, d), jnp.float32))
+            jnp.asarray(rng.randn(1, 2, sk, dv or d), jnp.float32))
 
 
 def _routes():
@@ -113,6 +113,10 @@ _ROUTE_CASES = [
     ("split-heads-short", "qkv", dict(sq=128, sk=128), "xla"),
     ("cross-attention", "qkv", dict(sq=128, sk=256), "xla"),
     ("long-k", "qkv", dict(sq=64, sk=2048), "stream"),
+    # latent attention: keys wider than values, the same gate and kernel
+    ("long-k-wide-keys", "qkv", dict(sq=1024, sk=1024, d=24, dv=16,
+                                     is_causal=True), "stream"),
+    ("short-k-wide-keys", "qkv", dict(sq=128, sk=128, d=24, dv=16), "xla"),
     ("long-q-short-k", "qkv", dict(sq=2048, sk=128), "xla"),
     # ADVICE: 8192 x 128 has a min_seq^2 logits product but one k block
     ("very-long-q-short-k", "qkv", dict(sq=8192, sk=128), "xla"),
@@ -136,8 +140,11 @@ def test_routes(entry, args, route, track_kernel, track_short):
             training=False, **args)
         assert tuple(out.shape) == (2, seq, heads * head_dim)
     else:
-        q, k, v = _qkv(args["sq"], args["sk"])
-        attention.scaled_dot_product_attention(q, k, v, training=False)
+        q, k, v = _qkv(args["sq"], args["sk"], args.get("d", 16),
+                       args.get("dv"))
+        out = attention.scaled_dot_product_attention(
+            q, k, v, training=False, is_causal=args.get("is_causal", False))
+        assert tuple(out.shape) == (1, 2, args["sq"], v.shape[-1])
     assert len(track_short) == (route == "short")
     assert len(track_kernel) == (route == "stream")
     after = _routes()

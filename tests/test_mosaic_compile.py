@@ -52,6 +52,14 @@ STREAM_CASES = {
     "olmoe-cell-bf16": (4, 16, 4096, 128, "bfloat16"),
     "olmoe-check-f32": (1, 16, 4096, 128, "float32"),
     "d256-bf16": (2, 8, 8192, 256, "bfloat16"),
+    "d64-bf16": (2, 12, 2048, 64, "bfloat16"),
+    # latent attention's core at the JoyAI cell: keys (192: not a multiple
+    # of the 128 lanes) wider than values (128); in float32 for its check
+    "joyai-cell-bf16": (1, 32, 8192, (192, 128), "bfloat16"),
+    "joyai-check-f32": (1, 32, 8192, (192, 128), "float32"),
+    # with a dropout mask (a sixth element): the hash's tiles need VMEM too
+    "joyai-cell-bf16-p0.1": (1, 32, 8192, (192, 128), "bfloat16", 0.1),
+    "olmoe-cell-bf16-p0.1": (4, 16, 4096, 128, "bfloat16", 0.1),
 }
 STREAM_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
                 "flash_stream_bwd_dkv")
@@ -154,17 +162,26 @@ def _child():
                      "wider_admitted": wider,
                      "scores_in_hbm": f"{batch},{heads},{seq},{seq}" in text}
 
-    for name, (batch, heads, seq, head_dim, dtype) in STREAM_CASES.items():
-        qkv = jax.ShapeDtypeStruct((batch, heads, seq, head_dim), dtype,
-                                   sharding=one)
+    for name, (batch, heads, seq, head_dim, dtype, *p) in STREAM_CASES.items():
+        d_qk, d_v = (head_dim if isinstance(head_dim, tuple)
+                     else (head_dim, head_dim))
+        qk = jax.ShapeDtypeStruct((batch, heads, seq, d_qk), dtype,
+                                  sharding=one)
+        v = jax.ShapeDtypeStruct((batch, heads, seq, d_v), dtype,
+                                 sharding=one)
         text = jax.jit(jax.grad(
-            lambda q, k, v: jnp.sum(fa.mha(q, k, v, causal=True)
-                                    .astype(jnp.float32)),
-            argnums=(0, 1, 2))).lower(qkv, qkv, qkv).compile().as_text()
+            lambda q, k, v: jnp.sum(fa.mha(
+                q, k, v, causal=True, dropout_p=p[0] if p else 0.0,
+                seed=jnp.zeros((), jnp.int32)).astype(jnp.float32)),
+            argnums=(0, 1, 2))).lower(qk, qk, v).compile().as_text()
         out["stream-" + name] = {
             "mosaic": text.count(MOSAIC),
             "calls": [c for c in STREAM_CALLS if c in text],
-            "scores_in_hbm": f"{seq},{seq}]" in text}
+            "scores_in_hbm": f"{seq},{seq}]" in text,
+            "value_wide_results": text.count(
+                f"[{batch * heads},{seq},{d_v}]"),
+            "key_wide_results": text.count(
+                f"[{batch * heads},{seq},{d_qk}]")}
 
     # the s128 cell's FFN under amp O1, forward + backward: what F.gelu's
     # erf lowers to behind linear1's gemm, and what leaves that fusion
@@ -306,12 +323,19 @@ def test_short_kernel_compiles(compiled, case):
 @pytest.mark.parametrize("case", list(STREAM_CASES))
 def test_stream_kernel_compiles(compiled, case):
     """The streaming kernel, forward and both backward calls, causal, at
-    the OLMoE cell's b4 h16 s4096 d128 bf16, in float32 and at d256: three
-    Mosaic calls under their names within VMEM at the block sizes the
-    kernel picks, no [seq, seq] scores in HBM."""
+    the OLMoE cell's b4 h16 s4096 d128 bf16, in float32, at d256 and d64,
+    and at the JoyAI cell's b1 h32 s8192 with 192-wide keys and 128-wide
+    values (bf16 and the check's float32): three Mosaic calls under their
+    names within VMEM at the block sizes the kernel picks, no [seq, seq]
+    scores in HBM."""
     got = compiled["stream-" + case]
     assert got["mosaic"] == 3 and got["calls"] == list(STREAM_CALLS)
     assert not got["scores_in_hbm"]
+    head_dim = STREAM_CASES[case][3]
+    if isinstance(head_dim, tuple):
+        # keys and values keep their own widths through all three calls:
+        # nothing is padded to the other's
+        assert got["value_wide_results"] and got["key_wide_results"]
 
 
 def test_gelu_stays_one_erf_behind_the_ffn_up_gemm(compiled):
