@@ -10,18 +10,23 @@ paddle/fluid/operators/fused/multihead_matmul_op.cu) — those are CUDA
 softmax-fused matmuls; here the idiomatic TPU design is the standard
 flash-attention online-softmax recurrence tiled for the MXU:
 
-- streaming 3-d grids: forward and dq run (bh, q_blocks, k_blocks)
-  with ONE K/V tile fetched per grid step (Mosaic double-buffers the
-  DMA against compute); dk/dv runs (bh, k_blocks, q_blocks) streaming
-  Q/dO tiles. Accumulators (running max/sum, output/grad partials)
-  live in VMEM scratch that persists across the inner grid dimension,
+- streaming 3-d grids: the forward runs (bh, q_blocks, k_blocks) with ONE
+  K/V tile fetched per grid step (Mosaic double-buffers the DMA against
+  compute); the backward runs (bh, k_blocks, q_blocks) streaming Q/dO
+  tiles. Accumulators (running max/sum, output/grad partials) live in
+  VMEM scratch that persists across the inner grid dimension,
   lane-replicated at [block, 128] where narrow columns would waste the
   vector registers. Causal grids skip fully-masked steps and remap
   their tile index so the revisit cache elides the dead DMA.
-- backward is the standard two-kernel flash backward recomputing
-  probabilities from the saved logsumexp (no S*S materialisation
-  anywhere, and no full-K/V VMEM residency: seq length is not capped
-  by the 16 MB scoped-VMEM limit).
+- the backward is ONE kernel: it recomputes each (q block, k block)
+  tile's probabilities from the saved logsumexp once and takes dv, dk
+  and dq from it — dk/dv into per-k-block scratch, dq into a float32
+  slab that holds a whole (batch . head) row of queries in VMEM across
+  the k blocks (no S*S materialisation anywhere, no full-K/V VMEM
+  residency). Where that row is past the slab's VMEM budget
+  (``_one_pass_backward``: tens of thousands of queries) the backward is
+  the standard two calls, dq on a (bh, q_blocks, k_blocks) grid of its
+  own, each recomputing the probabilities.
 
 All matmuls (both kernels') request `preferred_element_type=float32` so
 the MXU accumulates in f32 even for bf16 inputs, and both draw dropout
@@ -39,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.random import fmix32, keep_thresh_u32
+from ...obs import metrics as obs_metrics
 
 NEG_INF = -1e30
 
@@ -86,9 +92,10 @@ def _keep_mask(seed, b, rows, cols, seq_q, seq_k, keep_thresh):
 
 LANES = 128
 
-# all three kernels run (outer, outer, streamed) grids: the outer dims
-# are independent work; only the streamed accumulation dim is
-# order-dependent
+# the forward and the two-call backward run (outer, outer, streamed) grids:
+# the outer dims are independent work; only the streamed accumulation dim
+# is order-dependent (the one-pass backward accumulates dq across its k
+# blocks too, and says so in its own parameters)
 _STREAM_GRID_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
@@ -344,13 +351,27 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, causal,
-                    block_q, block_k, seq_q, seq_k, offset, dropout_p,
-                    keep_thresh):
+                    dk_ref, dv_ref, *rest, scale, causal, block_q, block_k,
+                    seq_q, seq_k, offset, dropout_p, keep_thresh, with_dq):
     """Streaming dk/dv: grid (bh, k_blocks, q_blocks), one Q/dO tile per
     step; dk/dv accumulators in VMEM scratch. The last q block always
     attends every k block (causal or not), so the finalize write keys
-    off qi == num_qb - 1 unconditionally."""
+    off qi == num_qb - 1 unconditionally.
+
+    ``with_dq`` (the one-pass backward, wherever ``_one_pass_backward``
+    says the slab fits): the score tile's ``ds`` gives dq too, so no second
+    call recomputes q.k^T, exp and dO.v^T. ``ds . k`` is added into rows
+    [q_start, q_start + block_q) of a float32 slab [seq_q, d] that stays in
+    VMEM for the whole (batch . head) row of the grid: zeroed at the row's
+    first k block, added to in ascending k (the order, operands and
+    accumulator dtype of ``_bwd_dq_kernel``'s scratch: the same bits), and
+    scaled and cast into the [seq_q, d] output block when the q block has
+    met its last needed k block. The output block's index moves with the
+    (batch . head) index alone, so it is written back once a row."""
+    if with_dq:
+        dq_ref, dk_acc_ref, dv_acc_ref, dq_acc_ref = rest
+    else:
+        dk_acc_ref, dv_acc_ref = rest
     bi = _i32(pl.program_id(0))
     ki = _i32(pl.program_id(1))
     qi = _i32(pl.program_id(2))
@@ -358,6 +379,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     num_qb = seq_q // block_q
     k_start = ki * _i32(block_k)
     q_start = qi * _i32(block_q)
+    q_rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
 
     if causal:
         # q blocks strictly before the diagonal see only masked rows
@@ -369,6 +391,12 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dk_acc_ref[...] = jnp.zeros(dk_acc_ref.shape, jnp.float32)
         dv_acc_ref[...] = jnp.zeros(dv_acc_ref.shape, jnp.float32)
+
+    if with_dq:
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_acc_ref[q_rows, :] = jnp.zeros(
+                (block_q, dq_acc_ref.shape[-1]), jnp.float32)
 
     def _compute():
         k = k_ref[0]                                    # [block_k, d]
@@ -403,10 +431,14 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  preferred_element_type=jnp.float32)
         if dropout_p > 0.0:
             dp = jnp.where(keep, dp * inv, 0.0)
-        ds = p * (dp - delta)
+        ds = (p * (dp - delta)).astype(q.dtype)
         dk_acc_ref[...] = dk_acc_ref[...] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            dq_acc_ref[q_rows, :] = dq_acc_ref[q_rows, :] + (
+                jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32))
 
     if causal:
         pl.when(needed)(_compute)
@@ -418,6 +450,49 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
+    if with_dq:
+        num_kb = seq_k // block_k
+        last_kb = (_causal_last_kb(qi, block_q, block_k, offset, num_kb)
+                   if causal else _i32(num_kb - 1))
+
+        @pl.when(ki == last_kb)
+        def _finalize_dq():
+            dq_ref[0, q_rows, :] = (
+                dq_acc_ref[q_rows, :] * scale).astype(dq_ref.dtype)
+
+
+_BACKWARD_TOTAL = obs_metrics.counter(
+    "paddle_tpu_flash_stream_backward_total",
+    "streaming flash backward passes by their Mosaic calls (one: dq, dk "
+    "and dv from one set of score tiles | two: the dq slab is over its "
+    "VMEM budget); under jit one count per traced backward",
+    labelnames=("calls",))
+
+# The one-pass backward's VMEM: beside what the two-call kernel holds at
+# the blocks ``_stream_block`` picks (within the 16 MiB default scoped
+# limit: tests/test_mosaic_compile.py) and one more [block_q, d] f32
+# product, the dq slab and its double-buffered output block may take the
+# budget; a v5e core has 128 MiB.
+_ONE_PASS_VMEM_LIMIT = 64 * 2**20
+_ONE_PASS_SLAB_BUDGET = 40 * 2**20
+
+
+def _in_lanes(d):
+    """A row of width d as VMEM holds it: rounded up to the 128 lanes."""
+    return -(-d // LANES) * LANES
+
+
+def _one_pass_backward(seq_q, d_qk, itemsize):
+    """Whether the backward takes dq from the dk/dv call's score tiles: it
+    does where a whole (batch . head) row of dq — the float32 slab
+    [seq_q, d_qk] and the double-buffered output block in the input dtype,
+    lane-padded — fits ``_ONE_PASS_SLAB_BUDGET``. 8,192 x 192 bf16 (the
+    JoyAI cell) takes 16 MiB, in float32 24; 4,096 x 128 bf16 (OLMoE) 4;
+    past the budget (32,768 queries of 192 in bf16: 64 MiB) the backward is
+    the two calls, each streaming its own accumulator."""
+    return (seq_q * _in_lanes(d_qk) * (4 + 2 * itemsize)
+            <= _ONE_PASS_SLAB_BUDGET)
+
 
 def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
     q, k, v, o, lse, seed = res
@@ -428,6 +503,11 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
     off = seq_k - seq_q
     nkb = seq_k // block_k
     nqb = seq_q // block_q
+    one_pass = _one_pass_backward(seq_q, d, q.dtype.itemsize)
+    _BACKWARD_TOTAL.inc(calls="one" if one_pass else "two")
+    statics = dict(scale=scale, causal=causal, block_q=block_q,
+                   block_k=block_k, seq_q=seq_q, seq_k=seq_k, offset=off,
+                   dropout_p=dropout_p, keep_thresh=_keep_thresh(dropout_p))
 
     if causal:
         # causal DMA dedup (see _fwd): skipped steps remap to a tile the
@@ -443,13 +523,56 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
         kv_index = lambda b, i, j: (b, j, 0)  # noqa: E731
         q_index = lambda b, i, j: (b, j, 0)  # noqa: E731
 
+    dkv_in_specs = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, block_q, d), q_index),
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_q, dv), q_index),
+        pl.BlockSpec((1, block_q, 1), q_index),
+        pl.BlockSpec((1, block_q, 1), q_index),
+    ]
+    dkv_out_specs = [
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
+    ]
+    dkv_out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                     jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    dkv_scratch = [pltpu.VMEM((block_k, d), jnp.float32),
+                   pltpu.VMEM((block_k, dv), jnp.float32)]
+
+    if one_pass:
+        pairs = bh * seq_q * seq_k
+        dk, dv, dq = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, with_dq=True, **statics),
+            grid=(bh, nkb, nqb),
+            in_specs=dkv_in_specs,
+            out_specs=dkv_out_specs + [
+                pl.BlockSpec((1, seq_q, d), lambda b, i, j: (b, 0, 0))],
+            out_shape=dkv_out_shape + [
+                jax.ShapeDtypeStruct(q.shape, q.dtype)],
+            scratch_shapes=dkv_scratch + [
+                pltpu.VMEM((seq_q, d), jnp.float32)],
+            interpret=interpret,
+            # the readers of a trace find the backward by "bwd_dkv"
+            name="flash_stream_bwd_dkv_dq",
+            # dq accumulates across the k blocks too
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_ONE_PASS_VMEM_LIMIT),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * pairs * (3 * d + 2 * dv),
+                # q, k, v, dO read; dq, dk, dv written
+                bytes_accessed=bh * (2 * (seq_q + seq_k) * d
+                                     + (seq_q + 2 * seq_k) * dv)
+                * q.dtype.itemsize,
+                transcendentals=pairs),
+        )(seed, q, k, v, do, lse, delta)
+        return dq, dk, dv
+
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_q=seq_q,
-                          seq_k=seq_k, offset=off,
-                          dropout_p=dropout_p,
-                          keep_thresh=_keep_thresh(dropout_p)),
-        grid=(bh, seq_q // block_q, nkb),
+        functools.partial(_bwd_dq_kernel, **statics),
+        grid=(bh, nqb, nkb),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -468,33 +591,12 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
     )(seed, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_q=seq_q,
-                          seq_k=seq_k, offset=off,
-                          dropout_p=dropout_p,
-                          keep_thresh=_keep_thresh(dropout_p)),
+        functools.partial(_bwd_dkv_kernel, with_dq=False, **statics),
         grid=(bh, nkb, nqb),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), q_index),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, dv), q_index),
-            pl.BlockSpec((1, block_q, 1), q_index),
-            pl.BlockSpec((1, block_q, 1), q_index),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, dv), jnp.float32),
-        ],
+        in_specs=dkv_in_specs,
+        out_specs=dkv_out_specs,
+        out_shape=dkv_out_shape,
+        scratch_shapes=dkv_scratch,
         interpret=interpret,
         name="flash_stream_bwd_dkv",
         compiler_params=_STREAM_GRID_PARAMS,
@@ -530,24 +632,26 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def _stream_block(d_qk, d_v, itemsize, dropout=False):
     """The streaming kernels' q and k block. A program pays a fixed latency
     per grid step, so blocks as large as VMEM allows: beside the f32 score
-    tiles [block, block] the backward kernels hold six [block, width]
-    operand tiles double-buffered, so the block halves as a row's bytes
-    double. A row's bytes are those of the mean of the key and the value
-    width, each as VMEM holds it (rounded up to the 128 lanes: latent
-    attention's 192-wide keys take two lane groups, its 128-wide values
-    one). Measured on the v5e, forward + backward: b4 h16 s4096 d128 bf16
-    causal (PERF.md section 6, PR 25) 256/256 26.2 ms, 512/512 12.7,
-    1024/512 11.6, 1024/1024 11.5; b1 h32 s8192 d192/128 bf16 causal (PR 29)
-    256 51.2, 512 31.8, 1024/512 29.6, 1024 28.8. Compiled for a described
+    tiles [block, block] the backward holds six [block, width] operand
+    tiles double-buffered, so the block halves as a row's bytes double. A
+    row's bytes are those of the mean of the key and the value width, each
+    as VMEM holds it (rounded up to the 128 lanes: latent attention's
+    192-wide keys take two lane groups, its 128-wide values one). That
+    account is the default 16 MiB scoped limit's; the one-pass backward
+    adds a whole row of dq (the f32 slab and its output block: 16 MiB at
+    8,192 x 192 bf16) and asks for ``_ONE_PASS_VMEM_LIMIT`` instead of a
+    smaller block. Measured on the v5e, forward + backward: b4 h16 s4096
+    d128 bf16 causal (PERF.md section 6, PR 25) 256/256 26.2 ms, 512/512
+    12.7, 1024/512 11.6, 1024/1024 11.5; b1 h32 s8192 d192/128 bf16 causal
+    (PR 29) 256 51.2, 512 31.8, 1024/512 29.6, 1024 28.8; with the backward
+    in one pass (PR 31) 1024: 8.63 and 22.60 (two calls: 11.52 and 28.76),
+    the JoyAI shape in float32 at 512 27.5 (35.5). Compiled for a described
     v5e (tests/test_mosaic_compile.py): 1024 runs out of VMEM at 512 bytes
     a row (d128 f32, d256 bf16) and at d192/128 f32 (768), 512 does not.
     With ``dropout`` the mask hash's [block, block] tiles take what one
     more lane group a row would: d192/128 bf16 ran out of VMEM at 1024 on
     the chip with a mask (chip_smoke.py, PR 29) and not without."""
-    def in_lanes(d):
-        return -(-d // LANES) * LANES
-
-    row_bytes = (in_lanes(d_qk) + in_lanes(d_v)) // 2 * itemsize
+    row_bytes = (_in_lanes(d_qk) + _in_lanes(d_v)) // 2 * itemsize
     if dropout:
         row_bytes += LANES
     return 1024 if row_bytes <= 384 else 512 if row_bytes <= 768 else 256
@@ -564,8 +668,10 @@ def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
     kernel (counter-based mask keyed by ``seed``, an int32 scalar array —
     pass a fresh seed per step; same seed -> same mask).
 
-    ``interpret=True`` runs the three kernels in the Pallas interpreter
-    (any backend) instead of compiling them with Mosaic."""
+    ``interpret=True`` runs the kernels (the forward and the one-pass
+    backward; past ``_one_pass_backward``'s budget the backward is two) in
+    the Pallas interpreter (any backend) instead of compiling them with
+    Mosaic."""
     squeeze = q.ndim == 3
     if squeeze:
         q, k, v = q[None], k[None], v[None]

@@ -45,9 +45,11 @@ GATE_CASES = ("announced-dp4", "plain-jit-over-mesh-arrays")
 #: the streaming kernel, causal (batch, heads, seq, head_dim, dtype): at the
 #: OLMoE cell's attention, at the same in float32 (the cell's reference
 #: check runs it) and at 512 bytes a row in bf16 — the block sizes
-#: ``_stream_block`` picks have to fit VMEM at each. STREAM_CALLS: the names
-#: its three Mosaic calls carry (the benchmark's flash_roofline finds them
-#: in a trace by these)
+#: ``_stream_block`` picks have to fit VMEM at each, the backward with a
+#: whole (batch . head) row of dq beside them. STREAM_CALLS: the names its
+#: two Mosaic calls carry (the benchmark's flash_roofline and
+#: mla_flash_roofline find them in a trace by ``flash_stream_`` and
+#: ``flash_stream_bwd_dkv``)
 STREAM_CASES = {
     "olmoe-cell-bf16": (4, 16, 4096, 128, "bfloat16"),
     "olmoe-check-f32": (1, 16, 4096, 128, "float32"),
@@ -61,8 +63,16 @@ STREAM_CASES = {
     "joyai-cell-bf16-p0.1": (1, 32, 8192, (192, 128), "bfloat16", 0.1),
     "olmoe-cell-bf16-p0.1": (4, 16, 4096, 128, "bfloat16", 0.1),
 }
-STREAM_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
-                "flash_stream_bwd_dkv")
+STREAM_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dkv_dq")
+#: a row of dq past the one-pass backward's VMEM budget (65,536 x 128 bf16:
+#: 64 MiB of slab and output block): the backward's two calls, each with
+#: its own streamed accumulator
+STREAM_TWO_CALL_CASES = {
+    "long-s65536-bf16": (1, 2, 65536, 128, "bfloat16"),
+}
+STREAM_TWO_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
+                    "flash_stream_bwd_dkv")
+ALL_STREAM_CASES = {**STREAM_CASES, **STREAM_TWO_CALL_CASES}
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
@@ -162,7 +172,8 @@ def _child():
                      "wider_admitted": wider,
                      "scores_in_hbm": f"{batch},{heads},{seq},{seq}" in text}
 
-    for name, (batch, heads, seq, head_dim, dtype, *p) in STREAM_CASES.items():
+    for name, (batch, heads, seq, head_dim, dtype, *p) in (
+            ALL_STREAM_CASES.items()):
         d_qk, d_v = (head_dim if isinstance(head_dim, tuple)
                      else (head_dim, head_dim))
         qk = jax.ShapeDtypeStruct((batch, heads, seq, d_qk), dtype,
@@ -176,7 +187,9 @@ def _child():
             argnums=(0, 1, 2))).lower(qk, qk, v).compile().as_text()
         out["stream-" + name] = {
             "mosaic": text.count(MOSAIC),
-            "calls": [c for c in STREAM_CALLS if c in text],
+            # a call's name, whole, as its op_name carries it
+            "calls": [c for c in dict.fromkeys(STREAM_CALLS + STREAM_TWO_CALLS)
+                      if f"({c})" in text],
             "scores_in_hbm": f"{seq},{seq}]" in text,
             "value_wide_results": text.count(
                 f"[{batch * heads},{seq},{d_v}]"),
@@ -320,20 +333,23 @@ def test_short_kernel_compiles(compiled, case):
     assert got["wider_admitted"] == case.startswith("cell-shape")
 
 
-@pytest.mark.parametrize("case", list(STREAM_CASES))
+@pytest.mark.parametrize("case", list(ALL_STREAM_CASES))
 def test_stream_kernel_compiles(compiled, case):
-    """The streaming kernel, forward and both backward calls, causal, at
-    the OLMoE cell's b4 h16 s4096 d128 bf16, in float32, at d256 and d64,
-    and at the JoyAI cell's b1 h32 s8192 with 192-wide keys and 128-wide
-    values (bf16 and the check's float32): three Mosaic calls under their
-    names within VMEM at the block sizes the kernel picks, no [seq, seq]
-    scores in HBM."""
+    """The streaming kernel, forward and the one-pass backward (dq, dk and
+    dv from one set of score tiles), causal, at the OLMoE cell's b4 h16
+    s4096 d128 bf16, in float32, at d256 and d64, and at the JoyAI cell's
+    b1 h32 s8192 with 192-wide keys and 128-wide values (bf16 and the
+    check's float32): two Mosaic calls under their names within the VMEM
+    the backward asks for at the block sizes the kernel picks, no
+    [seq, seq] scores in HBM. A row of dq past the slab's budget compiles
+    the two-call backward: three calls."""
     got = compiled["stream-" + case]
-    assert got["mosaic"] == 3 and got["calls"] == list(STREAM_CALLS)
+    calls = STREAM_CALLS if case in STREAM_CASES else STREAM_TWO_CALLS
+    assert got["mosaic"] == len(calls) and got["calls"] == list(calls)
     assert not got["scores_in_hbm"]
-    head_dim = STREAM_CASES[case][3]
+    head_dim = ALL_STREAM_CASES[case][3]
     if isinstance(head_dim, tuple):
-        # keys and values keep their own widths through all three calls:
+        # keys and values keep their own widths through every call:
         # nothing is padded to the other's
         assert got["value_wide_results"] and got["key_wide_results"]
 

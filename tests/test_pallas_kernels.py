@@ -266,6 +266,133 @@ def test_flash_head_dim_128(causal):
                                    rtol=3e-4, atol=3e-4)
 
 
+# ----------------------------------- the streaming kernel's one-pass backward
+
+def _ref_general(q, k, v, causal, seed, dropout_p):
+    """float32 attention with a value width of its own, the bottom-right
+    causal mask (row r sees keys <= r + seq_k - seq_q) and the kernel's
+    counter-hash dropout mask."""
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+    mask = jnp.ones((sq, sk), bool)
+    if causal:
+        mask = jnp.tril(mask, k=sk - sq)
+    # a row with no key at all (causal, seq_q > seq_k) attends to nothing
+    p = jnp.where(mask, jax.nn.softmax(jnp.where(mask, s, -1e30), -1), 0.0)
+    if dropout_p:
+        keep = _hash_keep_np(
+            seed, np.arange(b * h).reshape(b, h, 1, 1),
+            np.arange(sq).reshape(1, 1, sq, 1),
+            np.arange(sk).reshape(1, 1, 1, sk), sq, sk, dropout_p)
+        p = jnp.where(jnp.asarray(keep), p / (1.0 - dropout_p), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+#: (key width, value width, causal, seq_q, seq_k, dropout, dtype); blocks of
+#: 32, so four k blocks meet every q block (fewer under the causal mask)
+ONE_PASS_CASES = {
+    "d64-causal-f32": (64, 64, True, 128, 128, 0.0, "float32"),
+    "d64-full-bf16-p0.1": (64, 64, False, 128, 128, 0.1, "bfloat16"),
+    "d128-causal-bf16": (128, 128, True, 128, 128, 0.0, "bfloat16"),
+    "d128-full-f32-p0.1": (128, 128, False, 128, 128, 0.1, "float32"),
+    "d192/128-causal-f32-p0.1": (192, 128, True, 128, 128, 0.1, "float32"),
+    "d192/128-causal-bf16": (192, 128, True, 128, 128, 0.0, "bfloat16"),
+    "d192/128-full-f32": (192, 128, False, 128, 128, 0.0, "float32"),
+    "q<k-d64-causal-f32": (64, 64, True, 64, 128, 0.0, "float32"),
+    "q<k-d192/128-causal-bf16-p0.1": (192, 128, True, 64, 128, 0.1,
+                                      "bfloat16"),
+    "q<k-d128-full-f32": (128, 128, False, 64, 128, 0.0, "float32"),
+    # rows with no key at all: dq's slab rows are zeroed and written out
+    # though no k block adds to them
+    "q>k-d64-causal-f32": (64, 64, True, 128, 64, 0.0, "float32"),
+}
+
+
+@pytest.mark.parametrize("case", list(ONE_PASS_CASES))
+def test_stream_one_pass_backward(case, monkeypatch):
+    """dq, dk and dv from one set of score tiles (``flash_stream_bwd_dkv_dq``)
+    are the two-call path's BITWISE — the same products in the same order
+    into the same float32 accumulators — and a float32 reference's within
+    the file's tolerances."""
+    d_qk, d_v, causal, sq, sk, p_drop, dtype = ONE_PASS_CASES[case]
+    rng = np.random.RandomState(31)
+    q = jnp.array(rng.randn(1, 2, sq, d_qk) * 0.3, dtype)
+    k = jnp.array(rng.randn(1, 2, sk, d_qk) * 0.3, dtype)
+    v = jnp.array(rng.randn(1, 2, sk, d_v), dtype)
+    seed = 4321
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(
+            fn(q, k, v).astype(jnp.float32))), argnums=(0, 1, 2))(q, k, v)
+
+    def kernel(q, k, v):
+        return mha(q, k, v, causal=causal, dropout_p=p_drop,
+                   seed=jnp.int32(seed), block_q=32, block_k=32)
+
+    assert fa._one_pass_backward(sq, d_qk, q.dtype.itemsize)
+    one = grads(kernel)
+    monkeypatch.setattr(fa, "_one_pass_backward", lambda *shape: False)
+    two = grads(kernel)
+    ref = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(_ref_general(
+        q, k, v, causal, seed, p_drop))), argnums=(0, 1, 2))(
+        *(a.astype(jnp.float32) for a in (q, k, v)))
+    tol = 2e-3 if dtype == "float32" else 5e-2
+    for a, b, r in zip(one, two, ref):
+        assert a.dtype == b.dtype == jnp.dtype(dtype)
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r),
+                                   rtol=tol, atol=tol)
+
+
+def test_one_pass_backward_is_chosen_by_shape():
+    """The slab rule: a (batch . head) row of dq — float32 accumulator plus
+    the double-buffered output block, lane-padded — within the budget. Every
+    shape tests/test_mosaic_compile.py compiles for the chip takes one pass
+    (both LM cells', their float32 checks); a row past the budget takes the
+    two calls, and the rule turns exactly at the budget."""
+    from test_mosaic_compile import STREAM_CASES, STREAM_TWO_CALL_CASES
+
+    def one_pass(batch, heads, seq, head_dim, dtype, *dropout):
+        d_qk = head_dim[0] if isinstance(head_dim, tuple) else head_dim
+        return fa._one_pass_backward(seq, d_qk, jnp.dtype(dtype).itemsize)
+
+    assert all(one_pass(*shape) for shape in STREAM_CASES.values())
+    assert not any(one_pass(*shape)
+                   for shape in STREAM_TWO_CALL_CASES.values())
+    # 192-wide keys take two lane groups, like 256-wide ones
+    assert (fa._one_pass_backward(8192, 192, 2)
+            and not fa._one_pass_backward(32768, 192, 2))
+    rows = fa._ONE_PASS_SLAB_BUDGET // (256 * (4 + 2 * 2))
+    assert fa._one_pass_backward(rows, 256, 2)
+    assert not fa._one_pass_backward(rows + 8, 256, 2)
+    assert fa._ONE_PASS_SLAB_BUDGET < fa._ONE_PASS_VMEM_LIMIT
+
+
+@pytest.mark.parametrize("calls", ["one", "two"])
+def test_stream_backward_counter_names_the_path(calls, monkeypatch):
+    """``paddle_tpu_flash_stream_backward_total{calls}`` counts each traced
+    backward under the path it took, and a forward alone counts nothing."""
+    if calls == "two":
+        monkeypatch.setattr(fa, "_one_pass_backward", lambda *shape: False)
+    rng = np.random.RandomState(8)
+    q = jnp.array(rng.randn(1, 1, 64, 16), jnp.float32)
+
+    def count():
+        return {c: fa._BACKWARD_TOTAL.value(calls=c) for c in ("one", "two")}
+
+    before = count()
+    mha(q, q, q, causal=True, block_q=32, block_k=32)
+    assert count() == before
+    jax.grad(lambda q: jnp.sum(mha(q, q, q, causal=True, block_q=32,
+                                   block_k=32)))(q)
+    after = count()
+    assert after[calls] == before[calls] + 1
+    other = "two" if calls == "one" else "one"
+    assert after[other] == before[other]
+
+
 # ------------------------------------------- whole-sequence ("short") kernel
 
 from paddle_tpu.ops import attention  # noqa: E402
