@@ -1,0 +1,17 @@
+"""Device milliseconds a step in operations under a Kimi Delta Attention
+module (``text.models.KimiDeltaAttention``), forward, recomputed forward and
+backward: its projections, the three short convolutions, the L2 norms, the
+decay and beta, the chunked scan, the gated output norm and the output
+projection (traced slice, one device). None for a model without one."""
+from benchmark.harness import program_trace
+
+LAYER = "linear attention (ops/linear_attention.py, text/models.py)"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "train_samples_per_s"
+
+
+def read(record):
+    return program_trace.union_ms_per_step(
+        record, lambda scope: any(cls == "KimiDeltaAttention"
+                                  for _, cls in scope["modules"])) or None

@@ -63,6 +63,8 @@ FULL = dict(
     attn_shapes=((8, 12, 4096, 64), (4, 32, 2048, 128),
                  (1, 32, 8192, 192, 128)),
     short_shape=(256, 12, 128, 64),  # the BERT cells' attention, per chip
+    # one Kimi Delta Attention layer at the Kimi-Linear cell's head sizes
+    kda=dict(hidden_size=2304, num_heads=32, head_dim=128), kda_seq=2048,
     llama=dict(vocab_size=32000, hidden_size=1024, num_layers=4,
                num_heads=8, intermediate_size=2816, max_seq_len=2048),
     llama_seq=2048, llama_rows_per_chip=2,
@@ -77,6 +79,7 @@ TOY = dict(
     serve_requests=12, serve_clients=2,
     attn_shapes=((1, 2, 256, 64), (1, 2, 256, 24, 16)),
     short_shape=(4, 2, 128, 64),
+    kda=dict(hidden_size=64, num_heads=2, head_dim=16, chunk=16), kda_seq=40,
     llama=dict(vocab_size=256, hidden_size=64, num_layers=1, num_heads=2,
                intermediate_size=128, max_seq_len=256),
     llama_seq=256, llama_rows_per_chip=1,
@@ -100,6 +103,12 @@ SERVE_ATOL = {"tpu": 5e-2, "cpu": 1e-4}
 # to bf16, then multiplies. Errors are judged against the largest
 # reference magnitude of each tensor.
 ATTN_REL_TOL = 3e-2
+# A Kimi Delta Attention layer under amp O1 on the chunked scan vs the same
+# weights in float32 on the token recurrence: bf16 projections, q, k, v and
+# chunk operands (eps 3.9e-3 each) through a state that sums 2,048 tokens'
+# writes; judged against each tensor's largest magnitude. A wrong chunk
+# boundary or decay is off by O(1).
+KDA_REL_TOL = 5e-2
 # Dropout keep fraction over >= 6.5e4 causal entries (toy) / 8e8 (full):
 # binomial noise is ~1e-3 at most; bf16 rounding of the row sums it is
 # read from adds < 4e-3. (Observed on the v5e, PR 21: 0.8985.)
@@ -625,6 +634,7 @@ class Smoke:
             info[tag] = rec
 
         info["short"] = self.short_kernel()
+        info["kda"] = self.delta_layer()
 
         # the kernel inside the framework's own tape, amp and donation
         n, devs = self.n, self.devices
@@ -667,6 +677,55 @@ class Smoke:
             "mosaic_calls": n_calls, "loss": [round(loss0, 4),
                                               round(loss1, 4)]}
         return info
+
+    def delta_layer(self):
+        """One ``KimiDeltaAttention`` layer, forward and backward through
+        the eager tape: under amp O1 on the chunked scan (what the
+        Kimi-Linear cell's step runs) against float32 on the recurrence
+        over tokens, the same weights and input."""
+        import jax.numpy as jnp
+        import numpy as np
+        import paddle_tpu as paddle
+        from paddle_tpu.ops import linear_attention
+        from paddle_tpu.text.models import KimiDeltaAttention
+
+        seq = self.cfg["kda_seq"]
+        paddle.seed(6)
+        layer = KimiDeltaAttention(**self.cfg["kda"])
+        x = np.random.RandomState(6).randn(
+            1, seq, self.cfg["kda"]["hidden_size"]).astype(np.float32)
+        counts = {}
+
+        def run(path, amp):
+            """(output, d input, d A_log, d q_conv) on ``path``."""
+            before = linear_attention._CORE_TOTAL.value(path=path)
+            saved = linear_attention.core_path
+            linear_attention.core_path = lambda seq: path
+            try:
+                layer.clear_gradients()
+                t = paddle.to_tensor(x, stop_gradient=False)
+                with paddle.amp.auto_cast(enable=amp, level="O1",
+                                          dtype="bfloat16"):
+                    out = layer(t)
+                w = jnp.cos(jnp.arange(out.shape[-1], dtype=jnp.float32))
+                (out.astype("float32") * paddle.to_tensor(w)).sum().backward()
+            finally:
+                linear_attention.core_path = saved
+            counts[path] = linear_attention._CORE_TOTAL.value(
+                path=path) - before
+            return [out._value, t.grad._value, layer.A_log.grad._value,
+                    layer.q_conv.weight.grad._value]
+
+        t0 = time.monotonic()
+        got = run("chunked", amp=True)
+        chunked_s = time.monotonic() - t0
+        ref = run("recurrent", amp=False)
+        assert counts == {"chunked": 1, "recurrent": 1}, counts
+        worst = max(_rel_err(g, r) for g, r in zip(got, ref))
+        assert worst <= KDA_REL_TOL, worst
+        return {"seq": seq, **self.cfg["kda"],
+                "max_rel_err_vs_recurrence": round(worst, 5),
+                "smoke_chunked_fwd_bwd_s": round(chunked_s, 3)}
 
     def short_kernel(self):
         """The whole-sequence kernel at the BERT cells' attention shape,

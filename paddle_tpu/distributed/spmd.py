@@ -203,8 +203,11 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
     if recompute:
         forward_loss = jax.checkpoint(forward_loss, static_argnums=())
 
-    hypers = optimizer._hypers()
-    l1_coeff = type(optimizer)._take_l1(hypers)
+    # a parameter's own hypers, as the eager step takes them (its
+    # regularizer; AdamW's apply_decay_param_fun by its name)
+    named = dict(layer.named_parameters())
+    hypers = {n: optimizer._hypers(named[n]) for n in param_names}
+    l1_coeff = {n: type(optimizer)._take_l1(h) for n, h in hypers.items()}
     opt_update = type(optimizer)._update
     grad_clip = optimizer._grad_clip
 
@@ -222,7 +225,6 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
             opt_state_specs[n] = tuple(
                 (_zero1_spec(a, mesh) if sharding_stage >= 1 else repl)
                 for a in st)
-    named = dict(layer.named_parameters())
     has_mp = {n: getattr(named[n], "mp_spec", None) is not None
               for n in param_names}
     if sharding_stage >= 3:
@@ -307,10 +309,10 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
         with jax.named_scope("optimizer"):
             for name in param_names:
                 g = grads[name].astype(params[name].dtype)
-                if l1_coeff:
-                    g = g + l1_coeff * jnp.sign(params[name])
+                if l1_coeff[name]:
+                    g = g + l1_coeff[name] * jnp.sign(params[name])
                 out = opt_update(params[name], g, lr, *opt_state[name],
-                                 **hypers)
+                                 **hypers[name])
                 new_params[name] = out[0]
                 new_state[name] = tuple(out[1:])
         if use_local_grads and dgc_configs is not None:

@@ -314,10 +314,12 @@ def _combine(ys, topv, order, inv, *, shape, held=False):
     each, ``inv`` each pair's row or the zero row past them)."""
     with jax.named_scope("moe.combine"):
         n, k = topv.shape
-        back = _rows_from_held_order if held else _rows_to_token_order
-        by_token = back(ys, order, inv).reshape(n, k, -1)
-        out = jnp.einsum("nkh,nk->nh", by_token.astype(jnp.float32),
-                         topv.astype(jnp.float32))
+        if held:
+            out = _held_weighted_sum(ys, topv, order, inv)
+        else:
+            by_token = _rows_to_token_order(ys, order, inv).reshape(n, k, -1)
+            out = jnp.einsum("nkh,nk->nh", by_token.astype(jnp.float32),
+                             topv.astype(jnp.float32))
         return out.astype(jnp.promote_types(ys.dtype, topv.dtype)
                           ).reshape(shape)
 
@@ -353,24 +355,42 @@ _rows_to_held_order.defvjp(_rows_to_held_order_fwd, _rows_to_held_order_bwd)
 
 
 @jax.custom_vjp
-def _rows_from_held_order(ys, taken, inv):
-    """ys [rows, H] -> [N*k, H] in token-major order, zero at the pairs
-    that were not computed here."""
-    return jnp.concatenate([ys, jnp.zeros_like(ys[:1])])[inv]
+def _held_weighted_sum(ys, topv, taken, inv):
+    """ys [rows, H] and the weights [N, k] -> each token's weighted sum over
+    its k choices [N, H] in float32: the pairs gathered from the rows in
+    token-major order, zero at the pairs that were not computed here. The
+    gradient is taken from the ROWS — each row's weight times its token's
+    cotangent, each pair's weight gradient from its row — so a backward
+    pass holds [rows, H] where differentiating the gather would hold
+    [N, k, H] in float32 (1.2 GB at 16,384 tokens of 2,304, for 8,192
+    rows) and keep the gathered pairs for it."""
+    n, k = topv.shape
+    by_token = jnp.concatenate([ys, jnp.zeros_like(ys[:1])])[inv].reshape(
+        n, k, -1)
+    return jnp.einsum("nkh,nk->nh", by_token.astype(jnp.float32),
+                      topv.astype(jnp.float32))
 
 
-def _rows_from_held_order_fwd(ys, taken, inv):
-    return _rows_from_held_order(ys, taken, inv), (taken, inv)
+def _held_weighted_sum_fwd(ys, topv, taken, inv):
+    return _held_weighted_sum(ys, topv, taken, inv), (ys, topv, taken, inv)
 
 
-def _rows_from_held_order_bwd(res, g):
-    taken, inv = res
+def _held_weighted_sum_bwd(res, g):
+    ys, topv, taken, inv = res
+    n, k = topv.shape
     here = inv[taken] < taken.shape[0]
-    return jnp.where(here[:, None], g[taken], 0), None, None
+    g_rows = g[taken // k].astype(jnp.float32)
+    weights = topv.reshape(-1)[taken].astype(jnp.float32)
+    d_ys = jnp.where(here[:, None], weights[:, None] * g_rows, 0.0)
+    d_weights = jnp.where(here, jnp.sum(ys.astype(jnp.float32) * g_rows,
+                                        axis=-1), 0.0)
+    d_topv = jnp.zeros((n * k,), jnp.float32).at[taken].set(
+        d_weights, unique_indices=True)
+    return (d_ys.astype(ys.dtype), d_topv.reshape(n, k).astype(topv.dtype),
+            None, None)
 
 
-_rows_from_held_order.defvjp(_rows_from_held_order_fwd,
-                             _rows_from_held_order_bwd)
+_held_weighted_sum.defvjp(_held_weighted_sum_fwd, _held_weighted_sum_bwd)
 
 
 def held_rows(tokens, top_k, count, num_experts, factor, tile=512):

@@ -388,6 +388,32 @@ def conv3d(x, weight, bias=None, stride=1, padding=0, dilation=1, groups=1,
         dilation=_pair(dilation, 3), groups=int(groups), data_format=data_format, nd=3)
 
 
+def _causal_depthwise_conv1d(x, w, *, activation):
+    k = w.shape[0]
+    xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
+    padded = jnp.pad(xf, ((0, 0), (k - 1, 0), (0, 0)))
+    seq = x.shape[1]
+    out = sum(padded[:, j:j + seq] * wf[j] for j in range(k))
+    if activation == "silu":
+        out = jax.nn.silu(out)
+    return out.astype(x.dtype)
+
+
+def causal_depthwise_conv1d(x, weight, activation=None, name=None):
+    """The short convolution of linear-attention and state-space layers, on
+    ``x`` [batch, seq, channels] as it leaves a projection (no transpose to
+    ``conv1d``'s layout, no padding for the caller to get right): every
+    channel its own ``weight`` [kernel, channels] taps over the positions
+    t - kernel + 1 .. t (tap ``kernel - 1`` meets position t), zero history
+    before a row's start; ``activation`` None or 'silu'. The multiply-adds
+    are float32 whatever ``x`` is, the result takes x's dtype."""
+    if activation not in (None, "silu"):
+        raise ValueError(f"activation must be None or 'silu', got "
+                         f"{activation!r}")
+    return apply_op("causal_depthwise_conv1d", _causal_depthwise_conv1d, x,
+                    weight, activation=activation)
+
+
 def _conv_transpose_nd(x, w, b, *, stride, padding, output_padding, dilation, groups,
                        data_format, nd):
     """Transposed conv as the explicit input-gradient construction:

@@ -48,6 +48,25 @@ class Conv1D(_ConvNd):
                         self._dilation, self._groups, self._data_format)
 
 
+class CausalDepthwiseConv1D(Layer):
+    """``F.causal_depthwise_conv1d`` with its weight: [kernel_size,
+    channels], one filter a channel over the sequence axis of [batch, seq,
+    channels], no bias. Starts as torch's depthwise ``Conv1d`` does
+    (Uniform(+-1/sqrt(kernel_size)))."""
+
+    def __init__(self, channels, kernel_size, activation=None,
+                 weight_attr=None):
+        super().__init__()
+        self._activation = activation
+        bound = 1.0 / np.sqrt(kernel_size)
+        self.weight = self.create_parameter(
+            [kernel_size, channels], attr=weight_attr,
+            default_initializer=I.Uniform(-bound, bound))
+
+    def forward(self, x):
+        return F.causal_depthwise_conv1d(x, self.weight, self._activation)
+
+
 class Conv2D(_ConvNd):
     """reference: nn/layer/conv.py Conv2D -> operators/conv_op.cc."""
 

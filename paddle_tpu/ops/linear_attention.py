@@ -1,0 +1,354 @@
+"""Kimi Delta Attention's core: a gated delta rule whose decay is per key
+channel (Kimi Linear technical report, arXiv:2510.26692), as a recurrence
+over tokens and as the chunked scan a train step runs.
+
+Per head, with a state S in R^{d_k x d_v} (zero at a row's start), a decay
+``g_t <= 0`` per key channel (``alpha_t = exp(g_t)``), a write strength
+``beta_t`` and q, k, v of one token::
+
+    S'_t = Diag(alpha_t) S_{t-1}
+    u_t  = beta_t (v_t - S'_t^T k_t)
+    S_t  = S'_t + k_t u_t^T
+    o_t  = S_t^T q_t
+
+``kda_recurrent`` is that, one token a ``lax.scan`` step. ``kda_chunked``
+computes the same in chunks of C tokens. With ``G_r`` the decay summed from
+a chunk's first token to its r-th and ``S_0`` the state entering it::
+
+    A[r, i]  = beta_r <k_r * exp(G_r - G_i), k_i>            (i < r)
+    (I + A) U = Diag(beta) (V - (K * exp(G)) S_0)
+    o_r      = S_0^T (q_r * exp(G_r)) + sum_{i <= r} <q_r * exp(G_r - G_i), k_i> u_i
+    S_C      = Diag(exp(G_C)) S_0 + sum_i (k_i * exp(G_C - G_i)) u_i^T
+
+The decay per channel is the hazard: a pair term does not factor into
+``exp(G_r)`` times ``exp(-G_i)``, because the second overflows where a
+chunk's decay is strong. Every exponent here is a difference that is <= 0:
+the pair terms are computed exactly (one exponential a pair and channel) on
+the ``sub`` x ``sub`` diagonal sub-blocks, and against the row sub-block's
+own first token off the diagonal (``_pair_products``). ``T = (I + A)^-1``
+is built once a chunk (``_unit_lower_inverse``), so that only
+``U = T beta V - (T beta K exp(G)) S_0`` and the state's update run in the
+sequential loop over chunks; the outputs are batched matmuls after it.
+
+Precision: G, A, the inverse and the state are float32 whatever q, k and v
+are, and A's and the inverse's products ask for float32 in earnest
+(``Precision.HIGHEST``: they are a few GFLOP). The [C, d] x [d, d] and
+[C, C] x [C, d] products take operands of q's dtype (bf16 under amp O1)
+and accumulate in float32.
+
+Memory and time: the sequence is cut into segments of ``segment`` tokens,
+an outer scan over them carries the state, and what a backward pass keeps
+of one segment (a few arrays of [heads, tokens, d] in float32) is rebuilt
+from the segment's inputs (``jax.checkpoint`` on the segment), so the
+working set is one segment's and not the row's. On the v5e at 16,384 tokens
+(benchmark/tools/kda_candidates.py) a segment of 256 runs forward +
+backward in 53 ms where 2,048 takes 121: the transposed inner scan writes
+its stacked results a slice at a time, which costs by the stack's size.
+
+``gated_delta_rule`` is the entry point a layer calls: it picks the path
+from the length it can observe and counts the choice in
+``paddle_tpu_kda_core_total{path}``.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ..core.dispatch import apply_op
+from ..obs import metrics as obs_metrics
+
+_CORE_TOTAL = obs_metrics.counter(
+    "paddle_tpu_kda_core_total",
+    "gated-delta-rule cores by the path taken (chunked | recurrent); under "
+    "jit one count per traced layer call",
+    labelnames=("path",))
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+#: tokens in a diagonal sub-block, whose pair terms are computed exactly
+SUB = 16
+
+
+def kda_recurrent(q, k, v, g, beta, initial_state=None):
+    """The recurrence token by token. q, k, g: [B, T, H, d_k]; v: [B, T, H,
+    d_v]; beta: [B, T, H]. Returns (o [B, T, H, d_v] in v's dtype, the final
+    state [B, H, d_k, d_v] in float32). Everything is computed in float32."""
+    f32 = jnp.float32
+    b, _, h, dk = k.shape
+    state = (jnp.zeros((b, h, dk, v.shape[-1]), f32) if initial_state is None
+             else initial_state.astype(f32))
+
+    def step(s, xs):
+        q_t, k_t, v_t, g_t, beta_t = xs
+        s = jnp.exp(g_t)[..., None] * s
+        u = beta_t[..., None] * (v_t - jnp.einsum(
+            "bhkv,bhk->bhv", s, k_t, precision=_HIGHEST))
+        s = s + k_t[..., None] * u[..., None, :]
+        return s, jnp.einsum("bhkv,bhk->bhv", s, q_t, precision=_HIGHEST)
+
+    xs = tuple(jnp.moveaxis(x.astype(f32), 1, 0) for x in (q, k, v, g, beta))
+    state, o = jax.lax.scan(step, state, xs)
+    return jnp.moveaxis(o, 0, 1).astype(v.dtype), state
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _unit_lower_inverse(a, sub):
+    """X = (I + a)^-1 for strictly lower triangular ``a`` [..., n, n], n a
+    power of two times ``sub``. The ``sub`` x ``sub`` diagonal blocks are
+    inverted row by row (forward substitution, vector operations); two
+    neighbours [[P, 0], [R, Q]] then merge into [[P^-1, 0], [-Q^-1 R P^-1,
+    Q^-1]] until one block is left. (A recursion down to single rows is the
+    same arithmetic in a hundred small matmuls: it took the TPU compiler
+    four minutes a layer.) Its gradient is the inverse's own, d a = -X^T
+    (d X) X^T on the strict lower triangle: two products, where
+    differentiating the substitution scatters a row at a time."""
+    n = a.shape[-1]
+
+    def diagonal(m, row, col):
+        """The [m, m] blocks at block (2j + row, 2j + col) of every pair,
+        or with row = col = None every diagonal block: [..., blocks, m, m]"""
+        if row is None:
+            at = [(j, j) for j in range(n // m)]
+        else:
+            at = [(2 * j + row, 2 * j + col) for j in range(n // (2 * m))]
+        return jnp.stack([a[..., r * m:(r + 1) * m, c * m:(c + 1) * m]
+                          for r, c in at], axis=-3)
+
+    blocks = diagonal(sub, None, None)
+    eye = jnp.eye(sub, dtype=a.dtype)
+    rows = [jnp.broadcast_to(eye[0], blocks.shape[:-2] + (sub,))]
+    for r in range(1, sub):
+        done = jnp.stack(rows, axis=-2)
+        rows.append(eye[r] - jnp.sum(blocks[..., r, :r, None] * done,
+                                     axis=-2))
+    inv, m = jnp.stack(rows, axis=-2), sub
+    while m < n:
+        p, q = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        low = -jnp.matmul(jnp.matmul(q, diagonal(m, 1, 0),
+                                     precision=_HIGHEST), p,
+                          precision=_HIGHEST)
+        inv = jnp.concatenate(
+            [jnp.concatenate([p, jnp.zeros_like(p)], axis=-1),
+             jnp.concatenate([low, q], axis=-1)], axis=-2)
+        m *= 2
+    return inv[..., 0, :, :]
+
+
+def _unit_lower_inverse_fwd(a, sub):
+    x = _unit_lower_inverse(a, sub)
+    return x, x
+
+
+def _unit_lower_inverse_bwd(sub, x, g):
+    xt = jnp.swapaxes(x, -1, -2)
+    grad = -jnp.matmul(jnp.matmul(xt, g, precision=_HIGHEST), xt,
+                       precision=_HIGHEST)
+    n = x.shape[-1]
+    return (jnp.where(jnp.arange(n)[None, :] < jnp.arange(n)[:, None], grad,
+                      0.0),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+@jax.custom_vjp
+def _diagonal_pairs(qb, kb, gb):
+    """The pair terms inside the diagonal sub-blocks, exactly (one
+    exponential a (r, i, channel)): qb, kb, gb [..., sub, d] -> ``<k_r *
+    exp(G_r - G_i), k_i>`` for i < r and ``<q_r * exp(G_r - G_i), k_i>`` for
+    i <= r, zero elsewhere, each [..., sub, sub]."""
+    sub = kb.shape[-2]
+    r, i = jnp.arange(sub)[:, None], jnp.arange(sub)[None, :]
+    pairs = kb[..., None, :, :] * _sub_block_decay(gb)
+    return (jnp.where(i < r, jnp.sum(kb[..., :, None, :] * pairs, axis=-1),
+                      0.0),
+            jnp.where(i <= r, jnp.sum(qb[..., :, None, :] * pairs, axis=-1),
+                      0.0))
+
+
+def _sub_block_decay(gb):
+    """exp(G_r - G_i) [..., r, i, d], the exponent held at 0 above the
+    diagonal (where it would be positive and the term is masked)."""
+    return jnp.exp(jnp.minimum(gb[..., :, None, :] - gb[..., None, :, :],
+                               0.0))
+
+
+def _diagonal_pairs_fwd(qb, kb, gb):
+    return _diagonal_pairs(qb, kb, gb), (qb, kb, gb)
+
+
+def _diagonal_pairs_bwd(res, cotangents):
+    """Three passes over the [r, i, d] terms where differentiating the
+    forward takes five: with E = exp(G_r - G_i) and the cotangents masked
+    as the outputs are, t_k[r] = sum_i c_k[r, i] k_i E and t_q likewise are
+    the gradients of the row's k_r and q_r, t_i[i] = sum_r (c_k k_r + c_q
+    q_r) E that of the column's k_i, and the decay's is k_r t_k + q_r t_q at
+    the row minus k_i t_i at the column (the r = i term cancels)."""
+    qb, kb, gb = res
+    sub = kb.shape[-2]
+    r, i = jnp.arange(sub)[:, None], jnp.arange(sub)[None, :]
+    c_k = jnp.where(i < r, cotangents[0], 0.0)[..., None]
+    c_q = jnp.where(i <= r, cotangents[1], 0.0)[..., None]
+    decay = _sub_block_decay(gb)
+    pairs = kb[..., None, :, :] * decay
+    t_k = jnp.sum(c_k * pairs, axis=-2)
+    t_q = jnp.sum(c_q * pairs, axis=-2)
+    t_i = jnp.sum((c_k * kb[..., :, None, :] + c_q * qb[..., :, None, :])
+                  * decay, axis=-3)
+    return t_q, t_k + t_i, kb * (t_k - t_i) + qb * t_q
+
+
+_diagonal_pairs.defvjp(_diagonal_pairs_fwd, _diagonal_pairs_bwd)
+
+
+def _pair_products(q, k, cum, sub):
+    """The intra-chunk pair terms ``<x_r * exp(G_r - G_i), k_i>`` for x = k
+    and x = q: ([..., C, C] for k, zero unless i < r; for q, zero unless
+    i <= r). q, k, cum: [..., C, d] in float32, ``cum`` the inclusive sum
+    of the decay from the chunk's start. No exponent is positive."""
+    *lead, c, d = k.shape
+    nb = c // sub
+
+    def blocks(x):
+        return x.reshape(*lead, nb, sub, d)
+
+    qb, kb, gb = blocks(q), blocks(k), blocks(cum)
+    diag_k, diag_q = _diagonal_pairs(qb, kb, gb)
+    rows_k, rows_q = [], []
+    for i in range(nb):
+        parts_k, parts_q = [diag_k[..., i, :, :]], [diag_q[..., i, :, :]]
+        if i:
+            # against the row sub-block's first token: rows at or after it
+            # have decayed from it, columns before it decay up to it
+            ref = gb[..., i, :1, :]
+            left = jnp.concatenate([kb[..., i, :, :], qb[..., i, :, :]],
+                                   axis=-2) * jnp.tile(
+                jnp.exp(gb[..., i, :, :] - ref), (2, 1))
+            right = (kb[..., :i, :, :] * jnp.exp(
+                ref[..., None, :, :] - gb[..., :i, :, :])).reshape(
+                    *lead, i * sub, d)
+            off = jnp.einsum("...rd,...id->...ri", left, right,
+                             precision=_HIGHEST)
+            parts_k.insert(0, off[..., :sub, :])
+            parts_q.insert(0, off[..., sub:, :])
+        if i < nb - 1:
+            zeros = jnp.zeros((*lead, sub, (nb - 1 - i) * sub), k.dtype)
+            parts_k.append(zeros)
+            parts_q.append(zeros)
+        rows_k.append(jnp.concatenate(parts_k, axis=-1))
+        rows_q.append(jnp.concatenate(parts_q, axis=-1))
+    return (jnp.concatenate(rows_k, axis=-2),
+            jnp.concatenate(rows_q, axis=-2))
+
+
+def _segment(state, xs, *, sub):
+    """One segment of whole chunks. xs: q, k [B, H, N, C, d_k] and
+    v [B, H, N, C, d_v] in the operand dtype, g [B, H, N, C, d_k] and
+    beta [B, H, N, C] in float32; state [B, H, d_k, d_v] float32. Returns
+    (the state after the segment, o [B, H, N, C, d_v] float32)."""
+    q, k, v, g, beta = xs
+    f32, mm = jnp.float32, q.dtype
+    # the decay summed from the chunk's start, as a product with the lower
+    # triangle of ones (a cumsum lowers to a window reduction: 0.26 ms a
+    # segment on the v5e against 0.02)
+    c = g.shape[-2]
+    cum = jnp.einsum("ri,...id->...rd", jnp.tril(jnp.ones((c, c), f32)), g,
+                     precision=_HIGHEST)
+    last = cum[..., -1:, :]
+    qf, kf = q.astype(f32), k.astype(f32)
+    a_kk, a_qk = _pair_products(qf, kf, cum, sub)
+    # T = (I + A)^-1 Diag(beta): row r of A carries beta_r, T's columns
+    # carry the right-hand side's
+    t = (_unit_lower_inverse(beta[..., :, None] * a_kk, sub)
+         * beta[..., None, :]).astype(mm)
+    w = jnp.einsum("...ri,...id->...rd", t, (kf * jnp.exp(cum)).astype(mm),
+                   preferred_element_type=f32).astype(mm)
+    u_v = jnp.einsum("...ri,...id->...rd", t, v, preferred_element_type=f32)
+    k_out = (kf * jnp.exp(last - cum)).astype(mm)
+    decay = jnp.exp(last[..., 0, :])
+
+    def chunk(s, xs):
+        w_n, u_n, k_n, decay_n = xs
+        u = u_n - jnp.einsum("bhck,bhkv->bhcv", w_n, s.astype(mm),
+                             preferred_element_type=f32)
+        new = decay_n[..., None] * s + jnp.einsum(
+            "bhck,bhcv->bhkv", k_n, u.astype(mm), preferred_element_type=f32)
+        return new, (s, u)
+
+    state, (entering, u) = jax.lax.scan(chunk, state, tuple(
+        jnp.moveaxis(x, 2, 0) for x in (w, u_v, k_out, decay)))
+    entering, u = jnp.moveaxis(entering, 0, 2), jnp.moveaxis(u, 0, 2)
+    o = (jnp.einsum("bhnck,bhnkv->bhncv", (qf * jnp.exp(cum)).astype(mm),
+                    entering.astype(mm), preferred_element_type=f32)
+         + jnp.einsum("bhnri,bhniv->bhnrv", a_qk.astype(mm), u.astype(mm),
+                      preferred_element_type=f32))
+    return state, o
+
+
+def kda_chunked(q, k, v, g, beta, initial_state=None, *, chunk=64,
+                segment=256, sub=SUB):
+    """The same function as ``kda_recurrent`` (same arguments and results),
+    in chunks of ``chunk`` tokens (a power of two, at least ``sub``). Any
+    length: the row is padded to whole chunks with tokens that write nothing
+    and decay nothing. The large products take q's dtype as their operands'
+    (module docstring); the state, and o before its cast to v's dtype, are
+    float32."""
+    f32 = jnp.float32
+    b, t, h, dk = k.shape
+    dv = v.shape[-1]
+    sub = min(sub, chunk)
+    if chunk & (chunk - 1) or chunk % sub:
+        raise ValueError(f"chunk {chunk} must be a power of two and a "
+                         f"multiple of {sub}")
+    n = -(-t // chunk)
+    per_segment = max(1, min(segment // chunk, n))
+    segments = -(-n // per_segment)
+    pad = segments * per_segment * chunk - t
+
+    def split(x, dtype):
+        """[B, T, H, ...] -> [segments, B, H, chunks, C, ...]"""
+        x = jnp.pad(x.astype(dtype), [(0, 0), (0, pad)] + [(0, 0)] * (
+            x.ndim - 2))
+        x = x.reshape(b, segments, per_segment, chunk, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 4, 1), 2, 0)
+
+    xs = (split(q, q.dtype), split(k, q.dtype), split(v, q.dtype),
+          split(g, f32), split(beta, f32))
+    state = (jnp.zeros((b, h, dk, dv), f32) if initial_state is None
+             else initial_state.astype(f32))
+    body = functools.partial(_segment, sub=sub)
+    if segments == 1:
+        state, o = body(state, tuple(x[0] for x in xs))
+        o = o[None]
+    else:
+        state, o = jax.lax.scan(jax.checkpoint(body), state, xs)
+    # [segments, B, H, chunks, C, d_v] -> [B, T, H, d_v]
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 4).reshape(
+        b, segments * per_segment * chunk, h, dv)
+    return o[:, :t].astype(v.dtype), state
+
+
+def core_path(seq):
+    """``chunked`` | ``recurrent`` for a row of ``seq`` tokens: a chunk's
+    set-up (pair terms, an inverse) pays from one diagonal sub-block on."""
+    return "chunked" if seq >= SUB else "recurrent"
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
+    """Kimi Delta Attention's core on Tensors (shapes as ``kda_recurrent``):
+    o [B, T, H, d_v]. The final state stays inside: training starts every
+    row from a zero state and keeps none."""
+    path = core_path(q.shape[1])
+    _CORE_TOTAL.inc(path=path)
+    if path == "recurrent":
+        return apply_op("kda_core_recurrent", _recurrent_output, q, k, v, g,
+                        beta)
+    return apply_op("kda_core", _chunked_output, q, k, v, g, beta,
+                    chunk=int(chunk))
+
+
+def _recurrent_output(q, k, v, g, beta):
+    return kda_recurrent(q, k, v, g, beta)[0]
+
+
+def _chunked_output(q, k, v, g, beta, *, chunk):
+    return kda_chunked(q, k, v, g, beta, chunk=chunk)[0]

@@ -512,25 +512,35 @@ class MLAttention(nn.Layer):
     ``rope_dim`` query features and on the ONE ``rope_dim``-wide k_r that
     all heads share, then a causal core whose keys (``nope_dim + rope_dim``)
     are wider than its values (``v_dim``), through the dispatching sdpa (the
-    streaming kernel takes the two widths as they are)."""
+    streaming kernel takes the two widths as they are).
+    ``q_lora_rank=None``: one q projection (``q_proj``), no norm between.
+    ``rope=False`` (Kimi Linear's ``mla_use_nope``): nothing is rotated, the
+    ``rope_dim``-wide shared key part is broadcast and concatenated as it
+    is; positions then reach the layer only through the causal mask."""
 
     def __init__(self, hidden_size, num_heads, q_lora_rank, kv_lora_rank,
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
-                 rms_norm_eps=1e-6, rope_theta=10000.0, weight_attr=None):
+                 rms_norm_eps=1e-6, rope_theta=10000.0, weight_attr=None,
+                 rope=True):
         super().__init__()
         self.num_heads = num_heads
         self.nope_dim, self.rope_dim = qk_nope_head_dim, qk_rope_head_dim
         self.v_dim = v_head_dim
         self.kv_rank = kv_lora_rank
         self.rope_theta = float(rope_theta)
+        self.rope = bool(rope)
 
         def proj(i, o):
             return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
 
         qk = qk_nope_head_dim + qk_rope_head_dim
-        self.q_a_proj = proj(hidden_size, q_lora_rank)
-        self.q_a_layernorm = RMSNorm(q_lora_rank, eps=rms_norm_eps)
-        self.q_b_proj = proj(q_lora_rank, num_heads * qk)
+        self.q_lora_rank = q_lora_rank
+        if q_lora_rank is None:
+            self.q_proj = proj(hidden_size, num_heads * qk)
+        else:
+            self.q_a_proj = proj(hidden_size, q_lora_rank)
+            self.q_a_layernorm = RMSNorm(q_lora_rank, eps=rms_norm_eps)
+            self.q_b_proj = proj(q_lora_rank, num_heads * qk)
         self.kv_a_proj_with_mqa = proj(hidden_size,
                                        kv_lora_rank + qk_rope_head_dim)
         self.kv_a_layernorm = RMSNorm(kv_lora_rank, eps=rms_norm_eps)
@@ -551,21 +561,26 @@ class MLAttention(nn.Layer):
         def _split(kv_a, *, rank):
             return kv_a[..., :rank], kv_a[..., rank:]
 
-        def _heads(q, kv, k_r, *, base):
+        def _heads(q, kv, k_r, *, base, rotate):
             b, s, _ = q.shape
             q = q.reshape(b, s, nh, nope + rope).transpose(0, 2, 1, 3)
             kv = kv.reshape(b, s, nh, nope + dv).transpose(0, 2, 1, 3)
-            # interleaved pairs (2i, 2i + 1) as the checkpoint stores them
-            # (rope_interleave); one rotated k_r serves every head
-            q_r = _rope(q[..., nope:], base)
-            k_r = _rope(k_r[:, None], base)
+            k_r = k_r[:, None]
+            if rotate:
+                # interleaved pairs (2i, 2i + 1) as the checkpoint stores
+                # them (rope_interleave); one rotated k_r serves every head
+                q = jnp.concatenate([q[..., :nope],
+                                     _rope(q[..., nope:], base)], axis=-1)
+                k_r = _rope(k_r, base)
             k_r = jnp.broadcast_to(k_r, (b, nh, s, rope))
-            return (jnp.concatenate([q[..., :nope], q_r], axis=-1),
-                    jnp.concatenate([kv[..., :nope], k_r], axis=-1),
+            return (q, jnp.concatenate([kv[..., :nope], k_r], axis=-1),
                     kv[..., nope:])
 
         with jax.named_scope("mla.q"):
-            q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+            if self.q_lora_rank is None:
+                q = self.q_proj(x)
+            else:
+                q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
         with jax.named_scope("mla.kv"):
             c_kv, k_r = apply_op("mla_split", _split,
                                  self.kv_a_proj_with_mqa(x),
@@ -573,7 +588,7 @@ class MLAttention(nn.Layer):
             kv = self.kv_b_proj(self.kv_a_layernorm(c_kv))
         with jax.named_scope("mla.rope"):
             q, k, v = apply_op("mla_heads_rope", _heads, q, kv, k_r,
-                               base=self.rope_theta)
+                               base=self.rope_theta, rotate=self.rope)
         with jax.named_scope("mla.core"):
             out = _sdpa(q, k, v, is_causal=True, training=self.training)
 
@@ -586,22 +601,29 @@ class MLAttention(nn.Layer):
 
 
 class JoyAIDecoderLayer(nn.Layer):
-    """Pre-norm block of the DeepSeek-V3 family: latent attention, then a
-    dense SwiGLU (the leading layers) or the expert layer — sigmoid router
-    with a selection bias, renormalised top-k times ``routed_scaling_factor``,
-    a shared expert, and the held range of the routed experts."""
+    """Pre-norm block of the DeepSeek-V3 family and of Kimi Linear: a token
+    mixer by layer type — latent attention (``mixer='mla'``) or Kimi Delta
+    Attention (``'kda'``, sized by ``cfg['kda']``) — then a dense SwiGLU (the
+    leading layers) or the expert layer: sigmoid router with a selection
+    bias, renormalised top-k times ``routed_scaling_factor``, a shared
+    expert, and the held range of the routed experts."""
 
-    def __init__(self, cfg, dense, weight_attr=None):
+    def __init__(self, cfg, dense, weight_attr=None, mixer="mla"):
         super().__init__()
         from ..incubate.moe import MoELayer
 
         hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
         self.input_layernorm = RMSNorm(hidden, eps=eps)
-        self.self_attn = MLAttention(
-            hidden, cfg["num_attention_heads"], cfg["q_lora_rank"],
-            cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
-            cfg["qk_rope_head_dim"], cfg["v_head_dim"], eps,
-            cfg["rope_theta"], weight_attr)
+        if mixer == "kda":
+            self.self_attn = KimiDeltaAttention(
+                hidden, rms_norm_eps=eps, weight_attr=weight_attr,
+                **cfg["kda"])
+        else:
+            self.self_attn = MLAttention(
+                hidden, cfg["num_attention_heads"], cfg["q_lora_rank"],
+                cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
+                cfg["qk_rope_head_dim"], cfg["v_head_dim"], eps,
+                cfg["rope_theta"], weight_attr, rope=cfg.get("rope", True))
         self.post_attention_layernorm = RMSNorm(hidden, eps=eps)
         if dense:
             self.mlp = LlamaMLP(hidden, cfg["intermediate_size"],
@@ -627,7 +649,39 @@ class JoyAIDecoderLayer(nn.Layer):
         return x + self.mlp(self.post_attention_layernorm(x))
 
 
-class JoyAIFlashModel(nn.Layer):
+class _BlockwiseModel(nn.Layer):
+    """What the decoders that run block by block share: a block called
+    plainly or under ``fleet.utils.recompute`` (``use_recompute``, in a
+    training trace), and the taps a block-by-block comparison feeds on."""
+
+    def __init__(self, use_recompute):
+        super().__init__()
+        self.use_recompute = bool(use_recompute)
+        self._tap = None
+
+    def _block(self, block, *inputs):
+        if self.use_recompute and self.training:
+            from ..distributed.fleet.utils import recompute
+
+            out = recompute(block, *inputs)
+        else:
+            out = block(*inputs)
+        if self._tap is not None:
+            self._tap.append((block, inputs, out))
+        return out
+
+    @contextlib.contextmanager
+    def tapped(self):
+        """Collect (block, its inputs, its output) of every decoder block
+        and MTP module called inside."""
+        self._tap = taps = []
+        try:
+            yield taps
+        finally:
+            self._tap = None
+
+
+class JoyAIFlashModel(_BlockwiseModel):
     """JoyAI-LLM-Flash (HF ``joyai_llm_flash``; the DeepSeek-V3 family's
     equations): ``first_k_dense_replace`` dense blocks, then expert blocks,
     every one with latent attention; a final norm and an untied head; and
@@ -655,7 +709,7 @@ class JoyAIFlashModel(nn.Layer):
                  bias_update_speed=0.001, balance_loss_weight=0.0,
                  initializer_range=0.02, held_experts=None,
                  held_rows_factor=2.0, use_recompute=False):
-        super().__init__()
+        super().__init__(use_recompute)
         from ..framework.param_attr import ParamAttr
 
         def attr():
@@ -678,8 +732,6 @@ class JoyAIFlashModel(nn.Layer):
             balance_loss_weight=balance_loss_weight,
             held_experts=None if held_experts is None else tuple(held_experts),
             held_rows_factor=held_rows_factor)
-        self.use_recompute = bool(use_recompute)
-        self._tap = None
         self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
                                          weight_attr=attr())
         self.layers = nn.LayerList([
@@ -692,28 +744,6 @@ class JoyAIFlashModel(nn.Layer):
         self.mtp = nn.LayerList([
             MultiTokenPredictor(cfg, weight_attr=attr())
             for _ in range(num_nextn_predict_layers)])
-
-    def _block(self, block, *inputs):
-        if self.use_recompute and self.training:
-            from ..distributed.fleet.utils import recompute
-
-            out = recompute(block, *inputs)
-        else:
-            out = block(*inputs)
-        if self._tap is not None:
-            self._tap.append((block, inputs, out))
-        return out
-
-    @contextlib.contextmanager
-    def tapped(self):
-        """Collect (block, its inputs, its output) of every decoder block
-        and MTP module called inside: what a block-by-block comparison
-        with a reference feeds on."""
-        self._tap = taps = []
-        try:
-            yield taps
-        finally:
-            self._tap = None
 
     def _trunk(self, input_ids):
         """The last block's output, before the final norm."""
@@ -795,3 +825,260 @@ def mtp_lm_loss(hidden, mtp_hidden, head_weight, input_ids, mtp_weight=0.3):
         mtp = mtp + t
     mtp = mtp / float(len(terms))
     return main + mtp * float(mtp_weight), main, mtp
+
+
+#: Kimi-Linear-48B-A3B's layer pattern (1-indexed, as its config.json
+#: writes it): three Kimi Delta Attention layers, then one of latent
+#: attention, and latent attention again in the last layer
+KIMI_LINEAR_ATTN = {
+    "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21,
+                   22, 23, 25, 26],
+    "full_attn_layers": [4, 8, 12, 16, 20, 24, 27],
+    "num_heads": 32, "head_dim": 128, "short_conv_kernel_size": 4}
+
+
+# A KDA layer's element-wise stages run at [tokens, heads x head_dim] in
+# float32: kept for a backward pass they would be a dozen 268 MB arrays a
+# layer at 16,384 tokens. Each stage is a ``jax.checkpoint`` of its own: a
+# differentiated program keeps the stage's (bf16 or low-rank) inputs and
+# rebuilds the float32 inside it; without differentiation it is the plain
+# function.
+def _kda_streams(q, k, v, w_q, w_k, w_v, *, heads, eps):
+    """The projected [B, T, H * d] streams -> heads [B, T, H, d]: each
+    through its causal depthwise convolution and SiLU, q and k then
+    L2-normalised over a head's features (in float32), q scaled by
+    d^-0.5."""
+    import jax
+    import jax.numpy as jnp
+
+    def conv(x, w):
+        return F._causal_depthwise_conv1d(x, w, activation="silu")
+
+    def split(x):
+        return x.reshape(*x.shape[:-1], heads, x.shape[-1] // heads)
+
+    def l2(x, scale):
+        xf = split(x).astype(jnp.float32)
+        return (xf * (jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True)
+                                    + eps) * scale)).astype(x.dtype)
+
+    def streams(q, k, v, w_q, w_k, w_v):
+        d = q.shape[-1] // heads
+        return (l2(conv(q, w_q), d ** -0.5), l2(conv(k, w_k), 1.0),
+                split(conv(v, w_v)))
+
+    return jax.checkpoint(streams)(q, k, v, w_q, w_k, w_v)
+
+
+def _kda_decay(low, w_up, a_log, dt_bias, *, heads):
+    """g = -exp(A_log_h) softplus(low W + dt_bias) in float32: [B, T, H, d],
+    the decay's logarithm per head and key channel."""
+    import jax
+    import jax.numpy as jnp
+
+    def decay(low, w_up, a_log, dt_bias):
+        f32 = jnp.float32
+        z = jnp.dot(low.astype(f32), w_up.astype(f32)) + dt_bias.astype(f32)
+        z = z.reshape(*z.shape[:-1], heads, z.shape[-1] // heads)
+        return -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(z)
+
+    return jax.checkpoint(decay)(low, w_up, a_log, dt_bias)
+
+
+def _kda_beta(x, w):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                  w.astype(jnp.float32)))
+
+
+def _kda_gated_norm(o, gate, w, *, eps):
+    """sigmoid(gate) * RMSNorm_d(o) per head with the learned d-wide weight,
+    in float32; [B, T, H, d] -> [B, T, H * d] in o's dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    def gated(o, gate, w):
+        of = o.astype(jnp.float32)
+        normed = of * jax.lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True)
+                                    + eps) * w.astype(jnp.float32)
+        out = normed.reshape(gate.shape) * jax.nn.sigmoid(
+            gate.astype(jnp.float32))
+        return out.astype(o.dtype)
+
+    return jax.checkpoint(gated)(o, gate, w)
+
+
+class KimiDeltaAttention(nn.Layer):
+    """Kimi Delta Attention (Kimi Linear technical report, arXiv:2510.26692;
+    HF ``kimi_linear``): q, k and v each through a projection, a causal
+    depthwise convolution of ``short_conv_kernel_size`` taps and SiLU; q and
+    k L2-normalised a head; a decay per head AND key channel from a
+    low-rank pair, ``g = -exp(A_log) softplus(f_b(f_a(x)) + dt_bias)``; a
+    write strength ``beta = sigmoid(b(x))`` a head; the gated delta rule's
+    state recurrence (``ops.linear_attention``: the chunked scan from one
+    sub-block of tokens on); and ``o_proj(sigmoid(g_b(g_a(x))) *
+    RMSNorm_d(o))``. The decay, beta, the scan's state and the output norm
+    are float32 under amp O1; the projections and the scan's large products
+    take bf16 operands."""
+
+    def __init__(self, hidden_size, num_heads=32, head_dim=128,
+                 short_conv_kernel_size=4, gate_rank=None, chunk=64,
+                 rms_norm_eps=1e-5, l2_eps=1e-6, weight_attr=None):
+        super().__init__()
+        self.num_heads, self.head_dim = num_heads, head_dim
+        self.chunk, self.l2_eps = int(chunk), float(l2_eps)
+        inner = num_heads * head_dim
+        rank = gate_rank or head_dim
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        def conv():
+            return nn.CausalDepthwiseConv1D(inner, short_conv_kernel_size,
+                                            activation="silu")
+
+        self.q_proj, self.k_proj, self.v_proj = (
+            proj(hidden_size, inner) for _ in range(3))
+        self.q_conv, self.k_conv, self.v_conv = conv(), conv(), conv()
+        self.f_a_proj, self.f_b_proj = proj(hidden_size, rank), proj(rank,
+                                                                     inner)
+        # A = exp(A_log) ~ U(1, 16) a head; dt_bias the inverse softplus of
+        # dt ~ exp(U(log 1e-3, log 1e-1)) a channel (the Mamba-2 convention)
+        # named, so that an optimizer's apply_decay_param_fun can tell them
+        # (neither is decayed where the model was trained)
+        from ..framework.param_attr import ParamAttr
+
+        def named(name, low, high):
+            return ParamAttr(name=f"{self.full_name()}.{name}",
+                             initializer=nn.initializer.Uniform(low, high))
+
+        self.A_log = self.create_parameter(
+            [num_heads], attr=named("A_log", 1.0, 16.0))
+        self.A_log.set_value(np.log(np.asarray(self.A_log._value)))
+        self.dt_bias = self.create_parameter(
+            [inner], attr=named("dt_bias", math.log(1e-3), math.log(1e-1)))
+        dt = np.exp(np.asarray(self.dt_bias._value, np.float64))
+        self.dt_bias.set_value(dt + np.log(-np.expm1(-dt)))
+        self.b_proj = proj(hidden_size, num_heads)
+        self.g_a_proj, self.g_b_proj = proj(hidden_size, rank), proj(rank,
+                                                                     inner)
+        self.o_norm = RMSNorm(head_dim, eps=rms_norm_eps)
+        self.o_proj = proj(inner, hidden_size)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops.linear_attention import gated_delta_rule
+
+        with jax.named_scope("kda.proj"):
+            q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
+        with jax.named_scope("kda.conv"):
+            q, k, v = apply_op(
+                "kda_streams", _kda_streams, q, k, v, self.q_conv.weight,
+                self.k_conv.weight, self.v_conv.weight,
+                heads=self.num_heads, eps=self.l2_eps)
+        with jax.named_scope("kda.gate"):
+            g = apply_op("kda_decay", _kda_decay, self.f_a_proj(x),
+                         self.f_b_proj.weight, self.A_log, self.dt_bias,
+                         heads=self.num_heads)
+            beta = apply_op("kda_beta", _kda_beta, x, self.b_proj.weight)
+            gate = self.g_b_proj(self.g_a_proj(x))
+        with jax.named_scope("kda.core"):
+            o = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
+        with jax.named_scope("kda.out"):
+            return self.o_proj(apply_op(
+                "kda_gated_norm", _kda_gated_norm, o, gate,
+                self.o_norm.weight, eps=self.o_norm.eps))
+
+
+class KimiLinearModel(_BlockwiseModel):
+    """Kimi-Linear-48B-A3B (HF ``kimi_linear``): pre-norm blocks whose mixer
+    goes by layer type — Kimi Delta Attention on ``linear_attn_config``'s
+    ``kda_layers``, latent attention without positions (no q rank, nothing
+    rotated) on its ``full_attn_layers``, both lists 1-indexed — and whose
+    feed-forward is a dense SwiGLU in the first ``first_k_dense_replace``
+    layers and the DeepSeek-V3 family's expert layer elsewhere; a final norm
+    and an untied head; no MTP module. Defaults are the published sizes.
+
+    ``held_experts=(first, count)`` gives every expert layer this chip's
+    range of the routed experts; ``use_recompute`` runs each block under
+    ``fleet.utils.recompute`` in a traced step. ``forward`` gives the
+    logits; a training loss takes ``features`` and ``lm_head.weight`` to
+    ``mtp_lm_loss`` (no MTP term) or ``F.linear_cross_entropy``."""
+
+    def __init__(self, vocab_size=163840, hidden_size=2304,
+                 num_hidden_layers=27, num_attention_heads=32,
+                 intermediate_size=9216, moe_intermediate_size=1024,
+                 n_routed_experts=256, num_experts_per_tok=8,
+                 n_shared_experts=1, first_k_dense_replace=1,
+                 kv_lora_rank=512, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, rms_norm_eps=1e-5,
+                 norm_topk_prob=True, routed_scaling_factor=2.446,
+                 linear_attn_config=None, kda_gate_rank=None, kda_chunk=64,
+                 bias_update_speed=0.001, balance_loss_weight=0.0,
+                 initializer_range=0.02, held_experts=None,
+                 held_rows_factor=2.0, use_recompute=False):
+        super().__init__(use_recompute)
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        linear = dict(linear_attn_config or KIMI_LINEAR_ATTN)
+        cfg = dict(
+            hidden_size=hidden_size, num_attention_heads=num_attention_heads,
+            intermediate_size=intermediate_size,
+            moe_intermediate_size=moe_intermediate_size,
+            n_routed_experts=n_routed_experts,
+            num_experts_per_tok=num_experts_per_tok,
+            n_shared_experts=n_shared_experts, q_lora_rank=None,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rms_norm_eps=rms_norm_eps, rope_theta=10000.0, rope=False,
+            norm_topk_prob=norm_topk_prob,
+            routed_scaling_factor=routed_scaling_factor,
+            bias_update_speed=bias_update_speed,
+            balance_loss_weight=balance_loss_weight,
+            held_experts=None if held_experts is None else tuple(held_experts),
+            held_rows_factor=held_rows_factor,
+            kda=dict(num_heads=linear["num_heads"],
+                     head_dim=linear["head_dim"],
+                     short_conv_kernel_size=linear["short_conv_kernel_size"],
+                     gate_rank=kda_gate_rank, chunk=kda_chunk))
+        self.layer_types = kimi_layer_types(linear, num_hidden_layers)
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            JoyAIDecoderLayer(cfg, dense=i < first_k_dense_replace,
+                              weight_attr=attr(), mixer=mixer)
+            for i, mixer in enumerate(self.layer_types)])
+        self.norm = RMSNorm(hidden_size, eps=rms_norm_eps)
+        self.lm_head = nn.Linear(hidden_size, vocab_size,
+                                 weight_attr=attr(), bias_attr=False)
+
+    def features(self, input_ids):
+        x = self.embed_tokens(input_ids)
+        for layer in self.layers:
+            x = self._block(layer, x)
+        return self.norm(x)
+
+    def forward(self, input_ids):
+        return self.lm_head(self.features(input_ids))
+
+
+def kimi_layer_types(linear_attn_config, num_hidden_layers):
+    """['kda' | 'mla'] for layers 0 .. n - 1 from the config's two
+    1-indexed lists; a layer in neither, or in both, is an error."""
+    kda = set(linear_attn_config["kda_layers"])
+    full = set(linear_attn_config["full_attn_layers"])
+    types = []
+    for i in range(1, num_hidden_layers + 1):
+        if (i in kda) == (i in full):
+            raise ValueError(f"layer {i} must be in exactly one of "
+                             "kda_layers and full_attn_layers")
+        types.append("kda" if i in kda else "mla")
+    return types
