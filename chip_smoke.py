@@ -33,6 +33,7 @@ stdout: a header line, one JSON line per phase, and as the last line
 if every phase passed and paddle_tpu raised no RuntimeWarning.
 """
 import argparse
+import collections
 import contextlib
 import faulthandler
 import json
@@ -79,7 +80,8 @@ TOY = dict(
     serve_requests=12, serve_clients=2,
     attn_shapes=((1, 2, 256, 64), (1, 2, 256, 24, 16)),
     short_shape=(4, 2, 128, 64),
-    kda=dict(hidden_size=64, num_heads=2, head_dim=16, chunk=16), kda_seq=40,
+    # one lane group a head and over one chunk: the kernels' shortest case
+    kda=dict(hidden_size=64, num_heads=2, head_dim=128), kda_seq=72,
     llama=dict(vocab_size=256, hidden_size=64, num_layers=1, num_heads=2,
                intermediate_size=128, max_seq_len=256),
     llama_seq=256, llama_rows_per_chip=1,
@@ -103,8 +105,9 @@ SERVE_ATOL = {"tpu": 5e-2, "cpu": 1e-4}
 # to bf16, then multiplies. Errors are judged against the largest
 # reference magnitude of each tensor.
 ATTN_REL_TOL = 3e-2
-# A Kimi Delta Attention layer under amp O1 on the chunked scan vs the same
-# weights in float32 on the token recurrence: bf16 projections, q, k, v and
+# A Kimi Delta Attention layer under amp O1 on the scan (the Mosaic kernels,
+# and the XLA scan beside them) vs the same weights in float32 on the token
+# recurrence: bf16 projections, q, k, v and
 # chunk operands (eps 3.9e-3 each) through a state that sums 2,048 tokens'
 # writes; judged against each tensor's largest magnitude. A wrong chunk
 # boundary or decay is off by O(1).
@@ -680,27 +683,39 @@ class Smoke:
 
     def delta_layer(self):
         """One ``KimiDeltaAttention`` layer, forward and backward through
-        the eager tape: under amp O1 on the chunked scan (what the
-        Kimi-Linear cell's step runs) against float32 on the recurrence
-        over tokens, the same weights and input."""
+        the eager tape: under amp O1 on the path the platform gives (the
+        Mosaic kernels where this process has one device, what the
+        Kimi-Linear cell's step runs) and on the XLA scan it falls back to,
+        each against float32 on the recurrence over tokens, the same
+        weights and input."""
+        import jax
         import jax.numpy as jnp
         import numpy as np
         import paddle_tpu as paddle
         from paddle_tpu.ops import linear_attention
         from paddle_tpu.text.models import KimiDeltaAttention
 
-        seq = self.cfg["kda_seq"]
+        seq, d = self.cfg["kda_seq"], self.cfg["kda"]["head_dim"]
         paddle.seed(6)
         layer = KimiDeltaAttention(**self.cfg["kda"])
         x = np.random.RandomState(6).randn(
             1, seq, self.cfg["kda"]["hidden_size"]).astype(np.float32)
-        counts = {}
+        given = linear_attention.core_path(seq, d, d, jnp.bfloat16)
+        # an eager call on a host with several chips does not know its
+        # program's devices: there the XLA scan is what the route gives
+        assert given == ("kernel" if self.dry_run or jax.device_count() == 1
+                         else "chunked"), given
+        counts, times = {}, {}
 
         def run(path, amp):
-            """(output, d input, d A_log, d q_conv) on ``path``."""
-            before = linear_attention._CORE_TOTAL.value(path=path)
+            """(output, d input, d A_log, d q_conv) on ``path`` (None: the
+            route's own choice)."""
+            label = path or given
+            before = linear_attention._CORE_TOTAL.value(path=label)
             saved = linear_attention.core_path
-            linear_attention.core_path = lambda seq: path
+            if path:
+                linear_attention.core_path = lambda *shape: path
+            t0 = time.monotonic()
             try:
                 layer.clear_gradients()
                 t = paddle.to_tensor(x, stop_gradient=False)
@@ -711,21 +726,26 @@ class Smoke:
                 (out.astype("float32") * paddle.to_tensor(w)).sum().backward()
             finally:
                 linear_attention.core_path = saved
-            counts[path] = linear_attention._CORE_TOTAL.value(
-                path=path) - before
-            return [out._value, t.grad._value, layer.A_log.grad._value,
-                    layer.q_conv.weight.grad._value]
+            out = [out._value, t.grad._value, layer.A_log.grad._value,
+                   layer.q_conv.weight.grad._value]
+            jax.block_until_ready(out)
+            times[label] = time.monotonic() - t0
+            counts[label] = counts.get(label, 0) + (
+                linear_attention._CORE_TOTAL.value(path=label) - before)
+            return out
 
-        t0 = time.monotonic()
-        got = run("chunked", amp=True)
-        chunked_s = time.monotonic() - t0
+        got, fallback = run(None, amp=True), run("chunked", amp=True)
         ref = run("recurrent", amp=False)
-        assert counts == {"chunked": 1, "recurrent": 1}, counts
-        worst = max(_rel_err(g, r) for g, r in zip(got, ref))
-        assert worst <= KDA_REL_TOL, worst
-        return {"seq": seq, **self.cfg["kda"],
-                "max_rel_err_vs_recurrence": round(worst, 5),
-                "smoke_chunked_fwd_bwd_s": round(chunked_s, 3)}
+        assert counts == collections.Counter(
+            [given, "chunked", "recurrent"]), counts
+        worst = {path: max(_rel_err(g, r) for g, r in zip(outs, ref))
+                 for path, outs in ((given, got), ("chunked", fallback))}
+        assert max(worst.values()) <= KDA_REL_TOL, worst
+        return {"seq": seq, **self.cfg["kda"], "path": given,
+                "max_rel_err_vs_recurrence": {
+                    p: round(e, 5) for p, e in worst.items()},
+                "smoke_fwd_bwd_s": {p: round(times[p], 3)
+                                    for p in worst}}
 
     def short_kernel(self):
         """The whole-sequence kernel at the BERT cells' attention shape,

@@ -1,6 +1,9 @@
 """Kimi Delta Attention's core: a gated delta rule whose decay is per key
 channel (Kimi Linear technical report, arXiv:2510.26692), as a recurrence
-over tokens and as the chunked scan a train step runs.
+over tokens and as the chunked scan: here in XLA operations (what a CPU, a
+program whose devices are not known and odd widths run, and what the tests
+hold everything to), in ``ops/pallas/linear_attention.py`` as the Mosaic
+kernels a train step on a TPU runs.
 
 Per head, with a state S in R^{d_k x d_v} (zero at a row's start), a decay
 ``g_t <= 0`` per key channel (``alpha_t = exp(g_t)``), a write strength
@@ -46,8 +49,12 @@ backward in 53 ms where 2,048 takes 121: the transposed inner scan writes
 its stacked results a slice at a time, which costs by the stack's size.
 
 ``gated_delta_rule`` is the entry point a layer calls: it picks the path
-from the length it can observe and counts the choice in
-``paddle_tpu_kda_core_total{path}``.
+from what it can observe (``core_path``: length, widths, dtype, platform,
+whether the program's devices are known) and counts the choice in
+``paddle_tpu_kda_core_total{path}``. A train step of the Kimi-Linear
+configuration on a TPU takes ``kernel``: the same mathematics, a chunk's
+terms in VMEM, forward and backward hand-written, nothing of this file's
+segments; ``chunked`` and ``recurrent`` are this file's.
 """
 import functools
 
@@ -59,8 +66,8 @@ from ..obs import metrics as obs_metrics
 
 _CORE_TOTAL = obs_metrics.counter(
     "paddle_tpu_kda_core_total",
-    "gated-delta-rule cores by the path taken (chunked | recurrent); under "
-    "jit one count per traced layer call",
+    "gated-delta-rule cores by the path taken (kernel | chunked | "
+    "recurrent); under jit one count per traced layer call",
     labelnames=("path",))
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -327,21 +334,45 @@ def kda_chunked(q, k, v, g, beta, initial_state=None, *, chunk=64,
     return o[:, :t].astype(v.dtype), state
 
 
-def core_path(seq):
-    """``chunked`` | ``recurrent`` for a row of ``seq`` tokens: a chunk's
-    set-up (pair terms, an inverse) pays from one diagonal sub-block on."""
-    return "chunked" if seq >= SUB else "recurrent"
+def core_path(seq, d_k=None, d_v=None, dtype=None):
+    """``kernel`` | ``chunked`` | ``recurrent`` for a row of ``seq`` tokens
+    with keys ``d_k`` and values ``d_v`` wide in ``dtype``, from what can be
+    observed: a chunk's set-up (pair terms, an inverse) pays from one
+    diagonal sub-block on, else the recurrence; the Mosaic kernels
+    (``ops/pallas/linear_attention.py``) where the platform compiles them
+    (a TPU, or the ``pallas_interpret`` flag), the program's devices are
+    known (``ops.attention._placeable``: GSPMD cannot partition a Mosaic
+    call), the widths are one multiple of the 128 lanes, the operands bf16
+    or float32 and the row at least one chunk; the XLA scan everything
+    else."""
+    if seq < SUB:
+        return "recurrent"
+    from . import attention
+    from .pallas import linear_attention as kernels
+
+    if (d_k is not None and seq >= kernels.CHUNK
+            and kernels.supported(d_k, d_v, dtype)
+            and attention._use_pallas() and attention._placeable()):
+        return "kernel"
+    return "chunked"
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
     """Kimi Delta Attention's core on Tensors (shapes as ``kda_recurrent``):
     o [B, T, H, d_v]. The final state stays inside: training starts every
     row from a zero state and keeps none."""
-    path = core_path(q.shape[1])
+    path = core_path(q.shape[1], q.shape[-1], v.shape[-1], q.dtype)
     _CORE_TOTAL.inc(path=path)
     if path == "recurrent":
         return apply_op("kda_core_recurrent", _recurrent_output, q, k, v, g,
                         beta)
+    if path == "kernel":
+        from ..core import flags
+
+        # interpret rides the static kwargs so a flag flip retraces
+        return apply_op(
+            "kda_core_kernel", _kernel_output, q, k, v, g, beta,
+            interpret=bool(flags.flag_value("pallas_interpret")))
     return apply_op("kda_core", _chunked_output, q, k, v, g, beta,
                     chunk=int(chunk))
 
@@ -352,3 +383,20 @@ def _recurrent_output(q, k, v, g, beta):
 
 def _chunked_output(q, k, v, g, beta, *, chunk):
     return kda_chunked(q, k, v, g, beta, chunk=chunk)[0]
+
+
+def _kernel_output(q, k, v, g, beta, *, interpret):
+    """The Mosaic kernels, under a step's announced mesh inside the
+    ``shard_map`` the attention kernels use: rows over the data axes, heads
+    (dim 2 of all five arrays) over 'mp', each where it divides."""
+    from . import attention
+    from .pallas import linear_attention as kernels
+
+    def kernel(q, k, v, g, beta, seed):
+        del seed                                  # no dropout in the scan
+        return kernels.kda(q, k.astype(q.dtype), v.astype(q.dtype), g, beta,
+                           interpret=interpret).astype(v.dtype)
+
+    return attention._on_mesh(kernel, (q, k, v, g, beta),
+                              jnp.zeros((), jnp.int32), head_axis=2,
+                              seed_per_shard=False)

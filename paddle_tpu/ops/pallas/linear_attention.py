@@ -1,0 +1,682 @@
+"""The gated delta rule's chunked scan (``ops/linear_attention.py``, whose
+docstring has the equations) as two Pallas TPU kernels under a
+``jax.custom_vjp``: ``kda_chunk_fwd`` and ``kda_chunk_bwd``.
+
+A program is one batch row, ``TOGETHER`` heads and one block of ``TOKENS``
+tokens, taken from the layer's arrays viewed as [batch, tokens, heads x d]
+(block ``(1, tokens, G d)`` at ``(b, n, h)``, a head a lane-aligned slice
+of it: the scan's [heads, chunks] order never exists in HBM). The block
+axis is the grid's last, ``arbitrary``: the heads' states [d_k, d_v] live
+in float32 VMEM scratch across it. A block's set-up — everything that does
+not wait for the state — is computed for all its chunks and heads at once,
+as batched products; only two products a chunk wait for one another, the
+program's heads side by side, and the transposes they would need are made
+in the set-up.
+
+A chunk, in VMEM and registers only:
+
+- ``G`` = the decay summed from the chunk's first token (a product with
+  the triangle of ones, float32 in earnest: three bf16 passes over the
+  decay split exactly in three);
+- the pair terms ``<x_r * exp(G_r - G_i), k_i>`` for x = k (i < r) and
+  x = q (i <= r): on the ``SUB`` x ``SUB`` diagonal sub-blocks exactly, one
+  exponential a (r, i, channel), a column of every sub-block at a time;
+  off the diagonal against the row sub-block's first token, as a product.
+  No exponent is positive anywhere: a difference that could be is held at
+  0 where its term is masked. A column of a sub-block's later half meets
+  the rows of that half only, so those columns run on half the rows;
+- ``X = (I + diag(beta) A)^-1``: diagonal blocks of ``INVERSE_SUB`` rows by
+  forward substitution (rank-one updates on their columns), then block
+  merges ``[[P, 0], [-Q R P, Q]]`` up to the chunk, float32 in earnest;
+- ``T = X diag(beta)``, ``W = T (K e^G)``, ``U = T V - W S``, the state's
+  update and ``o = (q e^G) S + A_qk U``: operands of q's dtype, float32
+  accumulation.
+
+The forward under differentiation also writes what the backward takes
+instead of rebuilding it: each chunk's entering state [d_k, d_v] and its
+three [C, C] matrices (A for k, A for q, X), all float32 — 112 KB a chunk
+and head, alive from a block's recomputed forward to its backward. The
+backward walks the blocks and their chunks in reverse with ``dS`` in VMEM
+scratch, recomputes G, the decays, W and U from them, and emits dq, dk, dv
+(q's dtype), dg and dbeta (float32): the inverse's gradient is ``-X^T dX
+X^T`` on the strict lower triangle, the diagonal pair terms' is three
+sums over the same [r, i, d] terms the forward made (``ops/
+linear_attention._diagonal_pairs_bwd``), a column at a time again.
+
+``interpret=True`` runs both in the Pallas interpreter (the CPU tests and
+the chip_smoke dry run ask for it; never inferred from the backend).
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+CHUNK = 64
+#: tokens in a diagonal sub-block (``ops.linear_attention.SUB``)
+SUB = 16
+#: rows of the diagonal blocks the inverse takes by substitution (vector
+#: work, a step a row); the block merges above them are products
+INVERSE_SUB = 8
+#: tokens a program takes: its set-up is batched over their chunks, so more
+#: of them is more code and more to overlap
+TOKENS = 256
+#: heads a program takes, side by side in the lanes of its blocks: their
+#: states' products, which wait for one another along a row, interleave
+TOGETHER = 4
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+_F32 = jnp.float32
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=64 * 2**20)
+
+
+def supported(d_k, d_v, dtype):
+    """Whether the kernels take these widths and this operand dtype: one
+    width for keys and values that fills whole lane groups."""
+    return (d_k == d_v and d_k % 128 == 0
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(jnp.float32)))
+
+
+def _precision(a, precision):
+    """bf16 operands multiply as they are, whatever precision the caller's
+    context (``jax.default_matmul_precision``) asks of float32 ones."""
+    return jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 else precision
+
+
+def _dot(a, b, dims, precision=None):
+    return jax.lax.dot_general(a, b, dims, precision=_precision(a, precision),
+                               preferred_element_type=_F32)
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _grid_masks():
+    """[C, C] index planes: row, column."""
+    return _iota((CHUNK, CHUNK), 0), _iota((CHUNK, CHUNK), 1)
+
+
+def _eye(n):
+    return _iota((n, n), 0) == _iota((n, n), 1)
+
+
+def _local(rows):
+    """[C * rows / SUB, C]: for the ``rows`` last rows of every diagonal
+    sub-block, the column's place in the row's own sub-block."""
+    shape = (CHUNK * rows // SUB, CHUNK)
+    return _iota(shape, 1) - (_iota(shape, 0) // rows) * SUB
+
+
+def _bmm(a, b, dims, precision=None):
+    """A product a chunk: [N, ., .] x [N, ., .], ``dims`` the contracted
+    axes of one chunk's pair (``_NN`` | ``_NT`` | ``_TN``)."""
+    (ca,), (cb,) = dims[0]
+    return jax.lax.dot_general(
+        a, b, (((ca + 1,), (cb + 1,)), ((0,), (0,))),
+        precision=_precision(a, precision), preferred_element_type=_F32)
+
+
+def _own(x, j, sub=SUB):
+    """Row j of every sub-block of ``sub`` rows, for each of its rows:
+    [..., n] -> the same shape."""
+    x3 = x.reshape(-1, sub, x.shape[-1])
+    return jnp.broadcast_to(x3[:, j:j + 1, :], x3.shape).reshape(x.shape)
+
+
+def _lower_rows(x):
+    """The later half of every diagonal sub-block's rows: [N, C, n] -> [N,
+    C / 2, n] (whole 8-row tiles)."""
+    x3 = x.reshape(-1, SUB, x.shape[-1])[:, SUB // 2:]
+    return x3.reshape(x.shape[0], CHUNK // 2, x.shape[-1])
+
+
+def _with_lower_rows(x, lower):
+    """``x`` [N, C, n] with those rows replaced."""
+    x3 = x.reshape(-1, SUB, x.shape[-1])
+    return jnp.concatenate(
+        [x3[:, :SUB // 2], lower.reshape(-1, SUB // 2, x.shape[-1])],
+        axis=1).reshape(x.shape)
+
+
+def _in_three(x):
+    """x in float32 as three bf16 terms that sum to it exactly."""
+    terms, rest = [], x
+    for _ in range(3):
+        terms.append(rest.astype(jnp.bfloat16))
+        rest = rest - terms[-1].astype(_F32)
+    return terms
+
+
+def _ones_product(ones, x):
+    """ones [C, C] (entries 0 or 1) times x [N, C, d], float32 in earnest
+    in three bf16 passes instead of a float32 product's six: a 0 or 1
+    multiplies each of x's three bf16 terms exactly, and the sums are
+    float32."""
+    ones = jnp.broadcast_to(ones.astype(jnp.bfloat16),
+                            (x.shape[0], CHUNK, CHUNK))
+    return sum(_bmm(ones, term, _NN) for term in _in_three(x))
+
+
+def _picked(x, ones, dims):
+    """x [rows, .] times a matrix of 0 and 1 [., .], the same way."""
+    ones = ones.astype(jnp.bfloat16)
+    return sum(_dot(term, ones, dims) for term in _in_three(x))
+
+
+def _decay_sums(g):
+    """G [N, C, d]: g summed from each chunk's first token, inclusive."""
+    r, i = _grid_masks()
+    return _ones_product(i <= r, g)
+
+
+def _pair_terms(qf, kf, cum):
+    """(A_kk zero unless i < r, A_qk zero unless i <= r), each [N, C, C]
+    float32, from float32 q, k and the decay sums [N, C, d]."""
+    r, i = _grid_masks()
+    n = kf.shape[0]
+    a_kk = jnp.zeros((n, CHUNK, CHUNK), _F32)
+    a_qk = jnp.zeros((n, CHUNK, CHUNK), _F32)
+
+    def column(j, qf, kf, cum, rows, a_kk, a_qk):
+        """Column j of every diagonal sub-block into the two stacks, for
+        the sub-blocks' ``rows`` given (all SUB, or their later half: the
+        column's own row stands at ``j - (SUB - rows)`` among them). Rows
+        before the column are masked at the end, their exponent held at 0
+        here."""
+        at_row = j - (SUB - rows)
+        pairs = _own(kf, at_row, rows) * jnp.exp(
+            jnp.minimum(cum - _own(cum, at_row, rows), 0.0))
+        at = _local(rows) == j
+        return (jnp.where(at, jnp.sum(kf * pairs, axis=-1, keepdims=True),
+                          a_kk),
+                jnp.where(at, jnp.sum(qf * pairs, axis=-1, keepdims=True),
+                          a_qk))
+
+    for j in range(SUB // 2):
+        a_kk, a_qk = column(j, qf, kf, cum, SUB, a_kk, a_qk)
+    # a column of a sub-block's later half meets rows of that half only
+    lower = tuple(map(_lower_rows, (qf, kf, cum, a_kk, a_qk)))
+    low_kk, low_qk = lower[3:]
+    for j in range(SUB // 2, SUB):
+        low_kk, low_qk = column(j, *lower[:3], SUB // 2, low_kk, low_qk)
+    a_kk = _with_lower_rows(a_kk, low_kk)
+    a_qk = _with_lower_rows(a_qk, low_qk)
+    rows_kk, rows_qk = [a_kk[:, :SUB]], [a_qk[:, :SUB]]
+    for s in range(1, CHUNK // SUB):
+        rows = slice(s * SUB, (s + 1) * SUB)
+        left, right, _, _ = _against_first(qf, kf, cum, s)
+        off = _bmm(left, right, _NT, _HIGHEST)               # [N, 2 SUB, C]
+        before = _iota((SUB, CHUNK), 1) < s * SUB
+        rows_kk.append(jnp.where(before, off[:, :SUB], a_kk[:, rows]))
+        rows_qk.append(jnp.where(before, off[:, SUB:], a_qk[:, rows]))
+    a_kk = jnp.concatenate(rows_kk, axis=1)
+    a_qk = jnp.concatenate(rows_qk, axis=1)
+    return jnp.where(i < r, a_kk, 0.0), jnp.where(i <= r, a_qk, 0.0)
+
+
+def _against_first(qf, kf, cum, s):
+    """Row sub-block s against its own first token: (k and q of its rows,
+    decayed from that token on, [N, 2 SUB, d]; every k decayed up to it —
+    held where it comes after — [N, C, d]; and the two decays)."""
+    rows = slice(s * SUB, (s + 1) * SUB)
+    ref = cum[:, s * SUB:s * SUB + 1]
+    grown = jnp.exp(cum[:, rows] - ref)
+    shrunk = jnp.exp(jnp.minimum(ref - cum, 0.0))
+    left = jnp.concatenate([kf[:, rows] * grown, qf[:, rows] * grown], axis=1)
+    return left, kf * shrunk, grown, shrunk
+
+
+def _fold(sub):
+    """[C, sub]: picks, a row, the ``sub`` columns of its own diagonal
+    block of that size."""
+    return _iota((CHUNK, sub), 0) % sub == _iota((CHUNK, sub), 1)
+
+
+def _same_block(sub):
+    """[C, C]: whether a column lies in the row's own diagonal block."""
+    r, i = _grid_masks()
+    return r // sub == i // sub
+
+
+def _own_columns(a, sub=SUB):
+    """[N, C, C] -> [N C, sub]: each row's entries in its own diagonal
+    block of ``sub`` columns."""
+    return _picked(jnp.where(_same_block(sub), a, 0.0).reshape(-1, CHUNK),
+                   _fold(sub), _NN)
+
+
+def _unit_lower_inverse(n):
+    """(I + n)^-1 for strictly lower triangular n [N, C, C], float32."""
+    r, i = _grid_masks()
+    chunks, sub = n.shape[0], INVERSE_SUB
+    # the diagonal blocks of ``sub`` rows, ``sub`` columns wide: x starts
+    # as the identity; once row j of a block is final, every later row r of
+    # it takes -n[r, j] times that row
+    nd = _own_columns(n, sub)                                # [N C, sub]
+    x = (_iota(nd.shape, 1) == _iota(nd.shape, 0) % sub).astype(_F32)
+    for j in range(sub - 1):
+        x = x - nd[:, j:j + 1] * _own(x, j, sub)
+    x = _picked(x, _fold(sub), _NT).reshape(chunks, CHUNK, CHUNK)
+    x = jnp.where(_same_block(sub), x, 0.0)                  # block diagonal
+    # the merges: with R the blocks under the diagonal at this level,
+    # [[P, 0], [R, Q]]^-1 = X - X R X for the block-diagonal X so far; only
+    # the rows of the lower blocks move
+    m = sub
+    while m < CHUNK:
+        under = _same_block(2 * m) & ~_same_block(m)
+        pieces = [x[:, b * m:(b + 1) * m] for b in range(CHUNK // m)]
+        lower = jnp.concatenate(pieces[1::2], axis=1)        # [N, C / 2, C]
+        low = _bmm(_bmm(lower, jnp.where(under, n, 0.0), _NN, _HIGHEST), x,
+                   _NN, _HIGHEST)
+        for b in range(1, CHUNK // m, 2):
+            pieces[b] = pieces[b] - low[:, (b // 2) * m:(b // 2 + 1) * m]
+        x = jnp.concatenate(pieces, axis=1)
+        m *= 2
+    return x
+
+
+def _as_row(x):
+    """[N, C, 1] -> [N, 1, C] without a transpose: the diagonal's column
+    sums."""
+    return jnp.sum(jnp.where(_eye(CHUNK), x, 0.0), axis=1, keepdims=True)
+
+
+def _as_column(x):
+    """[N, 1, C] -> [N, C, 1], the diagonal's row sums."""
+    return jnp.sum(jnp.where(_eye(CHUNK), x, 0.0), axis=2, keepdims=True)
+
+
+def _set_up(q, k, v, g, beta, mats=None):
+    """What a block's chunks need that does not wait for the state. q, k,
+    v [N, C, d] (the operand dtype), g [N, C, d] and beta [N, 1, C]
+    float32; ``mats`` = (A_kk, A_qk, X) where the caller kept them. A dict
+    of [N, ...] stacks."""
+    mm = q.dtype
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    cum = _decay_sums(g)
+    if mats is None:
+        a_kk, a_qk = _pair_terms(qf, kf, cum)
+        x = _unit_lower_inverse(_as_column(beta) * a_kk)
+    else:
+        a_kk, a_qk, x = mats
+    grown = jnp.exp(cum)
+    last = cum[:, CHUNK - 1:CHUNK]                           # [N, 1, d]
+    shrunk = jnp.exp(last - cum)
+    t = (x * beta).astype(mm)
+    kg = (kf * grown).astype(mm)
+    w = _bmm(t, kg, _NN)
+    k_out = kf * shrunk
+    decay = jnp.exp(last)
+    # the transposes the loop over chunks would wait for are made here
+    return dict(
+        qf=qf, kf=kf, cum=cum, a_kk=a_kk, a_qk=a_qk, x=x, grown=grown,
+        shrunk=shrunk, decay=decay, t=t, kg=kg, w=w.astype(mm),
+        w_t=jnp.swapaxes(w, 1, 2).astype(mm), uv=_bmm(t, v, _NN),
+        k_out=k_out.astype(mm), k_out_t=jnp.swapaxes(k_out, 1, 2).astype(mm),
+        decay_column=jnp.sum(jnp.where(_eye(kf.shape[-1]), decay, 0.0),
+                             axis=2, keepdims=True),
+        qg=(qf * grown).astype(mm))
+
+
+def _heads_in(ref, tokens, heads):
+    """A [1, tokens, G d] block as its heads' chunks [G N, C, d]."""
+    d = ref.shape[-1] // heads
+    x = ref[0]
+    return jnp.concatenate(
+        [x[:, h * d:(h + 1) * d].reshape(tokens // CHUNK, CHUNK, d)
+         for h in range(heads)], axis=0)
+
+
+def _heads_out(ref, x, tokens, heads):
+    """[G N, C, d] into a [1, tokens, G d] block."""
+    d, n = x.shape[-1], tokens // CHUNK
+    for h in range(heads):
+        ref[0, :, h * d:(h + 1) * d] = x[h * n:(h + 1) * n].reshape(
+            tokens, d).astype(ref.dtype)
+
+
+def _stacked(ref):
+    """A [1, G, rows, ...] block with its first two axes merged."""
+    x = ref[0]
+    return x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
+
+
+def _by_chunk(x, heads):
+    """[G N, ...] -> [G, N, ...]: chunk c of every head is ``[:, c]``."""
+    return x.reshape(heads, x.shape[0] // heads, *x.shape[1:])
+
+
+def _by_head(xs):
+    """A list over chunks of [G, ...] -> [G N, ...]."""
+    x = jnp.stack(xs, axis=1)
+    return x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, tokens,
+                heads, keep):
+    if keep:
+        s0_ref, pair_ref, inv_ref, s_ref = rest
+    else:
+        (s_ref,) = rest
+    n = tokens // CHUNK
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        s_ref[...] = jnp.zeros(s_ref.shape, _F32)
+
+    q = _heads_in(q_ref, tokens, heads)
+    mm = q.dtype
+    s = _set_up(q, _heads_in(k_ref, tokens, heads),
+                _heads_in(v_ref, tokens, heads),
+                _heads_in(g_ref, tokens, heads), _stacked(beta_ref))
+    uv, w, k_out_t, decay = (_by_chunk(s[name], heads) for name in (
+        "uv", "w", "k_out_t", "decay_column"))
+    # the one part that waits for the state: two products a chunk, the
+    # program's heads side by side
+    state = s_ref[...]                                       # [G, d_k, d_v]
+    entering, us = [], []
+    for c in range(n):
+        if keep:
+            s0_ref[0, :, c] = state
+        sm = state.astype(mm)
+        um = (uv[:, c] - _bmm(w[:, c], sm, _NN)).astype(mm)
+        state = state * decay[:, c] + _bmm(k_out_t[:, c], um, _NN)
+        entering.append(sm)
+        us.append(um)
+    s_ref[...] = state
+
+    o = (_bmm(s["qg"], _by_head(entering), _NN)
+         + _bmm(s["a_qk"].astype(mm), _by_head(us), _NN))
+    _heads_out(o_ref, o, tokens, heads)
+    if keep:
+        pair_ref[0, :, :, :CHUNK] = s["a_kk"].reshape(heads, tokens, CHUNK)
+        pair_ref[0, :, :, CHUNK:] = s["a_qk"].reshape(heads, tokens, CHUNK)
+        x = _by_chunk(s["x"], heads)
+        for c in range(n):
+            inv_ref[(0, slice(None)) + _inv_at(c)] = x[:, c]
+
+
+def _inv_at(c):
+    """Where chunk c's X lies in a block's [tokens / 2, 2 C] stack: two
+    chunks side by side, so that the stack's rows fill the 128 lanes."""
+    return (slice(c // 2 * CHUNK, (c // 2 + 1) * CHUNK),
+            slice(c % 2 * CHUNK, (c % 2 + 1) * CHUNK))
+
+
+def _specs(tokens, d, heads, at):
+    """Block specs, ``heads`` heads a program, of a [B, T, H d] stream, the
+    [B, H, chunks, 1, C] beta (a row a chunk), the [B, H, chunks, d, d]
+    entering states, the [B, H, T, 2 C] pair terms (A for k | A for q) and
+    the [B, H, T / 2, 2 C] inverses; ``at`` maps the grid's block axis to
+    the block taken."""
+    stream = pl.BlockSpec((1, tokens, heads * d),
+                          lambda b, h, n: (b, at(n), h))
+    row = pl.BlockSpec((1, heads, tokens // CHUNK, 1, CHUNK),
+                       lambda b, h, n: (b, h, at(n), 0, 0))
+    states = pl.BlockSpec((1, heads, tokens // CHUNK, d, d),
+                          lambda b, h, n: (b, h, at(n), 0, 0))
+    pair = pl.BlockSpec((1, heads, tokens, 2 * CHUNK),
+                        lambda b, h, n: (b, h, at(n), 0))
+    inv = pl.BlockSpec((1, heads, tokens // 2, 2 * CHUNK),
+                       lambda b, h, n: (b, h, at(n), 0))
+    return stream, row, states, pair, inv
+
+
+def _forward(q, k, v, g, beta, *, tokens, together, keep, interpret):
+    """q, k, v, g [B, T, H d] (T whole blocks), beta [B, H, T / C, 1, C];
+    ``together`` heads a program."""
+    (b, t, hd), heads = q.shape, beta.shape[1]
+    d = hd // heads
+    stream, row, states, pair, inv = _specs(tokens, d, together, lambda n: n)
+    out_shape = [jax.ShapeDtypeStruct(q.shape, v.dtype)]
+    out_specs = [stream]
+    if keep:
+        out_shape += [
+            jax.ShapeDtypeStruct((b, heads, t // CHUNK, d, d), _F32),
+            jax.ShapeDtypeStruct((b, heads, t, 2 * CHUNK), _F32),
+            jax.ShapeDtypeStruct((b, heads, t // 2, 2 * CHUNK), _F32)]
+        out_specs += [states, pair, inv]
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, tokens=tokens, heads=together,
+                          keep=keep),
+        grid=(b, heads // together, t // tokens),
+        in_specs=[stream, stream, stream, stream, row],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((together, d, d), _F32)],
+        interpret=interpret, name="kda_chunk_fwd",
+        compiler_params=_PARAMS,
+    )(q, k, v, g, beta)
+    return out if keep else out[0]
+
+
+def _pair_terms_bwd(qf, kf, cum, d_kk, d_qk):
+    """The pair terms' gradient: (dq, dk, dG) [N, C, d] float32 from the
+    cotangents of A_kk (zero unless i < r) and A_qk (zero unless i <= r).
+    Off the diagonal the products' own; the reference token's terms cancel
+    (A does not depend on it). On the diagonal sub-blocks, a column j at a
+    time with E = exp(G_r - G_j): t_k[r] += c_k[r, j] k_j E and t_q
+    likewise are the row's gradients, t_i[j] = sum_r (c_k k_r + c_q q_r) E
+    the column's, and the decay's is k_r t_k + q_r t_q at the row minus k_j
+    t_i at the column (``ops.linear_attention._diagonal_pairs_bwd``)."""
+    shape = kf.shape
+    c_k = _own_columns(d_kk).reshape(shape[0], CHUNK, SUB)
+    c_q = _own_columns(d_qk).reshape(shape[0], CHUNK, SUB)
+
+    def column(j, qf, kf, cum, c_k, c_q, rows, t_k, t_q, t_i):
+        """Column j's terms into the three sums, for the sub-blocks'
+        ``rows`` given (``_pair_terms``'s ``column``); its own row takes
+        the column's sum over them."""
+        at_row = j - (SUB - rows)
+        decay = jnp.exp(jnp.minimum(cum - _own(cum, at_row, rows), 0.0))
+        pairs = _own(kf, at_row, rows) * decay
+        ck, cq = c_k[:, :, j:j + 1], c_q[:, :, j:j + 1]
+        onto = ((ck * kf + cq * qf) * decay).reshape(-1, rows, kf.shape[-1])
+        sums = jnp.broadcast_to(jnp.sum(onto, axis=1, keepdims=True),
+                                onto.shape).reshape(kf.shape)
+        place = _iota(kf.shape[1:], 0) % rows
+        return (t_k + ck * pairs, t_q + cq * pairs,
+                jnp.where(place == at_row, sums, t_i))
+
+    t_k, t_q, t_i = (jnp.zeros(shape, _F32) for _ in range(3))
+    for j in range(SUB // 2):
+        t_k, t_q, t_i = column(j, qf, kf, cum, c_k, c_q, SUB, t_k, t_q, t_i)
+    # a column of a sub-block's later half meets rows of that half only
+    lower = tuple(map(_lower_rows, (qf, kf, cum, c_k, c_q, t_k, t_q, t_i)))
+    sums = lower[5:]
+    for j in range(SUB // 2, SUB):
+        sums = column(j, *lower[:5], SUB // 2, *sums)
+    t_k, t_q, t_i = (_with_lower_rows(full, low)
+                     for full, low in zip((t_k, t_q, t_i), sums))
+    dq, dk = t_q, t_k + t_i
+    dcum = kf * (t_k - t_i) + qf * t_q
+    rows_q, rows_k, rows_g = [], [], []
+    for s in range(1, CHUNK // SUB):
+        rows = slice(s * SUB, (s + 1) * SUB)
+        left, right, grown, shrunk = _against_first(qf, kf, cum, s)
+        before = _iota((SUB, CHUNK), 1) < s * SUB
+        d_off = jnp.concatenate([jnp.where(before, d_kk[:, rows], 0.0),
+                                 jnp.where(before, d_qk[:, rows], 0.0)],
+                                axis=1)
+        d_left = _bmm(d_off, right, _NN, _HIGHEST)           # [N, 2 SUB, d]
+        d_right = _bmm(d_off, left, _TN, _HIGHEST)           # [N, C, d]
+        rows_k.append(d_left[:, :SUB] * grown)
+        rows_q.append(d_left[:, SUB:] * grown)
+        rows_g.append(d_left[:, :SUB] * left[:, :SUB]
+                      + d_left[:, SUB:] * left[:, SUB:])
+        dk = dk + d_right * shrunk
+        dcum = dcum - d_right * right
+    none = [jnp.zeros((shape[0], SUB, shape[-1]), _F32)]
+    return (dq + jnp.concatenate(none + rows_q, axis=1),
+            dk + jnp.concatenate(none + rows_k, axis=1),
+            dcum + jnp.concatenate(none + rows_g, axis=1))
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, pair_ref,
+                inv_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                ds_ref, *, tokens, heads):
+    n = tokens // CHUNK
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        ds_ref[...] = jnp.zeros(ds_ref.shape, _F32)
+
+    q, k, v = (_heads_in(ref, tokens, heads) for ref in (q_ref, k_ref, v_ref))
+    mm = q.dtype
+    beta = _stacked(beta_ref)
+    pair = pair_ref[0].reshape(heads * n, CHUNK, 2 * CHUNK)
+    x = jnp.stack([inv_ref[(0, slice(None)) + _inv_at(c)] for c in range(n)],
+                  axis=1).reshape(heads * n, CHUNK, CHUNK)
+    s = _set_up(q, k, v, _heads_in(g_ref, tokens, heads), beta,
+                (pair[:, :, :CHUNK], pair[:, :, CHUNK:], x))
+    a_kk, a_qk = s["a_kk"], s["a_qk"]
+    r, i = _grid_masks()
+    s0 = _stacked(s0_ref)                                    # [G N, d_k, d_v]
+    s0m = s0.astype(mm)
+    um = (s["uv"] - _bmm(s["w"], s0m, _NN)).astype(mm)
+    dom = _heads_in(do_ref, tokens, heads)
+    # what of dU and of the entering state's cotangent does not wait for
+    # the leaving state's
+    du_own, ds_own, k_out, w_t, decay = (_by_chunk(a, heads) for a in (
+        _bmm(a_qk.astype(mm), dom, _TN), _bmm(s["qg"], dom, _TN),
+        s["k_out"], s["w_t"], s["decay_column"]))
+    ds = ds_ref[...]                                         # [G, d_k, d_v]
+    leaving, dums = [None] * n, [None] * n
+    for c in reversed(range(n)):
+        leaving[c] = ds
+        dum = (du_own[:, c] + _bmm(k_out[:, c], ds.astype(mm), _NN)).astype(
+            mm)
+        ds = ds * decay[:, c] + ds_own[:, c] - _bmm(w_t[:, c], dum, _NN)
+        dums[c] = dum
+    ds_ref[...] = ds
+
+    ds1, dum = _by_head(leaving), _by_head(dums)
+    ds1m = ds1.astype(mm)
+    d_qk = jnp.where(i <= r, _bmm(dom, um, _NT), 0.0)
+    dqg = _bmm(dom, s0m, _NT)
+    dk_out = _bmm(um, ds1m, _NT)
+    dwm = (-_bmm(dum, s0m, _NT)).astype(mm)
+    dt = _bmm(dwm, s["kg"], _NT) + _bmm(dum, v, _NT)         # [G N, C, C]
+    dkg = _bmm(s["t"], dwm, _TN)
+    dv = _bmm(s["t"], dum, _TN)
+    dbeta = jnp.sum(dt * x, axis=1, keepdims=True)
+    # X = (I + N)^-1: dN = -X^T dX X^T under the diagonal
+    dn = jnp.where(i < r, -_bmm(_bmm(x, dt * beta, _TN, _HIGHEST), x, _NT,
+                                _HIGHEST), 0.0)
+    dbeta = dbeta + _as_row(jnp.sum(dn * a_kk, axis=2, keepdims=True))
+    qf, kf, cum = s["qf"], s["kf"], s["cum"]
+    dq, dk, dcum = _pair_terms_bwd(qf, kf, cum, _as_column(beta) * dn, d_qk)
+    grown, shrunk = s["grown"], s["shrunk"]
+    k_out_f = kf * shrunk
+    dq = dq + dqg * grown
+    dk = dk + dkg * grown + dk_out * shrunk
+    dcum = dcum + (dkg * kf + dqg * qf) * grown - dk_out * k_out_f
+    # the chunk's whole decay: a column over the key channels, made a row
+    dlast = jnp.sum(jnp.where(_eye(kf.shape[-1]),
+                              jnp.sum(ds1 * s0, axis=2, keepdims=True), 0.0),
+                    axis=1, keepdims=True) * s["decay"]
+    dlast = dlast + jnp.sum(dk_out * k_out_f, axis=1, keepdims=True)
+    dcum = dcum + jnp.where(_iota(dcum.shape[1:], 0) == CHUNK - 1, dlast, 0.0)
+    dg = _ones_product(i >= r, dcum)
+    _heads_out(dq_ref, dq, tokens, heads)
+    _heads_out(dk_ref, dk, tokens, heads)
+    _heads_out(dv_ref, dv, tokens, heads)
+    _heads_out(dg_ref, dg, tokens, heads)
+    dbeta_ref[0] = dbeta.reshape(heads, n, 1, CHUNK)
+
+
+def _backward(q, k, v, g, beta, s0, pair, inv, do, *, tokens, together,
+              interpret):
+    (b, t, hd), heads = q.shape, beta.shape[1]
+    d = hd // heads
+    blocks = t // tokens
+    stream, row, states, pair_spec, inv_spec = _specs(
+        tokens, d, together, lambda n: blocks - 1 - n)
+    like = jax.ShapeDtypeStruct
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, tokens=tokens, heads=together),
+        grid=(b, heads // together, blocks),
+        in_specs=[stream, stream, stream, stream, row, states, pair_spec,
+                  inv_spec, stream],
+        out_specs=[stream, stream, stream, stream, row],
+        out_shape=[like(q.shape, q.dtype), like(k.shape, k.dtype),
+                   like(v.shape, v.dtype), like(g.shape, _F32),
+                   like(beta.shape, _F32)],
+        scratch_shapes=[pltpu.VMEM((together, d, d), _F32)],
+        interpret=interpret, name="kda_chunk_bwd",
+        compiler_params=_PARAMS,
+    )(q, k, v, g, beta, s0, pair, inv, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _scan(q, k, v, g, beta, tokens, together, interpret):
+    return _forward(q, k, v, g, beta, tokens=tokens, together=together,
+                    keep=False, interpret=interpret)
+
+
+def _scan_fwd(q, k, v, g, beta, tokens, together, interpret):
+    o, *kept = _forward(q, k, v, g, beta, tokens=tokens, together=together,
+                        keep=True, interpret=interpret)
+    return o, (q, k, v, g, beta, *kept)
+
+
+def _scan_bwd(tokens, together, interpret, res, do):
+    return tuple(_backward(*res, do, tokens=tokens, together=together,
+                           interpret=interpret))
+
+
+_scan.defvjp(_scan_fwd, _scan_bwd)
+
+
+def _blocked(q, k, v, g, beta, tokens):
+    """The layer's [B, T, H, d] arrays as [B, T, H d] and its [B, T, H]
+    beta a row a chunk, the row padded to whole blocks with tokens that
+    write nothing and decay nothing."""
+    b, t, h, d = q.shape
+    pad = -t % tokens
+
+    def stream(x, dtype):
+        x = x.astype(dtype).reshape(b, t, h * d)
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+    beta = jnp.pad(beta.astype(_F32), ((0, 0), (0, pad), (0, 0)))
+    beta = jnp.moveaxis(beta, 1, 2).reshape(b, h, (t + pad) // CHUNK, 1,
+                                            CHUNK)
+    return (stream(q, q.dtype), stream(k, q.dtype), stream(v, q.dtype),
+            stream(g, _F32), beta)
+
+
+def _block_tokens(seq, tokens):
+    """Tokens a program takes: ``tokens`` or, for a shorter row, the row in
+    whole pairs of chunks."""
+    return min(tokens or TOKENS, -(-seq // (2 * CHUNK)) * 2 * CHUNK)
+
+
+def heads_together(heads, d, want=None):
+    """Heads a program takes: the most, up to ``want`` (``TOGETHER``) and
+    to ``TOGETHER`` lane groups of 128 in all (the backward's working set at
+    four 256-wide heads is past the VMEM it asks for), that divide."""
+    most = min(want or TOGETHER, max(1, TOGETHER * 128 // d))
+    return max(n for n in range(1, most + 1) if heads % n == 0)
+
+
+def kda(q, k, v, g, beta, *, tokens=None, together=None, interpret=False):
+    """The gated delta rule from a zero state: q, k, g [B, T, H, d], v [B,
+    T, H, d], beta [B, T, H] -> o [B, T, H, d] in v's dtype (the final state
+    stays inside). k and v take q's dtype, the decay and beta are float32;
+    differentiable in all five. ``tokens``: what a program takes of a row
+    (whole pairs of chunks; ``TOKENS``), ``together``: of how many heads
+    (``TOGETHER``)."""
+    b, t, h, d = q.shape
+    tokens = _block_tokens(t, tokens)
+    o = _scan(*_blocked(q, k, v, g, beta, tokens), tokens,
+              heads_together(h, d, together), bool(interpret))
+    return o[:, :t].reshape(b, t, h, d).astype(v.dtype)

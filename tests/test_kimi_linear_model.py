@@ -305,32 +305,46 @@ def test_share_test_the_shares_and_the_shared_expert_once(reference):
     assert np.abs(total - want).max() <= RTOL * np.abs(want).max()
 
 
-def test_a_traced_step_carries_the_scopes_and_counts_the_paths(ids):
-    net = build(use_recompute=True)
-    params = net.functional_state()[0]
+@pytest.mark.parametrize("given, head_dim, seq, interpreter", [
+    ("chunked", 16, SEQ, False),      # this file's widths: the XLA scan
+    ("kernel", 128, 72, True),        # a lane group a head, over one chunk
+])
+def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
+        given, head_dim, seq, interpreter):
+    """Four KDA layers on the path the route gives the platform and the
+    widths — the Mosaic kernels (here in the Pallas interpreter) at the
+    published 128 a head, the XLA scan at this file's 16 — one latent-
+    attention layer, four expert layers on the held path."""
     from paddle_tpu.ops import attention
 
-    counts = {"chunked": linear_attention._CORE_TOTAL.value(path="chunked"),
-              "recurrent": linear_attention._CORE_TOTAL.value(
-                  path="recurrent"),
-              "held": moe._DISPATCH_TOTAL.value(path="sorted_held"),
-              "xla": attention._ROUTE_TOTAL.value(route="xla")}
-    text = jax.jit(jax.grad(
-        lambda p: framework_terms(net, p, ids)[1])).lower(params).as_text(
-            debug_info=True)
+    paddle.set_flags({"pallas_interpret": interpreter})
+    try:
+        net = build(use_recompute=True, linear_attn_config=dict(
+            LINEAR, head_dim=head_dim, num_heads=64 // head_dim or 4))
+        params = net.functional_state()[0]
+        ids = jnp.asarray(np.random.default_rng(7).integers(
+            0, SIZES["vocab_size"], (ROWS, seq)), jnp.int32)
+        paths = ("kernel", "chunked", "recurrent")
+        counts = {p: linear_attention._CORE_TOTAL.value(path=p)
+                  for p in paths}
+        counts["held"] = moe._DISPATCH_TOTAL.value(path="sorted_held")
+        counts["xla"] = attention._ROUTE_TOTAL.value(route="xla")
+        text = jax.jit(jax.grad(
+            lambda p: framework_terms(net, p, ids)[1])).lower(
+                params).as_text(debug_info=True)
+    finally:
+        paddle.set_flags({"pallas_interpret": False})
     # the second forward of each block carries jax's scope for it, which
     # the benchmark's recompute_ms_per_step reads in a trace
     assert "rematted_computation" in text
     for scope in ("kda.proj", "kda.conv", "kda.gate", "kda.core", "kda.out",
                   "KimiDeltaAttention", "mla.q", "mla.core", "moe.shared"):
         assert scope in text, scope
-    # four KDA layers on the chunked path, one latent-attention layer, four
-    # expert layers on the held path: one count a traced call (jax traces a
-    # recomputed block once and replays its jaxpr in the backward pass)
-    assert linear_attention._CORE_TOTAL.value(
-        path="chunked") - counts["chunked"] == 4
-    assert linear_attention._CORE_TOTAL.value(
-        path="recurrent") == counts["recurrent"]
+    # one count a traced call (jax traces a recomputed block once and
+    # replays its jaxpr in the backward pass)
+    for p in paths:
+        assert linear_attention._CORE_TOTAL.value(path=p) - counts[p] == (
+            4 if p == given else 0), p
     assert moe._DISPATCH_TOTAL.value(
         path="sorted_held") - counts["held"] == 4
     assert attention._ROUTE_TOTAL.value(route="xla") - counts["xla"] == 1

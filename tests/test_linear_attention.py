@@ -4,8 +4,11 @@ recurrence over tokens — outputs, the final state and the gradients with
 respect to q, k, v, g and beta — at lengths that are no multiple of the
 chunk, across segments, from a given state, and with a decay strong enough
 to overflow a factorisation into exp(G_r) exp(-G_i); the operand dtype
-under amp O1; which path a row takes and the counter that says so; and the
-causal depthwise convolution against four shifted multiply-adds."""
+under amp O1; the Mosaic kernels (``ops/pallas/linear_attention.py``, in
+the Pallas interpreter) against the recurrence and against the chunked scan,
+outputs and all five gradients; which path a row takes and the counter that
+says so; and the causal depthwise convolution against four shifted
+multiply-adds."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,27 +17,30 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
+from paddle_tpu.ops import attention
 from paddle_tpu.ops import linear_attention as la
+from paddle_tpu.ops.pallas import linear_attention as kernels
 
 HEADS, D_K, D_V = 3, 32, 16
 
 
-def inputs(seed, batch, seq, strong=False, dtype=jnp.float32):
+def inputs(seed, batch, seq, strong=False, dtype=jnp.float32, heads=HEADS,
+           d_k=D_K, d_v=D_V):
     """q, k as a KDA layer makes them (L2-normalised, q scaled), v, a decay
     g <= 0 per channel and beta in (0, 1). ``strong``: |g| up to e^5 = 148 a
     token, so exp(-G) over a 16-token sub-block alone reaches e^2000."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
 
     def unit(key):
-        x = jax.random.normal(key, (batch, seq, HEADS, D_K))
+        x = jax.random.normal(key, (batch, seq, heads, d_k))
         return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
-    q, k = unit(ks[0]) * D_K ** -0.5, unit(ks[1])
-    v = jax.random.normal(ks[2], (batch, seq, HEADS, D_V))
-    g = -jnp.exp(jax.random.uniform(ks[3], (batch, seq, HEADS, D_K),
+    q, k = unit(ks[0]) * d_k ** -0.5, unit(ks[1])
+    v = jax.random.normal(ks[2], (batch, seq, heads, d_v))
+    g = -jnp.exp(jax.random.uniform(ks[3], (batch, seq, heads, d_k),
                                     minval=-6.0,
                                     maxval=5.0 if strong else 0.0))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (batch, seq, HEADS)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (batch, seq, heads)))
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
@@ -45,6 +51,7 @@ def _float32_matmuls():
 
 
 def close(got, want, rtol):
+    got, want = jnp.asarray(got), jnp.asarray(want)
     scale = float(jnp.abs(want).max())
     assert bool(jnp.isfinite(got).all())
     err = float(jnp.abs(got.astype(jnp.float32) - want).max())
@@ -149,10 +156,122 @@ def test_chunk_must_be_a_power_of_two_of_sub_blocks():
         la.kda_chunked(*args, chunk=48)
 
 
+# ------------------------------------------------ the Mosaic kernels
+# In the Pallas interpreter, at the one width they take (128 lanes), two
+# heads. Float32 both ways the kernels are the chunked scan's arithmetic in
+# another order: the tolerances are the chunked tests' own.
+def kernel_inputs(seed, seq, strong=False, dtype=jnp.float32):
+    return inputs(seed, 1, seq, strong, dtype, heads=2, d_k=128, d_v=128)
+
+
+def weighted(fn):
+    """value and the five gradients of a loss that tells positions and
+    features apart."""
+    def of(*a):
+        o = fn(*a).astype(jnp.float32)
+        return jnp.sum(o * jnp.cos(jnp.arange(o.size, dtype=jnp.float32)
+                                   .reshape(o.shape)))
+    return jax.jit(jax.value_and_grad(of, argnums=(0, 1, 2, 3, 4)))
+
+
+def kernel_scan(*a, tokens=None, together=None):
+    return kernels.kda(*a, tokens=tokens, together=together, interpret=True)
+
+
+@pytest.mark.parametrize("seq, tokens, together, strong, against, rtol", [
+    (200, 128, 2, False, "recurrent", 2e-5),   # two programs a row, ragged
+    (100, None, 2, False, "recurrent", 2e-5),  # one chunk and a part
+    (200, 128, 2, True, "recurrent", 5e-4),    # exp(-G) overflows in a chunk
+    (64, None, 2, False, "recurrent", 2e-5),   # the shortest row routed here
+    (200, 128, 1, False, "chunked", 2e-5),     # a head a program
+    (200, 256, 2, True, "chunked", 5e-4),
+])
+def test_the_kernels_are_the_scan_forward_and_backward(
+        seq, tokens, together, strong, against, rtol):
+    args = kernel_inputs(7, seq, strong)
+    other = {"recurrent": lambda *a: la.kda_recurrent(*a)[0],
+             "chunked": lambda *a: la.kda_chunked(*a)[0]}[against]
+    want_o = other(*args)
+    def scan(*a):
+        return kernel_scan(*a, tokens=tokens, together=together)
+
+    got_o = jax.jit(scan)(*args)
+    assert got_o.shape == want_o.shape and got_o.dtype == want_o.dtype
+    close(got_o, want_o, rtol / 10 if not strong else rtol / 5)
+    _, want = weighted(other)(*args)
+    _, got = weighted(scan)(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert float(jnp.abs(b).max()) > 0, name
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        close(a, b, rtol)
+
+
+def test_the_kernels_take_bf16_operands_and_keep_float32_inside():
+    """bf16 q, k, v, float32 decay and beta, as amp O1 hands them over: the
+    output and dq, dk, dv take bf16, dg and dbeta float32, and all of them
+    are a bf16 rounding off the float32 recurrence on the same (rounded)
+    inputs; the kept states and matrices are float32."""
+    args = kernel_inputs(8, 200, dtype=jnp.bfloat16)
+    exact = tuple(a.astype(jnp.float32) for a in args)
+    want_o, want = (la.kda_recurrent(*exact)[0],
+                    weighted(lambda *a: la.kda_recurrent(*a)[0])(*exact)[1])
+    got_o = kernel_scan(*args)
+    _, got = weighted(kernel_scan)(*args)
+    assert got_o.dtype == jnp.bfloat16
+    close(got_o, want_o, 3e-2)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert a.dtype == (jnp.float32 if name in ("g", "beta")
+                           else jnp.bfloat16), name
+        close(a, b, 3e-2)
+    blocked = kernels._blocked(*args, 128)
+    _, s0, pair, inv = kernels._forward(*blocked, tokens=128, together=2,
+                                        keep=True, interpret=True)
+    assert {x.dtype for x in (s0, pair, inv)} == {jnp.dtype(jnp.float32)}
+    assert s0.shape == (1, 2, 4, 128, 128)
+    jaxpr = str(jax.make_jaxpr(kernel_scan)(*args))
+    assert "bf16" in jaxpr and "preferred_element_type=float32" in jaxpr
+
+
+def test_no_exponent_in_the_kernels_is_positive():
+    """Every ``exp`` the kernels' bodies take — a block's set-up, which both
+    run, and the pair terms' gradient — on a decay that overflows a
+    factorisation: its argument is never above zero."""
+    q, k, v, g, beta = kernel_inputs(9, 128, strong=True)
+
+    def chunks(x):
+        return x[0, :, 0].reshape(2, kernels.CHUNK, -1)
+
+    seen = []
+    real = jnp.exp
+
+    def exp(x):
+        seen.append(float(jnp.max(x)))
+        return real(x)
+
+    try:
+        jnp.exp = exp
+        s = kernels._set_up(chunks(q), chunks(k), chunks(v), chunks(g),
+                            beta[0, :, 0].reshape(2, 1, kernels.CHUNK))
+        kernels._pair_terms_bwd(s["qf"], s["kf"], s["cum"], s["a_kk"],
+                                s["a_qk"])
+    finally:
+        jnp.exp = real
+    assert len(seen) > 2 * kernels.SUB and max(seen) <= 0.0
+    assert float(s["cum"].min()) < -1000       # exp(-G) would be inf
+
+
+@pytest.fixture
+def interpreter():
+    paddle.set_flags({"pallas_interpret": True})
+    yield
+    paddle.set_flags({"pallas_interpret": False})
+
+
 @pytest.mark.parametrize("seq, path", [(8, "recurrent"), (15, "recurrent"),
                                        (16, "chunked"), (100, "chunked")])
 def test_the_entry_point_picks_and_counts_the_path(seq, path):
     assert la.core_path(seq) == path
+    assert la.core_path(seq, D_K, D_V, jnp.float32) == path
     other = "recurrent" if path == "chunked" else "chunked"
     before = {p: la._CORE_TOTAL.value(path=p) for p in (path, other)}
     args = inputs(5, 1, seq)
@@ -161,6 +280,106 @@ def test_the_entry_point_picks_and_counts_the_path(seq, path):
     assert la._CORE_TOTAL.value(path=path) == before[path] + 1
     assert la._CORE_TOTAL.value(path=other) == before[other]
     close(out._value, la.kda_recurrent(*args)[0], 2e-6)
+
+
+@pytest.mark.parametrize("seq, d_k, d_v, dtype, path", [
+    (15, 128, 128, jnp.bfloat16, "recurrent"),
+    (63, 128, 128, jnp.bfloat16, "chunked"),      # under one chunk
+    (64, 128, 128, jnp.bfloat16, "kernel"),
+    (16384, 128, 128, jnp.float32, "kernel"),
+    (16384, 256, 256, jnp.bfloat16, "kernel"),
+    (16384, 64, 64, jnp.bfloat16, "chunked"),     # half a lane group
+    (16384, 192, 128, jnp.bfloat16, "chunked"),   # two widths
+    (16384, 128, 128, jnp.float16, "chunked"),
+])
+def test_the_route_goes_by_widths_dtype_and_length(interpreter, seq, d_k,
+                                                   d_v, dtype, path):
+    assert la.core_path(seq, d_k, d_v, dtype) == path
+
+
+def test_the_route_needs_a_platform_and_known_devices(monkeypatch):
+    """No TPU and no interpreter flag: the XLA scan. A platform that
+    compiles the kernels but a program whose devices are not known (a plain
+    jit on several devices): the XLA scan again."""
+    shape = (16384, 128, 128, jnp.bfloat16)
+    assert la.core_path(*shape) == "chunked"
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(attention, "_placeable", lambda: False)
+    assert la.core_path(*shape) == "chunked"
+    monkeypatch.setattr(attention, "_placeable", lambda: True)
+    assert la.core_path(*shape) == "kernel"
+
+
+@pytest.mark.parametrize("seq, d, path", [(150, 128, "kernel"),
+                                          (150, 32, "chunked"),
+                                          (40, 128, "chunked"),
+                                          (12, 128, "recurrent")])
+def test_the_entry_point_counts_the_kernel_path(interpreter, seq, d, path):
+    before = {p: la._CORE_TOTAL.value(path=p)
+              for p in ("kernel", "chunked", "recurrent")}
+    args = inputs(10, 1, seq, heads=2, d_k=d, d_v=d)
+    out = la.gated_delta_rule(*(paddle.to_tensor(np.asarray(a))
+                                for a in args))
+    for p, n in before.items():
+        assert la._CORE_TOTAL.value(path=p) == n + (p == path), p
+    close(out._value, la.kda_recurrent(*args)[0], 2e-6)
+
+
+def test_the_kernel_path_differentiates_through_the_tape(interpreter):
+    """The layer's call: Tensors in, ``backward`` through the eager tape,
+    the gradients those of the chunked scan."""
+    args = kernel_inputs(11, 130)
+
+    def run(fn):
+        ts = [paddle.to_tensor(np.asarray(a), stop_gradient=False)
+              for a in args]
+        (fn(*ts) ** 2).sum().backward()
+        return [t.grad._value for t in ts]
+
+    got = run(la.gated_delta_rule)
+    want = jax.grad(lambda *a: jnp.sum(la.kda_chunked(*a)[0] ** 2),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(got, want):
+        close(a, b, 2e-5)
+
+
+def test_the_kernel_path_shards_itself_over_an_announced_mesh(interpreter):
+    """Inside a step traced for a mesh (``topology.tracing_for``) the call
+    runs under the attention kernels' ``shard_map``: rows over the data
+    axis, heads over 'mp', nothing replicated; the result is the one-device
+    one and the program holds a shard_map."""
+    from paddle_tpu.distributed import topology
+
+    q, k, v, g, beta = (jnp.concatenate([a, a[:, ::-1]], axis=0)
+                        for a in kernel_inputs(12, 128))
+    args = tuple(paddle.to_tensor(np.asarray(a)) for a in (q, k, v, g, beta))
+    want = la.gated_delta_rule(*args)._value
+    mesh = topology.build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+
+    def step(*a):
+        with topology.tracing_for(mesh):
+            return la._kernel_output(*a, interpret=True)
+
+    text = jax.jit(step).lower(q, k, v, g, beta).as_text()
+    assert "shard_map" in text or "manual" in text
+    close(np.asarray(jax.jit(step)(q, k, v, g, beta)), np.asarray(want),
+          1e-6)
+
+
+def test_the_kernel_microbenchmark_measures_on_a_tpu_only():
+    """``tools/kda_kernel_bench.py`` exits 2 where there is no TPU: a number
+    from this CPU is never printed under a device's name."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, os.path.join(root, "tools", "kda_kernel_bench.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+        text=True, timeout=300)
+    assert done.returncode == 2 and "TPU only" in done.stderr
+    assert "fwd_ms" not in done.stdout
 
 
 def test_the_core_differentiates_inside_jax_checkpoint():

@@ -73,6 +73,16 @@ STREAM_TWO_CALL_CASES = {
 STREAM_TWO_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
                     "flash_stream_bwd_dkv")
 ALL_STREAM_CASES = {**STREAM_CASES, **STREAM_TWO_CALL_CASES}
+#: the gated delta rule's scan (batch, seq, heads, head_dim, dtype): at the
+#: Kimi-Linear cell's four layers, in float32 (the cell's check runs it),
+#: and at a head of two lane groups. KDA_CALLS: the names its two Mosaic
+#: calls carry in a trace, under the ``kda.core`` scope
+KDA_CASES = {
+    "kimi-cell-bf16": (1, 16384, 32, 128, "bfloat16"),
+    "kimi-check-f32": (1, 16384, 32, 128, "float32"),
+    "d256-bf16": (1, 2048, 4, 256, "bfloat16"),
+}
+KDA_CALLS = ("kda_chunk_fwd", "kda_chunk_bwd")
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
@@ -195,6 +205,31 @@ def _child():
                 f"[{batch * heads},{seq},{d_v}]"),
             "key_wide_results": text.count(
                 f"[{batch * heads},{seq},{d_qk}]")}
+
+    from paddle_tpu.ops.pallas import linear_attention as kda
+
+    for name, (batch, seq, heads, head_dim, dtype) in KDA_CASES.items():
+        def like(*shape, dtype=dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        x = like(batch, seq, heads, head_dim)
+        # the cell's float32 check asks every product for float32 in
+        # earnest: the bf16 ones inside the kernels must not take that
+        with jax.default_matmul_precision("highest"):
+            compiled = jax.jit(jax.grad(
+                lambda *a: jnp.sum(kda.kda(*a).astype(jnp.float32)),
+                argnums=(0, 1, 2, 3, 4))).lower(
+                    x, x, x,
+                    like(batch, seq, heads, head_dim, dtype=jnp.float32),
+                    like(batch, seq, heads, dtype=jnp.float32)).compile()
+        text = compiled.as_text()
+        out["kda-" + name] = {
+            "mosaic": text.count(MOSAIC),
+            "calls": [c for c in KDA_CALLS if f"({c})" in text],
+            "loops": text.count(" while("),
+            # the head split's [tokens, heads] <-> [heads, chunks] copies
+            "transposes": len(re.findall(r" (transpose|copy)\(", text)),
+            "temp_gb": compiled.memory_analysis().temp_size_in_bytes / 1e9}
 
     # the s128 cell's FFN under amp O1, forward + backward: what F.gelu's
     # erf lowers to behind linear1's gemm, and what leaves that fusion
@@ -352,6 +387,27 @@ def test_stream_kernel_compiles(compiled, case):
         # keys and values keep their own widths through every call:
         # nothing is padded to the other's
         assert got["value_wide_results"] and got["key_wide_results"]
+
+
+@pytest.mark.parametrize("case", list(KDA_CASES))
+def test_delta_rule_kernels_compile(compiled, case):
+    """The gated delta rule's scan, forward (keeping what the backward
+    takes) and backward, at the Kimi-Linear cell's b1 s16384 h32 d128 in
+    bf16 and in the check's float32 and at a 256-wide head: two Mosaic calls
+    under their names within the VMEM they ask for, no loop over chunks or
+    segments left to XLA, and the layer's [batch, tokens, heads x d] arrays
+    read in place — what is kept for the backward (entering states, pair
+    terms, inverses: 0.94 GB at the cell's shape) is the temporary memory."""
+    got = compiled["kda-" + case]
+    assert got["mosaic"] == 2 and got["calls"] == list(KDA_CALLS)
+    assert got["loops"] == 0
+    batch, seq, heads, d, _ = KDA_CASES[case]
+    kept = 4 * batch * heads * seq * (d * d // 64 + 128 + 64) / 1e9
+    # and, for arrays given as [batch, tokens, heads, d], their [tokens,
+    # heads x d] copies: in a step XLA fuses those into the producers
+    streams = batch * seq * heads * d * (3 * 2 + 4 + (4 if "f32" in case
+                                                      else 0)) * 2 / 1e9
+    assert got["temp_gb"] <= 1.25 * (kept + streams) + 0.05, (got, kept)
 
 
 def test_gelu_stays_one_erf_behind_the_ffn_up_gemm(compiled):
