@@ -145,42 +145,33 @@ def _read_reply(sock):
     return body[0], body[1:]
 
 
-class _CompileMeter:
-    """Sums jax's own lowering + XLA-compile durations and
-    persistent-cache events (jax.monitoring), so each phase line can say
-    how much of its wall time was compilation (a cache read counts as
-    one) and whether executables came from the cache. Python tracing is
-    left in the rest: its events nest and would count twice."""
+def _compile_seconds():
+    """Lowering + backend-compile seconds so far: what the program's own
+    bridge from ``jax.monitoring`` (``obs/ledger.py``) has put into the span
+    layer, so each phase line can say how much of its wall time was
+    compilation (a cache read counts as one). Python tracing is left in the
+    rest."""
+    from paddle_tpu.obs import tracing
 
-    _DURATIONS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
+    totals = {r["name"]: r["total"] for r in tracing.summary_rows()}
+    return totals.get("compile.lower", 0.0) + totals.get("compile.backend",
+                                                         0.0)
 
-    def __init__(self):
-        import jax.monitoring
 
-        self._lock = threading.Lock()
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        self.cache_writes = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_dur)
-        jax.monitoring.register_event_listener(self._on_event)
+def _cache_events(since):
+    """(executables read from the persistent cache, executables written to
+    it) by the ``compile.backend`` spans that ended after ``since`` (on
+    ``time.monotonic``, the span layer's clock), from their ``cache``
+    attribute; (None, None) where the ring may have dropped some of them."""
+    from paddle_tpu.obs import tracing
 
-    def _on_dur(self, event, duration, **_):
-        if event in self._DURATIONS:
-            with self._lock:
-                self.compile_s += duration
-
-    def _on_event(self, event, **_):
-        with self._lock:
-            if event == "/jax/compilation_cache/cache_hits":
-                self.cache_hits += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                # recorded when an executable is WRITTEN to the cache
-                self.cache_writes += 1
-
-    def snapshot(self):
-        with self._lock:
-            return (self.compile_s, self.cache_hits, self.cache_writes)
+    spans = tracing.finished()
+    if tracing.ring_full() and spans[0]["t1"] > since:
+        return None, None
+    said = collections.Counter(
+        s["attrs"]["cache"] for s in spans
+        if s["t1"] > since and s["name"] == "compile.backend")
+    return said["hit"], said["written"]
 
 
 class Smoke:
@@ -192,7 +183,6 @@ class Smoke:
         self.devices = jax.local_devices()
         self.n = len(self.devices)
         self.platform = self.devices[0].platform
-        self.meter = _CompileMeter()
         self.failed = []
         self.warned = []  # every warning shown: (category, filename, text)
         self.scratch = tempfile.TemporaryDirectory(prefix="chip_smoke_")
@@ -218,7 +208,7 @@ class Smoke:
         print(json.dumps(obj), flush=True)
 
     def run_phase(self, idx, name, fn):
-        c0 = self.meter.snapshot()
+        c0, mark = _compile_seconds(), time.monotonic()
         t0 = time.perf_counter()
         rec = {"phase": idx, "name": name}
         try:
@@ -232,13 +222,12 @@ class Smoke:
             rec["ok"] = False
             rec["error"] = f"{type(e).__name__}: {e}"[:2000]
             self.failed.append(idx)
-        c1 = self.meter.snapshot()
+        compile_s = _compile_seconds() - c0
         wall = time.perf_counter() - t0
         rec["smoke_wall_s"] = round(wall, 2)
-        rec["smoke_compile_s"] = round(c1[0] - c0[0], 2)
-        rec["smoke_rest_s"] = round(wall - (c1[0] - c0[0]), 2)
-        rec["cache_hits"] = c1[1] - c0[1]
-        rec["cache_writes"] = c1[2] - c0[2]
+        rec["smoke_compile_s"] = round(compile_s, 2)
+        rec["smoke_rest_s"] = round(wall - compile_s, 2)
+        rec["cache_hits"], rec["cache_writes"] = _cache_events(mark)
         self.emit(rec)
 
     # ------------------------------------------------------------ 0
@@ -271,7 +260,7 @@ class Smoke:
         rows = cfg["rows_per_chip"] * n
         n_batches = cfg["warmup"] + cfg["steps"] + 2  # + the two timed ways
         t_phase = time.perf_counter()
-        c_phase = self.meter.snapshot()
+        mark = time.monotonic()
         n_warned = len(self.warned)
 
         paddle.seed(0)
@@ -350,11 +339,10 @@ class Smoke:
             loss, (x, y, k) = step(i)
             losses.append(float(loss))
             if i == 0:
-                c1 = self.meter.snapshot()
                 info["smoke_time_to_first_step_s"] = round(
                     time.perf_counter() - t_phase, 2)
-                info["first_step_cache_hits"] = c1[1] - c_phase[1]
-                info["first_step_cache_writes"] = c1[2] - c_phase[2]
+                (info["first_step_cache_hits"],
+                 info["first_step_cache_writes"]) = _cache_events(mark)
                 # what the compiler did with the donation: bytes of
                 # output that alias an input (a persistent-cache read of
                 # the executable the step just compiled)

@@ -21,6 +21,11 @@ from .core import random as _random
 from .core import tape as _tape
 from .core.tensor import Tensor, to_tensor  # noqa: F401
 
+# jax is imported: its trace / lower / compile events become spans
+from .obs import ledger as _ledger
+
+_ledger.bridge_jax_monitoring()
+
 # dtypes
 from .core.dtype import (  # noqa: F401
     bool, uint8, int8, int16, int32, int64, float16, bfloat16, float32,

@@ -39,6 +39,7 @@ from jax import shard_map
 
 from ..core import dispatch, random as random_core
 from ..core.tensor import Tensor
+from ..obs import tracing
 from . import topology
 
 
@@ -132,6 +133,7 @@ def init_dgc_state(params0, mesh, data_axes):
     return state
 
 
+@tracing.spanned("train.build_step", builder="localsgd")
 def build_localsgd_train_step(layer, loss_fn, optimizer, mesh=None,
                               k_steps=4, amp_level="O0",
                               amp_dtype="bfloat16", adaptive=False,
@@ -299,17 +301,23 @@ def build_localsgd_train_step(layer, loss_fn, optimizer, mesh=None,
     step_fn.comm_state = counter
 
     def init_fn():
-        params = {}
-        opt_state = {}
-        for n in param_names:
-            rep = jnp.broadcast_to(jnp.asarray(params0[n]),
-                                   (world,) + tuple(params0[n].shape))
-            params[n] = jax.device_put(rep, NamedSharding(mesh, pspec))
-            st = optimizer._init_state(params0[n])
-            opt_state[n] = tuple(
-                jax.device_put(
-                    jnp.broadcast_to(a, (world,) + tuple(a.shape)),
-                    NamedSharding(mesh, pspec)) for a in st)
+        from .spmd import _count_leaves
+
+        # one loop places a parameter's replicas and its optimizer state
+        # together: one span, no ``.params`` / ``.opt_state`` children
+        with tracing.span("train.init_state") as sp:
+            params = {}
+            opt_state = {}
+            for n in param_names:
+                rep = jnp.broadcast_to(jnp.asarray(params0[n]),
+                                       (world,) + tuple(params0[n].shape))
+                params[n] = jax.device_put(rep, NamedSharding(mesh, pspec))
+                st = optimizer._init_state(params0[n])
+                opt_state[n] = tuple(
+                    jax.device_put(
+                        jnp.broadcast_to(a, (world,) + tuple(a.shape)),
+                        NamedSharding(mesh, pspec)) for a in st)
+            _count_leaves(sp, (params, opt_state))
         return params, opt_state
 
     return step_fn, init_fn

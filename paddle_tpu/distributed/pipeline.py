@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import dispatch, random as random_core
 from ..core.tensor import Tensor
+from ..obs import tracing
 from . import topology
 
 
@@ -82,6 +83,7 @@ def split_pre_trunk_post(layers, num_stages):
             list(layers[start + length:]))
 
 
+@tracing.spanned("train.build_step", builder="pipeline")
 def build_pipeline_train_step(pre_layers, trunk_layers, post_layers, loss_fn,
                               optimizer, mesh=None, num_micro=None,
                               recompute=False, donate=True,
@@ -333,7 +335,7 @@ def build_pipeline_train_step(pre_layers, trunk_layers, post_layers, loss_fn,
     # behind the [stage, layer] stacking dims, pre/post states exactly
     # like spmd's ZeRO-1 (same _zero1_spec). Elementwise updates keep
     # the layout: the memory win of sharding_optimizer.py stage 1.
-    from .spmd import _zero1_spec
+    from .spmd import _spanned_init, _zero1_spec
 
     zero_axes = tuple(ax for ax in ("dp", "sharding")
                       if mesh.shape.get(ax, 1) > 1)
@@ -348,9 +350,11 @@ def build_pipeline_train_step(pre_layers, trunk_layers, post_layers, loss_fn,
                                prefix=tuple(shardings[name].spec))
         return _zero1_spec(a, mesh, axes=zero_axes)
 
-    def init_fn():
-        params = {n: jax.device_put(params0[n], shardings[n])
-                  for n in param_names}
+    def place_params():
+        return {n: jax.device_put(params0[n], shardings[n])
+                for n in param_names}
+
+    def place_opt_state():
         opt_state = {}
         for n in param_names:
             st = optimizer._init_state(params0[n])
@@ -358,7 +362,9 @@ def build_pipeline_train_step(pre_layers, trunk_layers, post_layers, loss_fn,
             # states inherit the stacked pp sharding (+ ZeRO-1 sharding)
             opt_state[n] = tuple(
                 jax.device_put(a, _opt_state_sharding(n, a)) for a in st)
-        return params, opt_state
+        return opt_state
+
+    init_fn = _spanned_init(place_params, place_opt_state)
 
     in_shardings = (shardings, None, batch_spec, None, repl, repl)
     out_shardings = (repl, shardings, None)

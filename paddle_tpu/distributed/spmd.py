@@ -66,6 +66,31 @@ def _zero1_spec(arr, mesh, axes=("dp", "sharding"), start=0, prefix=()):
     return NamedSharding(mesh, base)
 
 
+def _count_leaves(sp, tree):
+    """A start-up span's ``leaves`` and ``bytes``: the arrays it placed."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    sp.attrs.update(leaves=len(leaves),
+                    bytes=sum(int(a.nbytes) for a in leaves))
+
+
+def _spanned_init(place_params, place_opt_state):
+    """The ``init_fn`` of a builder whose state is placed in two phases:
+    one ``train.init_state`` span a call, a child span a phase, each with
+    the ``leaves`` and ``bytes`` it placed."""
+    def init_fn():
+        with tracing.span("train.init_state") as sp:
+            with tracing.span("train.init_state.params") as sp_params:
+                params = place_params()
+                _count_leaves(sp_params, params)
+            with tracing.span("train.init_state.opt_state") as sp_opt:
+                opt_state = place_opt_state()
+                _count_leaves(sp_opt, opt_state)
+            _count_leaves(sp, (params, opt_state))
+        return params, opt_state
+    return init_fn
+
+
+@tracing.spanned("train.build_step", builder="spmd")
 def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
                      shard_optimizer=False, sharding_stage=None, donate=True,
                      amp_level="O0", amp_dtype="bfloat16",
@@ -331,7 +356,7 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
             return loss, new_params, new_state, new_buffers, bad
         return loss, new_params, new_state, new_buffers
 
-    def init_fn():
+    def place_params():
         # Always copy: (a) cloned layers (TransformerEncoder-style
         # deepcopy) share init arrays, and device_put would alias them
         # into one buffer — donating the same buffer twice is an error;
@@ -347,6 +372,9 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
             else:
                 seen_ids.add(id(src))
             params[n] = jax.device_put(src, param_shards[n])
+        return params
+
+    def place_opt_state():
         opt_state = {}
         for n in param_names:
             st = optimizer._init_state(params0[n])
@@ -365,7 +393,9 @@ def build_train_step(layer, loss_fn, optimizer, mesh=None, recompute=False,
 
             opt_state["__comm__"] = comm_opt.init_dgc_state(
                 params0, mesh, data_axes)
-        return params, opt_state
+        return opt_state
+
+    init_fn = _spanned_init(place_params, place_opt_state)
 
     in_shardings = (
         param_shards,
@@ -471,6 +501,7 @@ def shard_batch(batch, mesh=None, axis=None):
         return jax.device_put(arr, sharding)
 
 
+@tracing.spanned("train.build_step", builder="fsdp")
 def build_fsdp_train_step(layers, loss_fn, optimizer, mesh=None,
                           recompute=True, amp_level="O0",
                           amp_dtype="bfloat16", donate=False):
@@ -621,9 +652,11 @@ def build_fsdp_train_step(layers, loss_fn, optimizer, mesh=None,
         out_shardings=(repl, param_shards, None),
         donate_argnums=(0, 1) if donate else ())
 
-    def init_fn():
-        params = {n: jax.device_put(params0[n], param_shards[n])
-                  for n in param_names}
+    def place_params():
+        return {n: jax.device_put(params0[n], param_shards[n])
+                for n in param_names}
+
+    def place_opt_state():
         opt_state = {}
         for n in param_names:
             st = optimizer._init_state(np.asarray(params0[n]))
@@ -631,7 +664,9 @@ def build_fsdp_train_step(layers, loss_fn, optimizer, mesh=None,
                 jax.device_put(a, _stacked_spec(a)
                                if n.startswith("trunk.") else repl)
                 for a in st)
-        return params, opt_state
+        return opt_state
+
+    init_fn = _spanned_init(place_params, place_opt_state)
 
     def step_fn(params, opt_state, x, y, key=None, lr=None):
         if key is None:

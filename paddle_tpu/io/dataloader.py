@@ -313,14 +313,17 @@ class DataLoader:
                                               drop_last=drop_last)
 
     def __iter__(self):
-        if self._iterable:
-            inner = _IterableDatasetIter(self)
-        elif self.num_workers > 0:
-            inner = _MultiprocessIter(self)
-        else:
-            inner = _SingleProcessIter(self)
-        it = (_PrefetchIter(inner, depth=self.prefetch_factor)
-              if self.use_buffer_reader else inner)
+        # forking the workers and starting the reader thread: start-up work
+        with tracing.span("io.loader.start", workers=self.num_workers,
+                          buffered=bool(self.use_buffer_reader)):
+            if self._iterable:
+                inner = _IterableDatasetIter(self)
+            elif self.num_workers > 0:
+                inner = _MultiprocessIter(self)
+            else:
+                inner = _SingleProcessIter(self)
+            it = (_PrefetchIter(inner, depth=self.prefetch_factor)
+                  if self.use_buffer_reader else inner)
 
         class _Wrapper:
             def __iter__(w):

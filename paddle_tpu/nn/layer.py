@@ -8,6 +8,8 @@ jit/pjit (params-as-pytree) is provided by ``functional_state`` /
 steps use to thread parameters through pure functions.
 """
 import collections
+import contextlib
+import math
 
 import jax
 import numpy as np
@@ -16,6 +18,7 @@ from ..core import dispatch
 from ..core import dtype as dtype_mod
 from ..core.tensor import Parameter, Tensor
 from ..framework.param_attr import ParamAttr
+from ..obs import tracing
 from . import initializer as init_mod
 
 _LAYER_COUNTERS = collections.defaultdict(int)
@@ -62,7 +65,14 @@ class Layer:
         init = attr.initializer or default_initializer or init_mod.global_initializer(is_bias)
         if init is None:
             init = init_mod.Constant(0.0) if is_bias else init_mod.XavierNormal()
-        value = init._generate(tuple(int(s) for s in shape), np_dtype)
+        shape = tuple(int(s) for s in shape)
+        # one ``nn.init`` span a parameter where values are really drawn
+        # (and the draw's program compiled on a shape's first use): not
+        # inside a program jax is tracing
+        with (contextlib.nullcontext() if dispatch.in_trace() else
+              tracing.span("nn.init", initializer=type(init).__name__,
+                           bytes=math.prod(shape) * np_dtype.itemsize)):
+            value = init._generate(shape, np_dtype)
         p = Parameter(value, trainable=attr.trainable, name=attr.name)
         p.optimize_attr["learning_rate"] = attr.learning_rate
         p.regularizer = attr.regularizer

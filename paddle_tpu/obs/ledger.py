@@ -19,9 +19,16 @@ exactly from run to run. Every entry records:
 The serving suites read it to count real compiles apart from store
 loads (tests/test_artifact_serving.py, test_quant_serving.py,
 test_sharded_serving.py); the counts are exact, never a speed.
+
+Those entries are the compiles a caller reports. What jax itself traces,
+lowers and compiles — every ``jax.jit`` program of the process — reaches
+the span layer and this module's two metrics through
+:func:`bridge_jax_monitoring`, the process's one set of listeners on
+``jax.monitoring`` (installed at ``paddle_tpu``'s import).
 """
 import hashlib
 import re
+import sys
 import threading
 import time
 
@@ -206,3 +213,117 @@ class CompileLedger:
 
 #: Default process ledger (the serving engine's AOT compiles land here).
 LEDGER = CompileLedger()
+
+
+# ------------------------------------------ jax's compile events -> spans
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BRIDGED = {  # jax's duration event -> (span name, the metrics' ``kind``)
+    _TRACE_EVENT: ("compile.trace", "trace"),
+    _LOWER_EVENT: ("compile.lower", "lower"),
+    "/jax/core/compile/backend_compile_duration":
+        ("compile.backend", "backend"),
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_WRITE = "/jax/compilation_cache/cache_misses"
+_CACHE_READ_S = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: the attribute on ``jax.monitoring`` that says the listeners are in: it
+#: outlives a reload or a second import of this module, as they do
+_INSTALLED = "_paddle_tpu_compile_bridge"
+#: a trace region NESTED in another trace region or in a lowering becomes a
+#: span of its own from this many seconds on. Every jitted ``jax.numpy``
+#: function a traced program calls is such a region (BERT's train step:
+#: 6,900 of them, 0.1 ms each), and so is every helper a lowering rule traces
+#: (760 ``add`` / ``bitwise_xor`` under the BERT cell's parameter draws, my
+#: chip run, PR 35): the short ones stay in the enclosing span and out of the
+#: ring
+_NESTED_TRACE_MIN_S = 0.01
+_bridge_tls = threading.local()
+
+
+def _on_region_start(event, _value, **_):
+    """jax announces a timed region's start as a scalar. Trace regions
+    nest in trace regions (a jit traced inside a jit) and in lowering
+    regions (a lowering rule that traces a helper): open a frame, which for
+    a trace region sums the seconds of the ``compile.trace`` spans nested
+    in it, so that the outer span can say what was its own."""
+    if event == _TRACE_EVENT or event == _LOWER_EVENT:
+        frames = getattr(_bridge_tls, "frames", None)
+        if frames is None:
+            frames = _bridge_tls.frames = []
+        frames.append(0.0)
+
+
+def _on_cache_event(event, **_):
+    """jax says what its persistent cache did before it ends the backend
+    region on the same thread: a hit when it read the executable, a
+    "miss" when it WROTE the one it just compiled."""
+    if event == _CACHE_HIT:
+        _bridge_tls.cache = "hit"
+    elif event == _CACHE_WRITE:
+        _bridge_tls.cache = "written"
+
+
+def _on_duration(event, duration, fun_name=None, **_):
+    """One of jax's regions ended NOW on this thread, ``duration`` seconds
+    long: a pre-measured span under the thread's ambient span, and a
+    count and an observation on the ledger's metrics."""
+    if event == _CACHE_READ_S:
+        _bridge_tls.read_s = duration
+        return
+    bridged = _BRIDGED.get(event)
+    if bridged is None:
+        return
+    name, kind = bridged
+    attrs = {"fun": fun_name}
+    if kind != "backend":
+        frames = getattr(_bridge_tls, "frames", None)
+        nested = frames.pop() if frames else 0.0
+    if kind == "trace":
+        if frames:  # nested in another trace region, or in a lowering
+            if duration < _NESTED_TRACE_MIN_S:
+                return
+            frames[-1] += duration
+        attrs["self_s"] = max(duration - nested, 0.0)
+    elif kind == "backend":
+        # neither event since the last backend region: the program was
+        # compiled and not kept (under the cache's threshold, or no cache)
+        attrs["cache"] = _bridge_tls.__dict__.pop("cache", "uncached")
+        read_s = _bridge_tls.__dict__.pop("read_s", None)
+        if read_s is not None:
+            attrs["read_s"] = read_s
+    _tracing.record_span(name, duration, **attrs)
+    _COMPILES.inc(kind=kind)
+    _COMPILE_SECONDS.observe(duration)
+
+
+def bridge_jax_monitoring():
+    """Install the process's one set of ``jax.monitoring`` listeners: each
+    trace / lowering / backend-compile region jax times becomes a span
+    ``compile.trace`` [``fun``, ``self_s``: the duration less the
+    ``compile.trace`` spans nested in it; an outermost region always, one
+    nested in a trace or lowering region from ``_NESTED_TRACE_MIN_S`` on, so
+    the ``self_s`` of a stretch add up to its tracing seconds] /
+    ``compile.lower`` [``fun``] /
+    ``compile.backend`` [``fun``, ``cache`` = ``hit`` | ``written`` |
+    ``uncached``, ``read_s`` on a hit], a child of whatever span the
+    compiling thread is inside, and feeds ``paddle_compile_events_total
+    {kind="trace"|"lower"|"backend"}`` and ``paddle_compile_seconds``.
+
+    The spans are pre-measured (``tracing.record_span``): jax fires an
+    event when its region ends, so ``t1`` is the callback's
+    ``time.monotonic()`` and ``t0 = t1 - duration``. They are memory-only:
+    a profiler's trace cannot take an event after the fact; it holds the
+    region span the compile ran inside (``train.step.call`` as wide as its
+    compile). There is no switch. Idempotent, whichever copy of this
+    module is asked; False in a process that has not imported jax."""
+    if "jax" not in sys.modules:
+        return False
+    import jax.monitoring as monitoring
+
+    if not getattr(monitoring, _INSTALLED, False):
+        setattr(monitoring, _INSTALLED, True)
+        monitoring.register_scalar_listener(_on_region_start)
+        monitoring.register_event_listener(_on_cache_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
+    return True

@@ -15,7 +15,12 @@ annotation is TraceMe's inactive path; that is all "tracing off" means
 here: there is no switch. jax is only used where the process has already
 imported it, so the jax-free wire clients stay jax-free and memory-only.
 
-Spans come from: the train path (``io.next_batch``, ``spmd.shard_batch``,
+Spans come from: start-up (``nn.init`` a parameter drawn,
+``train.build_step``, ``train.init_state`` + ``.params`` / ``.opt_state``,
+``io.loader.start``, and jax's own trace / lowering / backend-compile
+regions as pre-measured ``compile.trace`` / ``.lower`` / ``.backend``,
+bridged from ``jax.monitoring`` by ``obs/ledger.py``: memory only, as every
+``record_span``), the train path (``io.next_batch``, ``spmd.shard_batch``,
 ``train.step`` and their children), the serving engine
 (``serving.scheduler.loop`` / ``.execute`` / ``.compile`` as regions;
 ``serving.queue`` / ``.request`` / ``.reply`` pre-measured per traced
@@ -30,6 +35,7 @@ installs an ambient id for the thread and an explicit ``trace_id=`` wins.
 """
 import collections
 import contextlib
+import functools
 import itertools
 import random
 import sys
@@ -38,7 +44,10 @@ import time
 
 #: prefix of the program's spans in the profiler's trace
 ANNOTATION_PREFIX = "paddle_tpu:"
-#: finished spans kept; a 10 s benchmark window leaves under a thousand
+#: finished spans kept; a benchmark run (start-up, a 10 s window, a traced
+#: slice) leaves under two thousand. A reader that sums over a stretch of
+#: the run and finds the ring full cannot know what fell out: it reports
+#: nothing rather than a short sum
 _RING = 8192
 
 _lock = threading.Lock()
@@ -159,6 +168,18 @@ def start_span(name, trace_id=None, parent_id=None, **attrs):
 span = start_span
 
 
+def spanned(name, **attrs):
+    """Decorator: each call of the function is one region span ``name``
+    (the ambient parent of what the call opens on its thread)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with Span(name, attrs=dict(attrs)):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
+
+
 def _agg_update_locked(name, duration_s):
     """Fold one duration into the summary table. Caller holds _lock."""
     rec = _agg.get(name)
@@ -205,6 +226,13 @@ def finished(trace_id=None, name=None):
     return [s.as_dict() for s in spans
             if (trace_id is None or s.trace_id == trace_id)
             and (name is None or s.name == name)]
+
+
+def ring_full():
+    """True once the ring holds ``_RING`` spans: older ones may have fallen
+    out, so a sum over "everything since X" may be short."""
+    with _lock:
+        return len(_finished) == _RING
 
 
 def self_times(spans):
