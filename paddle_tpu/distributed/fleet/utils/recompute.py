@@ -2,11 +2,17 @@
 RecomputeFunction; static analog backward.py:729
 _append_backward_ops_with_checkpoints_).
 
-TPU-native: in traced mode this is literally ``jax.checkpoint`` — XLA
-rematerialises the segment in backward. Where ``function`` is a Layer (or
-a Layer's bound method), the buffers it rewrites and the auxiliary losses
-it emits leave the checkpointed function as outputs and are put back where
-an unwrapped call would have left them. In eager mode recompute is the
+TPU-native: in traced mode this is ``jax.checkpoint`` — XLA rematerialises
+the segment in backward — under one policy: what a kernel inside the
+segment offered by name (``ops/residuals.py``: the streaming flash kernel's
+output and log-sum-exp) is kept from the first forward, so the backward
+does not run the kernel a second time to get it; everything else is
+recomputed. What is kept follows from the segment's trace alone: a segment
+with no such kernel differentiates to what a plain ``jax.checkpoint`` gives.
+Where ``function`` is a Layer (or a Layer's bound method), the buffers it
+rewrites and the auxiliary losses it emits leave the checkpointed function
+as outputs and are put back where an unwrapped call would have left them.
+In eager mode recompute is the
 identity: the tape's per-op cached vjps already recompute each op's
 forward inside the backward (inherent rematerialisation), and wrapping
 the segment as one opaque op would hide captured Layer parameters from
@@ -16,6 +22,7 @@ import jax
 
 from ....core import dispatch
 from ....core.tensor import Tensor
+from ....ops import residuals
 
 
 def recompute(function, *args, **kwargs):
@@ -51,7 +58,8 @@ def recompute(function, *args, **kwargs):
                 layer.load_functional_state(None, before)
             return outs, rewritten, (total_aux_loss(auxes) if auxes else None)
 
-        out, rewritten, aux = jax.checkpoint(pure)(*arrs)
+        out, rewritten, aux = jax.checkpoint(
+            pure, policy=residuals.keep_offered)(*arrs)
         if rewritten:
             layer.load_functional_state(None, rewritten)
         if aux is not None:

@@ -45,6 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.random import fmix32, keep_thresh_u32
 from ...obs import metrics as obs_metrics
+from .. import residuals
 
 NEG_INF = -1e30
 
@@ -616,6 +617,20 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
                interpret):
     o, lse = _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
                   interpret)
+    # what only this call can make: a recomputed block keeps the two (its
+    # second forward then needs no kernel call); q, k and v come back from
+    # the block's projections
+    o = residuals.offer(o, "flash_stream.out")
+    # as [bh, seq_q]: a trailing dimension of one is padded to the 128
+    # lanes in HBM, 128 times the bytes for as long as a block keeps it.
+    # STOP-GAP, the two negations (they cancel bit for bit, and fold away
+    # with the reshapes where nothing keeps the array): XLA names the
+    # un-padding after its operand, this kernel's call, and a trace's
+    # readers would count it as one. To go with a lane-dense log-sum-exp
+    # from the kernel (ROADMAP Speed 1c) or with readers that match a
+    # call's own name (PERF.md section 7)
+    lse = -residuals.offer((-lse).reshape(lse.shape[:2]),
+                           "flash_stream.lse").reshape(lse.shape)
     return o, (q, k, v, o, lse, seed)
 
 

@@ -86,6 +86,22 @@ def _restrace_census_guard():
             restrace.assert_clean()
 
 
+@pytest.fixture
+def residual_counts():
+    """Reader of ``paddle_tpu_recompute_residual_total`` (ops/residuals.py):
+    ``{(name, event): count}`` over every name and event; with an earlier
+    reading ``since``, what was counted after it."""
+    from paddle_tpu.ops import residuals
+
+    def counts(since=None):
+        return {(name, event): residuals._RESIDUAL_TOTAL.value(
+                    name=name, event=event)
+                - (since[name, event] if since else 0)
+                for name in residuals.NAMES
+                for event in ("offered", "kept")}
+    return counts
+
+
 @pytest.fixture(autouse=True)
 def _seed():
     import numpy as np

@@ -356,7 +356,16 @@ def test_overflow_count_leaves_the_layer_as_a_buffer():
     assert int(layer.held_overflow._value) == 0
 
 
-def test_recomputation_gives_the_same_loss_and_gradients(ids):
+@pytest.mark.parametrize("kernels", [False, True])
+def test_recomputation_gives_the_same_loss_and_gradients(
+        ids, kernels, residual_counts):
+    """``kernels``: the streaming kernel on every latent-attention core
+    (here in the Pallas interpreter), so each recomputed block keeps the
+    kernel's output and log-sum-exp (ops/residuals.py) — four kernel calls
+    a traced step (three blocks + the MTP module's), each offering its two
+    arrays, kept where ``recompute`` wraps the block and nowhere else."""
+    from paddle_tpu.ops import residuals
+
     plain, remat = build(use_recompute=False), build(use_recompute=True)
     params, buffers = plain.functional_state()
 
@@ -364,10 +373,22 @@ def test_recomputation_gives_the_same_loss_and_gradients(ids):
         def fn(p):
             out = framework_terms(net, p, ids, buffers)
             return out[1], out[4]
-        return jax.jit(jax.value_and_grad(fn, has_aux=True))(params)
+        before = residual_counts()
+        got = jax.jit(jax.value_and_grad(fn, has_aux=True))(params)
+        calls, kept = (4, 4 * (net is remat)) if kernels else (0, 0)
+        assert residual_counts(before) == {
+            (n, e): calls if e == "offered" else kept
+            for n, e in before}
+        return got
 
-    (loss_a, buf_a), grads_a = loss_and_state(plain)
-    (loss_b, buf_b), grads_b = loss_and_state(remat)
+    paddle.set_flags({"pallas_interpret": kernels,
+                      "pallas_attention_min_seq": 0 if kernels else 1024})
+    try:
+        (loss_a, buf_a), grads_a = loss_and_state(plain)
+        (loss_b, buf_b), grads_b = loss_and_state(remat)
+    finally:
+        paddle.set_flags({"pallas_interpret": False,
+                          "pallas_attention_min_seq": 1024})
     assert float(loss_a) == pytest.approx(float(loss_b), rel=1e-6)
     for name in grads_a:
         scale = float(jnp.abs(grads_a[name]).max())

@@ -372,6 +372,52 @@ def test_recomputation_gives_the_same_loss_and_gradients(ids):
                                       np.asarray(buf_b[name]))
 
 
+def test_a_traced_step_keeps_the_one_latent_layers_kernel_residuals(
+        ids, residual_counts):
+    """With the streaming kernel on the latent-attention core (here in the
+    Pallas interpreter) a traced step counts ONE kernel call offering its
+    output and log-sum-exp and one recomputed block keeping them
+    (ops/residuals.py): the four KDA blocks hold no offering kernel."""
+    from paddle_tpu.ops import residuals
+
+    net = build(use_recompute=True)
+    params = net.functional_state()[0]
+    paddle.set_flags({"pallas_interpret": True,
+                      "pallas_attention_min_seq": 0})
+    try:
+        before = residual_counts()
+        jax.make_jaxpr(jax.grad(
+            lambda p: framework_terms(net, p, ids)[1]))(params)
+    finally:
+        paddle.set_flags({"pallas_interpret": False,
+                          "pallas_attention_min_seq": 1024})
+    assert residual_counts(before) == (
+        dict.fromkeys(before, 1))
+
+
+def test_blocks_without_an_offering_kernel_are_checkpointed_as_before(
+        ids, monkeypatch, residual_counts):
+    """On the XLA routes no block of the model holds a kernel that offers
+    residuals (the KDA blocks never do): ``recompute`` under its policy
+    lowers the step's gradient to the text ``jax.checkpoint`` with no
+    policy gives, and keeps nothing."""
+    from paddle_tpu.ops import residuals
+
+    net = build(use_recompute=True)
+    params = net.functional_state()[0]
+
+    def lowered():
+        return jax.jit(jax.grad(
+            lambda p: framework_terms(net, p, ids)[1])).lower(
+                params).as_text()
+
+    before = residual_counts()
+    now = lowered()
+    assert residual_counts() == before
+    monkeypatch.setattr(residuals, "keep_offered", None)
+    assert lowered() == now and "optimization_barrier" in now
+
+
 def test_a_train_step_decays_every_weight_but_the_decays_own(ids):
     """Through ``spmd.build_train_step`` with a learning rate that leaves
     only the decay to see: ``apply_decay_param_fun`` reaches the compiled

@@ -13,6 +13,7 @@ once, and the test workers' environment stays as it was. Only a missing
 libtpu skips; a child that fails, fails the tests.
 """
 import collections
+import functools
 import importlib.util
 import json
 import os
@@ -73,6 +74,18 @@ STREAM_TWO_CALL_CASES = {
 STREAM_TWO_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
                     "flash_stream_bwd_dkv")
 ALL_STREAM_CASES = {**STREAM_CASES, **STREAM_TWO_CALL_CASES}
+#: a latent-attention block under ``fleet.utils.recompute``, forward +
+#: backward (hidden, heads, q rank, kv rank, nope, rope, value width, seq,
+#: dtype, rotated): the JoyAI cell's in its check's float32 and the
+#: Kimi-Linear cell's (no q rank, nothing rotated) under amp O1 — the
+#: kernel's output and log-sum-exp kept, so two Mosaic calls where a plain
+#: ``jax.checkpoint`` compiles three
+RECOMPUTED_MLA_CASES = {
+    "joyai-check-f32": (2048, 32, 1536, 512, 128, 64, 128, 8192,
+                        "float32", True),
+    "kimi-cell-bf16": (2304, 32, None, 512, 128, 64, 128, 16384,
+                       "bfloat16", False),
+}
 #: the gated delta rule's scan (batch, seq, heads, head_dim, dtype): at the
 #: Kimi-Linear cell's four layers, in float32 (the cell's check runs it),
 #: and at a head of two lane groups. KDA_CALLS: the names its two Mosaic
@@ -206,6 +219,121 @@ def _child():
             "key_wide_results": text.count(
                 f"[{batch * heads},{seq},{d_qk}]")}
 
+    # what the kernel's forward rule does for a recomputed block
+    # (ops/residuals.py: the tags, the log-sum-exp's reshape between two
+    # negations) folds away where nothing keeps it: the OLMoE cell's
+    # attention compiles to the program of the forward rule as it was
+    # before there were residuals to offer
+    from paddle_tpu.ops import residuals
+
+    def unnamed_fwd(q, k, v, seed, *statics):
+        o, lse = fa._fwd(q, k, v, seed, *statics)
+        return o, (q, k, v, o, lse, seed)
+
+    def stream_program():
+        batch, heads, seq, head_dim, dtype = STREAM_CASES["olmoe-cell-bf16"]
+        qkv = jax.ShapeDtypeStruct((batch, heads, seq, head_dim), dtype,
+                                   sharding=one)
+        offered = residuals._RESIDUAL_TOTAL.value(
+            name=residuals.NAMES[1], event="offered")
+        text = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(fa.mha(q, k, v, causal=True).astype(
+                jnp.float32)), argnums=(0, 1, 2))).lower(
+                    qkv, qkv, qkv).compile().as_text()
+        # the computations, less what differs by where a rule's lines
+        # stand: the source tables above them, metadata, the Mosaic bodies
+        # (they carry their callers' source lines), and the numbers XLA's
+        # names end in (a folded negation has used one up)
+        lines = [line.split(", backend_config=")[0] if MOSAIC in line
+                 else line for line in text.splitlines()
+                 if re.match(r"\s*(%|ROOT |ENTRY |\})", line)]
+        text = re.sub(r"(%[A-Za-z_\-]+)[.\d]*", r"\1", re.sub(
+            r", metadata=\{[^}]*\}", "", "\n".join(lines)))
+        return text, residuals._RESIDUAL_TOTAL.value(
+            name=residuals.NAMES[1], event="offered") - offered
+
+    programs = []
+    for forward_rule in (fa._flash_fwd, unnamed_fwd):
+        fa._flash.defvjp(forward_rule, fa._flash_bwd)
+        programs.append(stream_program())
+    fa._flash.defvjp(fa._flash_fwd, fa._flash_bwd)
+    out["stream-offers"] = {
+        "same_program": programs[0][0] == programs[1][0],
+        "offered": [programs[0][1], programs[1][1]],
+        "mosaic": programs[0][0].count(MOSAIC),
+        "negations": [text.count(" negate(") for text, _ in programs]}
+
+    # a recomputed latent-attention block through the gate, as a step on
+    # one chip traces it
+    from paddle_tpu.core import dispatch
+    from paddle_tpu.distributed.fleet.utils import recompute
+    from paddle_tpu.text.models import MLAttention
+
+    attention._use_pallas = lambda: True
+    one_mesh = topology.build_mesh(dp=1, devices=v5e[:1])
+
+    def mosaic_calls(text):
+        """(name, result shapes) of a compiled program's Mosaic calls."""
+        found = []
+        for line in text.splitlines():
+            if MOSAIC in line:
+                name = re.search(r"/(\w+)/pallas_call", line)
+                result = line.split(" custom-call(")[0].split(" = ", 1)[1]
+                found.append([name.group(1) if name else "",
+                              re.sub(r"\{[^}]*\}", "", result)])
+        return sorted(found)
+
+    def named_after_a_kernel(text):
+        """Operations that run, other than the Mosaic calls, whose op_name
+        holds the forward kernel's name: a trace's readers find a kernel's
+        calls by that name and would count each as one."""
+        return sum(STREAM_CALLS[0] in line and MOSAIC not in line
+                   and not re.search(
+                       r" (get-tuple-element|constant|bitcast)\(", line)
+                   for line in text.splitlines())
+
+    for name, (hidden, heads, q_rank, kv_rank, nope, rope, d_v, seq, dtype,
+               rotated) in RECOMPUTED_MLA_CASES.items():
+        block = MLAttention(hidden, heads, q_rank, kv_rank, nope, rope, d_v,
+                            rope=rotated)
+        block.train()
+        params0, buffers0 = block.functional_state()
+
+        def loss(params, x, kept):
+            saved = block.functional_state()
+            try:
+                with dispatch.trace_mode(), topology.tracing_for(one_mesh), \
+                        paddle.amp.auto_cast(enable=dtype == "bfloat16",
+                                             level="O1"):
+                    block.load_functional_state(params, buffers0)
+                    if kept:
+                        o = recompute(block, paddle.Tensor(x))._value
+                    else:
+                        o = jax.checkpoint(
+                            lambda a: block(paddle.Tensor(a))._value)(x)
+            finally:
+                block.load_functional_state(*saved)
+            return jnp.sum(o.astype(jnp.float32))
+
+        args = ({n: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+                 for n, a in params0.items()},
+                jax.ShapeDtypeStruct((1, seq, hidden), jnp.float32,
+                                     sharding=one))
+        calls, strays = {}, {}
+        for kept in (False, True):
+            # the float32 check asks every product for float32 in earnest
+            with jax.default_matmul_precision(
+                    "highest" if dtype == "float32" else "default"):
+                # with its value: a gradient alone needs no first forward
+                text = jax.jit(jax.value_and_grad(
+                    functools.partial(loss, kept=kept),
+                    argnums=(0, 1))).lower(*args).compile().as_text()
+            calls[kept] = mosaic_calls(text)
+            strays[kept] = named_after_a_kernel(text)
+        out["recomputed-mla-" + name] = {
+            "plain": calls[False], "kept": calls[True],
+            "named_after_a_kernel": [strays[False], strays[True]]}
+
     from paddle_tpu.ops.pallas import linear_attention as kda
 
     for name, (batch, seq, heads, head_dim, dtype) in KDA_CASES.items():
@@ -310,7 +438,6 @@ def _child():
     # through the gate, on a process with several devices: the kernel where
     # the step announced its mesh, XLA's route where a plain jit is handed
     # arrays on a mesh (the dp4 cell's reference check does that)
-    attention._use_pallas = lambda: True
     assert jax.device_count() > 1
     mesh = topology.build_mesh(dp=4, devices=v5e)
     sharded = NamedSharding(mesh, P("dp"))
@@ -387,6 +514,42 @@ def test_stream_kernel_compiles(compiled, case):
         # keys and values keep their own widths through every call:
         # nothing is padded to the other's
         assert got["value_wide_results"] and got["key_wide_results"]
+
+
+def test_an_offer_nothing_keeps_compiles_to_nothing(compiled):
+    """The streaming kernel's forward rule names its output and
+    log-sum-exp for the blocks that keep them (ops/residuals.py), the
+    log-sum-exp as [bh, seq] between two negations; at the OLMoE cell's
+    attention, which no ``recompute`` wraps, the compiled gradient is the
+    program of a forward rule that does none of it (the rule as it was
+    before): the tags lower to nothing, XLA folds the reshape there and
+    back and the two signs, and no negation is left. The two traces did
+    differ: one counted an offer, the other none."""
+    got = compiled["stream-offers"]
+    assert got["offered"] == [1, 0]
+    assert got["mosaic"] == 2 and got["negations"] == [0, 0]
+    assert got["same_program"]
+
+
+@pytest.mark.parametrize("case", list(RECOMPUTED_MLA_CASES))
+def test_recomputed_latent_attention_keeps_the_kernels_residuals(
+        compiled, case):
+    """A latent-attention block's gradient under ``fleet.utils.recompute``
+    at the JoyAI cell's widths (float32, inside the check's
+    ``default_matmul_precision("highest")``) and at the Kimi-Linear cell's
+    (bf16 under amp O1, 16,384 keys): ONE forward and one backward Mosaic
+    call, where the same block under a plain ``jax.checkpoint`` compiles a
+    second forward call — and each is the call Mosaic was asked for
+    before: the same names and result shapes."""
+    got = compiled["recomputed-mla-" + case]
+    assert [c[0] for c in got["kept"]] == sorted(STREAM_CALLS)
+    assert [c[0] for c in got["plain"]] == sorted(
+        STREAM_CALLS + STREAM_CALLS[:1])
+    assert all(call in got["plain"] for call in got["kept"])
+    # XLA names a relayout after its operand: the kept log-sum-exp's
+    # un-padding must not run under the kernel's name (the benchmark's
+    # readers count events by it)
+    assert got["named_after_a_kernel"] == [0, 0]
 
 
 @pytest.mark.parametrize("case", list(KDA_CASES))
