@@ -60,7 +60,8 @@ matrices; 'swiglu': ``w_down(silu(x w_gate) * (x w_up))``), ``gate_bias``,
 the scores for the CHOICE only and moved by ``bias_update_speed`` against
 each training step's loads — DeepSeek-V3's auxiliary-loss-free balancing —
 and ``routed_scale``), ``shared_width`` (a SwiGLU every token takes, added
-to the routed sum; sigmoid scoring and the shared expert run on the two
+to the routed sum — with ``shared_gate`` times ``sigmoid(x w_s)`` a token,
+Qwen3-Next's; sigmoid scoring and the shared expert run on the two
 sorted paths), and two auxiliary losses through ``nn.aux_loss.emit_aux_loss``: load balancing
 times ``aux_weight`` and the router z-loss ``mean_t logsumexp(r_t)^2`` times
 ``z_loss_weight``. The sorted path's load-balancing term is the
@@ -496,7 +497,7 @@ class MoELayer(nn.Layer):
                  norm_topk_prob=True, z_loss_weight=0.0, weight_attr=None,
                  scoring="softmax", select_bias=False, bias_update_speed=0.0,
                  routed_scale=1.0, shared_width=0, held=None,
-                 held_rows_factor=2.0):
+                 held_rows_factor=2.0, shared_gate=False):
         super().__init__()
         self.num_experts = int(num_experts)
         self.top_k = int(top_k)
@@ -554,6 +555,10 @@ class MoELayer(nn.Layer):
             from ..text.models import LlamaMLP
 
             self.shared = LlamaMLP(hidden_size, shared_width, weight_attr)
+        # Qwen3-Next's: the shared expert behind a gate of its own, a token
+        self.shared_gate = (nn.Linear(hidden_size, 1, weight_attr=weight_attr,
+                                      bias_attr=False)
+                            if shared_gate and shared_width else None)
         if select_bias:
             # DeepSeek-V3's e_score_correction_bias: no gradient, moved
             # after each training step against the step's loads
@@ -597,7 +602,10 @@ class MoELayer(nn.Layer):
             if self.shared is None:
                 return out
             with jax.named_scope("moe.shared"):
-                return out + self.shared(x)
+                shared = self.shared(x)
+                if self.shared_gate is not None:
+                    shared = nn.functional.sigmoid(self.shared_gate(x)) * shared
+                return out + shared
         if self.scoring != "softmax" or self.shared is not None:
             raise NotImplementedError(
                 f"the {mode} path has the softmax router and no shared "

@@ -1,15 +1,19 @@
-"""Kimi Delta Attention's core: a gated delta rule whose decay is per key
-channel (Kimi Linear technical report, arXiv:2510.26692), as a recurrence
-over tokens and as the chunked scan: here in XLA operations (what a CPU, a
-program whose devices are not known and odd widths run, and what the tests
-hold everything to), in ``ops/pallas/linear_attention.py`` as the Mosaic
-kernels a train step on a TPU runs.
+"""The gated delta rule's core, under either of two decays: ONE PER KEY
+CHANNEL (Kimi Delta Attention; Kimi Linear technical report,
+arXiv:2510.26692), ``g`` of [B, T, H, d_k], and ONE A HEAD (Gated DeltaNet;
+Yang et al., arXiv:2412.06464 — Qwen3-Next's linear layers), ``g`` of
+[B, T, H]. As a recurrence over tokens and as the chunked scan: here in XLA
+operations (what a CPU, a program whose devices are not known and odd widths
+run, and what the tests hold everything to), in
+``ops/pallas/linear_attention.py`` as the Mosaic kernels a train step on a
+TPU runs. Every path takes either decay as it comes: a decay a head is never
+broadcast to the channels, and its gradient leaves as [B, T, H].
 
 Per head, with a state S in R^{d_k x d_v} (zero at a row's start), a decay
-``g_t <= 0`` per key channel (``alpha_t = exp(g_t)``), a write strength
-``beta_t`` and q, k, v of one token::
+``g_t <= 0`` (``alpha_t = exp(g_t)``: a vector over the key channels, or a
+number), a write strength ``beta_t`` and q, k, v of one token::
 
-    S'_t = Diag(alpha_t) S_{t-1}
+    S'_t = Diag(alpha_t) S_{t-1}          (alpha_t S_{t-1} for a decay a head)
     u_t  = beta_t (v_t - S'_t^T k_t)
     S_t  = S'_t + k_t u_t^T
     o_t  = S_t^T q_t
@@ -23,15 +27,27 @@ a chunk's first token to its r-th and ``S_0`` the state entering it::
     o_r      = S_0^T (q_r * exp(G_r)) + sum_{i <= r} <q_r * exp(G_r - G_i), k_i> u_i
     S_C      = Diag(exp(G_C)) S_0 + sum_i (k_i * exp(G_C - G_i)) u_i^T
 
-The decay per channel is the hazard: a pair term does not factor into
-``exp(G_r)`` times ``exp(-G_i)``, because the second overflows where a
-chunk's decay is strong. Every exponent here is a difference that is <= 0:
-the pair terms are computed exactly (one exponential a pair and channel) on
-the ``sub`` x ``sub`` diagonal sub-blocks, and against the row sub-block's
-own first token off the diagonal (``_pair_products``). ``T = (I + A)^-1``
-is built once a chunk (``_unit_lower_inverse``), so that only
-``U = T beta V - (T beta K exp(G)) S_0`` and the state's update run in the
-sequential loop over chunks; the outputs are batched matmuls after it.
+**Which pair terms each decay computes.** The decay per channel is the
+hazard: a pair term does not factor into ``exp(G_r)`` times ``exp(-G_i)``,
+because the second overflows where a chunk's decay is strong. Every
+exponent there is a difference that is <= 0: the pair terms are computed
+exactly (one exponential a pair and channel) on the ``sub`` x ``sub``
+diagonal sub-blocks, and against the row sub-block's own first token off
+the diagonal (``_pair_products``). With ONE decay a head the scalar leaves
+the inner product, ``A[r, i] = beta_r <k_r, k_i> exp(G_r - G_i)``: a
+chunk's pair terms are one [C, d] x [d, C] product under a [C, C] mask of
+exponentials whose exponents are all <= 0 on the triangle
+(``_scalar_pair_products``) — no diagonal sub-blocks, no reference token —
+and ``exp(G)``, ``exp(G_C - G)`` and ``exp(G_C)`` are a number a token (a
+chunk) that broadcasts over the channels. Everything after the pair terms is
+the same code for both. ``T = (I + A)^-1`` is built once a chunk
+(``_unit_lower_inverse``), so that only ``U = T beta V - (T beta K exp(G))
+S_0`` and the state's update run in the sequential loop over chunks; the
+outputs are batched matmuls after it.
+
+Key heads that serve several value heads (Gated DeltaNet's 16 on 32) are the
+caller's to repeat: the scan takes one head count (``text.models
+.GatedDeltaNet`` does it under a scope of its own).
 
 Precision: G, A, the inverse and the state are float32 whatever q, k and v
 are, and A's and the inverse's products ask for float32 in earnest
@@ -51,10 +67,12 @@ its stacked results a slice at a time, which costs by the stack's size.
 ``gated_delta_rule`` is the entry point a layer calls: it picks the path
 from what it can observe (``core_path``: length, widths, dtype, platform,
 whether the program's devices are known) and counts the choice in
-``paddle_tpu_kda_core_total{path}``. A train step of the Kimi-Linear
-configuration on a TPU takes ``kernel``: the same mathematics, a chunk's
-terms in VMEM, forward and backward hand-written, nothing of this file's
-segments; ``chunked`` and ``recurrent`` are this file's.
+``paddle_tpu_kda_core_total{path}`` (``kernel`` | ``chunked`` |
+``recurrent``, with ``_scalar`` appended for a decay a head). A train step
+of the Kimi-Linear or the Qwen3-Next configuration on a TPU takes the
+kernels: the same mathematics, a chunk's terms in VMEM, forward and
+backward hand-written, nothing of this file's segments; ``chunked`` and
+``recurrent`` are this file's.
 """
 import functools
 
@@ -67,7 +85,8 @@ from ..obs import metrics as obs_metrics
 _CORE_TOTAL = obs_metrics.counter(
     "paddle_tpu_kda_core_total",
     "gated-delta-rule cores by the path taken (kernel | chunked | "
-    "recurrent); under jit one count per traced layer call",
+    "recurrent, with _scalar appended for one decay a head); under jit one "
+    "count per traced layer call",
     labelnames=("path",))
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -76,9 +95,11 @@ SUB = 16
 
 
 def kda_recurrent(q, k, v, g, beta, initial_state=None):
-    """The recurrence token by token. q, k, g: [B, T, H, d_k]; v: [B, T, H,
-    d_v]; beta: [B, T, H]. Returns (o [B, T, H, d_v] in v's dtype, the final
-    state [B, H, d_k, d_v] in float32). Everything is computed in float32."""
+    """The recurrence token by token. q, k: [B, T, H, d_k]; v: [B, T, H,
+    d_v]; g: [B, T, H, d_k] (a decay per key channel) or [B, T, H] (one a
+    head); beta: [B, T, H]. Returns (o [B, T, H, d_v] in v's dtype, the
+    final state [B, H, d_k, d_v] in float32). Everything is computed in
+    float32."""
     f32 = jnp.float32
     b, _, h, dk = k.shape
     state = (jnp.zeros((b, h, dk, v.shape[-1]), f32) if initial_state is None
@@ -86,7 +107,7 @@ def kda_recurrent(q, k, v, g, beta, initial_state=None):
 
     def step(s, xs):
         q_t, k_t, v_t, g_t, beta_t = xs
-        s = jnp.exp(g_t)[..., None] * s
+        s = jnp.exp(g_t).reshape(g_t.shape + (1,) * (s.ndim - g_t.ndim)) * s
         u = beta_t[..., None] * (v_t - jnp.einsum(
             "bhkv,bhk->bhv", s, k_t, precision=_HIGHEST))
         s = s + k_t[..., None] * u[..., None, :]
@@ -247,22 +268,46 @@ def _pair_products(q, k, cum, sub):
             jnp.concatenate(rows_q, axis=-2))
 
 
+def _scalar_pair_products(q, k, cum):
+    """The pair terms under ONE decay a head: ``<x_r, k_i> exp(G_r - G_i)``
+    for x = k (zero unless i < r) and x = q (zero unless i <= r), each
+    [..., C, C]: one [C, d] x [d, C] product each under a [C, C] mask of
+    exponentials. q, k [..., C, d] and ``cum`` [..., C] in float32. No
+    exponent is positive on the triangle; above it the difference is held
+    at 0 and the term masked."""
+    c = k.shape[-2]
+    r, i = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    decay = jnp.exp(jnp.minimum(cum[..., :, None] - cum[..., None, :], 0.0))
+    both = jnp.einsum("...rd,...id->...ri", jnp.concatenate([k, q], axis=-2),
+                      k, precision=_HIGHEST)
+    return (jnp.where(i < r, both[..., :c, :] * decay, 0.0),
+            jnp.where(i <= r, both[..., c:, :] * decay, 0.0))
+
+
 def _segment(state, xs, *, sub):
     """One segment of whole chunks. xs: q, k [B, H, N, C, d_k] and
-    v [B, H, N, C, d_v] in the operand dtype, g [B, H, N, C, d_k] and
-    beta [B, H, N, C] in float32; state [B, H, d_k, d_v] float32. Returns
-    (the state after the segment, o [B, H, N, C, d_v] float32)."""
+    v [B, H, N, C, d_v] in the operand dtype, g [B, H, N, C, d_k] (or
+    [B, H, N, C]: a decay a head) and beta [B, H, N, C] in float32; state
+    [B, H, d_k, d_v] float32. Returns (the state after the segment,
+    o [B, H, N, C, d_v] float32)."""
     q, k, v, g, beta = xs
     f32, mm = jnp.float32, q.dtype
     # the decay summed from the chunk's start, as a product with the lower
     # triangle of ones (a cumsum lowers to a window reduction: 0.26 ms a
     # segment on the v5e against 0.02)
-    c = g.shape[-2]
-    cum = jnp.einsum("ri,...id->...rd", jnp.tril(jnp.ones((c, c), f32)), g,
-                     precision=_HIGHEST)
-    last = cum[..., -1:, :]
     qf, kf = q.astype(f32), k.astype(f32)
-    a_kk, a_qk = _pair_products(qf, kf, cum, sub)
+    c = q.shape[-2]
+    ones = jnp.tril(jnp.ones((c, c), f32))
+    if g.ndim == beta.ndim:
+        # a decay a head: the sums are a number a token, the pair terms
+        # factor, and everything below broadcasts them over the channels
+        cum = jnp.einsum("ri,...i->...r", ones, g, precision=_HIGHEST)
+        a_kk, a_qk = _scalar_pair_products(qf, kf, cum)
+        cum = cum[..., None]
+    else:
+        cum = jnp.einsum("ri,...id->...rd", ones, g, precision=_HIGHEST)
+        a_kk, a_qk = _pair_products(qf, kf, cum, sub)
+    last = cum[..., -1:, :]
     # T = (I + A)^-1 Diag(beta): row r of A carries beta_r, T's columns
     # carry the right-hand side's
     t = (_unit_lower_inverse(beta[..., :, None] * a_kk, sub)
@@ -358,11 +403,15 @@ def core_path(seq, d_k=None, d_v=None, dtype=None):
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
-    """Kimi Delta Attention's core on Tensors (shapes as ``kda_recurrent``):
-    o [B, T, H, d_v]. The final state stays inside: training starts every
-    row from a zero state and keeps none."""
+    """The gated delta rule on Tensors (shapes as ``kda_recurrent``): o [B,
+    T, H, d_v]. ``g`` is [B, T, H, d_k] (Kimi Delta Attention: a decay per
+    key channel) or [B, T, H] (Gated DeltaNet: one a head), and every path
+    takes either as it is: the second is never broadcast to the first. The
+    final state stays inside: training starts every row from a zero state
+    and keeps none. The counter's ``path`` ends in ``_scalar`` for a decay a
+    head."""
     path = core_path(q.shape[1], q.shape[-1], v.shape[-1], q.dtype)
-    _CORE_TOTAL.inc(path=path)
+    _CORE_TOTAL.inc(path=path + ("_scalar" if g.ndim == 3 else ""))
     if path == "recurrent":
         return apply_op("kda_core_recurrent", _recurrent_output, q, k, v, g,
                         beta)

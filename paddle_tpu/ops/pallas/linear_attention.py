@@ -1,6 +1,7 @@
 """The gated delta rule's chunked scan (``ops/linear_attention.py``, whose
 docstring has the equations) as two Pallas TPU kernels under a
-``jax.custom_vjp``: ``kda_chunk_fwd`` and ``kda_chunk_bwd``.
+``jax.custom_vjp``: ``kda_chunk_fwd`` and ``kda_chunk_bwd``, for a decay per
+key channel (Kimi Delta Attention) and for one a head (Gated DeltaNet).
 
 A program is one batch row, ``TOGETHER`` heads and one block of ``TOKENS``
 tokens, taken from the layer's arrays viewed as [batch, tokens, heads x d]
@@ -18,7 +19,7 @@ A chunk, in VMEM and registers only:
 - ``G`` = the decay summed from the chunk's first token (a product with
   the triangle of ones, float32 in earnest: three bf16 passes over the
   decay split exactly in three);
-- the pair terms ``<x_r * exp(G_r - G_i), k_i>`` for x = k (i < r) and
+- the pair terms (a decay per key channel; for one a head see below) ``<x_r * exp(G_r - G_i), k_i>`` for x = k (i < r) and
   x = q (i <= r): on the ``SUB`` x ``SUB`` diagonal sub-blocks exactly, one
   exponential a (r, i, channel), a column of every sub-block at a time;
   off the diagonal against the row sub-block's first token, as a product.
@@ -42,6 +43,22 @@ scratch, recomputes G, the decays, W and U from them, and emits dq, dk, dv
 X^T`` on the strict lower triangle, the diagonal pair terms' is three
 sums over the same [r, i, d] terms the forward made (``ops/
 linear_attention._diagonal_pairs_bwd``), a column at a time again.
+
+**One decay a head** (Gated DeltaNet: ``g`` of [B, T, H]). The kernels take
+it as it is, a row a chunk [B, H, T / C, 1, C] like beta — no [.., d_k]
+broadcast in HBM, forward or backward, and ``dg`` leaves in the same shape.
+In VMEM: ``G`` is a masked lane sum on the vector unit, a column [C, 1] a
+chunk; the pair terms FACTOR, ``<x_r, k_i> exp(G_r - G_i)``: one
+[C, d] x [d, C] product for k and one for q (bf16 operands multiply exactly
+into the float32 sum) under a [C, C] mask of exponentials, every exponent
+<= 0 on the triangle — none of the diagonal sub-blocks' column-at-a-time
+vector work, which is where the per-channel forward spends a seventh of its
+time (PERF.md section 5); ``exp(G)``, ``exp(G_C - G)`` are columns that
+broadcast over the lanes, the chunk's decay a number. The backward's pair
+terms are four products and the decay's gradient a row sum minus a column
+sum of (cotangent x term). Everything else — the inverse, T, W, U, the
+loop over chunks, what is kept — is the per-channel code, whose program is
+unchanged (the branch is on the decay's shape at trace time).
 
 ``interpret=True`` runs both in the Pallas interpreter (the CPU tests and
 the chip_smoke dry run ask for it; never inferred from the backend).
@@ -295,27 +312,67 @@ def _as_column(x):
     return jnp.sum(jnp.where(_eye(CHUNK), x, 0.0), axis=2, keepdims=True)
 
 
+def _scalar_decay_sums(g):
+    """G [N, C, 1] from a decay a head g [N, 1, C] (a row a chunk, as beta
+    comes): each token's sum from its chunk's first token, inclusive, in
+    float32 on the vector unit (a masked lane sum; no product to round)."""
+    r, i = _grid_masks()
+    return jnp.sum(jnp.where(i <= r, g, 0.0), axis=2, keepdims=True)
+
+
+def _scalar_decay_mask(cum):
+    """exp(G_r - G_i) [N, C, C] from the sums as a column [N, C, 1]; above
+    the diagonal, where the difference is positive and the term is masked,
+    it is held at 0."""
+    return jnp.exp(jnp.minimum(cum - _as_row(cum), 0.0))
+
+
+def _scalar_pair_terms(q, k, cum):
+    """The pair terms under ONE decay a head: they factor, ``<x_r, k_i>
+    exp(G_r - G_i)``, so a chunk's are one [C, d] x [d, C] product for k
+    and one for q under a [C, C] mask of exponentials — no diagonal
+    sub-blocks, no reference token. q, k [N, C, d] in the operand dtype
+    (bf16 products of bf16 numbers are exact in the float32 sum; float32
+    operands ask for float32 in earnest), ``cum`` [N, C, 1]."""
+    r, i = _grid_masks()
+    decay = _scalar_decay_mask(cum)
+    return (jnp.where(i < r, _bmm(k, k, _NT, _HIGHEST) * decay, 0.0),
+            jnp.where(i <= r, _bmm(q, k, _NT, _HIGHEST) * decay, 0.0))
+
+
 def _set_up(q, k, v, g, beta, mats=None):
     """What a block's chunks need that does not wait for the state. q, k,
-    v [N, C, d] (the operand dtype), g [N, C, d] and beta [N, 1, C]
-    float32; ``mats`` = (A_kk, A_qk, X) where the caller kept them. A dict
-    of [N, ...] stacks."""
+    v [N, C, d] (the operand dtype), beta [N, 1, C] float32, g float32
+    [N, C, d] (a decay per key channel) or [N, 1, C] (one a head, a row a
+    chunk like beta: its sums, ``grown``, ``shrunk`` and ``decay`` are then
+    [N, C, 1] and [N, 1, 1] and broadcast over the channels); ``mats`` =
+    (A_kk, A_qk, X) where the caller kept them. A dict of [N, ...]
+    stacks."""
     mm = q.dtype
     qf, kf = q.astype(_F32), k.astype(_F32)
-    cum = _decay_sums(g)
+    scalar = g.shape[1] == 1
+    cum = _scalar_decay_sums(g) if scalar else _decay_sums(g)
     if mats is None:
-        a_kk, a_qk = _pair_terms(qf, kf, cum)
+        a_kk, a_qk = (_scalar_pair_terms(q, k, cum) if scalar
+                      else _pair_terms(qf, kf, cum))
         x = _unit_lower_inverse(_as_column(beta) * a_kk)
     else:
         a_kk, a_qk, x = mats
     grown = jnp.exp(cum)
-    last = cum[:, CHUNK - 1:CHUNK]                           # [N, 1, d]
+    # [N, 1, d]; [N, 1, 1] for a decay a head: the chunk's whole sum (Mosaic
+    # does not cut a row out of an array one lane wide)
+    last = (jnp.sum(g, axis=2, keepdims=True) if scalar
+            else cum[:, CHUNK - 1:CHUNK])
     shrunk = jnp.exp(last - cum)
     t = (x * beta).astype(mm)
     kg = (kf * grown).astype(mm)
     w = _bmm(t, kg, _NN)
     k_out = kf * shrunk
-    decay = jnp.exp(last)
+    decay_head = jnp.exp(last)
+    # over the key channels [N, 1, d] either way, so that the states meet
+    # one layout
+    decay = (jnp.broadcast_to(decay_head, (kf.shape[0], 1, kf.shape[2]))
+             if scalar else decay_head)
     # the transposes the loop over chunks would wait for are made here
     return dict(
         qf=qf, kf=kf, cum=cum, a_kk=a_kk, a_qk=a_qk, x=x, grown=grown,
@@ -324,6 +381,7 @@ def _set_up(q, k, v, g, beta, mats=None):
         k_out=k_out.astype(mm), k_out_t=jnp.swapaxes(k_out, 1, 2).astype(mm),
         decay_column=jnp.sum(jnp.where(_eye(kf.shape[-1]), decay, 0.0),
                              axis=2, keepdims=True),
+        decay_head=decay_head,
         qg=(qf * grown).astype(mm))
 
 
@@ -348,6 +406,14 @@ def _stacked(ref):
     """A [1, G, rows, ...] block with its first two axes merged."""
     x = ref[0]
     return x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
+
+
+def _decay_in(ref, tokens, heads):
+    """The decay's block: a [1, tokens, G d] stream (per key channel) as
+    [G N, C, d], or a [1, G, N, 1, C] row a chunk (one a head) as
+    [G N, 1, C]."""
+    return (_stacked(ref) if len(ref.shape) == 5
+            else _heads_in(ref, tokens, heads))
 
 
 def _by_chunk(x, heads):
@@ -377,7 +443,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, tokens,
     mm = q.dtype
     s = _set_up(q, _heads_in(k_ref, tokens, heads),
                 _heads_in(v_ref, tokens, heads),
-                _heads_in(g_ref, tokens, heads), _stacked(beta_ref))
+                _decay_in(g_ref, tokens, heads), _stacked(beta_ref))
     uv, w, k_out_t, decay = (_by_chunk(s[name], heads) for name in (
         "uv", "w", "k_out_t", "decay_column"))
     # the one part that waits for the state: two products a chunk, the
@@ -432,8 +498,9 @@ def _specs(tokens, d, heads, at):
 
 
 def _forward(q, k, v, g, beta, *, tokens, together, keep, interpret):
-    """q, k, v, g [B, T, H d] (T whole blocks), beta [B, H, T / C, 1, C];
-    ``together`` heads a program."""
+    """q, k, v [B, T, H d] (T whole blocks), beta [B, H, T / C, 1, C], g
+    like q (per key channel) or like beta (a head); ``together`` heads a
+    program."""
     (b, t, hd), heads = q.shape, beta.shape[1]
     d = hd // heads
     stream, row, states, pair, inv = _specs(tokens, d, together, lambda n: n)
@@ -449,7 +516,8 @@ def _forward(q, k, v, g, beta, *, tokens, together, keep, interpret):
         functools.partial(_fwd_kernel, tokens=tokens, heads=together,
                           keep=keep),
         grid=(b, heads // together, t // tokens),
-        in_specs=[stream, stream, stream, stream, row],
+        in_specs=[stream, stream, stream, row if g.ndim == 5 else stream,
+                  row],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((together, d, d), _F32)],
         interpret=interpret, name="kda_chunk_fwd",
@@ -520,6 +588,23 @@ def _pair_terms_bwd(qf, kf, cum, d_kk, d_qk):
             dcum + jnp.concatenate(none + rows_g, axis=1))
 
 
+def _scalar_pair_terms_bwd(qf, kf, cum, a_kk, a_qk, d_kk, d_qk):
+    """The factored pair terms' gradient: (dq, dk [N, C, d], dG [N, C, 1])
+    float32 from the cotangents of A_kk (zero unless i < r) and A_qk (zero
+    unless i <= r). With E = exp(G_r - G_i): the products' cotangents are
+    the terms' times E; the decay's is each term times its cotangent,
+    summed along its row at r and taken off along its column at i."""
+    decay = _scalar_decay_mask(cum)
+    c_k, c_q = d_kk * decay, d_qk * decay
+    dq = _bmm(c_q, kf, _NN, _HIGHEST)
+    dk = (_bmm(c_k, kf, _NN, _HIGHEST) + _bmm(c_k, kf, _TN, _HIGHEST)
+          + _bmm(c_q, qf, _TN, _HIGHEST))
+    moved = d_kk * a_kk + d_qk * a_qk
+    dcum = (jnp.sum(moved, axis=2, keepdims=True)
+            - _as_column(jnp.sum(moved, axis=1, keepdims=True)))
+    return dq, dk, dcum
+
+
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, pair_ref,
                 inv_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
                 ds_ref, *, tokens, heads):
@@ -535,8 +620,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, pair_ref,
     pair = pair_ref[0].reshape(heads * n, CHUNK, 2 * CHUNK)
     x = jnp.stack([inv_ref[(0, slice(None)) + _inv_at(c)] for c in range(n)],
                   axis=1).reshape(heads * n, CHUNK, CHUNK)
-    s = _set_up(q, k, v, _heads_in(g_ref, tokens, heads), beta,
+    s = _set_up(q, k, v, _decay_in(g_ref, tokens, heads), beta,
                 (pair[:, :, :CHUNK], pair[:, :, CHUNK:], x))
+    scalar = len(g_ref.shape) == 5
     a_kk, a_qk = s["a_kk"], s["a_qk"]
     r, i = _grid_masks()
     s0 = _stacked(s0_ref)                                    # [G N, d_k, d_v]
@@ -573,23 +659,41 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, pair_ref,
                                 _HIGHEST), 0.0)
     dbeta = dbeta + _as_row(jnp.sum(dn * a_kk, axis=2, keepdims=True))
     qf, kf, cum = s["qf"], s["kf"], s["cum"]
-    dq, dk, dcum = _pair_terms_bwd(qf, kf, cum, _as_column(beta) * dn, d_qk)
+    d_kk = _as_column(beta) * dn
     grown, shrunk = s["grown"], s["shrunk"]
     k_out_f = kf * shrunk
+    # what the chunk's leaving state carries of the entering one, a key
+    # channel: its decay's cotangent before the decay itself
+    carried = jnp.sum(ds1 * s0, axis=2, keepdims=True)       # [G N, d_k, 1]
+    if scalar:
+        dq, dk, dcum = _scalar_pair_terms_bwd(qf, kf, cum, a_kk, a_qk, d_kk,
+                                              d_qk)
+        dcum = dcum + jnp.sum((dkg * kf + dqg * qf) * grown
+                              - dk_out * k_out_f, axis=2, keepdims=True)
+        dlast = jnp.sum(carried, axis=1, keepdims=True) * s["decay_head"]
+        dlast = dlast + jnp.sum(jnp.sum(dk_out * k_out_f, axis=2,
+                                        keepdims=True), axis=1, keepdims=True)
+    else:
+        dq, dk, dcum = _pair_terms_bwd(qf, kf, cum, d_kk, d_qk)
+        dcum = dcum + (dkg * kf + dqg * qf) * grown - dk_out * k_out_f
+        # the chunk's whole decay: a column over the key channels, made a
+        # row
+        dlast = jnp.sum(jnp.where(_eye(kf.shape[-1]), carried, 0.0),
+                        axis=1, keepdims=True) * s["decay"]
+        dlast = dlast + jnp.sum(dk_out * k_out_f, axis=1, keepdims=True)
     dq = dq + dqg * grown
     dk = dk + dkg * grown + dk_out * shrunk
-    dcum = dcum + (dkg * kf + dqg * qf) * grown - dk_out * k_out_f
-    # the chunk's whole decay: a column over the key channels, made a row
-    dlast = jnp.sum(jnp.where(_eye(kf.shape[-1]),
-                              jnp.sum(ds1 * s0, axis=2, keepdims=True), 0.0),
-                    axis=1, keepdims=True) * s["decay"]
-    dlast = dlast + jnp.sum(dk_out * k_out_f, axis=1, keepdims=True)
     dcum = dcum + jnp.where(_iota(dcum.shape[1:], 0) == CHUNK - 1, dlast, 0.0)
-    dg = _ones_product(i >= r, dcum)
     _heads_out(dq_ref, dq, tokens, heads)
     _heads_out(dk_ref, dk, tokens, heads)
     _heads_out(dv_ref, dv, tokens, heads)
-    _heads_out(dg_ref, dg, tokens, heads)
+    if scalar:
+        # every later token of the chunk carries this token's decay: the
+        # column of sums [G N, C, 1] into a row a chunk, as it came
+        dg_ref[0] = jnp.sum(jnp.where(r >= i, dcum, 0.0), axis=1,
+                            keepdims=True).reshape(heads, n, 1, CHUNK)
+    else:
+        _heads_out(dg_ref, _ones_product(i >= r, dcum), tokens, heads)
     dbeta_ref[0] = dbeta.reshape(heads, n, 1, CHUNK)
 
 
@@ -600,13 +704,14 @@ def _backward(q, k, v, g, beta, s0, pair, inv, do, *, tokens, together,
     blocks = t // tokens
     stream, row, states, pair_spec, inv_spec = _specs(
         tokens, d, together, lambda n: blocks - 1 - n)
+    decay = row if g.ndim == 5 else stream
     like = jax.ShapeDtypeStruct
     return pl.pallas_call(
         functools.partial(_bwd_kernel, tokens=tokens, heads=together),
         grid=(b, heads // together, blocks),
-        in_specs=[stream, stream, stream, stream, row, states, pair_spec,
+        in_specs=[stream, stream, stream, decay, row, states, pair_spec,
                   inv_spec, stream],
-        out_specs=[stream, stream, stream, stream, row],
+        out_specs=[stream, stream, stream, decay, row],
         out_shape=[like(q.shape, q.dtype), like(k.shape, k.dtype),
                    like(v.shape, v.dtype), like(g.shape, _F32),
                    like(beta.shape, _F32)],
@@ -638,8 +743,8 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 
 def _blocked(q, k, v, g, beta, tokens):
     """The layer's [B, T, H, d] arrays as [B, T, H d] and its [B, T, H]
-    beta a row a chunk, the row padded to whole blocks with tokens that
-    write nothing and decay nothing."""
+    beta (and a decay a head) a row a chunk, the row padded to whole blocks
+    with tokens that write nothing and decay nothing."""
     b, t, h, d = q.shape
     pad = -t % tokens
 
@@ -647,11 +752,13 @@ def _blocked(q, k, v, g, beta, tokens):
         x = x.astype(dtype).reshape(b, t, h * d)
         return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
-    beta = jnp.pad(beta.astype(_F32), ((0, 0), (0, pad), (0, 0)))
-    beta = jnp.moveaxis(beta, 1, 2).reshape(b, h, (t + pad) // CHUNK, 1,
-                                            CHUNK)
+    def rows(x):
+        x = jnp.pad(x.astype(_F32), ((0, 0), (0, pad), (0, 0)))
+        return jnp.moveaxis(x, 1, 2).reshape(b, h, (t + pad) // CHUNK, 1,
+                                             CHUNK)
+
     return (stream(q, q.dtype), stream(k, q.dtype), stream(v, q.dtype),
-            stream(g, _F32), beta)
+            rows(g) if g.ndim == 3 else stream(g, _F32), rows(beta))
 
 
 def _block_tokens(seq, tokens):
@@ -669,8 +776,9 @@ def heads_together(heads, d, want=None):
 
 
 def kda(q, k, v, g, beta, *, tokens=None, together=None, interpret=False):
-    """The gated delta rule from a zero state: q, k, g [B, T, H, d], v [B,
-    T, H, d], beta [B, T, H] -> o [B, T, H, d] in v's dtype (the final state
+    """The gated delta rule from a zero state: q, k [B, T, H, d], g [B, T,
+    H, d] (a decay per key channel) or [B, T, H] (one a head), v [B, T, H,
+    d], beta [B, T, H] -> o [B, T, H, d] in v's dtype (the final state
     stays inside). k and v take q's dtype, the decay and beta are float32;
     differentiable in all five. ``tokens``: what a program takes of a row
     (whole pairs of chunks; ``TOKENS``), ``together``: of how many heads

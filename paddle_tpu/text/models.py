@@ -212,15 +212,22 @@ def rms_norm(x, w, *, eps=1e-6):
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * w
 
 
-def _rope(x, base=10000.0, positions=None, pairing="interleaved"):
+def _rope(x, base=10000.0, positions=None, pairing="interleaved",
+          rotary_dim=None):
     """Rotary embedding. x: [B, H, T, D]; positions: [T] absolute positions
     (defaults to 0..T-1). Shared with generation.py's cached decode.
     ``pairing``: which features rotate together — ``interleaved`` pairs
     (2i, 2i+1) (the Llama block here), ``half`` pairs (i, i + D/2) (the
     rotate-half form of the HF sources; OLMoE). The two differ by a fixed
-    permutation of the columns of the q and k projections."""
+    permutation of the columns of the q and k projections. ``rotary_dim``:
+    only the first that many features rotate, among themselves (a partial
+    rotary factor: Qwen3-Next turns 64 of 256); the rest pass."""
     import jax.numpy as jnp
 
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [_rope(x[..., :rotary_dim], base, positions, pairing),
+             x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     t = x.shape[-2]
     if positions is None:
@@ -1082,3 +1089,437 @@ def kimi_layer_types(linear_attn_config, num_hidden_layers):
                              "kda_layers and full_attn_layers")
         types.append("kda" if i in kda else "mla")
     return types
+
+
+# ------------------------------------------------------------ Qwen3-Next
+class ZeroCenteredRMSNorm(nn.Layer):
+    """RMSNorm in float32 whose weight is stored about zero: ``x rsqrt(mean
+    x^2 + eps) (1 + w)``, ``w`` from 0 (Qwen3-Next's block, final and QK
+    norms; weight decay then pulls the scale to 1, not to 0 — and the
+    configurations that train it take it off weight decay all the same). With
+    ``zero_centered=False`` the same float32 arithmetic times ``w`` from 1.
+    The result takes x's dtype. The weight is named ``<layer>.norm_weight``
+    so that an optimizer's ``apply_decay_param_fun`` can tell it."""
+
+    def __init__(self, hidden_size, eps=1e-6, zero_centered=True):
+        super().__init__()
+        from ..framework.param_attr import ParamAttr
+
+        self.eps, self.zero_centered = float(eps), bool(zero_centered)
+        self.weight = self.create_parameter([hidden_size], attr=ParamAttr(
+            name=f"{self.full_name()}.norm_weight",
+            initializer=nn.initializer.Constant(
+                0.0 if zero_centered else 1.0)))
+
+    def forward(self, x):
+        from ..core.dispatch import apply_op
+
+        return apply_op("rms_norm_f32", _rms_norm_f32, x, self.weight,
+                        eps=self.eps, zero_centered=self.zero_centered)
+
+
+def _rms_norm_f32(x, w, *, eps, zero_centered):
+    import jax
+    import jax.numpy as jnp
+
+    xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
+    scale = 1.0 + wf if zero_centered else wf
+    return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                               + eps) * scale).astype(x.dtype)
+
+
+# A Gated DeltaNet layer's element-wise stages, each a ``jax.checkpoint`` of
+# its own for KimiDeltaAttention's reason: a backward pass keeps the stage's
+# bf16 inputs and rebuilds the float32 inside it.
+def _gdn_split(qkvz, ba, *, key_heads, d_k, d_v, per_key):
+    """The two fused projections, laid out a key head at a time as [q d_k |
+    k d_k | v per_key x d_v | z per_key x d_v] and [b per_key | a per_key]
+    -> (q | k | v over all heads as one [B, T, channels] stream for the
+    convolution, z [B, T, H_v x d_v], b and a [B, T, H_v])."""
+    import jax.numpy as jnp
+
+    lead = qkvz.shape[:-1]
+    x = qkvz.reshape(*lead, key_heads, 2 * d_k + 2 * per_key * d_v)
+    cuts = (d_k, 2 * d_k, 2 * d_k + per_key * d_v)
+    q, k, v, z = (part.reshape(*lead, -1)
+                  for part in jnp.split(x, cuts, axis=-1))
+    y = ba.reshape(*lead, key_heads, 2 * per_key)
+    return (jnp.concatenate([q, k, v], axis=-1), z,
+            y[..., :per_key].reshape(*lead, -1),
+            y[..., per_key:].reshape(*lead, -1))
+
+
+def _gdn_streams(mixed, w, *, key_heads, d_k, d_v, eps):
+    """The q | k | v stream through ONE causal depthwise convolution and
+    SiLU -> q, k [B, T, H_k, d_k] L2-normalised a head (in float32), q
+    scaled by d_k^-0.5, and v [B, T, H_v, d_v]."""
+    import jax
+    import jax.numpy as jnp
+
+    def streams(mixed, w):
+        x = F._causal_depthwise_conv1d(mixed, w, activation="silu")
+        key = key_heads * d_k
+        lead = x.shape[:-1]
+
+        def l2(x, scale):
+            xf = x.reshape(*lead, key_heads, d_k).astype(jnp.float32)
+            return (xf * (jax.lax.rsqrt(
+                jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
+                * scale)).astype(x.dtype)
+
+        return (l2(x[..., :key], d_k ** -0.5), l2(x[..., key:2 * key], 1.0),
+                x[..., 2 * key:].reshape(*lead, -1, d_v))
+
+    return jax.checkpoint(streams)(mixed, w)
+
+
+def _gdn_decay(a, a_log, dt_bias):
+    """g = -exp(A_log) softplus(a + dt_bias) in float32: [B, T, H_v], ONE
+    decay's logarithm a value head and token."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    return -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+        a.astype(f32) + dt_bias.astype(f32))
+
+
+def _gdn_beta(b):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.nn.sigmoid(b.astype(jnp.float32))
+
+
+def _repeat_heads(x, *, repeats, axis):
+    """Each head ``repeats`` times, neighbours: head h serves heads
+    h * repeats .. of the wider side."""
+    import jax.numpy as jnp
+
+    return x if repeats == 1 else jnp.repeat(x, repeats, axis=axis)
+
+
+def _gdn_gated_norm(o, z, w, *, eps):
+    """``w * RMSNorm_d(o) * silu(z)`` a head, in float32 (w from 1: this
+    norm is not zero-centred); [B, T, H, d] -> [B, T, H * d] in o's dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    def gated(o, z, w):
+        of = o.astype(jnp.float32)
+        normed = of * jax.lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True)
+                                    + eps) * w.astype(jnp.float32)
+        return (normed.reshape(z.shape) * jax.nn.silu(
+            z.astype(jnp.float32))).astype(o.dtype)
+
+    return jax.checkpoint(gated)(o, z, w)
+
+
+class GatedDeltaNet(nn.Layer):
+    """Gated DeltaNet as Qwen3-Next has it (HF ``qwen3_next``; Yang et al.,
+    arXiv:2412.06464): ``num_k_heads`` key heads serve ``num_v_heads`` value
+    heads (each q / k head ``num_v_heads / num_k_heads`` neighbours). One
+    fused projection gives q | k | v | z and one b | a, both laid out a key
+    head at a time; q | k | v pass ONE causal depthwise convolution of
+    ``conv_kernel_size`` taps and SiLU; q and k are L2-normalised a head;
+    ``beta = sigmoid(b)`` and ONE decay a value head and token, ``g =
+    -exp(A_log) softplus(a + dt_bias)``; the gated delta rule's state
+    recurrence (``ops.linear_attention`` with its ``[B, T, H]`` decay); and
+    ``out_proj(w * RMSNorm_d(o) * silu(z))``. The decay, beta, the scan's
+    state and the output norm are float32 under amp O1; the projections and
+    the scan's large products take bf16 operands. Scopes: ``gdn.proj`` /
+    ``.conv`` / ``.gate`` / ``.repeat`` (q and k written once a value head:
+    what an index map in the scan's kernels would save) / ``.core`` /
+    ``.out``."""
+
+    def __init__(self, hidden_size, num_k_heads=16, num_v_heads=32,
+                 head_k_dim=128, head_v_dim=128, conv_kernel_size=4,
+                 chunk=64, rms_norm_eps=1e-6, l2_eps=1e-6, weight_attr=None):
+        super().__init__()
+        if num_v_heads % num_k_heads:
+            raise ValueError(f"{num_v_heads} value heads are no multiple of "
+                             f"{num_k_heads} key heads")
+        self.num_k_heads, self.num_v_heads = num_k_heads, num_v_heads
+        self.head_k_dim, self.head_v_dim = head_k_dim, head_v_dim
+        self.chunk, self.l2_eps = int(chunk), float(l2_eps)
+        key, value = num_k_heads * head_k_dim, num_v_heads * head_v_dim
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.in_proj_qkvz = proj(hidden_size, 2 * key + 2 * value)
+        self.in_proj_ba = proj(hidden_size, 2 * num_v_heads)
+        self.conv1d = nn.CausalDepthwiseConv1D(2 * key + value,
+                                               conv_kernel_size,
+                                               activation="silu")
+        # A = exp(A_log) ~ U(1, 16) and dt_bias the inverse softplus of
+        # dt ~ exp(U(log 1e-3, log 1e-1)), one a value head (the Mamba-2
+        # convention, as KimiDeltaAttention's); named for an optimizer's
+        # apply_decay_param_fun
+        from ..framework.param_attr import ParamAttr
+
+        def named(name, low, high):
+            return ParamAttr(name=f"{self.full_name()}.{name}",
+                             initializer=nn.initializer.Uniform(low, high))
+
+        self.A_log = self.create_parameter(
+            [num_v_heads], attr=named("A_log", 1.0, 16.0))
+        self.A_log.set_value(np.log(np.asarray(self.A_log._value)))
+        self.dt_bias = self.create_parameter(
+            [num_v_heads], attr=named("dt_bias", math.log(1e-3),
+                                      math.log(1e-1)))
+        dt = np.exp(np.asarray(self.dt_bias._value, np.float64))
+        self.dt_bias.set_value(dt + np.log(-np.expm1(-dt)))
+        self.norm = ZeroCenteredRMSNorm(head_v_dim, eps=rms_norm_eps,
+                                        zero_centered=False)
+        self.out_proj = proj(value, hidden_size)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops.linear_attention import gated_delta_rule
+
+        per_key = self.num_v_heads // self.num_k_heads
+        with jax.named_scope("gdn.proj"):
+            mixed, z, b, a = apply_op(
+                "gdn_split", _gdn_split, self.in_proj_qkvz(x),
+                self.in_proj_ba(x), key_heads=self.num_k_heads,
+                d_k=self.head_k_dim, d_v=self.head_v_dim, per_key=per_key)
+        with jax.named_scope("gdn.conv"):
+            q, k, v = apply_op(
+                "gdn_streams", _gdn_streams, mixed, self.conv1d.weight,
+                key_heads=self.num_k_heads, d_k=self.head_k_dim,
+                d_v=self.head_v_dim, eps=self.l2_eps)
+        with jax.named_scope("gdn.gate"):
+            g = apply_op("gdn_decay", _gdn_decay, a, self.A_log,
+                         self.dt_bias)
+            beta = apply_op("gdn_beta", _gdn_beta, b)
+        with jax.named_scope("gdn.repeat"):
+            q, k = (apply_op("repeat_heads", _repeat_heads, t,
+                             repeats=per_key, axis=2) for t in (q, k))
+        with jax.named_scope("gdn.core"):
+            o = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
+        with jax.named_scope("gdn.out"):
+            return self.out_proj(apply_op(
+                "gdn_gated_norm", _gdn_gated_norm, o, z, self.norm.weight,
+                eps=self.norm.eps))
+
+
+def _gqa_heads(q, k, v, w_q, w_k, *, heads, kv_heads, d, eps, base,
+               rotary_dim):
+    """The projected streams -> (query, key, value [B, H, T, d] — key and
+    value on their own ``kv_heads`` — and the gate [B, T, heads x d]): the
+    query projection is laid out a head at a time as [query d | gate d];
+    query and key pass a zero-centred RMSNorm over a head's d features and
+    rotate-half RoPE on the first ``rotary_dim`` of them, in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    def split(q, k, v, w_q, w_k):
+        b, t, _ = q.shape
+        q = q.reshape(b, t, heads, 2 * d)
+        query, gate = q[..., :d], q[..., d:].reshape(b, t, heads * d)
+
+        def normed(x, w, n):
+            x = _rms_norm_f32(x.reshape(b, t, n, d).astype(jnp.float32), w,
+                              eps=eps, zero_centered=True)
+            return _rope(x.transpose(0, 2, 1, 3), base, pairing="half",
+                         rotary_dim=rotary_dim).astype(q.dtype)
+
+        return (normed(query, w_q, heads), normed(k, w_k, kv_heads),
+                v.reshape(b, t, kv_heads, d).transpose(0, 2, 1, 3), gate)
+
+    return jax.checkpoint(split)(q, k, v, w_q, w_k)
+
+
+def _gqa_gated_merge(o, gate):
+    """[B, H, T, d] -> [B, T, H x d], times sigmoid(gate)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, h, t, d = o.shape
+    merged = o.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+    return (merged.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32))).astype(o.dtype)
+
+
+class GatedGQAttention(nn.Layer):
+    """Qwen3-Next's full-attention mixer: grouped-query attention whose
+    head width is its own (``head_dim``, not hidden / heads), the query
+    projection twice as wide and split a head into [query | gate], a
+    zero-centred RMSNorm over each head's features of q and of k,
+    rotate-half RoPE on the first ``partial_rotary_factor`` of them, causal
+    softmax attention at ``head_dim ** -0.5`` with query head h on
+    key/value head ``h // (heads / kv_heads)``, the core's output times
+    ``sigmoid(gate)``, then ``o_proj``; no bias anywhere. The core goes
+    through the dispatching sdpa (the streaming flash kernel at long
+    sequences), K and V REPEATED to the query heads first, under the scope
+    ``gqa.repeat`` (ROADMAP Speed 13: the kernel takes one head count).
+    Scopes: ``gqa.proj`` / ``.repeat`` / ``.core`` / ``.out``."""
+
+    def __init__(self, hidden_size, num_heads=16, num_kv_heads=2,
+                 head_dim=256, partial_rotary_factor=0.25, rope_theta=1e7,
+                 rms_norm_eps=1e-6, weight_attr=None):
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads are no multiple of "
+                             f"{num_kv_heads} key/value heads")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.rope_theta = head_dim, float(rope_theta)
+        self.rotary_dim = int(head_dim * partial_rotary_factor)
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.q_proj = proj(hidden_size, 2 * num_heads * head_dim)
+        self.k_proj = proj(hidden_size, num_kv_heads * head_dim)
+        self.v_proj = proj(hidden_size, num_kv_heads * head_dim)
+        self.o_proj = proj(num_heads * head_dim, hidden_size)
+        self.q_norm = ZeroCenteredRMSNorm(head_dim, eps=rms_norm_eps)
+        self.k_norm = ZeroCenteredRMSNorm(head_dim, eps=rms_norm_eps)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops.attention import scaled_dot_product_attention as _sdpa
+
+        with jax.named_scope("gqa.proj"):
+            q, k, v, gate = apply_op(
+                "gqa_heads", _gqa_heads, self.q_proj(x), self.k_proj(x),
+                self.v_proj(x), self.q_norm.weight, self.k_norm.weight,
+                heads=self.num_heads, kv_heads=self.num_kv_heads,
+                d=self.head_dim, eps=self.q_norm.eps, base=self.rope_theta,
+                rotary_dim=self.rotary_dim)
+        with jax.named_scope("gqa.repeat"):
+            k, v = (apply_op("repeat_heads", _repeat_heads, t,
+                             repeats=self.num_heads // self.num_kv_heads,
+                             axis=1) for t in (k, v))
+        with jax.named_scope("gqa.core"):
+            o = _sdpa(q, k, v, is_causal=True, training=self.training)
+        with jax.named_scope("gqa.out"):
+            return self.o_proj(apply_op("gqa_gated_merge", _gqa_gated_merge,
+                                        o, gate))
+
+
+class Qwen3NextDecoderLayer(nn.Layer):
+    """Pre-norm block of Qwen3-Next: a token mixer by layer type — Gated
+    DeltaNet (``'linear_attention'``) or gated grouped-query attention
+    (``'full_attention'``) — then the expert layer: a softmax router over
+    all experts, top-k renormalised, a shared expert behind a sigmoid gate
+    of its own, and the held range of the routed experts. Both norms are
+    zero-centred and float32."""
+
+    def __init__(self, cfg, mixer, weight_attr=None):
+        super().__init__()
+        from ..incubate.moe import MoELayer
+
+        hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+        self.input_layernorm = ZeroCenteredRMSNorm(hidden, eps=eps)
+        if mixer == "linear_attention":
+            self.linear_attn = GatedDeltaNet(
+                hidden, rms_norm_eps=eps, weight_attr=weight_attr,
+                **cfg["gdn"])
+        else:
+            self.self_attn = GatedGQAttention(
+                hidden, rms_norm_eps=eps, weight_attr=weight_attr,
+                **cfg["gqa"])
+        self.mixer = mixer
+        self.post_attention_layernorm = ZeroCenteredRMSNorm(hidden, eps=eps)
+        self.mlp = MoELayer(
+            hidden, cfg["moe_intermediate_size"], cfg["num_experts"],
+            top_k=cfg["num_experts_per_tok"], activation="swiglu",
+            gate_bias=False, norm_topk_prob=cfg["norm_topk_prob"],
+            shared_width=cfg["shared_expert_intermediate_size"],
+            shared_gate=True, held=cfg["held_experts"],
+            held_rows_factor=cfg["held_rows_factor"],
+            aux_weight=cfg["router_aux_loss_coef"], weight_attr=weight_attr)
+
+    def forward(self, x):
+        mix = (self.linear_attn if self.mixer == "linear_attention"
+               else self.self_attn)
+        x = x + mix(self.input_layernorm(x))
+        return x + self.mlp(self.post_attention_layernorm(x))
+
+
+class Qwen3NextModel(_BlockwiseModel):
+    """Qwen3-Next-80B-A3B (HF ``qwen3_next``): pre-norm blocks whose mixer
+    goes by layer type — gated grouped-query attention on every
+    ``full_attention_interval``-th layer, Gated DeltaNet on the others —
+    each followed by the expert layer (``decoder_sparse_step`` 1, no dense
+    layer); a zero-centred final norm and an untied head; no MTP module
+    (the published checkpoint's is dropped by the HF model). Defaults are
+    the published sizes.
+
+    ``held_experts=(first, count)`` gives every expert layer this chip's
+    range of the routed experts; ``use_recompute`` runs each block under
+    ``fleet.utils.recompute`` in a traced step. ``forward`` gives the
+    logits; a training loss takes ``features`` and ``lm_head.weight`` to
+    ``F.linear_cross_entropy``."""
+
+    def __init__(self, vocab_size=151936, hidden_size=2048,
+                 num_hidden_layers=48, num_attention_heads=16,
+                 num_key_value_heads=2, head_dim=256,
+                 partial_rotary_factor=0.25, rope_theta=1e7,
+                 full_attention_interval=4, linear_num_key_heads=16,
+                 linear_num_value_heads=32, linear_key_head_dim=128,
+                 linear_value_head_dim=128, linear_conv_kernel_dim=4,
+                 moe_intermediate_size=512,
+                 shared_expert_intermediate_size=512, num_experts=512,
+                 num_experts_per_tok=10, norm_topk_prob=True,
+                 router_aux_loss_coef=0.001, rms_norm_eps=1e-6,
+                 gdn_chunk=64, initializer_range=0.02, held_experts=None,
+                 held_rows_factor=2.0, use_recompute=False):
+        super().__init__(use_recompute)
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        cfg = dict(
+            hidden_size=hidden_size, rms_norm_eps=rms_norm_eps,
+            moe_intermediate_size=moe_intermediate_size,
+            shared_expert_intermediate_size=shared_expert_intermediate_size,
+            num_experts=num_experts, num_experts_per_tok=num_experts_per_tok,
+            norm_topk_prob=norm_topk_prob,
+            router_aux_loss_coef=router_aux_loss_coef,
+            held_experts=None if held_experts is None else tuple(held_experts),
+            held_rows_factor=held_rows_factor,
+            gdn=dict(num_k_heads=linear_num_key_heads,
+                     num_v_heads=linear_num_value_heads,
+                     head_k_dim=linear_key_head_dim,
+                     head_v_dim=linear_value_head_dim,
+                     conv_kernel_size=linear_conv_kernel_dim,
+                     chunk=gdn_chunk),
+            gqa=dict(num_heads=num_attention_heads,
+                     num_kv_heads=num_key_value_heads, head_dim=head_dim,
+                     partial_rotary_factor=partial_rotary_factor,
+                     rope_theta=rope_theta))
+        self.layer_types = qwen3_next_layer_types(num_hidden_layers,
+                                                  full_attention_interval)
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            Qwen3NextDecoderLayer(cfg, mixer, weight_attr=attr())
+            for mixer in self.layer_types])
+        self.norm = ZeroCenteredRMSNorm(hidden_size, eps=rms_norm_eps)
+        self.lm_head = nn.Linear(hidden_size, vocab_size,
+                                 weight_attr=attr(), bias_attr=False)
+
+    def features(self, input_ids):
+        x = self.embed_tokens(input_ids)
+        for layer in self.layers:
+            x = self._block(layer, x)
+        return self.norm(x)
+
+    def forward(self, input_ids):
+        return self.lm_head(self.features(input_ids))
+
+
+def qwen3_next_layer_types(num_hidden_layers, full_attention_interval=4):
+    """Layer i (from 0) is ``full_attention`` where (i + 1) is a multiple of
+    the interval, else ``linear_attention`` (HF ``layer_types``' default)."""
+    return ["full_attention" if (i + 1) % full_attention_interval == 0
+            else "linear_attention" for i in range(num_hidden_layers)]

@@ -465,3 +465,122 @@ def test_bf16_input_convolves_in_float32_and_returns_bf16():
     np.testing.assert_allclose(
         np.asarray(out._value.astype(jnp.float32))[0, :, 0],
         [1 / 3, 2 / 3, 1.0, 4 / 3, 4 / 3], rtol=1e-2)
+
+
+# ------------------------------------------------- one decay a head (GDN)
+# Gated DeltaNet's decay is one number a head and token, [B, T, H]: every
+# path takes it as it is (its pair terms factor), and each is held to the
+# recurrence, to the others, and to the per-channel path fed the same decay
+# broadcast over the key channels — outputs and all five gradients, the
+# decay's summed over the channels it was broadcast to.
+def scalar_inputs(seed, batch, seq, strong=False, dtype=jnp.float32, **kw):
+    q, k, v, g, beta = inputs(seed, batch, seq, strong, dtype, **kw)
+    return q, k, v, g[..., 0], beta
+
+
+def broadcast(args):
+    q, k, v, g, beta = args
+    return q, k, v, jnp.broadcast_to(g[..., None], k.shape), beta
+
+
+@pytest.mark.parametrize("seq, chunk, segment, strong, rtol", [
+    (100, 64, 2048, False, 2e-6),
+    (300, 64, 128, False, 2e-6),
+    (70, 16, 32, False, 2e-6),
+    (300, 64, 128, True, 1e-4),
+])
+def test_scalar_decay_chunked_scan_is_the_recurrence(seq, chunk, segment,
+                                                     strong, rtol):
+    args = scalar_inputs(0, 2, seq, strong)
+    want_o, want_s = la.kda_recurrent(*args)
+    # the recurrence itself takes either form of the decay
+    wide_o, wide_s = la.kda_recurrent(*broadcast(args))
+    close(want_o, wide_o, 1e-6)
+    close(want_s, wide_s, 1e-6)
+    got_o, got_s = jax.jit(lambda *a: la.kda_chunked(
+        *a, chunk=chunk, segment=segment))(*args)
+    assert got_o.shape == want_o.shape == (2, seq, HEADS, D_V)
+    close(got_o, want_o, rtol)
+    close(got_s, want_s, rtol)
+
+
+@pytest.mark.parametrize("path", ["recurrent", "chunked"])
+def test_scalar_decay_gradients_are_the_per_channel_paths(path):
+    fn = {"recurrent": lambda *a: la.kda_recurrent(*a)[0],
+          "chunked": lambda *a: la.kda_chunked(*a, chunk=16, segment=64)[0]}
+    args = scalar_inputs(3, 2, 150)
+    value, got = weighted(fn[path])(*args)
+    want_value, want = weighted(fn["recurrent"])(*broadcast(args))
+    assert float(value) == pytest.approx(float(want_value), rel=2e-5)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        b = b.sum(-1) if name == "g" else b
+        assert a.shape == b.shape and float(jnp.abs(b).max()) > 0, name
+        close(a, b, 2e-5)
+
+
+@pytest.mark.parametrize("seq, tokens, together, strong, against, rtol", [
+    (200, 128, 2, False, "recurrent", 2e-5),   # two programs a row, ragged
+    (64, None, 2, False, "recurrent", 2e-5),   # the shortest row routed here
+    (200, 128, 2, True, "recurrent", 5e-4),    # exp(-G) overflows in a chunk
+    (200, 256, 1, False, "chunked", 2e-5),     # a head a program
+    (200, 128, 2, False, "per-channel kernel", 2e-5),
+])
+def test_the_kernels_take_a_decay_a_head_forward_and_backward(
+        seq, tokens, together, strong, against, rtol):
+    args = scalar_inputs(7, 1, seq, strong, heads=2, d_k=128, d_v=128)
+
+    def scan(*a):
+        return kernel_scan(*a, tokens=tokens, together=together)
+
+    other, theirs = {
+        "recurrent": (lambda *a: la.kda_recurrent(*a)[0], args),
+        "chunked": (lambda *a: la.kda_chunked(*a)[0], args),
+        "per-channel kernel": (scan, broadcast(args))}[against]
+    want_o = other(*theirs)
+    got_o = jax.jit(scan)(*args)
+    assert got_o.shape == want_o.shape and got_o.dtype == want_o.dtype
+    close(got_o, want_o, rtol / 10 if not strong else rtol / 5)
+    _, want = weighted(other)(*theirs)
+    _, got = weighted(scan)(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        if name == "g" and b.ndim == 4:
+            b = b.sum(-1)
+        # dg leaves as [B, T, H]: no [.., d_k] decay on the way back either
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert float(jnp.abs(b).max()) > 0, name
+        close(a, b, rtol)
+
+
+def test_the_scalar_kernels_take_bf16_operands():
+    """bf16 q, k, v, float32 decay a head and beta, as amp O1 hands them
+    over: the factored pair terms are bf16 products summed in float32, and
+    the outputs stay as near the float32 recurrence as the chunked scan's
+    do."""
+    args = scalar_inputs(9, 1, 200, dtype=jnp.bfloat16, heads=2, d_k=128,
+                         d_v=128)
+    exact = la.kda_recurrent(*(a.astype(jnp.float32) for a in args))[0]
+    got = jax.jit(kernel_scan)(*args)
+    assert got.dtype == jnp.bfloat16
+    close(got, exact, 2e-2)
+    _, grads = weighted(kernel_scan)(*args)
+    assert [g.dtype for g in grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+    assert grads[3].shape == args[3].shape
+
+
+@pytest.mark.parametrize("seq, d, path", [(150, 128, "kernel_scalar"),
+                                          (150, 32, "chunked_scalar"),
+                                          (12, 128, "recurrent_scalar")])
+def test_the_entry_point_counts_the_decay_with_the_path(interpreter, seq, d,
+                                                        path):
+    """``gated_delta_rule`` with a [B, T, H] decay: the same route, counted
+    under ``<path>_scalar``, so that the per-channel counts stay what they
+    were."""
+    names = [p + s for p in ("kernel", "chunked", "recurrent")
+             for s in ("", "_scalar")]
+    before = {p: la._CORE_TOTAL.value(path=p) for p in names}
+    args = scalar_inputs(10, 1, seq, heads=2, d_k=d, d_v=d)
+    out = la.gated_delta_rule(*(paddle.to_tensor(np.asarray(a))
+                                for a in args))
+    for p, n in before.items():
+        assert la._CORE_TOTAL.value(path=p) == n + (p == path), p
+    close(out._value, la.kda_recurrent(*args)[0], 2e-6)

@@ -56,6 +56,10 @@ STREAM_CASES = {
     "olmoe-check-f32": (1, 16, 4096, 128, "float32"),
     "d256-bf16": (2, 8, 8192, 256, "bfloat16"),
     "d64-bf16": (2, 12, 2048, 64, "bfloat16"),
+    # gated grouped-query attention at the Qwen3-Next cell: 16 query heads
+    # (K and V repeated to them) of 256 at 16,384 keys — 512-token blocks,
+    # and the longest row of dq the one-pass backward admits (32 of 40 MiB)
+    "qwen3-next-cell-bf16": (1, 16, 16384, 256, "bfloat16"),
     # latent attention's core at the JoyAI cell: keys (192: not a multiple
     # of the 128 lanes) wider than values (128); in float32 for its check
     "joyai-cell-bf16": (1, 32, 8192, (192, 128), "bfloat16"),
@@ -96,6 +100,13 @@ KDA_CASES = {
     "d256-bf16": (1, 2048, 4, 256, "bfloat16"),
 }
 KDA_CALLS = ("kda_chunk_fwd", "kda_chunk_bwd")
+#: the same scan with ONE decay a head, [batch, seq, heads] (Gated DeltaNet):
+#: at the Qwen3-Next cell's three layers (32 value heads, q and k already
+#: repeated to them) and in its check's float32
+GDN_CASES = {
+    "qwen3-next-cell-bf16": (1, 16384, 32, 128, "bfloat16"),
+    "qwen3-next-check-f32": (1, 16384, 32, 128, "float32"),
+}
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
@@ -336,22 +347,30 @@ def _child():
 
     from paddle_tpu.ops.pallas import linear_attention as kda
 
-    for name, (batch, seq, heads, head_dim, dtype) in KDA_CASES.items():
+    for name, (batch, seq, heads, head_dim, dtype) in (
+            list(KDA_CASES.items())
+            + [("scalar-" + n, c) for n, c in GDN_CASES.items()]):
         def like(*shape, dtype=dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
         x = like(batch, seq, heads, head_dim)
+        # a decay per key channel, or one a head
+        decay = ((batch, seq, heads) if name.startswith("scalar-")
+                 else (batch, seq, heads, head_dim))
         # the cell's float32 check asks every product for float32 in
         # earnest: the bf16 ones inside the kernels must not take that
         with jax.default_matmul_precision("highest"):
             compiled = jax.jit(jax.grad(
                 lambda *a: jnp.sum(kda.kda(*a).astype(jnp.float32)),
                 argnums=(0, 1, 2, 3, 4))).lower(
-                    x, x, x,
-                    like(batch, seq, heads, head_dim, dtype=jnp.float32),
+                    x, x, x, like(*decay, dtype=jnp.float32),
                     like(batch, seq, heads, dtype=jnp.float32)).compile()
         text = compiled.as_text()
         out["kda-" + name] = {
+            # float32 arrays as large as a per-channel decay, in any view
+            "decay_sized_f32": len(re.findall(
+                rf"f32\[{batch},{seq},({heads},{head_dim}|{heads * head_dim})"
+                r"\]", text)),
             "mosaic": text.count(MOSAIC),
             "calls": [c for c in KDA_CALLS if f"({c})" in text],
             "loops": text.count(" while("),
@@ -550,6 +569,28 @@ def test_recomputed_latent_attention_keeps_the_kernels_residuals(
     # un-padding must not run under the kernel's name (the benchmark's
     # readers count events by it)
     assert got["named_after_a_kernel"] == [0, 0]
+
+
+@pytest.mark.parametrize("case", list(GDN_CASES))
+def test_scalar_decay_delta_rule_kernels_compile(compiled, case):
+    """The scan with one decay a head at the Qwen3-Next cell's b1 s16384
+    h32 d128, in bf16 and in the check's float32: the same two Mosaic
+    calls, no loop left to XLA — and in bf16 NO float32 array of the
+    per-channel decay's size anywhere in the program, forward or backward:
+    the decay comes and its gradient leaves as [batch, seq, heads] (in the
+    float32 case q, k, v and their gradients are such arrays themselves)."""
+    got = compiled["kda-scalar-" + case]
+    assert got["mosaic"] == 2 and got["calls"] == list(KDA_CALLS)
+    assert got["loops"] == 0
+    if "bf16" in case:
+        assert got["decay_sized_f32"] == 0
+    # the per-channel program at the same shape does hold them
+    assert compiled["kda-kimi-cell-bf16"]["decay_sized_f32"] > 0
+    batch, seq, heads, d, _ = GDN_CASES[case]
+    kept = 4 * batch * heads * seq * (d * d // 64 + 128 + 64) / 1e9
+    streams = batch * seq * heads * d * (3 * 2 + (4 if "f32" in case
+                                                  else 0)) * 2 / 1e9
+    assert got["temp_gb"] <= 1.25 * (kept + streams) + 0.05, (got, kept)
 
 
 @pytest.mark.parametrize("case", list(KDA_CASES))
