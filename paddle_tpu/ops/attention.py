@@ -162,10 +162,11 @@ def _on_mesh(kernel, arrays, seed, *, head_axis, seed_per_shard):
     are independent per batch row and head, so dim 0 of every array
     shards over the data axes and dim
     ``head_axis`` (None: heads are not a dim of their own) over 'mp' —
-    each only where it divides; otherwise that dim is computed whole on
-    every device of the axis. ``seed_per_shard``: the kernel's mask hash
-    counts (batch, head) from 0 on every shard, so each shard gets a seed
-    of its own or they all drop the same entries."""
+    each only where it divides (the head dim in every array: it may hold a
+    head's features too, [.., heads x d]); otherwise that dim is computed
+    whole on every device of the axis. ``seed_per_shard``: the kernel's
+    mask hash counts (batch, head) from 0 on every shard, so each shard
+    gets a seed of its own or they all drop the same entries."""
     mesh = topology.traced_mesh()
     if (mesh is None or mesh.size == 1 or set(mesh.axis_names) <= set(
             jax.sharding.get_abstract_mesh().manual_axes)):
@@ -176,8 +177,8 @@ def _on_mesh(kernel, arrays, seed, *, head_axis, seed_per_shard):
     n_data = math.prod(mesh.shape[ax] for ax in data)
     n_mp = mesh.shape.get("mp", 1)
     b_axes = data if n_data > 1 and shape[0] % n_data == 0 else ()
-    h_axes = (("mp",) if head_axis is not None and n_mp > 1
-              and shape[head_axis] % n_mp == 0 else ())
+    h_axes = (("mp",) if head_axis is not None and n_mp > 1 and all(
+        a.shape[head_axis] % n_mp == 0 for a in arrays) else ())
 
     def spec(a):
         dims = [None] * a.ndim

@@ -73,6 +73,20 @@ of the Kimi-Linear or the Qwen3-Next configuration on a TPU takes the
 kernels: the same mathematics, a chunk's terms in VMEM, forward and
 backward hand-written, nothing of this file's segments; ``chunked`` and
 ``recurrent`` are this file's.
+
+**One tiling.** The kernels cut their blocks from [B, T, H d] — a head a
+lane-aligned slice of the last axis — and on a TPU that is another tiling
+than [B, T, H, d] (8 tokens x 128 lanes against 8 heads x 128 lanes): a
+reshape between the two is a physical relayout. So the entry point takes q,
+k, v (and a per-channel ``g``) in EITHER rank, told apart by ``q.ndim``
+with H from beta's [B, T, H], and returns ``o`` in the rank it was given:
+streams [B, T, H d] go to the kernels as they are, and a layer that keeps
+its element-wise stages on them never makes the head view (``head_sums`` /
+``on_head_lanes`` / ``head_rsqrt`` give it the per-head statistics there).
+``paddle_tpu_kda_core_entry_total{path, entry}`` counts which entry a call
+used: ``streams`` or ``heads`` — ``heads`` on the ``kernel`` path is a
+layer that still pays the relayouts. ``chunked`` and ``recurrent`` reshape
+streams to heads inside (free where they run).
 """
 import functools
 
@@ -89,9 +103,58 @@ _CORE_TOTAL = obs_metrics.counter(
     "count per traced layer call",
     labelnames=("path",))
 
+_ENTRY_TOTAL = obs_metrics.counter(
+    "paddle_tpu_kda_core_entry_total",
+    "gated-delta-rule cores by the path taken and the entry used: streams "
+    "([B, T, H d] in and out, the kernels' tiling) | heads ([B, T, H, d], "
+    "reshaped by the op: a relayout on the kernel path); under jit one "
+    "count per traced layer call",
+    labelnames=("path", "entry"))
+
 _HIGHEST = jax.lax.Precision.HIGHEST
 #: tokens in a diagonal sub-block, whose pair terms are computed exactly
 SUB = 16
+
+
+# ------------------------------------------- per-head statistics on streams
+# A layer's float32 statistics over a head's d features (the L2 norms of q
+# and k, the output norm's mean square) without the [.., H, d] view: a
+# product with the constant 0/1 matrix [H d, H] sums each head's lanes, its
+# transpose lays a number a head back on them. Float32 in earnest whatever
+# the ambient ``jax.default_matmul_precision``: HIGHEST on the data operand
+# (the 0/1 matrix is exact in one bf16 pass).
+_DATA_HIGHEST = (_HIGHEST, jax.lax.Precision.DEFAULT)
+
+
+def _head_lanes(heads, d):
+    """[H d, H] float32: 1 where lane ``i`` belongs to head ``h``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (heads * d, heads), 0)
+    head = jax.lax.broadcasted_iota(jnp.int32, (heads * d, heads), 1)
+    return (lane // d == head).astype(jnp.float32)
+
+
+def head_sums(x, heads):
+    """The sum over each head's d lanes: float32 [.., H d] -> [.., H]."""
+    return jnp.matmul(x, _head_lanes(heads, x.shape[-1] // heads),
+                      precision=_DATA_HIGHEST)
+
+
+def on_head_lanes(s, d):
+    """A number a head on each of the head's d lanes: float32 [.., H] ->
+    [.., H d]."""
+    return jnp.matmul(s, _head_lanes(s.shape[-1], d).T,
+                      precision=_DATA_HIGHEST)
+
+
+def head_rsqrt(x, heads, *, eps, mean):
+    """``rsqrt(sum_d x^2 + eps)`` of each head (``mean``: of the sum over
+    d), laid on the head's lanes: float32 [.., H d] -> the same shape. An
+    L2 norm is ``x * head_rsqrt(x, H, mean=False)``, an RMS norm a head
+    ``x * head_rsqrt(x, H, mean=True)``."""
+    d = x.shape[-1] // heads
+    squares = head_sums(x * x, heads)
+    return on_head_lanes(
+        jax.lax.rsqrt((squares / d if mean else squares) + eps), d)
 
 
 def kda_recurrent(q, k, v, g, beta, initial_state=None):
@@ -403,15 +466,22 @@ def core_path(seq, d_k=None, d_v=None, dtype=None):
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
-    """The gated delta rule on Tensors (shapes as ``kda_recurrent``): o [B,
-    T, H, d_v]. ``g`` is [B, T, H, d_k] (Kimi Delta Attention: a decay per
-    key channel) or [B, T, H] (Gated DeltaNet: one a head), and every path
-    takes either as it is: the second is never broadcast to the first. The
-    final state stays inside: training starts every row from a zero state
-    and keeps none. The counter's ``path`` ends in ``_scalar`` for a decay a
-    head."""
-    path = core_path(q.shape[1], q.shape[-1], v.shape[-1], q.dtype)
-    _CORE_TOTAL.inc(path=path + ("_scalar" if g.ndim == 3 else ""))
+    """The gated delta rule on Tensors: q, k, v as heads [B, T, H, d]
+    (shapes as ``kda_recurrent``) or as streams [B, T, H d] (the kernels'
+    tiling; H is beta's), o in the rank given. ``g`` is a decay per key
+    channel, shaped like k (Kimi Delta Attention), or one a head, [B, T, H]
+    like beta (Gated DeltaNet), and every path takes either as it is: the
+    second is never broadcast to the first. The final state stays inside:
+    training starts every row from a zero state and keeps none. The
+    counter's ``path`` ends in ``_scalar`` for a decay a head."""
+    heads = beta.shape[-1]
+    streams = q.ndim == 3
+    d_k, d_v = ((q.shape[-1] // heads, v.shape[-1] // heads) if streams
+                else (q.shape[-1], v.shape[-1]))
+    path = core_path(q.shape[1], d_k, d_v, q.dtype)
+    counted = path + ("_scalar" if _decay_a_head(g, beta) else "")
+    _CORE_TOTAL.inc(path=counted)
+    _ENTRY_TOTAL.inc(path=counted, entry="streams" if streams else "heads")
     if path == "recurrent":
         return apply_op("kda_core_recurrent", _recurrent_output, q, k, v, g,
                         beta)
@@ -426,18 +496,40 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
                     chunk=int(chunk))
 
 
+def _decay_a_head(g, beta):
+    """Whether ``g`` is one decay a head, [B, T, H] like beta, and not one
+    per key channel (shaped like k, in either rank)."""
+    return g.shape == beta.shape
+
+
+def _on_heads(scan, q, k, v, g, beta):
+    """``scan`` on [B, T, H, d] heads, for arguments of either rank: streams
+    [B, T, H d] are viewed as heads and o as a stream again."""
+    if q.ndim == 4:
+        return scan(q, k, v, g, beta)
+
+    def heads(x):
+        return x.reshape(*x.shape[:2], beta.shape[-1], -1)
+
+    o = scan(heads(q), heads(k), heads(v),
+             g if _decay_a_head(g, beta) else heads(g), beta)
+    return o.reshape(*o.shape[:2], -1)
+
+
 def _recurrent_output(q, k, v, g, beta):
-    return kda_recurrent(q, k, v, g, beta)[0]
+    return _on_heads(lambda *a: kda_recurrent(*a)[0], q, k, v, g, beta)
 
 
 def _chunked_output(q, k, v, g, beta, *, chunk):
-    return kda_chunked(q, k, v, g, beta, chunk=chunk)[0]
+    return _on_heads(lambda *a: kda_chunked(*a, chunk=chunk)[0], q, k, v, g,
+                     beta)
 
 
 def _kernel_output(q, k, v, g, beta, *, interpret):
     """The Mosaic kernels, under a step's announced mesh inside the
     ``shard_map`` the attention kernels use: rows over the data axes, heads
-    (dim 2 of all five arrays) over 'mp', each where it divides."""
+    (dim 2 of all five arrays, in either rank: a stream's heads are
+    contiguous lane slices) over 'mp', each where it divides."""
     from . import attention
     from .pallas import linear_attention as kernels
 

@@ -742,14 +742,16 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
 def _blocked(q, k, v, g, beta, tokens):
-    """The layer's [B, T, H, d] arrays as [B, T, H d] and its [B, T, H]
-    beta (and a decay a head) a row a chunk, the row padded to whole blocks
-    with tokens that write nothing and decay nothing."""
-    b, t, h, d = q.shape
+    """The layer's arrays as streams [B, T, H d] — as they come, or from
+    [B, T, H, d] heads (on a TPU that reshape is a relayout: a layer that
+    can hands over streams) — and its [B, T, H] beta (and a decay a head) a
+    row a chunk, the row padded to whole blocks with tokens that write
+    nothing and decay nothing."""
+    (b, t), h = q.shape[:2], beta.shape[2]
     pad = -t % tokens
 
     def stream(x, dtype):
-        x = x.astype(dtype).reshape(b, t, h * d)
+        x = x.astype(dtype).reshape(b, t, -1)
         return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
     def rows(x):
@@ -758,7 +760,8 @@ def _blocked(q, k, v, g, beta, tokens):
                                              CHUNK)
 
     return (stream(q, q.dtype), stream(k, q.dtype), stream(v, q.dtype),
-            rows(g) if g.ndim == 3 else stream(g, _F32), rows(beta))
+            rows(g) if g.shape == beta.shape else stream(g, _F32),
+            rows(beta))
 
 
 def _block_tokens(seq, tokens):
@@ -776,15 +779,16 @@ def heads_together(heads, d, want=None):
 
 
 def kda(q, k, v, g, beta, *, tokens=None, together=None, interpret=False):
-    """The gated delta rule from a zero state: q, k [B, T, H, d], g [B, T,
-    H, d] (a decay per key channel) or [B, T, H] (one a head), v [B, T, H,
-    d], beta [B, T, H] -> o [B, T, H, d] in v's dtype (the final state
-    stays inside). k and v take q's dtype, the decay and beta are float32;
-    differentiable in all five. ``tokens``: what a program takes of a row
-    (whole pairs of chunks; ``TOKENS``), ``together``: of how many heads
-    (``TOGETHER``)."""
-    b, t, h, d = q.shape
+    """The gated delta rule from a zero state: q, k, v [B, T, H, d] or, the
+    kernels' own tiling, [B, T, H d]; g like k (a decay per key channel) or
+    [B, T, H] (one a head), beta [B, T, H] -> o shaped like v, in v's dtype
+    (the final state stays inside). k and v take q's dtype, the decay and
+    beta are float32; differentiable in all five. ``tokens``: what a
+    program takes of a row (whole pairs of chunks; ``TOKENS``),
+    ``together``: of how many heads (``TOGETHER``)."""
+    t, h = q.shape[1], beta.shape[2]
     tokens = _block_tokens(t, tokens)
+    d = q.shape[-1] // (h if q.ndim == 3 else 1)
     o = _scan(*_blocked(q, k, v, g, beta, tokens), tokens,
               heads_together(h, d, together), bool(interpret))
-    return o[:, :t].reshape(b, t, h, d).astype(v.dtype)
+    return o[:, :t].reshape(v.shape).astype(v.dtype)

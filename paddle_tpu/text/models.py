@@ -849,45 +849,68 @@ KIMI_LINEAR_ATTN = {
 # layer at 16,384 tokens. Each stage is a ``jax.checkpoint`` of its own: a
 # differentiated program keeps the stage's (bf16 or low-rank) inputs and
 # rebuilds the float32 inside it; without differentiation it is the plain
-# function.
-def _kda_streams(q, k, v, w_q, w_k, w_v, *, heads, eps):
-    """The projected [B, T, H * d] streams -> heads [B, T, H, d]: each
-    through its causal depthwise convolution and SiLU, q and k then
-    L2-normalised over a head's features (in float32), q scaled by
-    d^-0.5."""
-    import jax
+# function. Every stage stays on [B, T, H * d] streams, the tiling of the
+# projections, the convolution and the scan's kernels: a head is a slice of
+# the last axis, its statistics come from ``ops.linear_attention
+# .head_rsqrt`` and no array is viewed as [.., H, d] (on a TPU that view is
+# a physical relayout of 268 MB, there and back).
+def _l2_normed(x, heads, *, eps, scale):
+    """x [.., H * d] L2-normalised over each head's d features in float32,
+    times ``scale``, in x's dtype."""
     import jax.numpy as jnp
+
+    from ..ops.linear_attention import head_rsqrt
+
+    xf = x.astype(jnp.float32)
+    return (xf * (head_rsqrt(xf, heads, eps=eps, mean=False) * scale)).astype(
+        x.dtype)
+
+
+def _gated_head_norm(o, gate, w, heads, *, eps, activation):
+    """``w * RMSNorm_d(o) * activation(gate)`` a head, in float32, with the
+    learned d-wide weight laid on every head's lanes; o and gate [B, T,
+    H * d] -> the same shape in o's dtype."""
+    import jax.numpy as jnp
+
+    from ..ops.linear_attention import head_rsqrt
+
+    of = o.astype(jnp.float32)
+    normed = of * head_rsqrt(of, heads, eps=eps, mean=True) * jnp.tile(
+        w.astype(jnp.float32), heads)
+    return (normed * activation(gate.astype(jnp.float32))).astype(o.dtype)
+
+
+def _kda_streams(q, k, v, w_q, w_k, w_v, *, heads, eps):
+    """The projected [B, T, H * d] streams, each through its causal
+    depthwise convolution and SiLU, q and k then L2-normalised over a
+    head's features (in float32), q scaled by d^-0.5: three [B, T, H * d]
+    streams."""
+    import jax
 
     def conv(x, w):
         return F._causal_depthwise_conv1d(x, w, activation="silu")
 
-    def split(x):
-        return x.reshape(*x.shape[:-1], heads, x.shape[-1] // heads)
-
-    def l2(x, scale):
-        xf = split(x).astype(jnp.float32)
-        return (xf * (jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True)
-                                    + eps) * scale)).astype(x.dtype)
-
     def streams(q, k, v, w_q, w_k, w_v):
         d = q.shape[-1] // heads
-        return (l2(conv(q, w_q), d ** -0.5), l2(conv(k, w_k), 1.0),
-                split(conv(v, w_v)))
+        return (_l2_normed(conv(q, w_q), heads, eps=eps, scale=d ** -0.5),
+                _l2_normed(conv(k, w_k), heads, eps=eps, scale=1.0),
+                conv(v, w_v))
 
     return jax.checkpoint(streams)(q, k, v, w_q, w_k, w_v)
 
 
 def _kda_decay(low, w_up, a_log, dt_bias, *, heads):
-    """g = -exp(A_log_h) softplus(low W + dt_bias) in float32: [B, T, H, d],
-    the decay's logarithm per head and key channel."""
+    """g = -exp(A_log_h) softplus(low W + dt_bias) in float32: [B, T,
+    H * d], the decay's logarithm per head and key channel (a head's factor
+    repeated over its d lanes)."""
     import jax
     import jax.numpy as jnp
 
     def decay(low, w_up, a_log, dt_bias):
         f32 = jnp.float32
         z = jnp.dot(low.astype(f32), w_up.astype(f32)) + dt_bias.astype(f32)
-        z = z.reshape(*z.shape[:-1], heads, z.shape[-1] // heads)
-        return -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(z)
+        return -jnp.repeat(jnp.exp(a_log.astype(f32)),
+                           z.shape[-1] // heads) * jax.nn.softplus(z)
 
     return jax.checkpoint(decay)(low, w_up, a_log, dt_bias)
 
@@ -900,19 +923,14 @@ def _kda_beta(x, w):
                                   w.astype(jnp.float32)))
 
 
-def _kda_gated_norm(o, gate, w, *, eps):
+def _kda_gated_norm(o, gate, w, *, heads, eps):
     """sigmoid(gate) * RMSNorm_d(o) per head with the learned d-wide weight,
-    in float32; [B, T, H, d] -> [B, T, H * d] in o's dtype."""
+    in float32; [B, T, H * d] -> the same in o's dtype."""
     import jax
-    import jax.numpy as jnp
 
     def gated(o, gate, w):
-        of = o.astype(jnp.float32)
-        normed = of * jax.lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True)
-                                    + eps) * w.astype(jnp.float32)
-        out = normed.reshape(gate.shape) * jax.nn.sigmoid(
-            gate.astype(jnp.float32))
-        return out.astype(o.dtype)
+        return _gated_head_norm(o, gate, w, heads, eps=eps,
+                                activation=jax.nn.sigmoid)
 
     return jax.checkpoint(gated)(o, gate, w)
 
@@ -998,7 +1016,8 @@ class KimiDeltaAttention(nn.Layer):
         with jax.named_scope("kda.out"):
             return self.o_proj(apply_op(
                 "kda_gated_norm", _kda_gated_norm, o, gate,
-                self.o_norm.weight, eps=self.o_norm.eps))
+                self.o_norm.weight, heads=self.num_heads,
+                eps=self.o_norm.eps))
 
 
 class KimiLinearModel(_BlockwiseModel):
@@ -1149,26 +1168,21 @@ def _gdn_split(qkvz, ba, *, key_heads, d_k, d_v, per_key):
             y[..., per_key:].reshape(*lead, -1))
 
 
-def _gdn_streams(mixed, w, *, key_heads, d_k, d_v, eps):
+def _gdn_streams(mixed, w, *, key_heads, d_k, eps):
     """The q | k | v stream through ONE causal depthwise convolution and
-    SiLU -> q, k [B, T, H_k, d_k] L2-normalised a head (in float32), q
-    scaled by d_k^-0.5, and v [B, T, H_v, d_v]."""
+    SiLU -> q, k [B, T, H_k * d_k] L2-normalised a head (in float32), q
+    scaled by d_k^-0.5, and v [B, T, H_v * d_v]: streams, as
+    KimiDeltaAttention's."""
     import jax
-    import jax.numpy as jnp
 
     def streams(mixed, w):
         x = F._causal_depthwise_conv1d(mixed, w, activation="silu")
         key = key_heads * d_k
-        lead = x.shape[:-1]
-
-        def l2(x, scale):
-            xf = x.reshape(*lead, key_heads, d_k).astype(jnp.float32)
-            return (xf * (jax.lax.rsqrt(
-                jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
-                * scale)).astype(x.dtype)
-
-        return (l2(x[..., :key], d_k ** -0.5), l2(x[..., key:2 * key], 1.0),
-                x[..., 2 * key:].reshape(*lead, -1, d_v))
+        return (_l2_normed(x[..., :key], key_heads, eps=eps,
+                           scale=d_k ** -0.5),
+                _l2_normed(x[..., key:2 * key], key_heads, eps=eps,
+                           scale=1.0),
+                x[..., 2 * key:])
 
     return jax.checkpoint(streams)(mixed, w)
 
@@ -1199,18 +1213,44 @@ def _repeat_heads(x, *, repeats, axis):
     return x if repeats == 1 else jnp.repeat(x, repeats, axis=axis)
 
 
-def _gdn_gated_norm(o, z, w, *, eps):
-    """``w * RMSNorm_d(o) * silu(z)`` a head, in float32 (w from 1: this
-    norm is not zero-centred); [B, T, H, d] -> [B, T, H * d] in o's dtype."""
+def _repeat_head_lanes(x, *, heads, repeats):
+    """``_repeat_heads`` on a stream [.., H * d]: each head's d lanes laid
+    down ``repeats`` times, neighbours — slices of the last axis side by
+    side, no [.., H, repeats, d] view. Its gradient is stated (a head's is
+    the sum of its copies' slices): differentiating the slices gives one
+    zero-padded stream a copy."""
     import jax
     import jax.numpy as jnp
 
+    if repeats == 1:
+        return x
+    d = x.shape[-1] // heads
+
+    def lanes(x, head):
+        return x[..., head * d:(head + 1) * d]
+
+    @jax.custom_vjp
+    def repeat(x):
+        return jnp.concatenate([lanes(x, h) for h in range(heads)
+                                for _ in range(repeats)], axis=-1)
+
+    def backward(_, dy):
+        return (jnp.concatenate(
+            [sum(lanes(dy, h * repeats + j) for j in range(repeats))
+             for h in range(heads)], axis=-1),)
+
+    repeat.defvjp(lambda x: (repeat(x), None), backward)
+    return repeat(x)
+
+
+def _gdn_gated_norm(o, z, w, *, heads, eps):
+    """``w * RMSNorm_d(o) * silu(z)`` a head, in float32 (w from 1: this
+    norm is not zero-centred); [B, T, H * d] -> the same in o's dtype."""
+    import jax
+
     def gated(o, z, w):
-        of = o.astype(jnp.float32)
-        normed = of * jax.lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True)
-                                    + eps) * w.astype(jnp.float32)
-        return (normed.reshape(z.shape) * jax.nn.silu(
-            z.astype(jnp.float32))).astype(o.dtype)
+        return _gated_head_norm(o, z, w, heads, eps=eps,
+                                activation=jax.nn.silu)
 
     return jax.checkpoint(gated)(o, z, w)
 
@@ -1290,20 +1330,21 @@ class GatedDeltaNet(nn.Layer):
             q, k, v = apply_op(
                 "gdn_streams", _gdn_streams, mixed, self.conv1d.weight,
                 key_heads=self.num_k_heads, d_k=self.head_k_dim,
-                d_v=self.head_v_dim, eps=self.l2_eps)
+                eps=self.l2_eps)
         with jax.named_scope("gdn.gate"):
             g = apply_op("gdn_decay", _gdn_decay, a, self.A_log,
                          self.dt_bias)
             beta = apply_op("gdn_beta", _gdn_beta, b)
         with jax.named_scope("gdn.repeat"):
-            q, k = (apply_op("repeat_heads", _repeat_heads, t,
-                             repeats=per_key, axis=2) for t in (q, k))
+            q, k = (apply_op("repeat_head_lanes", _repeat_head_lanes, t,
+                             heads=self.num_k_heads, repeats=per_key)
+                    for t in (q, k))
         with jax.named_scope("gdn.core"):
             o = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
         with jax.named_scope("gdn.out"):
             return self.out_proj(apply_op(
                 "gdn_gated_norm", _gdn_gated_norm, o, z, self.norm.weight,
-                eps=self.norm.eps))
+                heads=self.num_v_heads, eps=self.norm.eps))
 
 
 def _gqa_heads(q, k, v, w_q, w_k, *, heads, kv_heads, d, eps, base,

@@ -327,6 +327,8 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
         paths = ("kernel", "chunked", "recurrent")
         counts = {p: linear_attention._CORE_TOTAL.value(path=p)
                   for p in paths}
+        entries = {e: linear_attention._ENTRY_TOTAL.value(path=given, entry=e)
+                   for e in ("streams", "heads")}
         counts["held"] = moe._DISPATCH_TOTAL.value(path="sorted_held")
         counts["xla"] = attention._ROUTE_TOTAL.value(route="xla")
         text = jax.jit(jax.grad(
@@ -345,6 +347,10 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
     for p in paths:
         assert linear_attention._CORE_TOTAL.value(path=p) - counts[p] == (
             4 if p == given else 0), p
+    # every layer hands the scan [B, T, H d] streams: none pays a relayout
+    for e, n in entries.items():
+        assert linear_attention._ENTRY_TOTAL.value(
+            path=given, entry=e) - n == (4 if e == "streams" else 0), e
     assert moe._DISPATCH_TOTAL.value(
         path="sorted_held") - counts["held"] == 4
     assert attention._ROUTE_TOTAL.value(route="xla") - counts["xla"] == 1
@@ -459,3 +465,173 @@ def test_a_train_step_decays_every_weight_but_the_decays_own(ids):
     assert sum(n.endswith("e_score_correction_bias") for n in after) == 4
     assert all(int(after[n]) == 0 for n in after
                if n.endswith("held_overflow"))
+
+
+# ------------------------------------------------ one tiling: the streams
+# The layer's element-wise stages stay on [B, T, H d] (text/models.py): a
+# head's float32 statistics come from products with a 0/1 matrix
+# (ops.linear_attention.head_sums / head_rsqrt), not from a [.., H, d] view.
+# Held here to that view, in float32: the helper alone, then the whole layer
+# against PR 38's formulation, kept below.
+def _dot_precisions(jaxpr):
+    """The ``precision`` of every dot_general, nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["precision"])
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (list, tuple)) else [
+                    value]:
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    found += _dot_precisions(inner)
+    return found
+
+
+def _per_head(what, heads, d):
+    """(on streams, on the head view) of one statistic: x, gate [.., H d],
+    w [d] -> an array."""
+    from paddle_tpu.text import models
+
+    def view(x):
+        return x.reshape(*x.shape[:-1], heads, d)
+
+    eps = 1e-6
+    return {
+        "sum": (lambda x, gate, w: linear_attention.head_sums(x, heads),
+                lambda x, gate, w: view(x).sum(-1)),
+        "l2": (lambda x, gate, w: models._l2_normed(x, heads, eps=eps,
+                                                    scale=d ** -0.5),
+               lambda x, gate, w: (view(x) * jax.lax.rsqrt(
+                   jnp.sum(view(x) ** 2, -1, keepdims=True) + eps)
+                   * d ** -0.5).reshape(x.shape)),
+        "gated_rms": (lambda x, gate, w: models._gated_head_norm(
+            x, gate, w, heads, eps=eps, activation=jax.nn.sigmoid),
+                      lambda x, gate, w: (view(x) * jax.lax.rsqrt(
+                          jnp.mean(view(x) ** 2, -1, keepdims=True) + eps)
+                          * w).reshape(x.shape) * jax.nn.sigmoid(gate)),
+    }[what]
+
+
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+@pytest.mark.parametrize("what", ["sum", "l2", "gated_rms"])
+def test_per_head_statistics_on_streams_are_the_head_views(what, ambient):
+    """Values and gradients to 1e-6, and every product of the helper states
+    its precision — HIGHEST on the data — so that it is float32 in earnest
+    under ANY ambient ``jax.default_matmul_precision`` (on a TPU the default
+    is one bf16 pass: another sum of squares)."""
+    heads, d = 4, 32
+    ks = jax.random.split(jax.random.PRNGKey(39), 3)
+    # heads of very different sizes: a sum that leaked across would show
+    x = jax.random.normal(ks[0], (2, 24, heads * d)) * jnp.repeat(
+        jnp.asarray([1e-2, 1.0, 30.0, 3.0]), d)
+    gate, w = jax.random.normal(ks[1], x.shape), 1.0 + 0.1 * jax.random.normal(
+        ks[2], (d,))
+    streams, head_view = _per_head(what, heads, d)
+
+    def both(fn):
+        def loss(*a):
+            out = fn(*a)
+            return jnp.sum(out * jnp.cos(jnp.arange(
+                out.size, dtype=jnp.float32).reshape(out.shape))), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    with jax.default_matmul_precision(ambient):
+        (_, got), got_grads = both(streams)(x, gate, w)
+        precisions = _dot_precisions(jax.make_jaxpr(
+            jax.grad(lambda *a: jnp.sum(streams(*a)), argnums=0))(
+                x, gate, w).jaxpr)
+    (_, want), want_grads = both(head_view)(x, gate, w)
+    highest = jax.lax.Precision.HIGHEST
+    assert precisions and all(
+        p is not None and p[0] == highest for p in precisions), precisions
+
+    def near(a, b):
+        # per head: the smallest head is not judged by the largest's size
+        a, b = (np.asarray(t).reshape(-1, heads, t.shape[-1] // heads)
+                if t.ndim == 3 else np.asarray(t)[None, None]
+                for t in (a, b))
+        assert a.shape == b.shape
+        scale = np.abs(b).max(axis=(0, 2), keepdims=True)
+        assert (np.abs(a - b) <= 1e-6 * scale + 1e-30).all()
+
+    near(got, want)
+    for a, b in zip(got_grads, want_grads):
+        near(a, b)
+
+
+def _kda_layer_on_the_head_view(p, x, *, heads, chunk, l2_eps, norm_eps):
+    """``KimiDeltaAttention.forward`` as it stood until PR 38: q, k, v, the
+    decay and the output norm on [B, T, H, d], the scan called with heads."""
+    from paddle_tpu.nn import functional as F
+
+    f32 = jnp.float32
+
+    def conv(x, w):
+        return F._causal_depthwise_conv1d(x, w, activation="silu")
+
+    def split(x):
+        return x.reshape(*x.shape[:-1], heads, x.shape[-1] // heads)
+
+    def l2(x, scale):
+        xf = split(x).astype(f32)
+        return (xf * (jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True)
+                                    + l2_eps) * scale)).astype(x.dtype)
+
+    q, k, v = (x @ p[f"{n}_proj.weight"] for n in "qkv")
+    d = q.shape[-1] // heads
+    q = l2(conv(q, p["q_conv.weight"]), d ** -0.5)
+    k = l2(conv(k, p["k_conv.weight"]), 1.0)
+    v = split(conv(v, p["v_conv.weight"]))
+    z = jnp.dot((x @ p["f_a_proj.weight"]).astype(f32),
+                p["f_b_proj.weight"].astype(f32)) + p["dt_bias"].astype(f32)
+    g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(split(z))
+    beta = jax.nn.sigmoid(jnp.dot(x.astype(f32),
+                                  p["b_proj.weight"].astype(f32)))
+    gate = (x @ p["g_a_proj.weight"]) @ p["g_b_proj.weight"]
+    o = linear_attention._chunked_output(q, k, v, g, beta, chunk=chunk)
+    of = o.astype(f32)
+    normed = of * jax.lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True)
+                                + norm_eps) * p["o_norm.weight"].astype(f32)
+    out = normed.reshape(gate.shape) * jax.nn.sigmoid(gate.astype(f32))
+    return out.astype(o.dtype) @ p["o_proj.weight"]
+
+
+def test_the_layer_on_streams_is_the_layer_on_the_head_view():
+    """Output and every parameter's gradient (and the input's) of
+    ``KimiDeltaAttention`` against the [.., H, d] formulation above."""
+    paddle.seed(9)
+    layer = KimiDeltaAttention(64, num_heads=4, head_dim=16, gate_rank=16,
+                               chunk=16)
+    x = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (2, 50, 64)), jnp.float32)
+    params = dict(layer.functional_state()[0])
+
+    def on_streams(p, x):
+        with loaded(layer, p):
+            return layer(Tensor(x, stop_gradient=False))._value
+
+    def on_the_head_view(p, x):
+        return _kda_layer_on_the_head_view(
+            p, x, heads=4, chunk=16, l2_eps=layer.l2_eps,
+            norm_eps=layer.o_norm.eps)
+
+    def both(fn):
+        def loss(p, x):
+            out = fn(p, x)
+            return jnp.sum(out * jnp.cos(jnp.arange(
+                out.size, dtype=jnp.float32).reshape(out.shape))), out
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            params, x)
+
+    (_, got), (got_p, got_x) = both(on_streams)
+    (_, want), (want_p, want_x) = both(on_the_head_view)
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+        jnp.abs(want).max())
+    assert set(got_p) == set(want_p) == set(params)
+    for name in list(params) + ["x"]:
+        a, b = (got_x, want_x) if name == "x" else (got_p[name], want_p[name])
+        assert float(jnp.abs(b).max()) > 0, name
+        assert float(jnp.abs(a - b).max()) <= 2e-5 * float(
+            jnp.abs(b).max()), name

@@ -366,16 +366,19 @@ def test_the_kernel_path_shards_itself_over_an_announced_mesh(interpreter):
           1e-6)
 
 
-def test_the_kernel_microbenchmark_measures_on_a_tpu_only():
-    """``tools/kda_kernel_bench.py`` exits 2 where there is no TPU: a number
-    from this CPU is never printed under a device's name."""
+@pytest.mark.parametrize("mode", [[], ["--layer", "gdn"]])
+def test_the_kernel_microbenchmark_measures_on_a_tpu_only(mode):
+    """``tools/kda_kernel_bench.py`` exits 2 where there is no TPU, in its
+    scan mode and in its ``--layer`` mode: a number from this CPU is never
+    printed under a device's name."""
     import os
     import subprocess
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     done = subprocess.run(
-        [sys.executable, os.path.join(root, "tools", "kda_kernel_bench.py")],
+        [sys.executable, os.path.join(root, "tools", "kda_kernel_bench.py"),
+         *mode],
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
         text=True, timeout=300)
     assert done.returncode == 2 and "TPU only" in done.stderr
@@ -584,3 +587,126 @@ def test_the_entry_point_counts_the_decay_with_the_path(interpreter, seq, d,
     for p, n in before.items():
         assert la._CORE_TOTAL.value(path=p) == n + (p == path), p
     close(out._value, la.kda_recurrent(*args)[0], 2e-6)
+
+
+# ------------------------------------------- streams: the kernels' tiling
+# A layer that keeps its stages on [B, T, H d] hands the scan streams and
+# gets a stream back; H is beta's. Every path is held to its own rank-4
+# entry, outputs and all five gradients: the chunked scan and the
+# recurrence reshape inside, the kernels take the streams as they are.
+def as_streams(args):
+    """q, k, v (and a per-channel decay) [B, T, H, d] -> [B, T, H d]."""
+    *wide, beta = args
+    return tuple(a.reshape(*a.shape[:2], -1) if a.ndim == 4 else a
+                 for a in wide) + (beta,)
+
+
+def entry_output(path, interpret):
+    fn = {"recurrent": la._recurrent_output,
+          "chunked": lambda *a: la._chunked_output(*a, chunk=16),
+          "kernel": lambda *a: la._kernel_output(*a, interpret=interpret)}
+    return fn[path]
+
+
+@pytest.mark.parametrize("path, seq, scalar", [
+    ("recurrent", 12, False), ("recurrent", 12, True),
+    ("chunked", 100, False), ("chunked", 100, True),
+    ("kernel", 256, False), ("kernel", 256, True),
+    ("kernel", 200, False),        # a row ``_blocked`` pads to whole blocks
+    ("kernel", 200, True),
+])
+def test_the_stream_entry_is_the_heads_entry(path, seq, scalar):
+    d = 128 if path == "kernel" else D_K
+    args = (scalar_inputs if scalar else inputs)(
+        13, 1, seq, heads=2, d_k=d, d_v=d)
+    fn = entry_output(path, interpret=True)
+    _, want = weighted(fn)(*args)
+    _, got = weighted(fn)(*as_streams(args))
+    got_o = jax.jit(fn)(*as_streams(args))
+    assert got_o.shape == (1, seq, 2 * d)      # a stream in, a stream out
+    close(got_o.reshape(1, seq, 2, d), jax.jit(fn)(*args), 1e-6)
+    for name, a, b in zip("q k v g beta".split(), got, as_streams(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert float(jnp.abs(b).max()) > 0, name
+        close(a, b, 1e-5)
+
+
+@pytest.mark.parametrize("seq, d, path, scalar, entry", [
+    (150, 128, "kernel", False, "streams"),
+    (150, 128, "kernel", False, "heads"),
+    (150, 128, "kernel", True, "streams"),
+    (150, 32, "chunked", False, "streams"),
+    (150, 32, "chunked", True, "heads"),
+    (12, 128, "recurrent", True, "streams"),
+])
+def test_the_entry_point_counts_the_entry_with_the_path(
+        interpreter, seq, d, path, scalar, entry):
+    """``paddle_tpu_kda_core_entry_total{path, entry}``: ``streams`` for
+    [B, T, H d] arguments, ``heads`` for [B, T, H, d] — one count a call,
+    beside ``paddle_tpu_kda_core_total{path}``'s, which stays what it was;
+    o comes back in the rank given."""
+    path += "_scalar" if scalar else ""
+    names = [(p + s, e) for p in ("kernel", "chunked", "recurrent")
+             for s in ("", "_scalar") for e in ("streams", "heads")]
+    before = {n: la._ENTRY_TOTAL.value(path=n[0], entry=n[1]) for n in names}
+    paths = {n[0]: la._CORE_TOTAL.value(path=n[0]) for n in names}
+    args = (scalar_inputs if scalar else inputs)(
+        10, 1, seq, heads=2, d_k=d, d_v=d)
+    want = la.kda_recurrent(*args)[0]
+    if entry == "streams":
+        args, want = as_streams(args), want.reshape(1, seq, -1)
+    out = la.gated_delta_rule(*(paddle.to_tensor(np.asarray(a))
+                                for a in args))
+    for n, count in before.items():
+        assert la._ENTRY_TOTAL.value(path=n[0], entry=n[1]) == count + (
+            n == (path, entry)), n
+    for p, count in paths.items():
+        assert la._CORE_TOTAL.value(path=p) == count + (p == path), p
+    assert out.shape == list(want.shape)
+    close(out._value, want, 2e-6)
+
+
+def test_streams_shard_their_heads_over_an_announced_mesh(interpreter):
+    """Streams under ``_on_mesh``: dim 2 holds heads x d and shards over
+    'mp' where the HEADS divide (a head stays a contiguous slice); three
+    heads on mp = 2 are computed whole on both devices, not cut through a
+    head."""
+    from paddle_tpu.distributed import topology
+
+    mesh = topology.build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+    for heads, sharded in ((2, True), (3, False)):
+        args = tuple(jnp.concatenate([a, a[:, ::-1]], axis=0)
+                     for a in inputs(12, 1, 128, heads=heads, d_k=128,
+                                     d_v=128))
+        want = la._kernel_output(*args, interpret=True)
+
+        def step(*a):
+            with topology.tracing_for(mesh):
+                return la._kernel_output(*a, interpret=True)
+
+        streams = as_streams(args)
+        text = jax.jit(step).lower(*streams).as_text()
+        assert "shard_map" in text or "manual" in text
+        got = jax.jit(step)(*streams)
+        assert got.shape == (2, 128, heads * 128)
+        close(got.reshape(want.shape), want, 1e-6)
+        specs = [str(s) for s in _shard_map_in_specs(step, streams)]
+        assert any("mp" in s for s in specs) == sharded, specs
+
+
+def _shard_map_in_specs(fn, args):
+    """The in_specs (as jaxpr params) of the first shard_map ``fn`` traces."""
+    jaxpr = jax.make_jaxpr(fn)(*args)
+
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "shard_map":
+                return eqn.params["in_specs"]
+            for value in eqn.params.values():
+                inner = getattr(value, "jaxpr", value)
+                found = find(inner) if hasattr(inner, "eqns") else None
+                if found is not None:
+                    return found
+        return None
+
+    return find(jaxpr.jaxpr)

@@ -107,6 +107,14 @@ GDN_CASES = {
     "qwen3-next-cell-bf16": (1, 16384, 32, 128, "bfloat16"),
     "qwen3-next-check-f32": (1, 16384, 32, 128, "float32"),
 }
+#: one linear-attention LAYER between its projections — the stages of
+#: ``text/models.py`` and the scan — forward + backward in bf16 (batch, seq,
+#: key heads, value heads, head_dim): ``KimiDeltaAttention`` at the
+#: Kimi-Linear cell's shape, ``GatedDeltaNet`` at the Qwen3-Next cell's
+LAYER_CASES = {
+    "kda-kimi-cell": (1, 16384, 32, 32, 128),
+    "gdn-qwen3-next-cell": (1, 16384, 16, 32, 128),
+}
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
@@ -378,6 +386,62 @@ def _child():
             "transposes": len(re.findall(r" (transpose|copy)\(", text)),
             "temp_gb": compiled.memory_analysis().temp_size_in_bytes / 1e9}
 
+    from paddle_tpu.text import models
+
+    for name, (batch, seq, key_heads, heads, d) in LAYER_CASES.items():
+        bf16, f32 = jnp.bfloat16, jnp.float32
+
+        def like(*shape, dtype=bf16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        stream = like(batch, seq, heads * d)
+        per_head = like(batch, seq, heads, dtype=f32)
+
+        def kda_layer(q, k, v, w_q, w_k, w_v, low, w_up, a_log, dt_bias,
+                      beta, gate, w):
+            q, k, v = models._kda_streams(q, k, v, w_q, w_k, w_v,
+                                          heads=heads, eps=1e-6)
+            g = models._kda_decay(low, w_up, a_log, dt_bias, heads=heads)
+            return models._kda_gated_norm(kda.kda(q, k, v, g, beta), gate, w,
+                                          heads=heads, eps=1e-5)
+
+        def gdn_layer(mixed, taps, a, a_log, dt_bias, b, z, w):
+            q, k, v = models._gdn_streams(mixed, taps, key_heads=key_heads,
+                                          d_k=d, eps=1e-6)
+            q, k = (models._repeat_head_lanes(
+                x, heads=key_heads, repeats=heads // key_heads)
+                for x in (q, k))
+            o = kda.kda(q, k, v, models._gdn_decay(a, a_log, dt_bias),
+                        models._gdn_beta(b))
+            return models._gdn_gated_norm(o, z, w, heads=heads, eps=1e-6)
+
+        taps = like(4, heads * d)
+        mixed = (2 * key_heads + heads) * d
+        layer, args = {
+            "kda": (kda_layer, (stream, stream, stream, taps, taps, taps,
+                                like(batch, seq, d), like(d, heads * d),
+                                like(heads, dtype=f32),
+                                like(heads * d, dtype=f32), per_head, stream,
+                                like(d, dtype=f32))),
+            "gdn": (gdn_layer, (like(batch, seq, mixed), like(4, mixed),
+                                like(batch, seq, heads),
+                                like(heads, dtype=f32),
+                                like(heads, dtype=f32),
+                                like(batch, seq, heads), stream,
+                                like(d, dtype=f32)))}[name[:3]]
+        text = jax.jit(jax.grad(
+            lambda *a: jnp.sum(layer(*a).astype(f32)),
+            argnums=tuple(range(len(args))))).lower(*args).compile().as_text()
+        # a float32 array in the [tokens, heads, d] tiling, as XLA writes a
+        # relayout to or from it: [.., 16384, H, 128] or [2048, 8, H, 128]
+        head_view = rf"f32\[(\d+,)?({seq},\d+,{d}|{seq // 8},8,\d+,{d})\]"
+        out["layer-" + name] = {
+            "mosaic": text.count(MOSAIC),
+            "calls": [c for c in KDA_CALLS if f"({c})" in text],
+            "f32_relayouts": sorted(set(re.findall(
+                rf"= ({head_view})\S* (?:copy|reshape|transpose)\(", text))),
+            "f32_head_views": len(re.findall(head_view, text))}
+
     # the s128 cell's FFN under amp O1, forward + backward: what F.gelu's
     # erf lowers to behind linear1's gemm, and what leaves that fusion
     def ffn_loss(p, x):
@@ -591,6 +655,22 @@ def test_scalar_decay_delta_rule_kernels_compile(compiled, case):
     streams = batch * seq * heads * d * (3 * 2 + (4 if "f32" in case
                                                   else 0)) * 2 / 1e9
     assert got["temp_gb"] <= 1.25 * (kept + streams) + 0.05, (got, kept)
+
+
+@pytest.mark.parametrize("case", list(LAYER_CASES))
+def test_a_linear_attention_layer_keeps_one_tiling(compiled, case):
+    """A whole layer between its projections — convolution + SiLU, the L2
+    norms, the decay, (the key heads' repeat,) the scan, the gated output
+    norm — forward + backward at the cell's shape under amp O1's dtypes:
+    the scan's two Mosaic calls, and NO float32 array anywhere in the
+    program in the [tokens, heads, d] tiling, let alone a copy, reshape or
+    transpose to or from it: every stage stays on the kernels' [tokens,
+    heads x d] (until PR 39 the Kimi-Linear layer held 13 such relayouts of
+    268 MB, 95 ms of an 891 ms step)."""
+    got = compiled["layer-" + case]
+    assert got["mosaic"] == 2 and got["calls"] == list(KDA_CALLS)
+    assert got["f32_relayouts"] == []
+    assert got["f32_head_views"] == 0
 
 
 @pytest.mark.parametrize("case", list(KDA_CASES))

@@ -369,6 +369,8 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
                  for s in ("", "_scalar")]
         counts = {p: linear_attention._CORE_TOTAL.value(path=p)
                   for p in paths}
+        entries = {e: linear_attention._ENTRY_TOTAL.value(path=given, entry=e)
+                   for e in ("streams", "heads")}
         counts["held"] = moe._DISPATCH_TOTAL.value(path="sorted_held")
         counts["xla"] = attention._ROUTE_TOTAL.value(route="xla")
         text = jax.jit(jax.grad(
@@ -385,6 +387,10 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
     for p in paths:
         assert linear_attention._CORE_TOTAL.value(path=p) - counts[p] == (
             3 if p == given else 0), p
+    # every layer hands the scan [B, T, H d] streams: none pays a relayout
+    for e, n in entries.items():
+        assert linear_attention._ENTRY_TOTAL.value(
+            path=given, entry=e) - n == (3 if e == "streams" else 0), e
     assert moe._DISPATCH_TOTAL.value(
         path="sorted_held") - counts["held"] == 4
     assert attention._ROUTE_TOTAL.value(route="xla") - counts["xla"] == 1
@@ -482,3 +488,172 @@ def test_a_train_step_decays_no_decay_scale_and_no_norm_weight(ids):
     after = wrapper.functional_state()[1]
     assert all(int(after[n]) == 0 for n in after
                if n.endswith("held_overflow"))
+
+
+# ------------------------------------------------ one tiling: the streams
+# Gated DeltaNet's stages stay on [B, T, H d] as KimiDeltaAttention's do
+# (tests/test_kimi_linear_model.py holds the helper's sum and sigmoid-gated
+# norm): here this layer's forms — the L2 norm over 16 KEY heads' features,
+# the silu-gated norm, a key head's lanes laid down once a value head — each
+# against its [.., H, d] view in float32, then the whole layer against
+# PR 38's formulation, kept below.
+def _dot_precisions(jaxpr):
+    """The ``precision`` of every dot_general, nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["precision"])
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (list, tuple)) else [
+                    value]:
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    found += _dot_precisions(inner)
+    return found
+
+
+def _per_head(what, heads, d):
+    """(on streams, on the head view): x, gate [.., H d], w [d] -> an
+    array."""
+    from paddle_tpu.text import models
+
+    def view(x):
+        return x.reshape(*x.shape[:-1], heads, d)
+
+    eps = 1e-6
+    return {
+        "l2": (lambda x, gate, w: models._l2_normed(x, heads, eps=eps,
+                                                    scale=1.0),
+               lambda x, gate, w: (view(x) * jax.lax.rsqrt(
+                   jnp.sum(view(x) ** 2, -1, keepdims=True) + eps)).reshape(
+                       x.shape)),
+        "silu_gated_rms": (
+            lambda x, gate, w: models._gdn_gated_norm(x, gate, w,
+                                                      heads=heads, eps=eps),
+            lambda x, gate, w: (view(x) * jax.lax.rsqrt(
+                jnp.mean(view(x) ** 2, -1, keepdims=True) + eps)
+                * w).reshape(x.shape) * jax.nn.silu(gate)),
+        "repeat": (
+            lambda x, gate, w: models._repeat_head_lanes(x, heads=heads,
+                                                         repeats=3),
+            lambda x, gate, w: jnp.repeat(view(x), 3, axis=-2).reshape(
+                *x.shape[:-1], -1)),
+    }[what]
+
+
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+@pytest.mark.parametrize("what", ["l2", "silu_gated_rms", "repeat"])
+def test_per_head_stages_on_streams_are_the_head_views(what, ambient):
+    """Values and gradients to 1e-6 (the repeat: exactly), and the products
+    state HIGHEST on the data whatever the ambient matmul precision."""
+    heads, d = 3, 32
+    ks = jax.random.split(jax.random.PRNGKey(38), 3)
+    x = jax.random.normal(ks[0], (2, 24, heads * d)) * jnp.repeat(
+        jnp.asarray([1e-2, 1.0, 30.0]), d)
+    gate, w = jax.random.normal(ks[1], x.shape), 1.0 + 0.1 * jax.random.normal(
+        ks[2], (d,))
+    streams, head_view = _per_head(what, heads, d)
+
+    def both(fn):
+        def loss(*a):
+            out = fn(*a)
+            return jnp.sum(out * jnp.cos(jnp.arange(
+                out.size, dtype=jnp.float32).reshape(out.shape))), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    with jax.default_matmul_precision(ambient):
+        (_, got), got_grads = both(streams)(x, gate, w)
+        precisions = _dot_precisions(jax.make_jaxpr(
+            jax.grad(lambda *a: jnp.sum(streams(*a)), argnums=0))(
+                x, gate, w).jaxpr)
+    (_, want), want_grads = both(head_view)(x, gate, w)
+    if what == "repeat":
+        assert not precisions             # slices side by side: no product
+    else:
+        assert precisions and all(
+            p is not None and p[0] == jax.lax.Precision.HIGHEST
+            for p in precisions), precisions
+    for a, b in zip((got,) + got_grads, (want,) + want_grads):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape
+        if a.ndim == 3:       # a head is not judged by a larger head's size
+            a, b = (t.reshape(-1, t.shape[-1] // d, d) for t in (a, b))
+            scale = np.abs(b).max(axis=(0, 2), keepdims=True)
+        else:
+            scale = np.abs(b).max()
+        assert (np.abs(a - b) <= (0.0 if what == "repeat" else 1e-6)
+                * scale + 1e-30).all()
+
+
+def _gdn_layer_on_the_head_view(p, x, *, key_heads, value_heads, d_k, d_v,
+                                chunk, l2_eps, norm_eps):
+    """``GatedDeltaNet.forward`` as it stood in PR 38: q, k, v and the
+    output norm on [B, T, H, d], q and k repeated on the head axis, the
+    scan called with heads."""
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.text import models
+
+    f32 = jnp.float32
+    per_key = value_heads // key_heads
+    mixed, z, b, a = models._gdn_split(
+        x @ p["in_proj_qkvz.weight"], x @ p["in_proj_ba.weight"],
+        key_heads=key_heads, d_k=d_k, d_v=d_v, per_key=per_key)
+    conv = F._causal_depthwise_conv1d(mixed, p["conv1d.weight"],
+                                      activation="silu")
+    key, lead = key_heads * d_k, conv.shape[:-1]
+
+    def l2(x, scale):
+        xf = x.reshape(*lead, key_heads, d_k).astype(f32)
+        return (xf * (jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True)
+                                    + l2_eps) * scale)).astype(x.dtype)
+
+    q = jnp.repeat(l2(conv[..., :key], d_k ** -0.5), per_key, axis=2)
+    k = jnp.repeat(l2(conv[..., key:2 * key], 1.0), per_key, axis=2)
+    v = conv[..., 2 * key:].reshape(*lead, -1, d_v)
+    g = models._gdn_decay(a, p["A_log"], p["dt_bias"])
+    o = linear_attention._chunked_output(q, k, v, g, models._gdn_beta(b),
+                                         chunk=chunk)
+    of = o.astype(f32)
+    normed = of * jax.lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True)
+                                + norm_eps) * p["norm.weight"].astype(f32)
+    out = normed.reshape(z.shape) * jax.nn.silu(z.astype(f32))
+    return out.astype(o.dtype) @ p["out_proj.weight"]
+
+
+def test_the_layer_on_streams_is_the_layer_on_the_head_view():
+    """Output and every parameter's gradient (and the input's) of
+    ``GatedDeltaNet`` against the [.., H, d] formulation above."""
+    paddle.seed(4)
+    layer = GatedDeltaNet(64, num_k_heads=2, num_v_heads=4, head_k_dim=16,
+                          head_v_dim=16, chunk=16)
+    x = jnp.asarray(_mixer_input(4, seq=50))
+    params = dict(layer.functional_state()[0])
+
+    def on_streams(p, x):
+        with loaded(layer, p):
+            return layer(Tensor(x, stop_gradient=False))._value
+
+    def on_the_head_view(p, x):
+        return _gdn_layer_on_the_head_view(
+            p, x, key_heads=2, value_heads=4, d_k=16, d_v=16, chunk=16,
+            l2_eps=layer.l2_eps, norm_eps=layer.norm.eps)
+
+    def both(fn):
+        def loss(p, x):
+            out = fn(p, x)
+            return jnp.sum(out * jnp.cos(jnp.arange(
+                out.size, dtype=jnp.float32).reshape(out.shape))), out
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            params, x)
+
+    (_, got), (got_p, got_x) = both(on_streams)
+    (_, want), (want_p, want_x) = both(on_the_head_view)
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+        jnp.abs(want).max())
+    assert set(got_p) == set(want_p) == set(params)
+    for name in list(params) + ["x"]:
+        a, b = (got_x, want_x) if name == "x" else (got_p[name], want_p[name])
+        assert float(jnp.abs(b).max()) > 0, name
+        assert float(jnp.abs(a - b).max()) <= 2e-5 * float(
+            jnp.abs(b).max()), name

@@ -8,6 +8,18 @@ program's temporary bytes and how far the two are apart.
 
     chiprun -- python3 tools/kda_kernel_bench.py [256x2,512x1,...]
 
+``--layer [kda|gdn]`` times ONE linear-attention layer between its
+projections instead — the stages of ``text/models.py`` and the scan as a
+train step runs them (``KimiDeltaAttention`` at the Kimi-Linear cell's
+shape, ``GatedDeltaNet`` at the Qwen3-Next cell's 16 key / 32 value heads),
+from the projections' bf16 outputs to ``o_proj``'s input: the whole, then
+each stage alone, forward and forward + backward, with the compiled
+program's bytes accessed and temporaries. The before-number for work on
+this layer (a fused projection, a convolution kernel) that is not the whole
+cell:
+
+    chiprun -- python3 tools/kda_kernel_bench.py --layer [kda|gdn]
+
 A microbenchmark's numbers are findings for PERF.md, never a metric of the
 benchmark. Exits 2 without a TPU.
 """
@@ -64,6 +76,132 @@ def candidate(name, fn, args, against=None):
     return got
 
 
+#: key heads of the ``gdn`` layer (Qwen3-Next: 16 serve the 32 value heads)
+GDN_KEY_HEADS = 16
+
+
+def _layer_stages(kind):
+    """``[(stage, fn, args)]`` of one layer: its stages in order, each on
+    inputs as the stage before leaves them (drawn, not computed: a stage's
+    time does not depend on the values), and ``layer`` = all of them
+    chained from the projections' outputs."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import linear_attention as la
+    from paddle_tpu.ops.pallas import linear_attention as kernels
+    from paddle_tpu.text import models
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys = iter(jax.random.split(jax.random.PRNGKey(39), 16))
+
+    def normal(*shape, dtype=bf16, scale=1.0):
+        return (jax.random.normal(next(keys), shape, f32) * scale).astype(
+            dtype)
+
+    wide = HEADS * D
+    stream = (BATCH, SEQ, wide)
+    beta = jax.nn.sigmoid(normal(BATCH, SEQ, HEADS, dtype=f32))
+    gate, w_norm = normal(*stream), 1.0 + normal(D, dtype=f32, scale=0.1)
+    a_log = jnp.log(jax.random.uniform(next(keys), (HEADS,), f32, 1., 16.))
+    if kind == "kda":
+        taps = [normal(4, wide, scale=0.5) for _ in range(3)]
+        low, w_up = normal(BATCH, SEQ, D), normal(D, wide, scale=D ** -0.5)
+        dt_bias = normal(wide, dtype=f32)
+
+        def conv(q, k, v, *taps):
+            return models._kda_streams(q, k, v, *taps, heads=HEADS, eps=1e-6)
+
+        def decay(low, w_up, a_log, dt_bias):
+            return models._kda_decay(low, w_up, a_log, dt_bias, heads=HEADS)
+
+        def out(o, gate, w):
+            return models._kda_gated_norm(o, gate, w, heads=HEADS, eps=1e-5)
+
+        def layer(q, k, v, taps, low, w_up, a_log, dt_bias, beta, gate, w):
+            q, k, v = conv(q, k, v, *taps)
+            g = decay(low, w_up, a_log, dt_bias)
+            return out(kernels.kda(q, k, v, g, beta), gate, w)
+
+        raw = [normal(*stream) for _ in range(3)]
+        q, k, v = conv(*raw, *taps)
+        g = decay(low, w_up, a_log, dt_bias)
+        stages = [("conv", lambda *a: sum(conv(*a)), (*raw, *taps)),
+                  ("decay", decay, (low, w_up, a_log, dt_bias))]
+        whole = (*raw, taps, low, w_up, a_log, dt_bias, beta, gate, w_norm)
+    else:
+        per_key = HEADS // GDN_KEY_HEADS
+        key = GDN_KEY_HEADS * D
+        mixed, taps = normal(BATCH, SEQ, 2 * key + wide), normal(
+            4, 2 * key + wide, scale=0.5)
+        a, dt_bias = normal(BATCH, SEQ, HEADS), normal(HEADS, dtype=f32)
+
+        def conv(mixed, taps):
+            return models._gdn_streams(mixed, taps, key_heads=GDN_KEY_HEADS,
+                                       d_k=D, eps=1e-6)
+
+        def repeat(q, k):
+            return tuple(models._repeat_head_lanes(
+                x, heads=GDN_KEY_HEADS, repeats=per_key) for x in (q, k))
+
+        def out(o, gate, w):
+            return models._gdn_gated_norm(o, gate, w, heads=HEADS, eps=1e-6)
+
+        def layer(mixed, taps, a, a_log, dt_bias, beta, gate, w):
+            q, k, v = conv(mixed, taps)
+            g = models._gdn_decay(a, a_log, dt_bias)
+            return out(kernels.kda(*repeat(q, k), v, g, beta), gate, w)
+
+        q, k, v = conv(mixed, taps)
+        g = models._gdn_decay(a, a_log, dt_bias)
+        stages = [("conv", lambda *a: jnp.concatenate(conv(*a), -1),
+                   (mixed, taps)),
+                  ("repeat", lambda *a: sum(repeat(*a)), (q, k))]
+        q, k = repeat(q, k)
+        whole = (mixed, taps, a, a_log, dt_bias, beta, gate, w_norm)
+
+    def norm_on_streams(x):
+        return x * la.head_rsqrt(x, HEADS, eps=1e-6, mean=True)
+
+    def norm_on_the_head_view(x):
+        xh = x.reshape(BATCH, SEQ, HEADS, D)
+        return (xh * jax.lax.rsqrt(jnp.mean(xh * xh, axis=-1, keepdims=True)
+                                   + 1e-6)).reshape(x.shape)
+
+    # the per-head statistic alone, float32 in and out: the two 0/1
+    # products of ``head_rsqrt`` against the [.., H, d] view they replace
+    x = normal(*stream, dtype=f32)
+    stages += [("core", kernels.kda, (q, k, v, g, beta)),
+               ("out", out, (normal(*stream), gate, w_norm)),
+               ("norm alone, on streams", norm_on_streams, (x,)),
+               ("norm alone, on the head view", norm_on_the_head_view, (x,))]
+    return [("layer", layer, whole)] + stages
+
+
+def layer_mode(kind):
+    """One line a stage: forward and forward + backward (every floating
+    input differentiated, as a train step does)."""
+    import jax
+    import jax.numpy as jnp
+
+    line(device=jax.devices()[0].device_kind, layer=kind, batch=BATCH,
+         seq=SEQ, heads=HEADS, d=D,
+         key_heads=GDN_KEY_HEADS if kind == "gdn" else HEADS)
+    for stage, fn, args in _layer_stages(kind):
+        both = jax.jit(jax.grad(
+            lambda *a, fn=fn: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+            argnums=tuple(range(len(args)))))
+        # the executable itself is timed: calling ``both`` compiles again
+        compiled = both.lower(*args).compile()
+        cost = compiled.cost_analysis() or {}
+        line(stage=stage, fwd_ms=round(timed(jax.jit(fn), *args), 3),
+             fwd_bwd_ms=round(timed(compiled, *args), 3),
+             fwd_bwd_accessed_gb=round(cost.get("bytes accessed", 0) / 1e9,
+                                       3),
+             fwd_bwd_temp_gb=round(
+                 compiled.memory_analysis().temp_size_in_bytes / 1e9, 3))
+    return 0
+
+
 def main():
     import jax
     from paddle_tpu.ops import linear_attention as la
@@ -72,6 +210,8 @@ def main():
     if jax.devices()[0].platform != "tpu":
         print("kda_kernel_bench.py measures on a TPU only", file=sys.stderr)
         return 2
+    if sys.argv[1:2] == ["--layer"]:
+        return layer_mode(sys.argv[2] if len(sys.argv) > 2 else "kda")
     blocks = ([tuple(map(int, t.split("x"))) for t in sys.argv[1].split(",")]
               if len(sys.argv) > 1 else [(kernels.TOKENS, kernels.TOGETHER)])
     args = inputs(SEQ)
