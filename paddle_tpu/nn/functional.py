@@ -1139,15 +1139,18 @@ def square_error_cost(input, label):
 
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
-                                 is_causal=False, training=True, name=None):
+                                 is_causal=False, training=True, name=None,
+                                 window=None):
     """Fused attention entry point. Uses the Pallas flash kernel on TPU when
     enabled (ops/pallas/flash_attention.py); otherwise a jnp reference that
-    XLA fuses well. Layout: [batch, heads, seq, head_dim]."""
+    XLA fuses well. Layout: [batch, heads, seq, head_dim]. ``window`` (with
+    ``is_causal``): a sliding window of that many keys up to the query's own
+    position."""
     from ..ops import attention as attn_ops
 
     return attn_ops.scaled_dot_product_attention(
         q, k, v, attn_mask=attn_mask, dropout_p=dropout_p, is_causal=is_causal,
-        training=training)
+        training=training, window=window)
 
 
 # ------------------------------------------------------------- vision misc

@@ -1,4 +1,4 @@
-"""Scaled-dot-product attention: one gate, three routes.
+"""Scaled-dot-product attention: one gate, three routes, and a window.
 
 The reference has no flash attention (SURVEY §5 long-context: absent) —
 its closest analog is the fused BERT encoder functor
@@ -15,6 +15,15 @@ causality, platform), one of:
   head_dim] for long keys, where XLA's S^2 logits buffer explodes.
 - ``xla``: the jnp implementation below, which XLA fuses into a few
   kernels — every masked call, every CPU run without ``pallas_interpret``.
+
+A causal call may carry a static ``window``: query i attends the
+``window`` keys up to its own position (i - window < j <= i; a sliding-
+window layer). The window does not choose the route: ``stream`` runs the
+banded kernel (``mha(window=)``: Mosaic calls ``flash_band_*`` whose grids
+hold the band's blocks alone), ``xla`` builds the band inside ``_sdpa_ref``
+— never as an ``attn_mask``, which would force ``xla``. A window of the
+whole sequence or more is plain causal attention on both. Windowed calls
+count in ``paddle_tpu_attention_window_route_total{route}`` as well.
 
 A selected kernel that fails raises; no route falls back to another.
 GSPMD cannot partition a Mosaic call, so ``short`` is chosen only where
@@ -42,6 +51,14 @@ _ROUTE_TOTAL = obs_metrics.counter(
     "under jit one count per traced call site",
     labelnames=("route",))
 
+_WINDOW_ROUTE_TOTAL = obs_metrics.counter(
+    "paddle_tpu_attention_window_route_total",
+    "attention calls with a sliding window by the route the gate chose "
+    "(stream: the banded kernel | xla: the band built in the jnp path); "
+    "counted beside paddle_tpu_attention_route_total, one per traced call "
+    "site",
+    labelnames=("route",))
+
 # the stream kernel runs one k block of up to 256 keys per grid step: with
 # fewer keys than two blocks a program is per-program overhead however
 # long q is, so the logits-product clause needs this many keys too
@@ -49,12 +66,16 @@ _STREAM_MIN_KEYS = 512
 
 
 def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal,
-              fp32_softmax=True):
+              fp32_softmax=True, window=None):
     # q,k,v: [batch, heads, seq, head_dim]
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if is_causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         causal = jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
+        if window is not None:
+            # the band's lower edge: the query's own position and the
+            # window - 1 before it
+            causal &= jnp.triu(jnp.ones((s_q, s_k), bool), k=1 - window)
         logits = jnp.where(causal, logits, jnp.finfo(logits.dtype).min)
     if mask is not None:
         if not jnp.issubdtype(mask.dtype, jnp.floating):
@@ -109,10 +130,18 @@ def _placeable():
 
 
 def attention_route(*, batch, seq_q, seq_k, num_heads, head_dim, dtype,
-                    packed, masked, is_causal):
+                    packed, masked, is_causal, window=None):
     """``short`` | ``stream`` | ``xla`` for one attention call.
     ``packed`` says the caller holds the fused [batch, seq, 3*embed]
-    projection (so it is self-attention and seq_q == seq_k)."""
+    projection (so it is self-attention and seq_q == seq_k). ``window``
+    (static; causal self-attention only) changes no decision: the kernel's
+    saving grows with the keys a band leaves out, XLA's S^2 buffer does not
+    shrink with them."""
+    if window is not None and (not is_causal or seq_q != seq_k
+                               or window < 1):
+        raise ValueError(
+            f"a window ({window}) takes causal attention of queries on as "
+            f"many keys (is_causal={is_causal}, {seq_q} on {seq_k})")
     if masked or not _use_pallas():
         return "xla"
     from .pallas import flash_attention
@@ -200,13 +229,15 @@ def _on_mesh(kernel, arrays, seed, *, head_axis, seed_per_shard):
         out_specs=spec(arrays[0]), check_vma=False)(*arrays, seed)
 
 
-def _flash(q, k, v, key, *, scale, is_causal, dropout_p, interpret):
-    """The streaming kernel on [batch, heads, seq, head_dim]."""
+def _flash(q, k, v, key, *, scale, is_causal, dropout_p, interpret,
+           window=None):
+    """The streaming kernel on [batch, heads, seq, head_dim]; with a
+    ``window`` its banded calls."""
     from .pallas import flash_attention
 
     kernel = functools.partial(
         flash_attention.mha, scale=scale, causal=is_causal,
-        dropout_p=dropout_p, interpret=interpret)
+        dropout_p=dropout_p, interpret=interpret, window=window)
     return _on_mesh(kernel, (q, k, v), _kernel_seed(key), head_axis=1,
                     seed_per_shard=True)
 
@@ -229,7 +260,8 @@ def _short(qkv, key, *, num_heads, scale, dropout_p, interpret):
 
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
-                                 is_causal=False, training=True):
+                                 is_causal=False, training=True,
+                                 window=None):
     head_dim = q.shape[-1]
     scale = 1.0 / math.sqrt(head_dim)
     p = float(dropout_p) if training else 0.0
@@ -237,22 +269,28 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
         batch=q.shape[0], seq_q=q.shape[-2], seq_k=k.shape[-2],
         num_heads=q.shape[-3], head_dim=head_dim, dtype=q.dtype,
         packed=False, masked=attn_mask is not None,
-        is_causal=bool(is_causal))
+        is_causal=bool(is_causal), window=window)
     _ROUTE_TOTAL.inc(route=route)
+    # the static kwargs of a call without a window stay as they were (the
+    # per-(op, shape) dispatch cache keys on them)
+    windowed = {}
+    if window is not None and window < k.shape[-2]:
+        _WINDOW_ROUTE_TOTAL.inc(route=route)
+        windowed["window"] = int(window)
     key = random_core.next_key() if p > 0.0 else None
     if route == "stream":
         # interpret rides the static kwargs so a flag flip retraces
         return apply_op(
             "flash_attention", _flash, q, k, v, key,
             scale=scale, is_causal=bool(is_causal), dropout_p=p,
-            interpret=bool(flags.flag_value("pallas_interpret")))
+            interpret=bool(flags.flag_value("pallas_interpret")), **windowed)
 
     # the flag rides the static kwargs so the per-(op, shape) dispatch
     # cache keys on it — a flag flip must not serve a stale trace
     return apply_op(
         "sdpa", _sdpa_ref, q, k, v, attn_mask, key,
         scale=scale, dropout_p=p, is_causal=bool(is_causal),
-        fp32_softmax=bool(flags.flag_value("sdpa_softmax_fp32")))
+        fp32_softmax=bool(flags.flag_value("sdpa_softmax_fp32")), **windowed)
 
 
 def packed_self_attention(qkv, num_heads, attn_mask=None, dropout_p=0.0,

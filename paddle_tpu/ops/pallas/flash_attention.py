@@ -18,6 +18,13 @@ flash-attention online-softmax recurrence tiled for the MXU:
   lane-replicated at [block, 128] where narrow columns would waste the
   vector registers. Causal grids skip fully-masked steps and remap
   their tile index so the revisit cache elides the dead DMA.
+- a sliding window (``mha(window=)``: causal, query i on keys i - window <
+  j <= i) shrinks the grids instead of gating them: the inner dimension of
+  every call counts the blocks of ONE outer block's band (at 1,024-wide
+  blocks and a window of 2,048 three of a 16-block row's), the index maps
+  add the band's first block, and the calls carry names of their own
+  (``flash_band_*``). A gate alone would leave a 16,384-token row the
+  causal grid's 136 live steps a head (of 256) where the band holds 47.
 - the backward is ONE kernel: it recomputes each (q block, k block)
   tile's probabilities from the saved logsumexp once and takes dv, dk
   and dq from it — dk/dv into per-k-block scratch, dq into a float32
@@ -101,6 +108,14 @@ _STREAM_GRID_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
+def _clip_block(raw, num_blocks):
+    """A block index clamped into its grid: an int32 scalar inside a kernel
+    or an index map, a Python int where ``_band_steps`` sizes a grid."""
+    if isinstance(raw, int):
+        return min(max(raw, 0), num_blocks - 1)
+    return jnp.clip(raw, 0, num_blocks - 1).astype(jnp.int32)
+
+
 def _causal_last_kb(q_block, block_q, block_k, offset, num_kb):
     """Index of the LAST k block the rows of ``q_block`` attend to under
     bottom-right-aligned causal masking (row r attends cols <= r+offset),
@@ -108,14 +123,91 @@ def _causal_last_kb(q_block, block_q, block_k, offset, num_kb):
     AND the DMA index-map remaps — the two must stay bit-identical or a
     kernel computes against a tile the index map never fetched."""
     raw = (q_block * block_q + block_q - 1 + offset) // block_k
-    return jnp.clip(raw, 0, num_kb - 1).astype(jnp.int32)
+    return _clip_block(raw, num_kb)
 
 
 def _causal_first_qb(k_block, block_q, block_k, offset, num_qb):
     """Index of the FIRST q block with any unmasked row for ``k_block``
     (mirror of _causal_last_kb for the dk/dv streaming grid)."""
     raw = (k_block * block_k - offset) // block_q
-    return jnp.clip(raw, 0, num_qb - 1).astype(jnp.int32)
+    return _clip_block(raw, num_qb)
+
+
+# A sliding window of ``window`` keys (row r attends cols c with r - window
+# < c <= r; seq_q == seq_k, so no offset) gives the causal edge a lower
+# twin. The band's grids count the band's blocks alone: the inner dimension
+# of a banded call has ``_band_steps`` steps, step j of outer block i is
+# inner block ``first(i) + j``, live while it is not past ``last(i)``.
+def _band_first_kb(q_block, block_q, block_k, window, num_kb):
+    """Index of the FIRST k block any row of ``q_block`` attends to under
+    the window (lower-edge twin of _causal_last_kb; the same single source
+    for gates and index maps)."""
+    raw = (q_block * block_q - (window - 1)) // block_k
+    return _clip_block(raw, num_kb)
+
+
+def _band_last_qb(k_block, block_q, block_k, window, num_qb):
+    """Index of the LAST q block with a row that still sees ``k_block``
+    under the window (upper-edge twin of _causal_first_qb)."""
+    raw = (k_block * block_k + block_k - 1 + window - 1) // block_q
+    return _clip_block(raw, num_qb)
+
+
+def _band_steps(num_outer, first, last):
+    """The most inner blocks one outer block's band holds (static)."""
+    return max(last(i) - first(i) + 1 for i in range(num_outer))
+
+
+def band_pairs(seq, window):
+    """(query, key) pairs of one head under a causal window:
+    ``sum_i min(i + 1, window)``."""
+    w = min(window, seq)
+    return w * (w + 1) // 2 + (seq - w) * w
+
+
+def _band_index_maps(block_q, block_k, window, nqb, nkb):
+    """(k/v tile of (q block i, step j), q tile of (k block i, step j),
+    steps of a q block's band, steps of a k block's band): a step past its
+    band's last block maps to that block again, so the revisit cache
+    elides its DMA (the causal kernels' dedup, at both edges)."""
+    def first_kb(i):
+        return _band_first_kb(i, block_q, block_k, window, nkb)
+
+    def last_kb(i):
+        return _causal_last_kb(i, block_q, block_k, 0, nkb)
+
+    def first_qb(i):
+        return _causal_first_qb(i, block_q, block_k, 0, nqb)
+
+    def last_qb(i):
+        return _band_last_qb(i, block_q, block_k, window, nqb)
+
+    def kv_index(b, i, j):
+        return (b, jnp.minimum(first_kb(i) + j, last_kb(i)), 0)
+
+    def q_index(b, i, j):
+        return (b, jnp.minimum(first_qb(i) + j, last_qb(i)), 0)
+
+    return (kv_index, q_index, _band_steps(nqb, first_kb, last_kb),
+            _band_steps(nkb, first_qb, last_qb))
+
+
+def _band_cost(bh, seq, d, dv, window, itemsize, products, arrays):
+    """A banded call's cost: ``products`` (pairs x width) multiply-adds a
+    head over the band's pairs, ``arrays`` [seq, width] reads and writes."""
+    pairs = bh * band_pairs(seq, window)
+    return pl.CostEstimate(flops=2 * pairs * products,
+                           bytes_accessed=bh * seq * arrays * itemsize,
+                           transcendentals=pairs)
+
+
+def _visible(rows, cols, offset, window):
+    """The causal mask of a score tile, with the window's lower edge where
+    there is one."""
+    seen = rows + _i32(offset) >= cols
+    if window is not None:
+        seen &= cols > rows - _i32(window)
+    return seen
 
 
 def _lane_bcast(block_q, n):
@@ -129,7 +221,7 @@ def _lane_bcast(block_q, n):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, scale, causal, block_q, block_k,
-                seq_q, seq_k, offset, dropout_p, keep_thresh):
+                seq_q, seq_k, offset, dropout_p, keep_thresh, window=None):
     """Streaming-grid flash forward: grid (bh, q_blocks, k_blocks) with k
     innermost, one K/V tile per grid step (Mosaic double-buffers the tile
     DMA against compute — the full-K/V-in-VMEM design it replaces was
@@ -141,12 +233,17 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     update. MXU inputs stay in the source dtype (bf16): casting to f32
     forces multi-pass f32 MXU matmuls, measured ~8x slower; accumulation
     is f32 via preferred_element_type, and the softmax scale is applied
-    to the f32 scores rather than pre-scaling q."""
+    to the f32 scores rather than pre-scaling q.
+
+    With ``window`` the grid's inner dimension counts the band's k blocks:
+    step ``ji`` is k block ``_band_first_kb(qi) + ji``."""
     bi = _i32(pl.program_id(0))
     qi = _i32(pl.program_id(1))
-    ki = _i32(pl.program_id(2))
+    ji = _i32(pl.program_id(2))
     seed = seed_ref[0, 0].astype(jnp.uint32)
     num_kb = seq_k // block_k
+    ki = ji if window is None else ji + _band_first_kb(
+        qi, block_q, block_k, window, num_kb)
     q_start = qi * _i32(block_q)
     k_start = ki * _i32(block_k)
     bcast_k = _lane_bcast(block_q, block_k)
@@ -165,7 +262,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         last_kb = _i32(num_kb - 1)
         needed = None
 
-    @pl.when(ki == 0)
+    @pl.when(ji == 0)
     def _init():
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
@@ -183,7 +280,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         cols = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         if causal:
-            s = jnp.where(rows + _i32(offset) >= cols, s, NEG_INF)
+            s = jnp.where(_visible(rows, cols, offset, window), s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - bcast_k(m_new))
@@ -222,10 +319,14 @@ def _keep_thresh(dropout_p):
 
 
 def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
-         interpret):
+         interpret, window=None):
     bh, seq_q, d = q.shape
     seq_k, dv = k.shape[1], v.shape[2]
-    grid = (bh, seq_q // block_q, seq_k // block_k)
+    k_steps, name = seq_k // block_k, "flash_stream_fwd"
+    cost = pl.CostEstimate(
+        flops=2 * seq_q * seq_k * (d + dv),
+        bytes_accessed=((seq_q + seq_k) * d + seq_k * dv) * q.dtype.itemsize,
+        transcendentals=seq_q * seq_k)
     out_shape = (
         jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
         # lse kept 3-d with trailing dim 1: TPU block shapes must tile
@@ -236,8 +337,17 @@ def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,
         offset=seq_k - seq_q, dropout_p=dropout_p,
-        keep_thresh=_keep_thresh(dropout_p))
-    if causal:
+        keep_thresh=_keep_thresh(dropout_p), window=window)
+    if window is not None:
+        # the band alone: the inner dimension counts ITS k blocks, under a
+        # Mosaic name of its own (a trace tells a banded call from a full
+        # one); q, k read (d), v read and o written (dv)
+        kv_index, _, k_steps, _ = _band_index_maps(
+            block_q, block_k, window, seq_q // block_q, k_steps)
+        name = "flash_band_fwd"
+        cost = _band_cost(bh, seq_q, d, dv, window, q.dtype.itemsize,
+                          d + dv, 2 * d + 2 * dv)
+    elif causal:
         # skipped upper-triangle k steps map to the last NEEDED tile of
         # their q block, so Mosaic's revisit cache dedups the DMA — the
         # pl.when compute gate alone would still fetch every skipped
@@ -252,7 +362,7 @@ def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
         kv_index = lambda b, i, j: (b, j, 0)  # noqa: E731
     o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, seq_q // block_q, k_steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -270,13 +380,9 @@ def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
             pltpu.VMEM((block_q, dv), jnp.float32),      # output acc
         ],
         interpret=interpret,
-        name="flash_stream_fwd",
+        name=name,
         compiler_params=_STREAM_GRID_PARAMS,
-        cost_estimate=pl.CostEstimate(
-            flops=2 * seq_q * seq_k * (d + dv),
-            bytes_accessed=((seq_q + seq_k) * d + seq_k * dv)
-            * q.dtype.itemsize,
-            transcendentals=seq_q * seq_k),
+        cost_estimate=cost,
     )(seed, q, k, v)
     return o, lse
 
@@ -285,17 +391,21 @@ def _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, acc_ref, *, scale, causal, block_q, block_k,
-                   seq_q, seq_k, offset, dropout_p, keep_thresh):
+                   seq_q, seq_k, offset, dropout_p, keep_thresh,
+                   window=None):
     """Streaming dq: grid (bh, q_blocks, k_blocks), one K/V tile per step
     (same design as _fwd_kernel — no full-K/V VMEM residency, no seq
     cap); the dq accumulator lives in VMEM scratch across the k steps.
     Dot inputs stay in the source dtype; scale is applied to the f32
-    scores and folded into dq at the finalize step."""
+    scores and folded into dq at the finalize step. With ``window`` the
+    inner dimension counts the band's k blocks, as the forward's."""
     bi = _i32(pl.program_id(0))
     qi = _i32(pl.program_id(1))
-    ki = _i32(pl.program_id(2))
+    ji = _i32(pl.program_id(2))
     seed = seed_ref[0, 0].astype(jnp.uint32)
     num_kb = seq_k // block_k
+    ki = ji if window is None else ji + _band_first_kb(
+        qi, block_q, block_k, window, num_kb)
     q_start = qi * _i32(block_q)
     k_start = ki * _i32(block_k)
 
@@ -306,7 +416,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         last_kb = _i32(num_kb - 1)
         needed = None
 
-    @pl.when(ki == 0)
+    @pl.when(ji == 0)
     def _init():
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
@@ -324,7 +434,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         cols = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         if causal:
-            s = jnp.where(rows + _i32(offset) >= cols, s, NEG_INF)
+            s = jnp.where(_visible(rows, cols, offset, window), s, NEG_INF)
         p = jnp.exp(s - lse)                            # [bq, bk]
         if causal:
             # fully-masked rows have lse ~= NEG_INF, so exp(s - lse)
@@ -353,7 +463,8 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *rest, scale, causal, block_q, block_k,
-                    seq_q, seq_k, offset, dropout_p, keep_thresh, with_dq):
+                    seq_q, seq_k, offset, dropout_p, keep_thresh, with_dq,
+                    window=None):
     """Streaming dk/dv: grid (bh, k_blocks, q_blocks), one Q/dO tile per
     step; dk/dv accumulators in VMEM scratch. The last q block always
     attends every k block (causal or not), so the finalize write keys
@@ -368,33 +479,53 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     accumulator dtype of ``_bwd_dq_kernel``'s scratch: the same bits), and
     scaled and cast into the [seq_q, d] output block when the q block has
     met its last needed k block. The output block's index moves with the
-    (batch . head) index alone, so it is written back once a row."""
+    (batch . head) index alone, so it is written back once a row.
+
+    With ``window`` the inner dimension counts the band's q blocks: step
+    ``ji`` is q block ``_causal_first_qb(ki) + ji``, live up to
+    ``_band_last_qb(ki)``, where dk and dv are written; a q block's dq rows
+    are zeroed at the first k block of ITS band and written at the last."""
     if with_dq:
         dq_ref, dk_acc_ref, dv_acc_ref, dq_acc_ref = rest
     else:
         dk_acc_ref, dv_acc_ref = rest
     bi = _i32(pl.program_id(0))
     ki = _i32(pl.program_id(1))
-    qi = _i32(pl.program_id(2))
+    ji = _i32(pl.program_id(2))
     seed = seed_ref[0, 0].astype(jnp.uint32)
     num_qb = seq_q // block_q
+    num_kb = seq_k // block_k
+    if window is None:
+        qi, last_qb = ji, _i32(num_qb - 1)
+    else:
+        qi = ji + _causal_first_qb(ki, block_q, block_k, offset, num_qb)
+        last_qb = _band_last_qb(ki, block_q, block_k, window, num_qb)
     k_start = ki * _i32(block_k)
     q_start = qi * _i32(block_q)
     q_rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
 
-    if causal:
+    if window is not None:
+        # the band starts at the diagonal's q block: only its end gates
+        needed = qi <= last_qb
+    elif causal:
         # q blocks strictly before the diagonal see only masked rows
         needed = q_start + _i32(block_q - 1 + offset) >= k_start
     else:
         needed = None
 
-    @pl.when(qi == 0)
+    @pl.when(ji == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros(dk_acc_ref.shape, jnp.float32)
         dv_acc_ref[...] = jnp.zeros(dv_acc_ref.shape, jnp.float32)
 
     if with_dq:
-        @pl.when(ki == 0)
+        if window is None:
+            first_visit = ki == 0
+        else:
+            first_visit = needed & (ki == _band_first_kb(
+                qi, block_q, block_k, window, num_kb))
+
+        @pl.when(first_visit)
         def _init_dq():
             dq_acc_ref[q_rows, :] = jnp.zeros(
                 (block_q, dq_acc_ref.shape[-1]), jnp.float32)
@@ -413,7 +544,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         cols = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         if causal:
-            s = jnp.where(rows + _i32(offset) >= cols, s, NEG_INF)
+            s = jnp.where(_visible(rows, cols, offset, window), s, NEG_INF)
         p = jnp.exp(s - lse)
         if causal:
             # see _fwd_kernel: zero masked entries of fully-masked rows
@@ -446,17 +577,19 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         _compute()
 
-    @pl.when(qi == _i32(num_qb - 1))
+    @pl.when(qi == last_qb)
     def _finalize():
         dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
     if with_dq:
-        num_kb = seq_k // block_k
         last_kb = (_causal_last_kb(qi, block_q, block_k, offset, num_kb)
                    if causal else _i32(num_kb - 1))
+        last_visit = ki == last_kb
+        if window is not None:
+            last_visit &= needed
 
-        @pl.when(ki == last_kb)
+        @pl.when(last_visit)
         def _finalize_dq():
             dq_ref[0, q_rows, :] = (
                 dq_acc_ref[q_rows, :] * scale).astype(dq_ref.dtype)
@@ -495,7 +628,8 @@ def _one_pass_backward(seq_q, d_qk, itemsize):
             <= _ONE_PASS_SLAB_BUDGET)
 
 
-def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
+def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do,
+         window=None):
     q, k, v, o, lse, seed = res
     bh, seq_q, d = q.shape
     seq_k, dv = k.shape[1], v.shape[2]
@@ -509,8 +643,24 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
     statics = dict(scale=scale, causal=causal, block_q=block_q,
                    block_k=block_k, seq_q=seq_q, seq_k=seq_k, offset=off,
                    dropout_p=dropout_p, keep_thresh=_keep_thresh(dropout_p))
+    # the grids' inner dimensions: every block, or the band's alone
+    k_steps, q_steps, name = nkb, nqb, "flash_stream_bwd_"
+    dkv_dq_cost = dq_cost = dkv_cost = None
 
-    if causal:
+    if window is not None:
+        statics["window"] = window
+        kv_index, q_index, k_steps, q_steps = _band_index_maps(
+            block_q, block_k, window, nqb, nkb)
+        # a name of their own: a trace tells a banded call from a full one
+        name = "flash_band_bwd_"
+        cost = functools.partial(_band_cost, bh, seq_q, d, dv, window,
+                                 q.dtype.itemsize)
+        # products a pair (scores, dq, dk: d; dp, dv: dv), then arrays
+        # moved (q, k, dq, dk: d; v, dO, dv: dv) of what each call makes
+        dkv_dq_cost = cost(3 * d + 2 * dv, 4 * d + 3 * dv)
+        dq_cost = cost(2 * d + dv, 3 * d + 2 * dv)
+        dkv_cost = cost(2 * d + 2 * dv, 3 * d + 3 * dv)
+    elif causal:
         # causal DMA dedup (see _fwd): skipped steps remap to a tile the
         # revisit cache already holds
         def kv_index(b, i, j):
@@ -546,7 +696,7 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
         pairs = bh * seq_q * seq_k
         dk, dv, dq = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, with_dq=True, **statics),
-            grid=(bh, nkb, nqb),
+            grid=(bh, nkb, q_steps),
             in_specs=dkv_in_specs,
             out_specs=dkv_out_specs + [
                 pl.BlockSpec((1, seq_q, d), lambda b, i, j: (b, 0, 0))],
@@ -556,12 +706,12 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
                 pltpu.VMEM((seq_q, d), jnp.float32)],
             interpret=interpret,
             # the readers of a trace find the backward by "bwd_dkv"
-            name="flash_stream_bwd_dkv_dq",
+            name=name + "dkv_dq",
             # dq accumulates across the k blocks too
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
                 vmem_limit_bytes=_ONE_PASS_VMEM_LIMIT),
-            cost_estimate=pl.CostEstimate(
+            cost_estimate=dkv_dq_cost or pl.CostEstimate(
                 flops=2 * pairs * (3 * d + 2 * dv),
                 # q, k, v, dO read; dq, dk, dv written
                 bytes_accessed=bh * (2 * (seq_q + seq_k) * d
@@ -573,7 +723,7 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **statics),
-        grid=(bh, nqb, nkb),
+        grid=(bh, nqb, k_steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -587,36 +737,38 @@ def _bwd(scale, causal, block_q, block_k, dropout_p, interpret, res, do):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="flash_stream_bwd_dq",
+        name=name + "dq",
         compiler_params=_STREAM_GRID_PARAMS,
+        cost_estimate=dq_cost,
     )(seed, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, with_dq=False, **statics),
-        grid=(bh, nkb, nqb),
+        grid=(bh, nkb, q_steps),
         in_specs=dkv_in_specs,
         out_specs=dkv_out_specs,
         out_shape=dkv_out_shape,
         scratch_shapes=dkv_scratch,
         interpret=interpret,
-        name="flash_stream_bwd_dkv",
+        name=name + "dkv",
         compiler_params=_STREAM_GRID_PARAMS,
+        cost_estimate=dkv_cost,
     )(seed, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
-           interpret):
+           interpret, window):
     o, _ = _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
-                interpret)
+                interpret, window)
     return o
 
 
 def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
-               interpret):
+               interpret, window):
     o, lse = _fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
-                  interpret)
+                  interpret, window)
     # what only this call can make: a recomputed block keeps the two (its
     # second forward then needs no kernel call); q, k and v come back from
     # the block's projections
@@ -634,10 +786,10 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, dropout_p,
     return o, (q, k, v, o, lse, seed)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, dropout_p, interpret, res,
-               do):
+def _flash_bwd(scale, causal, block_q, block_k, dropout_p, interpret, window,
+               res, do):
     dq, dk, dv = _bwd(scale, causal, block_q, block_k, dropout_p, interpret,
-                      res, do)
+                      res, do, window)
     return dq, dk, dv, None
 
 
@@ -673,7 +825,7 @@ def _stream_block(d_qk, d_v, itemsize, dropout=False):
 
 
 def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
-        block_q=None, block_k=None, interpret=False):
+        block_q=None, block_k=None, interpret=False, window=None):
     """Flash attention. q,k: [batch, heads, seq, head_dim] (or 3-d
     [batch*heads, seq, head_dim]); v the same, or with a width of its own
     (latent attention: 192-wide keys, 128-wide values). Returns q's shape
@@ -682,6 +834,12 @@ def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
     dropout_p > 0 applies dropout to the attention probabilities inside the
     kernel (counter-based mask keyed by ``seed``, an int32 scalar array —
     pass a fresh seed per step; same seed -> same mask).
+
+    ``window`` (with ``causal``, queries and keys of one length): query i
+    attends the ``window`` keys up to its own position, i - window < j <=
+    i. The calls are banded ones (``flash_band_*``) whose grids hold the
+    band's blocks and nothing beside them; a window of the whole sequence
+    or more is the causal kernel itself.
 
     ``interpret=True`` runs the kernels (the forward and the one-pass
     backward; past ``_one_pass_backward``'s budget the backward is two) in
@@ -692,6 +850,12 @@ def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
         q, k, v = q[None], k[None], v[None]
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
+    if window is not None:
+        if not causal or sq != sk or window < 1:
+            raise ValueError(
+                f"a window ({window}) takes causal attention of queries on "
+                f"as many keys (causal={causal}, {sq} on {sk})")
+        window = None if window >= sk else int(window)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     want = _stream_block(d, dv, q.dtype.itemsize, dropout_p > 0.0)
@@ -704,7 +868,7 @@ def mha(q, k, v, *, scale=None, causal=False, dropout_p=0.0, seed=None,
         seed = jnp.zeros((), jnp.int32)
     seed2d = jnp.asarray(seed, jnp.int32).reshape(1, 1)
     o = _flash(q3, k3, v3, seed2d, float(scale), bool(causal), bq, bk,
-               float(dropout_p), bool(interpret))
+               float(dropout_p), bool(interpret), window)
     o = o.reshape(b, h, sq, dv)
     return o[0] if squeeze else o
 

@@ -1564,3 +1564,234 @@ def qwen3_next_layer_types(num_hidden_layers, full_attention_interval=4):
     the interval, else ``linear_attention`` (HF ``layer_types``' default)."""
     return ["full_attention" if (i + 1) % full_attention_interval == 0
             else "linear_attention" for i in range(num_hidden_layers)]
+
+
+# ------------------------------------------------------------ Trinity (AFMoE)
+def _afmoe_heads(q, k, v, w_q, w_k, *, heads, kv_heads, d, eps, base, rope):
+    """The projected streams -> (query [B, H, T, d], key and value on their
+    own ``kv_heads``): query and key pass an RMSNorm over a head's d
+    features (weight from 1) and, where ``rope``, rotate-half RoPE over all
+    of them, in float32; a layer without positions rotates nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    def split(q, k, v, w_q, w_k):
+        b, t, _ = q.shape
+
+        def normed(x, w, n):
+            x = _rms_norm_f32(x.reshape(b, t, n, d).astype(jnp.float32), w,
+                              eps=eps, zero_centered=False)
+            x = x.transpose(0, 2, 1, 3)
+            if rope:
+                x = _rope(x, base, pairing="half")
+            return x.astype(q.dtype)
+
+        return (normed(q, w_q, heads), normed(k, w_k, kv_heads),
+                v.reshape(b, t, kv_heads, d).transpose(0, 2, 1, 3))
+
+    return jax.checkpoint(split)(q, k, v, w_q, w_k)
+
+
+class AfmoeAttention(nn.Layer):
+    """Trinity's (HF ``afmoe``) attention by layer type. Both types: grouped
+    queries at a head width of its own (``head_dim``), an RMSNorm over each
+    head's features of q and of k, causal softmax at ``head_dim ** -0.5``
+    with query head h on key/value head ``h // (heads / kv_heads)``, the
+    core's output times ``sigmoid(gate_proj(x))`` — a matrix of its own —
+    then ``o_proj``; no bias. A ``sliding_attention`` layer rotates q and k
+    (rotate-half RoPE over all features) and sees the ``sliding_window``
+    keys up to the query's own position; a ``full_attention`` layer sees
+    every earlier key and rotates NOTHING (no positions). The core goes
+    through the dispatching sdpa with the layer's ``window`` (the banded
+    streaming kernel at long sequences), K and V REPEATED to the query
+    heads first (ROADMAP Speed 13). Scopes, so that a trace tells the two
+    types apart: ``swa.`` (sliding) or ``gattn.`` (full) + ``proj`` (q, k,
+    v and the gate) / ``qk`` (norms, RoPE, the head split) / ``repeat`` /
+    ``core`` / ``out`` (gate, merge, ``o_proj``)."""
+
+    def __init__(self, hidden_size, layer_type, num_heads=32, num_kv_heads=4,
+                 head_dim=128, sliding_window=2048, rope_theta=10000.0,
+                 rms_norm_eps=1e-5, weight_attr=None):
+        super().__init__()
+        if layer_type not in ("sliding_attention", "full_attention"):
+            raise ValueError(f"layer_type {layer_type!r} is neither "
+                             "'sliding_attention' nor 'full_attention'")
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads are no multiple of "
+                             f"{num_kv_heads} key/value heads")
+        self.sliding = layer_type == "sliding_attention"
+        self.window = int(sliding_window) if self.sliding else None
+        self.scope = "swa" if self.sliding else "gattn"
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.rope_theta = head_dim, float(rope_theta)
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.q_proj = proj(hidden_size, num_heads * head_dim)
+        self.k_proj = proj(hidden_size, num_kv_heads * head_dim)
+        self.v_proj = proj(hidden_size, num_kv_heads * head_dim)
+        self.gate_proj = proj(hidden_size, num_heads * head_dim)
+        self.o_proj = proj(num_heads * head_dim, hidden_size)
+        self.q_norm = ZeroCenteredRMSNorm(head_dim, eps=rms_norm_eps,
+                                          zero_centered=False)
+        self.k_norm = ZeroCenteredRMSNorm(head_dim, eps=rms_norm_eps,
+                                          zero_centered=False)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops.attention import scaled_dot_product_attention as _sdpa
+
+        scope = self.scope
+        with jax.named_scope(scope + ".proj"):
+            q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
+            gate = self.gate_proj(x)
+        with jax.named_scope(scope + ".qk"):
+            q, k, v = apply_op(
+                "afmoe_heads", _afmoe_heads, q, k, v, self.q_norm.weight,
+                self.k_norm.weight, heads=self.num_heads,
+                kv_heads=self.num_kv_heads, d=self.head_dim,
+                eps=self.q_norm.eps, base=self.rope_theta,
+                rope=self.sliding)
+        with jax.named_scope(scope + ".repeat"):
+            k, v = (apply_op("repeat_heads", _repeat_heads, t,
+                             repeats=self.num_heads // self.num_kv_heads,
+                             axis=1) for t in (k, v))
+        with jax.named_scope(scope + ".core"):
+            o = _sdpa(q, k, v, is_causal=True, training=self.training,
+                      window=self.window)
+        with jax.named_scope(scope + ".out"):
+            return self.o_proj(apply_op("gqa_gated_merge", _gqa_gated_merge,
+                                        o, gate))
+
+
+class AfmoeDecoderLayer(nn.Layer):
+    """Trinity's block, a norm before AND after each sublayer (four
+    RMSNorms, weight from 1, float32): ``a = h + post_attention_layernorm(
+    Attn(input_layernorm(h)))``, ``h' = a + post_mlp_layernorm(MLP(
+    pre_mlp_layernorm(a)))``. The MLP is a dense SwiGLU (the leading
+    layers) or the expert layer: a sigmoid router with a selection bias
+    moved without an auxiliary loss, the chosen scores renormalised times
+    ``route_scale``, a shared expert, and the held range of the routed
+    experts."""
+
+    def __init__(self, cfg, layer_type, dense, weight_attr=None):
+        super().__init__()
+        from ..incubate.moe import MoELayer
+
+        hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+
+        def norm():
+            return ZeroCenteredRMSNorm(hidden, eps=eps, zero_centered=False)
+
+        self.input_layernorm = norm()
+        self.self_attn = AfmoeAttention(
+            hidden, layer_type, rms_norm_eps=eps, weight_attr=weight_attr,
+            **cfg["attention"])
+        self.post_attention_layernorm = norm()
+        self.pre_mlp_layernorm = norm()
+        if dense:
+            self.mlp = LlamaMLP(hidden, cfg["intermediate_size"],
+                                weight_attr)
+        else:
+            self.mlp = MoELayer(
+                hidden, cfg["moe_intermediate_size"], cfg["num_experts"],
+                top_k=cfg["num_experts_per_tok"], activation="swiglu",
+                gate_bias=False, norm_topk_prob=cfg["route_norm"],
+                scoring="sigmoid", select_bias=True,
+                bias_update_speed=cfg["load_balance_coeff"],
+                routed_scale=cfg["route_scale"],
+                shared_width=cfg["num_shared_experts"]
+                * cfg["moe_intermediate_size"],
+                held=cfg["held_experts"],
+                held_rows_factor=cfg["held_rows_factor"], aux_weight=0.0,
+                weight_attr=weight_attr)
+        self.post_mlp_layernorm = norm()
+
+    def forward(self, x):
+        x = x + self.post_attention_layernorm(
+            self.self_attn(self.input_layernorm(x)))
+        return x + self.post_mlp_layernorm(
+            self.mlp(self.pre_mlp_layernorm(x)))
+
+
+class AfmoeModel(_BlockwiseModel):
+    """Trinity-Mini (arcee-ai, HF ``afmoe``): sandwich-norm blocks whose
+    attention goes by ``layer_types`` — a sliding window of
+    ``sliding_window`` keys with RoPE, or full attention without positions
+    on every ``global_attn_every_n_layers``-th layer —, the first
+    ``num_dense_layers`` with a dense SwiGLU and the others with the expert
+    layer; the embedding times ``sqrt(hidden_size)`` (``mup_enabled``); a
+    final norm and an untied head. Defaults are the published sizes.
+
+    ``held_experts=(first, count)`` gives every expert layer this chip's
+    range of the routed experts; ``use_recompute`` runs each block under
+    ``fleet.utils.recompute`` in a traced step. ``forward`` gives the
+    logits; a training loss takes ``features`` and ``lm_head.weight`` to
+    ``F.linear_cross_entropy``."""
+
+    def __init__(self, vocab_size=200192, hidden_size=2048,
+                 num_hidden_layers=32, num_attention_heads=32,
+                 num_key_value_heads=4, head_dim=128, intermediate_size=6144,
+                 moe_intermediate_size=1024, num_experts=128,
+                 num_experts_per_tok=8, num_shared_experts=1,
+                 num_dense_layers=2, layer_types=None,
+                 global_attn_every_n_layers=4, sliding_window=2048,
+                 rope_theta=10000.0, rms_norm_eps=1e-5, route_norm=True,
+                 route_scale=2.826, load_balance_coeff=0.001,
+                 mup_enabled=True, initializer_range=0.02, held_experts=None,
+                 held_rows_factor=2.0, use_recompute=False):
+        super().__init__(use_recompute)
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        cfg = dict(
+            hidden_size=hidden_size, rms_norm_eps=rms_norm_eps,
+            intermediate_size=intermediate_size,
+            moe_intermediate_size=moe_intermediate_size,
+            num_experts=num_experts, num_experts_per_tok=num_experts_per_tok,
+            num_shared_experts=num_shared_experts, route_norm=route_norm,
+            route_scale=route_scale, load_balance_coeff=load_balance_coeff,
+            held_experts=None if held_experts is None else tuple(held_experts),
+            held_rows_factor=held_rows_factor,
+            attention=dict(num_heads=num_attention_heads,
+                           num_kv_heads=num_key_value_heads,
+                           head_dim=head_dim, sliding_window=sliding_window,
+                           rope_theta=rope_theta))
+        self.layer_types = list(layer_types or afmoe_layer_types(
+            num_hidden_layers, global_attn_every_n_layers))
+        if len(self.layer_types) != num_hidden_layers:
+            raise ValueError(f"{len(self.layer_types)} layer types for "
+                             f"{num_hidden_layers} layers")
+        self.embed_scale = math.sqrt(hidden_size) if mup_enabled else 1.0
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            AfmoeDecoderLayer(cfg, layer_type, dense=i < num_dense_layers,
+                              weight_attr=attr())
+            for i, layer_type in enumerate(self.layer_types)])
+        self.norm = ZeroCenteredRMSNorm(hidden_size, eps=rms_norm_eps,
+                                        zero_centered=False)
+        self.lm_head = nn.Linear(hidden_size, vocab_size,
+                                 weight_attr=attr(), bias_attr=False)
+
+    def features(self, input_ids):
+        x = self.embed_tokens(input_ids) * self.embed_scale
+        for layer in self.layers:
+            x = self._block(layer, x)
+        return self.norm(x)
+
+    def forward(self, input_ids):
+        return self.lm_head(self.features(input_ids))
+
+
+def afmoe_layer_types(num_hidden_layers, global_attn_every_n_layers=4):
+    """Layer i (from 0) is ``full_attention`` where (i + 1) is a multiple of
+    the interval, else ``sliding_attention`` (the published ``layer_types``)."""
+    return ["full_attention" if (i + 1) % global_attn_every_n_layers == 0
+            else "sliding_attention" for i in range(num_hidden_layers)]

@@ -78,6 +78,21 @@ STREAM_TWO_CALL_CASES = {
 STREAM_TWO_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dq",
                     "flash_stream_bwd_dkv")
 ALL_STREAM_CASES = {**STREAM_CASES, **STREAM_TWO_CALL_CASES}
+#: the same kernel under a sliding window (batch, heads, seq, head_dim,
+#: dtype, window): its BANDED calls at the Trinity-Mini cell's sliding
+#: layers (32 query heads of 128, 2,048 of 16,384 keys) in bf16 and in its
+#: check's float32 — the one-pass backward's slab beside 1,024-wide blocks
+#: — and a row past the slab's budget (the two-call backward). The calls
+#: carry names of their own (the benchmark's swa_flash_roofline finds them
+#: by ``flash_band_``), and their grids' inner dimension is the band's
+BAND_CASES = {
+    "trinity-cell-bf16": (1, 32, 16384, 128, "bfloat16", 2048),
+    "trinity-check-f32": (1, 32, 16384, 128, "float32", 2048),
+    "long-s65536-bf16": (1, 2, 65536, 128, "bfloat16", 2048),
+}
+BAND_CALLS = ("flash_band_fwd", "flash_band_bwd_dkv_dq")
+BAND_TWO_CALLS = ("flash_band_fwd", "flash_band_bwd_dq",
+                  "flash_band_bwd_dkv")
 #: a latent-attention block under ``fleet.utils.recompute``, forward +
 #: backward (hidden, heads, q rank, kv rank, nope, rope, value width, seq,
 #: dtype, rotated): the JoyAI cell's in its check's float32 and the
@@ -237,6 +252,22 @@ def _child():
                 f"[{batch * heads},{seq},{d_v}]"),
             "key_wide_results": text.count(
                 f"[{batch * heads},{seq},{d_qk}]")}
+
+    for name, (batch, heads, seq, head_dim, dtype, window) in (
+            BAND_CASES.items()):
+        qkv = jax.ShapeDtypeStruct((batch, heads, seq, head_dim), dtype,
+                                   sharding=one)
+        text = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(fa.mha(
+                q, k, v, causal=True, window=window,
+                seed=jnp.zeros((), jnp.int32)).astype(jnp.float32)),
+            argnums=(0, 1, 2))).lower(qkv, qkv, qkv).compile().as_text()
+        out["band-" + name] = {
+            "mosaic": text.count(MOSAIC),
+            "calls": [c for c in dict.fromkeys(BAND_CALLS + BAND_TWO_CALLS)
+                      if f"({c})" in text],
+            "full_calls": "flash_stream_" in text,
+            "scores_in_hbm": f"{seq},{seq}]" in text}
 
     # what the kernel's forward rule does for a recomputed block
     # (ops/residuals.py: the tags, the log-sum-exp's reshape between two
@@ -597,6 +628,20 @@ def test_stream_kernel_compiles(compiled, case):
         # keys and values keep their own widths through every call:
         # nothing is padded to the other's
         assert got["value_wide_results"] and got["key_wide_results"]
+
+
+@pytest.mark.parametrize("case", list(BAND_CASES))
+def test_banded_stream_kernel_compiles(compiled, case):
+    """The streaming kernel's banded calls (``mha(window=2048)``) at the
+    Trinity-Mini cell's b1 h32 s16384 d128, in bf16 and the check's
+    float32: the forward and the one-pass backward under ``flash_band_*``
+    within the VMEM the backward asks for at 1,024-wide blocks, no
+    full-causal call beside them and no [seq, seq] scores in HBM; a row of
+    dq past the slab's budget compiles the banded two-call backward."""
+    got = compiled["band-" + case]
+    calls = BAND_TWO_CALLS if case.startswith("long") else BAND_CALLS
+    assert got["mosaic"] == len(calls) and got["calls"] == list(calls)
+    assert not got["full_calls"] and not got["scores_in_hbm"]
 
 
 def test_an_offer_nothing_keeps_compiles_to_nothing(compiled):
