@@ -45,13 +45,22 @@ experts and whether the program's mesh shards them:
   one chip's share of an expert-parallel deployment, without the exchange.
   The router scores and picks over ALL experts; only the (token, choice)
   pairs whose expert is one of the ``count`` held from ``first`` on are
-  sorted, gathered and multiplied, into a row buffer of
+  placed, gathered and multiplied, into a row buffer of
   ``held_rows_factor`` times the mean number of such pairs (rounded up to
   the row tile); the layer returns the held experts' part of the sum
-  (plus the shared expert). It is exact whenever the pairs fit, and the
-  pairs that do not are dropped AND counted in the ``held_overflow`` buffer,
-  which leaves a train step with the other buffers. Nothing stands in for
-  the absent experts or their traffic.
+  (plus the shared expert). Whatever is as wide as the hidden size is done
+  on the computed rows: ``rows`` rows of ``x`` are gathered into expert
+  order, and the rows are summed into tokens (the weighted sum over a
+  token's choices, and the dispatch gather's gradient) by putting them in
+  token order, adding each to its same-token neighbours and gathering N —
+  ``rows`` + N gathered rows, in float32, where the N*k pairs would be
+  4 to 11 times the rows. The row bound is the reference's
+  (``benchmark/references/*.py experts()``): held pairs in (expert, token,
+  choice) order; a pair whose rank reaches ``rows`` is dropped. It is
+  exact whenever the pairs fit, and the pairs that do not are dropped AND
+  counted in the ``held_overflow`` buffer, which leaves a train step with
+  the other buffers. Nothing stands in for the absent experts or their
+  traffic.
 
 The layer's arithmetic is the constructor's: ``activation`` ('gelu': two
 matrices; 'swiglu': ``w_down(silu(x w_gate) * (x w_up))``), ``gate_bias``,
@@ -312,7 +321,7 @@ def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel="xla"):
 def _combine(ys, topv, order, inv, *, shape, held=False):
     """Weight each pair's output, un-sort, sum a token's k choices (f32).
     ``held``: ys are a held share's rows (``order`` the pair computed in
-    each, ``inv`` each pair's row or the zero row past them)."""
+    each, ``inv`` each pair's row, ``rows`` where it has none)."""
     with jax.named_scope("moe.combine"):
         n, k = topv.shape
         if held:
@@ -328,12 +337,113 @@ def _combine(ys, topv, order, inv, *, shape, held=False):
 # ------------------------------------------------------------ held share
 # One chip's share of the experts (expert parallelism without the
 # exchange): the router scores and picks over ALL experts, and only the
-# (token, choice) pairs whose expert lives here are sorted, gathered and
+# (token, choice) pairs whose expert lives here are placed, gathered and
 # multiplied. How many pairs land here depends on the data, so they go
-# into a buffer of ``rows`` rows, a stated factor over the mean: pairs
-# beyond it are dropped and COUNTED (``held_overflow``), never silently.
-# Row ``rows`` of the padded arrays is a zero row that every pair not
-# computed here points at, so both directions stay gathers.
+# into a buffer of ``rows`` rows, a stated factor over the mean. The row
+# bound, in the reference's words (``benchmark/references/*.py experts()``):
+# held pairs in (expert, token, choice) order; a pair whose rank reaches
+# ``rows`` is dropped — and COUNTED (``held_overflow``), never silently.
+# Everything as wide as the hidden size is done on the ``rows`` computed
+# rows: the path gathers ``rows`` rows into expert order, and sums rows
+# into tokens (``_rows_to_tokens``) by gathering ``rows`` + N — no array
+# of N*k rows exists, forward or backward, where 1/8 to 1/32 of the pairs
+# are held. The index work and the shifted sum are jitted on their own:
+# a step traces each three times a layer (forward, recomputed forward,
+# backward), a start traces several steps, and an inner jit's trace is
+# made once a shape.
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _token_major(taken, inv, k):
+    """The computed pairs in (token, choice) order, from ``taken`` [rows]
+    and ``inv`` [N*k] as ``_held_experts`` gives them: ``row_at`` [rows]
+    (the row of the c-th such pair; a token's pairs are adjacent, at most
+    k of them), ``token_at`` [rows] (its token, N past the last pair),
+    ``first`` [N] (where a token's pairs start) and ``pairs`` [N] (how
+    many it has here). Index work on N*k integers and a sort of ``rows``
+    of them; nothing is as wide as the hidden size."""
+    rows = taken.shape[0]
+    n = inv.shape[0] // k
+    kept = (inv < rows).astype(jnp.int32).reshape(n, k)
+    pairs = jnp.sum(kept, axis=1)
+    first = jnp.cumsum(pairs) - pairs
+    place = first[:, None] + jnp.cumsum(kept, axis=1) - kept   # [N, k]
+    row = jnp.arange(rows, dtype=jnp.int32)
+    filled = row < jnp.sum(pairs)
+    # the filled rows are a prefix in both orders, so the rest keep their
+    # place and the whole is a permutation of the rows
+    place = jnp.where(filled, place.reshape(-1)[taken], row)
+    # its inverse by a sort: 0.02 ms for 32,768 places on the v5e where a
+    # scatter of them takes 0.62 (PERF.md section 6, PR 41)
+    row_at = jnp.argsort(place).astype(jnp.int32)
+    token_at = jnp.where(filled, (taken // k)[row_at], n)
+    return row_at, token_at, first, pairs
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _same_token_sums(vals, weights, row_at, token_at, k):
+    """[rows, H] float32 whose row c is the sum of ``vals[row_at[c + d]]``
+    (times ``weights[row_at[c + d]]``) over the d < k with ``token_at[c +
+    d] == token_at[c]``: k static shifts of the rows in token order, the
+    products and the sum in float32."""
+    rows = row_at.shape[0]
+    # k - 1 rows past the end, of no token, for the shifted views to read
+    tail = jnp.zeros((k - 1,), jnp.int32)
+    row_at = jnp.concatenate([row_at, tail])
+    token_at = jnp.concatenate([token_at, tail - 1])
+    ordered = vals[row_at]
+    if weights is not None:
+        weights = weights.astype(jnp.float32)[row_at]
+    total = 0.0
+    for shift in range(k):
+        # the row itself, then the rows after it while the token is the same
+        view = ordered[shift:shift + rows].astype(jnp.float32)
+        if weights is not None:
+            view = view * weights[shift:shift + rows, None]
+        same = token_at[shift:shift + rows] == token_at[:rows]
+        total = total + jnp.where(same[:, None], view, 0.0)
+    return total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_to_tokens(vals, weights, taken, inv, k):
+    """vals [rows, H] (row i belongs to pair ``taken[i]``) and each row's
+    weight [rows] (None: 1) -> [N, H] in float32: every token's sum over
+    its pairs computed here, zero for a token with none. Gathers only: the
+    rows are put in (token, choice) order, each is summed in float32 with
+    the up to k - 1 rows after it that belong to the same token
+    (``_same_token_sums``), and N rows are gathered, a token's first.
+    ``rows`` + N gathered rows where the pairs in token-major order would
+    be N*k, most of them the zero row. The gradient is the row gather
+    ``g[taken // k]`` (times the weight; a weight's is its row's dot with
+    it), so a backward pass holds [rows, H] too."""
+    rows = taken.shape[0]
+    row_at, token_at, first, pairs = _token_major(taken, inv, k)
+    total = _same_token_sums(vals, weights, row_at, token_at, k)
+    return jnp.where((pairs > 0)[:, None],
+                     total[jnp.minimum(first, rows - 1)], 0.0)
+
+
+def _rows_to_tokens_fwd(vals, weights, taken, inv, k):
+    return (_rows_to_tokens(vals, weights, taken, inv, k),
+            (vals, weights, taken, inv))
+
+
+def _rows_to_tokens_bwd(k, res, g):
+    vals, weights, taken, inv = res
+    rows = taken.shape[0]
+    filled = jnp.arange(rows) < jnp.sum(inv < rows)
+    g_rows = jnp.where(filled[:, None], g[taken // k].astype(jnp.float32),
+                       0.0)
+    if weights is None:
+        return g_rows.astype(vals.dtype), None, None, None
+    d_vals = weights.astype(jnp.float32)[:, None] * g_rows
+    d_weights = jnp.sum(vals.astype(jnp.float32) * g_rows, axis=-1)
+    return (d_vals.astype(vals.dtype), d_weights.astype(weights.dtype),
+            None, None)
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _rows_to_held_order(x, taken, inv, k):
@@ -342,56 +452,24 @@ def _rows_to_held_order(x, taken, inv, k):
 
 
 def _rows_to_held_order_fwd(x, taken, inv, k):
-    return x[taken // k], inv
+    return x[taken // k], (taken, inv)
 
 
-def _rows_to_held_order_bwd(k, inv, g):
-    padded = jnp.concatenate([g, jnp.zeros_like(g[:1])])
-    by_token = padded[inv].reshape(inv.shape[0] // k, k, g.shape[-1])
-    return (jnp.sum(by_token.astype(jnp.float32), axis=1).astype(g.dtype),
+def _rows_to_held_order_bwd(k, res, g):
+    taken, inv = res
+    return (_rows_to_tokens(g, None, taken, inv, k).astype(g.dtype),
             None, None)
 
 
 _rows_to_held_order.defvjp(_rows_to_held_order_fwd, _rows_to_held_order_bwd)
 
 
-@jax.custom_vjp
 def _held_weighted_sum(ys, topv, taken, inv):
     """ys [rows, H] and the weights [N, k] -> each token's weighted sum over
-    its k choices [N, H] in float32: the pairs gathered from the rows in
-    token-major order, zero at the pairs that were not computed here. The
-    gradient is taken from the ROWS — each row's weight times its token's
-    cotangent, each pair's weight gradient from its row — so a backward
-    pass holds [rows, H] where differentiating the gather would hold
-    [N, k, H] in float32 (1.2 GB at 16,384 tokens of 2,304, for 8,192
-    rows) and keep the gathered pairs for it."""
-    n, k = topv.shape
-    by_token = jnp.concatenate([ys, jnp.zeros_like(ys[:1])])[inv].reshape(
-        n, k, -1)
-    return jnp.einsum("nkh,nk->nh", by_token.astype(jnp.float32),
-                      topv.astype(jnp.float32))
-
-
-def _held_weighted_sum_fwd(ys, topv, taken, inv):
-    return _held_weighted_sum(ys, topv, taken, inv), (ys, topv, taken, inv)
-
-
-def _held_weighted_sum_bwd(res, g):
-    ys, topv, taken, inv = res
-    n, k = topv.shape
-    here = inv[taken] < taken.shape[0]
-    g_rows = g[taken // k].astype(jnp.float32)
-    weights = topv.reshape(-1)[taken].astype(jnp.float32)
-    d_ys = jnp.where(here[:, None], weights[:, None] * g_rows, 0.0)
-    d_weights = jnp.where(here, jnp.sum(ys.astype(jnp.float32) * g_rows,
-                                        axis=-1), 0.0)
-    d_topv = jnp.zeros((n * k,), jnp.float32).at[taken].set(
-        d_weights, unique_indices=True)
-    return (d_ys.astype(ys.dtype), d_topv.reshape(n, k).astype(topv.dtype),
-            None, None)
-
-
-_held_weighted_sum.defvjp(_held_weighted_sum_fwd, _held_weighted_sum_bwd)
+    its choices computed here [N, H] in float32: each row times its pair's
+    weight, summed into its token (``_rows_to_tokens``)."""
+    return _rows_to_tokens(ys, topv.reshape(-1)[taken], taken, inv,
+                           topv.shape[1])
 
 
 def held_rows(tokens, top_k, count, num_experts, factor, tile=512):
@@ -403,32 +481,52 @@ def held_rows(tokens, top_k, count, num_experts, factor, tile=512):
     return min(rows, tokens * top_k)
 
 
+@functools.partial(jax.jit, static_argnames=("first", "count", "rows"))
+def _held_places(topi, *, first, count, rows):
+    """Where each pair goes: the expert ids [N, k] over ALL experts ->
+    ``taken`` [rows], ``inv`` [N*k], the grouped matmul's ``group_sizes``
+    [count] and the overflow, as ``_held_experts`` returns them. A held
+    pair's place in (expert, token, choice) order is where its expert's
+    rows start plus the pairs of that expert before it: prefix sums over
+    the [N, count] counts give ``inv`` with no scatter, and a sort of
+    ``inv`` gives ``taken``."""
+    n, k = topi.shape
+    here = jax.nn.one_hot(topi - first, count, dtype=jnp.int32)  # [N, k, c]
+    by_token = jnp.sum(here, axis=1)
+    sizes = jnp.sum(by_token, axis=0)
+    overflow = jnp.maximum(jnp.sum(sizes) - rows, 0)
+    # every row belongs to a group: the last one takes the rows that no
+    # held pair fills (their output meets no weight: ``inv`` never points
+    # at them)
+    ends = jnp.minimum(jnp.cumsum(sizes), rows).at[-1].set(rows)
+    group_sizes = jnp.diff(ends, prepend=0)
+    before = (jnp.cumsum(sizes) - sizes
+              + jnp.cumsum(by_token, axis=0) - by_token)         # [N, c]
+    place = []
+    for choice in range(k):   # a token's earlier choices of the same expert
+        place.append(jnp.sum(here[:, choice] * before, axis=-1))
+        before = before + here[:, choice]
+    place = jnp.stack(place, axis=1)
+    held = jnp.sum(here, axis=-1) > 0
+    inv = jnp.where(held & (place < rows), place, rows).reshape(-1)
+    taken = jnp.argsort(inv)[:rows].astype(jnp.int32)
+    return taken, inv, group_sizes, overflow
+
+
 def _held_experts(x, topi, w_gate, w_up, w_down, *, first, rows,
                   kernel="xla"):
     """Dispatch and experts of a held share: [B, S, H] and the expert ids
     [N, k] over ALL experts -> the outputs [rows, H] of the pairs whose
     expert is one of the ``w_up.shape[0]`` held from ``first`` on, in
-    expert order; ``taken`` [rows] (the pair computed in each row), ``inv``
-    [N*k] (each pair's row, ``rows`` where it was not computed here) and
-    the number of held pairs that did not fit (exact whenever it is 0)."""
+    (expert, token, choice) order; ``taken`` [rows] (the pair computed in
+    each row; a pair that is not computed here in a row that no held pair
+    fills), ``inv`` [N*k] (each pair's row, ``rows`` where it was not
+    computed here) and the number of held pairs that did not fit (exact
+    whenever it is 0)."""
     n, k = topi.shape
-    count = w_up.shape[0]
     with jax.named_scope("moe.dispatch"):
-        local = topi.reshape(-1) - first
-        held = (local >= 0) & (local < count)
-        group = jnp.where(held, local, count)       # absent experts sort last
-        order = jnp.argsort(group).astype(jnp.int32)
-        position = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
-        sizes = jnp.sum(jax.nn.one_hot(group, count, dtype=jnp.int32), axis=0)
-        overflow = jnp.maximum(jnp.sum(sizes) - rows, 0)
-        # every row belongs to a group: the last one takes the rows that no
-        # held pair fills (pairs of absent experts; their output meets no
-        # weight: ``inv`` never points at them)
-        ends = jnp.minimum(jnp.cumsum(sizes), rows).at[-1].set(rows)
-        group_sizes = jnp.diff(ends, prepend=0)
-        taken = order[:rows]
-        inv = jnp.where(held & (position < rows), position, rows)
+        taken, inv, group_sizes, overflow = _held_places(
+            topi, first=first, count=w_up.shape[0], rows=rows)
         xs = _rows_to_held_order(x.reshape(n, x.shape[-1]), taken, inv, k)
     ys = _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel)
     return ys, taken, inv, overflow
