@@ -87,6 +87,14 @@ its element-wise stages on them never makes the head view (``head_sums`` /
 used: ``streams`` or ``heads`` — ``heads`` on the ``kernel`` path is a
 layer that still pays the relayouts. ``chunked`` and ``recurrent`` reshape
 streams to heads inside (free where they run).
+
+**The convolution stage** before the scan — each stream's causal depthwise
+convolution and SiLU, q's and k's L2 norm a head — is ``conv_streams``, by
+the same rule: ``conv_path`` gives ``kernel`` (one Mosaic call a pass on
+the streams, its float32 in VMEM; ``ops/pallas/linear_attention.py``) or
+``xla`` (this file's ``_conv_xla``: float32 arrays under a
+``jax.checkpoint``, what every other program runs and what the kernels are
+held to), counted in ``paddle_tpu_conv_streams_total{path}``.
 """
 import functools
 
@@ -110,6 +118,13 @@ _ENTRY_TOTAL = obs_metrics.counter(
     "reshaped by the op: a relayout on the kernel path); under jit one "
     "count per traced layer call",
     labelnames=("path", "entry"))
+
+_CONV_TOTAL = obs_metrics.counter(
+    "paddle_tpu_conv_streams_total",
+    "linear attention's convolution stages (taps, SiLU, q's and k's L2 "
+    "norm) by the path taken: kernel (one Mosaic call a pass) | xla; one "
+    "count per traced layer call",
+    labelnames=("path",))
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 #: tokens in a diagonal sub-block, whose pair terms are computed exactly
@@ -155,6 +170,14 @@ def head_rsqrt(x, heads, *, eps, mean):
     squares = head_sums(x * x, heads)
     return on_head_lanes(
         jax.lax.rsqrt((squares / d if mean else squares) + eps), d)
+
+
+def l2_normed(x, heads, *, eps, scale):
+    """x [.., H * d] L2-normalised over each head's d features in float32,
+    times ``scale``, in x's dtype."""
+    xf = x.astype(jnp.float32)
+    return (xf * (head_rsqrt(xf, heads, eps=eps, mean=False) * scale)).astype(
+        x.dtype)
 
 
 def kda_recurrent(q, k, v, g, beta, initial_state=None):
@@ -541,3 +564,105 @@ def _kernel_output(q, k, v, g, beta, *, interpret):
     return attention._on_mesh(kernel, (q, k, v, g, beta),
                               jnp.zeros((), jnp.int32), head_axis=2,
                               seed_per_shard=False)
+
+
+# ------------------------------------------------- the convolution stage
+def conv_path(seq, segments, head, taps, dtype):
+    """``kernel`` | ``xla`` for the convolution stage of a row of ``seq``
+    tokens whose ``segments`` (``(stream, start, width, scale)``, as
+    ``conv_streams`` takes them) have heads ``head`` wide and ``taps`` taps
+    in ``dtype``, from what can be observed: the Mosaic kernels where the
+    platform compiles them (a TPU, or the ``pallas_interpret`` flag), the
+    program's devices are known (``ops.attention._placeable``), the heads
+    and every segment's channels fill whole lane groups, the history fits
+    the rows the kernels carry, the streams are bf16 or float32 and the row
+    is at least one token block; under an announced mesh with an ``mp``
+    axis, where that axis cuts every stream between whole heads of ONE
+    segment. The XLA stage everything else."""
+    from ..distributed import topology
+    from . import attention
+    from .pallas import linear_attention as kernels
+
+    if not (seq >= kernels.CONV_TOKENS
+            and kernels.conv_supported(segments, head, taps, dtype)
+            and attention._use_pallas() and attention._placeable()):
+        return "xla"
+    mesh = topology.traced_mesh()
+    mp = 1 if mesh is None else mesh.shape.get("mp", 1)
+    streams = [stream for stream, *_ in segments]
+    if mp > 1 and not (len(set(streams)) == len(streams) and all(
+            width % (mp * head) == 0 for _, _, width, _ in segments)):
+        return "xla"
+    return "kernel"
+
+
+def conv_streams(xs, ws, segments, *, head, eps, interpret=None):
+    """Linear attention's stage between the projections and the scan, on
+    arrays: streams ``xs`` [B, T, C_i] with their taps ``ws`` [K, C_i] ->
+    one [B, T, width] array a segment ``(stream, start, width, scale)``:
+    ``width`` channels of ``xs[stream]`` from ``start`` on through the
+    causal depthwise convolution (float32 multiply-adds, zero history) and
+    SiLU, then, where ``scale`` is a number, L2-normalised over each
+    ``head`` features in float32 and scaled; where it is None, as SiLU left
+    them. The segments cover every stream in order. Results take their
+    stream's dtype. Counts the path (``conv_path``) once a trace.
+    ``interpret``: the ``pallas_interpret`` flag unless given (a caller
+    under ``apply_op`` passes it, so that a flag flip retraces)."""
+    from ..core import flags
+
+    segments = tuple((int(s), int(a), int(n), None if c is None else float(c))
+                     for s, a, n, c in segments)
+    path = conv_path(xs[0].shape[1], segments, head, ws[0].shape[0],
+                     xs[0].dtype)
+    _CONV_TOTAL.inc(path=path)
+    if path == "xla":
+        return _conv_xla(tuple(xs), tuple(ws), segments, head, eps)
+    if interpret is None:
+        interpret = bool(flags.flag_value("pallas_interpret"))
+    return _conv_kernel(tuple(xs), tuple(ws), segments, head, eps,
+                        interpret)
+
+
+def _conv_xla(xs, ws, segments, head, eps):
+    """The stage in XLA operations: float32 arrays as large as a stream,
+    so a ``jax.checkpoint`` of its own — a differentiated program keeps
+    the (bf16) streams and rebuilds the float32 inside it."""
+    from ..nn import functional as F
+
+    def stage(xs, ws):
+        made = [F._causal_depthwise_conv1d(x, w, activation="silu")
+                for x, w in zip(xs, ws)]
+        outs = []
+        for stream, start, width, scale in segments:
+            y = made[stream][..., start:start + width]
+            outs.append(y if scale is None else l2_normed(
+                y, width // head, eps=eps, scale=scale))
+        return tuple(outs)
+
+    return jax.checkpoint(stage)(xs, ws)
+
+
+def _conv_kernel(xs, ws, segments, head, eps, interpret):
+    """The Mosaic kernels, under a step's announced mesh inside the
+    ``shard_map`` the attention kernels use: rows over the data axes and,
+    where ``conv_path`` lets an ``mp`` axis through, every stream's heads
+    over it. The taps go a copy a row ([B, K, C]), so that they shard as
+    the streams do and their gradient is summed over the rows outside."""
+    from . import attention
+    from .pallas import linear_attention as kernels
+
+    n, batch = len(xs), xs[0].shape[0]
+
+    def kernel(*arrays, seed):
+        del seed                                # no dropout in the stage
+        xs, ws = arrays[:n], arrays[n:]
+        # a stream cut over 'mp' is one segment: what this shard holds of it
+        local = tuple((s, start, min(width, xs[s].shape[-1]), scale)
+                      for s, start, width, scale in segments)
+        return kernels.conv_streams(xs, ws, local, head=head, eps=eps,
+                                    interpret=interpret)
+
+    rows = tuple(jnp.broadcast_to(w[None], (batch,) + w.shape) for w in ws)
+    return tuple(attention._on_mesh(
+        kernel, (*xs, *rows), jnp.zeros((), jnp.int32), head_axis=2,
+        seed_per_shard=False))

@@ -792,3 +792,317 @@ def kda(q, k, v, g, beta, *, tokens=None, together=None, interpret=False):
     o = _scan(*_blocked(q, k, v, g, beta, tokens), tokens,
               heads_together(h, d, together), bool(interpret))
     return o[:, :t].reshape(v.shape).astype(v.dtype)
+
+
+# ------------------------------------------------- the convolution stage
+# What a linear-attention layer does between its projections and the scan,
+# as one forward and one backward kernel on the [B, T, channels] streams:
+# every channel its K taps over the tokens t - K + 1 .. t (zero history
+# before a row's start), SiLU, and for a q or k segment the L2 norm over
+# each head's d lanes times a scale — ``ops.linear_attention._conv_xla`` is
+# the same function in XLA operations. Float32 throughout, and only in
+# VMEM: a program reads a block in the stream's dtype and writes one.
+#
+# A SEGMENT is ``(stream, start, width, scale)``: ``width`` channels of
+# input ``stream`` from ``start`` on, L2-normed a head and scaled where
+# ``scale`` is a number, left as SiLU made them where it is None; every
+# segment leaves as an array of its own. Kimi Delta Attention's q, k, v are
+# a segment each of three streams, Gated DeltaNet's are three of its one
+# q | k | v stream — the body is the same. The grid is (batch, channel
+# steps, token blocks): a program takes one token block of EVERY segment,
+# each ``width / steps`` channels wide (whole heads), so q, k and v share
+# a call; tokens are innermost and sequential.
+#
+# - ``conv_streams_fwd`` carries a block's last rows to the next in VMEM
+#   scratch (the K - 1 earlier tokens; zeros at a row's start).
+# - ``conv_streams_bwd`` keeps nothing of the forward but x and the taps: it
+#   rebuilds the pre-activation and the norm from x (the rows before the
+#   block arrive as a 16-row block of their own), walks the token blocks
+#   from the last to the first with the first rows of the pre-activation's
+#   gradient in scratch (what the earlier block's dx needs), and sums the
+#   taps' gradient [K, channels] over the tokens in float32, one a batch
+#   row.
+#
+# The body runs a head's lanes at a time (columns are independent but for
+# the head's sums): its float32 temporaries are [tokens, d].
+
+#: tokens a program of the convolution stage takes
+CONV_TOKENS = 256
+#: channels it takes of the narrowest segment
+CONV_LANES = 512
+#: rows carried between token blocks: one float32 tile, so K - 1 <= 8
+CONV_HALO = 8
+#: rows of the block that brings the backward the tokens before its own
+_BEFORE = 16
+
+
+def conv_steps(segments, head, lanes=None):
+    """Channel steps of the convolution stage's grid for these segments
+    (``(stream, start, width, scale)``) and this head width: the most that
+    leave the narrowest segment ``lanes`` channels a program, every
+    segment whole heads (whole lane groups where it is not normed) and
+    starting on a block of its own width. 0 where no cut serves."""
+    lanes = lanes or CONV_LANES
+    narrow = min(width for _, _, width, _ in segments)
+
+    def cuts(n):
+        return all(
+            width % n == 0 and start % (width // n) == 0
+            and (width // n) % (128 if scale is None else head) == 0
+            for _, start, width, scale in segments)
+
+    return next((n for n in range(max(1, narrow // lanes), 0, -1)
+                 if cuts(n)), 0)
+
+
+def conv_supported(segments, head, taps, dtype):
+    """Whether the kernels take these segments: heads that fill whole lane
+    groups, taps whose history fits the carried rows, bf16 or float32
+    streams, and a channel cut (``conv_steps``)."""
+    return (head % 128 == 0 and 1 <= taps <= CONV_HALO + 1
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(jnp.float32))
+            and conv_steps(segments, head) > 0)
+
+
+def _shifted(ext, taps, up=False):
+    """[x[t - s] for s < taps] from ``ext`` = the CONV_HALO rows before
+    the block on top of the block's own; with ``up``, [x[t + s]] from the
+    block's rows on top of the rows after it. Whole-tile rotations and
+    aligned slices."""
+    rows = ext.shape[0] - CONV_HALO
+    if up:
+        return [ext[:rows]] + [
+            pltpu.roll(ext, ext.shape[0] - s, 0)[:rows]
+            for s in range(1, taps)]
+    return [ext[CONV_HALO:]] + [pltpu.roll(ext, s, 0)[CONV_HALO:]
+                                for s in range(1, taps)]
+
+
+def _pre_activation(shifted, w):
+    """sum_s w[K - 1 - s] x[t - s]: tap K - 1 meets token t."""
+    taps = len(shifted)
+    return sum(shifted[s] * w[taps - 1 - s:taps - s] for s in range(taps))
+
+
+def _head_sum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _conv_fwd_kernel(*refs, segments, head, eps):
+    n = len(segments)
+    xs, ws, outs, halos = (refs[i * n:(i + 1) * n] for i in range(4))
+
+    @pl.when(pl.program_id(2) == 0)
+    def _row_start():
+        for halo in halos:
+            halo[...] = jnp.zeros(halo.shape, _F32)
+
+    for x_ref, w_ref, o_ref, halo, (_, _, _, scale) in zip(
+            xs, ws, outs, halos, segments):
+        tokens, width = x_ref.shape[1:]
+        taps = w_ref.shape[1]
+        step = 128 if scale is None else head
+        for at in range(0, width, step):
+            lanes = slice(at, at + step)
+            xf = x_ref[0, :, lanes].astype(_F32)
+            ext = jnp.concatenate([halo[:, lanes], xf], axis=0)
+            halo[:, lanes] = xf[tokens - CONV_HALO:]
+            a = _pre_activation(_shifted(ext, taps),
+                                w_ref[0, :, lanes].astype(_F32))
+            y = a * jax.nn.sigmoid(a)
+            if scale is not None:
+                y = y * (jax.lax.rsqrt(_head_sum(y * y) + eps) * scale)
+            o_ref[0, :, lanes] = y.astype(o_ref.dtype)
+
+
+def _conv_bwd_kernel(*refs, segments, head, eps):
+    n = len(segments)
+    xs, befores, ws, dys, dxs, dws, carries = (
+        refs[i * n:(i + 1) * n] for i in range(7))
+    first = pl.program_id(2) == 0
+    # the grid's last token step is the row's first block: zero history
+    row_start = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(first)
+    def _row_end():
+        for dw_ref, carry in zip(dws, carries):
+            dw_ref[...] = jnp.zeros(dw_ref.shape, _F32)
+            carry[...] = jnp.zeros(carry.shape, _F32)
+
+    for (x_ref, before_ref, w_ref, dy_ref, dx_ref, dw_ref, carry,
+         (_, _, _, scale)) in zip(xs, befores, ws, dys, dxs, dws, carries,
+                                  segments):
+        tokens, width = x_ref.shape[1:]
+        taps = w_ref.shape[1]
+        step = 128 if scale is None else head
+        for at in range(0, width, step):
+            lanes = slice(at, at + step)
+            w = w_ref[0, :, lanes].astype(_F32)
+            before = before_ref[0, :, lanes].astype(_F32)[
+                _BEFORE - CONV_HALO:]
+            ext = jnp.concatenate(
+                [jnp.where(row_start, 0.0, before),
+                 x_ref[0, :, lanes].astype(_F32)], axis=0)
+            shifted = _shifted(ext, taps)
+            a = _pre_activation(shifted, w)
+            sig = jax.nn.sigmoid(a)
+            dy = dy_ref[0, :, lanes].astype(_F32)
+            if scale is not None:
+                # o = scale y r, r = rsqrt(sum_d y^2 + eps):
+                # dy = scale r (do - y r^2 <do, y>)
+                y = a * sig
+                r = jax.lax.rsqrt(_head_sum(y * y) + eps)
+                dy = (dy - y * (r * r * _head_sum(dy * y))) * (r * scale)
+            da = dy * (sig * (1.0 + a * (1.0 - sig)))
+            dw_ref[0, :, lanes] += jnp.concatenate(
+                [jnp.sum(da * shifted[taps - 1 - j], axis=0, keepdims=True)
+                 for j in range(taps)], axis=0)
+            later = _shifted(jnp.concatenate([da, carry[:, lanes]], axis=0),
+                             taps, up=True)
+            carry[:, lanes] = da[:CONV_HALO]
+            dx_ref[0, :, lanes] = _pre_activation(later, w).astype(
+                dx_ref.dtype)
+
+
+def _conv_specs(segments, tokens, steps, taps, token_block):
+    """A segment's BlockSpecs — its block of its stream, of the stream's
+    taps, of an array of its own (q, k or v; a cotangent; dx) — and its
+    block's width; ``token_block``: the grid's token step -> the row's."""
+    streams, weights, own, widths = [], [], [], []
+    for stream, start, width, _ in segments:
+        wide = width // steps
+        first = start // wide
+        streams.append(pl.BlockSpec(
+            (1, tokens, wide),
+            lambda b, c, n, first=first: (b, token_block(n), first + c)))
+        weights.append(pl.BlockSpec(
+            (1, taps, wide), lambda b, c, n, first=first: (b, 0, first + c)))
+        own.append(pl.BlockSpec(
+            (1, tokens, wide), lambda b, c, n: (b, token_block(n), c)))
+        widths.append(wide)
+    return streams, weights, own, widths
+
+
+_CONV_STATIC = ("segments", "head", "eps", "tokens", "steps", "interpret")
+
+
+# jitted, as the backward is: a layer's call sites (forward, recomputed
+# forward, backward, in every layer and every program a start traces) then
+# share ONE trace and one lowering of the kernel's body a shape, where each
+# would run its Python again — 0.2 s a site, 48 sites a start of the
+# Kimi-Linear cell
+@functools.partial(jax.jit, static_argnames=_CONV_STATIC)
+def _conv_forward(xs, ws, *, segments, head, eps, tokens, steps, interpret):
+    b, t, _ = xs[0].shape
+    taps = ws[0].shape[1]
+    streams, weights, own, widths = _conv_specs(
+        segments, tokens, steps, taps, lambda n: n)
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, segments=segments, head=head,
+                          eps=eps),
+        grid=(b, steps, t // tokens),
+        in_specs=streams + weights, out_specs=own,
+        out_shape=[jax.ShapeDtypeStruct((b, t, width), xs[s].dtype)
+                   for s, _, width, _ in segments],
+        scratch_shapes=[pltpu.VMEM((CONV_HALO, wide), _F32)
+                        for wide in widths],
+        interpret=interpret, name="conv_streams_fwd",
+        compiler_params=_PARAMS,
+    )(*(xs[s] for s, *_ in segments), *(ws[s] for s, *_ in segments))
+
+
+@functools.partial(jax.jit, static_argnames=_CONV_STATIC)
+def _conv_backward(xs, ws, dys, *, segments, head, eps, tokens, steps,
+                   interpret):
+    b, t, _ = xs[0].shape
+    taps = ws[0].shape[1]
+    blocks = t // tokens
+
+    def rev(n):
+        return blocks - 1 - n
+
+    streams, weights, own, widths = _conv_specs(
+        segments, tokens, steps, taps, rev)
+    befores = [
+        pl.BlockSpec(
+            (1, _BEFORE, wide),
+            lambda b_, c, n, first=start // wide: (
+                b_, jnp.maximum(rev(n) * (tokens // _BEFORE) - 1, 0),
+                first + c))
+        for (_, start, _, _), wide in zip(segments, widths)]
+    taps_own = [pl.BlockSpec((1, taps, wide), lambda b_, c, n: (b_, 0, c))
+                for wide in widths]
+    like = jax.ShapeDtypeStruct
+    of = [s for s, *_ in segments]
+    grads = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, segments=segments, head=head,
+                          eps=eps),
+        grid=(b, steps, blocks),
+        in_specs=streams + befores + weights + own,
+        out_specs=own + taps_own,
+        out_shape=[like((b, t, width), xs[s].dtype)
+                   for s, _, width, _ in segments]
+        + [like((b, taps, width), _F32) for _, _, width, _ in segments],
+        scratch_shapes=[pltpu.VMEM((CONV_HALO, wide), _F32)
+                        for wide in widths],
+        interpret=interpret, name="conv_streams_bwd",
+        compiler_params=_PARAMS,
+    )(*(xs[s] for s in of), *(xs[s] for s in of), *(ws[s] for s in of),
+      *dys)
+    n = len(segments)
+
+    def by_stream(parts, dtypes):
+        """A stream's segments side by side again, in its dtype."""
+        return tuple(
+            jnp.concatenate([p for p, s in zip(parts, of) if s == i],
+                            axis=-1).astype(dtype)
+            for i, dtype in enumerate(dtypes))
+
+    return (by_stream(grads[:n], [x.dtype for x in xs]),
+            by_stream(grads[n:], [w.dtype for w in ws]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _conv(xs, ws, static):
+    return tuple(_conv_forward(xs, ws, **dict(static)))
+
+
+def _conv_fwd(xs, ws, static):
+    return _conv(xs, ws, static), (xs, ws)
+
+
+def _conv_bwd(static, res, dys):
+    return _conv_backward(*res, dys, **dict(static))
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def conv_streams(xs, ws, segments, *, head, eps, tokens=None, lanes=None,
+                 interpret=False):
+    """The convolution stage: streams ``xs`` [B, T, C_i], their taps ``ws``
+    [B, K, C_i] (a copy a batch row: the taps' gradient leaves a row at a
+    time) and ``segments`` ``(stream, start, width, scale)`` that cover
+    every stream in order -> one [B, T, width] array a segment, in its
+    stream's dtype: the causal depthwise convolution and SiLU, and where
+    ``scale`` is a number the L2 norm over each ``head`` lanes times it.
+    Differentiable in the streams and the taps; what a backward pass keeps
+    is those. ``tokens``: what a program takes of a row (a multiple of 16;
+    ``CONV_TOKENS``; a shorter row is padded to it), ``lanes``: of the
+    narrowest segment's channels (``CONV_LANES``)."""
+    segments = tuple(tuple(s) for s in segments)
+    steps = conv_steps(segments, head, lanes)
+    if not steps:
+        raise ValueError(f"no channel cut serves the segments {segments} "
+                         f"with heads of {head}")
+    t = xs[0].shape[1]
+    tokens = tokens or CONV_TOKENS
+    pad = -t % tokens
+    if pad:
+        xs = tuple(jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in xs)
+    static = (("segments", segments), ("head", int(head)),
+              ("eps", float(eps)), ("tokens", int(tokens)),
+              ("steps", steps), ("interpret", bool(interpret)))
+    outs = _conv(tuple(xs), tuple(ws), static)
+    return tuple(o[:, :t] for o in outs) if pad else outs

@@ -846,26 +846,20 @@ KIMI_LINEAR_ATTN = {
 
 # A KDA layer's element-wise stages run at [tokens, heads x head_dim] in
 # float32: kept for a backward pass they would be a dozen 268 MB arrays a
-# layer at 16,384 tokens. Each stage is a ``jax.checkpoint`` of its own: a
-# differentiated program keeps the stage's (bf16 or low-rank) inputs and
-# rebuilds the float32 inside it; without differentiation it is the plain
-# function. Every stage stays on [B, T, H * d] streams, the tiling of the
+# layer at 16,384 tokens. The decay and the output norm are each a
+# ``jax.checkpoint`` of their own: a differentiated program keeps the
+# stage's (bf16 or low-rank) inputs and rebuilds the float32 inside it;
+# without differentiation it is the plain function. The convolution stage
+# is ``ops.linear_attention.conv_streams``: where its Mosaic kernels run
+# (a TPU, lane-wide heads) that float32 exists in VMEM only, forward and
+# backward, and what a backward pass keeps is the stage's inputs by the
+# op's own rule; elsewhere it is the same ``jax.checkpoint`` of XLA
+# operations. Every stage stays on [B, T, H * d] streams, the tiling of the
 # projections, the convolution and the scan's kernels: a head is a slice of
-# the last axis, its statistics come from ``ops.linear_attention
-# .head_rsqrt`` and no array is viewed as [.., H, d] (on a TPU that view is
-# a physical relayout of 268 MB, there and back).
-def _l2_normed(x, heads, *, eps, scale):
-    """x [.., H * d] L2-normalised over each head's d features in float32,
-    times ``scale``, in x's dtype."""
-    import jax.numpy as jnp
-
-    from ..ops.linear_attention import head_rsqrt
-
-    xf = x.astype(jnp.float32)
-    return (xf * (head_rsqrt(xf, heads, eps=eps, mean=False) * scale)).astype(
-        x.dtype)
-
-
+# the last axis, its statistics come from a lane sum in the kernels and
+# from ``ops.linear_attention.head_rsqrt`` outside them, and no array is
+# viewed as [.., H, d] (on a TPU that view is a physical relayout of 268
+# MB, there and back).
 def _gated_head_norm(o, gate, w, heads, *, eps, activation):
     """``w * RMSNorm_d(o) * activation(gate)`` a head, in float32, with the
     learned d-wide weight laid on every head's lanes; o and gate [B, T,
@@ -880,23 +874,26 @@ def _gated_head_norm(o, gate, w, heads, *, eps, activation):
     return (normed * activation(gate.astype(jnp.float32))).astype(o.dtype)
 
 
-def _kda_streams(q, k, v, w_q, w_k, w_v, *, heads, eps):
+def _pallas_interpret():
+    """The ``pallas_interpret`` flag for an op's static arguments (an eager
+    trace is cached by them: a flag flip retraces)."""
+    from ..core import flags
+
+    return bool(flags.flag_value("pallas_interpret"))
+
+
+def _kda_streams(q, k, v, w_q, w_k, w_v, *, heads, eps, interpret=None):
     """The projected [B, T, H * d] streams, each through its causal
     depthwise convolution and SiLU, q and k then L2-normalised over a
     head's features (in float32), q scaled by d^-0.5: three [B, T, H * d]
     streams."""
-    import jax
+    from ..ops.linear_attention import conv_streams
 
-    def conv(x, w):
-        return F._causal_depthwise_conv1d(x, w, activation="silu")
-
-    def streams(q, k, v, w_q, w_k, w_v):
-        d = q.shape[-1] // heads
-        return (_l2_normed(conv(q, w_q), heads, eps=eps, scale=d ** -0.5),
-                _l2_normed(conv(k, w_k), heads, eps=eps, scale=1.0),
-                conv(v, w_v))
-
-    return jax.checkpoint(streams)(q, k, v, w_q, w_k, w_v)
+    wide, d = q.shape[-1], q.shape[-1] // heads
+    return conv_streams(
+        (q, k, v), (w_q, w_k, w_v),
+        ((0, 0, wide, d ** -0.5), (1, 0, wide, 1.0), (2, 0, wide, None)),
+        head=d, eps=eps, interpret=interpret)
 
 
 def _kda_decay(low, w_up, a_log, dt_bias, *, heads):
@@ -944,9 +941,13 @@ class KimiDeltaAttention(nn.Layer):
     write strength ``beta = sigmoid(b(x))`` a head; the gated delta rule's
     state recurrence (``ops.linear_attention``: the chunked scan from one
     sub-block of tokens on); and ``o_proj(sigmoid(g_b(g_a(x))) *
-    RMSNorm_d(o))``. The decay, beta, the scan's state and the output norm
-    are float32 under amp O1; the projections and the scan's large products
-    take bf16 operands."""
+    RMSNorm_d(o))``. The convolution's multiply-adds, SiLU, the L2 norms,
+    the decay, beta, the scan's state and the output norm are float32 under
+    amp O1; the projections and the scan's large products take bf16
+    operands. The stage between the projections and the scan (taps, SiLU,
+    L2 norms) is one op, ``ops.linear_attention.conv_streams``: one Mosaic
+    call a pass on a TPU at lane-wide heads, XLA operations elsewhere
+    (``paddle_tpu_conv_streams_total{path}`` says which)."""
 
     def __init__(self, hidden_size, num_heads=32, head_dim=128,
                  short_conv_kernel_size=4, gate_rank=None, chunk=64,
@@ -1004,7 +1005,8 @@ class KimiDeltaAttention(nn.Layer):
             q, k, v = apply_op(
                 "kda_streams", _kda_streams, q, k, v, self.q_conv.weight,
                 self.k_conv.weight, self.v_conv.weight,
-                heads=self.num_heads, eps=self.l2_eps)
+                heads=self.num_heads, eps=self.l2_eps,
+                interpret=_pallas_interpret())
         with jax.named_scope("kda.gate"):
             g = apply_op("kda_decay", _kda_decay, self.f_a_proj(x),
                          self.f_b_proj.weight, self.A_log, self.dt_bias,
@@ -1147,9 +1149,10 @@ def _rms_norm_f32(x, w, *, eps, zero_centered):
                                + eps) * scale).astype(x.dtype)
 
 
-# A Gated DeltaNet layer's element-wise stages, each a ``jax.checkpoint`` of
-# its own for KimiDeltaAttention's reason: a backward pass keeps the stage's
-# bf16 inputs and rebuilds the float32 inside it.
+# A Gated DeltaNet layer's element-wise stages, as KimiDeltaAttention's: a
+# backward pass keeps a stage's bf16 inputs and rebuilds the float32 — the
+# convolution stage's in VMEM where its kernels run, the output norm's
+# inside a ``jax.checkpoint`` of its own.
 def _gdn_split(qkvz, ba, *, key_heads, d_k, d_v, per_key):
     """The two fused projections, laid out a key head at a time as [q d_k |
     k d_k | v per_key x d_v | z per_key x d_v] and [b per_key | a per_key]
@@ -1168,23 +1171,20 @@ def _gdn_split(qkvz, ba, *, key_heads, d_k, d_v, per_key):
             y[..., per_key:].reshape(*lead, -1))
 
 
-def _gdn_streams(mixed, w, *, key_heads, d_k, eps):
+def _gdn_streams(mixed, w, *, key_heads, d_k, eps, interpret=None):
     """The q | k | v stream through ONE causal depthwise convolution and
     SiLU -> q, k [B, T, H_k * d_k] L2-normalised a head (in float32), q
     scaled by d_k^-0.5, and v [B, T, H_v * d_v]: streams, as
-    KimiDeltaAttention's."""
-    import jax
+    KimiDeltaAttention's, and the same op — three segments of one
+    stream."""
+    from ..ops.linear_attention import conv_streams
 
-    def streams(mixed, w):
-        x = F._causal_depthwise_conv1d(mixed, w, activation="silu")
-        key = key_heads * d_k
-        return (_l2_normed(x[..., :key], key_heads, eps=eps,
-                           scale=d_k ** -0.5),
-                _l2_normed(x[..., key:2 * key], key_heads, eps=eps,
-                           scale=1.0),
-                x[..., 2 * key:])
-
-    return jax.checkpoint(streams)(mixed, w)
+    key = key_heads * d_k
+    return conv_streams(
+        (mixed,), (w,),
+        ((0, 0, key, d_k ** -0.5), (0, key, key, 1.0),
+         (0, 2 * key, mixed.shape[-1] - 2 * key, None)),
+        head=d_k, eps=eps, interpret=interpret)
 
 
 def _gdn_decay(a, a_log, dt_bias):
@@ -1265,9 +1265,13 @@ class GatedDeltaNet(nn.Layer):
     ``beta = sigmoid(b)`` and ONE decay a value head and token, ``g =
     -exp(A_log) softplus(a + dt_bias)``; the gated delta rule's state
     recurrence (``ops.linear_attention`` with its ``[B, T, H]`` decay); and
-    ``out_proj(w * RMSNorm_d(o) * silu(z))``. The decay, beta, the scan's
-    state and the output norm are float32 under amp O1; the projections and
-    the scan's large products take bf16 operands. Scopes: ``gdn.proj`` /
+    ``out_proj(w * RMSNorm_d(o) * silu(z))``. The convolution's
+    multiply-adds, SiLU, the L2 norms, the decay, beta, the scan's state
+    and the output norm are float32 under amp O1; the projections and the
+    scan's large products take bf16 operands. Convolution, SiLU and norms
+    are one op (``ops.linear_attention.conv_streams``, q, k and v three
+    segments of the one stream: one Mosaic call a pass on a TPU at
+    lane-wide heads, XLA operations elsewhere). Scopes: ``gdn.proj`` /
     ``.conv`` / ``.gate`` / ``.repeat`` (q and k written once a value head:
     what an index map in the scan's kernels would save) / ``.core`` /
     ``.out``."""
@@ -1330,7 +1334,7 @@ class GatedDeltaNet(nn.Layer):
             q, k, v = apply_op(
                 "gdn_streams", _gdn_streams, mixed, self.conv1d.weight,
                 key_heads=self.num_k_heads, d_k=self.head_k_dim,
-                eps=self.l2_eps)
+                eps=self.l2_eps, interpret=_pallas_interpret())
         with jax.named_scope("gdn.gate"):
             g = apply_op("gdn_decay", _gdn_decay, a, self.A_log,
                          self.dt_bias)

@@ -305,12 +305,15 @@ def test_share_test_the_shares_and_the_shared_expert_once(reference):
     assert np.abs(total - want).max() <= RTOL * np.abs(want).max()
 
 
-@pytest.mark.parametrize("given, head_dim, seq, interpreter", [
-    ("chunked", 16, SEQ, False),      # this file's widths: the XLA scan
-    ("kernel", 128, 72, True),        # a lane group a head, over one chunk
+@pytest.mark.parametrize("given, conv, head_dim, seq, interpreter", [
+    ("chunked", "xla", 16, SEQ, False),   # this file's widths: XLA's paths
+    ("kernel", "xla", 128, 72, True),     # a lane group a head, over one
+                                          # chunk, under one block of the
+                                          # convolution stage's
+    ("kernel", "kernel", 128, 264, True),
 ])
 def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
-        given, head_dim, seq, interpreter):
+        given, conv, head_dim, seq, interpreter):
     """Four KDA layers on the path the route gives the platform and the
     widths — the Mosaic kernels (here in the Pallas interpreter) at the
     published 128 a head, the XLA scan at this file's 16 — one latent-
@@ -331,6 +334,8 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
                    for e in ("streams", "heads")}
         counts["held"] = moe._DISPATCH_TOTAL.value(path="sorted_held")
         counts["xla"] = attention._ROUTE_TOTAL.value(route="xla")
+        stages = {p: linear_attention._CONV_TOTAL.value(path=p)
+                  for p in ("kernel", "xla")}
         text = jax.jit(jax.grad(
             lambda p: framework_terms(net, p, ids)[1])).lower(
                 params).as_text(debug_info=True)
@@ -351,6 +356,13 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
     for e, n in entries.items():
         assert linear_attention._ENTRY_TOTAL.value(
             path=given, entry=e) - n == (4 if e == "streams" else 0), e
+    # the convolution stage: its kernels at lane-wide heads on a row of at
+    # least one of their blocks, the XLA stage at this file's widths
+    for p, n in stages.items():
+        assert linear_attention._CONV_TOTAL.value(path=p) - n == (
+            4 if p == conv else 0), p
+    assert ("conv_streams_fwd" in text and "conv_streams_bwd" in text) == (
+        conv == "kernel")
     assert moe._DISPATCH_TOTAL.value(
         path="sorted_held") - counts["held"] == 4
     assert attention._ROUTE_TOTAL.value(route="xla") - counts["xla"] == 1
@@ -500,7 +512,7 @@ def _per_head(what, heads, d):
     return {
         "sum": (lambda x, gate, w: linear_attention.head_sums(x, heads),
                 lambda x, gate, w: view(x).sum(-1)),
-        "l2": (lambda x, gate, w: models._l2_normed(x, heads, eps=eps,
+        "l2": (lambda x, gate, w: linear_attention.l2_normed(x, heads, eps=eps,
                                                     scale=d ** -0.5),
                lambda x, gate, w: (view(x) * jax.lax.rsqrt(
                    jnp.sum(view(x) ** 2, -1, keepdims=True) + eps)

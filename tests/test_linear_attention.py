@@ -710,3 +710,271 @@ def _shard_map_in_specs(fn, args):
         return None
 
     return find(jaxpr.jaxpr)
+
+
+# ------------------------------------------------ the convolution stage
+# ``ops.linear_attention.conv_streams``: the Mosaic kernels (in the Pallas
+# interpreter) against the XLA stage — what every other program runs —
+# forward and the stated VJP, in Kimi Delta Attention's form (three
+# streams, a segment each) and in Gated DeltaNet's (one stream, three
+# segments); which path a stage takes, and the counter that says so.
+CONV_D = 128
+
+
+def conv_form(form, seed, dtype, taps, batch=2, seq=80):
+    """(xs, ws, segments) of a stage with heads of 128: ``kda`` three
+    streams of two heads, q and k normed and v not; ``gdn`` one stream of
+    q | k | v with 2 | 2 | 4 heads, the published 2,048 | 2,048 | 4,096
+    scaled down."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+
+    def normal(*shape, scale=1.0):
+        return jax.random.normal(next(keys), shape, jnp.float32) * scale
+
+    d = CONV_D
+    if form == "kda":
+        wide = 2 * d
+        xs = tuple(normal(batch, seq, wide).astype(dtype) for _ in range(3))
+        ws = tuple(normal(taps, wide, scale=0.5) for _ in range(3))
+        segments = ((0, 0, wide, d ** -0.5), (1, 0, wide, 1.0),
+                    (2, 0, wide, None))
+    else:
+        xs = (normal(batch, seq, 8 * d).astype(dtype),)
+        ws = (normal(taps, 8 * d, scale=0.5),)
+        segments = ((0, 0, 2 * d, d ** -0.5), (0, 2 * d, 2 * d, 1.0),
+                    (0, 4 * d, 4 * d, None))
+    return xs, ws, segments
+
+
+def conv_kernels(xs, ws, segments, tokens=32, lanes=128):
+    """The kernels as the op calls them (the taps a copy a batch row), at
+    blocks small enough that a row is several of them and a segment
+    several channel steps."""
+    rows = tuple(jnp.broadcast_to(w[None], (xs[0].shape[0],) + w.shape)
+                 for w in ws)
+    return kernels.conv_streams(xs, rows, segments, head=CONV_D, eps=1e-6,
+                                tokens=tokens, lanes=lanes, interpret=True)
+
+
+def one_bf16_ulp(got, want):
+    """Every element of ``got`` (bf16) within one bf16 step of the float32
+    ``want``."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    step = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
+    assert np.isfinite(got).all()
+    assert (np.abs(got - want) <= step + 1e-6 * np.abs(want).max()).all()
+
+
+@pytest.mark.parametrize("taps", [4, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("form", ["kda", "gdn"])
+def test_the_convolution_kernels_are_the_xla_stage(form, dtype, taps):
+    """Forward and the VJP (dx and the taps' gradient) against the XLA
+    stage in float32 on the same values: batch 2 (a row's start is zero
+    history whatever the row before left), 80 tokens in blocks of 32 (the
+    halo crosses two block boundaries forward, the pre-activation's
+    gradient two backward, and the last block is padded), the first K - 1
+    tokens, two channel steps. Float32 to 1e-5; bf16 results (q, k, v and
+    dx) within one bf16 step of the float32 stage's, the taps' gradient —
+    float32 sums either way — to 1e-5."""
+    xs, ws, segments = conv_form(form, 21, dtype, taps)
+    assert kernels.conv_steps(segments, CONV_D, 128) == 2
+    keys = jax.random.split(jax.random.PRNGKey(22), len(segments))
+    cotangents = tuple(
+        jax.random.normal(key, xs[0].shape[:2] + (width,)).astype(dtype)
+        for key, (_, _, width, _) in zip(keys, segments))
+    f32 = jnp.float32
+    want, want_vjp = jax.vjp(
+        lambda xs, ws: la._conv_xla(xs, ws, segments, CONV_D, 1e-6),
+        tuple(x.astype(f32) for x in xs), ws)
+    want_dxs, want_dws = want_vjp(tuple(c.astype(f32) for c in cotangents))
+    got, got_vjp = jax.vjp(
+        lambda xs, ws: conv_kernels(xs, ws, segments), xs, ws)
+    got_dxs, got_dws = got_vjp(cotangents)
+    assert [o.dtype for o in got] == [dtype] * 3
+    assert [o.shape for o in got] == [w.shape for w in want]
+    for a, b in zip(got + got_dxs, want + want_dxs):
+        assert a.dtype == dtype
+        if dtype == jnp.bfloat16:
+            one_bf16_ulp(a, b)
+        else:
+            close(a, b, 1e-5)
+            close(a[:, :taps - 1], b[:, :taps - 1], 1e-5)
+    for a, b in zip(got_dws, want_dws):
+        assert a.dtype == f32 and a.shape == b.shape
+        close(a, b, 1e-5)
+
+
+def test_a_row_of_whole_blocks_and_one_channel_step():
+    """No padding and a program that holds every channel of its segments:
+    the blocks a train step's shapes get, scaled down."""
+    xs, ws, segments = conv_form("gdn", 23, jnp.float32, 4, seq=64)
+    want = la._conv_xla(xs, ws, segments, CONV_D, 1e-6)
+    got = conv_kernels(xs, ws, segments, tokens=32, lanes=512)
+    for a, b in zip(got, want):
+        close(a, b, 1e-5)
+
+
+@pytest.mark.parametrize("segments, head, lanes, steps", [
+    (((0, 0, 4096, 1.0), (1, 0, 4096, 1.0), (2, 0, 4096, None)), 128, None,
+     8),                                             # Kimi-Linear's
+    (((0, 0, 2048, 1.0), (0, 2048, 2048, 1.0), (0, 4096, 4096, None)), 128,
+     None, 4),                                       # Qwen3-Next's
+    (((0, 0, 512, 1.0), (0, 512, 256, None)), 256, 128, 2),   # whole heads
+    (((0, 0, 256, 1.0), (0, 256, 192, None)), 128, None, 0),  # half a group
+    (((0, 0, 256, 1.0),), 64, None, 0),              # half a lane group a head
+    (((0, 128, 256, 1.0),), 128, None, 0),           # off its own blocks
+])
+def test_the_channel_cut_keeps_heads_whole(segments, head, lanes, steps):
+    if steps == 0 and head % 128:
+        assert not kernels.conv_supported(segments, head, 4, jnp.bfloat16)
+    else:
+        assert kernels.conv_steps(segments, head, lanes) == steps
+        assert kernels.conv_supported(
+            segments, head, 4, jnp.bfloat16) == bool(steps)
+
+
+KDA_SEGMENTS = ((0, 0, 256, 128 ** -0.5), (1, 0, 256, 1.0),
+                (2, 0, 256, None))
+GDN_SEGMENTS = ((0, 0, 256, 128 ** -0.5), (0, 256, 256, 1.0),
+                (0, 512, 512, None))
+
+
+@pytest.mark.parametrize("seq, segments, head, taps, dtype, path", [
+    (256, KDA_SEGMENTS, 128, 4, jnp.bfloat16, "kernel"),
+    (16384, GDN_SEGMENTS, 128, 4, jnp.float32, "kernel"),
+    (16384, KDA_SEGMENTS, 128, 9, jnp.bfloat16, "kernel"),
+    (255, KDA_SEGMENTS, 128, 4, jnp.bfloat16, "xla"),    # under one block
+    (16384, ((0, 0, 64, 0.25), (1, 0, 64, 1.0), (2, 0, 64, None)), 16, 4,
+     jnp.bfloat16, "xla"),                               # heads of 16
+    (16384, ((0, 0, 192, None),), 128, 4, jnp.bfloat16, "xla"),
+    (16384, KDA_SEGMENTS, 128, 10, jnp.bfloat16, "xla"),  # past the halo
+    (16384, KDA_SEGMENTS, 128, 4, jnp.float16, "xla"),
+])
+def test_the_convolution_path_goes_by_widths_dtype_and_length(
+        interpreter, seq, segments, head, taps, dtype, path):
+    assert la.conv_path(seq, segments, head, taps, dtype) == path
+
+
+def test_the_convolution_path_needs_a_platform_and_known_devices(
+        monkeypatch):
+    """No TPU and no interpreter flag: the XLA stage. A platform that
+    compiles the kernels but a program whose devices are not known: the XLA
+    stage again. Under an announced mesh an 'mp' axis may cut a stream
+    between whole heads of one segment, not through q | k | v."""
+    from paddle_tpu.distributed import topology
+
+    shape = (16384, KDA_SEGMENTS, 128, 4, jnp.bfloat16)
+    assert la.conv_path(*shape) == "xla"
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(attention, "_placeable", lambda: False)
+    assert la.conv_path(*shape) == "xla"
+    monkeypatch.setattr(attention, "_placeable", lambda: True)
+    assert la.conv_path(*shape) == "kernel"
+    for axes, kda, gdn in ((dict(dp=4), "kernel", "kernel"),
+                           (dict(dp=2, mp=2), "kernel", "xla"),
+                           (dict(mp=4), "xla", "xla")):   # half a head each
+        mesh = topology.build_mesh(**axes, devices=jax.devices()[:4])
+        with topology.tracing_for(mesh):
+            assert la.conv_path(*shape) == kda, axes
+            assert la.conv_path(16384, GDN_SEGMENTS, 128, 4,
+                                jnp.bfloat16) == gdn, axes
+
+
+@pytest.mark.parametrize("seq, head, path", [(256, 128, "kernel"),
+                                             (300, 128, "kernel"),
+                                             (200, 128, "xla"),
+                                             (256, 16, "xla")])
+def test_the_stage_counts_the_path_once_a_trace(interpreter, seq, head,
+                                                path):
+    """One count a traced call, under the path taken, and either path's
+    result is the XLA stage's."""
+    wide = 2 * head
+    keys = jax.random.split(jax.random.PRNGKey(24), 2)
+    xs = (jax.random.normal(keys[0], (1, seq, 4 * wide)),)
+    ws = (jax.random.normal(keys[1], (4, 4 * wide)) * 0.5,)
+    segments = ((0, 0, wide, head ** -0.5), (0, wide, wide, 1.0),
+                (0, 2 * wide, 2 * wide, None))
+    before = {p: la._CONV_TOTAL.value(path=p) for p in ("kernel", "xla")}
+    stage = jax.jit(lambda xs, ws: la.conv_streams(
+        xs, ws, segments, head=head, eps=1e-6))
+    got = stage(xs, ws)
+    stage(xs, ws)                                   # traced once
+    for p, n in before.items():
+        assert la._CONV_TOTAL.value(path=p) == n + (p == path), p
+    for a, b in zip(got, la._conv_xla(xs, ws, segments, head, 1e-6)):
+        close(a, b, 1e-5)
+
+
+def test_the_convolution_kernels_shard_over_an_announced_mesh(interpreter):
+    """Inside a step traced for a mesh the stage runs under the attention
+    kernels' ``shard_map``: rows over the data axis and — three streams of
+    one segment each — heads over 'mp'; the taps go a copy a row, so their
+    gradient is summed over the shards. Result and gradients are the
+    one-device ones."""
+    from paddle_tpu.distributed import topology
+
+    xs, ws, segments = conv_form("kda", 25, jnp.float32, 4, seq=256)
+    mesh = topology.build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+
+    weights = jax.random.normal(jax.random.PRNGKey(26), (3,) + xs[0].shape)
+
+    def loss(stage):
+        return lambda xs, ws: sum(jnp.sum(o * c) for o, c in
+                                  zip(stage(xs, ws), weights))
+
+    def on_mesh(xs, ws):
+        with topology.tracing_for(mesh):
+            assert la.conv_path(256, segments, CONV_D, 4, xs[0].dtype) == (
+                "kernel")
+            return la.conv_streams(xs, ws, segments, head=CONV_D, eps=1e-6)
+
+    text = jax.jit(on_mesh).lower(xs, ws).as_text()
+    assert "shard_map" in text or "manual" in text
+    specs = [str(s) for s in _shard_map_in_specs(on_mesh, (xs, ws))]
+    # three streams and their taps, every one cut both ways; the seed not
+    assert sum("mp" in s and "dp" in s for s in specs) == 6, specs
+    want = jax.value_and_grad(loss(
+        lambda xs, ws: la._conv_xla(xs, ws, segments, CONV_D, 1e-6)),
+        argnums=(0, 1))(xs, ws)
+    got = jax.jit(jax.value_and_grad(loss(on_mesh), argnums=(0, 1)))(xs, ws)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        close(a, b, 1e-5)
+
+
+@pytest.mark.parametrize("kind", ["kda", "gdn"])
+def test_a_layer_takes_the_convolution_kernels_through_the_tape(kind):
+    """The layers' own call at lane-wide heads, eagerly: Tensors in,
+    ``backward`` through the tape, a row that is no whole block; with the
+    interpreter flag the stage counts ``kernel`` (a count a trace: the
+    forward's and the tape's), without it ``xla``, and the flag's flip
+    retraces (the stage's static arguments carry it). The two agree on the
+    output and on every gradient."""
+    from paddle_tpu.text.models import GatedDeltaNet, KimiDeltaAttention
+
+    paddle.seed(3)
+    layer = (KimiDeltaAttention(32, num_heads=2, head_dim=128)
+             if kind == "kda" else
+             GatedDeltaNet(32, num_k_heads=1, num_v_heads=2))
+    x = np.random.default_rng(4).standard_normal((2, 260, 32)).astype(
+        np.float32)
+    results = {}
+    for path, flag in (("xla", False), ("kernel", True), ("xla", False)):
+        other = "xla" if path == "kernel" else "kernel"
+        before = {p: la._CONV_TOTAL.value(path=p) for p in (path, other)}
+        paddle.set_flags({"pallas_interpret": flag})
+        try:
+            layer.clear_gradients()
+            given = paddle.to_tensor(x, stop_gradient=False)
+            out = layer(given)
+            (out * out).sum().backward()
+        finally:
+            paddle.set_flags({"pallas_interpret": False})
+        assert la._CONV_TOTAL.value(path=other) == before[other]
+        if path not in results:
+            assert la._CONV_TOTAL.value(path=path) > before[path]
+        results.setdefault(path, [out._value, given.grad._value] + [
+            p.grad._value for p in layer.parameters()])
+    assert len(results["kernel"]) > 6
+    for a, b in zip(results["kernel"], results["xla"]):
+        close(a, b, 2e-5)

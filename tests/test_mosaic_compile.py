@@ -130,6 +130,10 @@ LAYER_CASES = {
     "kda-kimi-cell": (1, 16384, 32, 32, 128),
     "gdn-qwen3-next-cell": (1, 16384, 16, 32, 128),
 }
+#: the layer's stage between the projections and the scan alone, the
+#: names its two Mosaic calls carry (the instructions': the calls sit in
+#: inner jits, under the ``kda.conv`` / ``gdn.conv`` scopes in a trace)
+CONV_CALLS = ("conv_streams_fwd", "conv_streams_bwd")
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
@@ -446,6 +450,18 @@ def _child():
                         models._gdn_beta(b))
             return models._gdn_gated_norm(o, z, w, heads=heads, eps=1e-6)
 
+        def stage_alone(*args):
+            """(q, k, v) and the stage's gradients, the stage as the layer
+            calls it."""
+            def stage(*a):
+                if name[:3] == "kda":
+                    return models._kda_streams(*a, heads=heads, eps=1e-6)
+                return models._gdn_streams(*a, key_heads=key_heads, d_k=d,
+                                           eps=1e-6)
+
+            out, vjp = jax.vjp(stage, *args)
+            return out, vjp(out)
+
         taps = like(4, heads * d)
         mixed = (2 * key_heads + heads) * d
         layer, args = {
@@ -460,15 +476,40 @@ def _child():
                                 like(heads, dtype=f32),
                                 like(batch, seq, heads), stream,
                                 like(d, dtype=f32)))}[name[:3]]
-        text = jax.jit(jax.grad(
-            lambda *a: jnp.sum(layer(*a).astype(f32)),
-            argnums=tuple(range(len(args))))).lower(*args).compile().as_text()
+        # what the cell's process observes: a platform that compiles
+        # Mosaic and one device (here only the compiler is a TPU's and the
+        # CPU devices are four, so the gate is told)
+        from paddle_tpu.ops import attention
+
+        here = attention._use_pallas, attention._placeable
+        attention._use_pallas = attention._placeable = lambda: True
+        try:
+            text = jax.jit(jax.grad(
+                lambda *a: jnp.sum(layer(*a).astype(f32)),
+                argnums=tuple(range(len(args))))).lower(
+                    *args).compile().as_text()
+            conv_args = (args[:6] if name[:3] == "kda" else args[:2])
+            conv = jax.jit(stage_alone).lower(*conv_args).compile()
+        finally:
+            attention._use_pallas, attention._placeable = here
+        conv_text = conv.as_text()
+        out["conv-" + name] = {
+            "mosaic": conv_text.count(MOSAIC),
+            "calls": [c for c in CONV_CALLS if f"%{c}" in conv_text],
+            # a float32 array as large as a stream in HBM, in any tiling:
+            # one the entry computation holds (a fusion's body computes in
+            # registers what it is printed to hold)
+            "stream_sized_f32": len(re.findall(
+                rf"f32\[{batch},{seq},\d\d\d+",
+                conv_text[conv_text.index("\nENTRY "):])),
+            "temp_gb": conv.memory_analysis().temp_size_in_bytes / 1e9}
         # a float32 array in the [tokens, heads, d] tiling, as XLA writes a
         # relayout to or from it: [.., 16384, H, 128] or [2048, 8, H, 128]
         head_view = rf"f32\[(\d+,)?({seq},\d+,{d}|{seq // 8},8,\d+,{d})\]"
         out["layer-" + name] = {
             "mosaic": text.count(MOSAIC),
-            "calls": [c for c in KDA_CALLS if f"({c})" in text],
+            "calls": [c for c in KDA_CALLS if f"({c})" in text]
+            + [c for c in CONV_CALLS if f"%{c}" in text],
             "f32_relayouts": sorted(set(re.findall(
                 rf"= ({head_view})\S* (?:copy|reshape|transpose)\(", text))),
             "f32_head_views": len(re.findall(head_view, text))}
@@ -707,15 +748,34 @@ def test_a_linear_attention_layer_keeps_one_tiling(compiled, case):
     """A whole layer between its projections — convolution + SiLU, the L2
     norms, the decay, (the key heads' repeat,) the scan, the gated output
     norm — forward + backward at the cell's shape under amp O1's dtypes:
-    the scan's two Mosaic calls, and NO float32 array anywhere in the
+    the scan's two Mosaic calls and the convolution stage's two (q, k and
+    v share a call a pass), and NO float32 array anywhere in the
     program in the [tokens, heads, d] tiling, let alone a copy, reshape or
     transpose to or from it: every stage stays on the kernels' [tokens,
     heads x d] (until PR 39 the Kimi-Linear layer held 13 such relayouts of
     268 MB, 95 ms of an 891 ms step)."""
     got = compiled["layer-" + case]
-    assert got["mosaic"] == 2 and got["calls"] == list(KDA_CALLS)
+    assert got["mosaic"] == 4
+    assert got["calls"] == list(KDA_CALLS + CONV_CALLS)
     assert got["f32_relayouts"] == []
     assert got["f32_head_views"] == 0
+
+
+@pytest.mark.parametrize("case", list(LAYER_CASES))
+def test_the_convolution_stage_keeps_its_float32_in_vmem(compiled, case):
+    """The stage alone, forward + VJP at the cell's shape in bf16: one
+    Mosaic call a pass for q, k and v together — three streams of 4,096
+    channels (Kimi-Linear) or the segments 2,048 | 2,048 | 4,096 of one
+    (Qwen3-Next) — and no float32 array as large as a stream in HBM (the
+    XLA stage held five of 268 MB a stream); what the program holds beside
+    its arguments and results is not more than the three bf16 streams the
+    backward writes before they are one again."""
+    got = compiled["conv-" + case]
+    assert got["mosaic"] == 2 and got["calls"] == list(CONV_CALLS)
+    assert got["stream_sized_f32"] == 0
+    batch, seq, key_heads, heads, d = LAYER_CASES[case]
+    streams = batch * seq * (2 * key_heads + heads) * d * 2 / 1e9
+    assert got["temp_gb"] <= 1.1 * streams, got
 
 
 @pytest.mark.parametrize("case", list(KDA_CASES))

@@ -343,12 +343,15 @@ def test_share_test_sixteen_shares_and_the_gated_shared_expert_once(
     assert np.abs(total - want).max() <= RTOL * np.abs(want).max()
 
 
-@pytest.mark.parametrize("given, head_dim, seq, interpreter", [
-    ("chunked_scalar", 16, SEQ, False),   # this file's widths: the XLA scan
-    ("kernel_scalar", 128, 72, True),     # a lane group a head, over a chunk
+@pytest.mark.parametrize("given, conv, head_dim, seq, interpreter", [
+    ("chunked_scalar", "xla", 16, SEQ, False),   # this file's widths
+    ("kernel_scalar", "xla", 128, 72, True),     # a lane group a head, over
+                                                 # a chunk, under one block
+                                                 # of the convolution stage's
+    ("kernel_scalar", "kernel", 128, 264, True),
 ])
 def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
-        given, head_dim, seq, interpreter):
+        given, conv, head_dim, seq, interpreter):
     """Three Gated DeltaNet layers on the path the route gives the platform
     and the widths — the Mosaic kernels (here in the Pallas interpreter) at
     the published 128 a head, the XLA scan at this file's 16 —, always with
@@ -373,6 +376,8 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
                    for e in ("streams", "heads")}
         counts["held"] = moe._DISPATCH_TOTAL.value(path="sorted_held")
         counts["xla"] = attention._ROUTE_TOTAL.value(route="xla")
+        stages = {p: linear_attention._CONV_TOTAL.value(path=p)
+                  for p in ("kernel", "xla")}
         text = jax.jit(jax.grad(
             lambda p: framework_terms(net, p, ids)[1])).lower(
                 params).as_text(debug_info=True)
@@ -391,6 +396,13 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
     for e, n in entries.items():
         assert linear_attention._ENTRY_TOTAL.value(
             path=given, entry=e) - n == (3 if e == "streams" else 0), e
+    # the convolution stage: its kernels at lane-wide heads on a row of at
+    # least one of their blocks, the XLA stage at this file's widths
+    for p, n in stages.items():
+        assert linear_attention._CONV_TOTAL.value(path=p) - n == (
+            3 if p == conv else 0), p
+    assert ("conv_streams_fwd" in text and "conv_streams_bwd" in text) == (
+        conv == "kernel")
     assert moe._DISPATCH_TOTAL.value(
         path="sorted_held") - counts["held"] == 4
     assert attention._ROUTE_TOTAL.value(route="xla") - counts["xla"] == 1
@@ -522,7 +534,7 @@ def _per_head(what, heads, d):
 
     eps = 1e-6
     return {
-        "l2": (lambda x, gate, w: models._l2_normed(x, heads, eps=eps,
+        "l2": (lambda x, gate, w: linear_attention.l2_normed(x, heads, eps=eps,
                                                     scale=1.0),
                lambda x, gate, w: (view(x) * jax.lax.rsqrt(
                    jnp.sum(view(x) ** 2, -1, keepdims=True) + eps)).reshape(

@@ -14,9 +14,11 @@ train step runs them (``KimiDeltaAttention`` at the Kimi-Linear cell's
 shape, ``GatedDeltaNet`` at the Qwen3-Next cell's 16 key / 32 value heads),
 from the projections' bf16 outputs to ``o_proj``'s input: the whole, then
 each stage alone, forward and forward + backward, with the compiled
-program's bytes accessed and temporaries. The before-number for work on
-this layer (a fused projection, a convolution kernel) that is not the whole
-cell:
+program's bytes accessed and temporaries; the convolution stage on both
+of its paths (``ops.linear_attention.conv_path``: the Mosaic kernels the
+layer runs here, and the XLA stage they replaced), so that before and
+after share a chip call. The before-number for work on this layer (a fused
+projection) that is not the whole cell:
 
     chiprun -- python3 tools/kda_kernel_bench.py --layer [kda|gdn]
 
@@ -80,6 +82,24 @@ def candidate(name, fn, args, against=None):
 GDN_KEY_HEADS = 16
 
 
+def _conv_on_both_paths(stage, args):
+    """The convolution stage's two lines: ``stage`` held to each path
+    while it is traced, whatever ``conv_path`` would choose here."""
+    from paddle_tpu.ops import linear_attention as la
+
+    def on(path):
+        def held(*args):
+            chosen, la.conv_path = la.conv_path, lambda *_: path
+            try:
+                return stage(*args)
+            finally:
+                la.conv_path = chosen
+
+        return held
+
+    return [(f"conv, {path}", on(path), args) for path in ("kernel", "xla")]
+
+
 def _layer_stages(kind):
     """``[(stage, fn, args)]`` of one layer: its stages in order, each on
     inputs as the stage before leaves them (drawn, not computed: a stage's
@@ -125,8 +145,9 @@ def _layer_stages(kind):
         raw = [normal(*stream) for _ in range(3)]
         q, k, v = conv(*raw, *taps)
         g = decay(low, w_up, a_log, dt_bias)
-        stages = [("conv", lambda *a: sum(conv(*a)), (*raw, *taps)),
-                  ("decay", decay, (low, w_up, a_log, dt_bias))]
+        stages = _conv_on_both_paths(lambda *a: sum(conv(*a)),
+                                     (*raw, *taps))
+        stages += [("decay", decay, (low, w_up, a_log, dt_bias))]
         whole = (*raw, taps, low, w_up, a_log, dt_bias, beta, gate, w_norm)
     else:
         per_key = HEADS // GDN_KEY_HEADS
@@ -153,9 +174,9 @@ def _layer_stages(kind):
 
         q, k, v = conv(mixed, taps)
         g = models._gdn_decay(a, a_log, dt_bias)
-        stages = [("conv", lambda *a: jnp.concatenate(conv(*a), -1),
-                   (mixed, taps)),
-                  ("repeat", lambda *a: sum(repeat(*a)), (q, k))]
+        stages = _conv_on_both_paths(
+            lambda *a: jnp.concatenate(conv(*a), -1), (mixed, taps))
+        stages += [("repeat", lambda *a: sum(repeat(*a)), (q, k))]
         q, k = repeat(q, k)
         whole = (mixed, taps, a, a_log, dt_bias, beta, gate, w_norm)
 
