@@ -77,7 +77,6 @@ define_flag("cudnn_deterministic", False, help="compat no-op; XLA is determinist
 define_flag("use_pallas_kernels", True, help="use Pallas fused kernels (flash attention etc.) on TPU")
 define_flag("pallas_interpret", False, help="run Pallas kernels in the Pallas interpreter (any backend) instead of compiling them with Mosaic, and select them off-TPU too. Set explicitly by the CPU tests and the chip_smoke dry run; never inferred from the backend")
 define_flag("pallas_attention_min_seq", 1024, help="key length from which unmasked attention on [batch, heads, seq, head_dim] takes the STREAMING Pallas flash kernel (route 'stream' of ops/attention.py attention_route) instead of XLA's fused path. It gates only that route: short unmasked self-attention (seq <= 512) runs the whole-sequence kernel on the packed projection whatever this says. Measured on the v5e (2026-07-31): at seq 128 the streaming kernel's one-(batch, head)-per-program grid is 3x SLOWER than XLA's batched-matmul attention (one 128-block per program = pure per-program overhead); at seq 4096 it wins (XLA materialises S^2). 1024 = where the S^2 buffer starts to dominate activation memory. 0 = always the streaming kernel")
-define_flag("sdpa_softmax_fp32", True, help="compute the XLA attention path's softmax in f32 (the amp-O1/NVIDIA-recipe default). False keeps the logits dtype (bf16 under amp) — halves the softmax HBM traffic; flip only with a measured accuracy check")
 define_flag("allocator_strategy", "auto_growth", help="compat: XLA owns HBM allocation")
 define_flag("fraction_of_gpu_memory_to_use", 0.92, help="compat no-op on TPU")
 define_flag("seed", 0, help="global RNG seed")

@@ -90,6 +90,7 @@ from .. import nn
 from ..core.dispatch import apply_op
 from ..core.tensor import Tensor
 from ..obs import metrics as obs_metrics
+from ..ops import placement
 from jax import shard_map
 
 _DISPATCH_TOTAL = obs_metrics.counter(
@@ -240,42 +241,17 @@ def _gmm_tiling(rows, itemsize, k, n):
     return lambda m, k, n: (tm, min(want[1], k), min(want[2], n))
 
 
-def _one_device_program():
-    """Whether the program being traced runs on one device: GSPMD cannot
-    partition a Mosaic call, and the expert layer has no shard_map of its
-    own (a step builder's announced mesh, else the process's devices)."""
-    from ..distributed import topology
-
-    mesh = topology.traced_mesh()
-    return mesh.size == 1 if mesh is not None else jax.device_count() == 1
-
-
-def _expert_kernel():
-    """Which grouped matmul the sorted path runs: ``mosaic`` (the Pallas
-    megablox kernel) where kernels are selected and the program runs on
-    one device, ``interpret`` under the ``pallas_interpret`` flag, else
-    ``xla`` (``jax.lax.ragged_dot``, which XLA partitions and the CPU
-    lowers). Decided where the op is dispatched, so it rides the op's
-    static arguments and a flag flip retraces."""
-    from ..core import flags
-    from ..ops.attention import _use_pallas
-
-    if not _use_pallas():
-        return "xla"
-    if flags.flag_value("pallas_interpret"):
-        return "interpret"
-    return "mosaic" if _one_device_program() else "xla"
-
-
 def _grouped_matmul(rows, w, group_sizes, kernel):
     """rows [M, K] sorted by group, w [G, K, N] -> [M, N]: each row times
     its own group's matrix; exact under any group sizes. ``kernel`` as
-    ``_expert_kernel`` says; the megablox kernel's row tile has to divide
-    M, else ragged_dot serves. Measured on the chip (PERF.md section 6, PR
-    25): the kernel is the faster, and XLA's TPU expansion of ragged_dot
-    loses the operation's scope in a trace."""
+    ``ops.placement.kernel(sharded=False)`` says where the op is dispatched
+    (no shard_map here: None under a mesh of several devices, as on a CPU:
+    ``jax.lax.ragged_dot``, which XLA partitions); the megablox kernel's
+    row tile has to divide M, else ragged_dot serves. Measured on the chip
+    (PERF.md section 6, PR 25): the kernel is the faster, and XLA's TPU
+    expansion of ragged_dot loses the operation's scope in a trace."""
     tiling = _gmm_tiling(rows.shape[0], rows.dtype.itemsize, *w.shape[1:])
-    if kernel != "xla" and tiling is not None and rows.dtype == w.dtype:
+    if kernel is not None and tiling is not None and rows.dtype == w.dtype:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         return gmm(rows, w, group_sizes, rows.dtype, tiling,
@@ -300,7 +276,7 @@ def _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel):
         return grouped(mid, w_down)
 
 
-def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel="xla"):
+def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel=None):
     """Dispatch and experts of the dropless path: [B, S, H] and the expert
     ids [N, k] -> every (token, choice) pair's expert output [N*k, H] in
     expert order, with the permutation and its inverse."""
@@ -514,7 +490,7 @@ def _held_places(topi, *, first, count, rows):
 
 
 def _held_experts(x, topi, w_gate, w_up, w_down, *, first, rows,
-                  kernel="xla"):
+                  kernel=None):
     """Dispatch and experts of a held share: [B, S, H] and the expert ids
     [N, k] over ALL experts -> the outputs [rows, H] of the pairs whose
     expert is one of the ``w_up.shape[0]`` held from ``first`` on, in
@@ -683,10 +659,7 @@ class MoELayer(nn.Layer):
             return "sorted_held"
         if self.num_experts < _DENSE_BELOW:
             return "dense"
-        from ..distributed import topology
-
-        mesh = topology.traced_mesh() or topology.get_global_mesh()
-        sharded = mesh.shape.get(self.shard_axis, 1) > 1
+        sharded = placement.axis_size(self.shard_axis, or_global=True) > 1
         return "capacity" if sharded else "sorted"
 
     def forward(self, x):
@@ -789,10 +762,11 @@ class MoELayer(nn.Layer):
             self.e_score_correction_bias, top_k=self.top_k,
             renorm=self.norm_topk_prob, scoring=self.scoring,
             routed_scale=self.routed_scale)
+        kernel = placement.kernel(sharded=False)   # no shard_map of its own
         if self.held is None:
             ys, order, inv = apply_op(
                 "moe_experts_sorted", _sorted_experts, x, topi, self.w_gate,
-                self.w_up, self.w_down, kernel=_expert_kernel())
+                self.w_up, self.w_down, kernel=kernel)
             out = apply_op("moe_combine", _combine, ys, topv, order, inv,
                            shape=tuple(x.shape))
         else:
@@ -802,7 +776,7 @@ class MoELayer(nn.Layer):
                 "moe_experts_held", _held_experts, x, topi, self.w_gate,
                 self.w_up, self.w_down, first=first, rows=held_rows(
                     tokens, self.top_k, count, self.num_experts,
-                    self.held_rows_factor), kernel=_expert_kernel())
+                    self.held_rows_factor), kernel=kernel)
             out = apply_op("moe_combine", _combine, ys, topv, taken, inv,
                            shape=tuple(x.shape), held=True)
             if self.training:

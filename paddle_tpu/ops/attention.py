@@ -14,7 +14,7 @@ causality, platform), one of:
 - ``stream``: the streaming flash kernel (``mha``) on [batch, heads, seq,
   head_dim] for long keys, where XLA's S^2 logits buffer explodes.
 - ``xla``: the jnp implementation below, which XLA fuses into a few
-  kernels — every masked call, every CPU run without ``pallas_interpret``.
+  kernels — every masked call, every CPU run without the interpreter flag.
 
 A causal call may carry a static ``window``: query i attends the
 ``window`` keys up to its own position (i - window < j <= i; a sliding-
@@ -26,10 +26,9 @@ whole sequence or more is plain causal attention on both. Windowed calls
 count in ``paddle_tpu_attention_window_route_total{route}`` as well.
 
 A selected kernel that fails raises; no route falls back to another.
-GSPMD cannot partition a Mosaic call, so ``short`` is chosen only where
-the gate knows the program's devices (``_placeable``): a step builder
-announced its mesh and the kernel shards itself over it, or the process
-has one device.
+Whether a program may hold a kernel at all is ``ops.placement``'s answer
+(its table): ``short`` asks as a call site ``on_mesh`` shards, ``stream``
+as one with no XLA way out at its lengths.
 Every decision counts in ``paddle_tpu_attention_route_total{route}`` (at
 trace time under jit: one count per traced call site).
 """
@@ -38,12 +37,11 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..core import flags, random as random_core
 from ..core.dispatch import apply_op
-from ..distributed import topology
 from ..obs import metrics as obs_metrics
+from . import placement
 
 _ROUTE_TOTAL = obs_metrics.counter(
     "paddle_tpu_attention_route_total",
@@ -65,8 +63,7 @@ _WINDOW_ROUTE_TOTAL = obs_metrics.counter(
 _STREAM_MIN_KEYS = 512
 
 
-def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal,
-              fp32_softmax=True, window=None):
+def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal, window=None):
     # q,k,v: [batch, heads, seq, head_dim]
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if is_causal:
@@ -86,47 +83,15 @@ def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal,
                                jnp.finfo(logits.dtype).min)
         else:
             logits = logits + mask
-    if fp32_softmax:
-        probs = (jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-                 .astype(q.dtype))
-    else:  # keep the q dtype: halves softmax HBM traffic under amp (an
-        # f32 additive mask can still have promoted the logits — cast
-        # back so both flag settings agree on the output dtype)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    # the softmax is float32 whatever q is (the amp O1 recipe)
+    probs = (jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+             .astype(q.dtype))
     if dropout_p > 0.0 and key is not None:
         # counter-hash mask, not threefry bernoulli (core/random.py
         # fast_keep_mask): attention-prob masks dominate dropout RNG cost
         keep = random_core.fast_keep_mask(key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-
-
-def _use_pallas():
-    """Kernel selection is a function of what can be observed: the
-    use_pallas_kernels flag and the platform (a TPU, or the
-    pallas_interpret flag the CPU tests and the chip_smoke dry run set).
-    A platform query that fails is an error, not "no kernel"."""
-    if not flags.flag_value("use_pallas_kernels"):
-        return False
-    if flags.flag_value("pallas_interpret"):
-        return True
-    from ..core.place import is_tpu_available
-
-    return is_tpu_available()
-
-
-def _placeable():
-    """Whether the program being traced can hold a Mosaic call that is
-    not told its mesh at lowering. GSPMD cannot partition one, so the gate
-    has to know the program's devices now: a mesh its builder announced
-    (topology.tracing_for; ``_on_mesh`` shards the kernel over it), or a
-    process with one device. A plain jax.jit on a process with several
-    devices may be partitioned over them, which only its lowering sees:
-    not known here, so no kernel. The interpreter's calls are plain HLO
-    and go anywhere."""
-    return (flags.flag_value("pallas_interpret")
-            or topology.traced_mesh() is not None
-            or jax.device_count() == 1)
 
 
 def attention_route(*, batch, seq_q, seq_k, num_heads, head_dim, dtype,
@@ -142,19 +107,19 @@ def attention_route(*, batch, seq_q, seq_k, num_heads, head_dim, dtype,
         raise ValueError(
             f"a window ({window}) takes causal attention of queries on as "
             f"many keys (is_causal={is_causal}, {seq_q} on {seq_k})")
-    if masked or not _use_pallas():
+    # ``stream`` asks as a site with no XLA way out (placement.kernel)
+    if masked or not placement.kernel(sharded=True, no_fallback=True):
         return "xla"
     from .pallas import flash_attention
 
     # the short kernel's grid is the batch in row blocks: a batch that is
     # a symbol (jit.save's batch-polymorphic export) has no divisors.
     # Where it cannot be placed XLA's route serves (its S^2 buffers are
-    # small at these lengths); the stream kernel has no such way out, so
-    # it is chosen all the same and raises if GSPMD has to partition it
+    # small at these lengths)
     if (packed and not is_causal and isinstance(batch, int)
             and flash_attention.short_supported(
                 seq_q, num_heads, head_dim, dtype)
-            and _placeable()):
+            and placement.kernel(sharded=True)):
         return "short"
     # kernel overhead is governed by seq_k (the per-program inner-loop
     # length), XLA's memory blowup by the seq_q*seq_k logits buffer. So:
@@ -182,53 +147,6 @@ def _kernel_seed(key):
     return jax.lax.bitcast_convert_type(seed, jnp.int32)
 
 
-def _on_mesh(kernel, arrays, seed, *, head_axis, seed_per_shard):
-    """Run ``kernel(*arrays, seed=seed)`` — directly, or inside a step
-    being traced for a multi-device mesh (topology.traced_mesh) under a
-    shard_map: GSPMD cannot partition a Mosaic kernel, every mesh axis has
-    to be manual around it (a builder whose step already runs inside a
-    shard_map over the whole mesh has done that: direct again). Programs
-    are independent per batch row and head, so dim 0 of every array
-    shards over the data axes and dim
-    ``head_axis`` (None: heads are not a dim of their own) over 'mp' —
-    each only where it divides (the head dim in every array: it may hold a
-    head's features too, [.., heads x d]); otherwise that dim is computed
-    whole on every device of the axis. ``seed_per_shard``: the kernel's
-    mask hash counts (batch, head) from 0 on every shard, so each shard
-    gets a seed of its own or they all drop the same entries."""
-    mesh = topology.traced_mesh()
-    if (mesh is None or mesh.size == 1 or set(mesh.axis_names) <= set(
-            jax.sharding.get_abstract_mesh().manual_axes)):
-        return kernel(*arrays, seed=seed)
-
-    shape = arrays[0].shape
-    data = topology.data_axes(mesh)
-    n_data = math.prod(mesh.shape[ax] for ax in data)
-    n_mp = mesh.shape.get("mp", 1)
-    b_axes = data if n_data > 1 and shape[0] % n_data == 0 else ()
-    h_axes = (("mp",) if head_axis is not None and n_mp > 1 and all(
-        a.shape[head_axis] % n_mp == 0 for a in arrays) else ())
-
-    def spec(a):
-        dims = [None] * a.ndim
-        dims[0] = b_axes or None
-        if h_axes:
-            dims[head_axis] = h_axes
-        return P(*dims)
-
-    def on_shard(*args):
-        *shards, seed = args
-        if seed_per_shard:
-            for ax in b_axes + h_axes:
-                seed = (seed * jnp.int32(mesh.shape[ax])
-                        + jax.lax.axis_index(ax))
-        return kernel(*shards, seed=seed)
-
-    return jax.shard_map(
-        on_shard, mesh=mesh, in_specs=tuple(map(spec, arrays)) + (P(),),
-        out_specs=spec(arrays[0]), check_vma=False)(*arrays, seed)
-
-
 def _flash(q, k, v, key, *, scale, is_causal, dropout_p, interpret,
            window=None):
     """The streaming kernel on [batch, heads, seq, head_dim]; with a
@@ -238,8 +156,8 @@ def _flash(q, k, v, key, *, scale, is_causal, dropout_p, interpret,
     kernel = functools.partial(
         flash_attention.mha, scale=scale, causal=is_causal,
         dropout_p=dropout_p, interpret=interpret, window=window)
-    return _on_mesh(kernel, (q, k, v), _kernel_seed(key), head_axis=1,
-                    seed_per_shard=True)
+    return placement.on_mesh(kernel, (q, k, v), head_axis=1,
+                             seed=_kernel_seed(key), seed_per_shard=True)
 
 
 def _short(qkv, key, *, num_heads, scale, dropout_p, interpret):
@@ -255,8 +173,8 @@ def _short(qkv, key, *, num_heads, scale, dropout_p, interpret):
             row_ids=row_ids, interpret=interpret)
 
     rows = jnp.arange(qkv.shape[0], dtype=jnp.int32)
-    return _on_mesh(kernel, (qkv, rows), _kernel_seed(key), head_axis=None,
-                    seed_per_shard=False)
+    return placement.on_mesh(kernel, (qkv, rows), head_axis=None,
+                             seed=_kernel_seed(key))
 
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
@@ -283,14 +201,12 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
         return apply_op(
             "flash_attention", _flash, q, k, v, key,
             scale=scale, is_causal=bool(is_causal), dropout_p=p,
-            interpret=bool(flags.flag_value("pallas_interpret")), **windowed)
+            interpret=placement.kernel(sharded=True) == "interpret",
+            **windowed)
 
-    # the flag rides the static kwargs so the per-(op, shape) dispatch
-    # cache keys on it — a flag flip must not serve a stale trace
     return apply_op(
         "sdpa", _sdpa_ref, q, k, v, attn_mask, key,
-        scale=scale, dropout_p=p, is_causal=bool(is_causal),
-        fp32_softmax=bool(flags.flag_value("sdpa_softmax_fp32")), **windowed)
+        scale=scale, dropout_p=p, is_causal=bool(is_causal), **windowed)
 
 
 def packed_self_attention(qkv, num_heads, attn_mask=None, dropout_p=0.0,
@@ -314,7 +230,7 @@ def packed_self_attention(qkv, num_heads, attn_mask=None, dropout_p=0.0,
         return apply_op(
             "short_attention", _short, qkv, key, num_heads=int(num_heads),
             scale=1.0 / math.sqrt(head_dim), dropout_p=p,
-            interpret=bool(flags.flag_value("pallas_interpret")))
+            interpret=placement.kernel(sharded=True) == "interpret")
 
     from .. import tensor as pt
 

@@ -65,8 +65,8 @@ backward in 53 ms where 2,048 takes 121: the transposed inner scan writes
 its stacked results a slice at a time, which costs by the stack's size.
 
 ``gated_delta_rule`` is the entry point a layer calls: it picks the path
-from what it can observe (``core_path``: length, widths, dtype, platform,
-whether the program's devices are known) and counts the choice in
+from what it can observe (``core_path``: length, widths, dtype and
+``ops.placement``'s answer for the program) and counts the choice in
 ``paddle_tpu_kda_core_total{path}`` (``kernel`` | ``chunked`` |
 ``recurrent``, with ``_scalar`` appended for a decay a head). A train step
 of the Kimi-Linear or the Qwen3-Next configuration on a TPU takes the
@@ -103,6 +103,7 @@ import jax.numpy as jnp
 
 from ..core.dispatch import apply_op
 from ..obs import metrics as obs_metrics
+from . import placement
 
 _CORE_TOTAL = obs_metrics.counter(
     "paddle_tpu_kda_core_total",
@@ -470,20 +471,17 @@ def core_path(seq, d_k=None, d_v=None, dtype=None):
     with keys ``d_k`` and values ``d_v`` wide in ``dtype``, from what can be
     observed: a chunk's set-up (pair terms, an inverse) pays from one
     diagonal sub-block on, else the recurrence; the Mosaic kernels
-    (``ops/pallas/linear_attention.py``) where the platform compiles them
-    (a TPU, or the ``pallas_interpret`` flag), the program's devices are
-    known (``ops.attention._placeable``: GSPMD cannot partition a Mosaic
-    call), the widths are one multiple of the 128 lanes, the operands bf16
-    or float32 and the row at least one chunk; the XLA scan everything
-    else."""
+    (``ops/pallas/linear_attention.py``) where the program may hold them
+    (``placement.kernel``: they run through ``on_mesh``), the widths are
+    one multiple of the 128 lanes, the operands bf16 or float32 and the row
+    at least one chunk; the XLA scan everything else."""
     if seq < SUB:
         return "recurrent"
-    from . import attention
     from .pallas import linear_attention as kernels
 
     if (d_k is not None and seq >= kernels.CHUNK
             and kernels.supported(d_k, d_v, dtype)
-            and attention._use_pallas() and attention._placeable()):
+            and placement.kernel(sharded=True)):
         return "kernel"
     return "chunked"
 
@@ -509,12 +507,10 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
         return apply_op("kda_core_recurrent", _recurrent_output, q, k, v, g,
                         beta)
     if path == "kernel":
-        from ..core import flags
-
         # interpret rides the static kwargs so a flag flip retraces
         return apply_op(
             "kda_core_kernel", _kernel_output, q, k, v, g, beta,
-            interpret=bool(flags.flag_value("pallas_interpret")))
+            interpret=placement.kernel(sharded=True) == "interpret")
     return apply_op("kda_core", _chunked_output, q, k, v, g, beta,
                     chunk=int(chunk))
 
@@ -549,21 +545,17 @@ def _chunked_output(q, k, v, g, beta, *, chunk):
 
 
 def _kernel_output(q, k, v, g, beta, *, interpret):
-    """The Mosaic kernels, under a step's announced mesh inside the
-    ``shard_map`` the attention kernels use: rows over the data axes, heads
+    """The Mosaic kernels, under a step's announced mesh inside
+    ``placement.on_mesh``'s ``shard_map``: rows over the data axes, heads
     (dim 2 of all five arrays, in either rank: a stream's heads are
     contiguous lane slices) over 'mp', each where it divides."""
-    from . import attention
     from .pallas import linear_attention as kernels
 
-    def kernel(q, k, v, g, beta, seed):
-        del seed                                  # no dropout in the scan
+    def kernel(q, k, v, g, beta):
         return kernels.kda(q, k.astype(q.dtype), v.astype(q.dtype), g, beta,
                            interpret=interpret).astype(v.dtype)
 
-    return attention._on_mesh(kernel, (q, k, v, g, beta),
-                              jnp.zeros((), jnp.int32), head_axis=2,
-                              seed_per_shard=False)
+    return placement.on_mesh(kernel, (q, k, v, g, beta), head_axis=2)
 
 
 # ------------------------------------------------- the convolution stage
@@ -572,23 +564,20 @@ def conv_path(seq, segments, head, taps, dtype):
     tokens whose ``segments`` (``(stream, start, width, scale)``, as
     ``conv_streams`` takes them) have heads ``head`` wide and ``taps`` taps
     in ``dtype``, from what can be observed: the Mosaic kernels where the
-    platform compiles them (a TPU, or the ``pallas_interpret`` flag), the
-    program's devices are known (``ops.attention._placeable``), the heads
-    and every segment's channels fill whole lane groups, the history fits
-    the rows the kernels carry, the streams are bf16 or float32 and the row
-    is at least one token block; under an announced mesh with an ``mp``
-    axis, where that axis cuts every stream between whole heads of ONE
-    segment. The XLA stage everything else."""
-    from ..distributed import topology
-    from . import attention
+    program may hold them (``placement.kernel``: they run through
+    ``on_mesh``), the heads and every segment's channels fill whole lane
+    groups, the history fits the rows the kernels carry, the streams are
+    bf16 or float32 and the row is at least one token block; where
+    ``on_mesh`` cuts the heads over an 'mp' axis, only if that cuts every
+    stream between whole heads of ONE segment. The XLA stage everything
+    else."""
     from .pallas import linear_attention as kernels
 
     if not (seq >= kernels.CONV_TOKENS
             and kernels.conv_supported(segments, head, taps, dtype)
-            and attention._use_pallas() and attention._placeable()):
+            and placement.kernel(sharded=True)):
         return "xla"
-    mesh = topology.traced_mesh()
-    mp = 1 if mesh is None else mesh.shape.get("mp", 1)
+    mp = placement.axis_size("mp")
     streams = [stream for stream, *_ in segments]
     if mp > 1 and not (len(set(streams)) == len(streams) and all(
             width % (mp * head) == 0 for _, _, width, _ in segments)):
@@ -596,7 +585,17 @@ def conv_path(seq, segments, head, taps, dtype):
     return "kernel"
 
 
-def conv_streams(xs, ws, segments, *, head, eps, interpret=None):
+def conv_kernel(x, w, segments, head):
+    """One call's decision, counted, as ``conv_streams`` takes it:
+    ``placement.kernel``'s answer where ``conv_path`` says ``kernel`` for
+    streams like ``x`` and taps like ``w``, else None (the XLA stage). An
+    op's caller asks OUTSIDE the op; the answer rides its static arguments."""
+    path = conv_path(x.shape[1], segments, head, w.shape[0], x.dtype)
+    _CONV_TOTAL.inc(path=path)
+    return placement.kernel(sharded=True) if path == "kernel" else None
+
+
+def conv_streams(xs, ws, segments, *, head, eps, kernel="ask"):
     """Linear attention's stage between the projections and the scan, on
     arrays: streams ``xs`` [B, T, C_i] with their taps ``ws`` [K, C_i] ->
     one [B, T, width] array a segment ``(stream, start, width, scale)``:
@@ -605,22 +604,16 @@ def conv_streams(xs, ws, segments, *, head, eps, interpret=None):
     SiLU, then, where ``scale`` is a number, L2-normalised over each
     ``head`` features in float32 and scaled; where it is None, as SiLU left
     them. The segments cover every stream in order. Results take their
-    stream's dtype. Counts the path (``conv_path``) once a trace.
-    ``interpret``: the ``pallas_interpret`` flag unless given (a caller
-    under ``apply_op`` passes it, so that a flag flip retraces)."""
-    from ..core import flags
-
+    stream's dtype. ``kernel``: ``conv_kernel``'s answer, from a caller under
+    ``apply_op``; ``"ask"`` (under the caller's own jit): taken here."""
     segments = tuple((int(s), int(a), int(n), None if c is None else float(c))
                      for s, a, n, c in segments)
-    path = conv_path(xs[0].shape[1], segments, head, ws[0].shape[0],
-                     xs[0].dtype)
-    _CONV_TOTAL.inc(path=path)
-    if path == "xla":
+    if kernel == "ask":
+        kernel = conv_kernel(xs[0], ws[0], segments, head)
+    if kernel is None:
         return _conv_xla(tuple(xs), tuple(ws), segments, head, eps)
-    if interpret is None:
-        interpret = bool(flags.flag_value("pallas_interpret"))
     return _conv_kernel(tuple(xs), tuple(ws), segments, head, eps,
-                        interpret)
+                        kernel == "interpret")
 
 
 def _conv_xla(xs, ws, segments, head, eps):
@@ -643,18 +636,16 @@ def _conv_xla(xs, ws, segments, head, eps):
 
 
 def _conv_kernel(xs, ws, segments, head, eps, interpret):
-    """The Mosaic kernels, under a step's announced mesh inside the
-    ``shard_map`` the attention kernels use: rows over the data axes and,
+    """The Mosaic kernels, under a step's announced mesh inside
+    ``placement.on_mesh``'s ``shard_map``: rows over the data axes and,
     where ``conv_path`` lets an ``mp`` axis through, every stream's heads
     over it. The taps go a copy a row ([B, K, C]), so that they shard as
     the streams do and their gradient is summed over the rows outside."""
-    from . import attention
     from .pallas import linear_attention as kernels
 
     n, batch = len(xs), xs[0].shape[0]
 
-    def kernel(*arrays, seed):
-        del seed                                # no dropout in the stage
+    def kernel(*arrays):
         xs, ws = arrays[:n], arrays[n:]
         # a stream cut over 'mp' is one segment: what this shard holds of it
         local = tuple((s, start, min(width, xs[s].shape[-1]), scale)
@@ -663,6 +654,4 @@ def _conv_kernel(xs, ws, segments, head, eps, interpret):
                                     interpret=interpret)
 
     rows = tuple(jnp.broadcast_to(w[None], (batch,) + w.shape) for w in ws)
-    return tuple(attention._on_mesh(
-        kernel, (*xs, *rows), jnp.zeros((), jnp.int32), head_axis=2,
-        seed_per_shard=False))
+    return tuple(placement.on_mesh(kernel, (*xs, *rows), head_axis=2))

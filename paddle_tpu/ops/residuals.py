@@ -17,7 +17,7 @@ recomputed forward runs anyway.
 The policy reaches the operations a block's trace holds directly, under a
 ``jit`` and inside a ``shard_map`` (jax's partial evaluation hands it on
 into the map's body, and what is kept leaves the map as an output): a
-kernel that ``ops.attention._on_mesh`` shards over an announced mesh keeps
+kernel that ``ops.placement.on_mesh`` shards over an announced mesh keeps
 its residuals too. The counter says what happened in a trace: ``offered``
 by a kernel's forward rule, ``kept`` by a block's policy — a step that
 recomputes its blocks and reads ``offered`` without ``kept`` runs its

@@ -874,26 +874,21 @@ def _gated_head_norm(o, gate, w, heads, *, eps, activation):
     return (normed * activation(gate.astype(jnp.float32))).astype(o.dtype)
 
 
-def _pallas_interpret():
-    """The ``pallas_interpret`` flag for an op's static arguments (an eager
-    trace is cached by them: a flag flip retraces)."""
-    from ..core import flags
-
-    return bool(flags.flag_value("pallas_interpret"))
+def _kda_segments(wide, d):
+    """``conv_streams``' segments of ``_kda_streams``' three streams."""
+    return ((0, 0, wide, d ** -0.5), (1, 0, wide, 1.0), (2, 0, wide, None))
 
 
-def _kda_streams(q, k, v, w_q, w_k, w_v, *, heads, eps, interpret=None):
+def _kda_streams(q, k, v, w_q, w_k, w_v, *, heads, eps, kernel="ask"):
     """The projected [B, T, H * d] streams, each through its causal
     depthwise convolution and SiLU, q and k then L2-normalised over a
     head's features (in float32), q scaled by d^-0.5: three [B, T, H * d]
-    streams."""
+    streams. ``kernel``: ``conv_kernel``'s answer, taken outside the op."""
     from ..ops.linear_attention import conv_streams
 
     wide, d = q.shape[-1], q.shape[-1] // heads
-    return conv_streams(
-        (q, k, v), (w_q, w_k, w_v),
-        ((0, 0, wide, d ** -0.5), (1, 0, wide, 1.0), (2, 0, wide, None)),
-        head=d, eps=eps, interpret=interpret)
+    return conv_streams((q, k, v), (w_q, w_k, w_v), _kda_segments(wide, d),
+                        head=d, eps=eps, kernel=kernel)
 
 
 def _kda_decay(low, w_up, a_log, dt_bias, *, heads):
@@ -997,7 +992,7 @@ class KimiDeltaAttention(nn.Layer):
         import jax
 
         from ..core.dispatch import apply_op
-        from ..ops.linear_attention import gated_delta_rule
+        from ..ops.linear_attention import conv_kernel, gated_delta_rule
 
         with jax.named_scope("kda.proj"):
             q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
@@ -1005,8 +1000,9 @@ class KimiDeltaAttention(nn.Layer):
             q, k, v = apply_op(
                 "kda_streams", _kda_streams, q, k, v, self.q_conv.weight,
                 self.k_conv.weight, self.v_conv.weight,
-                heads=self.num_heads, eps=self.l2_eps,
-                interpret=_pallas_interpret())
+                heads=self.num_heads, eps=self.l2_eps, kernel=conv_kernel(
+                    q, self.q_conv.weight, _kda_segments(
+                        q.shape[-1], self.head_dim), self.head_dim))
         with jax.named_scope("kda.gate"):
             g = apply_op("kda_decay", _kda_decay, self.f_a_proj(x),
                          self.f_b_proj.weight, self.A_log, self.dt_bias,
@@ -1171,20 +1167,24 @@ def _gdn_split(qkvz, ba, *, key_heads, d_k, d_v, per_key):
             y[..., per_key:].reshape(*lead, -1))
 
 
-def _gdn_streams(mixed, w, *, key_heads, d_k, eps, interpret=None):
+def _gdn_segments(wide, key_heads, d_k):
+    """``conv_streams``' segments of ``_gdn_streams``' ONE stream."""
+    key = key_heads * d_k
+    return ((0, 0, key, d_k ** -0.5), (0, key, key, 1.0),
+            (0, 2 * key, wide - 2 * key, None))
+
+
+def _gdn_streams(mixed, w, *, key_heads, d_k, eps, kernel="ask"):
     """The q | k | v stream through ONE causal depthwise convolution and
     SiLU -> q, k [B, T, H_k * d_k] L2-normalised a head (in float32), q
     scaled by d_k^-0.5, and v [B, T, H_v * d_v]: streams, as
     KimiDeltaAttention's, and the same op — three segments of one
-    stream."""
+    stream. ``kernel``: ``conv_kernel``'s answer, taken outside the op."""
     from ..ops.linear_attention import conv_streams
 
-    key = key_heads * d_k
     return conv_streams(
-        (mixed,), (w,),
-        ((0, 0, key, d_k ** -0.5), (0, key, key, 1.0),
-         (0, 2 * key, mixed.shape[-1] - 2 * key, None)),
-        head=d_k, eps=eps, interpret=interpret)
+        (mixed,), (w,), _gdn_segments(mixed.shape[-1], key_heads, d_k),
+        head=d_k, eps=eps, kernel=kernel)
 
 
 def _gdn_decay(a, a_log, dt_bias):
@@ -1322,7 +1322,7 @@ class GatedDeltaNet(nn.Layer):
         import jax
 
         from ..core.dispatch import apply_op
-        from ..ops.linear_attention import gated_delta_rule
+        from ..ops.linear_attention import conv_kernel, gated_delta_rule
 
         per_key = self.num_v_heads // self.num_k_heads
         with jax.named_scope("gdn.proj"):
@@ -1334,7 +1334,10 @@ class GatedDeltaNet(nn.Layer):
             q, k, v = apply_op(
                 "gdn_streams", _gdn_streams, mixed, self.conv1d.weight,
                 key_heads=self.num_k_heads, d_k=self.head_k_dim,
-                eps=self.l2_eps, interpret=_pallas_interpret())
+                eps=self.l2_eps, kernel=conv_kernel(
+                    mixed, self.conv1d.weight, _gdn_segments(
+                        mixed.shape[-1], self.num_k_heads, self.head_k_dim),
+                    self.head_k_dim))
         with jax.named_scope("gdn.gate"):
             g = apply_op("gdn_decay", _gdn_decay, a, self.A_log,
                          self.dt_bias)

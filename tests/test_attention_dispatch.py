@@ -25,7 +25,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.core import dispatch, flags
-from paddle_tpu.ops import attention
+from paddle_tpu.ops import attention, placement
 
 
 @pytest.fixture(autouse=True)
@@ -193,7 +193,7 @@ def test_short_needs_to_know_the_programs_devices(where, route, monkeypatch):
     assert jax.device_count() > 1                       # conftest's mesh
     interpret = where == "interpreter-many-devices"
     paddle.set_flags({"pallas_interpret": interpret})
-    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(placement, "is_tpu_available", lambda: True)
     if where == "one-device":
         monkeypatch.setattr(jax, "device_count", lambda: 1)
     mesh = topology.build_mesh(dp=jax.device_count())
@@ -303,11 +303,11 @@ def test_kernel_failure_raises(monkeypatch, recwarn):
 
 def test_kernel_not_selected_off_tpu_without_the_flag():
     paddle.set_flags({"pallas_interpret": False})
-    assert attention._use_pallas() is False  # CPU backend, no flag
+    assert placement.kernel(sharded=True) is None  # CPU backend, no flag
     paddle.set_flags({"pallas_interpret": True})
-    assert attention._use_pallas() is True
+    assert placement.kernel(sharded=True) == "interpret"
     paddle.set_flags({"use_pallas_kernels": False})
     try:
-        assert attention._use_pallas() is False
+        assert placement.kernel(sharded=True) is None
     finally:
         paddle.set_flags({"use_pallas_kernels": True})

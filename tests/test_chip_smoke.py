@@ -296,7 +296,7 @@ def test_step_builders_announce_their_mesh(builder, monkeypatch):
     """The gate picks the short kernel only where it knows the program's
     devices, so every builder that owns a mesh says so (tracing_for). The
     kernel then sees one shard's rows with every mesh axis manual around
-    it: under _on_mesh's shard_map, or LocalSGD's own."""
+    it: under on_mesh's shard_map, or LocalSGD's own."""
     from paddle_tpu import nn, optimizer
     from paddle_tpu.distributed import comm_opt, spmd, topology
     from paddle_tpu.ops import attention

@@ -4,7 +4,7 @@ band mask, in the Pallas interpreter on the CPU: outputs and all three
 gradients for windows smaller than a block, not a multiple of a block, equal
 to and longer than the sequence (the last two ARE the causal kernel, bit for
 bit), with and without dropout's mask, the one-pass and the two-call
-backward, under ``_on_mesh`` on a dp2 x mp2 host mesh; the gate with a
+backward, under ``on_mesh`` on a dp2 x mp2 host mesh; the gate with a
 window, its counter, and the band the ``xla`` route builds.
 tests/test_mosaic_compile.py compiles the same calls with Mosaic at the
 Trinity-Mini cell's shape."""
@@ -276,7 +276,7 @@ def test_the_xla_route_builds_the_band_itself():
 
 
 def test_band_shards_over_an_announced_mesh(kernels):
-    """Under ``_on_mesh`` on a dp2 x mp2 host mesh the banded kernel runs a
+    """Under ``on_mesh`` on a dp2 x mp2 host mesh the banded kernel runs a
     shard a device (batch over dp, heads over mp) and gives the unsharded
     call's outputs and gradients."""
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -17,8 +17,8 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
-from paddle_tpu.ops import attention
 from paddle_tpu.ops import linear_attention as la
+from paddle_tpu.ops import placement
 from paddle_tpu.ops.pallas import linear_attention as kernels
 
 HEADS, D_K, D_V = 3, 32, 16
@@ -303,10 +303,10 @@ def test_the_route_needs_a_platform_and_known_devices(monkeypatch):
     jit on several devices): the XLA scan again."""
     shape = (16384, 128, 128, jnp.bfloat16)
     assert la.core_path(*shape) == "chunked"
-    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
-    monkeypatch.setattr(attention, "_placeable", lambda: False)
+    monkeypatch.setattr(placement, "is_tpu_available", lambda: True)
+    assert jax.device_count() > 1                       # conftest's mesh
     assert la.core_path(*shape) == "chunked"
-    monkeypatch.setattr(attention, "_placeable", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
     assert la.core_path(*shape) == "kernel"
 
 
@@ -667,7 +667,7 @@ def test_the_entry_point_counts_the_entry_with_the_path(
 
 
 def test_streams_shard_their_heads_over_an_announced_mesh(interpreter):
-    """Streams under ``_on_mesh``: dim 2 holds heads x d and shards over
+    """Streams under ``placement.on_mesh``: dim 2 holds heads x d and shards over
     'mp' where the HEADS divide (a head stays a contiguous slice); three
     heads on mp = 2 are computed whole on both devices, not cut through a
     head."""
@@ -866,10 +866,10 @@ def test_the_convolution_path_needs_a_platform_and_known_devices(
 
     shape = (16384, KDA_SEGMENTS, 128, 4, jnp.bfloat16)
     assert la.conv_path(*shape) == "xla"
-    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
-    monkeypatch.setattr(attention, "_placeable", lambda: False)
+    monkeypatch.setattr(placement, "is_tpu_available", lambda: True)
+    assert jax.device_count() > 1                       # conftest's mesh
     assert la.conv_path(*shape) == "xla"
-    monkeypatch.setattr(attention, "_placeable", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
     assert la.conv_path(*shape) == "kernel"
     for axes, kda, gdn in ((dict(dp=4), "kernel", "kernel"),
                            (dict(dp=2, mp=2), "kernel", "xla"),

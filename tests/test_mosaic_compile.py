@@ -203,7 +203,7 @@ def _child():
     import paddle_tpu.nn.functional as F
     from paddle_tpu.core import random as random_core
     from paddle_tpu.distributed import topology
-    from paddle_tpu.ops import attention
+    from paddle_tpu.ops import attention, placement
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     # an executable for a described device cannot be read back
@@ -323,7 +323,7 @@ def _child():
     from paddle_tpu.distributed.fleet.utils import recompute
     from paddle_tpu.text.models import MLAttention
 
-    attention._use_pallas = lambda: True
+    placement.is_tpu_available = lambda: True
     one_mesh = topology.build_mesh(dp=1, devices=v5e[:1])
 
     def mosaic_calls(text):
@@ -479,10 +479,8 @@ def _child():
         # what the cell's process observes: a platform that compiles
         # Mosaic and one device (here only the compiler is a TPU's and the
         # CPU devices are four, so the gate is told)
-        from paddle_tpu.ops import attention
-
-        here = attention._use_pallas, attention._placeable
-        attention._use_pallas = attention._placeable = lambda: True
+        here = placement.kernel
+        placement.kernel = lambda **site: "mosaic"
         try:
             text = jax.jit(jax.grad(
                 lambda *a: jnp.sum(layer(*a).astype(f32)),
@@ -491,7 +489,7 @@ def _child():
             conv_args = (args[:6] if name[:3] == "kda" else args[:2])
             conv = jax.jit(stage_alone).lower(*conv_args).compile()
         finally:
-            attention._use_pallas, attention._placeable = here
+            placement.kernel = here
         conv_text = conv.as_text()
         out["conv-" + name] = {
             "mosaic": conv_text.count(MOSAIC),

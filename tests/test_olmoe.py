@@ -170,7 +170,7 @@ def dense_every_expert(x, topv, topi, w_gate, w_up, w_down):
     return jnp.einsum("nkh,nk->nh", picked, topv).reshape(x.shape)
 
 
-def sorted_path(x, topv, topi, w_gate, w_up, w_down, kernel="xla"):
+def sorted_path(x, topv, topi, w_gate, w_up, w_down, kernel=None):
     ys, order, inv = moe._sorted_experts(x, topi, w_gate, w_up, w_down,
                                          kernel=kernel)
     return moe._combine(ys, topv, order, inv, shape=x.shape)
@@ -190,7 +190,8 @@ def _routing(case, n, e, rng):
     return jnp.asarray(topv, jnp.float32), jnp.asarray(topi, jnp.int32)
 
 
-@pytest.mark.parametrize("kernel", ["xla", "interpret"])
+@pytest.mark.parametrize("kernel", [None, "interpret"],
+                         ids=["xla", "interpret"])
 @pytest.mark.parametrize("case", ["uniform", "drawn", "one-expert-empty",
                                   "all-on-one-expert"])
 def test_dropless_path_equals_every_expert_dense(case, kernel):

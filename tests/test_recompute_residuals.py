@@ -204,7 +204,7 @@ def test_a_block_without_a_kernel_is_checkpointed_as_before(
 @pytest.mark.parametrize("mode", ["plain", "kept"])
 def test_on_a_mesh_a_recomputed_block_traces_and_matches(
         mode, residual_counts):
-    """On an announced dp2 mesh the kernel runs inside ``_on_mesh``'s
+    """On an announced dp2 mesh the kernel runs inside ``on_mesh``'s
     ``shard_map``. jax's partial evaluation hands a checkpoint's policy on
     into the map's body, so the residuals are kept there too (they leave
     the map as its outputs); kept or not, the block gives the unrecomputed
