@@ -164,7 +164,7 @@ _rows_to_token_order.defvjp(_rows_to_token_order_fwd,
 
 
 def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
-           scoring="softmax", routed_scale=1.0):
+           scoring="softmax", routed_scale=1.0, renorm_eps=1e-20):
     """The router in float32: [B, S, H] -> the k weights [N, k] and expert
     ids [N, k] of every token, the load-balancing term (Switch / HF form
     over all k choices) and the z-loss.
@@ -172,7 +172,8 @@ def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
     ``renorm``. 'sigmoid' (DeepSeek-V3's ``noaux_tc``): s = sigmoid(logits);
     the choice is top-k of s + ``select_bias``, the weights are s at the
     chosen experts (the bias moves the choice and never the weight),
-    divided by their sum if ``renorm``, times ``routed_scale``; the
+    divided by their sum + ``renorm_eps`` if ``renorm`` (the sources differ
+    in what they add: 1e-20, LFM2 1e-6), times ``routed_scale``; the
     balancing term takes s normalised over the experts, and there is no
     z-loss."""
     with jax.named_scope("moe.route"):
@@ -197,7 +198,8 @@ def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
             topv = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32)
                            * scores[:, None, :], axis=-1)
             if renorm:
-                topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+                topv = topv / (jnp.sum(topv, axis=-1, keepdims=True)
+                               + renorm_eps)
             topv = topv * routed_scale
             probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
         else:
@@ -222,13 +224,29 @@ def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
 _GMM_TILING = {2: (512, 1024, 1024), 4: (256, 512, 512)}
 
 
+def _operand_tile(tile, size):
+    """The tile for an operand ``size`` wide under the table's ``tile``: the
+    operand itself where it is no wider (width 768 under 1,024), the tile
+    where it divides the operand, else the largest multiple of the 128
+    lanes under the tile that does (1,792 = 7 x 256 takes 896: two full
+    tiles where 1,024 would leave the second a quarter empty on ``n`` and a
+    masked remainder on ``k``; as many grid steps, an eighth less tile
+    work). An operand that no multiple of 128 divides keeps the table's
+    tile, and the kernel masks its last one."""
+    if size <= tile:
+        return size
+    return next((t for t in range(tile, 0, -128) if size % t == 0), tile)
+
+
 def _gmm_tiling(rows, itemsize, k, n):
     """The kernel's tiling for this many assigned rows of a [k -> n] gemm,
     or None where it cannot take them: its row tile has to divide the
-    rows. Where a tile is wider than the operand (an expert of width 768
-    under the 1024 tile) the answer is the kernel's table form, a function
-    of each call's (m, k, n), so that the backward calls, whose k and n
-    swap, are clamped too and no tile is masked or half empty."""
+    rows. Where the table's tile does not divide an operand — it is wider
+    than the operand, or the operand is no multiple of it — the answer is
+    the kernel's table form, a function of each call's (m, k, n), so that
+    the backward calls, whose k and n swap, are answered too: every
+    operand takes ``_operand_tile``'s divisor, and no tile is masked or
+    part empty."""
     want = _GMM_TILING.get(itemsize)
     if want is None:
         return None
@@ -238,7 +256,8 @@ def _gmm_tiling(rows, itemsize, k, n):
         return None
     if k % want[1] == 0 and n % want[2] == 0:
         return (tm,) + want[1:]
-    return lambda m, k, n: (tm, min(want[1], k), min(want[2], n))
+    return lambda m, k, n: (tm, _operand_tile(want[1], k),
+                            _operand_tile(want[2], n))
 
 
 def _grouped_matmul(rows, w, group_sizes, kernel):
@@ -553,7 +572,8 @@ class MoELayer(nn.Layer):
     softmax's own values (OLMoE). ``activation``: 'gelu' (two matrices)
     or 'swiglu' (three). ``scoring='sigmoid'``, ``select_bias``,
     ``bias_update_speed``, ``routed_scale`` and ``shared_width`` give the
-    DeepSeek-V3 family's layer, ``held=(first, count)`` one chip's range of
+    DeepSeek-V3 family's layer (``renorm_eps``: what its renormalisation
+    adds to the sum), ``held=(first, count)`` one chip's range of
     its experts (module docstring). The auxiliary losses (load balancing times
     ``aux_weight``, router z-loss times ``z_loss_weight``) are routed
     through ``nn.aux_loss.emit_aux_loss``: in eager mode they land on
@@ -571,7 +591,7 @@ class MoELayer(nn.Layer):
                  norm_topk_prob=True, z_loss_weight=0.0, weight_attr=None,
                  scoring="softmax", select_bias=False, bias_update_speed=0.0,
                  routed_scale=1.0, shared_width=0, held=None,
-                 held_rows_factor=2.0, shared_gate=False):
+                 held_rows_factor=2.0, shared_gate=False, renorm_eps=1e-20):
         super().__init__()
         self.num_experts = int(num_experts)
         self.top_k = int(top_k)
@@ -589,6 +609,7 @@ class MoELayer(nn.Layer):
                              f"{scoring!r}")
         self.scoring = scoring
         self.routed_scale = float(routed_scale)
+        self.renorm_eps = float(renorm_eps)
         self.bias_update_speed = float(bias_update_speed)
         first, count = (0, self.num_experts) if held is None else held
         if not 0 <= first < first + count <= self.num_experts:
@@ -761,7 +782,7 @@ class MoELayer(nn.Layer):
             "moe_route", _route, x, self.gate.weight, self.gate.bias,
             self.e_score_correction_bias, top_k=self.top_k,
             renorm=self.norm_topk_prob, scoring=self.scoring,
-            routed_scale=self.routed_scale)
+            routed_scale=self.routed_scale, renorm_eps=self.renorm_eps)
         kernel = placement.kernel(sharded=False)   # no shard_map of its own
         if self.held is None:
             ys, order, inv = apply_op(

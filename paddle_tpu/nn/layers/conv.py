@@ -51,7 +51,11 @@ class Conv1D(_ConvNd):
 class CausalDepthwiseConv1D(Layer):
     """``F.causal_depthwise_conv1d`` with its weight: [kernel_size,
     channels], one filter a channel over the sequence axis of [batch, seq,
-    channels], no bias. Starts as torch's depthwise ``Conv1d`` does
+    channels], no bias. A row's history is its own: the ``kernel_size`` - 1
+    positions before a row's first token are zeros, whatever the batch
+    holds before it (row r never reads row r - 1; a caller that carries a
+    history across calls, as a decoder would, has to pass it in the row).
+    Starts as torch's depthwise ``Conv1d`` does
     (Uniform(+-1/sqrt(kernel_size)))."""
 
     def __init__(self, channels, kernel_size, activation=None,

@@ -12,7 +12,11 @@ causality, platform), one of:
   one tile (BERT at 128..512). Only ``packed_self_attention`` can take it:
   it owns the layout that makes the head transposes unnecessary.
 - ``stream``: the streaming flash kernel (``mha``) on [batch, heads, seq,
-  head_dim] for long keys, where XLA's S^2 logits buffer explodes.
+  head_dim] for long keys, where XLA's S^2 logits buffer explodes. Heads
+  of 64 (half a lane group a row: the cell ``lfm2-8b-a1b.train-lm-s8192-b4``,
+  32 query heads at 8,192 keys), 128 (OLMoE, Trinity-Mini), 256
+  (Qwen3-Next) and latent attention's 192-wide keys on 128-wide values
+  (JoyAI, Kimi-Linear) each run it in a cell.
 - ``xla``: the jnp implementation below, which XLA fuses into a few
   kernels — every masked call, every CPU run without the interpreter flag.
 

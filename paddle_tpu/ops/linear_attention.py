@@ -95,6 +95,12 @@ the streams, its float32 in VMEM; ``ops/pallas/linear_attention.py``) or
 ``xla`` (this file's ``_conv_xla``: float32 arrays under a
 ``jax.checkpoint``, what every other program runs and what the kernels are
 held to), counted in ``paddle_tpu_conv_streams_total{path}``.
+
+**The gated short convolution** (``gated_short_conv``) is another model's
+whole mixer, not a stage before a scan: LFM2's ``C * conv3(B * u)`` between
+its two projections, no activation, no norm. It runs in XLA operations
+under a ``jax.checkpoint`` of its own, as ``_conv_xla`` does, and counts in
+``paddle_tpu_shortconv_total{path}`` (``xla`` today).
 """
 import functools
 
@@ -125,6 +131,12 @@ _CONV_TOTAL = obs_metrics.counter(
     "linear attention's convolution stages (taps, SiLU, q's and k's L2 "
     "norm) by the path taken: kernel (one Mosaic call a pass) | xla; one "
     "count per traced layer call",
+    labelnames=("path",))
+
+_SHORTCONV_TOTAL = obs_metrics.counter(
+    "paddle_tpu_shortconv_total",
+    "gated short convolutions (gate, taps, gate between two projections: "
+    "LFM2's mixer) by the path taken: xla; one count per traced layer call",
     labelnames=("path",))
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -655,3 +667,35 @@ def _conv_kernel(xs, ws, segments, head, eps, interpret):
 
     rows = tuple(jnp.broadcast_to(w[None], (batch,) + w.shape) for w in ws)
     return tuple(placement.on_mesh(kernel, (*xs, *rows), head_axis=2))
+
+
+# ------------------------------------------- the gated short convolution
+def shortconv_path():
+    """One call's decision, counted, as ``gated_short_conv``'s caller takes
+    it OUTSIDE the op: ``xla``, the one path there is (a Mosaic stage for
+    gate-taps-gate would be chosen here, as ``conv_path`` chooses)."""
+    _SHORTCONV_TOTAL.inc(path="xla")
+    return "xla"
+
+
+def gated_short_conv(bcu, w):
+    """LFM2's stage between its two projections, on arrays: ``bcu`` [B, T,
+    3 C] as ``in_proj`` leaves it, split [B | C | u] in that order, and the
+    taps ``w`` [K, C] -> ``C * conv(B * u)`` [B, T, C] in bcu's dtype. The
+    convolution is causal and depthwise (``F.causal_depthwise_conv1d``: tap
+    K - 1 meets the token itself, zero history before a row's first
+    token — a row never sees another row), no bias, no activation. Gates
+    and taps are float32 arrays as large as a stream, so a
+    ``jax.checkpoint`` of its own, as ``_conv_xla``: a differentiated
+    program keeps ``bcu`` and rebuilds the float32 inside it."""
+    from ..nn import functional as F
+
+    channels = w.shape[1]
+
+    def stage(bcu, w):
+        b, c, u = (bcu[..., i * channels:(i + 1) * channels]
+                   .astype(jnp.float32) for i in range(3))
+        mixed = F._causal_depthwise_conv1d(b * u, w, activation=None)
+        return (c * mixed).astype(bcu.dtype)
+
+    return jax.checkpoint(stage)(bcu, w)

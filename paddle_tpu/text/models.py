@@ -246,6 +246,12 @@ def _rope(x, base=10000.0, positions=None, pairing="interleaved",
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape)
 
 
+def _merge_heads(o):
+    """[B, H, T, d] -> [B, T, H x d]."""
+    b, h, t, d = o.shape
+    return o.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
 class LlamaAttention(nn.Layer):
     def __init__(self, hidden_size, num_heads, num_kv_heads=None):
         super().__init__()
@@ -425,12 +431,7 @@ class OlmoeAttention(nn.Layer):
             self.k_norm(self.k_proj(x)), self.v_proj(x),
             nh=self.num_heads, hd=self.head_dim, base=self.rope_theta)
         out = _sdpa(q, k, v, is_causal=True, training=self.training)
-
-        def _merge(out):
-            b, h, t, d = out.shape
-            return out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-
-        return self.o_proj(apply_op("merge_heads", _merge, out))
+        return self.o_proj(apply_op("merge_heads", _merge_heads, out))
 
 
 class OlmoeDecoderLayer(nn.Layer):
@@ -598,13 +599,8 @@ class MLAttention(nn.Layer):
                                base=self.rope_theta, rotate=self.rope)
         with jax.named_scope("mla.core"):
             out = _sdpa(q, k, v, is_causal=True, training=self.training)
-
-        def _merge(out):
-            b, h, t, d = out.shape
-            return out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-
         with jax.named_scope("mla.out"):
-            return self.o_proj(apply_op("merge_heads", _merge, out))
+            return self.o_proj(apply_op("merge_heads", _merge_heads, out))
 
 
 class JoyAIDecoderLayer(nn.Layer):
@@ -1802,3 +1798,261 @@ def afmoe_layer_types(num_hidden_layers, global_attn_every_n_layers=4):
     the interval, else ``sliding_attention`` (the published ``layer_types``)."""
     return ["full_attention" if (i + 1) % global_attn_every_n_layers == 0
             else "sliding_attention" for i in range(num_hidden_layers)]
+
+
+# ------------------------------------------------------------ LFM2 (lfm2_moe)
+class Lfm2ShortConv(nn.Layer):
+    """LFM2's token mixer in its ``conv`` layers, the doubly gated short
+    convolution: ``[B | C | u] = in_proj(x)`` (hidden -> 3 x hidden, split
+    in that order), ``y = out_proj(C * conv(B * u))`` with ``conv`` a causal
+    depthwise convolution of ``kernel_size`` (``conv_L_cache`` = 3) taps,
+    one filter a channel; no bias, no activation, nothing recurrent: the
+    mixer reaches ``kernel_size`` tokens, and a row's first tokens see
+    zeros, never another row. The stage between the projections is
+    ``ops.linear_attention.gated_short_conv`` (XLA operations under a
+    ``jax.checkpoint`` of their own; ``paddle_tpu_shortconv_total{path}``).
+    Scopes: ``shortconv.in_proj`` / ``.stage`` / ``.out_proj``."""
+
+    def __init__(self, hidden_size, kernel_size=3, weight_attr=None):
+        super().__init__()
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.in_proj = proj(hidden_size, 3 * hidden_size)
+        self.conv = nn.CausalDepthwiseConv1D(hidden_size, kernel_size)
+        self.out_proj = proj(hidden_size, hidden_size)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops import linear_attention
+
+        with jax.named_scope("shortconv.in_proj"):
+            bcu = self.in_proj(x)
+        with jax.named_scope("shortconv.stage"):
+            linear_attention.shortconv_path()
+            y = apply_op("gated_short_conv",
+                         linear_attention.gated_short_conv, bcu,
+                         self.conv.weight)
+        with jax.named_scope("shortconv.out_proj"):
+            return self.out_proj(y)
+
+
+class Lfm2Attention(nn.Layer):
+    """LFM2's ``full_attention`` mixer: grouped queries (``num_heads`` on
+    ``num_kv_heads`` heads of ``head_dim`` = hidden / heads = 64), an
+    RMSNorm over each head's features of q and of k (``q_layernorm``,
+    ``k_layernorm``, weight from 1), rotate-half RoPE over all of them,
+    causal softmax at ``head_dim ** -0.5`` with query head h on key/value
+    head ``h // (heads / kv_heads)``, then ``out_proj``; no bias, no gate.
+    The core goes through the dispatching sdpa (the streaming flash kernel
+    at long sequences), K and V REPEATED to the query heads first (ROADMAP
+    Speed 13). Scopes: ``lfm2attn.proj`` (q, k, v) / ``.qk`` (norms, RoPE,
+    the head split) / ``.repeat`` / ``.core`` / ``.out`` (merge,
+    ``out_proj``)."""
+
+    def __init__(self, hidden_size, num_heads=32, num_kv_heads=8,
+                 rope_theta=1e6, rms_norm_eps=1e-5, weight_attr=None):
+        super().__init__()
+        if hidden_size % num_heads or num_heads % num_kv_heads:
+            raise ValueError(
+                f"{num_heads} query heads on {num_kv_heads} key/value heads "
+                f"do not divide a hidden size of {hidden_size}")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = hidden_size // num_heads
+        self.rope_theta = float(rope_theta)
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.q_proj = proj(hidden_size, hidden_size)
+        self.k_proj = proj(hidden_size, num_kv_heads * self.head_dim)
+        self.v_proj = proj(hidden_size, num_kv_heads * self.head_dim)
+        self.out_proj = proj(hidden_size, hidden_size)
+        self.q_layernorm = ZeroCenteredRMSNorm(
+            self.head_dim, eps=rms_norm_eps, zero_centered=False)
+        self.k_layernorm = ZeroCenteredRMSNorm(
+            self.head_dim, eps=rms_norm_eps, zero_centered=False)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops.attention import scaled_dot_product_attention as _sdpa
+
+        with jax.named_scope("lfm2attn.proj"):
+            q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
+        with jax.named_scope("lfm2attn.qk"):
+            q, k, v = apply_op(
+                "afmoe_heads", _afmoe_heads, q, k, v,
+                self.q_layernorm.weight, self.k_layernorm.weight,
+                heads=self.num_heads, kv_heads=self.num_kv_heads,
+                d=self.head_dim, eps=self.q_layernorm.eps,
+                base=self.rope_theta, rope=True)
+        with jax.named_scope("lfm2attn.repeat"):
+            k, v = (apply_op("repeat_heads", _repeat_heads, t,
+                             repeats=self.num_heads // self.num_kv_heads,
+                             axis=1) for t in (k, v))
+        with jax.named_scope("lfm2attn.core"):
+            o = _sdpa(q, k, v, is_causal=True, training=self.training)
+        with jax.named_scope("lfm2attn.out"):
+            return self.out_proj(apply_op("merge_heads", _merge_heads, o))
+
+
+class Lfm2DecoderLayer(nn.Layer):
+    """LFM2's pre-norm block: ``a = h + Op(operator_norm(h))``, ``h' = a +
+    FFN(ffn_norm(a))``. The operator goes by the layer's type — the gated
+    short convolution (``conv``) or grouped-query attention
+    (``full_attention``) —, the FFN by its index: a dense SwiGLU in the
+    leading layers, else the expert layer: a sigmoid router over all
+    experts, the choice by score + ``expert_bias`` (moved without an
+    auxiliary loss), the chosen scores over their sum + 1e-6 times
+    ``routed_scaling_factor``, no shared expert, and the held range of the
+    routed experts."""
+
+    def __init__(self, cfg, layer_type, dense, weight_attr=None):
+        super().__init__()
+        from ..incubate.moe import MoELayer
+
+        if layer_type not in ("conv", "full_attention"):
+            raise ValueError(f"layer_type {layer_type!r} is neither 'conv' "
+                             "nor 'full_attention'")
+        hidden, eps = cfg["hidden_size"], cfg["norm_eps"]
+        self.operator_norm = ZeroCenteredRMSNorm(hidden, eps=eps,
+                                                 zero_centered=False)
+        self.is_attention = layer_type == "full_attention"
+        if self.is_attention:
+            self.self_attn = Lfm2Attention(
+                hidden, rms_norm_eps=eps, weight_attr=weight_attr,
+                **cfg["attention"])
+        else:
+            self.conv = Lfm2ShortConv(hidden, cfg["conv_L_cache"],
+                                      weight_attr)
+        self.ffn_norm = ZeroCenteredRMSNorm(hidden, eps=eps,
+                                            zero_centered=False)
+        if dense:
+            self.feed_forward = LlamaMLP(hidden, cfg["intermediate_size"],
+                                         weight_attr)
+        else:
+            self.feed_forward = MoELayer(
+                hidden, cfg["moe_intermediate_size"], cfg["num_experts"],
+                top_k=cfg["num_experts_per_tok"], activation="swiglu",
+                gate_bias=False, norm_topk_prob=cfg["norm_topk_prob"],
+                scoring="sigmoid", select_bias=cfg["use_expert_bias"],
+                bias_update_speed=cfg["bias_update_speed"],
+                routed_scale=cfg["routed_scaling_factor"], renorm_eps=1e-6,
+                held=cfg["held_experts"],
+                held_rows_factor=cfg["held_rows_factor"], aux_weight=0.0,
+                weight_attr=weight_attr)
+
+    def forward(self, x):
+        operator = self.self_attn if self.is_attention else self.conv
+        x = x + operator(self.operator_norm(x))
+        return x + self.feed_forward(self.ffn_norm(x))
+
+
+class _TiedHead(nn.Layer):
+    """The head that IS the embedding: ``logits = x E^T`` on the
+    embedding's own [vocab, hidden] parameter (one entry in the state, under
+    the embedding's name). ``weight`` gives it as an ``nn.Linear``'s
+    [hidden, vocab], what ``F.linear_cross_entropy`` takes."""
+
+    def __init__(self, embedding):
+        super().__init__()
+        self.embedding_weight = embedding.weight
+
+    @property
+    def weight(self):
+        from .. import tensor as pt
+
+        return pt.transpose(self.embedding_weight, [1, 0])
+
+    def forward(self, x):
+        from .. import tensor as pt
+
+        return pt.matmul(x, self.embedding_weight, transpose_y=True)
+
+
+class Lfm2Model(_BlockwiseModel):
+    """LFM2-8B-A1B (LiquidAI, HF ``lfm2_moe``): pre-norm blocks whose
+    operator goes by ``layer_types`` — the doubly gated short convolution
+    in three layers of every four, grouped-query attention at heads of
+    hidden / heads in the fourth —, the first ``num_dense_layers`` with a
+    dense SwiGLU and the others with the expert layer; a final norm
+    (``embedding_norm``) and a head TIED to the embedding. Defaults are the
+    published sizes.
+
+    ``held_experts=(first, count)`` gives every expert layer this chip's
+    range of the routed experts; ``use_recompute`` runs each block under
+    ``fleet.utils.recompute`` in a traced step. ``forward`` gives the
+    logits; a training loss takes ``features`` and ``lm_head.weight`` to
+    ``F.linear_cross_entropy``."""
+
+    def __init__(self, vocab_size=65536, hidden_size=2048,
+                 num_hidden_layers=24, num_attention_heads=32,
+                 num_key_value_heads=8, intermediate_size=7168,
+                 moe_intermediate_size=1792, num_experts=32,
+                 num_experts_per_tok=4, num_dense_layers=2, layer_types=None,
+                 conv_L_cache=3, rope_theta=1e6, norm_eps=1e-5,
+                 norm_topk_prob=True, use_expert_bias=True,
+                 routed_scaling_factor=1.0, bias_update_speed=0.001,
+                 initializer_range=0.02, held_experts=None,
+                 held_rows_factor=2.0, use_recompute=False):
+        super().__init__(use_recompute)
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        cfg = dict(
+            hidden_size=hidden_size, norm_eps=norm_eps,
+            intermediate_size=intermediate_size,
+            moe_intermediate_size=moe_intermediate_size,
+            num_experts=num_experts, num_experts_per_tok=num_experts_per_tok,
+            norm_topk_prob=norm_topk_prob, use_expert_bias=use_expert_bias,
+            routed_scaling_factor=routed_scaling_factor,
+            bias_update_speed=bias_update_speed, conv_L_cache=conv_L_cache,
+            held_experts=None if held_experts is None else tuple(held_experts),
+            held_rows_factor=held_rows_factor,
+            attention=dict(num_heads=num_attention_heads,
+                           num_kv_heads=num_key_value_heads,
+                           rope_theta=rope_theta))
+        self.layer_types = list(layer_types or lfm2_layer_types(
+            num_hidden_layers))
+        if len(self.layer_types) != num_hidden_layers:
+            raise ValueError(f"{len(self.layer_types)} layer types for "
+                             f"{num_hidden_layers} layers")
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            Lfm2DecoderLayer(cfg, layer_type, dense=i < num_dense_layers,
+                             weight_attr=attr())
+            for i, layer_type in enumerate(self.layer_types)])
+        self.embedding_norm = ZeroCenteredRMSNorm(hidden_size, eps=norm_eps,
+                                                  zero_centered=False)
+        self.lm_head = _TiedHead(self.embed_tokens)
+
+    def features(self, input_ids):
+        x = self.embed_tokens(input_ids)
+        for layer in self.layers:
+            x = self._block(layer, x)
+        return self.embedding_norm(x)
+
+    def forward(self, input_ids):
+        return self.lm_head(self.features(input_ids))
+
+
+#: LFM2-8B-A1B's published ``layer_types``: two convolution layers, then
+#: attention before every three of them, the last two periods one shorter
+_LFM2_ATTENTION_LAYERS = (2, 6, 10, 14, 18, 21)
+
+
+def lfm2_layer_types(num_hidden_layers):
+    """Layer i (from 0) of the published 24 is ``full_attention`` at 2, 6,
+    10, 14, 18 and 21 and ``conv`` elsewhere; another depth takes the
+    pattern's start."""
+    return ["full_attention" if i in _LFM2_ATTENTION_LAYERS else "conv"
+            for i in range(num_hidden_layers)]

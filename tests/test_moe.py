@@ -369,3 +369,81 @@ class TestCapacityDispatch:
             np.random.RandomState(0).rand(3, 2, 8).astype(np.float32))
         with pytest.raises(ValueError, match="divisible"):
             moe8(bad_batch)
+
+
+#: an expert gemm's operand against the grouped matmul's tile (bf16: 1,024;
+#: float32: 512): narrower (JoyAI's 768, Qwen3-Next's 512), the tile, a
+#: multiple of it (2,048), and wider without being one — LFM2's 1,792 = 7 x
+#: 256 and Kimi-Linear's 2,304 = 9 x 256
+GMM_OPERANDS = (512, 768, 1024, 1792, 2048, 2304)
+
+
+@pytest.mark.parametrize("itemsize", [2, 4], ids=["bf16", "f32"])
+@pytest.mark.parametrize("operand", GMM_OPERANDS)
+def test_gmm_tiling_divides_every_operand(operand, itemsize):
+    """The grouped matmul's tiles, forward ([2048 -> operand], [operand ->
+    2048]) and as the backward's calls see them (k and n swapped): every
+    answer divides its operand — no tile is masked or part empty —, is the
+    operand itself or a multiple of the 128 lanes, and never passes the
+    table's tile; the row tile divides the rows."""
+    from paddle_tpu.incubate.moe import _GMM_TILING, _gmm_tiling
+
+    rows, hidden = 65536, 2048
+    table = _GMM_TILING[itemsize]
+    for k, n in ((hidden, operand), (operand, hidden)):
+        tiling = _gmm_tiling(rows, itemsize, k, n)
+        calls = ((rows, k, n), (rows, n, k))   # forward, the backward's swap
+        answers = [tiling if isinstance(tiling, tuple) else tiling(*call)
+                   for call in calls]
+        for (m, kk, nn_), (tm, tk, tn) in zip(calls, answers):
+            assert m % tm == 0 and tm == table[0]
+            for size, tile, limit in ((kk, tk, table[1]), (nn_, tn, table[2])):
+                assert size % tile == 0, (size, tile)
+                assert tile <= limit and (tile == size or tile % 128 == 0)
+    if itemsize == 2:
+        want = {512: 512, 768: 768, 1024: 1024, 1792: 896, 2048: 1024,
+                2304: 768}[operand]
+        tiling = _gmm_tiling(rows, 2, hidden, operand)
+        got = tiling if isinstance(tiling, tuple) else tiling(
+            rows, hidden, operand)
+        assert got == (512, 1024, want)
+
+
+def test_gmm_tiling_keeps_the_table_for_what_no_lane_multiple_divides():
+    """An operand wider than the tile that no multiple of 128 divides keeps
+    the table's tile (the kernel masks its last one); rows that no row tile
+    divides have no tiling, and ``ragged_dot`` serves."""
+    from paddle_tpu.incubate.moe import _gmm_tiling, _operand_tile
+
+    assert _operand_tile(1024, 1500) == 1024
+    assert _operand_tile(1024, 1792) == 896 and _operand_tile(512, 1792) == 256
+    assert _gmm_tiling(65536, 2, 2048, 1500)(65536, 2048, 1500) == (
+        512, 1024, 1024)
+    assert _gmm_tiling(65531, 2, 2048, 1792) is None
+    assert _gmm_tiling(65536, 1, 2048, 1792) is None
+
+
+def test_grouped_matmul_at_a_width_of_seven_quarter_tiles_is_exact():
+    """[256 -> 1792] and [1792 -> 256] through the megablox kernel (the
+    Pallas interpreter) at the divisor tiles against ``ragged_dot``, with
+    an empty group and an uneven one, output and both gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.incubate.moe import _grouped_matmul
+
+    rng = np.random.default_rng(44)
+    sizes = jnp.asarray([200, 0, 312, 512], jnp.int32)
+    for k, n in ((256, 1792), (1792, 256)):
+        x = jnp.asarray(rng.standard_normal((1024, k)), jnp.float32)
+        w = jnp.asarray(rng.standard_normal((4, k, n)) * 0.05, jnp.float32)
+
+        def loss(x, w, kernel):
+            return jnp.sum(_grouped_matmul(x, w, sizes, kernel) ** 2)
+
+        got = jax.value_and_grad(loss, (0, 1))(x, w, "interpret")
+        want = jax.value_and_grad(loss, (0, 1))(x, w, None)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+        for g, wnt in zip(got[1], want[1]):
+            assert float(jnp.abs(g - wnt).max()) <= 1e-5 * float(
+                jnp.abs(wnt).max())

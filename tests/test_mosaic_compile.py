@@ -67,6 +67,11 @@ STREAM_CASES = {
     # with a dropout mask (a sixth element): the hash's tiles need VMEM too
     "joyai-cell-bf16-p0.1": (1, 32, 8192, (192, 128), "bfloat16", 0.1),
     "olmoe-cell-bf16-p0.1": (4, 16, 4096, 128, "bfloat16", 0.1),
+    # LFM2's attention layer at its cell: 32 query heads (K and V repeated
+    # from 8) of 64 — half a lane group a row, 1,024-token blocks, a dq slab
+    # of 8 MiB — 4 rows of 8,192 keys, and the check's 2 rows in float32
+    "lfm2-cell-bf16": (4, 32, 8192, 64, "bfloat16"),
+    "lfm2-check-f32": (2, 32, 8192, 64, "float32"),
 }
 STREAM_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dkv_dq")
 #: a row of dq past the one-pass backward's VMEM budget (65,536 x 128 bf16:
@@ -91,6 +96,15 @@ BAND_CASES = {
     "long-s65536-bf16": (1, 2, 65536, 128, "bfloat16", 2048),
 }
 BAND_CALLS = ("flash_band_fwd", "flash_band_bwd_dkv_dq")
+#: the held experts' grouped matmul where the table's 1,024 tile does not
+#: divide an operand (rows, groups, k, n, dtype): LFM2's width 1,792 = 7 x
+#: 256 on ``n`` (up, gate) and on ``k`` (down) at the cell's 65,536-row
+#: buffer, and in its check's float32 (tile 512: 256 divides)
+GMM_CASES = {
+    "lfm2-up-bf16": (65536, 8, 2048, 1792, "bfloat16"),
+    "lfm2-down-bf16": (65536, 8, 1792, 2048, "bfloat16"),
+    "lfm2-up-f32": (32768, 8, 2048, 1792, "float32"),
+}
 BAND_TWO_CALLS = ("flash_band_fwd", "flash_band_bwd_dq",
                   "flash_band_bwd_dkv")
 #: a latent-attention block under ``fleet.utils.recompute``, forward +
@@ -272,6 +286,22 @@ def _child():
                       if f"({c})" in text],
             "full_calls": "flash_stream_" in text,
             "scores_in_hbm": f"{seq},{seq}]" in text}
+
+    from paddle_tpu.incubate import moe
+
+    for name, (rows, groups, k, n, dtype) in GMM_CASES.items():
+        tiling = moe._gmm_tiling(rows, jnp.dtype(dtype).itemsize, k, n)
+        text = jax.jit(jax.grad(
+            lambda x, w, sizes: jnp.sum(moe._grouped_matmul(
+                x, w, sizes, "mosaic").astype(jnp.float32) ** 2),
+            argnums=(0, 1))).lower(
+                jax.ShapeDtypeStruct((rows, k), dtype, sharding=one),
+                jax.ShapeDtypeStruct((groups, k, n), dtype, sharding=one),
+                jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one),
+            ).compile().as_text()
+        out["gmm-" + name] = {
+            "mosaic": text.count(MOSAIC),
+            "tiles": [list(tiling(rows, *kn)) for kn in ((k, n), (n, k))]}
 
     # what the kernel's forward rule does for a recomputed block
     # (ops/residuals.py: the tags, the log-sum-exp's reshape between two
@@ -652,8 +682,9 @@ def test_short_kernel_compiles(compiled, case):
 def test_stream_kernel_compiles(compiled, case):
     """The streaming kernel, forward and the one-pass backward (dq, dk and
     dv from one set of score tiles), causal, at the OLMoE cell's b4 h16
-    s4096 d128 bf16, in float32, at d256 and d64, and at the JoyAI cell's
+    s4096 d128 bf16, in float32, at d256 and d64, at the JoyAI cell's
     b1 h32 s8192 with 192-wide keys and 128-wide values (bf16 and the
+    check's float32) and at the LFM2 cell's b4 h32 s8192 d64 (and its
     check's float32): two Mosaic calls under their names within the VMEM
     the backward asks for at the block sizes the kernel picks, no
     [seq, seq] scores in HBM. A row of dq past the slab's budget compiles
@@ -667,6 +698,21 @@ def test_stream_kernel_compiles(compiled, case):
         # keys and values keep their own widths through every call:
         # nothing is padded to the other's
         assert got["value_wide_results"] and got["key_wide_results"]
+
+
+@pytest.mark.parametrize("case", list(GMM_CASES))
+def test_grouped_matmul_compiles_at_a_divisor_tile(compiled, case):
+    """The megablox kernel at LFM2's expert gemms, forward and both
+    backward calls (gmm, gmm on the transposed weights, tgmm): three Mosaic
+    calls within VMEM at the tiles ``_gmm_tiling`` answers, each of which
+    divides its operand (896 of 1,792 in bf16, 256 in float32)."""
+    got = compiled["gmm-" + case]
+    rows, _, k, n, dtype = GMM_CASES[case]
+    assert got["mosaic"] == 3
+    narrow = 896 if dtype == "bfloat16" else 256
+    for (tm, tk, tn), (kk, nn) in zip(got["tiles"], ((k, n), (n, k))):
+        assert rows % tm == 0 and kk % tk == 0 and nn % tn == 0
+        assert narrow in (tk, tn)
 
 
 @pytest.mark.parametrize("case", list(BAND_CASES))
