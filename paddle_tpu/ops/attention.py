@@ -35,6 +35,15 @@ Whether a program may hold a kernel at all is ``ops.placement``'s answer
 as one with no XLA way out at its lengths.
 Every decision counts in ``paddle_tpu_attention_route_total{route}`` (at
 trace time under jit: one count per traced call site).
+
+**The stage before the core** — a head's RMSNorm of q and of k, rotate-half
+RoPE, the split into [batch, heads, seq, head_dim] — is ``qk_heads``, by the
+convolution stage's rule (``ops.linear_attention.conv_streams``):
+``qk_path`` gives ``kernel`` (one Mosaic call a pass on the projected
+streams, the head split its BlockSpec's index map, its float32 in VMEM;
+``ops/pallas/qk_heads.py``) or ``xla`` (``_qk_xla``: float32 arrays under a
+``jax.checkpoint``, what every other program runs and what the kernels are
+held to), counted in ``paddle_tpu_qk_heads_total{path}``.
 """
 import functools
 import math
@@ -60,6 +69,13 @@ _WINDOW_ROUTE_TOTAL = obs_metrics.counter(
     "counted beside paddle_tpu_attention_route_total, one per traced call "
     "site",
     labelnames=("route",))
+
+_QK_TOTAL = obs_metrics.counter(
+    "paddle_tpu_qk_heads_total",
+    "softmax attention's stages before the core (a head's RMSNorm of q and "
+    "k, rotate-half RoPE, the head split) by the path taken: kernel (one "
+    "Mosaic call a pass) | xla; one count per traced layer call",
+    labelnames=("path",))
 
 # the stream kernel runs one k block of up to 256 keys per grid step: with
 # fewer keys than two blocks a program is per-program overhead however
@@ -96,6 +112,144 @@ def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal, window=None):
         keep = random_core.fast_keep_mask(key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def rotary(x, base=10000.0, positions=None, pairing="interleaved",
+           rotary_dim=None):
+    """Rotary embedding. x: [B, H, T, D]; positions: [T] absolute positions
+    (defaults to 0..T-1). ``text/models.py``'s ``_rope``, shared with
+    generation.py's cached decode.
+    ``pairing``: which features rotate together — ``interleaved`` pairs
+    (2i, 2i+1) (the Llama block here), ``half`` pairs (i, i + D/2) (the
+    rotate-half form of the HF sources; OLMoE). The two differ by a fixed
+    permutation of the columns of the q and k projections. ``rotary_dim``:
+    only the first that many features rotate, among themselves (a partial
+    rotary factor: Qwen3-Next turns 64 of 256); the rest pass."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [rotary(x[..., :rotary_dim], base, positions, pairing),
+             x[..., rotary_dim:]], axis=-1)
+    d = x.shape[-1]
+    t = x.shape[-2]
+    if positions is None:
+        positions = jnp.arange(t)
+    inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    freqs = jnp.outer(positions, inv)
+    cos = jnp.cos(freqs)[None, None].astype(x.dtype)
+    sin = jnp.sin(freqs)[None, None].astype(x.dtype)
+    if pairing == "half":
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1)
+    x1, x2 = x[..., ::2], x[..., 1::2]
+    out1 = x1 * cos - x2 * sin
+    out2 = x2 * cos + x1 * sin
+    return jnp.stack([out1, out2], axis=-1).reshape(x.shape)
+
+
+# ------------------------------------------------- the stage before the core
+def qk_path(seq, heads, kv_heads, d, dtype, rotary_dim=None):
+    """``kernel`` | ``xla`` for the stage before the core of a row of
+    ``seq`` tokens with ``heads`` query heads on ``kv_heads`` key heads of
+    ``d`` features in ``dtype``, the first ``rotary_dim`` of them rotated
+    (None: none), from what can be observed: the Mosaic kernels where the
+    program may hold them (``placement.kernel``: they run through
+    ``on_mesh``), a head fills whole lane groups (d = 64 does not: two
+    heads a lane group is a layout the core would have to share), the
+    streams are bf16 or float32 and the row is at least one token block;
+    where ``on_mesh`` cuts the heads over an 'mp' axis, only if that cuts
+    both streams between whole heads. The XLA stage everything else."""
+    from .pallas import qk_heads as kernels
+
+    if not (seq >= kernels.QK_TOKENS
+            and kernels.supported(heads, kv_heads, d, dtype, rotary_dim)
+            and placement.kernel(sharded=True)):
+        return "xla"
+    mp = placement.axis_size("mp")
+    return "xla" if heads % mp or kv_heads % mp else "kernel"
+
+
+def qk_kernel(q, heads, kv_heads, d, rotary_dim=None):
+    """One call's decision, counted, as ``qk_heads`` takes it:
+    ``placement.kernel``'s answer where ``qk_path`` says ``kernel`` for a q
+    stream like ``q``, else None (the XLA stage). An op's caller asks
+    OUTSIDE the op; the answer rides its static arguments."""
+    path = qk_path(q.shape[1], heads, kv_heads, d, q.dtype, rotary_dim)
+    _QK_TOTAL.inc(path=path)
+    return placement.kernel(sharded=True) if path == "kernel" else None
+
+
+def qk_heads(q, k, w_q, w_k, *, heads, kv_heads, zero_centered, eps, rope,
+             base=10000.0, rotary_dim=None, stride=1, kernel=None):
+    """Softmax attention's stage between the projections and the core, on
+    arrays: the projected streams q [B, T, heads x stride x d] and k [B, T,
+    kv_heads x d] with their norms' weights [d] -> q [B, heads, T, d], k [B,
+    kv_heads, T, d] in the streams' dtype. A head passes an RMSNorm over its
+    d features in float32 (the weight, or 1 + weight where
+    ``zero_centered``) and, where ``rope``, rotate-half RoPE in float32 on
+    the first ``rotary_dim`` of them (None: all) at positions 0 .. T - 1;
+    one rounding at the end. ``stride``: a query head's d features are the
+    first of ``stride`` x d columns (Qwen3-Next lays a head out as [query |
+    gate]: 2). ``kernel``: ``qk_kernel``'s answer, which the caller takes
+    outside its dispatched op (None: the XLA stage)."""
+    d = k.shape[-1] // kv_heads
+    static = dict(heads=int(heads), kv_heads=int(kv_heads), d=d,
+                  stride=int(stride), zero_centered=bool(zero_centered),
+                  eps=float(eps), base=float(base), rotary_dim=(
+                      (d if rotary_dim is None else int(rotary_dim))
+                      if rope else None))
+    if kernel is None:
+        return _qk_xla(q, k, w_q, w_k, **static)
+    return _qk_kernel(q, k, w_q, w_k, interpret=kernel == "interpret",
+                      **static)
+
+
+def _qk_xla(q, k, w_q, w_k, *, heads, kv_heads, d, stride, zero_centered,
+            eps, base, rotary_dim):
+    """The stage in XLA operations: float32 arrays as large as a stream in
+    both the [.., T, heads, d] and the [.., heads, T, d] order, so a
+    ``jax.checkpoint`` of its own — a differentiated program keeps the
+    (bf16) streams and rebuilds the float32 inside it."""
+    def stage(q, k, w_q, w_k):
+        b, t, _ = q.shape
+        if stride > 1:
+            q = q.reshape(b, t, heads, stride * d)[..., :d]
+
+        def normed(x, w, n):
+            xf = x.reshape(b, t, n, d).astype(jnp.float32)
+            wf = w.astype(jnp.float32)
+            xf = (xf * jax.lax.rsqrt(
+                jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+                * (1.0 + wf if zero_centered else wf)).transpose(0, 2, 1, 3)
+            if rotary_dim is not None:
+                xf = rotary(xf, base, pairing="half", rotary_dim=rotary_dim)
+            return xf.astype(x.dtype)
+
+        return normed(q, w_q, heads), normed(k, w_k, kv_heads)
+
+    return jax.checkpoint(stage)(q, k, w_q, w_k)
+
+
+def _qk_kernel(q, k, w_q, w_k, *, heads, kv_heads, d, interpret, **static):
+    """The Mosaic kernels, under a step's announced mesh inside
+    ``placement.on_mesh``'s ``shard_map``: rows over the data axes and,
+    where ``qk_path`` lets an ``mp`` axis through, both streams' heads over
+    it (dim 2 in, dim 1 out). The weights go laid on every head's lanes a
+    row ([B, 1, heads x d]), so that they cut as the streams do and their
+    gradient is summed over rows and heads outside."""
+    from .pallas import qk_heads as kernels
+
+    def kernel(q, k, wq, wk):
+        return kernels.qk_heads(q, k, wq, wk, d=d, interpret=interpret,
+                                **static)
+
+    def rows(w, n):
+        return jnp.broadcast_to(jnp.tile(w, n)[None, None],
+                                (q.shape[0], 1, n * d))
+
+    return tuple(placement.on_mesh(
+        kernel, (q, k, rows(w_q, heads), rows(w_k, kv_heads)), head_axis=2,
+        out_head_axis=1))
 
 
 def attention_route(*, batch, seq_q, seq_k, num_heads, head_dim, dtype,

@@ -27,6 +27,17 @@ yes       no         yes  size > 1  any      mosaic   None       mosaic
 The answer is taken where an op is dispatched, outside it, and rides the
 op's static arguments: an eager trace is cached by them, so a flag flipped
 or a mesh announced retraces.
+
+Who asks (each from what else it can observe — shapes, dtype, the 'mp'
+axis — and each counted under a label of its own): the attention gate
+(``ops.attention.attention_route``: ``short`` sharded, ``stream`` with no
+fallback), the stage before that core (``ops.attention.qk_path``: a head's
+RMSNorm, RoPE and the head split, decided by ``qk_kernel`` in the layers of
+``text/models.py`` that call ``qk_heads``: Trinity's, Qwen3-Next's gated
+attention, LFM2's — the last always ``xla``, heads of 64), the delta rule's
+scan and the convolution stage before it (``ops.linear_attention.core_path``
+/ ``conv_path``), all sharded through ``on_mesh``; and the expert layer's
+grouped matmul (``incubate.moe``), unsharded.
 """
 import math
 
@@ -67,7 +78,8 @@ def axis_size(axis, *, or_global=False):
     return 1 if mesh is None else mesh.shape.get(axis, 1)
 
 
-def on_mesh(call, arrays, *, head_axis, seed=None, seed_per_shard=False):
+def on_mesh(call, arrays, *, head_axis, out_head_axis=None, seed=None,
+            seed_per_shard=False):
     """Run ``call(*arrays)`` (``call(*arrays, seed=seed)`` where a seed
     is given) — directly, or inside a step being traced for a
     multi-device mesh (topology.traced_mesh) under a shard_map: GSPMD
@@ -78,9 +90,12 @@ def on_mesh(call, arrays, *, head_axis, seed=None, seed_per_shard=False):
     axes and dim ``head_axis`` (None: heads are not a dim of their own)
     over 'mp' — each only where it divides (the head dim in every array:
     it may hold a head's features too, [.., heads x d]); otherwise that dim
-    is computed whole on every device of the axis. ``seed_per_shard``: the
-    kernel's mask hash counts (batch, head) from 0 on every shard, so each
-    shard gets a seed of its own or they all drop the same entries."""
+    is computed whole on every device of the axis. ``out_head_axis``: the
+    dim the results carry their heads on, where not ``head_axis`` (a stage
+    that takes streams [.., T, heads x d] and leaves [.., heads, T, d]).
+    ``seed_per_shard``: the kernel's mask hash counts (batch, head) from 0
+    on every shard, so each shard gets a seed of its own or they all drop
+    the same entries."""
     def run(*args):
         *shards, seed = args
         return call(*shards) if seed is None else call(*shards, seed=seed)
@@ -98,7 +113,7 @@ def on_mesh(call, arrays, *, head_axis, seed=None, seed_per_shard=False):
     h_axes = (("mp",) if head_axis is not None and n_mp > 1 and all(
         a.shape[head_axis] % n_mp == 0 for a in arrays) else ())
 
-    def spec(a):
+    def spec(a, head_axis=head_axis):
         dims = [None] * a.ndim
         dims[0] = b_axes or None
         if h_axes:
@@ -116,4 +131,6 @@ def on_mesh(call, arrays, *, head_axis, seed=None, seed_per_shard=False):
     # no seed is an empty operand: the map then takes the arrays alone
     return jax.shard_map(
         on_shard, mesh=mesh, in_specs=tuple(map(spec, arrays)) + (P(),),
-        out_specs=spec(arrays[0]), check_vma=False)(*arrays, seed)
+        out_specs=spec(arrays[0], head_axis if out_head_axis is None
+                       else out_head_axis),
+        check_vma=False)(*arrays, seed)

@@ -13,6 +13,7 @@ import numpy as np
 
 from .. import nn
 from ..nn import functional as F
+from ..ops.attention import rotary as _rope
 
 
 class BertEmbeddings(nn.Layer):
@@ -210,40 +211,6 @@ def rms_norm(x, w, *, eps=1e-6):
 
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * w
-
-
-def _rope(x, base=10000.0, positions=None, pairing="interleaved",
-          rotary_dim=None):
-    """Rotary embedding. x: [B, H, T, D]; positions: [T] absolute positions
-    (defaults to 0..T-1). Shared with generation.py's cached decode.
-    ``pairing``: which features rotate together — ``interleaved`` pairs
-    (2i, 2i+1) (the Llama block here), ``half`` pairs (i, i + D/2) (the
-    rotate-half form of the HF sources; OLMoE). The two differ by a fixed
-    permutation of the columns of the q and k projections. ``rotary_dim``:
-    only the first that many features rotate, among themselves (a partial
-    rotary factor: Qwen3-Next turns 64 of 256); the rest pass."""
-    import jax.numpy as jnp
-
-    if rotary_dim is not None and rotary_dim != x.shape[-1]:
-        return jnp.concatenate(
-            [_rope(x[..., :rotary_dim], base, positions, pairing),
-             x[..., rotary_dim:]], axis=-1)
-    d = x.shape[-1]
-    t = x.shape[-2]
-    if positions is None:
-        positions = jnp.arange(t)
-    inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = jnp.outer(positions, inv)
-    cos = jnp.cos(freqs)[None, None].astype(x.dtype)
-    sin = jnp.sin(freqs)[None, None].astype(x.dtype)
-    if pairing == "half":
-        x1, x2 = x[..., :d // 2], x[..., d // 2:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               axis=-1)
-    x1, x2 = x[..., ::2], x[..., 1::2]
-    out1 = x1 * cos - x2 * sin
-    out2 = x2 * cos + x1 * sin
-    return jnp.stack([out1, out2], axis=-1).reshape(x.shape)
 
 
 def _merge_heads(o):
@@ -1351,30 +1318,26 @@ class GatedDeltaNet(nn.Layer):
 
 
 def _gqa_heads(q, k, v, w_q, w_k, *, heads, kv_heads, d, eps, base,
-               rotary_dim):
+               rotary_dim, kernel=None):
     """The projected streams -> (query, key, value [B, H, T, d] — key and
     value on their own ``kv_heads`` — and the gate [B, T, heads x d]): the
     query projection is laid out a head at a time as [query d | gate d];
     query and key pass a zero-centred RMSNorm over a head's d features and
-    rotate-half RoPE on the first ``rotary_dim`` of them, in float32."""
-    import jax
-    import jax.numpy as jnp
+    rotate-half RoPE on the first ``rotary_dim`` of them, in float32. That
+    stage is one op, ``ops.attention.qk_heads``, which reads a head's query
+    out of every second d-wide column block of the stream; the gate's cut
+    and v's head split are views and stay here. ``kernel``: ``qk_kernel``'s
+    answer, taken outside the op (None: the XLA stage)."""
+    from ..ops.attention import qk_heads
 
-    def split(q, k, v, w_q, w_k):
-        b, t, _ = q.shape
-        q = q.reshape(b, t, heads, 2 * d)
-        query, gate = q[..., :d], q[..., d:].reshape(b, t, heads * d)
-
-        def normed(x, w, n):
-            x = _rms_norm_f32(x.reshape(b, t, n, d).astype(jnp.float32), w,
-                              eps=eps, zero_centered=True)
-            return _rope(x.transpose(0, 2, 1, 3), base, pairing="half",
-                         rotary_dim=rotary_dim).astype(q.dtype)
-
-        return (normed(query, w_q, heads), normed(k, w_k, kv_heads),
-                v.reshape(b, t, kv_heads, d).transpose(0, 2, 1, 3), gate)
-
-    return jax.checkpoint(split)(q, k, v, w_q, w_k)
+    b, t, _ = q.shape
+    query, key = qk_heads(
+        q, k, w_q, w_k, heads=heads, kv_heads=kv_heads, zero_centered=True,
+        eps=eps, rope=True, base=base, rotary_dim=rotary_dim, stride=2,
+        kernel=kernel)
+    gate = q.reshape(b, t, heads, 2 * d)[..., d:].reshape(b, t, heads * d)
+    return (query, key, v.reshape(b, t, kv_heads, d).transpose(0, 2, 1, 3),
+            gate)
 
 
 def _gqa_gated_merge(o, gate):
@@ -1400,7 +1363,11 @@ class GatedGQAttention(nn.Layer):
     through the dispatching sdpa (the streaming flash kernel at long
     sequences), K and V REPEATED to the query heads first, under the scope
     ``gqa.repeat`` (ROADMAP Speed 13: the kernel takes one head count).
-    Scopes: ``gqa.proj`` / ``.repeat`` / ``.core`` / ``.out``."""
+    Scopes: ``gqa.proj`` / ``.repeat`` / ``.core`` / ``.out``. The norms,
+    RoPE and the head split under ``gqa.proj`` are ``ops.attention.qk_heads``
+    (``forward`` asks ``qk_kernel`` outside the dispatched op), which reads
+    a head's query out of every second column block of ``q_proj``'s
+    output."""
 
     def __init__(self, hidden_size, num_heads=16, num_kv_heads=2,
                  head_dim=256, partial_rotary_factor=0.25, rope_theta=1e7,
@@ -1427,15 +1394,19 @@ class GatedGQAttention(nn.Layer):
         import jax
 
         from ..core.dispatch import apply_op
-        from ..ops.attention import scaled_dot_product_attention as _sdpa
+        from ..ops.attention import (
+            qk_kernel, scaled_dot_product_attention as _sdpa)
 
         with jax.named_scope("gqa.proj"):
+            q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
             q, k, v, gate = apply_op(
-                "gqa_heads", _gqa_heads, self.q_proj(x), self.k_proj(x),
-                self.v_proj(x), self.q_norm.weight, self.k_norm.weight,
+                "gqa_heads", _gqa_heads, q, k, v, self.q_norm.weight,
+                self.k_norm.weight,
                 heads=self.num_heads, kv_heads=self.num_kv_heads,
                 d=self.head_dim, eps=self.q_norm.eps, base=self.rope_theta,
-                rotary_dim=self.rotary_dim)
+                rotary_dim=self.rotary_dim, kernel=qk_kernel(
+                    q, self.num_heads, self.num_kv_heads, self.head_dim,
+                    self.rotary_dim))
         with jax.named_scope("gqa.repeat"):
             k, v = (apply_op("repeat_heads", _repeat_heads, t,
                              repeats=self.num_heads // self.num_kv_heads,
@@ -1570,29 +1541,25 @@ def qwen3_next_layer_types(num_hidden_layers, full_attention_interval=4):
 
 
 # ------------------------------------------------------------ Trinity (AFMoE)
-def _afmoe_heads(q, k, v, w_q, w_k, *, heads, kv_heads, d, eps, base, rope):
+def _afmoe_heads(q, k, v, w_q, w_k, *, heads, kv_heads, d, eps, base, rope,
+                 kernel=None):
     """The projected streams -> (query [B, H, T, d], key and value on their
     own ``kv_heads``): query and key pass an RMSNorm over a head's d
     features (weight from 1) and, where ``rope``, rotate-half RoPE over all
-    of them, in float32; a layer without positions rotates nothing."""
-    import jax
-    import jax.numpy as jnp
+    of them, in float32; a layer without positions rotates nothing. That
+    stage is one op, ``ops.attention.qk_heads`` (one Mosaic call a pass on
+    the streams where ``qk_path`` says ``kernel`` — heads of whole lane
+    groups on a TPU —, XLA operations under a ``jax.checkpoint`` elsewhere:
+    heads of 64); v's head split needs no arithmetic and stays here.
+    ``kernel``: ``qk_kernel``'s answer, taken outside the op (None: the XLA
+    stage)."""
+    from ..ops.attention import qk_heads
 
-    def split(q, k, v, w_q, w_k):
-        b, t, _ = q.shape
-
-        def normed(x, w, n):
-            x = _rms_norm_f32(x.reshape(b, t, n, d).astype(jnp.float32), w,
-                              eps=eps, zero_centered=False)
-            x = x.transpose(0, 2, 1, 3)
-            if rope:
-                x = _rope(x, base, pairing="half")
-            return x.astype(q.dtype)
-
-        return (normed(q, w_q, heads), normed(k, w_k, kv_heads),
-                v.reshape(b, t, kv_heads, d).transpose(0, 2, 1, 3))
-
-    return jax.checkpoint(split)(q, k, v, w_q, w_k)
+    b, t, _ = q.shape
+    query, key = qk_heads(
+        q, k, w_q, w_k, heads=heads, kv_heads=kv_heads, zero_centered=False,
+        eps=eps, rope=rope, base=base, kernel=kernel)
+    return query, key, v.reshape(b, t, kv_heads, d).transpose(0, 2, 1, 3)
 
 
 class AfmoeAttention(nn.Layer):
@@ -1610,7 +1577,12 @@ class AfmoeAttention(nn.Layer):
     heads first (ROADMAP Speed 13). Scopes, so that a trace tells the two
     types apart: ``swa.`` (sliding) or ``gattn.`` (full) + ``proj`` (q, k,
     v and the gate) / ``qk`` (norms, RoPE, the head split) / ``repeat`` /
-    ``core`` / ``out`` (gate, merge, ``o_proj``)."""
+    ``core`` / ``out`` (gate, merge, ``o_proj``). The ``qk`` stage is
+    ``ops.attention.qk_heads``: at this head width one Mosaic call a pass on
+    the projected streams where a program may hold kernels; ``forward`` asks
+    ``qk_kernel`` (``ops.attention.qk_path`` over ``ops.placement``) outside
+    the dispatched op, and ``paddle_tpu_qk_heads_total{path}`` counts the
+    answer."""
 
     def __init__(self, hidden_size, layer_type, num_heads=32, num_kv_heads=4,
                  head_dim=128, sliding_window=2048, rope_theta=10000.0,
@@ -1645,7 +1617,8 @@ class AfmoeAttention(nn.Layer):
         import jax
 
         from ..core.dispatch import apply_op
-        from ..ops.attention import scaled_dot_product_attention as _sdpa
+        from ..ops.attention import (
+            qk_kernel, scaled_dot_product_attention as _sdpa)
 
         scope = self.scope
         with jax.named_scope(scope + ".proj"):
@@ -1657,7 +1630,9 @@ class AfmoeAttention(nn.Layer):
                 self.k_norm.weight, heads=self.num_heads,
                 kv_heads=self.num_kv_heads, d=self.head_dim,
                 eps=self.q_norm.eps, base=self.rope_theta,
-                rope=self.sliding)
+                rope=self.sliding, kernel=qk_kernel(
+                    q, self.num_heads, self.num_kv_heads, self.head_dim,
+                    self.head_dim if self.sliding else None))
         with jax.named_scope(scope + ".repeat"):
             k, v = (apply_op("repeat_heads", _repeat_heads, t,
                              repeats=self.num_heads // self.num_kv_heads,
@@ -1851,7 +1826,11 @@ class Lfm2Attention(nn.Layer):
     at long sequences), K and V REPEATED to the query heads first (ROADMAP
     Speed 13). Scopes: ``lfm2attn.proj`` (q, k, v) / ``.qk`` (norms, RoPE,
     the head split) / ``.repeat`` / ``.core`` / ``.out`` (merge,
-    ``out_proj``)."""
+    ``out_proj``). The ``qk`` stage is ``ops.attention.qk_heads`` as
+    Trinity's; ``forward`` asks ``qk_kernel`` the same way, and at heads of
+    64 — half a lane group — ``qk_path`` answers ``xla`` on every platform:
+    the stage runs as XLA operations under its own ``jax.checkpoint``
+    (ROADMAP Speed 11b: two heads a lane group, stage and core together)."""
 
     def __init__(self, hidden_size, num_heads=32, num_kv_heads=8,
                  rope_theta=1e6, rms_norm_eps=1e-5, weight_attr=None):
@@ -1880,7 +1859,8 @@ class Lfm2Attention(nn.Layer):
         import jax
 
         from ..core.dispatch import apply_op
-        from ..ops.attention import scaled_dot_product_attention as _sdpa
+        from ..ops.attention import (
+            qk_kernel, scaled_dot_product_attention as _sdpa)
 
         with jax.named_scope("lfm2attn.proj"):
             q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
@@ -1890,7 +1870,9 @@ class Lfm2Attention(nn.Layer):
                 self.q_layernorm.weight, self.k_layernorm.weight,
                 heads=self.num_heads, kv_heads=self.num_kv_heads,
                 d=self.head_dim, eps=self.q_layernorm.eps,
-                base=self.rope_theta, rope=True)
+                base=self.rope_theta, rope=True, kernel=qk_kernel(
+                    q, self.num_heads, self.num_kv_heads, self.head_dim,
+                    self.head_dim))
         with jax.named_scope("lfm2attn.repeat"):
             k, v = (apply_op("repeat_heads", _repeat_heads, t,
                              repeats=self.num_heads // self.num_kv_heads,

@@ -167,6 +167,29 @@ def test_the_expert_layer_asks_unsharded(monkeypatch, where, want, held):
     assert ("pallas_call" in text) == (want is not None)
 
 
+def test_on_mesh_cuts_the_results_on_their_own_head_axis():
+    """A stage that takes streams [B, T, heads x d] and leaves head arrays
+    [B, heads, T, d] (``ops.attention.qk_heads``): ``head_axis`` cuts the
+    operands, ``out_head_axis`` the results, and the values are the direct
+    call's."""
+    x = jnp.arange(4 * 6 * 8, dtype=jnp.float32).reshape(4, 6, 8)
+
+    def split(a):
+        return a.reshape(a.shape[0], 6, -1, 2).transpose(0, 2, 1, 3)
+
+    want = split(x)
+    mesh = topology.build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+
+    def on_mesh(a):
+        with topology.tracing_for(mesh):
+            return placement.on_mesh(split, (a,), head_axis=2,
+                                     out_head_axis=1)
+
+    np.testing.assert_array_equal(jax.jit(on_mesh)(x), want)
+    text = str(jax.make_jaxpr(on_mesh)(x))
+    assert "shard_map" in text
+
+
 def test_the_convolution_stage_is_decided_outside_its_op(monkeypatch):
     """Two eager calls of a KimiDeltaAttention at a kernel-eligible shape
     (interpreter), ``use_pallas_kernels`` flipped between them: the answer

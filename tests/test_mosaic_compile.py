@@ -148,6 +148,20 @@ LAYER_CASES = {
 #: names its two Mosaic calls carry (the instructions': the calls sit in
 #: inner jits, under the ``kda.conv`` / ``gdn.conv`` scopes in a trace)
 CONV_CALLS = ("conv_streams_fwd", "conv_streams_bwd")
+#: softmax attention's stage before the core alone, the stems'
+#: ``text/models.py`` functions forward + VJP in bf16 (batch, seq, heads,
+#: kv_heads, head_dim, rotary_dim): Trinity-Mini's sliding layers (all 128
+#: features rotated) and its full layer (no positions) through
+#: ``_afmoe_heads``, Qwen3-Next's gated attention (64 of 256 rotated, the
+#: query every second column block of its projection) through
+#: ``_gqa_heads``. QK_CALLS: the names its two Mosaic calls carry in a
+#: trace, under the ``swa.qk`` / ``gattn.qk`` / ``gqa.proj`` scopes
+QK_CASES = {
+    "trinity-swa": (1, 16384, 32, 4, 128, 128),
+    "trinity-gattn": (1, 16384, 32, 4, 128, None),
+    "qwen3-next-gqa": (1, 16384, 16, 2, 256, 64),
+}
+QK_CALLS = ("qk_heads_fwd", "qk_heads_bwd")
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
@@ -542,6 +556,53 @@ def _child():
                 rf"= ({head_view})\S* (?:copy|reshape|transpose)\(", text))),
             "f32_head_views": len(re.findall(head_view, text))}
 
+    for name, (batch, seq, heads, kv_heads, d, rotary_dim) in (
+            QK_CASES.items()):
+        def like(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        gated = name.startswith("qwen3")
+
+        def stage_alone(*args):
+            """(q, k, v[, gate]) and the stage's gradients, the stage as
+            the layer calls it."""
+            def stage(*a):
+                kernel = attention.qk_kernel(a[0], heads, kv_heads, d,
+                                             rotary_dim)
+                if gated:
+                    return models._gqa_heads(
+                        *a, heads=heads, kv_heads=kv_heads, d=d, eps=1e-6,
+                        base=1e7, rotary_dim=rotary_dim, kernel=kernel)
+                return models._afmoe_heads(
+                    *a, heads=heads, kv_heads=kv_heads, d=d, eps=1e-5,
+                    base=1e4, rope=rotary_dim is not None, kernel=kernel)
+
+            out, vjp = jax.vjp(stage, *args)
+            return out, vjp(out)
+
+        args = (like(batch, seq, heads * d * (2 if gated else 1)),
+                like(batch, seq, kv_heads * d), like(batch, seq, kv_heads * d),
+                like(d, dtype=jnp.float32), like(d, dtype=jnp.float32))
+        here = placement.kernel
+        placement.kernel = lambda **site: "mosaic"
+        paths = {p: attention._QK_TOTAL.value(path=p)
+                 for p in ("kernel", "xla")}
+        try:
+            text = jax.jit(stage_alone).lower(*args).compile().as_text()
+        finally:
+            placement.kernel = here
+        out["qk-" + name] = {
+            "mosaic": text.count(MOSAIC),
+            "calls": [c for c in QK_CALLS if f"%{c}" in text],
+            "paths": {p: attention._QK_TOTAL.value(path=p) - n
+                      for p, n in paths.items()},
+            # a float32 array as large as q in HBM, in the streams' order or
+            # the core's, in any tiling: one the entry computation holds
+            "q_sized_f32": len(re.findall(
+                rf"f32\[{batch},({seq},{heads}|{heads},{seq}),{d}\]"
+                rf"|f32\[{batch},{seq},{heads * d}\]",
+                text[text.index("\nENTRY "):]))}
+
     # the s128 cell's FFN under amp O1, forward + backward: what F.gelu's
     # erf lowers to behind linear1's gemm, and what leaves that fusion
     def ffn_loss(p, x):
@@ -820,6 +881,19 @@ def test_the_convolution_stage_keeps_its_float32_in_vmem(compiled, case):
     batch, seq, key_heads, heads, d = LAYER_CASES[case]
     streams = batch * seq * (2 * key_heads + heads) * d * 2 / 1e9
     assert got["temp_gb"] <= 1.1 * streams, got
+
+
+@pytest.mark.parametrize("case", list(QK_CASES))
+def test_the_stage_before_the_core_keeps_its_float32_in_vmem(compiled, case):
+    """A stem's stage alone, forward + VJP at its cell's shape in bf16: the
+    layer's own function takes the kernels (counted ``kernel``), one Mosaic
+    call a pass for q and k together, and no float32 array as large as q in
+    HBM in either order (the XLA stage holds several of 268 MB a pass, and
+    the relayouts between them)."""
+    got = compiled["qk-" + case]
+    assert got["mosaic"] == 2 and got["calls"] == list(QK_CALLS)
+    assert got["paths"] == {"kernel": 1, "xla": 0}
+    assert got["q_sized_f32"] == 0
 
 
 @pytest.mark.parametrize("case", list(KDA_CASES))
