@@ -98,9 +98,17 @@ held to), counted in ``paddle_tpu_conv_streams_total{path}``.
 
 **The gated short convolution** (``gated_short_conv``) is another model's
 whole mixer, not a stage before a scan: LFM2's ``C * conv3(B * u)`` between
-its two projections, no activation, no norm. It runs in XLA operations
-under a ``jax.checkpoint`` of its own, as ``_conv_xla`` does, and counts in
-``paddle_tpu_shortconv_total{path}`` (``xla`` today).
+its two projections, no activation, no norm. By the same rule again:
+``shortconv_path`` gives ``kernel`` (one Mosaic call a pass on the bf16
+``[B | C | u]`` stream, ``gated_conv_fwd`` / ``_bwd`` of
+``ops/pallas/linear_attention.py``: the gates, ``B * u``, the taps'
+products and the backward's rebuilt ``conv(B * u)`` float32 in VMEM only,
+``bcu`` and the taps all a backward pass keeps, ``d bcu`` one array) or
+``xla`` (``_gated_xla``: float32 arrays under a ``jax.checkpoint`` of its
+own, as ``_conv_xla``; what every other program runs and what the kernels
+are held to), counted in ``paddle_tpu_shortconv_total{path}``. The layer's
+call carries no decision — two arrays, no keyword — so the op asks for
+itself (``kernel="ask"``).
 """
 import functools
 
@@ -136,7 +144,8 @@ _CONV_TOTAL = obs_metrics.counter(
 _SHORTCONV_TOTAL = obs_metrics.counter(
     "paddle_tpu_shortconv_total",
     "gated short convolutions (gate, taps, gate between two projections: "
-    "LFM2's mixer) by the path taken: xla; one count per traced layer call",
+    "LFM2's mixer) by the path taken: kernel (one Mosaic call a pass) | "
+    "xla; one count per traced layer call",
     labelnames=("path",))
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -670,24 +679,50 @@ def _conv_kernel(xs, ws, segments, head, eps, interpret):
 
 
 # ------------------------------------------- the gated short convolution
-def shortconv_path():
-    """One call's decision, counted, as ``gated_short_conv``'s caller takes
-    it OUTSIDE the op: ``xla``, the one path there is (a Mosaic stage for
-    gate-taps-gate would be chosen here, as ``conv_path`` chooses)."""
-    _SHORTCONV_TOTAL.inc(path="xla")
-    return "xla"
+def shortconv_path(seq, channels, taps, dtype):
+    """``kernel`` | ``xla`` for the gated short convolution of a row of
+    ``seq`` tokens, ``channels`` channels a third and ``taps`` taps in
+    ``dtype``, counted, from what can be observed, as ``conv_path``: the
+    Mosaic kernels where the program may hold them (``placement.kernel``:
+    they run through ``on_mesh``), the channels fill whole lane groups, the
+    history fits the rows the kernels carry, the stream is bf16 or float32
+    and the row is at least one token block. The XLA stage everything
+    else."""
+    from .pallas import linear_attention as kernels
+
+    path = ("kernel" if kernels.gated_conv_supported(channels, taps, dtype)
+            and seq >= kernels.gated_conv_tokens(channels, dtype)
+            and placement.kernel(sharded=True) else "xla")
+    _SHORTCONV_TOTAL.inc(path=path)
+    return path
 
 
-def gated_short_conv(bcu, w):
+def gated_short_conv(bcu, w, kernel="ask"):
     """LFM2's stage between its two projections, on arrays: ``bcu`` [B, T,
     3 C] as ``in_proj`` leaves it, split [B | C | u] in that order, and the
     taps ``w`` [K, C] -> ``C * conv(B * u)`` [B, T, C] in bcu's dtype. The
     convolution is causal and depthwise (``F.causal_depthwise_conv1d``: tap
     K - 1 meets the token itself, zero history before a row's first
-    token — a row never sees another row), no bias, no activation. Gates
-    and taps are float32 arrays as large as a stream, so a
-    ``jax.checkpoint`` of its own, as ``_conv_xla``: a differentiated
-    program keeps ``bcu`` and rebuilds the float32 inside it."""
+    token — a row never sees another row), no bias, no activation; gates,
+    products and sums are float32 on either path. ``kernel``:
+    ``placement.kernel``'s answer or None (the XLA stage); ``"ask"``, what
+    the layer's two-argument call leaves it at: ``shortconv_path`` is asked
+    here, once a call (an eager op's cached trace keeps its first answer:
+    ``dispatch.evict_ops("gated_short_conv")`` after a flag's flip)."""
+    if kernel == "ask":
+        kernel = (placement.kernel(sharded=True) if shortconv_path(
+            bcu.shape[1], w.shape[1], w.shape[0], bcu.dtype) == "kernel"
+            else None)
+    if kernel is None:
+        return _gated_xla(bcu, w)
+    return _gated_kernel(bcu, w, kernel == "interpret")
+
+
+def _gated_xla(bcu, w):
+    """The stage in XLA operations: gates and taps are float32 arrays as
+    large as a stream, so a ``jax.checkpoint`` of its own, as ``_conv_xla``:
+    a differentiated program keeps ``bcu`` and rebuilds the float32 inside
+    it."""
     from ..nn import functional as F
 
     channels = w.shape[1]
@@ -699,3 +734,20 @@ def gated_short_conv(bcu, w):
         return (c * mixed).astype(bcu.dtype)
 
     return jax.checkpoint(stage)(bcu, w)
+
+
+def _gated_kernel(bcu, w, interpret):
+    """The Mosaic kernels (``gated_conv_fwd`` / ``_bwd``: ``bcu`` and the
+    taps are all a backward pass keeps, no checkpoint), under a step's
+    announced mesh inside ``placement.on_mesh``'s ``shard_map``: rows over
+    the data axes, whole on every device of an 'mp' axis (a cut of 3 C
+    would not cut B, C and u alike). The taps go a copy a row ([B, K, C]),
+    so that they shard as the stream does and their gradient is summed over
+    the rows outside."""
+    from .pallas import linear_attention as kernels
+
+    def kernel(bcu, rows):
+        return kernels.gated_conv(bcu, rows, interpret=interpret)
+
+    rows = jnp.broadcast_to(w[None], (bcu.shape[0],) + w.shape)
+    return placement.on_mesh(kernel, (bcu, rows), head_axis=None)

@@ -1106,3 +1106,198 @@ def conv_streams(xs, ws, segments, *, head, eps, tokens=None, lanes=None,
               ("steps", steps), ("interpret", bool(interpret)))
     outs = _conv(tuple(xs), tuple(ws), static)
     return tuple(o[:, :t] for o in outs) if pad else outs
+
+
+# ------------------------------------------- the gated short convolution
+# LFM2's stage between its two projections, ``C * conv(B * u)`` on the ONE
+# [B, T, 3 C] stream ``in_proj`` leaves (thirds B | C | u in that order):
+# a gate before the K taps, no activation, a gate after —
+# ``ops.linear_attention._gated_xla`` is the same function in XLA
+# operations. Another algebra than the convolution stage's above, so other
+# bodies; they share its pure helpers (``_shifted``, ``_pre_activation``,
+# the carried rows). Float32 throughout, and only in VMEM. The grid is
+# (row, token blocks), tokens sequential; a program holds every channel of
+# its tokens, a lane group at a time in the body.
+#
+# - ``gated_conv_fwd`` reads its block of B, of C and of u out of the one
+#   array (three BlockSpecs, a third's width apart) and carries the last
+#   rows of ``B * u`` to the next block in VMEM scratch (zeros at a row's
+#   first block).
+# - ``gated_conv_bwd`` keeps nothing of the forward but ``bcu`` and the
+#   taps. It walks the token blocks from the last to the first, rebuilds
+#   ``B * u`` and ``conv(B * u)`` (the rows before the block arrive as a
+#   16-row block of their own), carries the first rows of the convolution's
+#   cotangent for the earlier block, sums the taps' gradient [K, C] over
+#   the tokens in float32, one a batch row, and writes ``d bcu`` as ONE
+#   [B, T, 3 C] array, thirds side by side as they came (three arrays and
+#   XLA's concatenation: 2.86 ms against 1.64 at the LFM2 cell's shape,
+#   ``tools/shortconv_bench.py``).
+
+#: bytes of a program's block of ONE third: 256 tokens of 2,048 bf16
+#: channels. On the v5e 128 to 512 tokens of them, and a third cut in
+#: channel steps, all move the same GB/s (``tools/shortconv_bench.py``)
+GATED_BLOCK_BYTES = 2**20
+_GATED_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=64 * 2**20)
+
+
+def gated_conv_tokens(channels, dtype):
+    """Tokens a program takes of a stream of 3 x ``channels`` in ``dtype``:
+    ``GATED_BLOCK_BYTES`` a third in whole 16-row tiles, 512 at most; under
+    16 (the rows the backward is brought from before its block) where a
+    third is too wide."""
+    most = GATED_BLOCK_BYTES // (channels * jnp.dtype(dtype).itemsize)
+    return min(512, most // _BEFORE * _BEFORE)
+
+
+def gated_conv_supported(channels, taps, dtype):
+    """Whether the kernels take this stage: channels that fill whole lane
+    groups, taps whose history fits the carried rows, a bf16 or float32
+    stream, and a block of at least 16 tokens."""
+    return (channels % 128 == 0 and 1 <= taps <= CONV_HALO + 1
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(jnp.float32))
+            and gated_conv_tokens(channels, dtype) >= _BEFORE)
+
+
+def _gated_fwd_kernel(b_ref, c_ref, u_ref, w_ref, o_ref, halo):
+    @pl.when(pl.program_id(1) == 0)
+    def _row_start():
+        halo[...] = jnp.zeros(halo.shape, _F32)
+
+    tokens, channels = o_ref.shape[1:]
+    taps = w_ref.shape[1]
+    for at in range(0, channels, 128):
+        lanes = slice(at, at + 128)
+        m = (b_ref[0, :, lanes].astype(_F32)
+             * u_ref[0, :, lanes].astype(_F32))
+        ext = jnp.concatenate([halo[:, lanes], m], axis=0)
+        halo[:, lanes] = m[tokens - CONV_HALO:]
+        mixed = _pre_activation(_shifted(ext, taps),
+                                w_ref[0, :, lanes].astype(_F32))
+        o_ref[0, :, lanes] = (c_ref[0, :, lanes].astype(_F32)
+                              * mixed).astype(o_ref.dtype)
+
+
+def _gated_bwd_kernel(bcu_ref, before_ref, w_ref, dy_ref, dbcu_ref, dw_ref,
+                      carry):
+    # the grid's last token step is the row's first block: zero history
+    row_start = pl.program_id(1) == pl.num_programs(1) - 1
+
+    @pl.when(pl.program_id(1) == 0)
+    def _row_end():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, _F32)
+        carry[...] = jnp.zeros(carry.shape, _F32)
+
+    channels = dy_ref.shape[2]
+    taps = w_ref.shape[1]
+    for at in range(0, channels, 128):
+        lanes = slice(at, at + 128)
+        b_at, c_at, u_at = (slice(i * channels + at, i * channels + at + 128)
+                            for i in range(3))
+        w = w_ref[0, :, lanes].astype(_F32)
+        b = bcu_ref[0, :, b_at].astype(_F32)
+        u = bcu_ref[0, :, u_at].astype(_F32)
+        before = (before_ref[0, :, b_at].astype(_F32)
+                  * before_ref[0, :, u_at].astype(_F32))[_BEFORE - CONV_HALO:]
+        shifted = _shifted(jnp.concatenate(
+            [jnp.where(row_start, 0.0, before), b * u], axis=0), taps)
+        dy = dy_ref[0, :, lanes].astype(_F32)
+        dbcu_ref[0, :, c_at] = (dy * _pre_activation(shifted, w)).astype(
+            dbcu_ref.dtype)
+        da = dy * bcu_ref[0, :, c_at].astype(_F32)
+        dw_ref[0, :, lanes] += jnp.concatenate(
+            [jnp.sum(da * shifted[taps - 1 - j], axis=0, keepdims=True)
+             for j in range(taps)], axis=0)
+        dm = _pre_activation(_shifted(
+            jnp.concatenate([da, carry[:, lanes]], axis=0), taps, up=True), w)
+        carry[:, lanes] = da[:CONV_HALO]
+        dbcu_ref[0, :, b_at] = (dm * u).astype(dbcu_ref.dtype)
+        dbcu_ref[0, :, u_at] = (dm * b).astype(dbcu_ref.dtype)
+
+
+# jitted for the reason ``_conv_forward`` is: one trace and one lowering of
+# a body a shape, whatever the number of call sites
+@functools.partial(jax.jit, static_argnames=("tokens", "interpret"))
+def _gated_forward(bcu, w, *, tokens, interpret):
+    b, t, c3 = bcu.shape
+    channels, taps = c3 // 3, w.shape[1]
+    thirds = [pl.BlockSpec((1, tokens, channels),
+                           lambda b_, n, third=i: (b_, n, third))
+              for i in range(3)]
+    return pl.pallas_call(
+        _gated_fwd_kernel, grid=(b, t // tokens),
+        in_specs=thirds + [pl.BlockSpec((1, taps, channels),
+                                        lambda b_, n: (b_, 0, 0))],
+        out_specs=thirds[0],
+        out_shape=jax.ShapeDtypeStruct((b, t, channels), bcu.dtype),
+        scratch_shapes=[pltpu.VMEM((CONV_HALO, channels), _F32)],
+        interpret=interpret, name="gated_conv_fwd",
+        compiler_params=_GATED_PARAMS,
+    )(bcu, bcu, bcu, w)
+
+
+@functools.partial(jax.jit, static_argnames=("tokens", "interpret"))
+def _gated_backward(bcu, w, dy, *, tokens, interpret):
+    b, t, c3 = bcu.shape
+    channels, taps = c3 // 3, w.shape[1]
+    blocks = t // tokens
+
+    def rev(n):
+        return blocks - 1 - n
+
+    def whole(wide):
+        return pl.BlockSpec((1, tokens, wide), lambda b_, n: (b_, rev(n), 0))
+
+    before = pl.BlockSpec(
+        (1, _BEFORE, c3), lambda b_, n: (
+            b_, jnp.maximum(rev(n) * (tokens // _BEFORE) - 1, 0), 0))
+    taps_row = pl.BlockSpec((1, taps, channels), lambda b_, n: (b_, 0, 0))
+    like = jax.ShapeDtypeStruct
+    return pl.pallas_call(
+        _gated_bwd_kernel, grid=(b, blocks),
+        in_specs=[whole(c3), before, taps_row, whole(channels)],
+        out_specs=[whole(c3), taps_row],
+        out_shape=[like(bcu.shape, bcu.dtype),
+                   like((b, taps, channels), _F32)],
+        scratch_shapes=[pltpu.VMEM((CONV_HALO, channels), _F32)],
+        interpret=interpret, name="gated_conv_bwd",
+        compiler_params=_GATED_PARAMS,
+    )(bcu, bcu, w, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gated(bcu, w, tokens, interpret):
+    return _gated_forward(bcu, w, tokens=tokens, interpret=interpret)
+
+
+def _gated_fwd(bcu, w, tokens, interpret):
+    return _gated(bcu, w, tokens, interpret), (bcu, w)
+
+
+def _gated_bwd(tokens, interpret, res, dy):
+    bcu, w = res
+    dbcu, dw = _gated_backward(bcu, w, dy, tokens=tokens,
+                               interpret=interpret)
+    return dbcu, dw.astype(w.dtype)
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+def gated_conv(bcu, w, *, tokens=None, interpret=False):
+    """The gated short convolution: ``bcu`` [B, T, 3 C] (thirds B | C | u)
+    and its taps ``w`` [B, K, C] (a copy a batch row: the taps' gradient
+    leaves a row at a time) -> ``C * conv(B * u)`` [B, T, C] in bcu's
+    dtype, the convolution causal and depthwise with zero history before a
+    row's first token. Differentiable in both; what a backward pass keeps
+    is those. ``tokens``: what a program takes of a row (a multiple of 16;
+    ``gated_conv_tokens``; a shorter row is padded to it)."""
+    t = bcu.shape[1]
+    tokens = int(tokens or gated_conv_tokens(w.shape[2], bcu.dtype))
+    pad = -t % tokens
+    if pad:
+        bcu = jnp.pad(bcu, ((0, 0), (0, pad), (0, 0)))
+    y = _gated(bcu, w, tokens, bool(interpret))
+    return y[:, :t] if pad else y

@@ -1784,9 +1784,12 @@ class Lfm2ShortConv(nn.Layer):
     one filter a channel; no bias, no activation, nothing recurrent: the
     mixer reaches ``kernel_size`` tokens, and a row's first tokens see
     zeros, never another row. The stage between the projections is
-    ``ops.linear_attention.gated_short_conv`` (XLA operations under a
-    ``jax.checkpoint`` of their own; ``paddle_tpu_shortconv_total{path}``).
-    Scopes: ``shortconv.in_proj`` / ``.stage`` / ``.out_proj``."""
+    ``ops.linear_attention.gated_short_conv``, which takes the decision
+    itself (``shortconv_path``, counted in
+    ``paddle_tpu_shortconv_total{path}``): one Mosaic kernel a pass on the
+    ``[B | C | u]`` stream where the program may hold it and the channels
+    fill lane groups, else XLA operations under a ``jax.checkpoint`` of
+    their own. Scopes: ``shortconv.in_proj`` / ``.stage`` / ``.out_proj``."""
 
     def __init__(self, hidden_size, kernel_size=3, weight_attr=None):
         super().__init__()
@@ -1807,7 +1810,6 @@ class Lfm2ShortConv(nn.Layer):
         with jax.named_scope("shortconv.in_proj"):
             bcu = self.in_proj(x)
         with jax.named_scope("shortconv.stage"):
-            linear_attention.shortconv_path()
             y = apply_op("gated_short_conv",
                          linear_attention.gated_short_conv, bcu,
                          self.conv.weight)

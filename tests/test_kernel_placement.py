@@ -2,8 +2,9 @@
 are the program's devices known". Its docstring's table as one parametrised
 test (platform and device count patched, meshes from the conftest's CPU
 devices), ``axis_size`` and ``on_mesh``'s optional seed, the expert layer's
-call site (``sharded=False``: the grouped matmul has no shard_map), and the
-convolution stage's decision taken outside its dispatched op."""
+call site (``sharded=False``: the grouped matmul has no shard_map), the
+convolution stage's decision taken outside its dispatched op, and the gated
+short convolution's table (``shortconv_path``)."""
 import contextlib
 
 import jax
@@ -228,3 +229,46 @@ def test_the_convolution_stage_is_decided_outside_its_op(monkeypatch):
                           "use_pallas_kernels": True})
     np.testing.assert_allclose(np.asarray(first._value),
                                np.asarray(second._value), atol=2e-5)
+
+
+#: (row, what ``placement`` observes, (seq, channels, taps, dtype)) -> the
+#: gated short convolution's path; the LFM2 cell's stage is 8,192 tokens of
+#: 2,048 channels a third, 3 taps, bf16
+CELL = (8192, 2048, 3, jnp.bfloat16)
+SHORTCONV = [
+    ("the-cell-on-one-chip", dict(), CELL, "kernel"),
+    ("the-check-in-float32", dict(), (8192, 2048, 3, jnp.float32), "kernel"),
+    ("as-many-taps-as-rows-carried", dict(), (8192, 2048, 9, jnp.bfloat16),
+     "kernel"),
+    ("announced-mesh", dict(mesh=4, devices=8), CELL, "kernel"),
+    ("interpreter", dict(interpret=True, tpu=False, devices=8), CELL,
+     "kernel"),
+    ("flag-off", dict(selected=False), CELL, "xla"),
+    ("no-tpu", dict(tpu=False), CELL, "xla"),
+    ("plain-jit-many-devices", dict(devices=8), CELL, "xla"),
+    ("half-a-lane-group", dict(), (8192, 2112, 3, jnp.bfloat16), "xla"),
+    ("taps-past-the-carried-rows", dict(), (8192, 2048, 10, jnp.bfloat16),
+     "xla"),
+    ("a-row-under-one-block", dict(), (255, 2048, 3, jnp.bfloat16), "xla"),
+    ("a-third-too-wide-for-a-block", dict(), (8192, 2**16, 3, jnp.float32),
+     "xla"),
+    ("float16", dict(), (8192, 2048, 3, jnp.float16), "xla"),
+]
+
+
+@pytest.mark.parametrize("how, stage, path",
+                         [row[1:] for row in SHORTCONV],
+                         ids=[row[0] for row in SHORTCONV])
+def test_the_gated_short_convolution_goes_by_what_it_observes(
+        monkeypatch, how, stage, path):
+    """``shortconv_path``: the kernels where ``placement`` lets a sharded
+    site hold them and the shape fits (lane groups, the carried rows, a
+    token block, bf16 or float32), the XLA stage everything else; one count
+    a call under the label of the path taken."""
+    before = {p: linear_attention._SHORTCONV_TOTAL.value(path=p)
+              for p in ("kernel", "xla")}
+    with observed(monkeypatch, **how):
+        assert linear_attention.shortconv_path(*stage) == path
+    for p, n in before.items():
+        assert linear_attention._SHORTCONV_TOTAL.value(path=p) == n + (
+            p == path), p

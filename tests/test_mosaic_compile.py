@@ -162,6 +162,16 @@ QK_CASES = {
     "qwen3-next-gqa": (1, 16384, 16, 2, 256, 64),
 }
 QK_CALLS = ("qk_heads_fwd", "qk_heads_bwd")
+#: LFM2's gated short convolution (rows, seq, channels a third, taps,
+#: dtype): the stage alone, forward + VJP, at the cell's four rows in bf16
+#: and at its check's two rows in float32 (half the tokens a block), and —
+#: the bf16 case — ``Lfm2ShortConv`` whole under amp O1. GATED_CALLS: the
+#: names its two Mosaic calls carry in a trace, under ``shortconv.stage``
+SHORTCONV_CASES = {
+    "lfm2-cell-bf16": (4, 8192, 2048, 3, "bfloat16"),
+    "lfm2-check-f32": (2, 8192, 2048, 3, "float32"),
+}
+GATED_CALLS = ("gated_conv_fwd", "gated_conv_bwd")
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
@@ -603,6 +613,70 @@ def _child():
                 rf"|f32\[{batch},{seq},{heads * d}\]",
                 text[text.index("\nENTRY "):]))}
 
+    from paddle_tpu.ops import linear_attention
+    from paddle_tpu.text.models import Lfm2ShortConv
+
+    for name, (batch, seq, channels, taps, dtype) in SHORTCONV_CASES.items():
+        def like(*shape, dtype=dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        def entry_f32(text, scope=""):
+            """float32 arrays as large as a third or as the stream that the
+            entry computation holds (under ``scope``)."""
+            return sum(
+                scope in line for line in
+                text[text.index("\nENTRY "):].splitlines() if re.search(
+                    rf"= f32\[{batch},{seq},({channels}|{3 * channels})\]",
+                    line))
+
+        def stage_alone(bcu, w, dy):
+            out, vjp = jax.vjp(linear_attention.gated_short_conv, bcu, w)
+            return out, vjp(dy)
+
+        paths = {p: linear_attention._SHORTCONV_TOTAL.value(path=p)
+                 for p in ("kernel", "xla")}
+        # the gate itself: a TPU's platform (told above) and a mesh of one
+        with topology.tracing_for(one_mesh), jax.default_matmul_precision(
+                "highest" if dtype == "float32" else "default"):
+            stage = jax.jit(stage_alone).lower(
+                like(batch, seq, 3 * channels),
+                like(taps, channels, dtype=jnp.float32),
+                like(batch, seq, channels)).compile()
+        text = stage.as_text()
+        out["shortconv-" + name] = {
+            "mosaic": text.count(MOSAIC),
+            "calls": [c for c in GATED_CALLS if f"%{c}" in text],
+            "paths": {p: linear_attention._SHORTCONV_TOTAL.value(path=p) - n
+                      for p, n in paths.items()},
+            "stream_sized_f32": entry_f32(text),
+            "temp_gb": stage.memory_analysis().temp_size_in_bytes / 1e9}
+        if dtype != "bfloat16":
+            continue
+        layer = Lfm2ShortConv(channels, taps)
+        layer.train()
+        params0, buffers0 = layer.functional_state()
+
+        def loss(params, x):
+            saved = layer.functional_state()
+            try:
+                with dispatch.trace_mode(), topology.tracing_for(one_mesh), \
+                        paddle.amp.auto_cast(level="O1"):
+                    layer.load_functional_state(params, buffers0)
+                    o = layer(paddle.Tensor(x))._value
+            finally:
+                layer.load_functional_state(*saved)
+            return jnp.sum(o.astype(jnp.float32))
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            {n: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+             for n, a in params0.items()},
+            like(batch, seq, channels)).compile().as_text()
+        out["shortconv-" + name].update({
+            "layer_mosaic": text.count(MOSAIC),
+            "layer_calls": [c for c in GATED_CALLS if f"%{c}" in text],
+            "layer_f32": entry_f32(text),
+            "layer_f32_under_stage": entry_f32(text, "shortconv.stage")})
+
     # the s128 cell's FFN under amp O1, forward + backward: what F.gelu's
     # erf lowers to behind linear1's gemm, and what leaves that fusion
     def ffn_loss(p, x):
@@ -894,6 +968,28 @@ def test_the_stage_before_the_core_keeps_its_float32_in_vmem(compiled, case):
     assert got["mosaic"] == 2 and got["calls"] == list(QK_CALLS)
     assert got["paths"] == {"kernel": 1, "xla": 0}
     assert got["q_sized_f32"] == 0
+
+
+@pytest.mark.parametrize("case", list(SHORTCONV_CASES))
+def test_the_gated_short_convolution_keeps_its_float32_in_vmem(compiled,
+                                                               case):
+    """LFM2's stage alone through its own gate, forward + VJP at the cell's
+    shape in bf16 and at its check's in float32: both kernels compile for
+    the described v5e within the VMEM they ask for, one Mosaic call a pass
+    (counted ``kernel``), what the program holds beside its arguments and
+    results is nothing (``bcu`` and the taps are the residuals, ``d bcu``
+    is written as one array), and in bf16 no float32 array as large as a
+    third or as the stream is in HBM — nor in ``Lfm2ShortConv`` whole under
+    amp O1, where the XLA stage's backward holds them by the GB."""
+    got = compiled["shortconv-" + case]
+    assert got["mosaic"] == 2 and got["calls"] == list(GATED_CALLS)
+    assert got["paths"] == {"kernel": 1, "xla": 0}
+    assert got["temp_gb"] <= 0.01, got
+    if "bf16" in case:
+        assert got["stream_sized_f32"] == 0
+        assert got["layer_mosaic"] == 2
+        assert got["layer_calls"] == list(GATED_CALLS)
+        assert got["layer_f32"] == got["layer_f32_under_stage"] == 0
 
 
 @pytest.mark.parametrize("case", list(KDA_CASES))
