@@ -388,30 +388,38 @@ def conv3d(x, weight, bias=None, stride=1, padding=0, dilation=1, groups=1,
         dilation=_pair(dilation, 3), groups=int(groups), data_format=data_format, nd=3)
 
 
-def _causal_depthwise_conv1d(x, w, *, activation):
+def _causal_depthwise_conv1d(x, w, bias=None, *, activation):
     k = w.shape[0]
     xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
     padded = jnp.pad(xf, ((0, 0), (k - 1, 0), (0, 0)))
     seq = x.shape[1]
     out = sum(padded[:, j:j + seq] * wf[j] for j in range(k))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     if activation == "silu":
         out = jax.nn.silu(out)
     return out.astype(x.dtype)
 
 
-def causal_depthwise_conv1d(x, weight, activation=None, name=None):
+def causal_depthwise_conv1d(x, weight, activation=None, name=None,
+                            bias=None):
     """The short convolution of linear-attention and state-space layers, on
     ``x`` [batch, seq, channels] as it leaves a projection (no transpose to
     ``conv1d``'s layout, no padding for the caller to get right): every
     channel its own ``weight`` [kernel, channels] taps over the positions
     t - kernel + 1 .. t (tap ``kernel - 1`` meets position t), zero history
-    before a row's start; ``activation`` None or 'silu'. The multiply-adds
-    are float32 whatever ``x`` is, the result takes x's dtype."""
+    before a row's start; ``bias`` None or [channels], one number a channel
+    added to the taps' sum (Mamba-2's convolution; at a row's first token it
+    stands on a zero history like any other); ``activation`` None or 'silu',
+    after the bias. The multiply-adds are float32 whatever ``x`` is, the
+    result takes x's dtype."""
     if activation not in (None, "silu"):
         raise ValueError(f"activation must be None or 'silu', got "
                          f"{activation!r}")
-    return apply_op("causal_depthwise_conv1d", _causal_depthwise_conv1d, x,
-                    weight, activation=activation)
+    # without a bias, the call every layer made before there was one
+    args = (x, weight) if bias is None else (x, weight, bias)
+    return apply_op("causal_depthwise_conv1d", _causal_depthwise_conv1d,
+                    *args, activation=activation)
 
 
 def _conv_transpose_nd(x, w, b, *, stride, padding, output_padding, dilation, groups,
@@ -1140,17 +1148,18 @@ def square_error_cost(input, label):
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
                                  is_causal=False, training=True, name=None,
-                                 window=None):
+                                 window=None, scale=None):
     """Fused attention entry point. Uses the Pallas flash kernel on TPU when
     enabled (ops/pallas/flash_attention.py); otherwise a jnp reference that
     XLA fuses well. Layout: [batch, heads, seq, head_dim]. ``window`` (with
     ``is_causal``): a sliding window of that many keys up to the query's own
-    position."""
+    position. ``scale``: what multiplies the scores before the softmax;
+    None is ``head_dim ** -0.5``."""
     from ..ops import attention as attn_ops
 
     return attn_ops.scaled_dot_product_attention(
         q, k, v, attn_mask=attn_mask, dropout_p=dropout_p, is_causal=is_causal,
-        training=training, window=window)
+        training=training, window=window, scale=scale)
 
 
 # ------------------------------------------------------------- vision misc

@@ -51,24 +51,31 @@ class Conv1D(_ConvNd):
 class CausalDepthwiseConv1D(Layer):
     """``F.causal_depthwise_conv1d`` with its weight: [kernel_size,
     channels], one filter a channel over the sequence axis of [batch, seq,
-    channels], no bias. A row's history is its own: the ``kernel_size`` - 1
-    positions before a row's first token are zeros, whatever the batch
-    holds before it (row r never reads row r - 1; a caller that carries a
-    history across calls, as a decoder would, has to pass it in the row).
-    Starts as torch's depthwise ``Conv1d`` does
+    channels], and with ``bias=True`` one number a channel added to the
+    taps' sum before the activation (``bias`` [channels]; Mamba-2's
+    convolution has one, the linear-attention layers' and LFM2's have none).
+    A row's history is its own: the ``kernel_size`` - 1 positions before a
+    row's first token are zeros, whatever the batch holds before it (row r
+    never reads row r - 1; a caller that carries a history across calls, as
+    a decoder would, has to pass it in the row). Taps and bias start as
+    torch's depthwise ``Conv1d`` starts them
     (Uniform(+-1/sqrt(kernel_size)))."""
 
     def __init__(self, channels, kernel_size, activation=None,
-                 weight_attr=None):
+                 weight_attr=None, bias=False):
         super().__init__()
         self._activation = activation
         bound = 1.0 / np.sqrt(kernel_size)
         self.weight = self.create_parameter(
             [kernel_size, channels], attr=weight_attr,
             default_initializer=I.Uniform(-bound, bound))
+        self.bias = self.create_parameter(
+            [channels], default_initializer=I.Uniform(-bound, bound)
+        ) if bias else None
 
     def forward(self, x):
-        return F.causal_depthwise_conv1d(x, self.weight, self._activation)
+        return F.causal_depthwise_conv1d(x, self.weight, self._activation,
+                                         bias=self.bias)
 
 
 class Conv2D(_ConvNd):

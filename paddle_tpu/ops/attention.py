@@ -337,9 +337,19 @@ def _short(qkv, key, *, num_heads, scale, dropout_p, interpret):
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
                                  is_causal=False, training=True,
-                                 window=None):
+                                 window=None, scale=None):
+    """Attention on [batch, heads, seq, head_dim] Tensors by the route
+    ``attention_route`` picks (``stream``: the streaming flash kernel, with
+    a window its banded calls; else ``xla``: ``_sdpa_ref``), counted in
+    ``paddle_tpu_attention_route_total{route}``. ``scale`` multiplies the
+    scores before the softmax on every route; None is ``head_dim ** -0.5``
+    (Granite's ``attention_multiplier`` is 1/64 at heads of 64, not 1/8).
+    ``window`` (static; with ``is_causal``, queries on as many keys): a
+    sliding window of that many keys up to the query's own position; None,
+    or one that reaches every key, is full attention. A call that gives
+    neither lowers to the program it always did."""
     head_dim = q.shape[-1]
-    scale = 1.0 / math.sqrt(head_dim)
+    scale = 1.0 / math.sqrt(head_dim) if scale is None else float(scale)
     p = float(dropout_p) if training else 0.0
     route = attention_route(
         batch=q.shape[0], seq_q=q.shape[-2], seq_k=k.shape[-2],

@@ -1,10 +1,29 @@
-"""The gated delta rule's core, under either of two decays: ONE PER KEY
-CHANNEL (Kimi Delta Attention; Kimi Linear technical report,
-arXiv:2510.26692), ``g`` of [B, T, H, d_k], and ONE A HEAD (Gated DeltaNet;
-Yang et al., arXiv:2412.06464 — Qwen3-Next's linear layers), ``g`` of
-[B, T, H]. As a recurrence over tokens and as the chunked scan: here in XLA
-operations (what a CPU, a program whose devices are not known and odd widths
-run, and what the tests hold everything to), in
+"""Linear-time token mixers' cores and the stages beside them. Two
+recurrences and two convolution stages live here; which function is whose:
+
+- **the gated delta rule** (``kda_recurrent`` / ``kda_chunked`` /
+  ``gated_delta_rule``, with ``_unit_lower_inverse``, ``_pair_products``,
+  ``_scalar_pair_products``, ``_segment``, ``core_path``), under either of
+  two decays: ONE PER KEY CHANNEL (Kimi Delta Attention; Kimi Linear
+  technical report, arXiv:2510.26692), ``g`` of [B, T, H, d_k], and ONE A
+  HEAD (Gated DeltaNet; Yang et al., arXiv:2412.06464 — Qwen3-Next's linear
+  layers), ``g`` of [B, T, H];
+- **the selective state-space scan** (``ssd_recurrent`` / ``ssd_chunked`` /
+  ``ssd_scan``, with ``_ssd_segment``, ``ssd_path``): Mamba-2's (Dao & Gu,
+  arXiv:2405.21060 — Granite-4.0-H's ``mamba`` layers), the scalar-decay
+  scan WITHOUT the delta correction, its own section below;
+- **the convolution stage before a scan** (``conv_streams`` with
+  ``conv_path`` / ``conv_kernel`` / ``_conv_xla`` / ``_conv_kernel``): taps,
+  an optional bias, SiLU and q's and k's L2 norm — the three layers' above;
+- **the gated short convolution** (``gated_short_conv`` with
+  ``shortconv_path`` / ``_gated_xla`` / ``_gated_kernel``): LFM2's whole
+  mixer;
+- ``head_sums`` / ``on_head_lanes`` / ``head_rsqrt`` / ``l2_normed``: a
+  head's statistics on [.., H d] streams, for any of them.
+
+**The gated delta rule.** As a recurrence over tokens and as the chunked
+scan: here in XLA operations (what a CPU, a program whose devices are not
+known and odd widths run, and what the tests hold everything to), in
 ``ops/pallas/linear_attention.py`` as the Mosaic kernels a train step on a
 TPU runs. Every path takes either decay as it comes: a decay a head is never
 broadcast to the channels, and its gradient leaves as [B, T, H].
@@ -109,6 +128,48 @@ own, as ``_conv_xla``; what every other program runs and what the kernels
 are held to), counted in ``paddle_tpu_shortconv_total{path}``. The layer's
 call carries no decision — two arrays, no keyword — so the op asks for
 itself (``kernel="ask"``).
+
+**The selective state-space scan** (Mamba-2's SSD). Per head, with a state
+S in R^{N x P} (zero at a row's start), x_t in R^P, a step ``dt_t > 0``
+(after its softplus), ``A < 0`` a head, and B_t, C_t in R^N that belong to
+the head's GROUP (``H % G == 0``; Granite-4.0-H has one group for 64
+heads)::
+
+    alpha_t = exp(dt_t A)
+    S_t = alpha_t S_{t-1} + B_t (dt_t x_t)^T
+    y_t = S_t^T C_t + D x_t
+
+It is the delta rule's scalar-decay scan with q = C, k = B, v = dt x and
+NOTHING corrected: no ``S'^T k`` is taken off what is written, so there is
+no inverse, and ``beta = 0`` in the delta rule is not it (that writes
+nothing). ``ssd_recurrent`` is the recurrence, one token a ``lax.scan``
+step. ``ssd_chunked`` computes the same in chunks of C tokens, with ``G_r``
+the decay's logarithm ``dt A`` summed from a chunk's first token to its
+r-th and ``S_0`` the state entering it::
+
+    y_r = sum_{i <= r} <C_r, B_i> exp(G_r - G_i) dt_i x_i
+          + exp(G_r) S_0^T C_r + D x_r
+    S_C = exp(G_C) S_0 + sum_i exp(G_C - G_i) B_i (dt_i x_i)^T
+
+``<C_r, B_i>`` is the GROUP's: one [C, N] x [N, C] product a chunk and
+group, and only the [C, C] mask of exponentials (every exponent <= 0 on the
+triangle, as ``_scalar_pair_products``') is a head's. B and C are never
+repeated to the heads: the products that meet x carry a group axis and a
+heads-in-group axis. The states entering a segment's chunks come from the
+chunks' own sums by a [chunks, chunks] triangle of decays a head (no
+sequential loop inside a segment: nothing a chunk writes depends on the
+state, where the delta rule's ``u`` does); an outer ``lax.scan`` over
+segments carries the state, each segment under ``jax.checkpoint``, so a
+backward pass holds one segment's float32 (mask, sums, states) and not the
+row's. The backward is plain autodiff. Precision: ``dt A``, its sums, the
+mask, the state and y before its cast are float32 (the sums and the
+triangle over chunks ``Precision.HIGHEST``); the [C, N] x [N, C],
+[C, C] x [C, P] and [C, N] x [N, P] products take operands of x's dtype
+(bf16 under amp O1) and accumulate in float32. ``ssd_scan`` is the entry
+point a layer calls: the path from length alone today (``ssd_path``:
+``chunked`` | ``recurrent``), counted in ``paddle_tpu_ssd_core_total{path}``
+— a Mosaic kernel at keys of 128 and values of 64 is a third label, not a
+new counter.
 """
 import functools
 
@@ -146,6 +207,13 @@ _SHORTCONV_TOTAL = obs_metrics.counter(
     "gated short convolutions (gate, taps, gate between two projections: "
     "LFM2's mixer) by the path taken: kernel (one Mosaic call a pass) | "
     "xla; one count per traced layer call",
+    labelnames=("path",))
+
+_SSD_TOTAL = obs_metrics.counter(
+    "paddle_tpu_ssd_core_total",
+    "selective state-space scans (Mamba-2's SSD: a scalar decay a head, no "
+    "delta correction) by the path taken (chunked | recurrent); under jit "
+    "one count per traced layer call",
     labelnames=("path",))
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -579,8 +647,181 @@ def _kernel_output(q, k, v, g, beta, *, interpret):
     return placement.on_mesh(kernel, (q, k, v, g, beta), head_axis=2)
 
 
+# ------------------------------------- the selective state-space scan
+#: tokens a chunk and a segment of ``ssd_chunked`` where the caller gives
+#: none: a v5e reading at Granite-4.0-H's shape (tools/ssd_bench.py, ms
+#: forward / forward + backward, PR 47: 64 x 512 3.98 / 12.15, 128 x 1024
+#: 2.54 / 7.64, 256 x 1024 2.36 / 7.10, 256 x 2048 2.23 / 6.86)
+SSD_CHUNK, SSD_SEGMENT = 256, 2048
+
+
+def ssd_recurrent(x, dt, a, b, c, d, initial_state=None):
+    """The recurrence token by token. x: [B, T, H, P]; dt: [B, T, H], the
+    step after its softplus; a: [H], negative; b, c: [B, T, G, N] with
+    ``H % G == 0`` (head h reads group ``h // (H / G)``); d: [H], the
+    skip. Returns (y [B, T, H, P] in x's dtype, the final state [B, H, N,
+    P] in float32). Everything is computed in float32."""
+    f32 = jnp.float32
+    bsz, _, h, p = x.shape
+    g, n = b.shape[-2:]
+    state = (jnp.zeros((bsz, h, n, p), f32) if initial_state is None
+             else initial_state.astype(f32))
+    af = a.astype(f32)
+
+    def heads(t):
+        """a group's [B, G, N] on each of its heads: [B, H, N]"""
+        return jnp.repeat(t, h // g, axis=1)
+
+    def step(s, xs):
+        x_t, dt_t, b_t, c_t = xs
+        s = (jnp.exp(dt_t * af)[..., None, None] * s
+             + heads(b_t)[..., None] * (dt_t[..., None] * x_t)[..., None, :])
+        return s, jnp.einsum("bhnp,bhn->bhp", s, heads(c_t),
+                             precision=_HIGHEST)
+
+    xs = tuple(jnp.moveaxis(t.astype(f32), 1, 0) for t in (x, dt, b, c))
+    state, y = jax.lax.scan(step, state, xs)
+    y = jnp.moveaxis(y, 0, 1) + d.astype(f32)[:, None] * x.astype(f32)
+    return y.astype(x.dtype), state
+
+
+def _ssd_segment(state, xs, *, a):
+    """One segment of whole chunks. xs: x [B, n, C, G, R, P] (R heads a
+    group) and b, c [B, n, C, G, N] in the operand dtype, dt [B, n, C, G, R]
+    float32; a [G, R] float32; state [B, G, R, N, P] float32. Returns (the
+    state after the segment, y [B, n, C, G, R, P] float32 without the D
+    skip)."""
+    x, dt, b, c = xs
+    f32, mm = jnp.float32, x.dtype
+    chunk, chunks = x.shape[2], x.shape[1]
+    # the decay's logarithm summed from the chunk's start, as a product with
+    # the lower triangle of ones (``_segment``'s reason)
+    ones = jnp.tril(jnp.ones((chunk, chunk), f32))
+    cum = jnp.einsum("ri,bnigh->bnrgh", ones, dt * a, precision=_HIGHEST)
+    last = cum[:, :, -1]                                      # [B, n, G, R]
+    # the group's pair terms, and a head's mask of exponentials on them
+    pairs = jnp.einsum("bnrgk,bnigk->bngri", c, b, preferred_element_type=f32)
+    r, i = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    lanes = jnp.moveaxis(cum, 2, -1)                          # [B, n, G, R, C]
+    mask = jnp.where(i <= r, jnp.exp(jnp.minimum(
+        lanes[..., :, None] - lanes[..., None, :], 0.0)), 0.0)
+    xf = x.astype(f32)
+    written = (xf * dt[..., None]).astype(mm)                 # dt x
+    y = jnp.einsum("bnghri,bnighp->bnrghp",
+                   (pairs[:, :, :, None] * mask).astype(mm), written,
+                   preferred_element_type=f32)
+    # what each chunk adds to the state by its end
+    sums = jnp.einsum(
+        "bnigk,bnighp->bnghkp", b,
+        (xf * (dt * jnp.exp(last[:, :, None] - cum))[..., None]).astype(mm),
+        preferred_element_type=f32)
+    # the state entering chunk m (m = chunks: leaving the segment) from the
+    # entering state and the chunks before it: exponents of whole chunks'
+    # decays, every one <= 0
+    total = jnp.cumsum(last, axis=1)                          # [B, n, G, R]
+    upto = jnp.concatenate([jnp.zeros_like(total[:, :1]), total], axis=1)
+    m, j = jnp.arange(chunks + 1)[:, None], jnp.arange(chunks)[None, :]
+    upto_l, total_l = jnp.moveaxis(upto, 1, -1), jnp.moveaxis(total, 1, -1)
+    carry = jnp.where(j < m, jnp.exp(jnp.minimum(
+        upto_l[..., :, None] - total_l[..., None, :], 0.0)), 0.0)
+    states = (jnp.exp(upto_l)[..., None, None] * state[..., None, :, :]
+              + jnp.einsum("bghmj,bjghkp->bghmkp", carry, sums,
+                           precision=_HIGHEST))    # [B, G, R, n + 1, N, P]
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "bnrgk,bghnkp->bnrghp", c, states[:, :, :, :-1].astype(mm),
+        preferred_element_type=f32)
+    return states[:, :, :, -1], y
+
+
+def ssd_chunked(x, dt, a, b, c, d, initial_state=None, *,
+                chunk=SSD_CHUNK, segment=SSD_SEGMENT):
+    """The same function as ``ssd_recurrent`` (same arguments and results),
+    in chunks of ``chunk`` tokens and segments of ``segment`` (whole
+    chunks). Any length: the row is padded to whole segments with tokens of
+    step 0, which write nothing and decay nothing. The large products take
+    x's dtype as their operands' (module docstring); the state, and y before
+    its cast to x's dtype, are float32."""
+    f32 = jnp.float32
+    bsz, t, h, p = x.shape
+    g, n = b.shape[-2:]
+    if h % g:
+        raise ValueError(f"{h} heads are no multiple of {g} groups")
+    chunk = min(int(chunk), max(1, t))
+    chunks = -(-t // chunk)
+    per_segment = max(1, min(int(segment) // chunk, chunks))
+    segments = -(-chunks // per_segment)
+    pad = segments * per_segment * chunk - t
+
+    def split(v, dtype, *tail):
+        """[B, T, ...] -> [segments, B, chunks, C, *tail]"""
+        v = jnp.pad(v.astype(dtype), [(0, 0), (0, pad)] + [(0, 0)] * (
+            v.ndim - 2))
+        return jnp.moveaxis(
+            v.reshape(bsz, segments, per_segment, chunk, *tail), 1, 0)
+
+    xs = (split(x, x.dtype, g, h // g, p), split(dt, f32, g, h // g),
+          split(b, x.dtype, g, n), split(c, x.dtype, g, n))
+    state = (jnp.zeros((bsz, h, n, p), f32) if initial_state is None
+             else initial_state.astype(f32)).reshape(bsz, g, h // g, n, p)
+    body = functools.partial(_ssd_segment,
+                             a=a.astype(f32).reshape(g, h // g))
+    if segments == 1:
+        state, y = body(state, tuple(v[0] for v in xs))
+        y = y[None]
+    else:
+        state, y = jax.lax.scan(jax.checkpoint(body), state, xs)
+    # [segments, B, chunks, C, G, R, P] -> [B, T, H, P]
+    y = (jnp.moveaxis(y, 0, 1).reshape(bsz, -1, h, p)[:, :t]
+         + d.astype(f32)[:, None] * x.astype(f32))
+    return y.astype(x.dtype), state.reshape(bsz, h, n, p)
+
+
+def ssd_path(seq):
+    """``chunked`` | ``recurrent`` for a row of ``seq`` tokens, from its
+    length alone today: a chunk's set-up (the pair product, the mask) pays
+    from ``SUB`` tokens on, else the recurrence."""
+    return "recurrent" if seq < SUB else "chunked"
+
+
+def ssd_scan(x, dt, a, b, c, d, *, groups=1, chunk=SSD_CHUNK,
+             segment=SSD_SEGMENT):
+    """The selective state-space scan on Tensors: x as heads [B, T, H, P]
+    or as a stream [B, T, H P] (H is dt's), y in the rank given; dt [B, T,
+    H] after its softplus, a [H], b and c [B, T, G, N] or as streams [B, T,
+    G N] of ``groups`` groups, d [H]. The final state stays inside:
+    training starts every row from a zero state and keeps none. Counted in
+    ``paddle_tpu_ssd_core_total{path}``, one count a traced call."""
+    path = ssd_path(x.shape[1])
+    _SSD_TOTAL.inc(path=path)
+    if path == "recurrent":
+        return apply_op("ssd_core_recurrent", _ssd_recurrent_output, x, dt,
+                        a, b, c, d, groups=int(groups))
+    return apply_op("ssd_core", _ssd_chunked_output, x, dt, a, b, c, d,
+                    groups=int(groups), chunk=int(chunk),
+                    segment=int(segment))
+
+
+def _ssd_on_heads(scan, x, dt, a, b, c, d, groups):
+    """``scan``'s y for x, b and c of either rank: streams are viewed as
+    heads (groups) and y as x came."""
+    if b.ndim == 3:
+        b, c = (t.reshape(*t.shape[:2], groups, -1) for t in (b, c))
+    heads = x.reshape(*x.shape[:2], dt.shape[-1], -1)
+    return scan(heads, dt, a, b, c, d)[0].reshape(x.shape)
+
+
+def _ssd_recurrent_output(x, dt, a, b, c, d, *, groups):
+    return _ssd_on_heads(ssd_recurrent, x, dt, a, b, c, d, groups)
+
+
+def _ssd_chunked_output(x, dt, a, b, c, d, *, groups, chunk, segment):
+    return _ssd_on_heads(functools.partial(
+        ssd_chunked, chunk=chunk, segment=segment), x, dt, a, b, c, d,
+        groups)
+
+
 # ------------------------------------------------- the convolution stage
-def conv_path(seq, segments, head, taps, dtype):
+def conv_path(seq, segments, head, taps, dtype, bias=False):
     """``kernel`` | ``xla`` for the convolution stage of a row of ``seq``
     tokens whose ``segments`` (``(stream, start, width, scale)``, as
     ``conv_streams`` takes them) have heads ``head`` wide and ``taps`` taps
@@ -591,10 +832,11 @@ def conv_path(seq, segments, head, taps, dtype):
     bf16 or float32 and the row is at least one token block; where
     ``on_mesh`` cuts the heads over an 'mp' axis, only if that cuts every
     stream between whole heads of ONE segment. The XLA stage everything
-    else."""
+    else — and every stage with a ``bias``: the kernels' bodies take none
+    (Mamba-2's stage; ROADMAP Speed)."""
     from .pallas import linear_attention as kernels
 
-    if not (seq >= kernels.CONV_TOKENS
+    if bias or not (seq >= kernels.CONV_TOKENS
             and kernels.conv_supported(segments, head, taps, dtype)
             and placement.kernel(sharded=True)):
         return "xla"
@@ -606,17 +848,18 @@ def conv_path(seq, segments, head, taps, dtype):
     return "kernel"
 
 
-def conv_kernel(x, w, segments, head):
+def conv_kernel(x, w, segments, head, bias=False):
     """One call's decision, counted, as ``conv_streams`` takes it:
     ``placement.kernel``'s answer where ``conv_path`` says ``kernel`` for
-    streams like ``x`` and taps like ``w``, else None (the XLA stage). An
-    op's caller asks OUTSIDE the op; the answer rides its static arguments."""
-    path = conv_path(x.shape[1], segments, head, w.shape[0], x.dtype)
+    streams like ``x`` and taps like ``w`` (never with a ``bias``), else
+    None (the XLA stage). An op's caller asks OUTSIDE the op; the answer
+    rides its static arguments."""
+    path = conv_path(x.shape[1], segments, head, w.shape[0], x.dtype, bias)
     _CONV_TOTAL.inc(path=path)
     return placement.kernel(sharded=True) if path == "kernel" else None
 
 
-def conv_streams(xs, ws, segments, *, head, eps, kernel="ask"):
+def conv_streams(xs, ws, segments, *, head, eps, kernel="ask", biases=None):
     """Linear attention's stage between the projections and the scan, on
     arrays: streams ``xs`` [B, T, C_i] with their taps ``ws`` [K, C_i] ->
     one [B, T, width] array a segment ``(stream, start, width, scale)``:
@@ -625,27 +868,32 @@ def conv_streams(xs, ws, segments, *, head, eps, kernel="ask"):
     SiLU, then, where ``scale`` is a number, L2-normalised over each
     ``head`` features in float32 and scaled; where it is None, as SiLU left
     them. The segments cover every stream in order. Results take their
-    stream's dtype. ``kernel``: ``conv_kernel``'s answer, from a caller under
-    ``apply_op``; ``"ask"`` (under the caller's own jit): taken here."""
+    stream's dtype. ``biases``: None, or one [C_i] array a stream, added to
+    the taps' sum before SiLU (the state-space layer's stage: x | B | C are
+    three ``scale=None`` segments of one biased stream) — the XLA stage
+    whatever ``kernel`` says. ``kernel``: ``conv_kernel``'s answer, from a
+    caller under ``apply_op``; ``"ask"`` (under the caller's own jit): taken
+    here."""
     segments = tuple((int(s), int(a), int(n), None if c is None else float(c))
                      for s, a, n, c in segments)
     if kernel == "ask":
-        kernel = conv_kernel(xs[0], ws[0], segments, head)
-    if kernel is None:
-        return _conv_xla(tuple(xs), tuple(ws), segments, head, eps)
+        kernel = conv_kernel(xs[0], ws[0], segments, head,
+                             biases is not None)
+    if kernel is None or biases is not None:
+        return _conv_xla(tuple(xs), tuple(ws), segments, head, eps, biases)
     return _conv_kernel(tuple(xs), tuple(ws), segments, head, eps,
                         kernel == "interpret")
 
 
-def _conv_xla(xs, ws, segments, head, eps):
+def _conv_xla(xs, ws, segments, head, eps, biases=None):
     """The stage in XLA operations: float32 arrays as large as a stream,
     so a ``jax.checkpoint`` of its own — a differentiated program keeps
     the (bf16) streams and rebuilds the float32 inside it."""
     from ..nn import functional as F
 
-    def stage(xs, ws):
-        made = [F._causal_depthwise_conv1d(x, w, activation="silu")
-                for x, w in zip(xs, ws)]
+    def stage(xs, ws, *biases):
+        made = [F._causal_depthwise_conv1d(x, w, *bias, activation="silu")
+                for x, w, *bias in zip(xs, ws, *biases)]
         outs = []
         for stream, start, width, scale in segments:
             y = made[stream][..., start:start + width]
@@ -653,7 +901,8 @@ def _conv_xla(xs, ws, segments, head, eps):
                 y, width // head, eps=eps, scale=scale))
         return tuple(outs)
 
-    return jax.checkpoint(stage)(xs, ws)
+    return jax.checkpoint(stage)(
+        xs, ws, *(() if biases is None else (tuple(biases),)))
 
 
 def _conv_kernel(xs, ws, segments, head, eps, interpret):

@@ -2040,3 +2040,350 @@ def lfm2_layer_types(num_hidden_layers):
     pattern's start."""
     return ["full_attention" if i in _LFM2_ATTENTION_LAYERS else "conv"
             for i in range(num_hidden_layers)]
+
+
+# A Mamba-2 layer's element-wise stages, as the linear-attention layers':
+# float32 arrays as large as a stream, each under a ``jax.checkpoint`` of
+# its own (the convolution stage's is ``conv_streams``'), so that a backward
+# pass keeps the stage's bf16 inputs and rebuilds the float32.
+def _mamba_split(zxbcdt, *, inner, conv):
+    """``in_proj``'s output, split [z inner | xBC conv | dt heads] in that
+    order."""
+    return (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv],
+            zxbcdt[..., inner + conv:])
+
+
+def _mamba_segments(inner, state):
+    """``conv_streams``' segments of the ONE biased x | B | C stream: none
+    of them normed."""
+    return ((0, 0, inner, None), (0, inner, state, None),
+            (0, inner + state, state, None))
+
+
+def _mamba_streams(xbc, w, bias, *, inner, state, head, kernel="ask"):
+    """The x | B | C stream through ONE causal depthwise convolution, its
+    bias and SiLU -> x [B, T, inner], B and C [B, T, groups x d_state]:
+    ``conv_streams`` on three segments that take no norm. ``kernel``:
+    ``conv_kernel``'s answer, taken outside the op (with a bias: None)."""
+    from ..ops.linear_attention import conv_streams
+
+    return conv_streams((xbc,), (w,), _mamba_segments(inner, state),
+                        head=head, eps=0.0, kernel=kernel,
+                        biases=None if bias is None else (bias,))
+
+
+def _mamba_step(dt, dt_bias, a_log):
+    """(the step a head and token, ``softplus(dt + dt_bias)`` — no clamp:
+    ``time_step_limit`` is (0, inf) —, the decay's rate a head, ``A =
+    -exp(A_log)``), in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    return (jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)),
+            -jnp.exp(a_log.astype(f32)))
+
+
+def _mamba_gated_norm(y, z, w, *, eps):
+    """``w * RMSNorm(y * silu(z))``: THE GATE FIRST, then one mean square
+    over ALL the features (not a head's), in float32 (w from 1); [B, T,
+    inner] -> the same in y's dtype. (``_gdn_gated_norm`` /
+    ``_kda_gated_norm`` norm a head and gate after.)"""
+    import jax
+    import jax.numpy as jnp
+
+    def gated(y, z, w):
+        f32 = jnp.float32
+        g = y.astype(f32) * jax.nn.silu(z.astype(f32))
+        return (g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                                  + eps) * w.astype(f32)).astype(y.dtype)
+
+    return jax.checkpoint(gated)(y, z, w)
+
+
+class Mamba2Mixer(nn.Layer):
+    """Mamba-2's selective state-space mixer as Granite-4.0-H has it (HF
+    ``granitemoehybrid`` / ``mamba2``; Dao & Gu, arXiv:2405.21060): ``[z |
+    xBC | dt] = in_proj(x)`` (hidden -> inner + (inner + 2 groups x d_state)
+    + heads, split in that order, no bias); ``xBC`` through ONE causal
+    depthwise convolution of ``d_conv`` taps WITH A BIAS a channel, then
+    SiLU, and split ``[x | B | C]`` — x is ``num_heads`` heads of
+    ``head_dim``, B and C are a GROUP's, shared by ``num_heads / n_groups``
+    heads; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` a head;
+    the state-space scan ``S_t = exp(dt_t A) S_{t-1} + B_t (dt_t x_t)^T``,
+    ``y_t = S_t^T C_t + D x_t`` (``ops.linear_attention.ssd_scan``: the
+    scalar-decay scan with no delta correction, counted in
+    ``paddle_tpu_ssd_core_total{path}``); then THE GATE BEFORE THE NORM,
+    ``out_proj(w * RMSNorm_inner(y * silu(z)))`` — one mean square over all
+    ``inner`` features. Convolution, bias, SiLU, the step, the scan's decay
+    sums, mask and state, and the gated norm are float32 under amp O1; the
+    projections and the scan's large products take bf16 operands. ``A_log``,
+    ``dt_bias``, ``D`` and the norm's weight are named for an optimizer's
+    ``apply_decay_param_fun``. Scopes: ``mamba.in_proj`` / ``.conv`` (taps,
+    bias, SiLU, the split) / ``.dt`` (softplus, ``-exp(A_log)``) / ``.core``
+    (the scan: ``dt x``, ``dt A``, the ``D`` skip) / ``.norm`` /
+    ``.out_proj``."""
+
+    #: Mamba-2's own start of the step: dt ~ exp(U(log min, log max)),
+    #: floored, behind the softplus
+    TIME_STEP_MIN, TIME_STEP_MAX, TIME_STEP_FLOOR = 1e-3, 1e-1, 1e-4
+
+    def __init__(self, hidden_size, num_heads=64, head_dim=64, d_state=128,
+                 n_groups=1, d_conv=4, conv_bias=True, chunk=None,
+                 segment=None, rms_norm_eps=1e-5, weight_attr=None):
+        super().__init__()
+        from ..framework.param_attr import ParamAttr
+        from ..ops import linear_attention
+
+        if num_heads % n_groups:
+            raise ValueError(f"{num_heads} heads are no multiple of "
+                             f"{n_groups} groups")
+        self.num_heads, self.head_dim = num_heads, head_dim
+        self.d_state, self.n_groups = d_state, n_groups
+        self.inner = num_heads * head_dim
+        self.conv_dim = self.inner + 2 * n_groups * d_state
+        self.chunk = int(chunk or linear_attention.SSD_CHUNK)
+        self.segment = int(segment or linear_attention.SSD_SEGMENT)
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.in_proj = proj(hidden_size,
+                            self.inner + self.conv_dim + num_heads)
+        self.conv1d = nn.CausalDepthwiseConv1D(
+            self.conv_dim, d_conv, activation="silu", bias=conv_bias)
+
+        # A = exp(A_log) ~ U(1, 16); dt_bias the inverse softplus of dt ~
+        # exp(U(log min, log max)) floored; D = 1: Mamba-2's own start
+        def named(name, initializer):
+            return ParamAttr(name=f"{self.full_name()}.{name}",
+                             initializer=initializer)
+
+        self.A_log = self.create_parameter(
+            [num_heads], attr=named("A_log", nn.initializer.Uniform(1.0, 16.0)))
+        self.A_log.set_value(np.log(np.asarray(self.A_log._value)))
+        self.dt_bias = self.create_parameter(
+            [num_heads], attr=named("dt_bias", nn.initializer.Uniform(
+                math.log(self.TIME_STEP_MIN), math.log(self.TIME_STEP_MAX))))
+        dt = np.maximum(np.exp(np.asarray(self.dt_bias._value, np.float64)),
+                        self.TIME_STEP_FLOOR)
+        self.dt_bias.set_value(dt + np.log(-np.expm1(-dt)))
+        self.D = self.create_parameter(
+            [num_heads], attr=named("D", nn.initializer.Constant(1.0)))
+        self.norm = ZeroCenteredRMSNorm(self.inner, eps=rms_norm_eps,
+                                        zero_centered=False)
+        self.out_proj = proj(self.inner, hidden_size)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops.linear_attention import conv_kernel, ssd_scan
+
+        state = self.n_groups * self.d_state
+        with jax.named_scope("mamba.in_proj"):
+            z, xbc, dt = apply_op(
+                "mamba_split", _mamba_split, self.in_proj(x),
+                inner=self.inner, conv=self.conv_dim)
+        with jax.named_scope("mamba.conv"):
+            xs, b, c = apply_op(
+                "mamba_streams", _mamba_streams, xbc, self.conv1d.weight,
+                self.conv1d.bias, inner=self.inner, state=state,
+                head=self.head_dim, kernel=conv_kernel(
+                    xbc, self.conv1d.weight,
+                    _mamba_segments(self.inner, state), self.head_dim,
+                    bias=self.conv1d.bias is not None))
+        with jax.named_scope("mamba.dt"):
+            dt, a = apply_op("mamba_step", _mamba_step, dt, self.dt_bias,
+                             self.A_log)
+        with jax.named_scope("mamba.core"):
+            y = ssd_scan(xs, dt, a, b, c, self.D, groups=self.n_groups,
+                         chunk=self.chunk, segment=self.segment)
+        with jax.named_scope("mamba.norm"):
+            y = apply_op("mamba_gated_norm", _mamba_gated_norm, y, z,
+                         self.norm.weight, eps=self.norm.eps)
+        with jax.named_scope("mamba.out_proj"):
+            return self.out_proj(y)
+
+
+def _split_heads(x, *, heads):
+    """[B, T, H x d] -> [B, H, T, d]."""
+    b, t, _ = x.shape
+    return x.reshape(b, t, heads, -1).transpose(0, 2, 1, 3)
+
+
+class GraniteAttention(nn.Layer):
+    """Granite-4.0-H's ``attention`` mixer: plain projections, grouped
+    queries (``num_heads`` on ``num_kv_heads`` heads of hidden / heads =
+    64), NOTHING ROTATED (``position_embedding_type`` ``nope``), no norm a
+    head, causal softmax at ``attention_multiplier`` (1/64 — not ``64 **
+    -0.5``) with query head h on key/value head ``h // (heads /
+    kv_heads)``, then ``o_proj``; no bias, no gate. The core goes through
+    the dispatching sdpa with ``scale=`` (the streaming flash kernel at long
+    sequences), K and V REPEATED to the query heads first, as
+    ``Lfm2Attention``'s. Scopes: ``gattn64.proj`` (q, k, v and the head
+    split) / ``.repeat`` / ``.core`` / ``.out`` (merge, ``o_proj``)."""
+
+    def __init__(self, hidden_size, num_heads=32, num_kv_heads=8,
+                 attention_multiplier=0.015625, weight_attr=None):
+        super().__init__()
+        if hidden_size % num_heads or num_heads % num_kv_heads:
+            raise ValueError(
+                f"{num_heads} query heads on {num_kv_heads} key/value heads "
+                f"do not divide a hidden size of {hidden_size}")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = hidden_size // num_heads
+        self.attention_multiplier = float(attention_multiplier)
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.q_proj = proj(hidden_size, hidden_size)
+        self.k_proj = proj(hidden_size, num_kv_heads * self.head_dim)
+        self.v_proj = proj(hidden_size, num_kv_heads * self.head_dim)
+        self.o_proj = proj(hidden_size, hidden_size)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops.attention import scaled_dot_product_attention as _sdpa
+
+        with jax.named_scope("gattn64.proj"):
+            q, k, v = (apply_op("split_heads", _split_heads, proj(x),
+                                heads=heads)
+                       for proj, heads in ((self.q_proj, self.num_heads),
+                                           (self.k_proj, self.num_kv_heads),
+                                           (self.v_proj, self.num_kv_heads)))
+        with jax.named_scope("gattn64.repeat"):
+            k, v = (apply_op("repeat_heads", _repeat_heads, t,
+                             repeats=self.num_heads // self.num_kv_heads,
+                             axis=1) for t in (k, v))
+        with jax.named_scope("gattn64.core"):
+            o = _sdpa(q, k, v, is_causal=True, training=self.training,
+                      scale=self.attention_multiplier)
+        with jax.named_scope("gattn64.out"):
+            return self.o_proj(apply_op("merge_heads", _merge_heads, o))
+
+
+class GraniteHybridDecoderLayer(nn.Layer):
+    """Granite-4.0-H's pre-norm block with its residual multiplier ``m``:
+    ``a = h + m Mixer(input_layernorm(h))``, ``h' = a + m
+    MLP(post_attention_layernorm(a))``. The mixer goes by the layer's type —
+    the state-space mixer (``mamba``) or grouped-query attention
+    (``attention``) —; the feed-forward part is ONE dense SwiGLU on every
+    layer (``num_local_experts`` 0: the model's ``shared_mlp``)."""
+
+    def __init__(self, cfg, layer_type, weight_attr=None):
+        super().__init__()
+        if layer_type not in ("mamba", "attention"):
+            raise ValueError(f"layer_type {layer_type!r} is neither 'mamba' "
+                             "nor 'attention'")
+        hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+        self.residual_multiplier = float(cfg["residual_multiplier"])
+        self.input_layernorm = ZeroCenteredRMSNorm(hidden, eps=eps,
+                                                   zero_centered=False)
+        self.is_attention = layer_type == "attention"
+        if self.is_attention:
+            self.self_attn = GraniteAttention(
+                hidden, weight_attr=weight_attr, **cfg["attention"])
+        else:
+            self.mamba = Mamba2Mixer(hidden, rms_norm_eps=eps,
+                                     weight_attr=weight_attr, **cfg["mamba"])
+        self.post_attention_layernorm = ZeroCenteredRMSNorm(
+            hidden, eps=eps, zero_centered=False)
+        self.shared_mlp = LlamaMLP(hidden, cfg["intermediate_size"],
+                                   weight_attr)
+
+    def forward(self, x):
+        mixer = self.self_attn if self.is_attention else self.mamba
+        m = self.residual_multiplier
+        x = x + mixer(self.input_layernorm(x)) * m
+        return x + self.shared_mlp(self.post_attention_layernorm(x)) * m
+
+
+class GraniteHybridModel(_BlockwiseModel):
+    """Granite-4.0-H (IBM, HF ``granitemoehybrid``; granite-4.0-h-micro's
+    sizes are the defaults): ``h0 = embedding_multiplier x E[ids]``; pre-norm
+    blocks whose mixer goes by ``layer_types`` — Mamba-2's state-space mixer
+    in nine layers of every ten, grouped-query attention without positions
+    in the tenth — and whose two residual adds carry ``residual_multiplier``,
+    a dense SwiGLU in every one; a final norm; ``logits = (h E^T) /
+    logits_scaling`` on a head TIED to the embedding.
+
+    ``use_recompute`` runs each block under ``fleet.utils.recompute`` in a
+    traced step. ``features`` gives the final hidden states ALREADY divided
+    by ``logits_scaling`` (a power of two at the published 8: the same
+    logits, exactly), so that ``forward`` = ``lm_head(features)`` and a
+    training loss takes ``features`` and ``lm_head.weight`` to
+    ``F.linear_cross_entropy``, which has no scale of its own."""
+
+    def __init__(self, vocab_size=100352, hidden_size=2048,
+                 num_hidden_layers=40, num_attention_heads=32,
+                 num_key_value_heads=8, intermediate_size=8192,
+                 layer_types=None, mamba_n_heads=64, mamba_d_head=64,
+                 mamba_d_state=128, mamba_n_groups=1, mamba_d_conv=4,
+                 mamba_conv_bias=True, mamba_chunk=None, mamba_segment=None,
+                 attention_multiplier=0.015625, embedding_multiplier=12.0,
+                 residual_multiplier=0.22, logits_scaling=8.0,
+                 rms_norm_eps=1e-5, initializer_range=0.02,
+                 use_recompute=False):
+        super().__init__(use_recompute)
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        cfg = dict(
+            hidden_size=hidden_size, rms_norm_eps=rms_norm_eps,
+            intermediate_size=intermediate_size,
+            residual_multiplier=residual_multiplier,
+            attention=dict(num_heads=num_attention_heads,
+                           num_kv_heads=num_key_value_heads,
+                           attention_multiplier=attention_multiplier),
+            mamba=dict(num_heads=mamba_n_heads, head_dim=mamba_d_head,
+                       d_state=mamba_d_state, n_groups=mamba_n_groups,
+                       d_conv=mamba_d_conv, conv_bias=mamba_conv_bias,
+                       chunk=mamba_chunk, segment=mamba_segment))
+        self.layer_types = list(layer_types or granite_hybrid_layer_types(
+            num_hidden_layers))
+        if len(self.layer_types) != num_hidden_layers:
+            raise ValueError(f"{len(self.layer_types)} layer types for "
+                             f"{num_hidden_layers} layers")
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.logits_scaling = float(logits_scaling)
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            GraniteHybridDecoderLayer(cfg, layer_type, weight_attr=attr())
+            for layer_type in self.layer_types])
+        self.norm = ZeroCenteredRMSNorm(hidden_size, eps=rms_norm_eps,
+                                        zero_centered=False)
+        self.lm_head = _TiedHead(self.embed_tokens)
+
+    def embed(self, input_ids):
+        """The first block's input: ``embedding_multiplier x E[ids]``."""
+        return self.embed_tokens(input_ids) * self.embedding_multiplier
+
+    def final(self, x):
+        """The last block's output -> what the tied head multiplies: the
+        final norm over ``logits_scaling``."""
+        return self.norm(x) / self.logits_scaling
+
+    def features(self, input_ids):
+        x = self.embed(input_ids)
+        for layer in self.layers:
+            x = self._block(layer, x)
+        return self.final(x)
+
+    def forward(self, input_ids):
+        return self.lm_head(self.features(input_ids))
+
+
+def granite_hybrid_layer_types(num_hidden_layers):
+    """granite-4.0-h-micro's published ``layer_types``: ``attention`` at
+    layers 5, 15, 25 and 35 (from 0) and ``mamba`` elsewhere — nine to one
+    in every ten; another depth takes the pattern's start."""
+    return ["attention" if i % 10 == 5 else "mamba"
+            for i in range(num_hidden_layers)]

@@ -275,6 +275,75 @@ def test_the_xla_route_builds_the_band_itself():
             is_causal=True, window=10)._value, _sdpa(q, k, v, window=10))
 
 
+def _scaled(q, k, v, scale, window=None):
+    """Softmax attention at ``scale`` under an explicit causal (banded)
+    mask."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    s = jnp.where(jnp.asarray(_visible(q.shape[2], window or q.shape[2])),
+                  s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+@pytest.mark.parametrize("route, seq, window", [
+    ("xla", 64, None), ("xla", 64, 10), ("stream", 256, None),
+    ("stream", 256, 40)], ids=["xla", "xla-banded", "stream", "banded"])
+def test_a_scale_reaches_every_route(kernels, route, seq, window):
+    """``scaled_dot_product_attention(scale=)``: Granite-4.0-H's scores are
+    at 1/64 where ``head_dim ** -0.5`` is 1/8. On XLA's route, on the
+    streaming kernel and on its banded calls the scores take the scale
+    given, forward and backward, and the nn.functional entry hands it on."""
+    q, k, v = _qkv(11, s=seq, d=64)
+    q = q * 4
+    scale = 1 / 64
+    kw = {} if window is None else {"window": window}
+    before = attention._ROUTE_TOTAL.value(route=route)
+    out = _sdpa(q, k, v, scale=scale, **kw)
+    assert attention._ROUTE_TOTAL.value(route=route) == before + 1
+    want = _scaled(q, k, v, scale, window)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-6)
+    # 1/8 is another function
+    assert float(jnp.abs(_sdpa(q, k, v, **kw) - want).max()) > 1e-2
+    got = _grads(lambda *a: _sdpa(*a, scale=scale, **kw), q, k, v)
+    for a, b in zip(got, _grads(
+            lambda *a: _scaled(*a, scale, window), q, k, v)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=5e-6)
+    from paddle_tpu.nn import functional as F
+
+    np.testing.assert_array_equal(
+        F.scaled_dot_product_attention(
+            paddle.Tensor(q), paddle.Tensor(k), paddle.Tensor(v),
+            is_causal=True, scale=scale, **kw)._value, out)
+
+
+@pytest.mark.parametrize("seq", [64, 256], ids=["xla", "stream"])
+def test_a_call_without_a_scale_lowers_to_what_it_did(kernels, seq):
+    """No ``scale`` is ``scale=None`` is ``head_dim ** -0.5``: the same
+    static arguments reach the op (the dispatch cache's key), and the same
+    lowered text; another scale is another program."""
+    q, k, v = _qkv(12, s=seq)
+
+    def text(**kw):
+        return jax.jit(lambda q, k, v: _sdpa(q, k, v, **kw)).lower(
+            q, k, v).as_text()
+
+    assert text() == text(scale=None) == text(scale=32 ** -0.5)
+    assert text(scale=1 / 32) != text()
+    seen = []
+    apply_op = attention.apply_op
+    attention.apply_op = lambda name, fn, *args, **kw: (
+        seen.append((name, kw["scale"], sorted(kw))),
+        apply_op(name, fn, *args, **kw))[1]
+    try:
+        _sdpa(q, k, v)
+    finally:
+        attention.apply_op = apply_op
+    name = "sdpa" if seq == 64 else "flash_attention"
+    statics = {"sdpa": ["dropout_p", "is_causal", "scale"],
+               "flash_attention": ["dropout_p", "interpret", "is_causal",
+                                   "scale"]}[name]
+    assert seen == [(name, 1.0 / np.sqrt(32), statics)]
+
+
 def test_band_shards_over_an_announced_mesh(kernels):
     """Under ``on_mesh`` on a dp2 x mp2 host mesh the banded kernel runs a
     shard a device (batch over dp, heads over mp) and gives the unsharded

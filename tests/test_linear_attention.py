@@ -756,6 +756,151 @@ def conv_kernels(xs, ws, segments, tokens=32, lanes=128):
                                 tokens=tokens, lanes=lanes, interpret=True)
 
 
+@pytest.mark.parametrize("activation", [None, "silu"])
+def test_a_bias_joins_the_taps_sum_before_the_activation(activation):
+    """Mamba-2's convolution: one number a channel on four shifted
+    multiply-adds, then SiLU — at a row's first token too, on a zero
+    history; through the functional and the layer, with its gradient."""
+    rng = np.random.default_rng(2)
+    x = rng.standard_normal((2, 11, 6)).astype(np.float32)
+    w = rng.standard_normal((4, 6)).astype(np.float32)
+    b = rng.standard_normal(6).astype(np.float32)
+    want = shifted_multiply_adds(x, w) + b
+    if activation:
+        want = want / (1.0 + np.exp(-want))
+    got = F.causal_depthwise_conv1d(
+        paddle.to_tensor(x), paddle.to_tensor(w), activation,
+        bias=paddle.to_tensor(b))
+    np.testing.assert_allclose(np.asarray(got._value), want, rtol=1e-5,
+                               atol=1e-6)
+    paddle.seed(0)
+    conv = nn.CausalDepthwiseConv1D(6, 4, activation=activation, bias=True)
+    assert conv.bias.shape == [6]
+    # taps and bias start as torch's depthwise Conv1d starts them
+    assert 0 < float(np.abs(np.asarray(conv.bias._value)).max()) <= 0.5
+    assert nn.CausalDepthwiseConv1D(6, 4).bias is None
+    conv.weight.set_value(w)
+    conv.bias.set_value(b)
+    xt = paddle.to_tensor(x, stop_gradient=False)
+    out = conv(xt)
+    np.testing.assert_allclose(np.asarray(out._value), want, rtol=1e-5,
+                               atol=1e-6)
+    out.sum().backward()
+
+    def fn(bv):
+        pre = sum(jnp.pad(x, ((0, 0), (3 - j, 0), (0, 0)))[:, :11] * w[j]
+                  for j in range(4)) + bv
+        return jnp.sum(jax.nn.silu(pre) if activation else pre)
+
+    np.testing.assert_allclose(np.asarray(conv.bias.grad._value),
+                               jax.grad(fn)(jnp.asarray(b)), rtol=1e-5,
+                               atol=1e-5)
+
+
+def _lowered(fn, *args):
+    """``fn``'s lowered text less the module's name."""
+    text = jax.jit(fn).lower(*args).as_text()
+    return text.split("\n", 1)[1]
+
+
+def test_a_convolution_without_a_bias_lowers_to_what_it_did():
+    """The functional's body as it stood before there was a bias (PR 46),
+    written out here: a call without one lowers to that text, on the
+    functional and through ``conv_streams``' XLA stage; a call with one
+    does not."""
+    def before(x, w, *, activation):
+        k = w.shape[0]
+        xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
+        padded = jnp.pad(xf, ((0, 0), (k - 1, 0), (0, 0)))
+        seq = x.shape[1]
+        out = sum(padded[:, j:j + seq] * wf[j] for j in range(k))
+        if activation == "silu":
+            out = jax.nn.silu(out)
+        return out.astype(x.dtype)
+
+    x = jnp.ones((2, 11, 6), jnp.bfloat16)
+    w, b = jnp.ones((4, 6)), jnp.ones((6,))
+    for activation in (None, "silu"):
+        want = _lowered(lambda x, w: before(x, w, activation=activation),
+                        x, w)
+        assert _lowered(lambda x, w: F._causal_depthwise_conv1d(
+            x, w, activation=activation), x, w) == want
+        assert _lowered(lambda x, w: F._causal_depthwise_conv1d(
+            x, w, None, activation=activation), x, w) == want
+    assert _lowered(lambda x, w, b: F._causal_depthwise_conv1d(
+        x, w, b, activation="silu"), x, w, b) != want
+    # the op a layer's call makes: its name and arguments as before
+    from paddle_tpu.core import dispatch
+
+    seen = []
+    apply_op = dispatch.apply_op
+    F.apply_op = lambda name, fn, *args, **kw: (
+        seen.append((name, len(args), sorted(kw))),
+        apply_op(name, fn, *args, **kw))[1]
+    try:
+        F.causal_depthwise_conv1d(paddle.to_tensor(np.ones((1, 5, 6),
+                                                           np.float32)),
+                                  paddle.to_tensor(np.asarray(w)), "silu")
+    finally:
+        F.apply_op = apply_op
+    assert seen == [("causal_depthwise_conv1d", 2, ["activation"])]
+    # the stage: no ``biases`` is ``biases=None`` is the stage before
+    xs, ws, segments = conv_form("gdn", 31, jnp.bfloat16, 4, seq=32)
+
+    def stage(**kw):
+        return _lowered(lambda xs, ws: la.conv_streams(
+            xs, ws, segments, head=CONV_D, eps=1e-6, kernel=None, **kw),
+            xs, ws)
+
+    def stage_before(xs, ws):
+        def body(xs, ws):
+            made = [before(x, w, activation="silu") for x, w in zip(xs, ws)]
+            return tuple(
+                y if scale is None else la.l2_normed(
+                    y, width // CONV_D, eps=1e-6, scale=scale)
+                for y, (_, _, width, scale) in (
+                    (made[stream][..., start:start + width], seg)
+                    for seg in segments
+                    for stream, start, width, _ in [seg]))
+        return jax.checkpoint(body)(xs, ws)
+
+    assert stage() == stage(biases=None) == _lowered(stage_before, xs, ws)
+
+
+def test_a_biased_stage_is_the_xla_stage_whatever_the_kernels_admit(
+        interpreter):
+    """``conv_streams(biases=)``: taps, bias, SiLU and the segments' cuts
+    against explicit shifted sums; ``conv_path`` answers ``xla`` for a shape
+    whose unbiased stage takes the kernels, and the counter says so."""
+    xs, ws, _ = conv_form("gdn", 33, jnp.float32, 4, seq=256)
+    # Mamba-2's segments: x | B | C of the one stream, none of them normed
+    segments = ((0, 0, 6 * CONV_D, None), (0, 6 * CONV_D, CONV_D, None),
+                (0, 7 * CONV_D, CONV_D, None))
+    bias = jax.random.normal(jax.random.PRNGKey(34), (8 * CONV_D,))
+    shape = (256, segments, CONV_D, 4, xs[0].dtype)
+    assert la.conv_path(*shape) == "kernel"
+    assert la.conv_path(*shape, bias=True) == "xla"
+    before = {p: la._CONV_TOTAL.value(path=p) for p in ("kernel", "xla")}
+    assert la.conv_kernel(xs[0], ws[0], segments, CONV_D, bias=True) is None
+    got = jax.jit(lambda xs, ws, b: la.conv_streams(
+        xs, ws, segments, head=CONV_D, eps=1e-6, biases=(b,)))(xs, ws, bias)
+    assert la._CONV_TOTAL.value(path="xla") == before["xla"] + 2
+    assert la._CONV_TOTAL.value(path="kernel") == before["kernel"]
+    pre = shifted_multiply_adds(xs[0], np.asarray(ws[0])) + np.asarray(bias)
+    want = pre / (1.0 + np.exp(-pre))
+    for out, (_, start, width, _) in zip(got, segments):
+        np.testing.assert_allclose(out, want[..., start:start + width],
+                                   rtol=1e-5, atol=1e-5)
+    # its gradient reaches the bias (the stage is a checkpoint of its own)
+    grad = jax.grad(lambda b: sum(jnp.sum(o) for o in la.conv_streams(
+        xs, ws, segments, head=CONV_D, eps=1e-6, kernel=None,
+        biases=(b,))))(bias)
+    sig = 1.0 / (1.0 + np.exp(-pre))
+    np.testing.assert_allclose(
+        grad, (sig * (1 + pre * (1 - sig))).sum(axis=(0, 1)), rtol=1e-4,
+        atol=1e-4)
+
+
 def one_bf16_ulp(got, want):
     """Every element of ``got`` (bf16) within one bf16 step of the float32
     ``want``."""

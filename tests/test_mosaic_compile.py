@@ -72,6 +72,11 @@ STREAM_CASES = {
     # of 8 MiB — 4 rows of 8,192 keys, and the check's 2 rows in float32
     "lfm2-cell-bf16": (4, 32, 8192, 64, "bfloat16"),
     "lfm2-check-f32": (2, 32, 8192, 64, "float32"),
+    # Granite-4.0-H's attention layer at its cell: LFM2's geometry at one
+    # row, the scores at a scale of its own (a seventh element: 1/64, not
+    # 64 ** -0.5), and its check's float32
+    "granite-cell-bf16": (1, 32, 8192, 64, "bfloat16", 0.0, 1 / 64),
+    "granite-check-f32": (1, 32, 8192, 64, "float32", 0.0, 1 / 64),
 }
 STREAM_CALLS = ("flash_stream_fwd", "flash_stream_bwd_dkv_dq")
 #: a row of dq past the one-pass backward's VMEM budget (65,536 x 128 bf16:
@@ -282,6 +287,7 @@ def _child():
         text = jax.jit(jax.grad(
             lambda q, k, v: jnp.sum(fa.mha(
                 q, k, v, causal=True, dropout_p=p[0] if p else 0.0,
+                scale=p[1] if len(p) > 1 else None,
                 seed=jnp.zeros((), jnp.int32)).astype(jnp.float32)),
             argnums=(0, 1, 2))).lower(qk, qk, v).compile().as_text()
         out["stream-" + name] = {
@@ -820,7 +826,8 @@ def test_stream_kernel_compiles(compiled, case):
     s4096 d128 bf16, in float32, at d256 and d64, at the JoyAI cell's
     b1 h32 s8192 with 192-wide keys and 128-wide values (bf16 and the
     check's float32) and at the LFM2 cell's b4 h32 s8192 d64 (and its
-    check's float32): two Mosaic calls under their names within the VMEM
+    check's float32; Granite-4.0-H's one row of it at ``scale=1/64``): two
+    Mosaic calls under their names within the VMEM
     the backward asks for at the block sizes the kernel picks, no
     [seq, seq] scores in HBM. A row of dq past the slab's budget compiles
     the two-call backward: three calls."""

@@ -1,0 +1,94 @@
+#!/usr/bin/env python3
+"""The selective state-space scan alone on the chip —
+``ops.linear_attention.ssd_chunked``, Mamba-2's scalar-decay scan with no
+delta correction, in XLA operations — at the granite-4.0-h-micro cell's
+shape: 1 row x 8,192 tokens, 64 heads of 64 on a state of 128, B and C one
+group's, bf16 operands; by chunk and segment.
+
+A line a (chunk, segment): ms forward and forward + backward (plain
+autodiff, every segment under ``jax.checkpoint``), and the share of the
+scan's roofline (``benchmark/layer_metrics/ssd_core_roofline.py``'s FLOPs
+and least bytes for one forward and one backward).
+
+    chiprun -- python3 tools/ssd_bench.py [chunk x segment,...]
+
+(default: 64x512, 128x1024, 256x1024, 256x2048, 256x4096, 512x2048,
+512x4096).
+It is what the configuration's ``mamba_chunk`` / ``mamba_segment`` were read
+from (PERF.md section 6, PR 47). A microbenchmark's numbers are findings
+for PERF.md, never a metric of the benchmark. Exits 2 without a TPU.
+"""
+import importlib.util
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+BATCH, SEQ, HEADS, D_HEAD, GROUPS, D_STATE = 1, 8192, 64, 64, 1, 128
+DEFAULT = "64x512,128x1024,256x1024,256x2048,256x4096,512x2048,512x4096"
+
+
+def _load(*path):
+    spec = importlib.util.spec_from_file_location(
+        path[-1][:-3], os.path.join(ROOT, *path))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def main():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import linear_attention
+
+    if jax.devices()[0].platform != "tpu":
+        print("ssd_bench.py times the chip: no TPU", file=sys.stderr)
+        return 2
+    clock = _load("benchmark", "tools", "kda_candidates.py")
+    roofline = _load("benchmark", "layer_metrics", "ssd_core_roofline.py")
+    peaks = _load("benchmark", "harness", "peaks.py").PEAKS[
+        jax.devices()[0].device_kind]
+    keys = jax.random.split(jax.random.PRNGKey(47), 6)
+    bf16 = jnp.bfloat16
+    x = jax.random.normal(keys[0], (BATCH, SEQ, HEADS, D_HEAD), bf16)
+    dt = jax.nn.softplus(jax.random.normal(keys[1], (BATCH, SEQ, HEADS))
+                         - 4.0)
+    a = -jnp.exp(jax.random.uniform(keys[2], (HEADS,), minval=0.0,
+                                    maxval=2.77))
+    b, c = (jax.random.normal(k, (BATCH, SEQ, GROUPS, D_STATE), bf16)
+            for k in keys[3:5])
+    d = jnp.ones((HEADS,))
+    dy = jax.random.normal(keys[5], x.shape, bf16)
+    shape = (BATCH * SEQ, HEADS, D_HEAD, D_STATE, GROUPS)
+
+    for pair in (sys.argv[1] if len(sys.argv) > 1 else DEFAULT).split(","):
+        chunk, segment = (int(v) for v in pair.split("x"))
+
+        def scan(x, dt, a, b, c, d):
+            return linear_attention.ssd_chunked(
+                x, dt, a, b, c, d, chunk=chunk, segment=segment)[0]
+
+        def both(x, dt, a, b, c, d, dy):
+            out, vjp = jax.vjp(scan, x, dt, a, b, c, d)
+            return out, vjp(dy)
+
+        try:
+            f = clock.timed(jax.jit(scan), x, dt, a, b, c, d)
+            fb = clock.timed(jax.jit(both), x, dt, a, b, c, d, dy)
+        except Exception as e:          # a candidate that does not fit
+            clock.line(chunk=chunk, segment=segment, error=str(e)[:300])
+            continue
+        least_ms = 1e3 * max(
+            roofline.ssd_core_flops(*shape, chunk, 1, 1)
+            / peaks["bf16_flops_per_s"],
+            roofline.ssd_core_bytes(*shape, 1, 1) / peaks["hbm_bytes_per_s"])
+        clock.line(chunk=chunk, segment=segment, fwd_ms=round(f, 3),
+                   fwd_bwd_ms=round(fb, 3), bwd_ms=round(fb - f, 3),
+                   roofline_pct=round(100 * least_ms / fb, 2))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
