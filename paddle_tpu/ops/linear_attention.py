@@ -108,9 +108,10 @@ layer that still pays the relayouts. ``chunked`` and ``recurrent`` reshape
 streams to heads inside (free where they run).
 
 **The convolution stage** before the scan — each stream's causal depthwise
-convolution and SiLU, q's and k's L2 norm a head — is ``conv_streams``, by
-the same rule: ``conv_path`` gives ``kernel`` (one Mosaic call a pass on
-the streams, its float32 in VMEM; ``ops/pallas/linear_attention.py``) or
+convolution, its bias where it has one (Mamba-2's) and SiLU, q's and k's L2
+norm a head — is ``conv_streams``, by the same rule: ``conv_path`` gives
+``kernel`` (one Mosaic call a pass on the streams, its float32 in VMEM;
+``ops/pallas/linear_attention.py``) or
 ``xla`` (this file's ``_conv_xla``: float32 arrays under a
 ``jax.checkpoint``, what every other program runs and what the kernels are
 held to), counted in ``paddle_tpu_conv_streams_total{path}``.
@@ -197,9 +198,9 @@ _ENTRY_TOTAL = obs_metrics.counter(
 
 _CONV_TOTAL = obs_metrics.counter(
     "paddle_tpu_conv_streams_total",
-    "linear attention's convolution stages (taps, SiLU, q's and k's L2 "
-    "norm) by the path taken: kernel (one Mosaic call a pass) | xla; one "
-    "count per traced layer call",
+    "linear attention's convolution stages (taps, a bias, SiLU, q's and "
+    "k's L2 norm) by the path taken: kernel (one Mosaic call a pass) | "
+    "xla; one count per traced layer call",
     labelnames=("path",))
 
 _SHORTCONV_TOTAL = obs_metrics.counter(
@@ -685,12 +686,13 @@ def ssd_recurrent(x, dt, a, b, c, d, initial_state=None):
     return y.astype(x.dtype), state
 
 
-def _ssd_segment(state, xs, *, a):
+def _ssd_segment(state, xs, *, a, d):
     """One segment of whole chunks. xs: x [B, n, C, G, R, P] (R heads a
     group) and b, c [B, n, C, G, N] in the operand dtype, dt [B, n, C, G, R]
-    float32; a [G, R] float32; state [B, G, R, N, P] float32. Returns (the
-    state after the segment, y [B, n, C, G, R, P] float32 without the D
-    skip)."""
+    float32; a, d [G, R] float32; state [B, G, R, N, P] float32. Returns
+    (the state after the segment, y [B, n, C, G, R, P] in the operand dtype:
+    the float32 sum with the D skip, rounded once, HERE — what leaves a
+    segment, and is laid out again as a stream, is not float32)."""
     x, dt, b, c = xs
     f32, mm = jnp.float32, x.dtype
     chunk, chunks = x.shape[2], x.shape[1]
@@ -730,7 +732,7 @@ def _ssd_segment(state, xs, *, a):
     y = y + jnp.exp(cum)[..., None] * jnp.einsum(
         "bnrgk,bghnkp->bnrghp", c, states[:, :, :, :-1].astype(mm),
         preferred_element_type=f32)
-    return states[:, :, :, -1], y
+    return states[:, :, :, -1], (y + d[:, :, None] * xf).astype(mm)
 
 
 def ssd_chunked(x, dt, a, b, c, d, initial_state=None, *,
@@ -739,8 +741,9 @@ def ssd_chunked(x, dt, a, b, c, d, initial_state=None, *,
     in chunks of ``chunk`` tokens and segments of ``segment`` (whole
     chunks). Any length: the row is padded to whole segments with tokens of
     step 0, which write nothing and decay nothing. The large products take
-    x's dtype as their operands' (module docstring); the state, and y before
-    its cast to x's dtype, are float32."""
+    x's dtype as their operands' (module docstring); the state, and y until
+    its one rounding to x's dtype — the D skip added, at a segment's end —
+    are float32."""
     f32 = jnp.float32
     bsz, t, h, p = x.shape
     g, n = b.shape[-2:]
@@ -764,16 +767,16 @@ def ssd_chunked(x, dt, a, b, c, d, initial_state=None, *,
     state = (jnp.zeros((bsz, h, n, p), f32) if initial_state is None
              else initial_state.astype(f32)).reshape(bsz, g, h // g, n, p)
     body = functools.partial(_ssd_segment,
-                             a=a.astype(f32).reshape(g, h // g))
+                             a=a.astype(f32).reshape(g, h // g),
+                             d=d.astype(f32).reshape(g, h // g))
     if segments == 1:
         state, y = body(state, tuple(v[0] for v in xs))
         y = y[None]
     else:
         state, y = jax.lax.scan(jax.checkpoint(body), state, xs)
     # [segments, B, chunks, C, G, R, P] -> [B, T, H, P]
-    y = (jnp.moveaxis(y, 0, 1).reshape(bsz, -1, h, p)[:, :t]
-         + d.astype(f32)[:, None] * x.astype(f32))
-    return y.astype(x.dtype), state.reshape(bsz, h, n, p)
+    return (jnp.moveaxis(y, 0, 1).reshape(bsz, -1, h, p)[:, :t],
+            state.reshape(bsz, h, n, p))
 
 
 def ssd_path(seq):
@@ -821,22 +824,22 @@ def _ssd_chunked_output(x, dt, a, b, c, d, *, groups, chunk, segment):
 
 
 # ------------------------------------------------- the convolution stage
-def conv_path(seq, segments, head, taps, dtype, bias=False):
+def conv_path(seq, segments, head, taps, dtype):
     """``kernel`` | ``xla`` for the convolution stage of a row of ``seq``
     tokens whose ``segments`` (``(stream, start, width, scale)``, as
     ``conv_streams`` takes them) have heads ``head`` wide and ``taps`` taps
     in ``dtype``, from what can be observed: the Mosaic kernels where the
     program may hold them (``placement.kernel``: they run through
-    ``on_mesh``), the heads and every segment's channels fill whole lane
-    groups, the history fits the rows the kernels carry, the streams are
-    bf16 or float32 and the row is at least one token block; where
-    ``on_mesh`` cuts the heads over an 'mp' axis, only if that cuts every
-    stream between whole heads of ONE segment. The XLA stage everything
-    else — and every stage with a ``bias``: the kernels' bodies take none
-    (Mamba-2's stage; ROADMAP Speed)."""
+    ``on_mesh``), every segment's channels fill whole lane groups — a
+    normed segment's heads too —, the history fits the rows the kernels
+    carry, the streams are bf16 or float32 and the row is at least one
+    token block; where ``on_mesh`` cuts the heads over an 'mp' axis, only
+    if that cuts every stream between whole heads of ONE segment. The XLA
+    stage everything else. A bias changes nothing of it: the kernels take
+    one beside the taps."""
     from .pallas import linear_attention as kernels
 
-    if bias or not (seq >= kernels.CONV_TOKENS
+    if not (seq >= kernels.CONV_TOKENS
             and kernels.conv_supported(segments, head, taps, dtype)
             and placement.kernel(sharded=True)):
         return "xla"
@@ -848,13 +851,13 @@ def conv_path(seq, segments, head, taps, dtype, bias=False):
     return "kernel"
 
 
-def conv_kernel(x, w, segments, head, bias=False):
+def conv_kernel(x, w, segments, head):
     """One call's decision, counted, as ``conv_streams`` takes it:
     ``placement.kernel``'s answer where ``conv_path`` says ``kernel`` for
-    streams like ``x`` and taps like ``w`` (never with a ``bias``), else
+    streams like ``x`` and taps like ``w`` (with a bias or without), else
     None (the XLA stage). An op's caller asks OUTSIDE the op; the answer
     rides its static arguments."""
-    path = conv_path(x.shape[1], segments, head, w.shape[0], x.dtype, bias)
+    path = conv_path(x.shape[1], segments, head, w.shape[0], x.dtype)
     _CONV_TOTAL.inc(path=path)
     return placement.kernel(sharded=True) if path == "kernel" else None
 
@@ -870,19 +873,17 @@ def conv_streams(xs, ws, segments, *, head, eps, kernel="ask", biases=None):
     them. The segments cover every stream in order. Results take their
     stream's dtype. ``biases``: None, or one [C_i] array a stream, added to
     the taps' sum before SiLU (the state-space layer's stage: x | B | C are
-    three ``scale=None`` segments of one biased stream) — the XLA stage
-    whatever ``kernel`` says. ``kernel``: ``conv_kernel``'s answer, from a
-    caller under ``apply_op``; ``"ask"`` (under the caller's own jit): taken
-    here."""
+    three ``scale=None`` segments of one biased stream), on either path.
+    ``kernel``: ``conv_kernel``'s answer, from a caller under ``apply_op``;
+    ``"ask"`` (under the caller's own jit): taken here."""
     segments = tuple((int(s), int(a), int(n), None if c is None else float(c))
                      for s, a, n, c in segments)
     if kernel == "ask":
-        kernel = conv_kernel(xs[0], ws[0], segments, head,
-                             biases is not None)
-    if kernel is None or biases is not None:
+        kernel = conv_kernel(xs[0], ws[0], segments, head)
+    if kernel is None:
         return _conv_xla(tuple(xs), tuple(ws), segments, head, eps, biases)
     return _conv_kernel(tuple(xs), tuple(ws), segments, head, eps,
-                        kernel == "interpret")
+                        kernel == "interpret", biases)
 
 
 def _conv_xla(xs, ws, segments, head, eps, biases=None):
@@ -905,15 +906,17 @@ def _conv_xla(xs, ws, segments, head, eps, biases=None):
         xs, ws, *(() if biases is None else (tuple(biases),)))
 
 
-def _conv_kernel(xs, ws, segments, head, eps, interpret):
+def _conv_kernel(xs, ws, segments, head, eps, interpret, biases=None):
     """The Mosaic kernels, under a step's announced mesh inside
     ``placement.on_mesh``'s ``shard_map``: rows over the data axes and,
     where ``conv_path`` lets an ``mp`` axis through, every stream's heads
     over it. The taps go a copy a row ([B, K, C]), so that they shard as
-    the streams do and their gradient is summed over the rows outside."""
+    the streams do and their gradient is summed over the rows outside; a
+    stream's bias goes with them, as the copy's row K."""
     from .pallas import linear_attention as kernels
 
     n, batch = len(xs), xs[0].shape[0]
+    bias = biases is not None
 
     def kernel(*arrays):
         xs, ws = arrays[:n], arrays[n:]
@@ -921,8 +924,10 @@ def _conv_kernel(xs, ws, segments, head, eps, interpret):
         local = tuple((s, start, min(width, xs[s].shape[-1]), scale)
                       for s, start, width, scale in segments)
         return kernels.conv_streams(xs, ws, local, head=head, eps=eps,
-                                    interpret=interpret)
+                                    bias=bias, interpret=interpret)
 
+    if bias:
+        ws = tuple(jnp.concatenate([w, b[None]]) for w, b in zip(ws, biases))
     rows = tuple(jnp.broadcast_to(w[None], (batch,) + w.shape) for w in ws)
     return tuple(placement.on_mesh(kernel, (*xs, *rows), head_axis=2))
 

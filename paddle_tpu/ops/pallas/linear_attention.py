@@ -798,10 +798,11 @@ def kda(q, k, v, g, beta, *, tokens=None, together=None, interpret=False):
 # What a linear-attention layer does between its projections and the scan,
 # as one forward and one backward kernel on the [B, T, channels] streams:
 # every channel its K taps over the tokens t - K + 1 .. t (zero history
-# before a row's start), SiLU, and for a q or k segment the L2 norm over
-# each head's d lanes times a scale — ``ops.linear_attention._conv_xla`` is
-# the same function in XLA operations. Float32 throughout, and only in
-# VMEM: a program reads a block in the stream's dtype and writes one.
+# before a row's start), where the stage has one its bias, SiLU, and for a
+# q or k segment the L2 norm over each head's d lanes times a scale —
+# ``ops.linear_attention._conv_xla`` is the same function in XLA operations.
+# Float32 throughout, and only in VMEM: a program reads a block in the
+# stream's dtype and writes one.
 #
 # A SEGMENT is ``(stream, start, width, scale)``: ``width`` channels of
 # input ``stream`` from ``start`` on, L2-normed a head and scaled where
@@ -856,10 +857,13 @@ def conv_steps(segments, head, lanes=None):
 
 
 def conv_supported(segments, head, taps, dtype):
-    """Whether the kernels take these segments: heads that fill whole lane
-    groups, taps whose history fits the carried rows, bf16 or float32
-    streams, and a channel cut (``conv_steps``)."""
-    return (head % 128 == 0 and 1 <= taps <= CONV_HALO + 1
+    """Whether the kernels take these segments: where one is normed, heads
+    that fill whole lane groups (an un-normed segment is cut by lane groups
+    whatever the head), taps whose history fits the carried rows, bf16 or
+    float32 streams, and a channel cut (``conv_steps``)."""
+    return (all(scale is None or head % 128 == 0
+                for _, _, _, scale in segments)
+            and 1 <= taps <= CONV_HALO + 1
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32))
             and conv_steps(segments, head) > 0)
@@ -889,7 +893,7 @@ def _head_sum(x):
     return jnp.sum(x, axis=1, keepdims=True)
 
 
-def _conv_fwd_kernel(*refs, segments, head, eps):
+def _conv_fwd_kernel(*refs, segments, head, eps, bias):
     n = len(segments)
     xs, ws, outs, halos = (refs[i * n:(i + 1) * n] for i in range(4))
 
@@ -901,22 +905,25 @@ def _conv_fwd_kernel(*refs, segments, head, eps):
     for x_ref, w_ref, o_ref, halo, (_, _, _, scale) in zip(
             xs, ws, outs, halos, segments):
         tokens, width = x_ref.shape[1:]
-        taps = w_ref.shape[1]
+        taps = w_ref.shape[1] - bias
         step = 128 if scale is None else head
         for at in range(0, width, step):
             lanes = slice(at, at + step)
             xf = x_ref[0, :, lanes].astype(_F32)
             ext = jnp.concatenate([halo[:, lanes], xf], axis=0)
             halo[:, lanes] = xf[tokens - CONV_HALO:]
-            a = _pre_activation(_shifted(ext, taps),
-                                w_ref[0, :, lanes].astype(_F32))
+            shifted = _shifted(ext, taps)
+            w = w_ref[0, :, lanes].astype(_F32)
+            a = _pre_activation(shifted, w)
+            if bias:
+                a = a + w[taps:]
             y = a * jax.nn.sigmoid(a)
             if scale is not None:
                 y = y * (jax.lax.rsqrt(_head_sum(y * y) + eps) * scale)
             o_ref[0, :, lanes] = y.astype(o_ref.dtype)
 
 
-def _conv_bwd_kernel(*refs, segments, head, eps):
+def _conv_bwd_kernel(*refs, segments, head, eps, bias):
     n = len(segments)
     xs, befores, ws, dys, dxs, dws, carries = (
         refs[i * n:(i + 1) * n] for i in range(7))
@@ -934,7 +941,7 @@ def _conv_bwd_kernel(*refs, segments, head, eps):
          (_, _, _, scale)) in zip(xs, befores, ws, dys, dxs, dws, carries,
                                   segments):
         tokens, width = x_ref.shape[1:]
-        taps = w_ref.shape[1]
+        taps = w_ref.shape[1] - bias
         step = 128 if scale is None else head
         for at in range(0, width, step):
             lanes = slice(at, at + step)
@@ -946,6 +953,8 @@ def _conv_bwd_kernel(*refs, segments, head, eps):
                  x_ref[0, :, lanes].astype(_F32)], axis=0)
             shifted = _shifted(ext, taps)
             a = _pre_activation(shifted, w)
+            if bias:
+                a = a + w[taps:]
             sig = jax.nn.sigmoid(a)
             dy = dy_ref[0, :, lanes].astype(_F32)
             if scale is not None:
@@ -955,9 +964,12 @@ def _conv_bwd_kernel(*refs, segments, head, eps):
                 r = jax.lax.rsqrt(_head_sum(y * y) + eps)
                 dy = (dy - y * (r * r * _head_sum(dy * y))) * (r * scale)
             da = dy * (sig * (1.0 + a * (1.0 - sig)))
+            # the taps' gradient and, in its row, the bias's
             dw_ref[0, :, lanes] += jnp.concatenate(
                 [jnp.sum(da * shifted[taps - 1 - j], axis=0, keepdims=True)
-                 for j in range(taps)], axis=0)
+                 for j in range(taps)]
+                + ([jnp.sum(da, axis=0, keepdims=True)] if bias else []),
+                axis=0)
             later = _shifted(jnp.concatenate([da, carry[:, lanes]], axis=0),
                              taps, up=True)
             carry[:, lanes] = da[:CONV_HALO]
@@ -965,10 +977,11 @@ def _conv_bwd_kernel(*refs, segments, head, eps):
                 dx_ref.dtype)
 
 
-def _conv_specs(segments, tokens, steps, taps, token_block):
-    """A segment's BlockSpecs — its block of its stream, of the stream's
-    taps, of an array of its own (q, k or v; a cotangent; dx) — and its
-    block's width; ``token_block``: the grid's token step -> the row's."""
+def _conv_specs(segments, tokens, steps, rows, token_block):
+    """A segment's BlockSpecs — its block of its stream, of the ``rows``
+    rows of the stream's taps (the bias's among them), of an array of its
+    own (q, k or v; a cotangent; dx) — and its block's width;
+    ``token_block``: the grid's token step -> the row's."""
     streams, weights, own, widths = [], [], [], []
     for stream, start, width, _ in segments:
         wide = width // steps
@@ -977,14 +990,15 @@ def _conv_specs(segments, tokens, steps, taps, token_block):
             (1, tokens, wide),
             lambda b, c, n, first=first: (b, token_block(n), first + c)))
         weights.append(pl.BlockSpec(
-            (1, taps, wide), lambda b, c, n, first=first: (b, 0, first + c)))
+            (1, rows, wide), lambda b, c, n, first=first: (b, 0, first + c)))
         own.append(pl.BlockSpec(
             (1, tokens, wide), lambda b, c, n: (b, token_block(n), c)))
         widths.append(wide)
     return streams, weights, own, widths
 
 
-_CONV_STATIC = ("segments", "head", "eps", "tokens", "steps", "interpret")
+_CONV_STATIC = ("segments", "head", "eps", "tokens", "steps", "interpret",
+                "bias")
 
 
 # jitted, as the backward is: a layer's call sites (forward, recomputed
@@ -993,14 +1007,14 @@ _CONV_STATIC = ("segments", "head", "eps", "tokens", "steps", "interpret")
 # would run its Python again — 0.2 s a site, 48 sites a start of the
 # Kimi-Linear cell
 @functools.partial(jax.jit, static_argnames=_CONV_STATIC)
-def _conv_forward(xs, ws, *, segments, head, eps, tokens, steps, interpret):
+def _conv_forward(xs, ws, *, segments, head, eps, tokens, steps, interpret,
+                  bias):
     b, t, _ = xs[0].shape
-    taps = ws[0].shape[1]
     streams, weights, own, widths = _conv_specs(
-        segments, tokens, steps, taps, lambda n: n)
+        segments, tokens, steps, ws[0].shape[1], lambda n: n)
     return pl.pallas_call(
         functools.partial(_conv_fwd_kernel, segments=segments, head=head,
-                          eps=eps),
+                          eps=eps, bias=bias),
         grid=(b, steps, t // tokens),
         in_specs=streams + weights, out_specs=own,
         out_shape=[jax.ShapeDtypeStruct((b, t, width), xs[s].dtype)
@@ -1014,16 +1028,16 @@ def _conv_forward(xs, ws, *, segments, head, eps, tokens, steps, interpret):
 
 @functools.partial(jax.jit, static_argnames=_CONV_STATIC)
 def _conv_backward(xs, ws, dys, *, segments, head, eps, tokens, steps,
-                   interpret):
+                   interpret, bias):
     b, t, _ = xs[0].shape
-    taps = ws[0].shape[1]
+    rows = ws[0].shape[1]               # the taps and, with one, the bias
     blocks = t // tokens
 
     def rev(n):
         return blocks - 1 - n
 
     streams, weights, own, widths = _conv_specs(
-        segments, tokens, steps, taps, rev)
+        segments, tokens, steps, rows, rev)
     befores = [
         pl.BlockSpec(
             (1, _BEFORE, wide),
@@ -1031,19 +1045,19 @@ def _conv_backward(xs, ws, dys, *, segments, head, eps, tokens, steps,
                 b_, jnp.maximum(rev(n) * (tokens // _BEFORE) - 1, 0),
                 first + c))
         for (_, start, _, _), wide in zip(segments, widths)]
-    taps_own = [pl.BlockSpec((1, taps, wide), lambda b_, c, n: (b_, 0, c))
+    taps_own = [pl.BlockSpec((1, rows, wide), lambda b_, c, n: (b_, 0, c))
                 for wide in widths]
     like = jax.ShapeDtypeStruct
     of = [s for s, *_ in segments]
     grads = pl.pallas_call(
         functools.partial(_conv_bwd_kernel, segments=segments, head=head,
-                          eps=eps),
+                          eps=eps, bias=bias),
         grid=(b, steps, blocks),
         in_specs=streams + befores + weights + own,
         out_specs=own + taps_own,
         out_shape=[like((b, t, width), xs[s].dtype)
                    for s, _, width, _ in segments]
-        + [like((b, taps, width), _F32) for _, _, width, _ in segments],
+        + [like((b, rows, width), _F32) for _, _, width, _ in segments],
         scratch_shapes=[pltpu.VMEM((CONV_HALO, wide), _F32)
                         for wide in widths],
         interpret=interpret, name="conv_streams_bwd",
@@ -1079,18 +1093,20 @@ def _conv_bwd(static, res, dys):
 _conv.defvjp(_conv_fwd, _conv_bwd)
 
 
-def conv_streams(xs, ws, segments, *, head, eps, tokens=None, lanes=None,
-                 interpret=False):
+def conv_streams(xs, ws, segments, *, head, eps, bias=False, tokens=None,
+                 lanes=None, interpret=False):
     """The convolution stage: streams ``xs`` [B, T, C_i], their taps ``ws``
     [B, K, C_i] (a copy a batch row: the taps' gradient leaves a row at a
     time) and ``segments`` ``(stream, start, width, scale)`` that cover
     every stream in order -> one [B, T, width] array a segment, in its
     stream's dtype: the causal depthwise convolution and SiLU, and where
     ``scale`` is a number the L2 norm over each ``head`` lanes times it.
-    Differentiable in the streams and the taps; what a backward pass keeps
-    is those. ``tokens``: what a program takes of a row (a multiple of 16;
-    ``CONV_TOKENS``; a shorter row is padded to it), ``lanes``: of the
-    narrowest segment's channels (``CONV_LANES``)."""
+    With ``bias`` (static) every ``ws`` is [B, K + 1, C_i], row K the
+    stream's bias, added to the taps' sum before SiLU. Differentiable in
+    the streams and the taps (the bias's gradient is row K of theirs); what
+    a backward pass keeps is those. ``tokens``: what a program takes of a
+    row (a multiple of 16; ``CONV_TOKENS``; a shorter row is padded to it),
+    ``lanes``: of the narrowest segment's channels (``CONV_LANES``)."""
     segments = tuple(tuple(s) for s in segments)
     steps = conv_steps(segments, head, lanes)
     if not steps:
@@ -1103,7 +1119,8 @@ def conv_streams(xs, ws, segments, *, head, eps, tokens=None, lanes=None,
         xs = tuple(jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in xs)
     static = (("segments", segments), ("head", int(head)),
               ("eps", float(eps)), ("tokens", int(tokens)),
-              ("steps", steps), ("interpret", bool(interpret)))
+              ("steps", steps), ("interpret", bool(interpret)),
+              ("bias", bool(bias)))
     outs = _conv(tuple(xs), tuple(ws), static)
     return tuple(o[:, :t] for o in outs) if pad else outs
 

@@ -2064,7 +2064,7 @@ def _mamba_streams(xbc, w, bias, *, inner, state, head, kernel="ask"):
     """The x | B | C stream through ONE causal depthwise convolution, its
     bias and SiLU -> x [B, T, inner], B and C [B, T, groups x d_state]:
     ``conv_streams`` on three segments that take no norm. ``kernel``:
-    ``conv_kernel``'s answer, taken outside the op (with a bias: None)."""
+    ``conv_kernel``'s answer, taken outside the op."""
     from ..ops.linear_attention import conv_streams
 
     return conv_streams((xbc,), (w,), _mamba_segments(inner, state),
@@ -2107,9 +2107,12 @@ class Mamba2Mixer(nn.Layer):
     xBC | dt] = in_proj(x)`` (hidden -> inner + (inner + 2 groups x d_state)
     + heads, split in that order, no bias); ``xBC`` through ONE causal
     depthwise convolution of ``d_conv`` taps WITH A BIAS a channel, then
-    SiLU, and split ``[x | B | C]`` — x is ``num_heads`` heads of
-    ``head_dim``, B and C are a GROUP's, shared by ``num_heads / n_groups``
-    heads; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` a head;
+    SiLU, and split ``[x | B | C]`` (``ops.linear_attention.conv_streams``
+    on three un-normed segments: the Mosaic kernels or the XLA stage as
+    ``conv_path`` says, ``paddle_tpu_conv_streams_total{path}``) — x is
+    ``num_heads`` heads of ``head_dim``, B and C are a GROUP's, shared by
+    ``num_heads / n_groups`` heads; ``dt = softplus(dt + dt_bias)`` and
+    ``A = -exp(A_log)`` a head;
     the state-space scan ``S_t = exp(dt_t A) S_{t-1} + B_t (dt_t x_t)^T``,
     ``y_t = S_t^T C_t + D x_t`` (``ops.linear_attention.ssd_scan``: the
     scalar-decay scan with no delta correction, counted in
@@ -2191,8 +2194,7 @@ class Mamba2Mixer(nn.Layer):
                 self.conv1d.bias, inner=self.inner, state=state,
                 head=self.head_dim, kernel=conv_kernel(
                     xbc, self.conv1d.weight,
-                    _mamba_segments(self.inner, state), self.head_dim,
-                    bias=self.conv1d.bias is not None))
+                    _mamba_segments(self.inner, state), self.head_dim))
         with jax.named_scope("mamba.dt"):
             dt, a = apply_op("mamba_step", _mamba_step, dt, self.dt_bias,
                              self.A_log)

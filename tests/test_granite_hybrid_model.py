@@ -569,13 +569,14 @@ def test_a_traced_step_counts_once_a_call_site_and_carries_the_scopes(
         residual_counts):
     """With the kernels on (here in the Pallas interpreter) a traced step of
     the cell's ten recomputed blocks counts each call site ONCE — nine scans on
-    their ``chunked`` path, nine biased convolution stages on ``xla``, one
-    attention core on the ``stream`` route —, the one core offers its output
-    and log-sum-exp and its block keeps them, and the program carries the
-    scopes that tell the state-space mixer's parts and the attention layer's
-    apart."""
+    their ``chunked`` path, nine biased convolution stages on ``kernel`` (a
+    state of the published 128, so that x | B | C fill whole lane groups) and
+    none on ``xla``, one attention core on the ``stream`` route —, the one
+    core offers its output and log-sum-exp and its block keeps them, and the
+    program carries the scopes that tell the state-space mixer's parts and
+    the attention layer's apart, the stage's two calls under ``mamba.conv``."""
     net = build(use_recompute=True, num_hidden_layers=10,
-                layer_types=CELL_TYPES)
+                layer_types=CELL_TYPES, mamba_d_state=128)
     params = net.functional_state()[0]
     ids = jnp.asarray(np.random.default_rng(7).integers(
         0, SIZES["vocab_size"], (1, 256)), jnp.int32)
@@ -599,8 +600,8 @@ def test_a_traced_step_counts_once_a_call_site_and_carries_the_scopes(
     assert residual_counts(before) == dict.fromkeys(before, 1)
     assert attention._ROUTE_TOTAL.value(route="stream") - stream == 1
     assert linear_attention._SSD_TOTAL.value(path="chunked") - scans == 9
-    assert linear_attention._CONV_TOTAL.value(path="xla") - convs == 9
-    assert linear_attention._CONV_TOTAL.value(path="kernel") == kernels
+    assert linear_attention._CONV_TOTAL.value(path="kernel") - kernels == 9
+    assert linear_attention._CONV_TOTAL.value(path="xla") == convs
     # the delta rule's counter counts nothing here
     assert delta == {p: linear_attention._CORE_TOTAL.value(path=p)
                      for p in delta}
@@ -614,6 +615,11 @@ def test_a_traced_step_counts_once_a_call_site_and_carries_the_scopes(
         assert scope in text, scope
     for name in ("flash_stream_fwd", "flash_stream_bwd_dkv_dq"):
         assert name in text, name
+    # the stage's calls sit in inner jits, entered under the stage's scope
+    for jitted, name in (("_conv_forward", "conv_streams_fwd"),
+                         ("_conv_backward", "conv_streams_bwd")):
+        assert f"mamba.conv/jit({jitted})" in text, jitted
+        assert f"{name}/pallas_call" in text, name
 
 
 def test_a_train_step_decays_no_scan_parameter_and_no_norm_weight(ids):

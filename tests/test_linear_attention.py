@@ -385,6 +385,24 @@ def test_the_kernel_microbenchmark_measures_on_a_tpu_only(mode):
     assert "fwd_ms" not in done.stdout
 
 
+@pytest.mark.parametrize("mode", [[], ["--stage", "conv"]])
+def test_the_scan_microbenchmark_measures_on_a_tpu_only(mode):
+    """``tools/ssd_bench.py`` — the state-space scan by chunk and segment,
+    and with ``--stage conv`` the biased convolution stage on both of its
+    paths — exits 2 where there is no TPU, as the delta rule's does."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, os.path.join(root, "tools", "ssd_bench.py"), *mode],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+        text=True, timeout=300)
+    assert done.returncode == 2 and "no TPU" in done.stderr
+    assert "fwd_ms" not in done.stdout
+
+
 def test_the_core_differentiates_inside_jax_checkpoint():
     args = inputs(6, 1, 96)
 
@@ -746,14 +764,18 @@ def conv_form(form, seed, dtype, taps, batch=2, seq=80):
     return xs, ws, segments
 
 
-def conv_kernels(xs, ws, segments, tokens=32, lanes=128):
-    """The kernels as the op calls them (the taps a copy a batch row), at
-    blocks small enough that a row is several of them and a segment
-    several channel steps."""
+def conv_kernels(xs, ws, segments, tokens=32, lanes=128, biases=None,
+                 head=CONV_D):
+    """The kernels as the op calls them (the taps a copy a batch row, a
+    stream's bias the copy's last row), at blocks small enough that a row is
+    several of them and a segment several channel steps."""
+    if biases is not None:
+        ws = tuple(jnp.concatenate([w, b[None]]) for w, b in zip(ws, biases))
     rows = tuple(jnp.broadcast_to(w[None], (xs[0].shape[0],) + w.shape)
                  for w in ws)
-    return kernels.conv_streams(xs, rows, segments, head=CONV_D, eps=1e-6,
-                                tokens=tokens, lanes=lanes, interpret=True)
+    return kernels.conv_streams(xs, rows, segments, head=head, eps=1e-6,
+                                bias=biases is not None, tokens=tokens,
+                                lanes=lanes, interpret=True)
 
 
 @pytest.mark.parametrize("activation", [None, "silu"])
@@ -867,38 +889,46 @@ def test_a_convolution_without_a_bias_lowers_to_what_it_did():
     assert stage() == stage(biases=None) == _lowered(stage_before, xs, ws)
 
 
-def test_a_biased_stage_is_the_xla_stage_whatever_the_kernels_admit(
+def test_a_biased_stage_takes_the_kernels_where_the_unbiased_one_does(
         interpreter):
     """``conv_streams(biases=)``: taps, bias, SiLU and the segments' cuts
-    against explicit shifted sums; ``conv_path`` answers ``xla`` for a shape
-    whose unbiased stage takes the kernels, and the counter says so."""
+    against explicit shifted sums, through the kernels: ``conv_path`` and
+    ``conv_kernel`` answer what they answer without a bias, and the counter
+    says so. What the kernels refuse they refuse with a bias too: taps past
+    the carried rows, a normed head that fills no lane group, a short row."""
     xs, ws, _ = conv_form("gdn", 33, jnp.float32, 4, seq=256)
     # Mamba-2's segments: x | B | C of the one stream, none of them normed
     segments = ((0, 0, 6 * CONV_D, None), (0, 6 * CONV_D, CONV_D, None),
                 (0, 7 * CONV_D, CONV_D, None))
     bias = jax.random.normal(jax.random.PRNGKey(34), (8 * CONV_D,))
-    shape = (256, segments, CONV_D, 4, xs[0].dtype)
-    assert la.conv_path(*shape) == "kernel"
-    assert la.conv_path(*shape, bias=True) == "xla"
+    # heads of 64 (Mamba-2's): no segment is normed, so no head is asked
+    for head in (CONV_D, 64):
+        assert la.conv_path(256, segments, head, 4, xs[0].dtype) == "kernel"
+    assert la.conv_path(256, segments, 64, 10, xs[0].dtype) == "xla"
+    assert la.conv_path(255, segments, 64, 4, xs[0].dtype) == "xla"
+    normed = ((0, 0, 6 * CONV_D, 1.0),) + segments[1:]
+    assert la.conv_path(256, normed, CONV_D, 4, xs[0].dtype) == "kernel"
+    assert la.conv_path(256, normed, 64, 4, xs[0].dtype) == "xla"
     before = {p: la._CONV_TOTAL.value(path=p) for p in ("kernel", "xla")}
-    assert la.conv_kernel(xs[0], ws[0], segments, CONV_D, bias=True) is None
+    assert la.conv_kernel(xs[0], ws[0], segments, 64) == "interpret"
     got = jax.jit(lambda xs, ws, b: la.conv_streams(
-        xs, ws, segments, head=CONV_D, eps=1e-6, biases=(b,)))(xs, ws, bias)
-    assert la._CONV_TOTAL.value(path="xla") == before["xla"] + 2
-    assert la._CONV_TOTAL.value(path="kernel") == before["kernel"]
+        xs, ws, segments, head=64, eps=1e-6, biases=(b,)))(xs, ws, bias)
+    assert la._CONV_TOTAL.value(path="kernel") == before["kernel"] + 2
+    assert la._CONV_TOTAL.value(path="xla") == before["xla"]
     pre = shifted_multiply_adds(xs[0], np.asarray(ws[0])) + np.asarray(bias)
     want = pre / (1.0 + np.exp(-pre))
     for out, (_, start, width, _) in zip(got, segments):
         np.testing.assert_allclose(out, want[..., start:start + width],
                                    rtol=1e-5, atol=1e-5)
-    # its gradient reaches the bias (the stage is a checkpoint of its own)
-    grad = jax.grad(lambda b: sum(jnp.sum(o) for o in la.conv_streams(
-        xs, ws, segments, head=CONV_D, eps=1e-6, kernel=None,
-        biases=(b,))))(bias)
+    # its gradient reaches the bias on either path
     sig = 1.0 / (1.0 + np.exp(-pre))
-    np.testing.assert_allclose(
-        grad, (sig * (1 + pre * (1 - sig))).sum(axis=(0, 1)), rtol=1e-4,
-        atol=1e-4)
+    for kernel in ("interpret", None):
+        grad = jax.grad(lambda b: sum(jnp.sum(o) for o in la.conv_streams(
+            xs, ws, segments, head=64, eps=1e-6, kernel=kernel,
+            biases=(b,))))(bias)
+        np.testing.assert_allclose(
+            grad, (sig * (1 + pre * (1 - sig))).sum(axis=(0, 1)), rtol=1e-4,
+            atol=1e-4)
 
 
 def one_bf16_ulp(got, want):
@@ -958,6 +988,246 @@ def test_a_row_of_whole_blocks_and_one_channel_step():
     got = conv_kernels(xs, ws, segments, tokens=32, lanes=512)
     for a, b in zip(got, want):
         close(a, b, 1e-5)
+
+
+#: Mamba-2's stage as granite-4.0-h-micro has it: x | B | C = 4,096 | 128 |
+#: 128 channels of ONE biased stream, 64 heads of 64, none of them normed
+MAMBA_SEGMENTS = ((0, 0, 4096, None), (0, 4096, 128, None),
+                  (0, 4224, 128, None))
+
+
+def biased_vjps(xs, ws, biases, segments, head, cotangents, **blocks):
+    """((outputs, (dxs, dws, dbs)) of the XLA stage in float32, the same of
+    the kernels on the values as given)."""
+    f32 = jnp.float32
+    want, want_vjp = jax.vjp(
+        lambda xs, ws, bs: la._conv_xla(xs, ws, segments, head, 1e-6, bs),
+        tuple(x.astype(f32) for x in xs), ws, biases)
+    got, got_vjp = jax.vjp(
+        lambda xs, ws, bs: conv_kernels(xs, ws, segments, biases=bs,
+                                        head=head, **blocks), xs, ws, biases)
+    return ((want, want_vjp(tuple(c.astype(f32) for c in cotangents))),
+            (got, got_vjp(cotangents)))
+
+
+@pytest.mark.parametrize("dtype, seq, tokens", [
+    (jnp.bfloat16, 300, None),      # CONV_TOKENS a block, the last padded
+    (jnp.float32, 80, 32),          # three blocks, the last padded
+])
+def test_the_biased_kernels_are_the_xla_stage_at_granites_segments(
+        dtype, seq, tokens):
+    """Mamba-2's stage at granite-4.0-h-micro's widths (4,096 | 128 | 128 of
+    one stream, heads of 64: ONE channel step, 34 lane groups a program),
+    batch 2, a row that is no whole number of blocks: the outputs and the
+    gradients of the stream, the taps AND the bias against the XLA stage in
+    float32 on the same values. Float32 to 1e-5; bf16 results (x, B, C and
+    dx) within one bf16 step, the float32 sums to 1e-5."""
+    assert kernels.conv_steps(MAMBA_SEGMENTS, 64) == 1
+    assert kernels.conv_supported(MAMBA_SEGMENTS, 64, 4, dtype)
+    keys = jax.random.split(jax.random.PRNGKey(41), 6)
+    xs = (jax.random.normal(keys[0], (2, seq, 4352)).astype(dtype),)
+    ws = (jax.random.normal(keys[1], (4, 4352)) * 0.5,)
+    biases = (jax.random.normal(keys[2], (4352,)),)
+    cotangents = tuple(
+        jax.random.normal(key, (2, seq, width)).astype(dtype)
+        for key, (_, _, width, _) in zip(keys[3:], MAMBA_SEGMENTS))
+    (want, want_grads), (got, got_grads) = biased_vjps(
+        xs, ws, biases, MAMBA_SEGMENTS, 64, cotangents, tokens=tokens,
+        lanes=None)
+    assert [o.shape for o in got] == [(2, seq, 4096), (2, seq, 128),
+                                      (2, seq, 128)]
+    for a, b in zip(got + got_grads[0], want + want_grads[0]):
+        assert a.dtype == dtype
+        if dtype == jnp.bfloat16:
+            one_bf16_ulp(a, b)
+        else:
+            close(a, b, 1e-5)
+    for a, b in zip(got_grads[1] + got_grads[2],
+                    want_grads[1] + want_grads[2]):
+        assert a.dtype == jnp.float32 and a.shape == b.shape
+        close(a, b, 1e-5)
+
+
+@pytest.mark.parametrize("form", ["kda", "gdn"])
+def test_a_bias_rides_normed_segments_too(form):
+    """The bias is not Mamba-2's alone: a biased stream whose q and k
+    segments take the L2 norm (three streams with a bias each; one stream of
+    three segments), two channel steps, against the XLA stage — outputs and
+    every gradient."""
+    xs, ws, segments = conv_form(form, 43, jnp.float32, 4)
+    keys = jax.random.split(jax.random.PRNGKey(44), len(xs) + len(segments))
+    biases = tuple(jax.random.normal(key, (x.shape[-1],))
+                   for key, x in zip(keys, xs))
+    cotangents = tuple(
+        jax.random.normal(key, xs[0].shape[:2] + (width,))
+        for key, (_, _, width, _) in zip(keys[len(xs):], segments))
+    (want, want_grads), (got, got_grads) = biased_vjps(
+        xs, ws, biases, segments, CONV_D, cotangents)
+    for a, b in zip(jax.tree.leaves((got, got_grads)),
+                    jax.tree.leaves((want, want_grads))):
+        assert a.shape == b.shape
+        close(a, b, 1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_zero_bias_is_the_unbiased_kernels_bit_for_bit(dtype):
+    """``a + 0`` is ``a``: outputs, dx and the taps' gradient of the biased
+    kernels at a bias of zero are the unbiased kernels', every bit —
+    compiled without XLA's operation fusion, which on a CPU contracts one
+    interpreted body's multiply-adds where it leaves the other's."""
+    xs, ws, segments = conv_form("gdn", 45, dtype, 4, batch=1, seq=48)
+    zeros = (jnp.zeros(xs[0].shape[-1]),)
+    cotangents = tuple(
+        jax.random.normal(key, xs[0].shape[:2] + (width,)).astype(dtype)
+        for key, (_, _, width, _) in zip(
+            jax.random.split(jax.random.PRNGKey(46), 3), segments))
+
+    def stage(xs, ws, *biases):
+        out, vjp = jax.vjp(lambda xs, ws, *bs: conv_kernels(
+            xs, ws, segments, biases=bs[0] if bs else None), xs, ws, *biases)
+        return out, vjp(cotangents)[:2]
+
+    def unfused(*args):
+        return jax.jit(stage).lower(*args).compile(compiler_options={
+            "xla_disable_hlo_passes": "fusion,cpu-instruction-fusion"})(*args)
+
+    for a, b in zip(jax.tree.leaves(unfused(xs, ws, zeros)),
+                    jax.tree.leaves(unfused(xs, ws))):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def _fwd_kernel_before(*refs, segments, head, eps, bias=False):
+    """``kernels._conv_fwd_kernel`` as it stood before it took a bias
+    (PR 47), written out."""
+    f32, halo_rows = jnp.float32, kernels.CONV_HALO
+    n = len(segments)
+    xs, ws, outs, halos = (refs[i * n:(i + 1) * n] for i in range(4))
+
+    @kernels.pl.when(kernels.pl.program_id(2) == 0)
+    def _row_start():
+        for halo in halos:
+            halo[...] = jnp.zeros(halo.shape, f32)
+
+    for x_ref, w_ref, o_ref, halo, (_, _, _, scale) in zip(
+            xs, ws, outs, halos, segments):
+        tokens, width = x_ref.shape[1:]
+        taps = w_ref.shape[1]
+        step = 128 if scale is None else head
+        for at in range(0, width, step):
+            lanes = slice(at, at + step)
+            xf = x_ref[0, :, lanes].astype(f32)
+            ext = jnp.concatenate([halo[:, lanes], xf], axis=0)
+            halo[:, lanes] = xf[tokens - halo_rows:]
+            a = kernels._pre_activation(kernels._shifted(ext, taps),
+                                        w_ref[0, :, lanes].astype(f32))
+            y = a * jax.nn.sigmoid(a)
+            if scale is not None:
+                y = y * (jax.lax.rsqrt(kernels._head_sum(y * y) + eps)
+                         * scale)
+            o_ref[0, :, lanes] = y.astype(o_ref.dtype)
+
+
+def _bwd_kernel_before(*refs, segments, head, eps, bias=False):
+    """``kernels._conv_bwd_kernel`` as it stood before it took a bias
+    (PR 47), written out."""
+    f32, halo_rows, pl = jnp.float32, kernels.CONV_HALO, kernels.pl
+    n = len(segments)
+    xs, befores, ws, dys, dxs, dws, carries = (
+        refs[i * n:(i + 1) * n] for i in range(7))
+    first = pl.program_id(2) == 0
+    row_start = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(first)
+    def _row_end():
+        for dw_ref, carry in zip(dws, carries):
+            dw_ref[...] = jnp.zeros(dw_ref.shape, f32)
+            carry[...] = jnp.zeros(carry.shape, f32)
+
+    for (x_ref, before_ref, w_ref, dy_ref, dx_ref, dw_ref, carry,
+         (_, _, _, scale)) in zip(xs, befores, ws, dys, dxs, dws, carries,
+                                  segments):
+        tokens, width = x_ref.shape[1:]
+        taps = w_ref.shape[1]
+        step = 128 if scale is None else head
+        for at in range(0, width, step):
+            lanes = slice(at, at + step)
+            w = w_ref[0, :, lanes].astype(f32)
+            before = before_ref[0, :, lanes].astype(f32)[
+                kernels._BEFORE - halo_rows:]
+            ext = jnp.concatenate(
+                [jnp.where(row_start, 0.0, before),
+                 x_ref[0, :, lanes].astype(f32)], axis=0)
+            shifted = kernels._shifted(ext, taps)
+            a = kernels._pre_activation(shifted, w)
+            sig = jax.nn.sigmoid(a)
+            dy = dy_ref[0, :, lanes].astype(f32)
+            if scale is not None:
+                y = a * sig
+                r = jax.lax.rsqrt(kernels._head_sum(y * y) + eps)
+                dy = (dy - y * (r * r * kernels._head_sum(dy * y))) * (
+                    r * scale)
+            da = dy * (sig * (1.0 + a * (1.0 - sig)))
+            dw_ref[0, :, lanes] += jnp.concatenate(
+                [jnp.sum(da * shifted[taps - 1 - j], axis=0, keepdims=True)
+                 for j in range(taps)], axis=0)
+            later = kernels._shifted(
+                jnp.concatenate([da, carry[:, lanes]], axis=0), taps,
+                up=True)
+            carry[:, lanes] = da[:halo_rows]
+            dx_ref[0, :, lanes] = kernels._pre_activation(later, w).astype(
+                dx_ref.dtype)
+
+
+@pytest.mark.parametrize("form", ["kda", "gdn"])
+def test_the_kernels_without_a_bias_lower_to_what_they_did(form,
+                                                           monkeypatch):
+    """The pattern of ``test_a_convolution_without_a_bias_lowers_to_what_it_
+    did``, through the KERNEL path: the stage's forward and VJP at Kimi Delta
+    Attention's three streams and at Gated DeltaNet's one, without a bias,
+    lower (in the interpreter, where the bodies are part of the text) to
+    the text the bodies of before the bias lower to; with a bias they do
+    not."""
+    xs, ws, segments = conv_form(form, 47, jnp.bfloat16, 4, batch=1, seq=64)
+    zeros = tuple(jnp.zeros(x.shape[-1]) for x in xs)
+
+    def lowered(*biases):
+        # the bodies are traced under inner jits: one trace a shape
+        kernels._conv_forward.clear_cache()
+        kernels._conv_backward.clear_cache()
+
+        def stage(xs, ws, *biases):
+            out, vjp = jax.vjp(lambda xs, ws, *bs: conv_kernels(
+                xs, ws, segments, tokens=32, biases=bs[0] if bs else None),
+                xs, ws, *biases)
+            return out, vjp(out)
+
+        return _lowered(stage, xs, ws, *biases)
+
+    now, biased = lowered(), lowered(zeros)
+    traced = []
+
+    def body(name, kernel):
+        def traced_body(*refs, **static):
+            traced.append(name)
+            return kernel(*refs, **static)
+        return traced_body
+
+    monkeypatch.setattr(kernels, "_conv_fwd_kernel",
+                        body("fwd", _fwd_kernel_before))
+    monkeypatch.setattr(kernels, "_conv_bwd_kernel",
+                        body("bwd", _bwd_kernel_before))
+    try:
+        before = lowered()
+    finally:
+        monkeypatch.undo()
+        kernels._conv_forward.clear_cache()
+        kernels._conv_backward.clear_cache()
+    assert sorted(set(traced)) == ["bwd", "fwd"]
+    assert "while" in now              # the interpreter's grid, bodies inside
+    assert now == before
+    assert biased != before
 
 
 @pytest.mark.parametrize("segments, head, lanes, steps", [
@@ -1051,43 +1321,50 @@ def test_the_stage_counts_the_path_once_a_trace(interpreter, seq, head,
         close(a, b, 1e-5)
 
 
-def test_the_convolution_kernels_shard_over_an_announced_mesh(interpreter):
+@pytest.mark.parametrize("biased", [False, True])
+def test_the_convolution_kernels_shard_over_an_announced_mesh(interpreter,
+                                                              biased):
     """Inside a step traced for a mesh the stage runs under the attention
     kernels' ``shard_map``: rows over the data axis and — three streams of
-    one segment each — heads over 'mp'; the taps go a copy a row, so their
-    gradient is summed over the shards. Result and gradients are the
-    one-device ones."""
+    one segment each — heads over 'mp'; the taps go a copy a row (a bias
+    its last row, cut with them), so their gradient is summed over the
+    shards. Result and gradients are the one-device ones."""
     from paddle_tpu.distributed import topology
 
     xs, ws, segments = conv_form("kda", 25, jnp.float32, 4, seq=256)
+    biases = tuple(jax.random.normal(key, (x.shape[-1],)) for key, x in zip(
+        jax.random.split(jax.random.PRNGKey(27), 3), xs)) if biased else None
     mesh = topology.build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
 
     weights = jax.random.normal(jax.random.PRNGKey(26), (3,) + xs[0].shape)
 
     def loss(stage):
-        return lambda xs, ws: sum(jnp.sum(o * c) for o, c in
-                                  zip(stage(xs, ws), weights))
+        return lambda *args: sum(jnp.sum(o * c) for o, c in
+                                 zip(stage(*args), weights))
 
-    def on_mesh(xs, ws):
+    def on_mesh(xs, ws, biases=None):
         with topology.tracing_for(mesh):
             assert la.conv_path(256, segments, CONV_D, 4, xs[0].dtype) == (
                 "kernel")
-            return la.conv_streams(xs, ws, segments, head=CONV_D, eps=1e-6)
+            return la.conv_streams(xs, ws, segments, head=CONV_D, eps=1e-6,
+                                   biases=biases)
 
-    text = jax.jit(on_mesh).lower(xs, ws).as_text()
+    args = (xs, ws) + ((biases,) if biased else ())
+    text = jax.jit(on_mesh).lower(*args).as_text()
     assert "shard_map" in text or "manual" in text
-    specs = [str(s) for s in _shard_map_in_specs(on_mesh, (xs, ws))]
+    specs = [str(s) for s in _shard_map_in_specs(on_mesh, args)]
     # three streams and their taps, every one cut both ways; the seed not
     assert sum("mp" in s and "dp" in s for s in specs) == 6, specs
+    argnums = tuple(range(len(args)))
     want = jax.value_and_grad(loss(
-        lambda xs, ws: la._conv_xla(xs, ws, segments, CONV_D, 1e-6)),
-        argnums=(0, 1))(xs, ws)
-    got = jax.jit(jax.value_and_grad(loss(on_mesh), argnums=(0, 1)))(xs, ws)
+        lambda xs, ws, biases=None: la._conv_xla(
+            xs, ws, segments, CONV_D, 1e-6, biases)), argnums)(*args)
+    got = jax.jit(jax.value_and_grad(loss(on_mesh), argnums))(*args)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         close(a, b, 1e-5)
 
 
-@pytest.mark.parametrize("kind", ["kda", "gdn"])
+@pytest.mark.parametrize("kind", ["kda", "gdn", "mamba"])
 def test_a_layer_takes_the_convolution_kernels_through_the_tape(kind):
     """The layers' own call at lane-wide heads, eagerly: Tensors in,
     ``backward`` through the tape, a row that is no whole block; with the
@@ -1095,12 +1372,15 @@ def test_a_layer_takes_the_convolution_kernels_through_the_tape(kind):
     forward's and the tape's), without it ``xla``, and the flag's flip
     retraces (the stage's static arguments carry it). The two agree on the
     output and on every gradient."""
-    from paddle_tpu.text.models import GatedDeltaNet, KimiDeltaAttention
+    from paddle_tpu.text.models import (GatedDeltaNet, KimiDeltaAttention,
+                                        Mamba2Mixer)
 
     paddle.seed(3)
-    layer = (KimiDeltaAttention(32, num_heads=2, head_dim=128)
-             if kind == "kda" else
-             GatedDeltaNet(32, num_k_heads=1, num_v_heads=2))
+    layer = {"kda": lambda: KimiDeltaAttention(32, num_heads=2, head_dim=128),
+             "gdn": lambda: GatedDeltaNet(32, num_k_heads=1, num_v_heads=2),
+             # heads of 64 and a bias: x | B | C of 128 channels each
+             "mamba": lambda: Mamba2Mixer(32, num_heads=2, head_dim=64,
+                                          d_state=128)}[kind]()
     x = np.random.default_rng(4).standard_normal((2, 260, 32)).astype(
         np.float32)
     results = {}

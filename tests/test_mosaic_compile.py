@@ -149,6 +149,14 @@ LAYER_CASES = {
     "kda-kimi-cell": (1, 16384, 32, 32, 128),
     "gdn-qwen3-next-cell": (1, 16384, 16, 32, 128),
 }
+#: Mamba-2's biased stage alone (batch, seq, heads, head_dim, d_state, one
+#: group): ``text.models._mamba_streams`` forward + VJP in bf16 at the
+#: granite-4.0-h-micro cell's shape — x | B | C = 4,096 | 128 | 128 channels
+#: of one stream, heads of 64, a bias a channel; the same two calls, under
+#: ``mamba.conv`` in a trace
+MAMBA_CASES = {
+    "mamba-granite-cell": (1, 8192, 64, 64, 128),
+}
 #: the layer's stage between the projections and the scan alone, the
 #: names its two Mosaic calls carry (the instructions': the calls sit in
 #: inner jits, under the ``kda.conv`` / ``gdn.conv`` scopes in a trace)
@@ -572,6 +580,34 @@ def _child():
                 rf"= ({head_view})\S* (?:copy|reshape|transpose)\(", text))),
             "f32_head_views": len(re.findall(head_view, text))}
 
+    for name, (batch, seq, heads, d, state) in MAMBA_CASES.items():
+        def like(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        def stage_alone(*args):
+            out, vjp = jax.vjp(lambda *a: models._mamba_streams(
+                *a, inner=heads * d, state=state, head=d), *args)
+            return out, vjp(out)
+
+        channels = heads * d + 2 * state
+        here = placement.kernel
+        placement.kernel = lambda **site: "mosaic"
+        try:
+            conv = jax.jit(stage_alone).lower(
+                like(batch, seq, channels),
+                like(4, channels, dtype=jnp.float32),
+                like(channels, dtype=jnp.float32)).compile()
+        finally:
+            placement.kernel = here
+        conv_text = conv.as_text()
+        out["conv-" + name] = {
+            "mosaic": conv_text.count(MOSAIC),
+            "calls": [c for c in CONV_CALLS if f"%{c}" in conv_text],
+            "stream_sized_f32": len(re.findall(
+                rf"f32\[{batch},{seq},\d\d\d+",
+                conv_text[conv_text.index("\nENTRY "):])),
+            "temp_gb": conv.memory_analysis().temp_size_in_bytes / 1e9}
+
     for name, (batch, seq, heads, kv_heads, d, rotary_dim) in (
             QK_CASES.items()):
         def like(*shape, dtype=jnp.bfloat16):
@@ -947,21 +983,27 @@ def test_a_linear_attention_layer_keeps_one_tiling(compiled, case):
     assert got["f32_head_views"] == 0
 
 
-@pytest.mark.parametrize("case", list(LAYER_CASES))
+@pytest.mark.parametrize("case", list(LAYER_CASES) + list(MAMBA_CASES))
 def test_the_convolution_stage_keeps_its_float32_in_vmem(compiled, case):
     """The stage alone, forward + VJP at the cell's shape in bf16: one
     Mosaic call a pass for q, k and v together — three streams of 4,096
-    channels (Kimi-Linear) or the segments 2,048 | 2,048 | 4,096 of one
-    (Qwen3-Next) — and no float32 array as large as a stream in HBM (the
-    XLA stage held five of 268 MB a stream); what the program holds beside
-    its arguments and results is not more than the three bf16 streams the
-    backward writes before they are one again."""
+    channels (Kimi-Linear), the segments 2,048 | 2,048 | 4,096 of one
+    (Qwen3-Next) or, with a bias a channel and heads of 64, Mamba-2's
+    x | B | C = 4,096 | 128 | 128 of one (granite-4.0-h-micro) — and no
+    float32 array as large as a stream in HBM (the XLA stage held five of
+    268 MB a stream); what the program holds beside its arguments and
+    results is not more than the three bf16 streams the backward writes
+    before they are one again."""
     got = compiled["conv-" + case]
     assert got["mosaic"] == 2 and got["calls"] == list(CONV_CALLS)
     assert got["stream_sized_f32"] == 0
-    batch, seq, key_heads, heads, d = LAYER_CASES[case]
-    streams = batch * seq * (2 * key_heads + heads) * d * 2 / 1e9
-    assert got["temp_gb"] <= 1.1 * streams, got
+    if case in MAMBA_CASES:
+        batch, seq, heads, d, state = MAMBA_CASES[case]
+        channels = heads * d + 2 * state
+    else:
+        batch, seq, key_heads, heads, d = LAYER_CASES[case]
+        channels = (2 * key_heads + heads) * d
+    assert got["temp_gb"] <= 1.1 * batch * seq * channels * 2 / 1e9, got
 
 
 @pytest.mark.parametrize("case", list(QK_CASES))

@@ -15,8 +15,20 @@ and least bytes for one forward and one backward).
 (default: 64x512, 128x1024, 256x1024, 256x2048, 256x4096, 512x2048,
 512x4096).
 It is what the configuration's ``mamba_chunk`` / ``mamba_segment`` were read
-from (PERF.md section 6, PR 47). A microbenchmark's numbers are findings
-for PERF.md, never a metric of the benchmark. Exits 2 without a TPU.
+from (PERF.md section 6, PR 47).
+
+``--stage conv`` times the mixer's CONVOLUTION STAGE alone instead —
+``text.models._mamba_streams``: taps, bias, SiLU and the x | B | C split of
+the 1 x 8,192 x 4,352 bf16 stream — on both of its paths in one call (the
+Mosaic kernels ``conv_streams_fwd`` / ``_bwd`` and the XLA stage they are
+held to): ms forward and forward + backward, the GB/s those are of the
+stage's least bytes (``benchmark/layer_metrics/ssm_conv_stage_roofline.py
+stage_bytes``), and how far the kernels' results are from the XLA stage's.
+
+    chiprun -- python3 tools/ssd_bench.py --stage conv
+
+A microbenchmark's numbers are findings for PERF.md, never a metric of the
+benchmark. Exits 2 without a TPU.
 """
 import importlib.util
 import os
@@ -37,6 +49,62 @@ def _load(*path):
     return module
 
 
+def conv_stage(clock):
+    """A line a path of the convolution stage: ``kernel`` first, ``xla``
+    against it."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import placement
+    from paddle_tpu.text import models
+
+    stage_bytes = _load("benchmark", "layer_metrics",
+                        "ssm_conv_stage_roofline.py").stage_bytes
+    inner, state = HEADS * D_HEAD, GROUPS * D_STATE
+    channels = inner + 2 * state
+    keys = jax.random.split(jax.random.PRNGKey(48), 6)
+    bf16 = jnp.bfloat16
+    xbc = jax.random.normal(keys[0], (BATCH, SEQ, channels), bf16)
+    w = jax.random.normal(keys[1], (4, channels)) * 0.5
+    bias = jax.random.normal(keys[2], (channels,))
+    dys = tuple(jax.random.normal(k, (BATCH, SEQ, n), bf16)
+                for k, n in zip(keys[3:], (inner, state, state)))
+    clock.line(device=jax.devices()[0].device_kind, stage="conv",
+               batch=BATCH, seq=SEQ, channels=channels, taps=4)
+
+    def far(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(f"{jnp.abs(a - b).max() / jnp.abs(a).max():.3g}")
+
+    first, tokens = None, BATCH * SEQ
+    for path, kernel in (("kernel", placement.kernel(sharded=True)),
+                         ("xla", None)):
+        def stage(xbc, w, bias):
+            return models._mamba_streams(xbc, w, bias, inner=inner,
+                                         state=state, head=D_HEAD,
+                                         kernel=kernel)
+
+        def both(xbc, w, bias, dys):
+            out, vjp = jax.vjp(stage, xbc, w, bias)
+            return out, vjp(dys)
+
+        both = jax.jit(both)
+        f = clock.timed(jax.jit(stage), xbc, w, bias, reps=20)
+        fb = clock.timed(both, xbc, w, bias, dys, reps=20)
+        got = jax.tree.leaves(both(xbc, w, bias, dys))
+        first = first or got
+        apart = {tag: far(a, b) for tag, a, b in zip(
+            "x b c dxbc dw dbias".split(), first, got)}
+        clock.line(
+            path=path, fwd_ms=round(f, 3), fwd_bwd_ms=round(fb, 3),
+            fwd_gb_per_s=round(stage_bytes(tokens, channels, 1, 0)
+                               / f / 1e6, 1),
+            fwd_bwd_gb_per_s=round(stage_bytes(tokens, channels, 1, 1)
+                                   / fb / 1e6, 1),
+            apart_from_kernel=apart)
+    return 0
+
+
 def main():
     import jax
     import jax.numpy as jnp
@@ -47,6 +115,8 @@ def main():
         print("ssd_bench.py times the chip: no TPU", file=sys.stderr)
         return 2
     clock = _load("benchmark", "tools", "kda_candidates.py")
+    if sys.argv[1:3] == ["--stage", "conv"]:
+        return conv_stage(clock)
     roofline = _load("benchmark", "layer_metrics", "ssd_core_roofline.py")
     peaks = _load("benchmark", "harness", "peaks.py").PEAKS[
         jax.devices()[0].device_kind]
