@@ -21,10 +21,12 @@ from .core import random as _random
 from .core import tape as _tape
 from .core.tensor import Tensor, to_tensor  # noqa: F401
 
-# jax is imported: its trace / lower / compile events become spans
+# jax is imported: its trace / lower / compile events become spans, and so
+# do the interpreter's collections
 from .obs import ledger as _ledger
 
 _ledger.bridge_jax_monitoring()
+_ledger.bridge_gc()
 
 # dtypes
 from .core.dtype import (  # noqa: F401

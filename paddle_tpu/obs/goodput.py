@@ -44,10 +44,6 @@ _SECONDS = _metrics.counter(
     "paddle_goodput_seconds_total",
     "Wall-clock seconds per goodput category (step = useful time)",
     labelnames=("category",))
-_EVENTS = _metrics.counter(
-    "paddle_goodput_events_total",
-    "Recorded goodput events per category",
-    labelnames=("category",))
 
 
 class GoodputAccountant:
@@ -81,7 +77,6 @@ class GoodputAccountant:
             self._t_last = now
         if self._export:
             _SECONDS.inc(seconds, category=category)
-            _EVENTS.inc(category=category)
 
     @contextlib.contextmanager
     def _timed(self, category):

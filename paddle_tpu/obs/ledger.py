@@ -24,8 +24,11 @@ Those entries are the compiles a caller reports. What jax itself traces,
 lowers and compiles — every ``jax.jit`` program of the process — reaches
 the span layer and this module's two metrics through
 :func:`bridge_jax_monitoring`, the process's one set of listeners on
-``jax.monitoring`` (installed at ``paddle_tpu``'s import).
+``jax.monitoring`` (installed at ``paddle_tpu``'s import). The interpreter's
+own pauses reach both the same way: :func:`bridge_gc`, the process's one
+callback on ``gc.callbacks``.
 """
+import gc
 import hashlib
 import re
 import sys
@@ -326,4 +329,107 @@ def bridge_jax_monitoring():
         monitoring.register_scalar_listener(_on_region_start)
         monitoring.register_event_listener(_on_cache_event)
         monitoring.register_event_duration_secs_listener(_on_duration)
+    return True
+
+
+# ------------------------------------- the interpreter's collections -> spans
+#: a collection of generation 0 or 1 is a span from this many seconds on (a
+#: full collection always is): a large start runs some 10^5 young ones of
+#: tens of microseconds, and the ring holds 8,192 spans
+_GC_SPAN_MIN_S = 0.001
+_GC_MARK = "_paddle_tpu_gc_totals"
+
+
+def _gc_callback():
+    """A callback for ``gc.callbacks`` with counters of its own. The
+    interpreter calls it on the collecting thread before and after every
+    collection, one collection at a time, every other Python thread stopped
+    in between. It may interrupt its thread wherever an object is allocated,
+    inside a locked region of the span layer or of the metrics registry
+    too, so it takes no lock: ``tracing._record`` never waits for one, and
+    the counters are plain lists that only it writes. All it needs is in
+    its closure, so a young collection still counts while the interpreter
+    takes the modules apart at exit."""
+    counts, seconds = [0, 0, 0], [0.0, 0.0, 0.0]
+    running = [None, None]  # the collection under way: its start, its region
+    clock, min_s = time.monotonic, _GC_SPAN_MIN_S
+    Span, record_span = _tracing.Span, _tracing.record_span
+
+    def on_gc(phase, info):
+        if phase == "start":
+            if info["generation"] == 2:
+                running[1] = Span("host.gc", attrs={"generation": 2})
+            running[0] = clock()
+            return
+        t1, t0 = clock(), running[0]
+        if t0 is None:  # installed while this collection ran
+            return
+        running[0] = None
+        generation = info["generation"]
+        counts[generation] += 1
+        seconds[generation] += t1 - t0
+        if generation == 2 or t1 - t0 >= min_s:
+            attrs = {"collected": info["collected"],
+                     "uncollectable": info["uncollectable"],
+                     "counts": list(counts), "seconds": list(seconds)}
+            region, running[1] = running[1], None
+            if region is not None:
+                region.finish(**attrs)
+            else:
+                record_span("host.gc", t1 - t0, generation=generation,
+                            **attrs)
+
+    setattr(on_gc, _GC_MARK,
+            lambda: {g: (counts[g], seconds[g]) for g in range(3)})
+    return on_gc
+
+
+def gc_totals():
+    """{generation: (collections, seconds paused)} since the bridge went
+    in: what the two counters hold. None in a process without the bridge."""
+    for f in gc.callbacks:
+        totals = getattr(f, _GC_MARK, None)
+        if totals is not None:
+            return totals()
+    return None
+
+
+def _gc_families():
+    """The registry's view of the callback's counters (a collector, not two
+    ``Counter``s: a ``Counter``'s lock is one the interrupted thread may
+    hold)."""
+    rows = sorted((gc_totals() or {}).items())
+    return [
+        _metrics.Family(
+            "paddle_gc_collections_total", "counter",
+            "Collections of the cyclic garbage collector, by generation",
+            [("", {"generation": str(g)}, n) for g, (n, _) in rows]),
+        _metrics.Family(
+            "paddle_gc_pause_seconds_total", "counter",
+            "Seconds every Python thread stood still for a collection",
+            [("", {"generation": str(g)}, s) for g, (_, s) in rows])]
+
+
+def bridge_gc():
+    """Install the process's one callback on ``gc.callbacks``: a collection
+    of generation 2, or any that lasted ``_GC_SPAN_MIN_S`` or more, becomes
+    a span ``host.gc`` [``generation``, ``collected``, ``uncollectable``,
+    and ``counts`` / ``seconds``: the two counters by generation as they
+    stood when it ended, which places the young collections in time] on
+    the thread that triggered it, a child of the span that thread is
+    inside and nobody's ambient parent. A full collection is a region
+    span, opened at its start, so a profiler's trace holds it as
+    ``paddle_tpu:host.gc``; a young one that turns out long is
+    pre-measured, memory only. Every collection counts in
+    ``paddle_gc_collections_total{generation}`` and
+    ``paddle_gc_pause_seconds_total{generation}``; the young and short ones
+    (two clock reads and two adds each) count there only.
+
+    There is no switch. Idempotent, whichever copy of this module is asked
+    (the callback in ``gc.callbacks`` carries its counters: ``gc_totals``
+    reads the installed one's); a forked worker inherits it and counts its
+    own collections in its own memory."""
+    if gc_totals() is None:
+        gc.callbacks.append(_gc_callback())
+        _metrics.REGISTRY.register_collector(_gc_families)
     return True

@@ -20,8 +20,9 @@ Spans come from: start-up (``nn.init`` a parameter drawn,
 ``io.loader.start``, and jax's own trace / lowering / backend-compile
 regions as pre-measured ``compile.trace`` / ``.lower`` / ``.backend``,
 bridged from ``jax.monitoring`` by ``obs/ledger.py``: memory only, as every
-``record_span``), the train path (``io.next_batch``, ``spmd.shard_batch``,
-``train.step`` and their children), the serving engine
+``record_span``; the interpreter's collections as ``host.gc``, bridged
+from ``gc.callbacks`` there too), the train path (``io.next_batch``,
+``spmd.shard_batch``, ``train.step`` and their children), the serving engine
 (``serving.scheduler.loop`` / ``.execute`` / ``.compile`` as regions;
 ``serving.queue`` / ``.request`` / ``.reply`` pre-measured per traced
 request with ``record_span``), checkpoints, and the legacy
@@ -52,6 +53,16 @@ _RING = 8192
 
 _lock = threading.Lock()
 _finished = collections.deque(maxlen=_RING)
+#: finished spans on their way into the ring, oldest first. ``_record`` puts
+#: a span here without a lock and moves what is here into the ring only if it
+#: gets ``_lock`` without waiting: a span may finish on a thread that already
+#: holds ``_lock`` further down its own stack (an allocation under the lock
+#: starts a collection, whose callback finishes ``host.gc``), or in a forked
+#: child whose copy of the lock another thread of the parent held. Whoever
+#: holds the lock takes what the others left, and every reader drains it
+#: first, so nothing is lost and nothing is seen late (unbounded for that
+#: reason: it is empty again as soon as anyone gets the lock)
+_pending = collections.deque()
 _agg = {}  # name -> [calls, total_s, max_s, min_s]
 _tls = threading.local()
 _span_ids = itertools.count(1)  # next() is atomic under the GIL
@@ -191,10 +202,23 @@ def _agg_update_locked(name, duration_s):
     rec[3] = min(rec[3], duration_s)
 
 
-def _record(sp):
-    with _lock:
+def _drain_locked():
+    """Move the pending spans into the ring and the summary table. Caller
+    holds _lock; a span that arrives meanwhile (from another thread, or from
+    a collection this loop's own allocations started) is taken too."""
+    while _pending:
+        sp = _pending.popleft()
         _finished.append(sp)
         _agg_update_locked(sp.name, sp.t1 - sp.t0)
+
+
+def _record(sp):
+    _pending.append(sp)
+    if _lock.acquire(blocking=False):
+        try:
+            _drain_locked()
+        finally:
+            _lock.release()
 
 
 def observe(name, duration_s):
@@ -222,6 +246,7 @@ def finished(trace_id=None, name=None):
     trace id and/or span name. The ring is bounded: a window to read
     after a run, not a durable trace store."""
     with _lock:
+        _drain_locked()
         spans = list(_finished)
     return [s.as_dict() for s in spans
             if (trace_id is None or s.trace_id == trace_id)
@@ -232,6 +257,7 @@ def ring_full():
     """True once the ring holds ``_RING`` spans: older ones may have fallen
     out, so a sum over "everything since X" may be short."""
     with _lock:
+        _drain_locked()
         return len(_finished) == _RING
 
 
@@ -260,6 +286,7 @@ def summary_rows():
     """Aggregated per-name rows, the profiler.summary() table schema:
     {name, calls, total, avg, max, min}."""
     with _lock:
+        _drain_locked()
         return [{"name": n, "calls": c, "total": tot, "avg": tot / c,
                  "max": mx, "min": mn}
                 for n, (c, tot, mx, mn) in _agg.items()]
@@ -269,11 +296,13 @@ def reset_summary():
     """Clear the aggregation table (the profiler.reset_summary()
     contract); the finished-span ring survives."""
     with _lock:
+        _drain_locked()
         _agg.clear()
 
 
 def reset():
     """Clear both the aggregation table and the finished-span ring."""
     with _lock:
+        _pending.clear()
         _agg.clear()
         _finished.clear()

@@ -423,6 +423,9 @@ class TestResilienceTelemetry:
         assert _SAVE_SECONDS.value()["count"] == hist0 + 1
         assert goodput._SECONDS.value(category="checkpoint") > ckpt0
         assert tracing.finished(name="checkpoint.save")
+        # the only record of a resume's seconds (ROADMAP Reach 8b reads it)
+        (loaded,) = tracing.finished(name="checkpoint.load")[-1:]
+        assert loaded["attrs"] == {"step": 1} and loaded["duration_s"] > 0
 
     def test_retry_sleeps_counted(self):
         from paddle_tpu.resilience.retry import _RETRIES, call_with_retry
