@@ -66,6 +66,10 @@ FULL = dict(
     short_shape=(256, 12, 128, 64),  # the BERT cells' attention, per chip
     # one Kimi Delta Attention layer at the Kimi-Linear cell's head sizes
     kda=dict(hidden_size=2304, num_heads=32, head_dim=128), kda_seq=2048,
+    # one Mamba-2 mixer at the granite cell's sizes: 64 heads of 64 on a
+    # state of 128, one group
+    ssd=dict(hidden_size=2048, num_heads=64, head_dim=64, d_state=128),
+    ssd_seq=2048,
     llama=dict(vocab_size=32000, hidden_size=1024, num_layers=4,
                num_heads=8, intermediate_size=2816, max_seq_len=2048),
     llama_seq=2048, llama_rows_per_chip=2,
@@ -82,6 +86,10 @@ TOY = dict(
     short_shape=(4, 2, 128, 64),
     # one lane group a head and over one chunk: the kernels' shortest case
     kda=dict(hidden_size=64, num_heads=2, head_dim=128), kda_seq=72,
+    # values of half a lane group on a state of one, a token block and a
+    # part: the scan kernels' shortest case
+    ssd=dict(hidden_size=64, num_heads=8, head_dim=64, d_state=128),
+    ssd_seq=300,
     llama=dict(vocab_size=256, hidden_size=64, num_layers=1, num_heads=2,
                intermediate_size=128, max_seq_len=256),
     llama_seq=256, llama_rows_per_chip=1,
@@ -626,6 +634,7 @@ class Smoke:
 
         info["short"] = self.short_kernel()
         info["kda"] = self.delta_layer()
+        info["ssd"] = self.scan_layer()
 
         # the kernel inside the framework's own tape, amp and donation
         n, devs = self.n, self.devices
@@ -671,14 +680,12 @@ class Smoke:
 
     def delta_layer(self):
         """One ``KimiDeltaAttention`` layer, forward and backward through
-        the eager tape: under amp O1 on the path the platform gives (the
-        Mosaic kernels where this process has one device, what the
+        the eager tape, amp O1: on the path the route gives the gated delta
+        rule (the Mosaic kernels ``kda_chunk_fwd`` / ``_bwd`` — what the
         Kimi-Linear cell's step runs) and on the XLA scan it falls back to,
         each against float32 on the recurrence over tokens, the same
         weights and input."""
-        import jax
         import jax.numpy as jnp
-        import numpy as np
         import paddle_tpu as paddle
         from paddle_tpu.ops import linear_attention
         from paddle_tpu.text.models import KimiDeltaAttention
@@ -686,9 +693,49 @@ class Smoke:
         seq, d = self.cfg["kda_seq"], self.cfg["kda"]["head_dim"]
         paddle.seed(6)
         layer = KimiDeltaAttention(**self.cfg["kda"])
-        x = np.random.RandomState(6).randn(
-            1, seq, self.cfg["kda"]["hidden_size"]).astype(np.float32)
-        given = linear_attention.core_path(seq, d, d, jnp.bfloat16)
+        return self._layer_on_its_paths(
+            layer, self.cfg["kda"], seq, 6, "core_path",
+            linear_attention.core_path(seq, d, d, jnp.bfloat16),
+            linear_attention._CORE_TOTAL,
+            lambda: (layer.A_log, layer.q_conv.weight))
+
+    def scan_layer(self):
+        """One ``Mamba2Mixer``, likewise: on the path the route gives the
+        selective state-space scan (the Mosaic kernels ``ssd_chunk_fwd`` /
+        ``_bwd`` — what the granite cell's step runs) and on the XLA scan it
+        falls back to, each against float32 on the recurrence."""
+        import jax.numpy as jnp
+        import paddle_tpu as paddle
+        from paddle_tpu.ops import linear_attention
+        from paddle_tpu.text.models import Mamba2Mixer
+
+        seq, cfg = self.cfg["ssd_seq"], self.cfg["ssd"]
+        paddle.seed(7)
+        layer = Mamba2Mixer(**cfg)
+        return self._layer_on_its_paths(
+            layer, cfg, seq, 7, "ssd_path",
+            linear_attention.ssd_path(seq, cfg["num_heads"], 1,
+                                      cfg["head_dim"], cfg["d_state"],
+                                      jnp.bfloat16),
+            linear_attention._SSD_TOTAL,
+            lambda: (layer.A_log, layer.D, layer.conv1d.weight))
+
+    def _layer_on_its_paths(self, layer, cfg, seq, seed, route, given,
+                            counter, parameters):
+        """A linear-time mixer forward and backward through the eager tape
+        on the path its route (``ops.linear_attention.<route>``) gives under
+        amp O1, on the XLA scan (``chunked``) and, in float32, on the
+        recurrence over tokens the two are held to; each counted once in
+        ``counter`` under its own label. Compared: the output, the input's
+        gradient and those of ``parameters()``."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        import paddle_tpu as paddle
+        from paddle_tpu.ops import linear_attention
+
+        x = np.random.RandomState(seed).randn(
+            1, seq, cfg["hidden_size"]).astype(np.float32)
         # an eager call on a host with several chips does not know its
         # program's devices: there the XLA scan is what the route gives
         assert given == ("kernel" if self.dry_run or jax.device_count() == 1
@@ -696,13 +743,11 @@ class Smoke:
         counts, times = {}, {}
 
         def run(path, amp):
-            """(output, d input, d A_log, d q_conv) on ``path`` (None: the
-            route's own choice)."""
-            label = path or given
-            before = linear_attention._CORE_TOTAL.value(path=label)
-            saved = linear_attention.core_path
+            label = path or given               # None: the route's own choice
+            before = counter.value(path=label)
+            saved = getattr(linear_attention, route)
             if path:
-                linear_attention.core_path = lambda *shape: path
+                setattr(linear_attention, route, lambda *shape: path)
             t0 = time.monotonic()
             try:
                 layer.clear_gradients()
@@ -713,13 +758,13 @@ class Smoke:
                 w = jnp.cos(jnp.arange(out.shape[-1], dtype=jnp.float32))
                 (out.astype("float32") * paddle.to_tensor(w)).sum().backward()
             finally:
-                linear_attention.core_path = saved
-            out = [out._value, t.grad._value, layer.A_log.grad._value,
-                   layer.q_conv.weight.grad._value]
+                setattr(linear_attention, route, saved)
+            out = [out._value, t.grad._value,
+                   *(p.grad._value for p in parameters())]
             jax.block_until_ready(out)
             times[label] = time.monotonic() - t0
             counts[label] = counts.get(label, 0) + (
-                linear_attention._CORE_TOTAL.value(path=label) - before)
+                counter.value(path=label) - before)
             return out
 
         got, fallback = run(None, amp=True), run("chunked", amp=True)
@@ -729,7 +774,7 @@ class Smoke:
         worst = {path: max(_rel_err(g, r) for g, r in zip(outs, ref))
                  for path, outs in ((given, got), ("chunked", fallback))}
         assert max(worst.values()) <= KDA_REL_TOL, worst
-        return {"seq": seq, **self.cfg["kda"], "path": given,
+        return {"seq": seq, **cfg, "path": given,
                 "max_rel_err_vs_recurrence": {
                     p: round(e, 5) for p, e in worst.items()},
                 "smoke_fwd_bwd_s": {p: round(times[p], 3)

@@ -9,7 +9,8 @@ recurrences and two convolution stages live here; which function is whose:
   HEAD (Gated DeltaNet; Yang et al., arXiv:2412.06464 — Qwen3-Next's linear
   layers), ``g`` of [B, T, H];
 - **the selective state-space scan** (``ssd_recurrent`` / ``ssd_chunked`` /
-  ``ssd_scan``, with ``_ssd_segment``, ``ssd_path``): Mamba-2's (Dao & Gu,
+  ``ssd_scan``, with ``_ssd_segment``, ``ssd_path``; its Mosaic kernels in
+  ``ops/pallas/ssd.py``): Mamba-2's (Dao & Gu,
   arXiv:2405.21060 — Granite-4.0-H's ``mamba`` layers), the scalar-decay
   scan WITHOUT the delta correction, its own section below;
 - **the convolution stage before a scan** (``conv_streams`` with
@@ -162,15 +163,23 @@ sequential loop inside a segment: nothing a chunk writes depends on the
 state, where the delta rule's ``u`` does); an outer ``lax.scan`` over
 segments carries the state, each segment under ``jax.checkpoint``, so a
 backward pass holds one segment's float32 (mask, sums, states) and not the
-row's. The backward is plain autodiff. Precision: ``dt A``, its sums, the
-mask, the state and y before its cast are float32 (the sums and the
-triangle over chunks ``Precision.HIGHEST``); the [C, N] x [N, C],
+row's. The XLA path's backward is plain autodiff. Precision: ``dt A``, its
+sums, the mask, the state and y before its cast are float32 (the sums and
+the triangle over chunks ``Precision.HIGHEST``); the [C, N] x [N, C],
 [C, C] x [C, P] and [C, N] x [N, P] products take operands of x's dtype
 (bf16 under amp O1) and accumulate in float32. ``ssd_scan`` is the entry
-point a layer calls: the path from length alone today (``ssd_path``:
-``chunked`` | ``recurrent``), counted in ``paddle_tpu_ssd_core_total{path}``
-— a Mosaic kernel at keys of 128 and values of 64 is a third label, not a
-new counter.
+point a layer calls: it picks the path from what it can observe
+(``ssd_path``: length, widths, groups, dtype and ``ops.placement``'s answer
+for the program) and counts the choice in
+``paddle_tpu_ssd_core_total{path}`` (``kernel`` | ``chunked`` |
+``recurrent``). A train step of the granite-4.0-h-micro configuration on a
+TPU takes the kernels (``ops/pallas/ssd.py``: ``ssd_chunk_fwd`` /
+``ssd_chunk_bwd`` under one ``jax.custom_vjp``, the backward hand-written,
+on the layer's [B, T, H P] and [B, T, G N] streams as the convolution
+kernels leave them — the head view and this file's [segments, B, chunks, C,
+G, R, P] order never exist in HBM); ``chunked`` and ``recurrent`` are this
+file's, what a CPU, a float32 scan, odd widths and a program whose devices
+are not known run, and what the tests hold the kernels to.
 """
 import functools
 
@@ -213,8 +222,8 @@ _SHORTCONV_TOTAL = obs_metrics.counter(
 _SSD_TOTAL = obs_metrics.counter(
     "paddle_tpu_ssd_core_total",
     "selective state-space scans (Mamba-2's SSD: a scalar decay a head, no "
-    "delta correction) by the path taken (chunked | recurrent); under jit "
-    "one count per traced layer call",
+    "delta correction) by the path taken (kernel | chunked | recurrent); "
+    "under jit one count per traced layer call",
     labelnames=("path",))
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -779,11 +788,29 @@ def ssd_chunked(x, dt, a, b, c, d, initial_state=None, *,
             state.reshape(bsz, h, n, p))
 
 
-def ssd_path(seq):
-    """``chunked`` | ``recurrent`` for a row of ``seq`` tokens, from its
-    length alone today: a chunk's set-up (the pair product, the mask) pays
-    from ``SUB`` tokens on, else the recurrence."""
-    return "recurrent" if seq < SUB else "chunked"
+def ssd_path(seq, heads=None, groups=1, d_head=None, d_state=None,
+             dtype=None):
+    """``kernel`` | ``chunked`` | ``recurrent`` for a row of ``seq`` tokens
+    of ``heads`` heads of ``d_head`` in ``groups`` groups on a state of
+    ``d_state``, operands in ``dtype``, from what can be observed, as
+    ``core_path``: a chunk's set-up (the pair product, the mask) pays from
+    ``SUB`` tokens on, else the recurrence; the Mosaic kernels
+    (``ops/pallas/ssd.py``) where the program may hold them
+    (``placement.kernel``: they run through ``on_mesh``), the state fills
+    whole lane groups, the values' width divides or is a multiple of the 128
+    lanes, a head cut serves, the operands are bf16 (a float32 scan — the
+    cell's float32 check — stays the XLA path's) and the row is at least one
+    token block; the XLA scan everything else: every CPU run, every toy
+    width, ``use_pallas_kernels`` off."""
+    if seq < SUB:
+        return "recurrent"
+    from .pallas import ssd as kernels
+
+    if (heads is not None and seq >= kernels.TOKENS
+            and kernels.supported(heads, groups, d_head, d_state, dtype)
+            and placement.kernel(sharded=True)):
+        return "kernel"
+    return "chunked"
 
 
 def ssd_scan(x, dt, a, b, c, d, *, groups=1, chunk=SSD_CHUNK,
@@ -792,16 +819,29 @@ def ssd_scan(x, dt, a, b, c, d, *, groups=1, chunk=SSD_CHUNK,
     or as a stream [B, T, H P] (H is dt's), y in the rank given; dt [B, T,
     H] after its softplus, a [H], b and c [B, T, G, N] or as streams [B, T,
     G N] of ``groups`` groups, d [H]. The final state stays inside:
-    training starts every row from a zero state and keeps none. Counted in
-    ``paddle_tpu_ssd_core_total{path}``, one count a traced call."""
-    path = ssd_path(x.shape[1])
+    training starts every row from a zero state and keeps none. The path
+    is ``ssd_path``'s, taken here, outside the dispatched op (``interpret``
+    rides its static arguments, so a flag flipped or a mesh announced
+    retraces), and counted in ``paddle_tpu_ssd_core_total{path}``, one
+    count a traced call. ``chunk`` and ``segment`` are the XLA path's: the
+    kernels choose their own (``ops/pallas/ssd.py``'s constants)."""
+    heads = dt.shape[-1]
+    groups = int(groups) if b.ndim == 3 else b.shape[2]
+    path = ssd_path(
+        x.shape[1], heads, groups,
+        x.shape[-1] // (heads if x.ndim == 3 else 1),
+        b.shape[-1] // (groups if b.ndim == 3 else 1), x.dtype)
     _SSD_TOTAL.inc(path=path)
     if path == "recurrent":
         return apply_op("ssd_core_recurrent", _ssd_recurrent_output, x, dt,
-                        a, b, c, d, groups=int(groups))
+                        a, b, c, d, groups=groups)
+    if path == "kernel":
+        return apply_op(
+            "ssd_core_kernel", _ssd_kernel_output, x, dt, a, b, c, d,
+            groups=groups,
+            interpret=placement.kernel(sharded=True) == "interpret")
     return apply_op("ssd_core", _ssd_chunked_output, x, dt, a, b, c, d,
-                    groups=int(groups), chunk=int(chunk),
-                    segment=int(segment))
+                    groups=groups, chunk=int(chunk), segment=int(segment))
 
 
 def _ssd_on_heads(scan, x, dt, a, b, c, d, groups):
@@ -821,6 +861,35 @@ def _ssd_chunked_output(x, dt, a, b, c, d, *, groups, chunk, segment):
     return _ssd_on_heads(functools.partial(
         ssd_chunked, chunk=chunk, segment=segment), x, dt, a, b, c, d,
         groups)
+
+
+def _ssd_kernel_output(x, dt, a, b, c, d, *, groups, interpret):
+    """The Mosaic kernels on streams (heads [B, T, H, P] are viewed as
+    streams and y as x came: on a TPU a relayout, a layer that can hands
+    over streams), under a step's announced mesh inside
+    ``placement.on_mesh``'s ``shard_map``: rows over the data axes, THE
+    HEADS WHOLE on every device of an 'mp' axis — B and C are one group's
+    for all its heads, which ``on_mesh``'s single ``head_axis`` cannot
+    express. ``on_mesh`` shards dim 0 of every array, so the per-head A and
+    D go a copy a row ([B, H]) and their gradients are summed over the rows
+    outside."""
+    from .pallas import ssd as kernels
+
+    def kernel(x, dt, a, b, c, d):
+        return kernels.ssd(x, dt, a, b, c, d, groups=groups,
+                           interpret=interpret)
+
+    def stream(t):
+        return t.reshape(*t.shape[:2], -1)
+
+    def rows(t):
+        return jnp.broadcast_to(t.astype(jnp.float32)[None],
+                                (x.shape[0],) + t.shape)
+
+    y = placement.on_mesh(
+        kernel, (stream(x), dt, rows(a), stream(b), stream(c), rows(d)),
+        head_axis=None)
+    return y.reshape(x.shape)
 
 
 # ------------------------------------------------- the convolution stage

@@ -36,7 +36,11 @@ RMSNorm, RoPE and the head split, decided by ``qk_kernel`` in the layers of
 ``text/models.py`` that call ``qk_heads``: Trinity's, Qwen3-Next's gated
 attention, LFM2's — the last always ``xla``, heads of 64), the delta rule's
 scan and the convolution stage before it (``ops.linear_attention.core_path``
-/ ``conv_path``) and LFM2's gated short convolution (``shortconv_path``,
+/ ``conv_path``), the selective state-space scan (``ssd_path``, asked in
+``ssd_scan`` outside its dispatched op: rows over the data axes, the heads
+whole on an 'mp' axis — B and C are one group's for all its heads —, the
+per-head A and D a copy a row because ``on_mesh`` shards dim 0 of every
+array) and LFM2's gated short convolution (``shortconv_path``,
 asked inside ``gated_short_conv``: the layer's call carries two arrays and
 nothing else; rows over the data axes, whole on an 'mp' axis), all sharded
 through ``on_mesh``; and the expert layer's grouped matmul

@@ -2115,8 +2115,14 @@ class Mamba2Mixer(nn.Layer):
     ``A = -exp(A_log)`` a head;
     the state-space scan ``S_t = exp(dt_t A) S_{t-1} + B_t (dt_t x_t)^T``,
     ``y_t = S_t^T C_t + D x_t`` (``ops.linear_attention.ssd_scan``: the
-    scalar-decay scan with no delta correction, counted in
-    ``paddle_tpu_ssd_core_total{path}``); then THE GATE BEFORE THE NORM,
+    scalar-decay scan with no delta correction, on the streams as the
+    convolution stage leaves them — the Mosaic kernels ``ssd_chunk_fwd`` /
+    ``ssd_chunk_bwd`` where ``ssd_path`` lets a program hold them (bf16
+    operands, a state of whole lane groups, values that divide the 128
+    lanes, a row of one token block: a train step of the published widths
+    on a TPU), else the chunked XLA scan, ``chunk`` and ``segment`` its
+    alone; counted in ``paddle_tpu_ssd_core_total{path}``); then THE GATE
+    BEFORE THE NORM,
     ``out_proj(w * RMSNorm_inner(y * silu(z)))`` — one mean square over all
     ``inner`` features. Convolution, bias, SiLU, the step, the scan's decay
     sums, mask and state, and the gated norm are float32 under amp O1; the

@@ -216,30 +216,150 @@ def test_the_scan_is_not_the_delta_rule_with_nothing_written():
         jnp.abs(y).max())
 
 
-@pytest.mark.parametrize("seq,path", [(8, "recurrent"), (40, "chunked")])
-def test_the_entry_point_picks_by_length_and_counts(seq, path):
+@contextlib.contextmanager
+def interpreter(on=True):
+    """The Pallas interpreter selected (it also selects the kernels off a
+    TPU), or not."""
+    paddle.set_flags({"pallas_interpret": bool(on)})
+    try:
+        yield
+    finally:
+        paddle.set_flags({"pallas_interpret": False})
+
+
+#: seq, heads, d_head, groups, d_state, dtype, the interpreter flag, path —
+#: ``ssd_path`` goes by what it can observe: the kernels where the flag (off
+#: a TPU) lets a program hold them, the state fills whole lane groups, the
+#: values divide or are a multiple of 128 lanes, the operands are bf16 and
+#: the row is a token block or more
+ENTRIES = {
+    "short": (8, 4, 8, 2, 16, jnp.float32, False, "recurrent"),
+    "toy-widths": (40, 4, 8, 2, 16, jnp.float32, False, "chunked"),
+    "toy-widths-interpreter": (40, 4, 8, 2, 16, jnp.bfloat16, True,
+                               "chunked"),
+    "cell-widths-no-flag": (256, 8, 64, 1, 128, jnp.bfloat16, False,
+                            "chunked"),
+    "cell-widths": (256, 8, 64, 1, 128, jnp.bfloat16, True, "kernel"),
+    "cell-widths-two-groups": (300, 16, 64, 2, 128, jnp.bfloat16, True,
+                               "kernel"),
+    "values-of-128": (256, 8, 128, 1, 128, jnp.bfloat16, True, "kernel"),
+    "float32": (256, 8, 64, 1, 128, jnp.float32, True, "chunked"),
+    "under-a-token-block": (255, 8, 64, 1, 128, jnp.bfloat16, True,
+                            "chunked"),
+    "short-at-cell-widths": (8, 8, 64, 1, 128, jnp.bfloat16, True,
+                             "recurrent"),
+    "half-a-lane-group-of-state": (256, 8, 64, 1, 64, jnp.bfloat16, True,
+                                   "chunked"),
+    "values-across-lane-groups": (256, 8, 96, 1, 128, jnp.bfloat16, True,
+                                  "chunked"),
+}
+
+
+@pytest.mark.parametrize("case", ENTRIES)
+def test_the_entry_point_picks_by_length_and_counts(case):
     """``ssd_scan`` on Tensors: streams [B, T, H P] and [B, T, G N] in, a
     stream out, the same numbers as heads in and out; the path from the
-    length, one count a call."""
-    t = _scan_inputs(2, seq, 4, 8, 2, 16, seed=seq)
+    length, the widths, the operand dtype and what ``ops.placement`` lets
+    the program hold (never a flag or an argument of its own), one count a
+    call and none under another label."""
+    seq, heads, d_head, groups, d_state, dtype, flag, path = ENTRIES[case]
+    t = _scan_inputs(2, seq, heads, d_head, groups, d_state, seed=seq)
+    t = dict(t, **{n: t[n].astype(dtype) for n in "xbc"})
     names = ("x", "dt", "a", "b", "c", "d")
-    before = linear_attention._SSD_TOTAL.value(path=path)
-    heads = linear_attention.ssd_scan(
-        *(paddle.to_tensor(np.asarray(t[n])) for n in names), chunk=16,
-        segment=32)
-    flat = dict(t, x=t["x"].reshape(2, seq, 32), b=t["b"].reshape(2, seq, 32),
-                c=t["c"].reshape(2, seq, 32))
-    streams = linear_attention.ssd_scan(
-        *(paddle.to_tensor(np.asarray(flat[n])) for n in names), groups=2,
-        chunk=16, segment=32)
-    assert linear_attention._SSD_TOTAL.value(path=path) - before == 2
-    assert tuple(streams.shape) == (2, seq, 32)
+    labels = ("kernel", "chunked", "recurrent")
+    before = {p: linear_attention._SSD_TOTAL.value(path=p) for p in labels}
+    flat = dict(t, x=t["x"].reshape(2, seq, -1), b=t["b"].reshape(2, seq, -1),
+                c=t["c"].reshape(2, seq, -1))
+    with interpreter(flag):
+        assert linear_attention.ssd_path(seq, heads, groups, d_head, d_state,
+                                         dtype) == path
+        heads_out = linear_attention.ssd_scan(
+            *(paddle.to_tensor(np.asarray(t[n])) for n in names), chunk=16,
+            segment=32)
+        streams = linear_attention.ssd_scan(
+            *(paddle.to_tensor(np.asarray(flat[n])) for n in names),
+            groups=groups, chunk=16, segment=32)
+    for p in labels:
+        assert linear_attention._SSD_TOTAL.value(path=p) - before[p] == (
+            2 * (p == path)), p
+    assert tuple(streams.shape) == (2, seq, heads * d_head)
+    assert streams._value.dtype == dtype
     np.testing.assert_array_equal(
-        np.asarray(streams._value).reshape(2, seq, 4, 8),
-        np.asarray(heads._value))
-    want = linear_attention.ssd_recurrent(*(t[n] for n in names))[0]
-    np.testing.assert_allclose(heads._value, want, rtol=1e-4, atol=1e-5)
+        np.asarray(streams._value.astype(jnp.float32)).reshape(
+            2, seq, heads, d_head),
+        np.asarray(heads_out._value.astype(jnp.float32)))
+    want = linear_attention.ssd_recurrent(*(t[n].astype(jnp.float32)
+                                            for n in names))[0]
+    assert float(jnp.abs(heads_out._value.astype(jnp.float32)
+                         - want).max()) <= (
+        RTOL if dtype == jnp.float32 else AMP_RTOL) * float(
+            jnp.abs(want).max())
     assert linear_attention.ssd_path(linear_attention.SUB) == "chunked"
+
+
+def test_the_kernel_path_needs_a_platform_and_known_devices(monkeypatch):
+    """No TPU and no interpreter flag: the XLA scan. A platform that
+    compiles the kernels but a program whose devices are not known (a plain
+    jit on several devices): the XLA scan again. ``use_pallas_kernels`` off:
+    the XLA scan whatever else holds."""
+    from paddle_tpu.ops import placement
+
+    shape = (8192, 64, 1, 64, 128, jnp.bfloat16)
+    assert linear_attention.ssd_path(*shape) == "chunked"
+    monkeypatch.setattr(placement, "is_tpu_available", lambda: True)
+    assert jax.device_count() > 1                       # conftest's mesh
+    assert linear_attention.ssd_path(*shape) == "chunked"
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert linear_attention.ssd_path(*shape) == "kernel"
+    paddle.set_flags({"use_pallas_kernels": False})
+    try:
+        assert linear_attention.ssd_path(*shape) == "chunked"
+    finally:
+        paddle.set_flags({"use_pallas_kernels": True})
+
+
+def test_the_kernel_path_keeps_the_heads_whole_over_an_announced_mesh():
+    """Inside a step traced for a dp2 x mp2 mesh (``topology.tracing_for``)
+    the path is still ``kernel`` and the call runs under ``placement
+    .on_mesh``'s ``shard_map``: rows over the data axis, the heads WHOLE on
+    both devices of 'mp' (B and C are one group's for all heads: no spec
+    names 'mp'), A and D a copy a row; the result and the gradients of A
+    and D are the one-device ones."""
+    t = _scan_inputs(2, 256, 8, 64, 1, 128, seed=21)
+    args = tuple(t[n].astype(jnp.bfloat16) if n in "xbc" else t[n]
+                 for n in ("x", "dt", "a", "b", "c", "d"))
+    args = (args[0].reshape(2, 256, -1), args[1], args[2],
+            args[3].reshape(2, 256, -1), args[4].reshape(2, 256, -1), args[5])
+    mesh = topology.build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+
+    def scan(*a):
+        return linear_attention._ssd_kernel_output(*a, groups=1,
+                                                   interpret=True)
+
+    def step(*a):
+        with topology.tracing_for(mesh):
+            assert linear_attention.ssd_path(
+                256, 8, 1, 64, 128, jnp.bfloat16) == "kernel"
+            return scan(*a)
+
+    def grads(fn):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(
+            fn(*a).astype(jnp.float32) ** 2), argnums=(2, 5)))(*args)
+
+    with interpreter():
+        text = jax.jit(step).lower(*args).as_text()
+        assert "shard_map" in text or "manual" in text
+        jaxpr = str(jax.make_jaxpr(step)(*args))
+        assert "shard_map" in jaxpr and "'mp'" not in jaxpr.split(
+            "shard_map")[1].split("jaxpr=")[0]
+        got, want = jax.jit(step)(*args), scan(*args)
+        assert got.shape == (2, 256, 512) and got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                                   np.asarray(want.astype(jnp.float32)),
+                                   rtol=1e-6, atol=1e-6)
+        for g, w in zip(grads(step), grads(scan)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-5, atol=1e-5)
 
 
 def test_group_sizes_that_do_not_divide_are_refused():
@@ -563,6 +683,60 @@ def test_recomputation_gives_the_same_loss_and_gradients(ids):
         scale = float(jnp.abs(grads_a[name]).max())
         assert float(jnp.abs(grads_a[name] - grads_b[name]).max()) <= (
             1e-5 * scale), name
+
+
+def test_the_scan_kernels_carry_a_step_under_the_interpreter():
+    """The model at the cell's scan widths (8 heads of 64 on a state of
+    128, a row of 256 tokens) under amp O1: with the interpreter flag the
+    two state-space layers take the kernels — ``paddle_tpu_ssd_core_total
+    {path="kernel"}`` counts once a traced call site, ``chunked`` nothing —
+    and loss and gradients are the ``chunked`` path's within a bf16
+    rounding; under per-block ``recompute`` the kernels give the same loss
+    and gradients again, still one count a site, and the program holds
+    both calls in their inner jits under ``mamba.core``."""
+    sizes = dict(mamba_n_heads=8, mamba_d_head=64, mamba_d_state=128)
+    plain = build(use_recompute=False, **sizes)
+    remat = build(use_recompute=True, **sizes)
+    params = plain.functional_state()[0]
+    ids = jnp.asarray(np.random.default_rng(11).integers(
+        0, SIZES["vocab_size"], (1, 256)), jnp.int32)
+    labels = ("kernel", "chunked", "recurrent")
+
+    def run(net, flag):
+        before = {p: linear_attention._SSD_TOTAL.value(path=p)
+                  for p in labels}
+        with interpreter(flag):
+            step = jax.jit(jax.value_and_grad(
+                lambda p: framework_terms(net, p, ids, amp=True)[1]))
+            text = step.lower(params).as_text(debug_info=True)
+            loss, grads = step(params)
+        counted = {p: linear_attention._SSD_TOTAL.value(path=p) - before[p]
+                   for p in labels}
+        return loss, grads, counted, text
+
+    loss_x, grads_x, counted, text = run(plain, False)
+    # two state-space layers, each traced once
+    assert counted == {"kernel": 0, "chunked": 2, "recurrent": 0}
+    assert "ssd_chunk_fwd" not in text
+    loss_k, grads_k, counted, text = run(plain, True)
+    assert counted == {"kernel": 2, "chunked": 0, "recurrent": 0}
+    for jitted, name in (("_forward", "ssd_chunk_fwd"),
+                         ("_backward", "ssd_chunk_bwd")):
+        assert f"mamba.core/jit({jitted})" in text, jitted
+        assert f"{name}/pallas_call" in text, name
+    assert float(loss_k) == pytest.approx(float(loss_x), rel=3e-3)
+    for name in grads_x:
+        scale = float(jnp.abs(grads_x[name]).max())
+        assert float(jnp.abs(grads_k[name] - grads_x[name]).max()) <= (
+            AMP_RTOL * scale), name
+    loss_r, grads_r, counted, text = run(remat, True)
+    assert counted == {"kernel": 2, "chunked": 0, "recurrent": 0}
+    assert "rematted_computation" in text
+    assert float(loss_r) == pytest.approx(float(loss_k), rel=1e-5)
+    for name in grads_k:
+        scale = float(jnp.abs(grads_k[name]).max())
+        assert float(jnp.abs(grads_r[name] - grads_k[name]).max()) <= (
+            1e-3 * scale), name
 
 
 def test_a_traced_step_counts_once_a_call_site_and_carries_the_scopes(

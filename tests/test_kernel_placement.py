@@ -3,8 +3,10 @@ are the program's devices known". Its docstring's table as one parametrised
 test (platform and device count patched, meshes from the conftest's CPU
 devices), ``axis_size`` and ``on_mesh``'s optional seed, the expert layer's
 call site (``sharded=False``: the grouped matmul has no shard_map), the
-convolution stage's decision taken outside its dispatched op, and the gated
-short convolution's table (``shortconv_path``)."""
+convolution stage's decision taken outside its dispatched op, the gated
+short convolution's table (``shortconv_path``) and the selective state-space
+scan's (``ssd_path``: the table's ``sharded`` column at the granite cell's
+widths)."""
 import contextlib
 
 import jax
@@ -272,3 +274,43 @@ def test_the_gated_short_convolution_goes_by_what_it_observes(
     for p, n in before.items():
         assert linear_attention._SHORTCONV_TOTAL.value(path=p) == n + (
             p == path), p
+
+
+#: the granite cell's scan: 8,192 tokens, 64 heads of 64 in one group on a
+#: state of 128, bf16 under amp O1
+SCAN = (8192, 64, 1, 64, 128, jnp.bfloat16)
+SSD = [
+    ("the-cell-on-one-chip", dict(), SCAN, "kernel"),
+    ("announced-mesh", dict(mesh=4, devices=8), SCAN, "kernel"),
+    ("interpreter", dict(interpret=True, tpu=False, devices=8), SCAN,
+     "kernel"),
+    ("flag-off", dict(selected=False), SCAN, "chunked"),
+    ("no-tpu", dict(tpu=False), SCAN, "chunked"),
+    ("plain-jit-many-devices", dict(devices=8), SCAN, "chunked"),
+    ("the-check-in-float32", dict(), SCAN[:5] + (jnp.float32,), "chunked"),
+    ("eight-groups", dict(), (8192, 64, 8, 64, 128, jnp.bfloat16), "kernel"),
+    ("values-of-128-on-a-state-of-256", dict(),
+     (8192, 32, 1, 128, 256, jnp.bfloat16), "kernel"),
+    ("a-row-under-one-block", dict(), (255,) + SCAN[1:], "chunked"),
+    ("a-row-under-one-sub-block", dict(), (15,) + SCAN[1:], "recurrent"),
+    ("half-a-lane-group-of-state", dict(),
+     (8192, 64, 1, 64, 64, jnp.bfloat16), "chunked"),
+    ("values-across-lane-groups", dict(),
+     (8192, 64, 1, 96, 128, jnp.bfloat16), "chunked"),
+    ("four-heads-a-group", dict(), (8192, 64, 16, 64, 128, jnp.bfloat16),
+     "chunked"),
+    ("the-length-alone", dict(), (8192,), "chunked"),
+]
+
+
+@pytest.mark.parametrize("how, scan, path", [row[1:] for row in SSD],
+                         ids=[row[0] for row in SSD])
+def test_the_state_space_scan_goes_by_what_it_observes(monkeypatch, how,
+                                                       scan, path):
+    """``ssd_path``: the Mosaic kernels where ``placement`` lets a sharded
+    site hold them and the shape fits (a state of whole lane groups, values
+    that divide or are a multiple of the 128 lanes, a head cut, bf16, a
+    token block), the XLA scan everything else, the recurrence under 16
+    tokens — no flag, argument or name of its own."""
+    with observed(monkeypatch, **how):
+        assert linear_attention.ssd_path(*scan) == path

@@ -385,11 +385,13 @@ def test_the_kernel_microbenchmark_measures_on_a_tpu_only(mode):
     assert "fwd_ms" not in done.stdout
 
 
-@pytest.mark.parametrize("mode", [[], ["--stage", "conv"]])
+@pytest.mark.parametrize("mode", [[], ["--stage", "conv"], ["--path"]])
 def test_the_scan_microbenchmark_measures_on_a_tpu_only(mode):
     """``tools/ssd_bench.py`` — the state-space scan by chunk and segment,
-    and with ``--stage conv`` the biased convolution stage on both of its
-    paths — exits 2 where there is no TPU, as the delta rule's does."""
+    with ``--stage conv`` the biased convolution stage on both of its
+    paths, and with ``--path`` the scan on both of its own (the Mosaic
+    kernels against the XLA scan) — exits 2 where there is no TPU, as the
+    delta rule's does."""
     import os
     import subprocess
     import sys

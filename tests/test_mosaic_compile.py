@@ -157,6 +157,10 @@ LAYER_CASES = {
 MAMBA_CASES = {
     "mamba-granite-cell": (1, 8192, 64, 64, 128),
 }
+#: the selective state-space scan at the same shape, forward + VJP on the
+#: streams, through its own gate: the names its two Mosaic calls carry in a
+#: trace, under ``mamba.core``
+SSD_CALLS = ("ssd_chunk_fwd", "ssd_chunk_bwd")
 #: the layer's stage between the projections and the scan alone, the
 #: names its two Mosaic calls carry (the instructions': the calls sit in
 #: inner jits, under the ``kda.conv`` / ``gdn.conv`` scopes in a trace)
@@ -489,6 +493,7 @@ def _child():
             "transposes": len(re.findall(r" (transpose|copy)\(", text)),
             "temp_gb": compiled.memory_analysis().temp_size_in_bytes / 1e9}
 
+    from paddle_tpu.ops import linear_attention
     from paddle_tpu.text import models
 
     for name, (batch, seq, key_heads, heads, d) in LAYER_CASES.items():
@@ -608,6 +613,33 @@ def _child():
                 conv_text[conv_text.index("\nENTRY "):])),
             "temp_gb": conv.memory_analysis().temp_size_in_bytes / 1e9}
 
+        def scan_alone(*args):
+            out, vjp = jax.vjp(functools.partial(
+                linear_attention._ssd_kernel_output, groups=1,
+                interpret=False), *args[:-1])
+            return out, vjp(args[-1])
+
+        # the gate itself: a TPU's platform (told above) and a mesh of one
+        with topology.tracing_for(one_mesh):
+            path = linear_attention.ssd_path(seq, heads, 1, d, state,
+                                             jnp.bfloat16)
+            scan = jax.jit(scan_alone).lower(
+                like(batch, seq, heads * d),
+                like(batch, seq, heads, dtype=jnp.float32),
+                like(heads, dtype=jnp.float32), like(batch, seq, state),
+                like(batch, seq, state), like(heads, dtype=jnp.float32),
+                like(batch, seq, heads * d)).compile()
+        scan_text = scan.as_text()
+        out["ssd-" + name] = {
+            "path": path, "mosaic": scan_text.count(MOSAIC),
+            "calls": [c for c in SSD_CALLS if f"%{c}" in scan_text],
+            "loops": scan_text.count(" while("),
+            # the head view in HBM, in either order, in any dtype
+            "head_views": len(re.findall(
+                rf"\[{batch},{seq},{heads},{d}\]|\[{batch},{heads},{seq},{d}\]",
+                scan_text)),
+            "temp_gb": scan.memory_analysis().temp_size_in_bytes / 1e9}
+
     for name, (batch, seq, heads, kv_heads, d, rotary_dim) in (
             QK_CASES.items()):
         def like(*shape, dtype=jnp.bfloat16):
@@ -655,7 +687,6 @@ def _child():
                 rf"|f32\[{batch},{seq},{heads * d}\]",
                 text[text.index("\nENTRY "):]))}
 
-    from paddle_tpu.ops import linear_attention
     from paddle_tpu.text.models import Lfm2ShortConv
 
     for name, (batch, seq, channels, taps, dtype) in SHORTCONV_CASES.items():
@@ -1039,6 +1070,25 @@ def test_the_gated_short_convolution_keeps_its_float32_in_vmem(compiled,
         assert got["layer_mosaic"] == 2
         assert got["layer_calls"] == list(GATED_CALLS)
         assert got["layer_f32"] == got["layer_f32_under_stage"] == 0
+
+
+@pytest.mark.parametrize("case", list(MAMBA_CASES))
+def test_state_space_scan_kernels_compile(compiled, case):
+    """The selective state-space scan alone through its own gate, forward +
+    VJP on the streams at the granite cell's shape in bf16 (1 x 8,192 x 64
+    heads of 64 on a state of 128, one group): ``ssd_path`` says ``kernel``,
+    both kernels compile for the described v5e within the VMEM they ask for,
+    one Mosaic call a pass, no loop left to XLA, the head view [.., H, P]
+    nowhere in HBM, and what the program holds beside its arguments and
+    results is the kept entering states (8,192 / 128 chunks x 64 heads x 32
+    KB = 134 MB) and the float32 partial sums of dB and dC."""
+    got = compiled["ssd-" + case]
+    assert got["path"] == "kernel"
+    assert got["mosaic"] == 2 and got["calls"] == list(SSD_CALLS)
+    assert got["loops"] == 0 and got["head_views"] == 0
+    batch, seq, heads, d, state = MAMBA_CASES[case]
+    kept = 4 * batch * seq // 128 * heads * state * d / 1e9
+    assert got["temp_gb"] <= kept + 0.1, (got, kept)
 
 
 @pytest.mark.parametrize("case", list(KDA_CASES))

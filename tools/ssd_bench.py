@@ -27,9 +27,24 @@ stage_bytes``), and how far the kernels' results are from the XLA stage's.
 
     chiprun -- python3 tools/ssd_bench.py --stage conv
 
+``--path`` times the scan on BOTH of its paths in one call, streams in and
+streams out as the layer hands them over (x and y [1, 8192, 4096], B and C
+[1, 8192, 128]: the XLA path's relayouts to its head view are part of it) —
+the Mosaic kernels ``ssd_chunk_fwd`` / ``_bwd`` (``ops/pallas/ssd.py``) by
+chunk, token block and lanes a program, and ``ssd_chunked`` at the
+configuration's 256 x 2048: ms forward and forward + backward, the share of
+``ssd_core_roofline``'s bound (the algorithm's work at the configuration's
+chunk of 256, whatever the kernels' own), and the largest difference between
+the two paths' results (y and the six gradients, relative to the XLA path's
+largest). It is what the kernels' ``CHUNK`` / ``TOKENS`` / ``LANES`` were
+read from (PERF.md section 6, PR 50).
+
+    chiprun -- python3 tools/ssd_bench.py --path [chunk x tokens x lanes,...]
+
 A microbenchmark's numbers are findings for PERF.md, never a metric of the
 benchmark. Exits 2 without a TPU.
 """
+import functools
 import importlib.util
 import os
 import sys
@@ -105,21 +120,12 @@ def conv_stage(clock):
     return 0
 
 
-def main():
+def scan_inputs():
+    """The cell's scan operands as heads — x [B, T, H, P] bf16, dt, a, b, c
+    [B, T, G, N] bf16, d — a cotangent for y, and the roofline's shape."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.ops import linear_attention
-
-    if jax.devices()[0].platform != "tpu":
-        print("ssd_bench.py times the chip: no TPU", file=sys.stderr)
-        return 2
-    clock = _load("benchmark", "tools", "kda_candidates.py")
-    if sys.argv[1:3] == ["--stage", "conv"]:
-        return conv_stage(clock)
-    roofline = _load("benchmark", "layer_metrics", "ssd_core_roofline.py")
-    peaks = _load("benchmark", "harness", "peaks.py").PEAKS[
-        jax.devices()[0].device_kind]
     keys = jax.random.split(jax.random.PRNGKey(47), 6)
     bf16 = jnp.bfloat16
     x = jax.random.normal(keys[0], (BATCH, SEQ, HEADS, D_HEAD), bf16)
@@ -131,7 +137,107 @@ def main():
             for k in keys[3:5])
     d = jnp.ones((HEADS,))
     dy = jax.random.normal(keys[5], x.shape, bf16)
-    shape = (BATCH * SEQ, HEADS, D_HEAD, D_STATE, GROUPS)
+    return (x, dt, a, b, c, d), dy, (BATCH * SEQ, HEADS, D_HEAD, D_STATE,
+                                     GROUPS)
+
+
+@functools.lru_cache(maxsize=None)
+def _yardstick():
+    """(``ssd_core_roofline``'s module, the chip's peaks)."""
+    import jax
+
+    return (_load("benchmark", "layer_metrics", "ssd_core_roofline.py"),
+            _load("benchmark", "harness", "peaks.py").PEAKS[
+                jax.devices()[0].device_kind])
+
+
+def least_ms(shape, chunk=256):
+    """``ssd_core_roofline``'s bound for one forward and one backward."""
+    roofline, peaks = _yardstick()
+    return 1e3 * max(
+        roofline.ssd_core_flops(*shape, chunk, 1, 1)
+        / peaks["bf16_flops_per_s"],
+        roofline.ssd_core_bytes(*shape, 1, 1) / peaks["hbm_bytes_per_s"])
+
+
+def both_paths(clock, candidates):
+    """A line a candidate of the kernels, and the XLA path they are held
+    to, all on streams."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import linear_attention
+    from paddle_tpu.ops.pallas import ssd as kernels
+
+    (x, dt, a, b, c, d), dy, shape = scan_inputs()
+    flat = (x.reshape(BATCH, SEQ, -1), dt, a, b.reshape(BATCH, SEQ, -1),
+            c.reshape(BATCH, SEQ, -1), d)
+    dy = dy.reshape(flat[0].shape)
+    bound = least_ms(shape)
+    clock.line(device=jax.devices()[0].device_kind, stage="scan",
+               batch=BATCH, seq=SEQ, heads=HEADS, d_head=D_HEAD,
+               d_state=D_STATE, groups=GROUPS, least_ms=round(bound, 3))
+
+    def xla(*args):
+        return linear_attention._ssd_chunked_output(
+            *args, groups=GROUPS, chunk=256, segment=2048)
+
+    def measured(scan):
+        def both(*args):
+            out, vjp = jax.vjp(scan, *args[:-1])
+            return out, vjp(args[-1])
+
+        both = jax.jit(both)
+        f = clock.timed(jax.jit(scan), *flat, reps=20)
+        fb = clock.timed(both, *flat, dy, reps=20)
+        return f, fb, jax.tree.leaves(both(*flat, dy))
+
+    def far(got, want):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        return float(f"{jnp.abs(got - want).max() / jnp.abs(want).max():.3g}")
+
+    f, fb, want = measured(xla)
+    clock.line(path="chunked", chunk=256, segment=2048, fwd_ms=round(f, 3),
+               fwd_bwd_ms=round(fb, 3), roofline_pct=round(100 * bound / fb,
+                                                           2))
+    for cand in candidates.split(","):
+        chunk, tokens, lanes = (int(v) for v in cand.split("x"))
+
+        def kernel(*args):
+            return kernels.ssd(*args, groups=GROUPS, chunk=chunk,
+                               tokens=tokens, lanes=lanes)
+
+        try:
+            f, fb, got = measured(kernel)
+        except Exception as e:          # a candidate that does not fit
+            clock.line(path="kernel", chunk=chunk, tokens=tokens,
+                       lanes=lanes, error=str(e)[:300])
+            continue
+        clock.line(
+            path="kernel", chunk=chunk, tokens=tokens, lanes=lanes,
+            fwd_ms=round(f, 3), fwd_bwd_ms=round(fb, 3),
+            roofline_pct=round(100 * bound / fb, 2),
+            apart_from_chunked={tag: far(g, w) for tag, g, w in zip(
+                "y dx ddt da db dc dd".split(), got, want)})
+    return 0
+
+
+def main():
+    import jax
+
+    from paddle_tpu.ops import linear_attention
+    from paddle_tpu.ops.pallas import ssd as kernels
+
+    if jax.devices()[0].platform != "tpu":
+        print("ssd_bench.py times the chip: no TPU", file=sys.stderr)
+        return 2
+    clock = _load("benchmark", "tools", "kda_candidates.py")
+    if sys.argv[1:3] == ["--stage", "conv"]:
+        return conv_stage(clock)
+    if sys.argv[1:2] == ["--path"]:
+        return both_paths(clock, sys.argv[2] if len(sys.argv) > 2 else
+                          f"{kernels.CHUNK}x{kernels.TOKENS}x{kernels.LANES}")
+    args, dy, shape = scan_inputs()
 
     for pair in (sys.argv[1] if len(sys.argv) > 1 else DEFAULT).split(","):
         chunk, segment = (int(v) for v in pair.split("x"))
@@ -145,18 +251,14 @@ def main():
             return out, vjp(dy)
 
         try:
-            f = clock.timed(jax.jit(scan), x, dt, a, b, c, d)
-            fb = clock.timed(jax.jit(both), x, dt, a, b, c, d, dy)
+            f = clock.timed(jax.jit(scan), *args)
+            fb = clock.timed(jax.jit(both), *args, dy)
         except Exception as e:          # a candidate that does not fit
             clock.line(chunk=chunk, segment=segment, error=str(e)[:300])
             continue
-        least_ms = 1e3 * max(
-            roofline.ssd_core_flops(*shape, chunk, 1, 1)
-            / peaks["bf16_flops_per_s"],
-            roofline.ssd_core_bytes(*shape, 1, 1) / peaks["hbm_bytes_per_s"])
         clock.line(chunk=chunk, segment=segment, fwd_ms=round(f, 3),
                    fwd_bwd_ms=round(fb, 3), bwd_ms=round(fb - f, 3),
-                   roofline_pct=round(100 * least_ms / fb, 2))
+                   roofline_pct=round(100 * least_ms(shape, chunk) / fb, 2))
     return 0
 
 
