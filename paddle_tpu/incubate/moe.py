@@ -63,21 +63,30 @@ experts and whether the program's mesh shards them:
   traffic.
 
 The layer's arithmetic is the constructor's: ``activation`` ('gelu': two
-matrices; 'swiglu': ``w_down(silu(x w_gate) * (x w_up))``), ``gate_bias``,
+matrices; 'relu2': two matrices, ``w_down(relu(x w_up)^2)``, Nemotron-H's;
+'swiglu': ``w_down(silu(x w_gate) * (x w_up))``), ``latent_size``
+(LatentMoE, Nemotron 3: the routed experts live in a latent space — one
+shared ``latent_down`` hidden -> latent before the dispatch, experts latent
+-> ffn -> latent, one shared ``latent_up`` after the combine; the router
+and the shared expert stay on the hidden-wide stream; scopes
+``latentmoe.down`` / ``latentmoe.up``), ``gate_bias``,
 ``norm_topk_prob`` (renormalise the k weights or keep the router's own),
 ``scoring`` ('softmax'; 'sigmoid' with ``select_bias``, a buffer added to
 the scores for the CHOICE only and moved by ``bias_update_speed`` against
 each training step's loads — DeepSeek-V3's auxiliary-loss-free balancing —
-and ``routed_scale``), ``shared_width`` (a SwiGLU every token takes, added
-to the routed sum — with ``shared_gate`` times ``sigmoid(x w_s)`` a token,
-Qwen3-Next's; sigmoid scoring and the shared expert run on the two
-sorted paths), and two auxiliary losses through ``nn.aux_loss.emit_aux_loss``: load balancing
+and ``routed_scale``), ``shared_width`` (an expert every token takes, added
+to the routed sum: relu² on two matrices where the routed experts are
+'relu2', else a SwiGLU — with ``shared_gate`` times ``sigmoid(x w_s)`` a token,
+Qwen3-Next's; sigmoid scoring, the shared expert, relu² and the latent
+space run on the two sorted paths), and two auxiliary losses through ``nn.aux_loss.emit_aux_loss``: load balancing
 times ``aux_weight`` and the router z-loss ``mean_t logsumexp(r_t)^2`` times
 ``z_loss_weight``. The sorted path's load-balancing term is the
 Switch / HF form over all k choices, ``E * sum_e (n_e / N) * mean_t
 p[t, e]`` (n_e = assignments to e); dense divides it by k; capacity and
 alltoall use GShard's top-1 fraction. Which path a call took is counted in
-``paddle_tpu_moe_dispatch_total{path}`` (trace time: one a layer call).
+``paddle_tpu_moe_dispatch_total{path}``, which arithmetic in
+``paddle_tpu_moe_layer_total{activation, latent}`` (trace time: one a layer
+call).
 """
 import numpy as np
 import jax
@@ -99,6 +108,13 @@ _DISPATCH_TOTAL = obs_metrics.counter(
     "| dense | alltoall); under jit one count per traced layer call",
     labelnames=("path",))
 
+_LAYER_TOTAL = obs_metrics.counter(
+    "paddle_tpu_moe_layer_total",
+    "expert-layer calls by the experts' activation (gelu | relu2 | swiglu) "
+    "and the width of the latent space they live in (0: the hidden "
+    "stream's own); under jit one count per traced layer call",
+    labelnames=("activation", "latent"))
+
 #: ``auto`` runs every expert on every token below this many experts
 _DENSE_BELOW = 8
 
@@ -112,6 +128,11 @@ def _expert_ffn(buf, w_gate, w_up, w_down, eq_up, eq_down):
     else:
         h = jax.nn.silu(jnp.einsum(eq_up, buf, w_gate)) * h
     return jnp.einsum(eq_down, h, w_down)
+
+
+def _relu2(x):
+    """Nemotron-H's activation: ``relu(x)^2``."""
+    return jnp.square(jax.nn.relu(x))
 
 
 def _z_loss(logits):
@@ -279,23 +300,28 @@ def _grouped_matmul(rows, w, group_sizes, kernel):
                               preferred_element_type=rows.dtype)
 
 
-def _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel):
+def _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel,
+                  activation="gelu"):
     """The experts on rows in expert order: two grouped matmuls with gelu
-    between, or three with SwiGLU (its product in float32)."""
+    or relu² (in float32) between, or three with SwiGLU (its product in
+    float32)."""
     with jax.named_scope("moe.experts"):
         def grouped(rows, w):
             return _grouped_matmul(rows, w, group_sizes, kernel)
 
         up = grouped(xs, w_up)
-        if w_gate is None:
-            mid = jax.nn.gelu(up)
-        else:
+        if w_gate is not None:
             mid = (jax.nn.silu(grouped(xs, w_gate).astype(jnp.float32))
                    * up.astype(jnp.float32)).astype(xs.dtype)
+        elif activation == "gelu":
+            mid = jax.nn.gelu(up)
+        else:
+            mid = _relu2(up.astype(jnp.float32)).astype(xs.dtype)
         return grouped(mid, w_down)
 
 
-def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel=None):
+def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel=None,
+                    activation="gelu"):
     """Dispatch and experts of the dropless path: [B, S, H] and the expert
     ids [N, k] -> every (token, choice) pair's expert output [N*k, H] in
     expert order, with the permutation and its inverse."""
@@ -309,7 +335,8 @@ def _sorted_experts(x, topi, w_gate, w_up, w_down, *, kernel=None):
         group_sizes = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32),
                               axis=0)
         xs = _rows_to_expert_order(x.reshape(n, x.shape[-1]), order, inv, k)
-    ys = _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel)
+    ys = _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel,
+                       activation)
     return ys, order, inv
 
 
@@ -509,7 +536,7 @@ def _held_places(topi, *, first, count, rows):
 
 
 def _held_experts(x, topi, w_gate, w_up, w_down, *, first, rows,
-                  kernel=None):
+                  kernel=None, activation="gelu"):
     """Dispatch and experts of a held share: [B, S, H] and the expert ids
     [N, k] over ALL experts -> the outputs [rows, H] of the pairs whose
     expert is one of the ``w_up.shape[0]`` held from ``first`` on, in
@@ -523,7 +550,8 @@ def _held_experts(x, topi, w_gate, w_up, w_down, *, first, rows,
         taken, inv, group_sizes, overflow = _held_places(
             topi, first=first, count=w_up.shape[0], rows=rows)
         xs = _rows_to_held_order(x.reshape(n, x.shape[-1]), taken, inv, k)
-    ys = _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel)
+    ys = _expert_gemms(xs, w_gate, w_up, w_down, group_sizes, kernel,
+                       activation)
     return ys, taken, inv, overflow
 
 
@@ -569,11 +597,13 @@ class MoELayer(nn.Layer):
     forward: [B, S, H] -> [B, S, H]. The router is a softmax over all
     experts; the k selected weights are renormalised to sum to one
     (Switch/GShard style) unless ``norm_topk_prob=False`` keeps the
-    softmax's own values (OLMoE). ``activation``: 'gelu' (two matrices)
-    or 'swiglu' (three). ``scoring='sigmoid'``, ``select_bias``,
+    softmax's own values (OLMoE). ``activation``: 'gelu' or 'relu2' (two
+    matrices) or 'swiglu' (three). ``scoring='sigmoid'``, ``select_bias``,
     ``bias_update_speed``, ``routed_scale`` and ``shared_width`` give the
     DeepSeek-V3 family's layer (``renorm_eps``: what its renormalisation
-    adds to the sum), ``held=(first, count)`` one chip's range of
+    adds to the sum), ``latent_size`` Nemotron 3's LatentMoE (the experts
+    ``latent_size`` wide in and out, between two shared projections),
+    ``held=(first, count)`` one chip's range of
     its experts (module docstring). The auxiliary losses (load balancing times
     ``aux_weight``, router z-loss times ``z_loss_weight``) are routed
     through ``nn.aux_loss.emit_aux_loss``: in eager mode they land on
@@ -591,7 +621,8 @@ class MoELayer(nn.Layer):
                  norm_topk_prob=True, z_loss_weight=0.0, weight_attr=None,
                  scoring="softmax", select_bias=False, bias_update_speed=0.0,
                  routed_scale=1.0, shared_width=0, held=None,
-                 held_rows_factor=2.0, shared_gate=False, renorm_eps=1e-20):
+                 held_rows_factor=2.0, shared_gate=False, renorm_eps=1e-20,
+                 latent_size=0):
         super().__init__()
         self.num_experts = int(num_experts)
         self.top_k = int(top_k)
@@ -601,9 +632,11 @@ class MoELayer(nn.Layer):
         if dispatch_mode not in ("auto", "dense", "capacity", "alltoall"):
             raise ValueError(f"dispatch_mode must be 'auto'/'dense'/"
                              f"'capacity'/'alltoall', got {dispatch_mode!r}")
-        if activation not in ("gelu", "swiglu"):
-            raise ValueError(f"activation must be 'gelu' or 'swiglu', got "
-                             f"{activation!r}")
+        if activation not in ("gelu", "relu2", "swiglu"):
+            raise ValueError(f"activation must be 'gelu', 'relu2' or "
+                             f"'swiglu', got {activation!r}")
+        self.activation = activation
+        self.latent_size = int(latent_size)
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got "
                              f"{scoring!r}")
@@ -626,7 +659,10 @@ class MoELayer(nn.Layer):
         self.gate = nn.Linear(hidden_size, num_experts,
                               weight_attr=weight_attr,
                               bias_attr=None if gate_bias else False)
-        k = 1.0 / np.sqrt(hidden_size)
+        # the experts' width in and out: the latent space's, else the
+        # router's own
+        width = self.latent_size or hidden_size
+        k = 1.0 / np.sqrt(width)
         k2 = 1.0 / np.sqrt(ffn_hidden)
 
         def stacked(shape, bound):
@@ -640,16 +676,28 @@ class MoELayer(nn.Layer):
                 w.mp_spec = P(shard_axis)
             return w
 
-        self.w_gate = (stacked([count, hidden_size, ffn_hidden], k)
+        self.w_gate = (stacked([count, width, ffn_hidden], k)
                        if activation == "swiglu" else None)
-        self.w_up = stacked([count, hidden_size, ffn_hidden], k)
-        self.w_down = stacked([count, ffn_hidden, hidden_size], k2)
+        self.w_up = stacked([count, width, ffn_hidden], k)
+        self.w_down = stacked([count, ffn_hidden, width], k2)
+        # LatentMoE's two projections, shared by all experts (and whole on
+        # every chip of a held deployment)
+        self.latent_down = self.latent_up = None
+        if self.latent_size:
+            self.latent_down = nn.Linear(hidden_size, width,
+                                         weight_attr=weight_attr,
+                                         bias_attr=False)
+            self.latent_up = nn.Linear(width, hidden_size,
+                                       weight_attr=weight_attr,
+                                       bias_attr=False)
         self.shared = None
         if shared_width:
-            # the expert every token takes (DeepSeek's shared expert)
-            from ..text.models import LlamaMLP
+            # the expert every token takes (DeepSeek's shared expert), on
+            # the hidden-wide stream: relu² beside relu² experts
+            from ..text.models import LlamaMLP, Relu2MLP
 
-            self.shared = LlamaMLP(hidden_size, shared_width, weight_attr)
+            mlp = Relu2MLP if activation == "relu2" else LlamaMLP
+            self.shared = mlp(hidden_size, shared_width, weight_attr)
         # Qwen3-Next's: the shared expert behind a gate of its own, a token
         self.shared_gate = (nn.Linear(hidden_size, 1, weight_attr=weight_attr,
                                       bias_attr=False)
@@ -688,9 +736,19 @@ class MoELayer(nn.Layer):
 
         mode = self.resolved_mode()
         _DISPATCH_TOTAL.inc(path=mode)
+        _LAYER_TOTAL.inc(activation=self.activation,
+                         latent=str(self.latent_size))
         if mode in ("sorted", "sorted_held"):
-            out, aux = self._forward_sorted(x)
+            # what the experts read: the stream itself, or its latent form
+            inner = x
+            if self.latent_size:
+                with jax.named_scope("latentmoe.down"):
+                    inner = self.latent_down(x)
+            out, aux = self._forward_sorted(x, inner)
             emit_aux_loss(self, aux)
+            if self.latent_size:
+                with jax.named_scope("latentmoe.up"):
+                    out = self.latent_up(out)
             if self.shared is None:
                 return out
             with jax.named_scope("moe.shared"):
@@ -698,11 +756,13 @@ class MoELayer(nn.Layer):
                 if self.shared_gate is not None:
                     shared = nn.functional.sigmoid(self.shared_gate(x)) * shared
                 return out + shared
-        if self.scoring != "softmax" or self.shared is not None:
+        if (self.scoring != "softmax" or self.shared is not None
+                or self.activation == "relu2" or self.latent_size):
             raise NotImplementedError(
-                f"the {mode} path has the softmax router and no shared "
-                "expert; sigmoid scoring and a shared expert run on the "
-                "sorted paths")
+                f"the {mode} path has the softmax router, gelu or SwiGLU "
+                "experts on the hidden-wide stream and no shared expert; "
+                "sigmoid scoring, a shared expert, relu² and a latent space "
+                "run on the sorted paths")
         logits = self.gate(x)  # [B, S, E]
 
         def _moe(x, logits, w_gate, w_up, w_down, *, top_k, renorm):
@@ -774,10 +834,12 @@ class MoELayer(nn.Layer):
         emit_aux_loss(self, aux)
         return out
 
-    def _forward_sorted(self, x):
+    def _forward_sorted(self, x, inner):
         """The dropless path (module docstring), as three ops so that amp
         O1 casts only the expert matmuls' operands: the router and the
-        weighted sum over a token's k choices stay in float32."""
+        weighted sum over a token's k choices stay in float32. The router
+        reads ``x``, the experts ``inner`` (x itself, or its latent form)
+        and the sum is as wide as ``inner``."""
         topv, topi, balance, z = apply_op(
             "moe_route", _route, x, self.gate.weight, self.gate.bias,
             self.e_score_correction_bias, top_k=self.top_k,
@@ -786,20 +848,22 @@ class MoELayer(nn.Layer):
         kernel = placement.kernel(sharded=False)   # no shard_map of its own
         if self.held is None:
             ys, order, inv = apply_op(
-                "moe_experts_sorted", _sorted_experts, x, topi, self.w_gate,
-                self.w_up, self.w_down, kernel=kernel)
+                "moe_experts_sorted", _sorted_experts, inner, topi,
+                self.w_gate, self.w_up, self.w_down, kernel=kernel,
+                activation=self.activation)
             out = apply_op("moe_combine", _combine, ys, topv, order, inv,
-                           shape=tuple(x.shape))
+                           shape=tuple(inner.shape))
         else:
             first, count = self.held
             tokens = int(np.prod(x.shape[:-1]))
             ys, taken, inv, overflow = apply_op(
-                "moe_experts_held", _held_experts, x, topi, self.w_gate,
+                "moe_experts_held", _held_experts, inner, topi, self.w_gate,
                 self.w_up, self.w_down, first=first, rows=held_rows(
                     tokens, self.top_k, count, self.num_experts,
-                    self.held_rows_factor), kernel=kernel)
+                    self.held_rows_factor), kernel=kernel,
+                activation=self.activation)
             out = apply_op("moe_combine", _combine, ys, topv, taken, inv,
-                           shape=tuple(x.shape), held=True)
+                           shape=tuple(inner.shape), held=True)
             if self.training:
                 self.held_overflow.set_value(
                     self.held_overflow._value + overflow._value)

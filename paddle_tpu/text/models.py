@@ -284,6 +284,24 @@ class LlamaMLP(nn.Layer):
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
+class Relu2MLP(nn.Layer):
+    """Nemotron-H's feed-forward part: ``down(relu(up(x))^2)``, two
+    matrices, no gate, no biases."""
+
+    def __init__(self, hidden_size, intermediate_size, weight_attr=None):
+        super().__init__()
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.up_proj = proj(hidden_size, intermediate_size)
+        self.down_proj = proj(intermediate_size, hidden_size)
+
+    def forward(self, x):
+        h = F.relu(self.up_proj(x))
+        return self.down_proj(h * h)
+
+
 class LlamaDecoderLayer(nn.Layer):
     def __init__(self, hidden_size, num_heads, intermediate_size, num_kv_heads=None):
         super().__init__()
@@ -651,7 +669,46 @@ class _BlockwiseModel(nn.Layer):
             self._tap = None
 
 
-class JoyAIFlashModel(_BlockwiseModel):
+class _MTPDecoder(_BlockwiseModel):
+    """What the decoders with multi-token-prediction modules share
+    (``embed_tokens``, ``layers``, ``norm``, an untied ``lm_head``, ``mtp``):
+    ``forward`` gives the main logits; a training loss should not hold them:
+    ``training_features`` gives the final-normed hidden states of the main
+    model and of every MTP module, for ``mtp_lm_loss`` with
+    ``lm_head.weight``."""
+
+    def _trunk(self, input_ids):
+        """The last block's output, before the final norm."""
+        x = self.embed_tokens(input_ids)
+        for layer in self.layers:
+            x = self._block(layer, x)
+        return x
+
+    def features(self, input_ids):
+        return self.norm(self._trunk(input_ids))
+
+    def forward(self, input_ids):
+        return self.lm_head(self.features(input_ids))
+
+    def training_features(self, input_ids):
+        """(final-normed hidden states, [each MTP module's]): position i of
+        module d's output predicts token i + d + 2. The modules chain: the
+        first reads the last block's output (before the final norm), each
+        later one the module before it, with the embedding of the token one
+        further on; a row's last positions see the row's last token again
+        and carry no label."""
+        from .. import tensor as pt
+
+        h = self._trunk(input_ids)
+        main, heads, ids = self.norm(h), [], input_ids
+        for module in self.mtp:
+            ids = pt.concat([ids[:, 1:], ids[:, -1:]], axis=1)
+            h, normed = self._block(module, h, self.embed_tokens(ids))
+            heads.append(normed)
+        return main, heads
+
+
+class JoyAIFlashModel(_MTPDecoder):
     """JoyAI-LLM-Flash (HF ``joyai_llm_flash``; the DeepSeek-V3 family's
     equations): ``first_k_dense_replace`` dense blocks, then expert blocks,
     every one with latent attention; a final norm and an untied head; and
@@ -712,56 +769,33 @@ class JoyAIFlashModel(_BlockwiseModel):
         self.lm_head = nn.Linear(hidden_size, vocab_size,
                                  weight_attr=attr(), bias_attr=False)
         self.mtp = nn.LayerList([
-            MultiTokenPredictor(cfg, weight_attr=attr())
+            MultiTokenPredictor(
+                hidden_size, rms_norm_eps,
+                lambda: JoyAIDecoderLayer(cfg, dense=False,
+                                          weight_attr=attr()),
+                weight_attr=attr())
             for _ in range(num_nextn_predict_layers)])
-
-    def _trunk(self, input_ids):
-        """The last block's output, before the final norm."""
-        x = self.embed_tokens(input_ids)
-        for layer in self.layers:
-            x = self._block(layer, x)
-        return x
-
-    def features(self, input_ids):
-        return self.norm(self._trunk(input_ids))
-
-    def forward(self, input_ids):
-        return self.lm_head(self.features(input_ids))
-
-    def training_features(self, input_ids):
-        """(final-normed hidden states, [each MTP module's]): position i of
-        module d's output predicts token i + d + 2. The modules chain: the
-        first reads the last block's output (before the final norm), each
-        later one the module before it, with the embedding of the token one
-        further on; a row's last positions see the row's last token again
-        and carry no label."""
-        from .. import tensor as pt
-
-        h = self._trunk(input_ids)
-        main, heads, ids = self.norm(h), [], input_ids
-        for module in self.mtp:
-            ids = pt.concat([ids[:, 1:], ids[:, -1:]], axis=1)
-            h, normed = self._block(module, h, self.embed_tokens(ids))
-            heads.append(normed)
-        return main, heads
 
 
 class MultiTokenPredictor(nn.Layer):
     """One multi-token-prediction module (DeepSeek-V3 report, section 2.2):
-    ``h' = W_eh [RMSNorm_h(h_i) ; RMSNorm_e(Emb(t_{i+1}))]``, one decoder
-    block of the expert kind, and the norm before the shared head. Returns
-    (the block's output, for the next module; its normed form, for the
-    head)."""
+    ``h' = W_eh [RMSNorm_h(h_i) ; RMSNorm_e(Emb(t_{i+1}))]``, the model's
+    own block(s), and the norm before the shared head. ``block`` is what
+    the module wraps — a layer (an ``nn.Sequential`` of several), or a
+    function of no argument that builds it, called here after ``eh_proj``
+    so that a seed gives every parameter the value it had when the module
+    built its block itself: JoyAI's one expert block, Nemotron 3's
+    attention layer and expert layer (``mtp_hybrid_override_pattern``
+    ``*E``). Returns (the block's output, for the next module; its normed
+    form, for the head)."""
 
-    def __init__(self, cfg, weight_attr=None):
+    def __init__(self, hidden, eps, block, weight_attr=None):
         super().__init__()
-        hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
         self.hnorm = RMSNorm(hidden, eps=eps)
         self.enorm = RMSNorm(hidden, eps=eps)
         self.eh_proj = nn.Linear(2 * hidden, hidden, weight_attr=weight_attr,
                                  bias_attr=False)
-        self.block = JoyAIDecoderLayer(cfg, dense=False,
-                                       weight_attr=weight_attr)
+        self.block = block if isinstance(block, nn.Layer) else block()
         self.norm = RMSNorm(hidden, eps=eps)
 
     def forward(self, h, emb):
@@ -2084,19 +2118,23 @@ def _mamba_step(dt, dt_bias, a_log):
             -jnp.exp(a_log.astype(f32)))
 
 
-def _mamba_gated_norm(y, z, w, *, eps):
+def _mamba_gated_norm(y, z, w, *, eps, groups=1):
     """``w * RMSNorm(y * silu(z))``: THE GATE FIRST, then one mean square
-    over ALL the features (not a head's), in float32 (w from 1); [B, T,
-    inner] -> the same in y's dtype. (``_gdn_gated_norm`` /
-    ``_kda_gated_norm`` norm a head and gate after.)"""
+    over ALL the features of each of ``groups`` groups (Granite's one
+    group: all of ``inner``, not a head's; Nemotron-H's 8: ``inner / 8``
+    each), in float32 (w from 1); [B, T, inner] -> the same in y's dtype.
+    (``_gdn_gated_norm`` / ``_kda_gated_norm`` norm a head and gate
+    after.)"""
     import jax
     import jax.numpy as jnp
 
     def gated(y, z, w):
         f32 = jnp.float32
         g = y.astype(f32) * jax.nn.silu(z.astype(f32))
-        return (g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
-                                  + eps) * w.astype(f32)).astype(y.dtype)
+        if groups > 1:
+            g = g.reshape(*g.shape[:-1], groups, -1)
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+        return (g.reshape(y.shape) * w.astype(f32)).astype(y.dtype)
 
     return jax.checkpoint(gated)(y, z, w)
 
@@ -2131,7 +2169,21 @@ class Mamba2Mixer(nn.Layer):
     ``apply_decay_param_fun``. Scopes: ``mamba.in_proj`` / ``.conv`` (taps,
     bias, SiLU, the split) / ``.dt`` (softplus, ``-exp(A_log)``) / ``.core``
     (the scan: ``dt x``, ``dt A``, the ``D`` skip) / ``.norm`` /
-    ``.out_proj``."""
+    ``.out_proj``.
+
+    With ``n_groups`` > 1 (Nemotron-H: 128 heads in 8 groups) the gated
+    norm is A GROUP'S: one mean square over each group's ``inner /
+    n_groups`` features. ``held_heads=(first, count)`` builds ONE CHIP'S
+    SHARE of a mixer whose heads are divided over chips (tensor parallelism
+    without its exchange): whole groups of the ``num_heads`` heads — the
+    columns of ``in_proj`` for their z, x, B, C and dt, their taps, ``A_log``,
+    ``dt_bias``, ``D`` and norm weights, their rows of ``out_proj`` — and
+    nothing else; every stage is a head's or a group's own, so the share
+    computes exactly its heads' part, and ``out_proj`` gives a PARTIAL SUM
+    over the heads held (what the absent chips would add is left out:
+    nothing stands in for them or their all-reduce). The share's columns of
+    the whole ``in_proj`` are, in its own order, [z | x | B | C | dt] of its
+    heads and groups."""
 
     #: Mamba-2's own start of the step: dt ~ exp(U(log min, log max)),
     #: floored, behind the softplus
@@ -2139,7 +2191,8 @@ class Mamba2Mixer(nn.Layer):
 
     def __init__(self, hidden_size, num_heads=64, head_dim=64, d_state=128,
                  n_groups=1, d_conv=4, conv_bias=True, chunk=None,
-                 segment=None, rms_norm_eps=1e-5, weight_attr=None):
+                 segment=None, rms_norm_eps=1e-5, weight_attr=None,
+                 held_heads=None):
         super().__init__()
         from ..framework.param_attr import ParamAttr
         from ..ops import linear_attention
@@ -2147,6 +2200,19 @@ class Mamba2Mixer(nn.Layer):
         if num_heads % n_groups:
             raise ValueError(f"{num_heads} heads are no multiple of "
                              f"{n_groups} groups")
+        self.held_heads = None
+        if held_heads is not None:
+            first, count = (int(v) for v in held_heads)
+            per_group = num_heads // n_groups
+            if not (0 <= first < first + count <= num_heads
+                    and first % per_group == 0 and count % per_group == 0):
+                raise ValueError(
+                    f"held_heads=(first, count)={held_heads!r} is no range "
+                    f"of whole groups ({per_group} heads each) of the "
+                    f"{num_heads} heads")
+            self.held_heads = (first, count)
+            # what is built and run here: the share's heads and groups
+            num_heads, n_groups = count, count // per_group
         self.num_heads, self.head_dim = num_heads, head_dim
         self.d_state, self.n_groups = d_state, n_groups
         self.inner = num_heads * head_dim
@@ -2186,6 +2252,16 @@ class Mamba2Mixer(nn.Layer):
     def forward(self, x):
         import jax
 
+        y = self.heads_output(x)
+        with jax.named_scope("mamba.out_proj"):
+            return self.out_proj(y)
+
+    def heads_output(self, x):
+        """What ``out_proj`` multiplies: the gated, normed outputs of the
+        heads built here [B, T, inner] — a head's own function of x, so a
+        share's are the whole mixer's at its own features."""
+        import jax
+
         from ..core.dispatch import apply_op
         from ..ops.linear_attention import conv_kernel, ssd_scan
 
@@ -2208,10 +2284,10 @@ class Mamba2Mixer(nn.Layer):
             y = ssd_scan(xs, dt, a, b, c, self.D, groups=self.n_groups,
                          chunk=self.chunk, segment=self.segment)
         with jax.named_scope("mamba.norm"):
-            y = apply_op("mamba_gated_norm", _mamba_gated_norm, y, z,
-                         self.norm.weight, eps=self.norm.eps)
-        with jax.named_scope("mamba.out_proj"):
-            return self.out_proj(y)
+            # one group: Granite's call, as it was
+            groups = ({"groups": self.n_groups} if self.n_groups > 1 else {})
+            return apply_op("mamba_gated_norm", _mamba_gated_norm, y, z,
+                            self.norm.weight, eps=self.norm.eps, **groups)
 
 
 def _split_heads(x, *, heads):
@@ -2395,3 +2471,215 @@ def granite_hybrid_layer_types(num_hidden_layers):
     in every ten; another depth takes the pattern's start."""
     return ["attention" if i % 10 == 5 else "mamba"
             for i in range(num_hidden_layers)]
+
+
+class NemotronAttention(nn.Layer):
+    """Nemotron-H's ``*`` mixer: plain projections, grouped queries
+    (``num_heads`` heads of ``head_dim`` on ``num_kv_heads``), NOTHING
+    ROTATED and no norm a head (the family's attention carries no
+    positions), causal softmax of ``q k^T / sqrt(head_dim)`` with query head
+    h on key/value head ``h // (heads / kv_heads)``, then ``o_proj``; no
+    bias. The core goes through the dispatching sdpa (the streaming flash
+    kernel at long sequences), K and V REPEATED to the query heads first.
+
+    ``held_heads=(first, count)`` builds ONE CHIP'S SHARE of the heads:
+    those query heads' columns of ``q_proj`` and rows of ``o_proj``, and the
+    key/value head(s) they read — whole key/value heads' worth of queries,
+    or a part of ONE key/value head's (4 of 32 on 1 of 2) —; ``o_proj``
+    gives a PARTIAL SUM over the heads held, as ``Mamba2Mixer``'s share
+    does. Scopes: ``nattn.qkv`` (q, k, v, the head split and the repeat) /
+    ``.core`` / ``.out`` (merge, ``o_proj``)."""
+
+    def __init__(self, hidden_size, num_heads=32, num_kv_heads=2,
+                 head_dim=128, weight_attr=None, held_heads=None):
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads are no multiple of "
+                             f"{num_kv_heads} key/value heads")
+        self.held_heads = None
+        if held_heads is not None:
+            first, count = (int(v) for v in held_heads)
+            per_kv = num_heads // num_kv_heads
+            whole = first % per_kv == 0 and count % per_kv == 0
+            if not (0 <= first < first + count <= num_heads and (
+                    whole or (per_kv % count == 0 and first % count == 0))):
+                raise ValueError(
+                    f"held_heads=(first, count)={held_heads!r} is neither "
+                    f"whole key/value heads' queries ({per_kv} each) nor a "
+                    f"part of one's, of {num_heads} heads")
+            self.held_heads = (first, count)
+            # what is built and run here
+            num_heads, num_kv_heads = count, max(1, count // per_kv)
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = head_dim
+
+        def proj(i, o):
+            return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
+
+        self.q_proj = proj(hidden_size, num_heads * head_dim)
+        self.k_proj = proj(hidden_size, num_kv_heads * head_dim)
+        self.v_proj = proj(hidden_size, num_kv_heads * head_dim)
+        self.o_proj = proj(num_heads * head_dim, hidden_size)
+
+    def forward(self, x):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops.attention import scaled_dot_product_attention as _sdpa
+
+        with jax.named_scope("nattn.qkv"):
+            q, k, v = (apply_op("split_heads", _split_heads, proj(x),
+                                heads=heads)
+                       for proj, heads in ((self.q_proj, self.num_heads),
+                                           (self.k_proj, self.num_kv_heads),
+                                           (self.v_proj, self.num_kv_heads)))
+            k, v = (apply_op("repeat_heads", _repeat_heads, t,
+                             repeats=self.num_heads // self.num_kv_heads,
+                             axis=1) for t in (k, v))
+        with jax.named_scope("nattn.core"):
+            o = _sdpa(q, k, v, is_causal=True, training=self.training)
+        with jax.named_scope("nattn.out"):
+            return self.o_proj(apply_op("merge_heads", _merge_heads, o))
+
+
+class NemotronHLayer(nn.Layer):
+    """A Nemotron-H layer is ONE sublayer: ``h + Mixer(RMSNorm(h))``, the
+    mixer by the layer's letter in ``hybrid_override_pattern`` — ``M``
+    Mamba-2's state-space mixer (the gated norm a group's), ``*``
+    grouped-query attention without positions, ``E`` the LatentMoE expert
+    layer (relu² experts in a ``moe_latent_size``-wide latent space between
+    two shared projections; sigmoid router with a selection bias over the
+    hidden-wide stream, renormalised top-k times ``routed_scaling_factor``;
+    a relu² shared expert on the hidden-wide stream), ``-`` a dense relu²
+    MLP. No layer here is mixer + feed-forward."""
+
+    KINDS = {"M": "mamba", "*": "attention", "E": "moe", "-": "mlp"}
+
+    def __init__(self, cfg, kind, weight_attr=None):
+        super().__init__()
+        from ..incubate.moe import MoELayer
+
+        if kind not in self.KINDS.values():
+            raise ValueError(f"layer kind {kind!r} is none of "
+                             f"{sorted(self.KINDS.values())}")
+        hidden, eps = cfg["hidden_size"], cfg["layer_norm_epsilon"]
+        self.kind = kind
+        self.norm = RMSNorm(hidden, eps=eps)
+        if kind == "mamba":
+            self.mixer = Mamba2Mixer(hidden, rms_norm_eps=eps,
+                                     weight_attr=weight_attr, **cfg["mamba"])
+        elif kind == "attention":
+            self.mixer = NemotronAttention(hidden, weight_attr=weight_attr,
+                                           **cfg["attention"])
+        elif kind == "moe":
+            self.mixer = MoELayer(hidden, weight_attr=weight_attr,
+                                  **cfg["moe"])
+        else:
+            self.mixer = Relu2MLP(hidden, cfg["intermediate_size"],
+                                  weight_attr)
+
+    def forward(self, x):
+        return x + self.mixer(self.norm(x))
+
+
+def nemotron_layer_types(pattern):
+    """``hybrid_override_pattern``'s letters as layer kinds: ``M`` ->
+    ``mamba``, ``*`` -> ``attention``, ``E`` -> ``moe``, ``-`` -> ``mlp``."""
+    try:
+        return [NemotronHLayer.KINDS[c] for c in pattern]
+    except KeyError as e:
+        raise ValueError(f"{e.args[0]!r} in {pattern!r} is none of "
+                         f"{sorted(NemotronHLayer.KINDS)}") from None
+
+
+#: NVIDIA-Nemotron-3-Super-120B-A12B's 88 layers: 40 M, 40 E, 8 attention
+NEMOTRON_3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEM*EMEMEMEME")
+
+
+class NemotronHModel(_MTPDecoder):
+    """Nemotron-H / Nemotron 3 (NVIDIA, HF ``nemotron_h``;
+    NVIDIA-Nemotron-3-Super-120B-A12B's sizes are the defaults): ``h0 =
+    E[ids]``; layers that are ONE sublayer each, ``h + Mixer(RMSNorm(h))``
+    by ``hybrid_override_pattern`` (``NemotronHLayer``); a final norm; an
+    untied head; and ``num_nextn_predict_layers`` multi-token-prediction
+    modules that share the embedding and the head, each wrapping the layers
+    of ``mtp_hybrid_override_pattern`` (``*E``: an attention layer, then an
+    expert layer).
+
+    ``held_experts``, ``held_mamba_heads`` and ``held_attention_heads`` (each
+    ``(first, count)``) give every layer of its kind this chip's share of a
+    deployment that divides each layer over chips; each layer computes its
+    own experts' or heads' part and that partial sum goes on
+    (``MoELayer``, ``Mamba2Mixer``, ``NemotronAttention``).
+    ``use_recompute`` runs each layer under ``fleet.utils.recompute`` in a
+    traced step. ``forward`` gives the main logits; a training loss takes
+    ``training_features`` and ``lm_head.weight`` to ``mtp_lm_loss``."""
+
+    def __init__(self, vocab_size=131072, hidden_size=4096,
+                 hybrid_override_pattern=NEMOTRON_3_SUPER_PATTERN,
+                 mtp_hybrid_override_pattern="*E",
+                 num_nextn_predict_layers=1, num_attention_heads=32,
+                 num_key_value_heads=2, head_dim=128, mamba_num_heads=128,
+                 mamba_head_dim=64, ssm_state_size=128, n_groups=8,
+                 conv_kernel=4, use_conv_bias=True, mamba_chunk=None,
+                 mamba_segment=None, intermediate_size=2688,
+                 moe_intermediate_size=2688, moe_latent_size=1024,
+                 moe_shared_expert_intermediate_size=5376,
+                 n_routed_experts=512, num_experts_per_tok=22,
+                 norm_topk_prob=True, routed_scaling_factor=5.0,
+                 bias_update_speed=0.001, layer_norm_epsilon=1e-5,
+                 initializer_range=0.02, held_experts=None,
+                 held_rows_factor=2.0, held_mamba_heads=None,
+                 held_attention_heads=None, use_recompute=False):
+        super().__init__(use_recompute)
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        def held(share):
+            return None if share is None else tuple(share)
+
+        cfg = dict(
+            hidden_size=hidden_size, layer_norm_epsilon=layer_norm_epsilon,
+            intermediate_size=intermediate_size,
+            mamba=dict(num_heads=mamba_num_heads, head_dim=mamba_head_dim,
+                       d_state=ssm_state_size, n_groups=n_groups,
+                       d_conv=conv_kernel, conv_bias=use_conv_bias,
+                       chunk=mamba_chunk, segment=mamba_segment,
+                       held_heads=held(held_mamba_heads)),
+            attention=dict(num_heads=num_attention_heads,
+                           num_kv_heads=num_key_value_heads,
+                           head_dim=head_dim,
+                           held_heads=held(held_attention_heads)),
+            moe=dict(ffn_hidden=moe_intermediate_size,
+                     num_experts=n_routed_experts, top_k=num_experts_per_tok,
+                     activation="relu2", latent_size=moe_latent_size,
+                     gate_bias=False, norm_topk_prob=norm_topk_prob,
+                     scoring="sigmoid", select_bias=True,
+                     bias_update_speed=bias_update_speed,
+                     routed_scale=routed_scaling_factor,
+                     shared_width=moe_shared_expert_intermediate_size,
+                     held=held(held_experts),
+                     held_rows_factor=held_rows_factor, aux_weight=0.0))
+        self.layer_types = nemotron_layer_types(hybrid_override_pattern)
+        mtp_types = nemotron_layer_types(mtp_hybrid_override_pattern)
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            NemotronHLayer(cfg, kind, weight_attr=attr())
+            for kind in self.layer_types])
+        self.norm = RMSNorm(hidden_size, eps=layer_norm_epsilon)
+        self.lm_head = nn.Linear(hidden_size, vocab_size,
+                                 weight_attr=attr(), bias_attr=False)
+        self.mtp = nn.LayerList([
+            MultiTokenPredictor(
+                hidden_size, layer_norm_epsilon,
+                lambda: nn.Sequential(*[
+                    NemotronHLayer(cfg, kind, weight_attr=attr())
+                    for kind in mtp_types]),
+                weight_attr=attr())
+            for _ in range(num_nextn_predict_layers)])
