@@ -83,10 +83,26 @@ times ``aux_weight`` and the router z-loss ``mean_t logsumexp(r_t)^2`` times
 ``z_loss_weight``. The sorted path's load-balancing term is the
 Switch / HF form over all k choices, ``E * sum_e (n_e / N) * mean_t
 p[t, e]`` (n_e = assignments to e); dense divides it by k; capacity and
-alltoall use GShard's top-1 fraction. Which path a call took is counted in
+alltoall use GShard's top-1 fraction.
+
+The sorted paths' router (``_route``, scope ``moe.route``) is a float32
+product, the scoring, and THE CHOICE (``_choice``): the k ids of a token in
+``jax.lax.top_k``'s order, the scores at them, and n_e — made once: the
+balancing term and the selection bias's update read the same count. The
+choice has two implementations and no knob (``route_path``, asked by
+``route_kernel`` outside the dispatched op, as the grouped matmul's
+``placement.kernel(sharded=False)`` beside it): the Mosaic stage
+``ops/pallas/moe_route.py`` — tokens on the lanes, k rounds of arg-max in
+VMEM, one call a pass, the gradient k selects — where the program may hold
+kernels, the tokens fill a tile and the experts are 64 or more; else XLA's
+sort of every row and sums over [N, k, E] one-hots (a CPU, a mesh of several
+devices, 32 experts). Same ids, same weights, bit for bit.
+
+Which path a call took is counted in
 ``paddle_tpu_moe_dispatch_total{path}``, which arithmetic in
-``paddle_tpu_moe_layer_total{activation, latent}`` (trace time: one a layer
-call).
+``paddle_tpu_moe_layer_total{activation, latent}``, how the router's choice
+ran in ``paddle_tpu_moe_route_total{path}`` (``kernel`` | ``xla``) — trace
+time: one a layer call.
 """
 import numpy as np
 import jax
@@ -115,8 +131,24 @@ _LAYER_TOTAL = obs_metrics.counter(
     "stream's own); under jit one count per traced layer call",
     labelnames=("activation", "latent"))
 
+_ROUTE_TOTAL = obs_metrics.counter(
+    "paddle_tpu_moe_route_total",
+    "routers of the sorted expert paths by how their choice (top-k ids, "
+    "the scores at them, the loads) ran: kernel (one Mosaic call a pass) | "
+    "xla (a sort and one-hot sums); one count per traced layer call",
+    labelnames=("path",))
+
 #: ``auto`` runs every expert on every token below this many experts
 _DENSE_BELOW = 8
+
+#: the router's choice runs as the Mosaic stage from this many experts on:
+#: the tile holds whole lane groups of 128, so 64 experts run the rounds on
+#: twice their vregs and still beat XLA's sort (0.084 against 0.126 ms a
+#: forward call at OLMoE's 16,384 tokens, and 0.116 against 1.021 with the
+#: backward, whose XLA form is top_k's scatter), 32 on four times theirs and
+#: do not (0.119 against 0.107 at LFM2's 32,768; tools/route_bench.py on
+#: the v5e, PERF.md section 6, PR 52)
+_ROUTE_KERNEL_FROM = 64
 
 
 def _expert_ffn(buf, w_gate, w_up, w_down, eq_up, eq_down):
@@ -184,11 +216,70 @@ _rows_to_token_order.defvjp(_rows_to_token_order_fwd,
                             _rows_to_token_order_bwd)
 
 
+def route_path(tokens, experts, k):
+    """``kernel`` | ``xla`` for the choice of ``k`` of ``experts`` experts
+    by ``tokens`` tokens, from what can be observed: the Mosaic kernels
+    (``ops/pallas/moe_route.py``) where the program may hold them — the
+    expert layer takes no ``shard_map`` of its own, so
+    ``placement.kernel(sharded=False)``, as for its grouped matmul —, the
+    tokens fill a tile, and the experts are as many as the kernels beat
+    XLA's sort at (``_ROUTE_KERNEL_FROM``). The XLA stage everything
+    else."""
+    from ..ops.pallas import moe_route as kernels
+
+    if (tokens >= kernels.ROUTE_TOKENS and experts >= _ROUTE_KERNEL_FROM
+            and kernels.supported(experts, k)
+            and placement.kernel(sharded=False)):
+        return "kernel"
+    return "xla"
+
+
+def route_kernel(tokens, experts, k):
+    """One call's decision, counted, as ``_route`` takes it (``choice=``):
+    ``placement.kernel``'s answer where ``route_path`` says ``kernel``, else
+    None (the XLA stage). Asked OUTSIDE the dispatched op; the answer rides
+    its static arguments."""
+    path = route_path(tokens, experts, k)
+    _ROUTE_TOTAL.inc(path=path)
+    return placement.kernel(sharded=False) if path == "kernel" else None
+
+
+def _choice(select, scores, k, kernel=None):
+    """The router's choice: ``select`` [N, E] float32 (what the choice is
+    made on) and ``scores`` [N, E] (what the weights are read from; the
+    same array where they are one) -> the scores at the chosen experts
+    [N, k], their ids [N, k] (``jax.lax.top_k``'s order: descending, ties to
+    the lower index) and the pairs that chose each expert [E]. ``kernel``:
+    ``route_kernel``'s answer — the Mosaic stage (one call a pass, k rounds
+    of arg-max in VMEM), or None, the XLA stage: a sort of every row, and
+    [N, k, E] one-hots for the count and, where the choice is not made on
+    the scores themselves, for the scores at the ids (exact in float32: a
+    gather of 8 of 256 columns a row took 6.7 ms a step on the v5e at 8,192
+    tokens, PERF.md section 6, PR 29, and its gradient would be a
+    scatter)."""
+    if kernel is not None:
+        from ..ops.pallas.moe_route import route_choice
+
+        return route_choice(select, scores, k,
+                            interpret=kernel == "interpret")
+    e = select.shape[-1]
+    if select is scores:
+        topv, topi = jax.lax.top_k(scores, k)
+    else:
+        topi = jax.lax.top_k(select, k)[1]
+        topv = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32)
+                       * scores[:, None, :], axis=-1)
+    n_e = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32), axis=(0, 1))
+    return topv, topi, n_e
+
+
 def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
-           scoring="softmax", routed_scale=1.0, renorm_eps=1e-20):
+           scoring="softmax", routed_scale=1.0, renorm_eps=1e-20,
+           choice=None, counts=False):
     """The router in float32: [B, S, H] -> the k weights [N, k] and expert
     ids [N, k] of every token, the load-balancing term (Switch / HF form
-    over all k choices) and the z-loss.
+    over all k choices) and the z-loss; with ``counts`` also the pairs that
+    chose each expert [E], a fifth result.
     ``scoring`` 'softmax': the weights are the softmax's own unless
     ``renorm``. 'sigmoid' (DeepSeek-V3's ``noaux_tc``): s = sigmoid(logits);
     the choice is top-k of s + ``select_bias``, the weights are s at the
@@ -196,7 +287,7 @@ def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
     divided by their sum + ``renorm_eps`` if ``renorm`` (the sources differ
     in what they add: 1e-20, LFM2 1e-6), times ``routed_scale``; the
     balancing term takes s normalised over the experts, and there is no
-    z-loss."""
+    z-loss. ``choice``: ``route_kernel``'s answer (``_choice``)."""
     with jax.named_scope("moe.route"):
         xf = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
         # float32 in earnest: a TPU's default precision would multiply in
@@ -211,13 +302,8 @@ def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
             scores = jax.nn.sigmoid(logits)
             biased = (scores if select_bias is None else
                       scores + select_bias.astype(jnp.float32))
-            topi = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)[1]
-            # the scores at the chosen experts as a masked sum, exact in
-            # float32: a gather of 8 of 256 columns a row took 6.7 ms a
-            # step on the v5e at 8,192 tokens (PERF.md section 6, PR 29),
-            # and its gradient would be a scatter
-            topv = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32)
-                           * scores[:, None, :], axis=-1)
+            topv, topi, assigned = _choice(jax.lax.stop_gradient(biased),
+                                           scores, top_k, choice)
             if renorm:
                 topv = topv / (jnp.sum(topv, axis=-1, keepdims=True)
                                + renorm_eps)
@@ -225,16 +311,15 @@ def _route(x, w_router, b_router, select_bias=None, *, top_k, renorm,
             probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
         else:
             probs = jax.nn.softmax(logits, axis=-1)
-            topv, topi = jax.lax.top_k(probs, top_k)
+            topv, topi, assigned = _choice(probs, probs, top_k, choice)
             if renorm:
                 topv = topv / jnp.maximum(
                     jnp.sum(topv, axis=-1, keepdims=True), 1e-9)
-        assigned = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32),
-                           axis=(0, 1))                 # n_e
         balance = e * jnp.sum(assigned / n * jnp.mean(probs, axis=0))
         z = (_z_loss(logits) if scoring == "softmax"
              else jnp.zeros((), jnp.float32))
-        return topv, topi.astype(jnp.int32), balance, z
+        routed = (topv, topi.astype(jnp.int32), balance, z)
+        return routed + (assigned,) if counts else routed
 
 
 #: megablox tilings (rows, contraction, output columns) by operand size;
@@ -840,11 +925,19 @@ class MoELayer(nn.Layer):
         weighted sum over a token's k choices stay in float32. The router
         reads ``x``, the experts ``inner`` (x itself, or its latent form)
         and the sum is as wide as ``inner``."""
-        topv, topi, balance, z = apply_op(
+        # the noaux_tc balancing moves the selection bias against this
+        # step's loads: the router's own count of them leaves with its
+        # choices
+        balancing = bool(self.training and self.bias_update_speed
+                         and self.e_score_correction_bias is not None)
+        tokens = int(np.prod(x.shape[:-1]))
+        topv, topi, balance, z, *n_e = apply_op(
             "moe_route", _route, x, self.gate.weight, self.gate.bias,
             self.e_score_correction_bias, top_k=self.top_k,
             renorm=self.norm_topk_prob, scoring=self.scoring,
-            routed_scale=self.routed_scale, renorm_eps=self.renorm_eps)
+            routed_scale=self.routed_scale, renorm_eps=self.renorm_eps,
+            choice=route_kernel(tokens, self.num_experts, self.top_k),
+            counts=balancing)
         kernel = placement.kernel(sharded=False)   # no shard_map of its own
         if self.held is None:
             ys, order, inv = apply_op(
@@ -855,7 +948,6 @@ class MoELayer(nn.Layer):
                            shape=tuple(inner.shape))
         else:
             first, count = self.held
-            tokens = int(np.prod(x.shape[:-1]))
             ys, taken, inv, overflow = apply_op(
                 "moe_experts_held", _held_experts, inner, topi, self.w_gate,
                 self.w_up, self.w_down, first=first, rows=held_rows(
@@ -867,16 +959,14 @@ class MoELayer(nn.Layer):
             if self.training:
                 self.held_overflow.set_value(
                     self.held_overflow._value + overflow._value)
-        if (self.training and self.bias_update_speed
-                and self.e_score_correction_bias is not None):
-            # the noaux_tc balancing: an overloaded expert's bias falls, an
-            # underloaded one's rises, by a fixed step; it is part of the
-            # train step (the buffer threads through it)
-            n_e = jnp.sum(jax.nn.one_hot(topi._value, self.num_experts,
-                                         dtype=jnp.float32), axis=(0, 1))
+        if balancing:
+            # an overloaded expert's bias falls, an underloaded one's rises,
+            # by a fixed step; it is part of the train step (the buffer
+            # threads through it)
+            loads = n_e[0]._value
             bias = self.e_score_correction_bias
             bias.set_value(bias._value + self.bias_update_speed
-                           * jnp.sign(jnp.mean(n_e) - n_e))
+                           * jnp.sign(jnp.mean(loads) - loads))
         aux = balance * self.aux_weight
         if self.z_loss_weight:
             aux = aux + z * self.z_loss_weight

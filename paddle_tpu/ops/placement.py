@@ -43,8 +43,12 @@ per-head A and D a copy a row because ``on_mesh`` shards dim 0 of every
 array) and LFM2's gated short convolution (``shortconv_path``,
 asked inside ``gated_short_conv``: the layer's call carries two arrays and
 nothing else; rows over the data axes, whole on an 'mp' axis), all sharded
-through ``on_mesh``; and the expert layer's grouped matmul
-(``incubate.moe``), unsharded.
+through ``on_mesh``; and, unsharded, the expert layer (``incubate.moe``: it
+takes no ``shard_map`` of its own) for its grouped matmul and for its
+router's choice (``route_path``, decided by ``route_kernel`` in
+``_forward_sorted`` and riding ``_route``'s static arguments: top-k ids,
+the scores at them and the loads as one Mosaic call a pass from 64 experts
+on, XLA's sort and one-hot sums elsewhere).
 """
 import math
 

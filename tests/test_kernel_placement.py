@@ -4,9 +4,10 @@ test (platform and device count patched, meshes from the conftest's CPU
 devices), ``axis_size`` and ``on_mesh``'s optional seed, the expert layer's
 call site (``sharded=False``: the grouped matmul has no shard_map), the
 convolution stage's decision taken outside its dispatched op, the gated
-short convolution's table (``shortconv_path``) and the selective state-space
+short convolution's table (``shortconv_path``), the selective state-space
 scan's (``ssd_path``: the table's ``sharded`` column at the granite cell's
-widths)."""
+widths) and the router's choice (``route_path``: the ``unsharded`` column,
+as the grouped matmul beside it)."""
 import contextlib
 
 import jax
@@ -314,3 +315,47 @@ def test_the_state_space_scan_goes_by_what_it_observes(monkeypatch, how,
     tokens — no flag, argument or name of its own."""
     with observed(monkeypatch, **how):
         assert linear_attention.ssd_path(*scan) == path
+
+
+#: the Qwen3-Next cell's router: 16,384 tokens choose 10 of 512 experts
+ROUTER = (16384, 512, 10)
+ROUTE = [
+    ("the-cell-on-one-chip", dict(), ROUTER, "kernel"),
+    ("nemotron-top-22", dict(), (4096, 512, 22), "kernel"),
+    ("kimi-linear-256-experts", dict(), (16384, 256, 8), "kernel"),
+    ("joyai-8192-tokens", dict(), (8192, 256, 8), "kernel"),
+    ("trinity-128-experts", dict(), (16384, 128, 8), "kernel"),
+    ("mesh-of-one", dict(mesh=1, devices=8), ROUTER, "kernel"),
+    ("interpreter", dict(interpret=True, tpu=False, devices=8), ROUTER,
+     "kernel"),
+    # the expert layer takes no shard_map of its own: under dp4-style
+    # meshes the XLA stage, as ragged_dot for the grouped matmul
+    ("announced-mesh-of-many", dict(mesh=4, devices=8), ROUTER, "xla"),
+    ("plain-jit-many-devices", dict(devices=8), ROUTER, "xla"),
+    ("flag-off", dict(selected=False), ROUTER, "xla"),
+    ("no-tpu", dict(tpu=False), ROUTER, "xla"),
+    ("olmoe-64-experts", dict(), (16384, 64, 8), "kernel"),
+    ("lfm2-32-experts", dict(), (32768, 32, 4), "xla"),
+    ("tokens-under-one-tile", dict(), (511, 512, 10), "xla"),
+    ("more-experts-than-a-tile-holds", dict(), (16384, 4096, 8), "xla"),
+]
+
+
+@pytest.mark.parametrize("how, router, path", [row[1:] for row in ROUTE],
+                         ids=[row[0] for row in ROUTE])
+def test_the_routers_choice_goes_by_what_it_observes(monkeypatch, how,
+                                                     router, path):
+    """``route_path``: the Mosaic stage where ``placement`` lets an
+    UNSHARDED site hold it (no mesh of several devices), the tokens fill a
+    tile and the experts are 64 or more and no more than a tile holds; the
+    XLA stage everything else. ``route_kernel`` hands ``placement``'s answer
+    on and counts one call under the label of the path taken."""
+    before = {p: moe._ROUTE_TOTAL.value(path=p) for p in ("kernel", "xla")}
+    with observed(monkeypatch, **how):
+        assert moe.route_path(*router) == path
+        handed = moe.route_kernel(*router)
+        assert handed == (None if path == "xla" else
+                          placement.kernel(sharded=False))
+    assert (handed is None) == (path == "xla")
+    for p, n in before.items():
+        assert moe._ROUTE_TOTAL.value(path=p) == n + (p == path), p

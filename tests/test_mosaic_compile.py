@@ -189,6 +189,18 @@ SHORTCONV_CASES = {
     "lfm2-check-f32": (2, 8192, 2048, 3, "float32"),
 }
 GATED_CALLS = ("gated_conv_fwd", "gated_conv_bwd")
+#: the expert layer's router (tokens, hidden, experts, k, scoring):
+#: ``incubate.moe._route`` whole, forward + VJP, through its own gate, at the
+#: Qwen3-Next cell's (softmax, renormalised) and the Nemotron cell's (sigmoid
+#: + selection bias) and the OLMoE cell's (softmax on 64 experts: the tile
+#: padded to a lane group of 128). ROUTE_CALLS: the names the choice's two
+#: Mosaic calls carry in a trace, under ``moe.route``
+ROUTE_CASES = {
+    "qwen3-next-cell": (16384, 2048, 512, 10, "softmax"),
+    "nemotron-cell": (4096, 4096, 512, 22, "sigmoid"),
+    "olmoe-cell": (16384, 2048, 64, 8, "softmax"),
+}
+ROUTE_CALLS = ("moe_route_fwd", "moe_route_bwd")
 FFN_WIDTH = 3072                  # bert-base's intermediate_size
 #: what XLA's expansion of erfc brings into a fusion and erf does not
 ERFC_OPCODES = ("exponential", "divide", "select", "compare")
@@ -687,6 +699,45 @@ def _child():
                 rf"|f32\[{batch},{seq},{heads * d}\]",
                 text[text.index("\nENTRY "):]))}
 
+    from paddle_tpu.incubate import moe
+
+    for name, (tokens, hidden, experts, k, scoring) in ROUTE_CASES.items():
+        def like(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+        def router_alone(x, w, bias, c):
+            """The router's weights and their gradients, the router as the
+            layer calls it: the decision outside, riding ``choice=``."""
+            choice = moe.route_kernel(tokens, experts, k)
+
+            def weights(x, w):
+                return moe._route(
+                    x, w, None, bias if scoring == "sigmoid" else None,
+                    top_k=k, renorm=True, scoring=scoring, choice=choice,
+                    counts=True)
+
+            out, vjp = jax.vjp(lambda x, w: weights(x, w)[0], x, w)
+            return weights(x, w), vjp(c)
+
+        here = placement.kernel
+        placement.kernel = lambda **site: "mosaic"
+        paths = {p: moe._ROUTE_TOTAL.value(path=p) for p in ("kernel", "xla")}
+        try:
+            text = jax.jit(router_alone).lower(
+                like(1, tokens, hidden), like(hidden, experts), like(experts),
+                like(tokens, k)).compile().as_text()
+        finally:
+            placement.kernel = here
+        out["route-" + name] = {
+            "mosaic": text.count(MOSAIC),
+            "calls": [c for c in ROUTE_CALLS if f"%{c}" in text],
+            "paths": {p: moe._ROUTE_TOTAL.value(path=p) - n
+                      for p, n in paths.items()},
+            "sorts": text.count(" sort("),
+            # the XLA stage's one-hots, in any dtype and order
+            "pair_by_expert_arrays": len(re.findall(
+                rf"\[{tokens},({k},{experts}|{experts},{k})\]", text))}
+
     from paddle_tpu.text.models import Lfm2ShortConv
 
     for name, (batch, seq, channels, taps, dtype) in SHORTCONV_CASES.items():
@@ -1070,6 +1121,19 @@ def test_the_gated_short_convolution_keeps_its_float32_in_vmem(compiled,
         assert got["layer_mosaic"] == 2
         assert got["layer_calls"] == list(GATED_CALLS)
         assert got["layer_f32"] == got["layer_f32_under_stage"] == 0
+
+
+@pytest.mark.parametrize("case", list(ROUTE_CASES))
+def test_the_routers_choice_compiles_to_one_call_a_pass(compiled, case):
+    """The router whole through its own gate, forward + VJP at a cell's
+    shape: ``route_path`` says ``kernel`` (counted), both kernels compile
+    for the described v5e within the VMEM they ask for, one Mosaic call a
+    pass, and the XLA stage's work is gone from the program — no sort, no
+    array as large as [tokens, k, experts]."""
+    got = compiled["route-" + case]
+    assert got["mosaic"] == 2 and got["calls"] == list(ROUTE_CALLS)
+    assert got["paths"] == {"kernel": 1, "xla": 0}
+    assert got["sorts"] == 0 and got["pair_by_expert_arrays"] == 0
 
 
 @pytest.mark.parametrize("case", list(MAMBA_CASES))

@@ -376,6 +376,8 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
                    for e in ("streams", "heads")}
         counts["held"] = moe._DISPATCH_TOTAL.value(path="sorted_held")
         counts["xla"] = attention._ROUTE_TOTAL.value(route="xla")
+        routers = {p: moe._ROUTE_TOTAL.value(path=p)
+                   for p in ("kernel", "xla")}
         stages = {p: linear_attention._CONV_TOTAL.value(path=p)
                   for p in ("kernel", "xla")}
         text = jax.jit(jax.grad(
@@ -405,6 +407,11 @@ def test_a_traced_step_carries_the_scopes_and_counts_the_paths(
         conv == "kernel")
     assert moe._DISPATCH_TOTAL.value(
         path="sorted_held") - counts["held"] == 4
+    # the routers' choice: this file's 32 experts sort in XLA on every row
+    # (the cell's 512 take the stage kernel: tests/test_moe_route_kernel.py)
+    for p, n in routers.items():
+        assert moe._ROUTE_TOTAL.value(path=p) - n == (
+            4 if p == "xla" else 0), p
     assert attention._ROUTE_TOTAL.value(route="xla") - counts["xla"] == 1
     if given == "kernel_scalar":
         assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
