@@ -115,7 +115,7 @@ def _sdpa_ref(q, k, v, mask, key, *, scale, dropout_p, is_causal, window=None):
 
 
 def rotary(x, base=10000.0, positions=None, pairing="interleaved",
-           rotary_dim=None):
+           rotary_dim=None, inv_freq=None, mscale=1.0):
     """Rotary embedding. x: [B, H, T, D]; positions: [T] absolute positions
     (defaults to 0..T-1). ``text/models.py``'s ``_rope``, shared with
     generation.py's cached decode.
@@ -124,19 +124,35 @@ def rotary(x, base=10000.0, positions=None, pairing="interleaved",
     rotate-half form of the HF sources; OLMoE). The two differ by a fixed
     permutation of the columns of the q and k projections. ``rotary_dim``:
     only the first that many features rotate, among themselves (a partial
-    rotary factor: Qwen3-Next turns 64 of 256); the rest pass."""
+    rotary factor: Qwen3-Next turns 64 of 256); the rest pass.
+    ``inv_freq`` (static: D/2 numbers) takes the place of the one table
+    ``base ** (-2i/D)`` — a scaled RoPE's blended frequencies
+    (``yarn_rope``) — and ``mscale`` multiplies cos and sin (YaRN's
+    attention factor on the rotated features). A call that gives neither
+    lowers to the program it always did."""
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         return jnp.concatenate(
-            [rotary(x[..., :rotary_dim], base, positions, pairing),
+            [rotary(x[..., :rotary_dim], base, positions, pairing,
+                    inv_freq=inv_freq, mscale=mscale),
              x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     t = x.shape[-2]
     if positions is None:
         positions = jnp.arange(t)
-    inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv_freq is None:
+        inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
+        if inv.shape != (d // 2,):
+            raise ValueError(f"inv_freq holds {inv.shape} frequencies for "
+                             f"{d} rotated features ({d // 2} pairs)")
     freqs = jnp.outer(positions, inv)
-    cos = jnp.cos(freqs)[None, None].astype(x.dtype)
-    sin = jnp.sin(freqs)[None, None].astype(x.dtype)
+
+    def table(fn):
+        values = fn(freqs) if mscale == 1.0 else fn(freqs) * mscale
+        return values[None, None].astype(x.dtype)
+
+    cos, sin = table(jnp.cos), table(jnp.sin)
     if pairing == "half":
         x1, x2 = x[..., :d // 2], x[..., d // 2:]
         return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -145,6 +161,56 @@ def rotary(x, base=10000.0, positions=None, pairing="interleaved",
     out1 = x1 * cos - x2 * sin
     out2 = x2 * cos + x1 * sin
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape)
+
+
+def yarn_mscale(scale, mscale=1.0):
+    """YaRN's magnitude correction for a context stretched ``scale`` times:
+    ``0.1 mscale ln(scale) + 1`` (1 where nothing is stretched)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_rope(rope_scaling, dim, base=10000.0):
+    """A ``rope_scaling`` group of ``type: yarn`` (HF ``DeepseekV3``'s
+    reading of YaRN, arXiv:2309.00071) for ``dim`` rotated features ->
+    (``inv_freq``: dim/2 floats, ``mscale`` for cos and sin, the factor on
+    the softmax scale). The table blends ``base ** (-2i/dim)`` (kept where a
+    feature turns more than ``beta_fast`` times over the original context)
+    with the same over ``factor`` (where it turns fewer than ``beta_slow``
+    times), by a linear ramp between the two correction dims, floor and
+    ceiling taken. cos and sin carry ``yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)``, and the softmax scale
+    ``yarn_mscale(factor, mscale_all_dim) ** 2`` (1 without
+    ``mscale_all_dim``). Computed in float64 and rounded once."""
+    import numpy as np
+
+    kind = rope_scaling.get("type", rope_scaling.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling of type {kind!r}: only 'yarn' is "
+                         f"built")
+    factor = float(rope_scaling["factor"])
+    original = rope_scaling["original_max_position_embeddings"]
+
+    def correction_dim(rotations):
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(rope_scaling.get("beta_fast", 32))),
+              0)
+    high = min(math.ceil(correction_dim(rope_scaling.get("beta_slow", 1))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inv_freq = plain / factor * ramp + plain * (1.0 - ramp)
+    all_dim = rope_scaling.get("mscale_all_dim") or 0.0
+    mscale = (yarn_mscale(factor, rope_scaling.get("mscale", 1.0))
+              / yarn_mscale(factor, all_dim) if all_dim else
+              yarn_mscale(factor))
+    softmax = yarn_mscale(factor, all_dim) ** 2 if all_dim else 1.0
+    return (tuple(float(v) for v in inv_freq.astype(np.float32)),
+            float(mscale), float(softmax))
 
 
 # ------------------------------------------------- the stage before the core

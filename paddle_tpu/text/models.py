@@ -497,11 +497,27 @@ class OlmoeModel(nn.Layer):
         return self.lm_head(self.features(input_ids))
 
 
+def _held_range(held_heads, num_heads, fits=None, what="no range"):
+    """``held_heads=(first, count)`` as two ints: one chip's range of a
+    layer's ``num_heads`` heads, and — where the layer's heads come in
+    units (groups, key/value heads) — one that ``fits(first, count)``;
+    ``what`` says in the error what such a range is."""
+    first, count = (int(v) for v in held_heads)
+    if not (0 <= first < first + count <= num_heads
+            and (fits is None or fits(first, count))):
+        raise ValueError(f"held_heads=(first, count)={held_heads!r} is "
+                         f"{what} of the {num_heads} heads")
+    return first, count
+
+
 class MLAttention(nn.Layer):
     """Multi-head latent attention (DeepSeek-V2/V3; HF ``DeepseekV3Attention``)
-    in its training form, nothing absorbed: q through a low-rank pair with a
-    RMSNorm between, ``[c_kv ; k_r] = x W_kva`` with c_kv normed and expanded
-    to each head's (no-position key, value), RoPE on each head's
+    in its training form — the latent is EXPANDED to every head's keys and
+    values; the absorbed form, which folds ``kv_b_proj`` into the query and
+    the output, is a decode-time rewrite and is not built —: q through a
+    low-rank pair with a RMSNorm between, ``[c_kv ; k_r] = x W_kva`` with
+    c_kv normed and expanded to each head's (no-position key, value), RoPE
+    on each head's
     ``rope_dim`` query features and on the ONE ``rope_dim``-wide k_r that
     all heads share, then a causal core whose keys (``nope_dim + rope_dim``)
     are wider than its values (``v_dim``), through the dispatching sdpa (the
@@ -509,19 +525,51 @@ class MLAttention(nn.Layer):
     ``q_lora_rank=None``: one q projection (``q_proj``), no norm between.
     ``rope=False`` (Kimi Linear's ``mla_use_nope``): nothing is rotated, the
     ``rope_dim``-wide shared key part is broadcast and concatenated as it
-    is; positions then reach the layer only through the causal mask."""
+    is; positions then reach the layer only through the causal mask.
+
+    ``rope_scaling`` (a config's group of ``type: yarn``): the rotation's
+    frequencies are YaRN's blended table, cos and sin carry its attention
+    factor and the softmax scale ``(nope_dim + rope_dim) ** -0.5`` its
+    ``yarn_mscale(factor, mscale_all_dim) ** 2`` (``ops.attention
+    .yarn_rope``); None rotates by ``rope_theta``'s one table at the plain
+    scale, and lowers to the program it always did.
+
+    ``held_heads=(first, count)`` builds ONE CHIP'S SHARE of a layer whose
+    ``num_heads`` heads are divided over chips (tensor parallelism without
+    its exchange): those heads' columns of ``q_b_proj`` (or ``q_proj``) and
+    of ``kv_b_proj`` and their rows of ``o_proj``; the low-rank ``q_a_proj``
+    / ``kv_a_proj_with_mqa``, both latent norms and the one shared k_r are
+    every head's and are whole on every chip. ``o_proj`` gives a PARTIAL SUM
+    over the heads held: what the absent chips would add is left out, and
+    nothing stands in for them or their all-reduce. Scopes: ``mla.q`` /
+    ``.kv`` / ``.rope`` / ``.core`` / ``.out``."""
 
     def __init__(self, hidden_size, num_heads, q_lora_rank, kv_lora_rank,
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                  rms_norm_eps=1e-6, rope_theta=10000.0, weight_attr=None,
-                 rope=True):
+                 rope=True, held_heads=None, rope_scaling=None):
         super().__init__()
+        self.held_heads = None
+        if held_heads is not None:
+            self.held_heads = _held_range(held_heads, num_heads)
+            num_heads = self.held_heads[1]   # what is built and run here
         self.num_heads = num_heads
         self.nope_dim, self.rope_dim = qk_nope_head_dim, qk_rope_head_dim
         self.v_dim = v_head_dim
         self.kv_rank = kv_lora_rank
         self.rope_theta = float(rope_theta)
         self.rope = bool(rope)
+        # static arguments of the rotation and the core that only a scaled
+        # RoPE gives: without one both calls are the ones they always were
+        self._rope_args, self._core_args = {}, {}
+        if rope_scaling is not None:
+            from ..ops.attention import yarn_rope
+
+            inv_freq, mscale, softmax = yarn_rope(
+                rope_scaling, qk_rope_head_dim, self.rope_theta)
+            self._rope_args = {"inv_freq": inv_freq, "mscale": mscale}
+            self._core_args = {"scale": softmax * (
+                qk_nope_head_dim + qk_rope_head_dim) ** -0.5}
 
         def proj(i, o):
             return nn.Linear(i, o, weight_attr=weight_attr, bias_attr=False)
@@ -548,13 +596,14 @@ class MLAttention(nn.Layer):
         from ..core.dispatch import apply_op
         from ..ops.attention import scaled_dot_product_attention as _sdpa
 
-        nh, nope, rope, dv = (self.num_heads, self.nope_dim, self.rope_dim,
-                              self.v_dim)
-
         def _split(kv_a, *, rank):
             return kv_a[..., :rank], kv_a[..., rank:]
 
-        def _heads(q, kv, k_r, *, base, rotate):
+        # every size rides the static arguments: eager dispatch caches an
+        # op's program by name and static arguments, and a share's head
+        # count is not the whole layer's
+        def _heads(q, kv, k_r, *, nh, nope, rope, dv, base, rotate,
+                   **scaled):
             b, s, _ = q.shape
             q = q.reshape(b, s, nh, nope + rope).transpose(0, 2, 1, 3)
             kv = kv.reshape(b, s, nh, nope + dv).transpose(0, 2, 1, 3)
@@ -562,9 +611,10 @@ class MLAttention(nn.Layer):
             if rotate:
                 # interleaved pairs (2i, 2i + 1) as the checkpoint stores
                 # them (rope_interleave); one rotated k_r serves every head
-                q = jnp.concatenate([q[..., :nope],
-                                     _rope(q[..., nope:], base)], axis=-1)
-                k_r = _rope(k_r, base)
+                q = jnp.concatenate(
+                    [q[..., :nope], _rope(q[..., nope:], base, **scaled)],
+                    axis=-1)
+                k_r = _rope(k_r, base, **scaled)
             k_r = jnp.broadcast_to(k_r, (b, nh, s, rope))
             return (q, jnp.concatenate([kv[..., :nope], k_r], axis=-1),
                     kv[..., nope:])
@@ -581,9 +631,13 @@ class MLAttention(nn.Layer):
             kv = self.kv_b_proj(self.kv_a_layernorm(c_kv))
         with jax.named_scope("mla.rope"):
             q, k, v = apply_op("mla_heads_rope", _heads, q, kv, k_r,
-                               base=self.rope_theta, rotate=self.rope)
+                               nh=self.num_heads, nope=self.nope_dim,
+                               rope=self.rope_dim, dv=self.v_dim,
+                               base=self.rope_theta, rotate=self.rope,
+                               **self._rope_args)
         with jax.named_scope("mla.core"):
-            out = _sdpa(q, k, v, is_causal=True, training=self.training)
+            out = _sdpa(q, k, v, is_causal=True, training=self.training,
+                        **self._core_args)
         with jax.named_scope("mla.out"):
             return self.o_proj(apply_op("merge_heads", _merge_heads, out))
 
@@ -611,7 +665,9 @@ class JoyAIDecoderLayer(nn.Layer):
                 hidden, cfg["num_attention_heads"], cfg["q_lora_rank"],
                 cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
                 cfg["qk_rope_head_dim"], cfg["v_head_dim"], eps,
-                cfg["rope_theta"], weight_attr, rope=cfg.get("rope", True))
+                cfg["rope_theta"], weight_attr, rope=cfg.get("rope", True),
+                held_heads=cfg.get("held_attention_heads"),
+                rope_scaling=cfg.get("rope_scaling"))
         self.post_attention_layernorm = RMSNorm(hidden, eps=eps)
         if dense:
             self.mlp = LlamaMLP(hidden, cfg["intermediate_size"],
@@ -2202,15 +2258,12 @@ class Mamba2Mixer(nn.Layer):
                              f"{n_groups} groups")
         self.held_heads = None
         if held_heads is not None:
-            first, count = (int(v) for v in held_heads)
             per_group = num_heads // n_groups
-            if not (0 <= first < first + count <= num_heads
-                    and first % per_group == 0 and count % per_group == 0):
-                raise ValueError(
-                    f"held_heads=(first, count)={held_heads!r} is no range "
-                    f"of whole groups ({per_group} heads each) of the "
-                    f"{num_heads} heads")
-            self.held_heads = (first, count)
+            first, count = self.held_heads = _held_range(
+                held_heads, num_heads,
+                lambda first, count: (first % per_group == 0
+                                      and count % per_group == 0),
+                f"no range of whole groups ({per_group} heads each)")
             # what is built and run here: the share's heads and groups
             num_heads, n_groups = count, count // per_group
         self.num_heads, self.head_dim = num_heads, head_dim
@@ -2498,16 +2551,14 @@ class NemotronAttention(nn.Layer):
                              f"{num_kv_heads} key/value heads")
         self.held_heads = None
         if held_heads is not None:
-            first, count = (int(v) for v in held_heads)
             per_kv = num_heads // num_kv_heads
-            whole = first % per_kv == 0 and count % per_kv == 0
-            if not (0 <= first < first + count <= num_heads and (
-                    whole or (per_kv % count == 0 and first % count == 0))):
-                raise ValueError(
-                    f"held_heads=(first, count)={held_heads!r} is neither "
-                    f"whole key/value heads' queries ({per_kv} each) nor a "
-                    f"part of one's, of {num_heads} heads")
-            self.held_heads = (first, count)
+            first, count = self.held_heads = _held_range(
+                held_heads, num_heads,
+                lambda first, count: (
+                    (first % per_kv == 0 and count % per_kv == 0)
+                    or (per_kv % count == 0 and first % count == 0)),
+                f"neither whole key/value heads' queries ({per_kv} each) "
+                f"nor a part of one's,")
             # what is built and run here
             num_heads, num_kv_heads = count, max(1, count // per_kv)
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
@@ -2683,3 +2734,241 @@ class NemotronHModel(_MTPDecoder):
                     for kind in mtp_types]),
                 weight_attr=attr())
             for _ in range(num_nextn_predict_layers)])
+
+
+class HyperConnection(nn.Layer):
+    """One sublayer's manifold-constrained hyper-connection (mHC,
+    arXiv:2512.24880; ``ops/hyper_connections.py`` holds the equations and
+    the layout): the parameters ``phi [n C, 2 n + n^2]``, ``b`` and ``alpha =
+    (pre, post, res)`` of the three maps, and the two halves of the residual
+    path around a sublayer F on streams ``[B, T, n C]``:
+    ``u, mix = read(streams)`` — the maps from the streams' own RMS-normed
+    features, ``H_pre`` a sigmoid, ``H_post`` twice a sigmoid, ``H_res`` the
+    Sinkhorn-Knopp projection of ``exp(clamp(.))`` onto the doubly stochastic
+    matrices in ``iters`` rounds, and ``u = H_pre X`` for F's norm to read;
+    ``write(streams, F(norm(u)), mix)`` gives ``H_res X + H_post^T y``.
+    The maps' arithmetic is float32 whatever amp says, as a router's is.
+
+    At initialisation no map is constant or saturated: ``phi ~ Normal(0,
+    (n C)^-1/2)`` (the maps' pre-activations are spread by 1 over tokens),
+    ``alpha = 1``, ``b = 0`` but for ``RES_DIAGONAL`` on the diagonal of
+    ``H~_res`` (the residual mix leans to each stream keeping itself). ``b``
+    and ``alpha`` are named for an optimizer's ``apply_decay_param_fun``.
+    Scopes: ``mhc.maps`` / ``.sinkhorn`` / ``.pre`` / ``.post``."""
+
+    #: the start of ``H~_res``'s diagonal
+    RES_DIAGONAL = 2.0
+
+    def __init__(self, hidden_size, n=4, sinkhorn_iters=20, eps=1e-6,
+                 clamp=(-30.0, 30.0), rms_norm_eps=1e-6):
+        super().__init__("mhc")
+        from ..framework.param_attr import ParamAttr
+
+        self.n, self.iters = int(n), int(sinkhorn_iters)
+        self.eps, self.norm_eps = float(eps), float(rms_norm_eps)
+        self.clamp = None if clamp is None else tuple(float(v) for v in clamp)
+        wide, maps = n * hidden_size, 2 * n + n * n
+
+        def named(name, initializer):
+            return ParamAttr(name=f"{self.full_name()}.{name}",
+                             initializer=initializer)
+
+        self.phi = self.create_parameter(
+            [wide, maps], attr=named("phi", nn.initializer.Normal(
+                0.0, wide ** -0.5)))
+        self.b = self.create_parameter(
+            [maps], attr=named("b", nn.initializer.Constant(0.0)))
+        bias = np.zeros(maps, np.float32)
+        bias[2 * n:] = self.RES_DIAGONAL * np.eye(
+            n, dtype=np.float32).reshape(-1)
+        self.b.set_value(bias)
+        self.alpha = self.create_parameter(
+            [3], attr=named("alpha", nn.initializer.Constant(1.0)))
+
+    def read(self, streams):
+        """streams [B, T, n C] -> (u [B, T, C], (H_post, H_res))."""
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops import hyper_connections as hc
+
+        hc.count_sublayer(self.iters)
+        with jax.named_scope("mhc.maps"):
+            h = apply_op("mhc_maps", hc.maps, streams, self.phi, self.b,
+                         self.alpha, n=self.n, eps=self.norm_eps)
+        with jax.named_scope("mhc.sinkhorn"):
+            h_pre, h_post, h_res = apply_op(
+                "mhc_coefficients", hc.coefficients, h, n=self.n,
+                iters=self.iters, eps=self.eps, clamp=self.clamp)
+        with jax.named_scope("mhc.pre"):
+            u = apply_op("mhc_pre", hc.pre, streams, h_pre, n=self.n)
+        return u, (h_post, h_res)
+
+    def write(self, streams, y, mix):
+        import jax
+
+        from ..core.dispatch import apply_op
+        from ..ops import hyper_connections as hc
+
+        with jax.named_scope("mhc.post"):
+            return apply_op("mhc_post", hc.post, streams, y, *mix, n=self.n)
+
+
+def _mhc_expand(x, n):
+    import jax
+
+    from ..core.dispatch import apply_op
+    from ..ops import hyper_connections as hc
+
+    with jax.named_scope("mhc.expand"):
+        return apply_op("mhc_expand", hc.expand, x, n=n)
+
+
+def _mhc_reduce(streams, n):
+    import jax
+
+    from ..core.dispatch import apply_op
+    from ..ops import hyper_connections as hc
+
+    with jax.named_scope("mhc.reduce"):
+        return apply_op("mhc_reduce", hc.reduce, streams, n=n)
+
+
+class Xing4DecoderLayer(JoyAIDecoderLayer):
+    """A hyper-connected decoder block (Xing4.0; HF ``xing4_0``): the
+    DeepSeek-V3 family's two sublayers — latent attention behind
+    ``input_layernorm``, then the dense SwiGLU or the expert layer behind
+    ``post_attention_layernorm`` — each INSIDE its own ``HyperConnection``
+    (``attn_hc``, ``mlp_hc``): the block carries ``hc_mult`` residual
+    streams ``[B, T, n C]`` in and out, a sublayer reads ``H_pre X`` and its
+    output goes back through ``H_res X + H_post^T y``. ``own_streams``: the
+    block takes and gives ONE hidden state, replicating it to the streams at
+    its start and summing them at its end (the MTP module's block)."""
+
+    def __init__(self, cfg, dense, weight_attr=None, own_streams=False):
+        super().__init__(cfg, dense, weight_attr)
+        self.hc_mult, self.own_streams = cfg["hc_mult"], bool(own_streams)
+
+        def connection():
+            return HyperConnection(
+                cfg["hidden_size"], cfg["hc_mult"], cfg["hc_sinkhorn_iters"],
+                cfg["hc_eps"], cfg["mhc_h_res_clamp"], cfg["rms_norm_eps"])
+
+        self.attn_hc, self.mlp_hc = connection(), connection()
+
+    def forward(self, x):
+        if self.own_streams:
+            x = _mhc_expand(x, self.hc_mult)
+        u, mix = self.attn_hc.read(x)
+        x = self.attn_hc.write(
+            x, self.self_attn(self.input_layernorm(u)), mix)
+        u, mix = self.mlp_hc.read(x)
+        x = self.mlp_hc.write(
+            x, self.mlp(self.post_attention_layernorm(u)), mix)
+        return _mhc_reduce(x, self.hc_mult) if self.own_streams else x
+
+
+class Xing4Model(_MTPDecoder):
+    """Xing4.0 (XingChen-AGI, HF ``xing4_0``; Xing4.0-29B-A4B's sizes are
+    the defaults): the DeepSeek-V3 family's decoder — latent attention in
+    every block, ``first_k_dense_replace`` dense SwiGLU blocks, then expert
+    blocks under a sigmoid router with a selection bias and a shared expert,
+    an untied head, ``num_nextn_predict_layers`` multi-token-prediction
+    modules — on a CHANGED RESIDUAL PATH: manifold-constrained
+    hyper-connections (``Xing4DecoderLayer``, ``HyperConnection``). The
+    embedding is replicated to ``hc_mult`` streams, every block carries
+    them, and their SUM (arXiv:2409.19606) is what the final norm and the
+    MTP module read; the module's block has streams of its own. Latent
+    attention rotates by YaRN's table and scales its softmax by YaRN's
+    factor (``rope_scaling``, ``MLAttention``).
+
+    ``held_experts`` and ``held_attention_heads`` (each ``(first, count)``)
+    give every layer this chip's share of a deployment that divides each
+    layer over chips; each sublayer computes its own experts' or heads' part
+    and that partial sum goes on. ``use_recompute`` runs each block under
+    ``fleet.utils.recompute`` in a traced step. ``forward`` gives the main
+    logits; a training loss takes ``training_features`` and
+    ``lm_head.weight`` to ``mtp_lm_loss``."""
+
+    #: the published ``rope_scaling`` group
+    YARN = {"type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096}
+
+    def __init__(self, vocab_size=131072, hidden_size=3584,
+                 num_hidden_layers=40, num_attention_heads=32,
+                 intermediate_size=9216, moe_intermediate_size=1024,
+                 n_routed_experts=64, num_experts_per_tok=4,
+                 n_shared_experts=1, first_k_dense_replace=2,
+                 q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, rms_norm_eps=1e-6,
+                 rope_theta=10000.0, rope_scaling=YARN, norm_topk_prob=True,
+                 routed_scaling_factor=2.0, num_nextn_predict_layers=1,
+                 hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+                 mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0,
+                 bias_update_speed=0.001, balance_loss_weight=0.0,
+                 initializer_range=0.02, held_experts=None,
+                 held_rows_factor=2.0, held_attention_heads=None,
+                 use_recompute=False):
+        super().__init__(use_recompute)
+        from ..framework.param_attr import ParamAttr
+
+        def attr():
+            return ParamAttr(initializer=nn.initializer.Normal(
+                0.0, initializer_range))
+
+        def held(share):
+            return None if share is None else tuple(share)
+
+        self.hc_mult = int(hc_mult)
+        cfg = dict(
+            hidden_size=hidden_size, num_attention_heads=num_attention_heads,
+            intermediate_size=intermediate_size,
+            moe_intermediate_size=moe_intermediate_size,
+            n_routed_experts=n_routed_experts,
+            num_experts_per_tok=num_experts_per_tok,
+            n_shared_experts=n_shared_experts, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rms_norm_eps=rms_norm_eps, rope_theta=rope_theta,
+            rope_scaling=rope_scaling, norm_topk_prob=norm_topk_prob,
+            routed_scaling_factor=routed_scaling_factor,
+            bias_update_speed=bias_update_speed,
+            balance_loss_weight=balance_loss_weight,
+            held_experts=held(held_experts),
+            held_rows_factor=held_rows_factor,
+            held_attention_heads=held(held_attention_heads),
+            hc_mult=self.hc_mult, hc_sinkhorn_iters=hc_sinkhorn_iters,
+            hc_eps=hc_eps,
+            mhc_h_res_clamp=(mhc_h_res_clamp_min, mhc_h_res_clamp_max))
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=attr())
+        self.layers = nn.LayerList([
+            Xing4DecoderLayer(cfg, dense=i < first_k_dense_replace,
+                              weight_attr=attr())
+            for i in range(num_hidden_layers)])
+        self.norm = RMSNorm(hidden_size, eps=rms_norm_eps)
+        self.lm_head = nn.Linear(hidden_size, vocab_size,
+                                 weight_attr=attr(), bias_attr=False)
+        self.mtp = nn.LayerList([
+            MultiTokenPredictor(
+                hidden_size, rms_norm_eps,
+                lambda: Xing4DecoderLayer(cfg, dense=False,
+                                          weight_attr=attr(),
+                                          own_streams=True),
+                weight_attr=attr())
+            for _ in range(num_nextn_predict_layers)])
+
+    def embed(self, input_ids):
+        """The embedding, replicated to the streams: [B, T, hc_mult C]."""
+        return _mhc_expand(self.embed_tokens(input_ids), self.hc_mult)
+
+    def reduce(self, streams):
+        """The streams' sum: what the final norm and the MTP module read."""
+        return _mhc_reduce(streams, self.hc_mult)
+
+    def _trunk(self, input_ids):
+        x = self.embed(input_ids)
+        for layer in self.layers:
+            x = self._block(layer, x)
+        return self.reduce(x)

@@ -387,17 +387,6 @@ def test_a_held_mixer_is_the_whole_mixers_slice_bit_for_bit(held):
         np.asarray(whole.heads_output(x)._value)[..., at])
 
 
-def test_a_share_is_whole_groups_of_the_heads():
-    for bad in [(1, 2), (0, 3), (6, 4), (0, 0)]:
-        with pytest.raises(ValueError, match="whole groups"):
-            Mamba2Mixer(64, num_heads=8, head_dim=16, d_state=32, n_groups=4,
-                        held_heads=bad)
-    for bad in [(0, 3), (2, 4), (6, 4), (1, 2)]:
-        with pytest.raises(ValueError, match="key/value"):
-            NemotronAttention(64, num_heads=8, num_kv_heads=2, head_dim=16,
-                              held_heads=bad)
-
-
 def test_the_gated_norm_is_a_groups(reference):
     """With more than one group the mean square is over a group's features:
     another function than Granite's one mean square over all of them."""
